@@ -58,6 +58,8 @@ def initialize(args=None,
     tuple-compatibility with the reference; optimizer/lr_scheduler are the
     engine-owned objects.
     """
+    from deepspeed_tpu.utils import setup_compile_cache
+    setup_compile_cache()
     config = config if config is not None else config_params
     assert config is not None, "deepspeed_tpu.initialize requires a config"
     assert model is not None, "deepspeed_tpu.initialize requires a loss function"
@@ -121,6 +123,8 @@ def initialize(args=None,
 def init_inference(model=None, **kwargs):
     """Inference engine entry (ref: deepspeed/__init__.py:220)."""
     from deepspeed_tpu.inference.engine import InferenceEngine
+    from deepspeed_tpu.utils import setup_compile_cache
+    setup_compile_cache()
     return InferenceEngine(model, **kwargs)
 
 

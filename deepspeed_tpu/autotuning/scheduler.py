@@ -11,8 +11,9 @@ dispatches experiments, records results. Two dispatch modes:
   of the reference launching every experiment as a separate job
   (ref: scheduler.py:35 run_job + :183 parse_results). Process
   isolation is what makes unattended tuning safe here: a diverging
-  candidate, a borderline-HBM compile, or a wedged remote compile
-  helper costs its own timeout, never the tuning loop.
+  candidate or a borderline-HBM compile costs its own timeout, never
+  the tuning loop. A chip belongs to one process at a time, so the
+  process that runs ``SubprocessRunner`` must itself stay off the TPU.
 """
 
 import json
@@ -107,6 +108,13 @@ class SubprocessRunner:
         raise ExperimentError("error", "no {\"metric\": ...} line in output")
 
     def __call__(self, ds_config: Dict) -> float:
+        from deepspeed_tpu.utils import holds_chip
+        if holds_chip():
+            raise RuntimeError(
+                "this process has initialised the TPU backend and holds "
+                "the chip: an experiment subprocess could not reach it. "
+                "Run experiments in-process (Autotuner.run_ds_config) or "
+                "keep the scheduling process off JAX")
         tmp = None
         if self.cmd_builder is not None:
             argv = self.cmd_builder(ds_config)

@@ -83,9 +83,10 @@ def main():
             lines.append(f"HBM per device: {stats['bytes_limit'] / 1e9:.1f} GB")
     except Exception:  # dslint: disable=DS006 — best-effort report probe
         pass
-    cache = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if cache:
-        lines.append(f"compilation cache: {cache}")
+    from deepspeed_tpu.utils import setup_compile_cache
+    via = "JAX_COMPILATION_CACHE_DIR" \
+        if os.environ.get("JAX_COMPILATION_CACHE_DIR") else "default"
+    lines.append(f"compilation cache: {setup_compile_cache()} ({via})")
     lines.append("-" * 70)
 
     for name, ok, note in _feature_rows():

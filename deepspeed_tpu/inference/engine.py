@@ -20,6 +20,7 @@ KV-cache management via the global Context workspace). TPU-native design:
   a host loop over compiled prefill + decode programs.
 """
 
+import dataclasses
 from dataclasses import dataclass
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
@@ -102,6 +103,10 @@ def _block_prefill(x, p, cfg: GPTConfig, kv_mask=None, positions=None):
     h = _norm(x, p["ln1"], cfg)
     qkv = _dense(h, p["qkv"])
     q, k, v = gpt_lib._qkv_split_rotary(qkv, cfg, positions, B, S)
+    if cfg.use_flash_attention and S % 128:
+        # a prompt bucket the flash kernel cannot tile prefills through
+        # the dense path: chosen from the traced shape, on every platform
+        cfg = dataclasses.replace(cfg, use_flash_attention=False)
     attn = gpt_lib._attention(q, k, v, cfg, kv_mask=kv_mask).reshape(B, S, D)
     attn = _dense(attn, p["attn_out"])
     if cfg.parallel_residual:
@@ -642,17 +647,20 @@ class InferenceEngine:
         self.decode_impl = resolve_decode_impl(decode_impl)
 
         if mesh is None:
-            n = len(jax.devices())
-            assert n % mp_size == 0, (n, mp_size)
+            # one engine drives mp_size devices, the first of the host by
+            # default. Replicating one engine over every chip only repeats
+            # one chip's work: the many-chip form is one engine per device
+            # (pass ``mesh``) behind ReplicaRouter
+            devs = jax.devices()
+            assert len(devs) >= mp_size, (len(devs), mp_size)
             mesh = mesh_lib.make_mesh(
-                mesh_lib.MeshSpec(data=n // mp_size, model=mp_size))
+                mesh_lib.MeshSpec(data=1, model=mp_size), devs[:mp_size])
         self.mesh = mesh
 
         from deepspeed_tpu.models.bert import BertConfig as _BertConfig
         self.is_encoder = isinstance(config, _BertConfig)
         if self.is_encoder and config.dtype != dtype:
             # bert.encode casts by cfg.dtype; keep it in the engine dtype
-            import dataclasses
             self.cfg = config = dataclasses.replace(config, dtype=dtype)
 
         # dtype conversion (ref: engine.py:335 _convert_to_dtype) + TP placement
@@ -810,10 +818,13 @@ class InferenceEngine:
             self._gather_blocks_q = jax.jit(self._gather_blocks_q_fn)
             self._scatter_block_q = jax.jit(self._scatter_block_q_fn,
                                             donate_argnums=(0, 1, 2, 3))
-        log_dist(f"inference engine: {config.n_layers}L/{config.d_model}d "
-                 f"mp={mp_size} dtype={jnp.dtype(dtype).name} "
-                 f"{'encoder' if self.is_encoder else 'decoder'}",
-                 ranks=[0])
+        dev0 = mesh.devices.flat[0]
+        log_dist(f"inference engine ready: {config.n_layers}L/"
+                 f"{config.d_model}d mp={mp_size} "
+                 f"dtype={jnp.dtype(dtype).name} "
+                 f"{'encoder' if self.is_encoder else 'decoder'}, "
+                 f"platform={dev0.platform}, devices={mesh.devices.size}, "
+                 f"decode_impl={self.decode_impl}", ranks=[0])
 
     # ------------------------------------------------------------------
     # params are threaded explicitly (never via self) so jit treats the

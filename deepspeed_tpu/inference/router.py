@@ -2,7 +2,7 @@
 :class:`~deepspeed_tpu.inference.serving.ServingEngine` replicas.
 
 A single serving engine is a single failure domain: one watchdog trip
-or wedged device degrades ALL in-flight traffic. The router is the
+or hung device degrades ALL in-flight traffic. The router is the
 scale-out tier above it (the Orca/vLLM deployment shape): N replicas —
 each holding its own paged KV pool and slots — behind one
 :class:`~deepspeed_tpu.inference.serving.ServeRequest`-shaped front
@@ -445,7 +445,7 @@ class ReplicaRouter:
         # disaggregated handoff harvest: a prefill-role replica whose
         # chunked prefill just finished parks the request in a handoff
         # slot — migrate each one to a decode-capable replica now, or
-        # degrade it to a cold re-prefill (never leave it wedged)
+        # degrade it to a cold re-prefill (never leave it stuck)
         for rep in list(self.replicas):
             if rep.health in (BROKEN, RETIRED) or not rep.srv.prefill_only:
                 continue

@@ -136,15 +136,10 @@ def encode(params: Dict, tokens: jnp.ndarray, cfg: BertConfig,
         # selective policy never saves the attention output twice
         # (packed flash_out + 'attn').
         from deepspeed_tpu.models.gpt import remat_policy
-        head_dim = cfg.d_model // cfg.n_heads
-        try:
-            d0 = jax.devices()[0]
-            on_tpu = "tpu" in (d0.platform + d0.device_kind).lower()
-        except Exception:
-            on_tpu = False
+        from deepspeed_tpu.ops.transformer.encoder_layer import flash_block
         # masked batches take the flash path too (kv_mask support); the
         # gate must still mirror _attention_core's dropout condition
-        flash_used = (S >= 128 and head_dim % 8 == 0 and on_tpu
+        flash_used = (flash_block(S, cfg.d_model // cfg.n_heads) is not None
                       and (deterministic or cfg.dropout == 0.0))
         body = jax.checkpoint(
             body, policy=remat_policy(cfg.remat_policy, flash=flash_used))

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P, get_abstract_mesh
 
 from deepspeed_tpu.parallel.sharding import PartitionRule
 
@@ -96,9 +96,8 @@ class GPTConfig:
     # only — O(S*window) compute and HBM reads in the flash kernel
     attn_window: Optional[int] = None
     # "banded" (O(S*W) index-map clamps) or "masked" (in-body mask over
-    # plain causal geometry — the Mosaic-proven fallback while the
-    # banded clamp is under the r4 compile-hang quarantine); None
-    # resolves from DS_FLASH_WINDOW_IMPL (default banded)
+    # plain causal geometry, O(S^2) reads); None resolves from
+    # DS_FLASH_WINDOW_IMPL (default banded)
     attn_window_impl: Optional[str] = None
     # --- llama-family architecture knobs -------------------------------
     # norm: 'layernorm' (GPT-2) or 'rmsnorm' (llama — scale only, no
@@ -365,32 +364,79 @@ def _layernorm(x, scale, bias, eps=1e-5):
     return (y * scale + bias).astype(x.dtype)
 
 
-def _effective_block(pref: int, seq_len: int) -> Optional[int]:
-    """Largest block <= pref (>=128) that divides seq_len — keeps the
-    flash kernel active when the preferred size doesn't tile the
-    sequence (e.g. 1024-blocks with S=1536 fall back to 512)."""
-    b = min(pref, seq_len)
-    while b >= 128 and seq_len % b != 0:
-        b //= 2
-    return b if b >= 128 and seq_len % b == 0 else None
-
-
 def _flash_blocks(cfg: GPTConfig, seq_len: int):
-    """(block_q, block_kv) for this sequence, or None if ineligible.
-    Explicit gate (no blanket except — Mosaic failures surface at
-    jit-compile time, outside any trace-time try)."""
-    if not cfg.use_flash_attention or seq_len < 128:
+    """(block_q, block_kv) when ``_attention`` runs the Pallas flash
+    kernel for this sequence, None when it runs the dense reference.
+
+    The kernel is the TPU implementation. Off a TPU the platform default
+    is the dense path, and the engine's "engine ready" line says so. On
+    a TPU a config that asks for the kernel gets it or an error: a run
+    that printed an MFU never lost flash to a quiet shape gate."""
+    if not cfg.use_flash_attention:
         return None
-    bq = _effective_block(cfg.flash_block_q, seq_len)
-    bkv = _effective_block(cfg.flash_block_kv, seq_len)
-    if bq is None or bkv is None:
-        return None
+    from deepspeed_tpu.ops.attention.flash import fit_block
     from deepspeed_tpu.utils import on_tpu
-    return (bq, bkv) if on_tpu() else None
+    if not on_tpu():
+        return None
+    bq = fit_block(cfg.flash_block_q, seq_len)
+    bkv = fit_block(cfg.flash_block_kv, seq_len)
+    if bq is None or bkv is None:
+        raise ValueError(
+            f"use_flash_attention=True, but no flash block of 128 or more "
+            f"divides seq_len={seq_len}: pad the sequence to a multiple of "
+            f"128 or set use_flash_attention=False")
+    return bq, bkv
 
 
 def _flash_eligible(cfg: GPTConfig, seq_len: int) -> bool:
     return _flash_blocks(cfg, seq_len) is not None
+
+
+def attention_impl(cfg: GPTConfig, seq_len: Optional[int] = None) -> str:
+    """Name of the attention implementation ``_attention`` traces for
+    ``seq_len`` (default ``cfg.max_seq_len``) on this platform."""
+    S = seq_len or cfg.max_seq_len
+    sp = ""
+    if cfg.sequence_parallel and cfg.mesh is not None:
+        sp = cfg.sp_impl + "+"
+        if cfg.sp_impl == "ring":
+            S //= cfg.mesh.shape["sequence"]
+    blocks = _flash_blocks(cfg, S)
+    return sp + (f"flash({blocks[0]}x{blocks[1]})" if blocks else "dense")
+
+
+def _flash_per_device(q, k, v, segment_ids, kv_mask, **kw):
+    """``flash_attention`` on each device's own share of the batch and
+    heads. XLA cannot partition a Mosaic custom call, and jax refuses to
+    lower one inside a sharded jit, so under a mesh the call is mapped by
+    hand: batch over the data-parallel axes, heads over 'model' when it
+    divides them (qkv is column-parallel, so that is where they live).
+    The map takes EVERY axis no enclosing shard_map holds yet, those of
+    size 1 too: the kernel lowers only where the whole mesh is manual."""
+    from deepspeed_tpu.ops.attention.flash import flash_attention
+    m = get_abstract_mesh()
+    auto = [] if m is None or m.empty else [
+        n for n, ty in zip(m.axis_names, m.axis_types)
+        if ty != AxisType.Manual]
+    if all(m.shape[n] == 1 for n in auto):
+        return flash_attention(q, k, v, segment_ids=segment_ids,
+                               kv_mask=kv_mask, **kw)
+    batch = tuple(a for a in ("data", "fsdp") if a in auto) or None
+    tp = m.shape["model"] if "model" in auto else 1
+    heads = "model" if tp > 1 and q.shape[2] % tp == 0 \
+        and k.shape[2] % tp == 0 else None
+    spec, tok = P(batch, None, heads, None), P(batch, None)
+    # optional per-token metadata rides as extra mapped operands
+    extra = {name: x for name, x in (("segment_ids", segment_ids),
+                                     ("kv_mask", kv_mask)) if x is not None}
+
+    def local(q, k, v, *meta):
+        return flash_attention(q, k, v, **dict(zip(extra, meta)), **kw)
+
+    return jax.shard_map(
+        local, in_specs=(spec, spec, spec) + (tok,) * len(extra),
+        out_specs=spec, axis_names=set(auto), check_vma=False)(
+            q, k, v, *extra.values())
 
 
 def _attention(q, k, v, cfg: GPTConfig, segment_ids=None, kv_mask=None):
@@ -399,6 +445,7 @@ def _attention(q, k, v, cfg: GPTConfig, segment_ids=None, kv_mask=None):
     segment_ids: optional [B, S] packed-sequence ids — attention stays
     inside each segment (block-diagonal x causal).
     kv_mask: optional [B, S] key-validity mask (left-padded prompts)."""
+    from deepspeed_tpu.ops.attention.flash import fit_block, mha_reference
     scale = cfg.attn_scale  # None -> kernels default to 1/sqrt(Dh)
     if cfg.sequence_parallel and cfg.mesh is not None:
         # GQA works under both SP impls: ring rotates the small grouped
@@ -422,9 +469,9 @@ def _attention(q, k, v, cfg: GPTConfig, segment_ids=None, kv_mask=None):
                 segment_ids=segment_ids, kv_mask=kv_mask,
                 window=cfg.attn_window,
                 window_impl=cfg.attn_window_impl,
-                bwd_block_q=(_effective_block(cfg.flash_block_bwd_q, S)
+                bwd_block_q=(fit_block(cfg.flash_block_bwd_q, S)
                              if cfg.flash_block_bwd_q else None),
-                bwd_block_kv=(_effective_block(cfg.flash_block_bwd_kv, S)
+                bwd_block_kv=(fit_block(cfg.flash_block_bwd_kv, S)
                               if cfg.flash_block_bwd_kv else None))
         if cfg.sp_impl != "ring":
             raise ValueError(f"unknown sp_impl {cfg.sp_impl!r} "
@@ -445,22 +492,19 @@ def _attention(q, k, v, cfg: GPTConfig, segment_ids=None, kv_mask=None):
             window_impl=cfg.attn_window_impl)
     blocks = _flash_blocks(cfg, q.shape[1])
     if blocks is not None:
-        from deepspeed_tpu.ops.attention.flash import flash_attention
         # bwd overrides pass through the same divisibility normalization
         # as the fwd blocks (a non-dividing block would truncate the
         # backward grid); fall back to the fwd block when none divides
         S = q.shape[1]
-        bwd_q = (_effective_block(cfg.flash_block_bwd_q, S)
+        bwd_q = (fit_block(cfg.flash_block_bwd_q, S)
                  if cfg.flash_block_bwd_q else None)
-        bwd_kv = (_effective_block(cfg.flash_block_bwd_kv, S)
+        bwd_kv = (fit_block(cfg.flash_block_bwd_kv, S)
                   if cfg.flash_block_bwd_kv else None)
-        return flash_attention(q, k, v, causal=True, scale=scale,
-                               block_q=blocks[0], block_kv=blocks[1],
-                               segment_ids=segment_ids, kv_mask=kv_mask,
-                               window=cfg.attn_window,
-                               window_impl=cfg.attn_window_impl,
-                               bwd_block_q=bwd_q, bwd_block_kv=bwd_kv)
-    from deepspeed_tpu.ops.attention.flash import mha_reference
+        return _flash_per_device(
+            q, k, v, segment_ids, kv_mask, causal=True, scale=scale,
+            block_q=blocks[0], block_kv=blocks[1], window=cfg.attn_window,
+            window_impl=cfg.attn_window_impl,
+            bwd_block_q=bwd_q, bwd_block_kv=bwd_kv)
     return mha_reference(q, k, v, causal=True, scale=scale,
                          segment_ids=segment_ids, kv_mask=kv_mask,
                          window=cfg.attn_window)
@@ -564,17 +608,6 @@ def forward(params: Dict, tokens: jnp.ndarray, cfg: GPTConfig,
                    "sequence" if cfg.sequence_parallel else None, None)
 
     def _pin(t):
-        try:
-            from jax.sharding import AxisType, get_abstract_mesh
-        except ImportError:
-            # jax<=0.4.x has no AxisType/abstract-mesh introspection:
-            # apply the constraint and fall back where the trace context
-            # rejects it (no mesh in scope, or a shard_map manual region
-            # — both raise at trace time on those versions)
-            try:
-                return jax.lax.with_sharding_constraint(t, carry_spec)
-            except Exception:
-                return t
         m = get_abstract_mesh()
         if m is None or m.empty or not {"data", "fsdp"} <= set(m.axis_names):
             return t  # no engine mesh in context (e.g. raw single-device)
@@ -720,9 +753,11 @@ def loss_fn(params: Dict, batch: Dict, rng: jax.Array, cfg: GPTConfig,
 
 
 def make_loss_fn(cfg: GPTConfig):
-    """Engine-contract loss: (params, batch, rng) -> loss."""
+    """Engine-contract loss: (params, batch, rng) -> loss. ``describe``
+    feeds the engine's "engine ready" line."""
     def _loss(params, batch, rng):
         return loss_fn(params, batch, rng, cfg)
+    _loss.describe = lambda: {"attention": attention_impl(cfg)}
     return _loss
 
 

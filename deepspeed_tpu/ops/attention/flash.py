@@ -28,10 +28,6 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax<=0.4.x spells it TPUCompilerParams
-_CompilerParams = getattr(pltpu, 'CompilerParams', None) \
-    or pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
@@ -41,17 +37,15 @@ def _norm_window(window):
 
     ``window`` is either an int — the BANDED implementation: DMA-eliding
     index-map clamps + band-aware grid skipping + in-body mask — or the
-    tagged tuple ``("masked", int)`` — the FALLBACK that expresses the
-    sliding window purely as an in-body mask over the plain causal
-    geometry. The fallback exists because the banded index-map clamp is
-    the prime suspect in the round-4 on-chip Mosaic compile hang
-    (STATUS.md "Rig situation"; bisect: tools/flash_window_bisect.py):
-    it uses ONLY constructs already proven through real Mosaic (the
-    causal clamp/skip and the causal-mask `where` pattern from the
-    'plain' smoke case). Cost: O(S^2) HBM reads/compute like plain
-    causal instead of O(S*W) — correctness is identical because fully
-    out-of-band blocks wash out of the online softmax exactly like
-    fully-masked kv_mask blocks (see flash_attention docstring)."""
+    tagged tuple ``("masked", int)`` — the second implementation, which
+    expresses the sliding window purely as an in-body mask over the
+    plain causal geometry. It was written when an earlier toolchain's
+    Mosaic hung on the banded form; both compile under libtpu 0.0.34
+    (tools/kernel_census.py; ROADMAP D6 keeps one). Cost: O(S^2) HBM
+    reads/compute like plain causal instead of O(S*W) — correctness is
+    identical because fully out-of-band blocks wash out of the online
+    softmax exactly like fully-masked kv_mask blocks (see
+    flash_attention docstring)."""
     if window is None:
         return None, None
     if isinstance(window, tuple):
@@ -62,10 +56,10 @@ def _norm_window(window):
 
 
 def resolve_window_impl(window, window_impl=None):
-    """Tag ``window`` for the masked fallback when requested (explicit
-    arg wins, else DS_FLASH_WINDOW_IMPL, default banded). Shared by
-    every window entry point (flash_attention, ring, ulysses) so the
-    PARITY.md quarantine advice works uniformly."""
+    """Tag ``window`` for the masked implementation when requested
+    (explicit arg wins, else DS_FLASH_WINDOW_IMPL, default banded).
+    Shared by every window entry point (flash_attention, ring,
+    ulysses)."""
     if window is None or isinstance(window, tuple):
         return window
     from deepspeed_tpu.utils.env import resolve_flag
@@ -82,6 +76,32 @@ STATS = 8   # lane width for per-row softmax stats (lse/delta) — sublane-align
 
 def _ceil_to(x, m):
     return (x + m - 1) // m * m
+
+
+def fit_block(pref: int, seq_len: int) -> Optional[int]:
+    """Largest block <= pref (>=128) that divides seq_len, or None —
+    keeps the kernel on when the preferred size doesn't tile the
+    sequence (1024-blocks at S=1536 run as 512)."""
+    b = min(pref, seq_len)
+    while b >= 128 and seq_len % b != 0:
+        b //= 2
+    return b if b >= 128 and seq_len % b == 0 else None
+
+
+def refuse_partial_manual(mesh, axis: str, who: str) -> None:
+    """Sequence-parallel attention maps only ``axis`` by hand and leaves
+    the other mesh axes to XLA, and a Mosaic kernel lowers only where
+    every mesh axis is manual ("Mosaic kernels cannot be automatically
+    partitioned", jax 0.9). Say so at trace time on a TPU, before the
+    lowering does (ROADMAP: full-manual ring/Ulysses)."""
+    from deepspeed_tpu.utils import on_tpu
+    if tuple(mesh.axis_names) != (axis,) and on_tpu():
+        raise NotImplementedError(
+            f"{who} with the flash kernel needs a mesh whose only axis is "
+            f"{axis!r}; this mesh has {tuple(mesh.axis_names)}, and a Mosaic "
+            f"kernel cannot be lowered under a partly automatic mesh. Pass "
+            f"use_flash=False (GPTConfig.use_flash_attention=False) or a "
+            f"one-axis mesh.")
 
 
 def _causal_kv_index_map(block_q, block_kv, num_kv, window=None, q_off=0):
@@ -292,6 +312,7 @@ def _flash_fwd(q, k, v, mask, qsegs, ksegs, causal, scale, block_q, block_kv,
     ]
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -304,7 +325,7 @@ def _flash_fwd(q, k, v, mask, qsegs, ksegs, causal, scale, block_q, block_kv,
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
     )(*operands)
     return o, lse[..., 0]
@@ -491,13 +512,14 @@ def _flash_bwd(causal, scale, block_q, block_kv, window, res, g, q_off=0,
                           has_segs=has_segs,
                           scale=scale, block_q=block_q, block_kv=block_kv,
                           num_kv=num_kv, window=window, q_off=q_off),
+        name="flash_bwd_dq",
         grid=(B, H, num_q, num_kv),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, block_q, D), qmap),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct(
             (B, H, S, D), jnp.float32 if out_fp32 else q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
     )(*operands)
 
@@ -553,6 +575,7 @@ def _flash_bwd(causal, scale, block_q, block_kv, window, res, g, q_off=0,
                           has_segs=has_segs,
                           scale=scale, block_q=block_q, block_kv=block_kv,
                           num_q=num_q, window=window, q_off=q_off),
+        name="flash_bwd_dkv",
         grid=(B, H, num_kv, num_q),
         in_specs=in_specs,
         out_specs=[
@@ -573,7 +596,7 @@ def _flash_bwd(causal, scale, block_q, block_kv, window, res, g, q_off=0,
                 (B, H, Skv, D),
                 jnp.float32 if (group > 1 or out_fp32) else v.dtype),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
     )(*operands)
 
@@ -676,11 +699,9 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     reads (out-of-band blocks' fetches are elided via index-map clamps).
 
     window_impl: "banded" (default; also via DS_FLASH_WINDOW_IMPL) keeps
-    the O(S*W) index-map clamps; "masked" is the fallback that expresses
-    the window purely as an in-body mask over plain causal geometry —
-    O(S^2) reads, but built ONLY from constructs proven through real
-    Mosaic (see _norm_window; the banded clamp is the r4 compile-hang
-    suspect, quarantined until tools/flash_window_bisect.py clears it).
+    the O(S*W) index-map clamps; "masked" expresses the window purely as
+    an in-body mask over plain causal geometry — O(S^2) reads (see
+    _norm_window).
     """
     B, S, H, D = q.shape
     Hkv = k.shape[2]

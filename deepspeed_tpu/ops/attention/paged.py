@@ -39,8 +39,10 @@ softmax, Dao 2023):
   gather path stays the bit-reference, see docs/PARITY.md).
 
 The gather path remains the reference implementation and the non-TPU
-default; tests drive this kernel in interpret mode under
-``JAX_PLATFORMS=cpu`` (tests/test_paged_attention.py).
+default. Interpret mode is the tests' business: they pass
+``interpret=True`` or patch ``pl.pallas_call`` (tests/conftest.py
+``pallas_interpret``); nothing on the serving path selects it, so the
+kernel asked for off a TPU is an error, not an interpreted run.
 """
 
 import functools
@@ -50,10 +52,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax<=0.4.x spells it TPUCompilerParams
-_CompilerParams = getattr(pltpu, 'CompilerParams', None) \
-    or pltpu.TPUCompilerParams
 
 NEG_INF = -1e30
 LANES = 128
@@ -121,9 +119,9 @@ def _kv_index_map(bs: int, nb: int, window: Optional[int], q_len: int = 1,
     so the high clamp covers that block too.
 
     ``rank=4`` addresses the K/V pools ``[N, block, Hkv, Dh]``;
-    ``rank=2`` addresses the int8 mode's scale pools ``[N, Hkv]`` with
-    the SAME table indirection, so each grid step's scale rides the
-    same prefetch discipline as its block."""
+    ``rank=3`` addresses the int8 mode's scale pools (fed as
+    ``[N, Hkv, 1]``) with the SAME table indirection, so each grid
+    step's scale rides the same prefetch discipline as its block."""
     def imap(b, j, tables_ref, lengths_ref):
         pos = lengths_ref[b]
         hi = jnp.minimum((pos + (q_len - 1)) // bs, nb - 1)
@@ -154,10 +152,13 @@ def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref,
     scattered into the pool).
 
     ``quant=True``: k_ref/v_ref hold int8 and two extra refs
-    ks_ref/vs_ref ([1, Hkv] fp32 per-block scales, same table
+    ks_ref/vs_ref ([1, Hkv, 1] fp32 per-block scales, same table
     indirection) precede the output — the block is dequantized
     IN-REGISTER right after its DMA (the ops/int8_matmul.py idiom), so
-    HBM traffic stays the int8 payload + one scale vector per block."""
+    HBM traffic stays the int8 payload + one scale vector per block.
+    The scales sit with Hkv on the sublane axis, as in the block, and
+    multiply it whole as a lane-broadcast vector: Mosaic has no scalar
+    load from VMEM for a per-head ``ks_ref[0, h]``."""
     if quant:
         ks_ref, vs_ref, o_ref, m_scratch, l_scratch, acc_scratch = rest
     else:
@@ -188,6 +189,11 @@ def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref,
         q = q_ref[0]                          # [H*q_len, Dh]
         k = k_ref[0]                          # [bs, Hkv, Dh]
         v = v_ref[0]
+        if quant:
+            # in-register dequantize: int8 block x its [Hkv, 1] scales
+            q = q.astype(jnp.float32)
+            k = k.astype(jnp.float32) * ks_ref[0][None]
+            v = v.astype(jnp.float32) * vs_ref[0][None]
         # positions of this block's slots in the slot's virtual cache;
         # the final partial block masks by position exactly like the
         # gather path (idx <= pos + chunk offset, window band below it)
@@ -208,11 +214,6 @@ def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref,
             qh = q[rows, :]                   # [R, Dh] — one MXU matmul
             kh = k[:, h, :]                   # [bs, Dh]     covers the whole
             vh = v[:, h, :]                   # GQA group of this kv head
-            if quant:
-                # in-register dequantize: int8 block × its fp32 scale
-                qh = qh.astype(jnp.float32)
-                kh = kh.astype(jnp.float32) * ks_ref[0, h]
-                vh = vh.astype(jnp.float32) * vs_ref[0, h]
             s = jax.lax.dot_general(
                 qh, kh, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale   # [R, bs]
@@ -245,7 +246,7 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                            v_pool: jnp.ndarray, tables: jnp.ndarray,
                            lengths: jnp.ndarray, *, scale: float,
                            window: Optional[int] = None,
-                           interpret: Optional[bool] = None,
+                           interpret: bool = False,
                            k_scale=None, v_scale=None) -> jnp.ndarray:
     """Flash-decode one new token per serving slot THROUGH the block
     table — no dense cache materialization.
@@ -259,9 +260,8 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     ``k_scale``/``v_scale`` ([N, Hkv] fp32): int8 pools, dequantized
     in-register after each block DMA (DS_KV_QUANT=int8).
 
-    Returns [B, Hkv, group, Dh] in q's dtype. ``interpret`` defaults to
-    True off-TPU so the same call tests on CPU (interpret mode) and
-    compiles through Mosaic on chip."""
+    Returns [B, Hkv, group, Dh] in q's dtype. ``interpret=True`` is
+    for tests; the default compiles through Mosaic and fails off a TPU."""
     B, n_kv, group, Dh = q.shape
     return _paged_attention_call(
         q.reshape(B, n_kv * group, Dh), k_pool, v_pool, tables, lengths,
@@ -274,7 +274,7 @@ def paged_verify_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                            v_pool: jnp.ndarray, tables: jnp.ndarray,
                            lengths: jnp.ndarray, *, scale: float,
                            window: Optional[int] = None,
-                           interpret: Optional[bool] = None,
+                           interpret: bool = False,
                            k_scale=None, v_scale=None) -> jnp.ndarray:
     """Flash-verify a G-token speculative chunk per slot THROUGH the
     block table — the ``q_len > 1`` generalization of
@@ -301,8 +301,7 @@ def paged_verify_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
 
 def _paged_attention_call(q_rows, k_pool, v_pool, tables, lengths, *,
                           n_kv: int, group: int, q_len: int, scale: float,
-                          window: Optional[int],
-                          interpret: Optional[bool],
+                          window: Optional[int], interpret: bool,
                           k_scale=None, v_scale=None) -> jnp.ndarray:
     """Shared pallas_call plumbing for decode (q_len=1) and verify
     (q_len=G). q_rows: [B, n_kv*group*q_len, Dh], head-major rows.
@@ -315,9 +314,6 @@ def _paged_attention_call(q_rows, k_pool, v_pool, tables, lengths, *,
     assert v_pool.shape == k_pool.shape, (v_pool.shape, k_pool.shape)
     quant = k_scale is not None
     nb = tables.shape[1]
-    if interpret is None:
-        from deepspeed_tpu.utils import on_tpu
-        interpret = not on_tpu()
 
     kvmap = _kv_index_map(bs, nb, window, q_len)
 
@@ -331,10 +327,13 @@ def _paged_attention_call(q_rows, k_pool, v_pool, tables, lengths, *,
     ]
     operands = [q_rows, k_pool, v_pool]
     if quant:
-        smap = _kv_index_map(bs, nb, window, q_len, rank=2)
-        in_specs += [pl.BlockSpec((1, Hkv), smap),
-                     pl.BlockSpec((1, Hkv), smap)]
-        operands += [k_scale, v_scale]
+        # [N, Hkv] scales ride as [N, Hkv, 1]: the last two dims of a
+        # block must divide by (8, 128) or equal the array's, which a
+        # (1, Hkv) block of [N, Hkv] does not
+        smap = _kv_index_map(bs, nb, window, q_len, rank=3)
+        in_specs += [pl.BlockSpec((1, Hkv, 1), smap),
+                     pl.BlockSpec((1, Hkv, 1), smap)]
+        operands += [k_scale[..., None], v_scale[..., None]]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -352,11 +351,14 @@ def _paged_attention_call(q_rows, k_pool, v_pool, tables, lengths, *,
         scale=float(scale), window=window, nb=nb, quant=quant)
     return pl.pallas_call(
         kernel,
+        name="paged_decode" if q_len == 1 else "paged_verify",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, rows, Dh), q_rows.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        # passed only when a test asks: the conftest fixture's patched
+        # pallas_call keeps its own interpret=True
+        **({"interpret": True} if interpret else {}),
     )(jnp.asarray(tables, jnp.int32), jnp.asarray(lengths, jnp.int32),
       *operands)
 

@@ -74,8 +74,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from deepspeed_tpu.ops.attention.flash import (
     NEG_INF, _norm_window, _pad_heads, flash_block_bwd_t,
-    flash_block_fwd_t, resolve_window_impl)
-from deepspeed_tpu.utils.jax_compat import axis_size, shard_map
+    flash_block_fwd_t, refuse_partial_manual, resolve_window_impl)
 
 
 def _largest_divisor(n: int, cap: int) -> int:
@@ -276,7 +275,7 @@ def _rotate(xs, axis, perm):
 
 def _ring_fwd_inner(q, k, v, segs, kvm, axis, causal, scale, window,
                     use_flash, block_q, block_kv, chunk, layout):
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     B, S_loc, H, D = q.shape
     zig = layout == "zigzag"
@@ -410,7 +409,7 @@ def _ring_core_bwd(axis, causal, scale, window, use_flash, block_q,
                    block_kv, chunk, layout, res, g):
     q, k, v, segs, kvm, o, lse = res
     do = g
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     B, S_loc, H, D = q.shape
     Hkv = k.shape[2]
@@ -589,8 +588,8 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         scale = 1.0 / np.sqrt(q.shape[-1])
     if window is not None:
         assert causal, "sliding window requires causal attention"
-        # tag for the masked fallback (PARITY.md window quarantine); the
-        # tag rides the nondiff window arg into the flash block leafs
+        # tag for the masked implementation when asked for; the tag
+        # rides the nondiff window arg into the flash block leafs
         window = resolve_window_impl(window, window_impl)
     if layout not in ("contiguous", "zigzag"):
         raise ValueError(f"unknown ring layout {layout!r}")
@@ -613,6 +612,8 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if use_flash is None:
         from deepspeed_tpu.utils import on_tpu
         use_flash = on_tpu() and S_loc >= 128
+    if use_flash:
+        refuse_partial_manual(mesh, axis, "ring_attention")
     # zigzag steps run on half blocks — tiles must divide C as well
     blk_unit = S_loc // 2 if layout == "zigzag" else S_loc
     block_q = _largest_divisor(blk_unit, min(block_q, blk_unit))
@@ -632,7 +633,7 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     in_specs = [spec, spec, spec,
                 None if segment_ids is None else tok_spec,
                 None if kv_mask is None else tok_spec]
-    mapped = shard_map(
+    mapped = jax.shard_map(
         inner, mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=spec,

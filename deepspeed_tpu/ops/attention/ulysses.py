@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from deepspeed_tpu.utils.jax_compat import axis_size, shard_map
 
 
 def _ulysses_local(q, k, v, segs, mask, *, axis: str, causal: bool,
@@ -40,7 +39,7 @@ def _ulysses_local(q, k, v, segs, mask, *, axis: str, causal: bool,
                    window_impl: Optional[str] = None):
     """Inside shard_map: q local [B, S_loc, H, D]; k/v may carry Hkv < H
     heads (GQA) -> out [B, S_loc, H, D]. segs/mask: [B, S_loc] or None."""
-    sp = axis_size(axis)
+    sp = jax.lax.axis_size(axis)
     B, S_loc, H, D = q.shape
     Hkv = k.shape[2]
     assert H % sp == 0, f"n_heads {H} not divisible by sp degree {sp}"
@@ -113,6 +112,9 @@ def ulysses_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     """
     if scale is None:
         scale = 1.0 / np.sqrt(q.shape[-1])
+    if use_flash:
+        from deepspeed_tpu.ops.attention.flash import refuse_partial_manual
+        refuse_partial_manual(mesh, axis, "ulysses_attention")
     inner = partial(_ulysses_local, axis=axis, causal=causal, scale=scale,
                     use_flash=use_flash, block_q=block_q, block_kv=block_kv,
                     window=window, bwd_block_q=bwd_block_q,
@@ -124,7 +126,7 @@ def ulysses_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     for extra in (segment_ids, kv_mask):
         args.append(extra)
         in_specs.append(None if extra is None else tok_spec)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         inner, mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=spec,

@@ -6,7 +6,7 @@ mlp_gemm int8 variants, csrc/transformer/inference/csrc/dequantize.cu).
 Here weight-only int8 serving normally leans on XLA to fuse
 ``q.astype(bf16) * scale`` into the consuming matmul
 (models/gpt.py _kernel_of) — bandwidth-bound and usually fused. This
-kernel is the guaranteed-fused fallback (VERDICT r4 weak #6): the int8
+kernel is the guaranteed-fused form: the int8
 weight is the ONLY weight HBM traffic (1 byte/param), dequantized in
 VMEM tiles on the way into the MXU, fp32 accumulation over K tiles,
 per-output-channel scale applied once at the end.
@@ -24,10 +24,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax<=0.4.x spells it TPUCompilerParams
-_CompilerParams = getattr(pltpu, 'CompilerParams', None) \
-    or pltpu.TPUCompilerParams
 
 
 def _dq_matmul_kernel(x_ref, q_ref, s_ref, o_ref, acc, *, num_k: int):
@@ -70,6 +66,7 @@ def int8_matmul(x: jnp.ndarray, q: jnp.ndarray, scale: jnp.ndarray,
     grid = (Mp // block_m, N // block_n, K // block_k)
     out = pl.pallas_call(
         functools.partial(_dq_matmul_kernel, num_k=grid[2]),
+        name="int8_matmul",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda mi, ni, ki: (mi, ki)),
@@ -80,7 +77,7 @@ def int8_matmul(x: jnp.ndarray, q: jnp.ndarray, scale: jnp.ndarray,
                                lambda mi, ni, ki: (mi, ni)),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((Mp, N), x.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, q, scale)

@@ -8,12 +8,15 @@ compile a plain shared library with ``g++`` and bind it with ``ctypes`` —
 no pybind11 in the image, and ctypes avoids a Python ABI dependency.
 
 Build artifacts are cached under ``<repo>/build/`` keyed by a hash of the
-sources and flags, so repeat imports are instant.
+sources, the flags and, under ``-march=native``, the host CPU, so repeat
+imports are instant and a library built on one machine is never loaded on
+another.
 """
 
 import ctypes
 import hashlib
 import os
+import platform
 import subprocess
 import threading
 from typing import List, Optional
@@ -28,6 +31,24 @@ _BUILD_DIR = os.environ.get(  # dslint: disable=DS005,DS013 — build-dir path f
 
 _lock = threading.Lock()
 _loaded = {}
+
+
+def _host_cpu_id() -> str:
+    """What ``-march=native`` resolves to here: the model and feature
+    flags of the first CPU."""
+    try:
+        with open("/proc/cpuinfo") as f:
+            seen = {}
+            for line in f:
+                key = line.split(":", 1)[0].strip()
+                if key in ("model name", "flags", "Features") \
+                        and key not in seen:
+                    seen[key] = line.strip()
+            if seen:
+                return "|".join(seen.values())
+    except OSError:
+        pass
+    return f"{platform.machine()}|{platform.processor()}"
 
 
 class OpBuilder:
@@ -57,7 +78,10 @@ class OpBuilder:
         for src in self.abs_sources():
             with open(src, "rb") as f:
                 h.update(f.read())
-        h.update(" ".join(self.cxx_flags()).encode())
+        flags = self.cxx_flags()
+        h.update(" ".join(flags).encode())
+        if "-march=native" in flags:
+            h.update(_host_cpu_id().encode())
         return h.hexdigest()[:16]
 
     def lib_path(self) -> str:

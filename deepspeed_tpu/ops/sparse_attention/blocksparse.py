@@ -29,10 +29,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax<=0.4.x spells it TPUCompilerParams
-_CompilerParams = getattr(pltpu, 'CompilerParams', None) \
-    or pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 LANES = 128
 
@@ -225,9 +221,10 @@ def _bs_pallas_fwd(q, k, v, lut, nnz, block, causal, scale):
                                block=block, num_l=L)
     return pl.pallas_call(
         kernel,
+        name="blocksparse_fwd",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
     )(jnp.asarray(lut), jnp.asarray(nnz), q, k, v)
@@ -315,9 +312,9 @@ def blocksparse_attention(q, k, v, layout, causal: bool = False,
     block = S // nb
     lut, valid = lut_valid if lut_valid is not None else make_lut(layout)
     if use_kernel is None:
-        use_kernel = (jax.default_backend() == "tpu"
-                      and key_padding_mask is None and attn_mask is None
-                      and rpe is None and block % 8 == 0)
+        from deepspeed_tpu.utils import on_tpu
+        use_kernel = (key_padding_mask is None and attn_mask is None
+                      and rpe is None and block % 8 == 0 and on_tpu())
     if use_kernel:
         return blocksparse_attention_kernel(q, k, v, lut, valid, block,
                                             causal=causal, scale=scale)

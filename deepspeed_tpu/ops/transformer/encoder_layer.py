@@ -79,21 +79,30 @@ def _dropout(x, rate, rng):
     return jnp.where(keep, x / (1.0 - rate), 0.0).astype(x.dtype)
 
 
+def flash_block(seq_len: int, head_dim: int) -> Optional[int]:
+    """Block size at which the encoder's attention runs the Pallas flash
+    kernel, None where it runs the jnp softmax: off a TPU, and for
+    shapes the kernel does not tile. One gate for ``_attention_core``
+    and for the remat policy in models/bert.py."""
+    from deepspeed_tpu.ops.attention.flash import fit_block
+    from deepspeed_tpu.utils import on_tpu
+    if head_dim % 8 or not on_tpu():
+        return None
+    return fit_block(512, seq_len)
+
+
 def _attention_core(q, k, v, attn_mask, cfg, dropout_rng, deterministic,
                     allow_flash=True):
-    """[B,S,H,D] attention; flash kernel when unmasked + deterministic,
-    masked jnp softmax otherwise."""
+    """[B,S,H,D] attention; flash kernel on a TPU when deterministic and
+    the shape tiles, masked jnp softmax otherwise. A kernel that fails
+    to compile fails the step."""
     B, S, H, D = q.shape
-    use_flash = (allow_flash
-                 and (deterministic or cfg.attn_dropout_ratio == 0.0)
-                 and S >= 128 and D % 8 == 0)
-    if use_flash:
-        try:
-            from deepspeed_tpu.ops.attention.flash import flash_attention
-            return flash_attention(q, k, v, causal=False,
-                                   kv_mask=attn_mask)
-        except Exception:  # dslint: disable=DS006 — flash is an optimization; fall back to the reference einsum path
-            pass
+    block = flash_block(S, D) if allow_flash and (
+        deterministic or cfg.attn_dropout_ratio == 0.0) else None
+    if block is not None:
+        from deepspeed_tpu.ops.attention.flash import flash_attention
+        return flash_attention(q, k, v, causal=False, kv_mask=attn_mask,
+                               block_q=block, block_kv=block)
     scale = 1.0 / jnp.sqrt(jnp.float32(D))
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     if attn_mask is not None:

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from deepspeed_tpu.utils.jax_compat import shard_map
 
 PyTree = Any
 
@@ -108,7 +107,7 @@ def compressed_allreduce(tree: PyTree, error_tree: PyTree, mesh: Mesh,
         return tuple(outs) + tuple(errs)
 
     specs = tuple(P() for _ in range(2 * len(leaves)))
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         inner, mesh=mesh, in_specs=specs, out_specs=specs,
         axis_names={axis}, check_vma=False))
     out = fn(*leaves, *err_leaves)

@@ -37,7 +37,6 @@ from deepspeed_tpu.runtime.lr_schedules import get_lr_schedule
 from deepspeed_tpu.runtime.utils import (clip_by_global_norm, count_parameters,
                                          global_norm)
 from deepspeed_tpu.utils.logging import log_dist, logger
-from deepspeed_tpu.utils.jax_compat import shard_map
 from deepspeed_tpu.utils.timer import (NoopTimer, SynchronizedWallClockTimer,
                                        ThroughputTimer, TRAIN_BATCH_TIMER)
 
@@ -382,15 +381,27 @@ class DeepSpeedEngine:
             self._grad_step = self._build_grad_step()
         else:
             self._train_step = self._build_train_step(donate_state)
+            # commit every leaf, the scalar step/scale/rng included, to
+            # its mesh sharding now: an uncommitted first-call argument
+            # has another type than the step's own output, and the second
+            # step would trace and compile the whole program again
+            self.state = jax.device_put(self.state, self._state_shardings)
         self._eval_step = self._build_eval_step()
 
         n_params = count_parameters(params)
+        # what the model says of itself (models/gpt.py make_loss_fn: the
+        # attention implementation its step compiles on this platform)
+        describe = getattr(loss_fn, "describe", None)
+        model_says = "".join(f", {k}={v}" for k, v in describe().items()) \
+            if describe is not None else ""
         log_dist(
             f"engine ready: {n_params / 1e6:.2f}M params, zero_stage="
             f"{config.zero.stage}, precision={config.precision_name}, "
             f"dp={self.dp_world_size}, tp={self.mp_world_size}, "
             f"micro_bs={config.train_micro_batch_size_per_gpu}, "
-            f"gas={config.gradient_accumulation_steps}", ranks=[0])
+            f"gas={config.gradient_accumulation_steps}, "
+            f"platform={self.mesh.devices.flat[0].platform}{model_says}",
+            ranks=[0])
         self._warn_hbm_headroom(n_params)
 
     def _warn_hbm_headroom(self, n_params: int) -> None:
@@ -723,7 +734,7 @@ class DeepSpeedEngine:
             espec = tuple(P("data") for _ in err_leaves)
             pspec = jax.tree_util.tree_map(lambda _: P(), params)
             bspec = jax.tree_util.tree_map(lambda _: P("data"), batch)
-            out = shard_map(
+            out = jax.shard_map(
                 local_fn, mesh=mesh,
                 in_specs=(pspec, bspec, espec),
                 out_specs=(gspecs, espec, P(), P()),
@@ -1027,11 +1038,7 @@ class DeepSpeedEngine:
         from deepspeed_tpu.utils.trace import annotation
         # mesh in context: models can pin activation layouts with bare
         # PartitionSpecs (gpt.py scan-carry constraint) during tracing
-        # jax.set_mesh is the 0.5+ spelling; older jax enters the Mesh
-        # itself as the context manager to the same effect
-        with annotation("ds.train_batch"), \
-                (jax.set_mesh(self.mesh) if hasattr(jax, "set_mesh")
-                 else self.mesh):
+        with annotation("ds.train_batch"), jax.set_mesh(self.mesh):
             if self.offload_enabled:
                 metrics = self._offload_train_batch(batch)
             else:

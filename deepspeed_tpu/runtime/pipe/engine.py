@@ -28,7 +28,6 @@ from typing import Any, Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from deepspeed_tpu.utils.jax_compat import shard_map
 
 PyTree = Any
 
@@ -274,7 +273,7 @@ def make_1f1b_loss_fn(stage_fn: Callable,
     def run(stage_params, other_params, x_micro, target_micro):
         prog = partial(_one_f_one_b_program, stage_fn, head_loss_fn,
                        num_stages, axis)
-        return shard_map(
+        return jax.shard_map(
             prog, mesh=mesh,
             in_specs=(stage_params_specs, P(), P(), P()),
             out_specs=(P(), stage_params_specs, P(), P()),
@@ -360,7 +359,7 @@ def make_pipelined_loss_fn(embed_fn: Callable,
             _micro_split(params, batch)
         inner = partial(pipeline_loss, gpipe_stage_fn, head_loss_fn,
                         num_stages=num_stages, axis=axis)
-        sharded = shard_map(
+        sharded = jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(stage_params_specs,
@@ -627,7 +626,7 @@ def make_interleaved_loss_fn(stage_fn, head_loss_fn, num_stages, v,
     def run(stage_params, other_params, x_micro, target_micro):
         prog = partial(_interleaved_program, stage_fn, head_loss_fn,
                        num_stages, v, tables, k_act, k_cot, axis)
-        return shard_map(
+        return jax.shard_map(
             prog, mesh=mesh,
             in_specs=(stage_params_specs, P(), P(), P()),
             out_specs=(P(), stage_params_specs, P(), P()),

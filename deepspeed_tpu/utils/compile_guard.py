@@ -6,7 +6,7 @@ and ZERO recompiles across admission, eviction and requeue.  Nothing in
 the code *structurally* prevents a refactor from silently breaking that
 — a dynamic shape, a fresh lambda, a python int leaking into a traced
 position all recompile quietly and only show up as a latency cliff on
-the rig.  ``CompileWatch`` turns the contract into an executable assert.
+the chip.  ``CompileWatch`` turns the contract into an executable assert.
 
 Counting strategy, in preference order:
 

@@ -114,6 +114,7 @@ fire ``kind`` at ``site`` on visits ``[step, step+count)`` with float
 
 import time
 import warnings
+import weakref
 from contextlib import contextmanager
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -248,17 +249,17 @@ class FaultInjector:
         self.fired: List[Tuple[str, str, int]] = []
         # observers notified on every fired fault (before the kind
         # acts, so a raise still reaches them): telemetry tracers tag
-        # chaos events into the request-lifecycle timeline here
-        self._listeners: List = []
+        # chaos events into the request-lifecycle timeline here. Held
+        # WEAKLY: the ambient injector lives as long as the process, and a
+        # strong reference to a serving engine's callback would pin the
+        # engine, its parameters and its KV pools on the device for good
+        self._listeners: List[weakref.ref] = []
 
     def add_listener(self, cb) -> None:
         """Register ``cb(site, kind, visit)``, called on every fired
-        fault (including ones that then raise)."""
-        self._listeners.append(cb)
-
-    def remove_listener(self, cb) -> None:
-        if cb in self._listeners:
-            self._listeners.remove(cb)
+        fault (including ones that then raise). The subscriber keeps
+        ``cb`` alive; the injector drops it when the subscriber dies."""
+        self._listeners.append(weakref.ref(cb))
 
     @classmethod
     def from_env(cls, env=None) -> "FaultInjector":
@@ -279,8 +280,11 @@ class FaultInjector:
         for f in self.faults:
             if f.site == site and f.matches(n):
                 self.fired.append((site, f.kind, n))
-                for cb in self._listeners:
-                    cb(site, f.kind, n)
+                live = [(ref, ref()) for ref in self._listeners]
+                self._listeners = [ref for ref, cb in live if cb is not None]
+                for _, cb in live:
+                    if cb is not None:
+                        cb(site, f.kind, n)
                 return f
         return None
 

@@ -16,10 +16,6 @@ import time
 
 sys.path.insert(0, ".")
 
-from deepspeed_tpu.utils import honor_platform_request
-
-honor_platform_request()
-
 import jax
 import numpy as np
 

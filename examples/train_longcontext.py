@@ -28,15 +28,11 @@ import time
 sys.path.insert(0, ".")
 
 import jax
-
-from deepspeed_tpu.utils import honor_platform_request, on_tpu
-
-honor_platform_request()
-
 import jax.numpy as jnp
 import numpy as np
 
 import deepspeed_tpu
+from deepspeed_tpu.utils import on_tpu
 from deepspeed_tpu.models import gpt
 from deepspeed_tpu.parallel.mesh import MeshSpec, make_mesh
 

@@ -119,8 +119,9 @@ def test_kv_mask_grads_parity(devices):
 def test_encoder_layer_masked_flash_path(devices, monkeypatch):
     """The encoder attention core with a padding mask matches its jnp
     path when routed through the (interpret-mode) flash kernel — and the
-    flash path must actually be TAKEN (the core's try/except fallback
-    would otherwise make this comparison vacuous)."""
+    flash path must actually be TAKEN (the core's TPU gate is patched
+    open; off a TPU the core stays on its jnp path)."""
+    monkeypatch.setattr("deepspeed_tpu.utils.on_tpu", lambda: True)
     from deepspeed_tpu.ops.attention import flash as flash_mod
     from deepspeed_tpu.ops.transformer.encoder_layer import (
         DeepSpeedTransformerConfig, _attention_core)

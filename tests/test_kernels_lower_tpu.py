@@ -1,0 +1,109 @@
+"""Every Pallas kernel through the Pallas->Mosaic lowering, from the CPU.
+
+Interpret mode (how the parity suites run the kernels) checks neither block
+tiling nor layouts. ``lower(lowering_platforms=("tpu",))`` runs the TPU
+lowering without a chip and refuses, for one, a block whose last two
+dimensions neither divide by (8, 128) nor equal the array's: the int8 paged
+kernel's ``(1, Hkv)`` scale blocks were refused here at every shape while
+every CPU test passed. What libtpu's Mosaic compiler then makes of a kernel
+that lowers is tools/kernel_census.py's business, on the chip.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.ops.attention import flash, paged
+from deepspeed_tpu.ops.int8_matmul import fit_blocks, int8_matmul
+from deepspeed_tpu.ops.sparse_attention import blocksparse
+from deepspeed_tpu.ops.sparse_attention.sparsity_config import \
+    FixedSparsityConfig
+
+# (heads, kv_heads, head_dim, flash block): gpt2-medium, gpt2-1.5b, GQA
+SHAPES = [pytest.param(16, 16, 64, 1024, id="gpt2-medium"),
+          pytest.param(25, 25, 64, 1024, id="gpt2-1.5b"),
+          pytest.param(32, 8, 128, 512, id="gqa-dh128")]
+
+
+def S(shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def lower_tpu(fn, *args) -> str:
+    """StableHLO of ``fn`` lowered for the TPU platform; raises where the
+    Mosaic lowering refuses the kernel."""
+    return jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+
+
+@pytest.mark.parametrize("H,Hkv,D,blk", SHAPES)
+def test_flash_forward_and_backward_lower(H, Hkv, D, blk):
+    q, kv = S((2, 1024, H, D)), S((2, 1024, Hkv, D))
+
+    def fl(q, k, v):
+        return flash.flash_attention(q, k, v, causal=True, block_q=blk,
+                                     block_kv=blk)
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: fl(*a).astype(jnp.float32).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+    assert lower_tpu(fl, q, kv, kv).count("tpu_custom_call") == 1
+    # forward (for the residuals), dq, dk/dv
+    assert lower_tpu(grads, q, kv, kv).count("tpu_custom_call") == 3
+
+
+def test_flash_masks_segments_and_windows_lower():
+    q, m, sg = S((2, 1024, 8, 64)), S((2, 1024), jnp.float32), \
+        S((2, 1024), jnp.int32)
+    for impl in ("banded", "masked"):
+        def fl(q, k, v, m, sg, impl=impl):
+            return flash.flash_attention(
+                q, k, v, causal=True, block_q=256, block_kv=256, kv_mask=m,
+                segment_ids=sg, window=300, window_impl=impl)
+        assert "tpu_custom_call" in lower_tpu(fl, q, q, q, m, sg)
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("q_len", [1, 5], ids=["decode", "verify5"])
+@pytest.mark.parametrize("H,Hkv,D,blk", SHAPES)
+def test_paged_attention_lowers(H, Hkv, D, blk, q_len, quant):
+    B, bs, nb, N = 8, 16, 32, 257
+    pool = S((N, bs, Hkv, D), jnp.int8 if quant else jnp.bfloat16)
+    scales = {"k_scale": S((N, Hkv), jnp.float32),
+              "v_scale": S((N, Hkv), jnp.float32)} if quant else {}
+    tables, lengths = S((B, nb), jnp.int32), S((B,), jnp.int32)
+    if q_len == 1:
+        fn, q = paged.paged_decode_attention, S((B, Hkv, H // Hkv, D))
+    else:
+        fn, q = paged.paged_verify_attention, \
+            S((B, q_len, Hkv, H // Hkv, D))
+
+    def call(q, k, v, t, ln, *sc):
+        return fn(q, k, v, t, ln, scale=0.125,
+                  **dict(zip(scales, sc)))
+    assert "tpu_custom_call" in lower_tpu(call, q, pool, pool, tables,
+                                          lengths, *scales.values())
+
+
+@pytest.mark.parametrize("M,K,N", [(8, 1024, 3072), (256, 4096, 4096)])
+def test_int8_matmul_lowers(M, K, N):
+    bk, bn = fit_blocks(K, N)
+
+    def mm(x, q, s):
+        return int8_matmul(x, q, s, block_k=bk, block_n=bn)
+    assert "tpu_custom_call" in lower_tpu(
+        mm, S((M, K)), S((K, N), jnp.int8), S((1, N), jnp.float32))
+
+
+@pytest.mark.parametrize("H,D", [(16, 64), (25, 64), (8, 128)])
+def test_blocksparse_lowers(H, D):
+    layout = np.asarray(FixedSparsityConfig(num_heads=H, block=128)
+                        .make_layout(1024))
+    q = S((2, 1024, H, D))
+
+    def bsa(q, k, v):
+        return blocksparse.blocksparse_attention(q, k, v, layout,
+                                                 causal=True,
+                                                 use_kernel=True)
+    assert "tpu_custom_call" in lower_tpu(bsa, q, q, q)

@@ -1,0 +1,277 @@
+"""On-chip census of every Pallas kernel: compile through Mosaic, run,
+compare with the in-repo reference.
+
+CPU tests run the kernels in interpret mode, which checks neither tiling
+nor VMEM; tests/test_kernels_lower_tpu.py runs the Pallas->Mosaic lowering
+without a chip. This is the third leg: libtpu's compiler and the device's
+arithmetic, one row per kernel and shape. A census records what each
+kernel does, so a failing row is printed with the compiler's message and
+the run goes on; the exit code is non-zero if any row failed.
+
+Usage: python tools/kernel_census.py [substring ...]
+One JSON line per row, also appended to chiprun_out/kernel_census.jsonl.
+Needs a TPU; one process.
+"""
+
+import json
+import os
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+sys.path.insert(0, ".")
+
+from deepspeed_tpu.ops.attention import flash as F  # noqa: E402
+from deepspeed_tpu.ops.attention import paged as P  # noqa: E402
+from deepspeed_tpu.utils import require_tpu  # noqa: E402
+
+OUT = os.path.join("chiprun_out", "kernel_census.jsonl")
+# Kernels take bf16 and accumulate in fp32; each reference is given the
+# same values in fp32, so the error is the kernel's own: the bf16 rounding
+# of its output (2^-9 relative) and of the probabilities it feeds the
+# second matmul. Errors are relative to the largest reference value.
+TOL = 2e-2
+
+# (heads, kv_heads, head_dim): gpt2-1.5b, gpt2-medium, one GQA Dh=128
+MODEL_SHAPES = ((25, 25, 64), (16, 16, 64), (32, 8, 128))
+
+
+def _err(a, ref):
+    a, ref = a.astype(jnp.float32), ref.astype(jnp.float32)
+    return float(jnp.max(jnp.abs(a - ref))
+                 / jnp.maximum(1.0, jnp.max(jnp.abs(ref))))
+
+
+def _f32(*xs):
+    return [x.astype(jnp.float32) if jnp.issubdtype(x.dtype, jnp.floating)
+            else x for x in xs]
+
+
+def _rand(r, shape, dtype=jnp.bfloat16):
+    return jnp.asarray(r.standard_normal(shape), dtype)
+
+
+def flash_rows():
+    """Forward + all three gradients of flash_attention vs mha_reference,
+    at the model shapes and over the feature matrix (mask, segments,
+    banded and masked windows, backward tiles)."""
+    r = np.random.default_rng(0)
+    S = 1024
+    mask = jnp.asarray((r.random((2, S)) > 0.2).astype(np.float32))
+    segs = jnp.asarray(np.repeat(np.arange(4), S // 4)[None].repeat(2, 0),
+                       jnp.int32)
+    cases = [(f"flash H{H}/{Hkv} D{D} blk{blk}", (H, Hkv, D), blk, {})
+             for (H, Hkv, D), blk in zip(MODEL_SHAPES, (1024, 1024, 512))]
+    small = (8, 8, 64)
+    cases += [
+        ("flash kv_mask", small, 256, {"kv_mask": mask}),
+        ("flash segments", small, 256, {"segment_ids": segs}),
+        ("flash window banded", small, 256,
+         {"window": 256, "window_impl": "banded"}),
+        ("flash window masked", small, 256,
+         {"window": 256, "window_impl": "masked"}),
+        ("flash window banded GQA+segments", (8, 2, 64), 256,
+         {"window": 256, "window_impl": "banded", "segment_ids": segs}),
+        ("flash window banded H25 W300", (25, 25, 64), 512,
+         {"window": 300, "window_impl": "banded"}),
+        ("flash bwd tiles 128", small, 256,
+         {"bwd_block_q": 128, "bwd_block_kv": 128}),
+    ]
+    for name, (H, Hkv, D), blk, kw in cases:
+        def run(H=H, Hkv=Hkv, D=D, blk=blk, kw=kw):
+            q = _rand(r, (2, S, H, D))
+            k, v = _rand(r, (2, S, Hkv, D)), _rand(r, (2, S, Hkv, D))
+            ref_kw = {a: b for a, b in kw.items()
+                      if a in ("kv_mask", "segment_ids", "window")}
+
+            def fl(q, k, v):
+                return F.flash_attention(q, k, v, causal=True, block_q=blk,
+                                         block_kv=blk, **kw)
+
+            def rf(q, k, v):
+                return F.mha_reference(q, k, v, causal=True, **ref_kw)
+
+            def sq(f):
+                return lambda q, k, v: (f(q, k, v).astype(jnp.float32)
+                                        ** 2).sum()
+            out = jax.jit(fl)(q, k, v)
+            g = jax.jit(jax.grad(sq(fl), argnums=(0, 1, 2)))(q, k, v)
+            gr = jax.jit(jax.grad(sq(rf), argnums=(0, 1, 2)))(
+                *_f32(q, k, v))
+            fwd = _err(out, jax.jit(rf)(*_f32(q, k, v)))
+            bwd = max(_err(a, b) for a, b in zip(g, gr))
+            return {"fwd_err": fwd, "bwd_err": bwd,
+                    "ok": fwd < TOL and bwd < TOL}
+        yield name, run
+
+
+def ring_block_rows():
+    """The ring building blocks (static q_off, separate kv-side
+    segments) vs the jnp chunked block the ring falls back to."""
+    from deepspeed_tpu.ops.attention.ring import (_jnp_block_bwd,
+                                                  _jnp_block_fwd)
+    r = np.random.default_rng(1)
+    B, S, H, D = 1, 512, 4, 64
+    # every q row keeps at least one matching key: a row with none is
+    # garbage by contract in both implementations
+    qsegs = jnp.asarray(np.repeat(np.arange(2), S // 2)[None], jnp.int32)
+    ksegs = jnp.asarray(np.repeat([0, 1], [S // 4, 3 * S // 4])[None],
+                        jnp.int32)
+    for name, kw in [
+        ("ring block q_off", dict(q_off=S)),
+        ("ring block q_off window", dict(q_off=S, window=S + 128)),
+        ("ring block kv segments",
+         dict(q_off=S, q_segs=qsegs, kv_segs=ksegs)),
+    ]:
+        def run(kw=kw):
+            q, k, v = (_rand(r, (B, S, H, D)) for _ in range(3))
+            do = jnp.ones_like(q)
+            def fwd(q, k, v):
+                return F.flash_block_fwd(q, k, v, causal=True, block_q=256,
+                                         block_kv=256, **kw)
+
+            def bwd_(q, k, v, do, o, lse):
+                return F.flash_block_bwd(q, k, v, do, o, lse, causal=True,
+                                         block_q=256, block_kv=256, **kw)
+            o, lse = jax.jit(fwd)(q, k, v)
+            grads = jax.jit(bwd_)(q, k, v, do, o, lse)
+            scale = 1.0 / np.sqrt(D)
+            ref_kw = dict(blk_causal=True, window=kw.get("window"),
+                          q_off=kw["q_off"], scale=scale, chunk=256)
+            o_ref, _ = _jnp_block_fwd(q, k, v, kw.get("q_segs"),
+                                      kw.get("kv_segs"), None, **ref_kw)
+            delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                            axis=-1).transpose(0, 2, 1)
+            g_ref = _jnp_block_bwd(q, k, v, do, lse, delta,
+                                   kw.get("q_segs"), kw.get("kv_segs"),
+                                   None, **ref_kw)
+            fwd = _err(o, o_ref.transpose(0, 2, 1, 3))
+            bwd = max(_err(a, b.transpose(0, 2, 1, 3))
+                      for a, b in zip(grads, g_ref))
+            return {"fwd_err": fwd, "bwd_err": bwd,
+                    "ok": fwd < TOL and bwd < TOL}
+        yield name, run
+
+
+def _paged_inputs(r, B, Hkv, D, bs, nb, quant):
+    """Pools with every slot's blocks filled, tables that scatter them
+    over the pool, and lengths from one token to the last position."""
+    from deepspeed_tpu.ops import quantizer
+    N = B * nb + 1
+    k, v = _rand(r, (N, bs, Hkv, D)), _rand(r, (N, bs, Hkv, D))
+    tables = jnp.asarray(
+        1 + r.permutation(B * nb).reshape(B, nb), jnp.int32)
+    lengths = jnp.asarray(
+        np.linspace(0, nb * bs - 8, B).astype(np.int32))
+    if not quant:
+        return k, v, tables, lengths, {}
+    kq, ks = quantizer.kv_requantize_blocks(k)
+    vq, vs = quantizer.kv_requantize_blocks(v)
+    return kq, vq, tables, lengths, {"k_scale": ks, "v_scale": vs}
+
+
+def paged_rows():
+    """Paged decode and 5-token verify, bf16 and int8 pools, vs the
+    gather references."""
+    r = np.random.default_rng(2)
+    B, bs, nb = 8, 16, 32
+    for (H, Hkv, D) in MODEL_SHAPES:
+        G = H // Hkv
+        for quant in (False, True):
+            for q_len in (1, 5):
+                kind = "decode" if q_len == 1 else "verify5"
+                name = (f"paged {kind}{' int8' if quant else ''} "
+                        f"Hkv{Hkv} G{G} D{D}")
+
+                def run(Hkv=Hkv, G=G, D=D, quant=quant, q_len=q_len):
+                    k, v, tables, lengths, sc = _paged_inputs(
+                        r, B, Hkv, D, bs, nb, quant)
+                    scale = 1.0 / np.sqrt(D)
+                    if q_len == 1:
+                        q = _rand(r, (B, Hkv, G, D))
+                        fn, ref = (P.paged_decode_attention,
+                                   P.paged_decode_reference)
+                    else:
+                        q = _rand(r, (B, q_len, Hkv, G, D))
+                        fn, ref = (P.paged_verify_attention,
+                                   P.paged_verify_reference)
+                    def call(*a):
+                        return fn(*a, scale=scale, **sc)
+                    out = jax.jit(call)(q, k, v, tables, lengths)
+                    # int8 pools stay int8: the reference dequantizes
+                    e = _err(out, ref(q.astype(jnp.float32), *_f32(k, v),
+                                      tables, lengths, scale=scale, **sc))
+                    return {"fwd_err": e, "ok": e < TOL}
+                yield name, run
+
+
+def int8_matmul_rows():
+    from deepspeed_tpu.ops.int8_matmul import (fit_blocks, int8_matmul,
+                                               int8_matmul_reference)
+    r = np.random.default_rng(3)
+    for M, K, N in ((8, 1024, 3072), (64, 1024, 4096), (256, 4096, 4096)):
+        def run(M=M, K=K, N=N):
+            x = _rand(r, (M, K))
+            q = jnp.asarray(r.integers(-127, 128, (K, N)), jnp.int8)
+            s = jnp.asarray(r.random((1, N)) * 0.01, jnp.float32)
+            bk, bn = fit_blocks(K, N)
+            out = int8_matmul(x, q, s, block_k=bk, block_n=bn)
+            e = _err(out, int8_matmul_reference(x.astype(jnp.float32), q, s))
+            return {"fwd_err": e, "ok": e < TOL}
+        yield f"int8_matmul M{M} K{K} N{N}", run
+
+
+def blocksparse_rows():
+    from deepspeed_tpu.ops.sparse_attention import blocksparse as bsp
+    from deepspeed_tpu.ops.sparse_attention.sparsity_config import \
+        FixedSparsityConfig
+    r = np.random.default_rng(4)
+    S = 1024
+    for H, D in ((16, 64), (25, 64), (8, 128)):
+        def run(H=H, D=D):
+            layout = np.asarray(FixedSparsityConfig(
+                num_heads=H, block=128).make_layout(S))
+            q, k, v = (_rand(r, (2, S, H, D)) for _ in range(3))
+            def bsa(q, k, v):
+                return bsp.blocksparse_attention(q, k, v, layout, causal=True)
+            out = jax.jit(bsa)(q, k, v)
+            e = _err(out, bsp.blocksparse_reference(
+                *_f32(q, k, v), layout, causal=True))
+            return {"fwd_err": e, "ok": e < TOL}
+        yield f"blocksparse H{H} D{D}", run
+
+
+def main():
+    dev = require_tpu("kernel_census")
+    wanted = sys.argv[1:]
+    os.makedirs(os.path.dirname(OUT), exist_ok=True)
+    failed = 0
+    with open(OUT, "a") as out:
+        for rows in (flash_rows, ring_block_rows, paged_rows,
+                     int8_matmul_rows, blocksparse_rows):
+            for name, run in rows():
+                if wanted and not any(w in name for w in wanted):
+                    continue
+                t0 = time.perf_counter()
+                try:
+                    row = run()
+                except Exception as e:  # the census records the compiler's message and goes on; the exit code below still fails
+                    row = {"ok": False,
+                           "error": f"{type(e).__name__}: {e}"[:600]}
+                row = {"kernel": name, "device": dev.device_kind,
+                       **{k: (round(v, 5) if isinstance(v, float) else v)
+                          for k, v in row.items()},
+                       "seconds": round(time.perf_counter() - t0, 1)}
+                failed += not row["ok"]
+                line = json.dumps(row)
+                print(line, flush=True)
+                out.write(line + "\n")
+    print(json.dumps({"census": "done", "failed": failed}))
+    sys.exit(1 if failed else 0)
+
+
+if __name__ == "__main__":
+    main()

@@ -31,7 +31,7 @@ from deepspeed_tpu.models import gpt
 seq = {seq}
 batch = {batch}
 dt, tps, mfu = run_config('gpt2-small', batch, seq, 6,
-    {{'zero_optimization': {{'stage': 1}}}}, True,
+    {{'zero_optimization': {{'stage': 1}}}},
     flash_block=1024, remat_pol='{pol}', loss_chunk=2048)
 print(json.dumps({{'config': 'gpt2-small', 'seq': seq, 'batch': batch,
     'remat': '{pol}',
@@ -43,17 +43,17 @@ print(json.dumps({{'config': 'gpt2-small', 'seq': seq, 'batch': batch,
 def chip():
     from tools._subproc import run_json
 
-    # tokens/step held ~constant: long S trades batch. 1500s/config
-    # (matching the other bench tools, and 3x1500 fits chip_queue's
-    # 4800s item budget): on this rig a compile that runs longer is in
-    # the borderline-HBM grind and will not produce a number anyway
-    # (PERF.md).
+    # tokens/step held ~constant: long S trades batch. Each length builds
+    # its own engine and wants the whole device, so each runs in a child;
+    # this parent stays off JAX
     grid = [(8, 2048, "selective"), (2, 8192, "selective"),
             (1, 16384, "full")]
-    for batch, seq, pol in grid:
-        run_json([sys.executable, "-c",
-                  CHIP_CODE.format(seq=seq, batch=batch, pol=pol)],
-                 1500, {"seq": seq, "batch": batch})
+    ok = [run_json([sys.executable, "-c",
+                    CHIP_CODE.format(seq=seq, batch=batch, pol=pol)],
+                   1500, {"seq": seq, "batch": batch})
+          for batch, seq, pol in grid]
+    if not all(ok):
+        sys.exit(1)
 
 
 def mesh():

@@ -38,8 +38,7 @@ else:
                          num_experts={experts}, moe_k={k},
                          capacity_factor=1.25)
 if on_tpu:
-    # refuse borderline-HBM compiles before any backend contact
-    # (utils/hbm.py, PERF.md incident log)
+    # refuse a configuration whose estimate does not fit (utils/hbm.py)
     from deepspeed_tpu.utils import hbm
     try:
         if kind == 'dense':
@@ -81,11 +80,13 @@ def main():
     grid = [("dense", 0, 0), ("moe", 8, 1), ("moe", 8, 2), ("moe", 16, 1)]
     from tools._subproc import run_json
 
-    for kind, experts, k in grid:
-        run_json([sys.executable, "-c",
-                  CODE.format(kind=kind, experts=experts, k=k, batch=batch,
-                              seq=seq, steps=steps)],
-                 1500, {"kind": kind, "experts": experts})
+    ok = [run_json([sys.executable, "-c",
+                    CODE.format(kind=kind, experts=experts, k=k, batch=batch,
+                                seq=seq, steps=steps)],
+                   1500, {"kind": kind, "experts": experts})
+          for kind, experts, k in grid]
+    if not all(ok):
+        sys.exit(1)
 
 
 if __name__ == "__main__":

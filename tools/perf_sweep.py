@@ -11,8 +11,6 @@ Usage: python tools/perf_sweep.py [preset] [steps]
 import json
 import sys
 
-import jax
-
 sys.path.insert(0, ".")
 
 from bench import run_config  # noqa: E402
@@ -22,8 +20,6 @@ def main():
     preset = sys.argv[1] if len(sys.argv) > 1 else "gpt2-medium"
     steps = int(sys.argv[2]) if len(sys.argv) > 2 else 10
     seq = 1024
-    on_tpu = "tpu" in (jax.devices()[0].platform +
-                       jax.devices()[0].device_kind).lower()
     grid = [
         # (batch, flash_block, extra ds-config)
         (8, 512, {}),
@@ -36,19 +32,14 @@ def main():
     for batch, fb, extra in grid:
         overrides = {"zero_optimization": {"stage": 1}}
         overrides.update(extra)
-        try:
-            dt, tps, mfu = run_config(preset, batch, seq, steps,
-                                      overrides, on_tpu, flash_block=fb)
-            print(json.dumps({
-                "preset": preset, "batch": batch, "flash_block": fb,
-                "extra": extra,
-                "step_ms": round(dt * 1e3, 2),
-                "tokens_per_s": round(tps, 1), "mfu": round(mfu, 4)}),
-                flush=True)
-        except Exception as e:  # OOM etc — report and continue
-            print(json.dumps({
-                "preset": preset, "batch": batch, "flash_block": fb,
-                "error": repr(e)[:200]}), flush=True)
+        dt, tps, mfu = run_config(preset, batch, seq, steps,
+                                  overrides, flash_block=fb)
+        print(json.dumps({
+            "preset": preset, "batch": batch, "flash_block": fb,
+            "extra": extra,
+            "step_ms": round(dt * 1e3, 2),
+            "tokens_per_s": round(tps, 1), "mfu": round(mfu, 4)}),
+            flush=True)
 
 
 if __name__ == "__main__":
