@@ -1,0 +1,7 @@
+"""Median decode_dispatch span; it blocks on the device (inference/engine.py)."""
+
+from harness import readers
+
+
+def read(run):
+    return readers.median_span_ms(run, "decode_dispatch")

@@ -1,0 +1,7 @@
+"""prefill_dispatch spans over step wall time, chat cell (inference/serving.py)."""
+
+from harness import readers
+
+
+def read(run):
+    return readers.span_share(run, "prefill_dispatch")

@@ -1,0 +1,57 @@
+"""The generator's steadiness: the seed permutes, it does not resize."""
+
+import json
+import os
+
+import numpy as np
+
+from harness import traffic
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def mix(name):
+    return json.load(open(os.path.join(BENCH, "traffic", name + ".json")))
+
+
+def test_open_loop_same_multiset_and_counts_for_every_seed():
+    m = mix("chat-open-0p8")
+    plans = [traffic.open_loop_plan(m, 45.0, np.random.default_rng(s))
+             for s in (1, 2147483659, 77)]
+    ramp = plans[0][0]
+    assert ramp == m["ramp_requests"] / m["rate_rps"]
+    n_win = round(m["rate_rps"] * 45.0)
+    for ramp_s, plan in plans:
+        assert ramp_s == ramp
+        due = np.array([d for d, _, _ in plan])
+        assert (np.diff(due[:m["ramp_requests"]]) >= 0).all()
+        assert (due < ramp).sum() == m["ramp_requests"]
+        assert ((due >= ramp) & (due < ramp + 45.0)).sum() == n_win
+        assert all(p + a <= m["max_total"] for _, p, a in plan)
+    prompts = [sorted(p for _, p, _ in plan) for _, plan in plans]
+    answers = [sorted(a for _, _, a in plan) for _, plan in plans]
+    assert prompts[0] == prompts[1] == prompts[2]
+    assert answers[0] == answers[1] == answers[2]
+    # and the order does change with the seed
+    assert [p for _, p, _ in plans[0][1]] != [p for _, p, _ in plans[1][1]]
+    lo, hi = m["prompt"]["min"], m["prompt"]["max"]
+    assert lo <= prompts[0][0] and prompts[0][-1] <= hi
+    med = float(np.median(traffic.stratified_lengths(
+        m["prompt"], 1001, np.random.default_rng(0))))
+    assert abs(med - m["prompt"]["median"]) <= 1
+
+
+def test_closed_loop_first_generation_is_staggered_and_fixed():
+    m = mix("docs-backlog")
+    plans = [traffic.closed_loop_plan(m, 17, np.random.default_rng(s))
+             for s in (5, 6)]
+    for plan in plans:
+        assert len(plan) == m["backlog"]
+        first = sorted(p for p, _ in plan[:17])
+        assert first[0] < m["prompt"]["min"] < first[-1]
+        assert all(m["prompt"]["min"] <= p <= m["prompt"]["max"]
+                   for p, _ in plan[17:])
+    assert sorted(p for p, _ in plans[0][:17]) == sorted(
+        p for p, _ in plans[1][:17])
+    assert sorted(p for p, _ in plans[0][17:]) == sorted(
+        p for p, _ in plans[1][17:])
