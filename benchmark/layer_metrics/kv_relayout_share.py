@@ -1,0 +1,7 @@
+"""Device time of the copies that re-lay-out the paged KV pool (a `copy` in the kv_write, kv_gather or paged_attn scope, or one whose result is one layer's whole pool) over busy time."""
+
+from harness import provenance
+
+
+def read(run):
+    return provenance.kv_relayout_share(run)

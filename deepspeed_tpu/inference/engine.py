@@ -21,6 +21,7 @@ KV-cache management via the global Context workspace). TPU-native design:
 """
 
 import dataclasses
+import functools
 from dataclasses import dataclass
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
@@ -247,6 +248,18 @@ def _block_extend(x, k_cache, v_cache, pos, p, cfg: GPTConfig):
     return x + _ffn(h, p, cfg), k_cache, v_cache
 
 
+def _named(fn, name: str):
+    """``fn`` under an explicit ``__name__``: jax names a compiled module
+    ``jit_<name>``, and profiles, the persistent cache's entries and the
+    provenance table (telemetry/costs.py) keep that name when the Python
+    function is renamed."""
+    @functools.wraps(fn)
+    def call(*args, **kwargs):
+        return fn(*args, **kwargs)
+    call.__name__ = call.__qualname__ = name
+    return call
+
+
 def _gather_blocks(pool, tables):
     """Gather a block pool [N, block, Hkv, Dh] through block tables
     [B, NB] into the virtual contiguous cache [B, NB*block, Hkv, Dh].
@@ -292,83 +305,90 @@ def _block_decode_paged(x, k_pool, v_pool, tables, lengths, active, p,
     NB = tables.shape[1]
     lr = (lambda t: None) if lora is None else lora.get
 
-    h = _norm(x, p["ln1"], cfg)
-    qkv = _dense(h, p["qkv"], lora=lr("qkv"))
-    q, k, v = jnp.split(qkv, [H * Dh, (H + Hkv) * Dh], axis=-1)
-    if cfg.rotary_dim:
-        from deepspeed_tpu.ops.attention.rotary import apply_rotary
-        q, k = apply_rotary(q.reshape(B, 1, H, Dh), k.reshape(B, 1, Hkv, Dh),
-                            lengths[:, None], cfg.rotary_dim,
-                            base=cfg.rope_theta)
-    q = q.reshape(B, Hkv, group, Dh)
-    k = k.reshape(B, Hkv, Dh)
-    v = v.reshape(B, Hkv, Dh)
+    with jax.named_scope("attn_qkv"):
+        h = _norm(x, p["ln1"], cfg)
+        qkv = _dense(h, p["qkv"], lora=lr("qkv"))
+        q, k, v = jnp.split(qkv, [H * Dh, (H + Hkv) * Dh], axis=-1)
+        if cfg.rotary_dim:
+            from deepspeed_tpu.ops.attention.rotary import apply_rotary
+            q, k = apply_rotary(
+                q.reshape(B, 1, H, Dh), k.reshape(B, 1, Hkv, Dh),
+                lengths[:, None], cfg.rotary_dim, base=cfg.rope_theta)
+        q = q.reshape(B, Hkv, group, Dh)
+        k = k.reshape(B, Hkv, Dh)
+        v = v.reshape(B, Hkv, Dh)
 
-    # scatter the new token's K/V into each slot's current block; a slot
-    # whose block budget is exhausted (lengths == NB*bs) would CLAMP to
-    # the last block's live data — route it to the trash block instead
-    # (serving.py finishes such slots before they reach here; the mask
-    # is the engine-side belt to that suspender)
-    in_cap = lengths < NB * bs
-    blk = jnp.take_along_axis(
-        tables, jnp.clip(lengths // bs, 0, NB - 1)[:, None], axis=1)[:, 0]
-    blk = jnp.where(jnp.logical_and(active, in_cap), blk, 0)
-    off = lengths % bs
-    if k_scale is None:
-        k_pool = k_pool.at[blk, off].set(k)
-        v_pool = v_pool.at[blk, off].set(v)
-    else:
-        kb = quantizer.kv_dequantize_blocks(k_pool[blk], k_scale[blk])
-        vb = quantizer.kv_dequantize_blocks(v_pool[blk], v_scale[blk])
-        rows = jnp.arange(B)
-        kb = kb.at[rows, off].set(k.astype(jnp.float32))
-        vb = vb.at[rows, off].set(v.astype(jnp.float32))
-        # lanes past the new token are a previous owner's garbage
-        live = jnp.arange(bs)[None, :] <= off[:, None]
-        kq, ksn = quantizer.kv_requantize_blocks(kb, live)
-        vq, vsn = quantizer.kv_requantize_blocks(vb, live)
-        k_pool = k_pool.at[blk].set(kq)
-        v_pool = v_pool.at[blk].set(vq)
-        k_scale = k_scale.at[blk].set(ksn)
-        v_scale = v_scale.at[blk].set(vsn)
+    with jax.named_scope("kv_write"):
+        # scatter the new token's K/V into each slot's current block; a slot
+        # whose block budget is exhausted (lengths == NB*bs) would CLAMP to
+        # the last block's live data — route it to the trash block instead
+        # (serving.py finishes such slots before they reach here; the mask
+        # is the engine-side belt to that suspender)
+        in_cap = lengths < NB * bs
+        blk = jnp.take_along_axis(
+            tables, jnp.clip(lengths // bs, 0, NB - 1)[:, None], axis=1)[:, 0]
+        blk = jnp.where(jnp.logical_and(active, in_cap), blk, 0)
+        off = lengths % bs
+        if k_scale is None:
+            k_pool = k_pool.at[blk, off].set(k)
+            v_pool = v_pool.at[blk, off].set(v)
+        else:
+            kb = quantizer.kv_dequantize_blocks(k_pool[blk], k_scale[blk])
+            vb = quantizer.kv_dequantize_blocks(v_pool[blk], v_scale[blk])
+            rows = jnp.arange(B)
+            kb = kb.at[rows, off].set(k.astype(jnp.float32))
+            vb = vb.at[rows, off].set(v.astype(jnp.float32))
+            # lanes past the new token are a previous owner's garbage
+            live = jnp.arange(bs)[None, :] <= off[:, None]
+            kq, ksn = quantizer.kv_requantize_blocks(kb, live)
+            vq, vsn = quantizer.kv_requantize_blocks(vb, live)
+            k_pool = k_pool.at[blk].set(kq)
+            v_pool = v_pool.at[blk].set(vq)
+            k_scale = k_scale.at[blk].set(ksn)
+            v_scale = v_scale.at[blk].set(vsn)
 
     scale = cfg.attn_scale if cfg.attn_scale is not None \
         else 1.0 / np.sqrt(Dh)
     if impl == "pallas":
         from deepspeed_tpu.ops.attention.paged import paged_decode_attention
-        attn = paged_decode_attention(
-            q, k_pool, v_pool, tables, lengths, scale=float(scale),
-            window=cfg.attn_window, k_scale=k_scale,
-            v_scale=v_scale).reshape(B, 1, D)
+        with jax.named_scope("paged_attn"):
+            attn = paged_decode_attention(
+                q, k_pool, v_pool, tables, lengths, scale=float(scale),
+                window=cfg.attn_window, k_scale=k_scale,
+                v_scale=v_scale).reshape(B, 1, D)
     else:
-        if k_scale is None:
-            kc = _gather_blocks(k_pool, tables)  # [B, NB*bs, Hkv, Dh]
-            vc = _gather_blocks(v_pool, tables)
+        with jax.named_scope("kv_gather"):
+            if k_scale is None:
+                kc = _gather_blocks(k_pool, tables)  # [B, NB*bs, Hkv, Dh]
+                vc = _gather_blocks(v_pool, tables)
+            else:
+                kc = quantizer.kv_dequantize_blocks(
+                    k_pool[tables], k_scale[tables],
+                    dtype=x.dtype).reshape(B, NB * bs, Hkv, Dh)
+                vc = quantizer.kv_dequantize_blocks(
+                    v_pool[tables], v_scale[tables],
+                    dtype=x.dtype).reshape(B, NB * bs, Hkv, Dh)
+        with jax.named_scope("paged_attn"):
+            scores = jnp.einsum("bkgd,bskd->bkgs", q, kc).astype(jnp.float32)
+            scores *= scale
+            idx = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, NB * bs), 3)
+            pos = lengths[:, None, None, None]
+            scores = jnp.where(idx <= pos, scores, -1e30)
+            if cfg.attn_window is not None:
+                # block tables keep logical order, so cache-index distance IS
+                # logical distance — same banding as the static decode
+                scores = jnp.where(idx > pos - cfg.attn_window, scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+            attn = jnp.einsum("bkgs,bskd->bkgd", probs, vc).reshape(B, 1, D)
+    with jax.named_scope("attn_out"):
+        attn = _dense(attn, p["attn_out"], lora=lr("attn_out"))
+    with jax.named_scope("mlp"):
+        if cfg.parallel_residual:
+            y = x + attn + _ffn(h, p, cfg, lora=lora)
         else:
-            kc = quantizer.kv_dequantize_blocks(
-                k_pool[tables], k_scale[tables],
-                dtype=x.dtype).reshape(B, NB * bs, Hkv, Dh)
-            vc = quantizer.kv_dequantize_blocks(
-                v_pool[tables], v_scale[tables],
-                dtype=x.dtype).reshape(B, NB * bs, Hkv, Dh)
-        scores = jnp.einsum("bkgd,bskd->bkgs", q, kc).astype(jnp.float32)
-        scores *= scale
-        idx = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, NB * bs), 3)
-        pos = lengths[:, None, None, None]
-        scores = jnp.where(idx <= pos, scores, -1e30)
-        if cfg.attn_window is not None:
-            # block tables keep logical order, so cache-index distance IS
-            # logical distance — same banding as the static decode
-            scores = jnp.where(idx > pos - cfg.attn_window, scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-        attn = jnp.einsum("bkgs,bskd->bkgd", probs, vc).reshape(B, 1, D)
-    attn = _dense(attn, p["attn_out"], lora=lr("attn_out"))
-    if cfg.parallel_residual:
-        y = x + attn + _ffn(h, p, cfg, lora=lora)
-    else:
-        x = x + attn
-        h = _norm(x, p["ln2"], cfg)
-        y = x + _ffn(h, p, cfg, lora=lora)
+            x = x + attn
+            h = _norm(x, p["ln2"], cfg)
+            y = x + _ffn(h, p, cfg, lora=lora)
     if k_scale is None:
         return y, k_pool, v_pool
     return y, k_pool, v_pool, k_scale, v_scale
@@ -531,77 +551,85 @@ def _block_prefill_paged(x, k_pool, v_pool, table_row, positions, n_valid,
     NB = table_row.shape[0]
     lr = (lambda t: None) if lora is None else lora.get
 
-    h = _norm(x, p["ln1"], cfg)
-    qkv = _dense(h, p["qkv"], lora=lr("qkv"))
-    q, k, v = gpt_lib._qkv_split_rotary(qkv, cfg, positions[None], B, C)
+    with jax.named_scope("attn_qkv"):
+        h = _norm(x, p["ln1"], cfg)
+        qkv = _dense(h, p["qkv"], lora=lr("qkv"))
+        q, k, v = gpt_lib._qkv_split_rotary(qkv, cfg, positions[None], B, C)
 
     valid = jnp.arange(C) < n_valid
     if k_scale is None:
-        blk = table_row[jnp.clip(positions // bs, 0, NB - 1)]
-        blk = jnp.where(valid, blk, 0)       # padded lanes -> trash block
-        off = positions % bs
-        k_pool = k_pool.at[blk, off].set(k[0])
-        v_pool = v_pool.at[blk, off].set(v[0])
+        with jax.named_scope("kv_write"):
+            blk = table_row[jnp.clip(positions // bs, 0, NB - 1)]
+            blk = jnp.where(valid, blk, 0)       # padded lanes -> trash block
+            off = positions % bs
+            k_pool = k_pool.at[blk, off].set(k[0])
+            v_pool = v_pool.at[blk, off].set(v[0])
 
-        kc = k_pool[table_row].reshape(NB * bs, Hkv, Dh)
-        vc = v_pool[table_row].reshape(NB * bs, Hkv, Dh)
+        with jax.named_scope("kv_gather"):
+            kc = k_pool[table_row].reshape(NB * bs, Hkv, Dh)
+            vc = v_pool[table_row].reshape(NB * bs, Hkv, Dh)
     else:
-        kb = quantizer.kv_dequantize_blocks(k_pool[table_row],
-                                            k_scale[table_row])
-        vb = quantizer.kv_dequantize_blocks(v_pool[table_row],
-                                            v_scale[table_row])
-        tgt = jnp.where(jnp.logical_and(valid, positions < NB * bs),
-                        positions, NB * bs)  # padded lanes drop
-        kb = kb.reshape(NB * bs, Hkv, Dh).at[tgt].set(
-            k[0].astype(jnp.float32), mode="drop").reshape(NB, bs, Hkv, Dh)
-        vb = vb.reshape(NB * bs, Hkv, Dh).at[tgt].set(
-            v[0].astype(jnp.float32), mode="drop").reshape(NB, bs, Hkv, Dh)
-        start = positions[0]
-        new_total = start + n_valid
-        glob = jnp.arange(NB, dtype=jnp.int32)[:, None] * bs + \
-            jnp.arange(bs, dtype=jnp.int32)[None]
-        live = glob < new_total
-        kq, ksn = quantizer.kv_requantize_blocks(kb, live)
-        vq, vsn = quantizer.kv_requantize_blocks(vb, live)
-        # requantize only the chunk-touched blocks; everything else is
-        # scattered back byte-identical (shared prefix blocks included)
-        j = jnp.arange(NB, dtype=jnp.int32)
-        j0 = start // bs
-        j1 = jnp.maximum(start + n_valid - 1, start) // bs
-        touched = jnp.logical_and(j >= j0, j <= j1)
-        kq = jnp.where(touched[:, None, None, None], kq,
-                       k_pool[table_row])
-        vq = jnp.where(touched[:, None, None, None], vq,
-                       v_pool[table_row])
-        ksn = jnp.where(touched[:, None], ksn, k_scale[table_row])
-        vsn = jnp.where(touched[:, None], vsn, v_scale[table_row])
-        k_pool = k_pool.at[table_row].set(kq)
-        v_pool = v_pool.at[table_row].set(vq)
-        k_scale = k_scale.at[table_row].set(ksn)
-        v_scale = v_scale.at[table_row].set(vsn)
-        # attend over exactly what the pool now holds
-        kc = quantizer.kv_dequantize_blocks(
-            kq, ksn, dtype=x.dtype).reshape(NB * bs, Hkv, Dh)
-        vc = quantizer.kv_dequantize_blocks(
-            vq, vsn, dtype=x.dtype).reshape(NB * bs, Hkv, Dh)
-    qg = q[0].reshape(C, Hkv, group, Dh)
-    scores = jnp.einsum("ckgd,skd->ckgs", qg, kc).astype(jnp.float32)
-    scores *= cfg.attn_scale if cfg.attn_scale is not None \
-        else 1.0 / np.sqrt(Dh)
-    sidx = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, NB * bs), 3)
-    qpos = positions[:, None, None, None]
-    scores = jnp.where(sidx <= qpos, scores, -1e30)
-    if cfg.attn_window is not None:
-        scores = jnp.where(sidx > qpos - cfg.attn_window, scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-    attn = jnp.einsum("ckgs,skd->ckgd", probs, vc).reshape(1, C, D)
-    attn = _dense(attn, p["attn_out"], lora=lr("attn_out"))
-    if cfg.parallel_residual:
-        y = x + attn + _ffn(h, p, cfg, lora=lora)
-    else:
-        x = x + attn
-        h = _norm(x, p["ln2"], cfg)
-        y = x + _ffn(h, p, cfg, lora=lora)
+        with jax.named_scope("kv_write"):
+            kb = quantizer.kv_dequantize_blocks(k_pool[table_row],
+                                                k_scale[table_row])
+            vb = quantizer.kv_dequantize_blocks(v_pool[table_row],
+                                                v_scale[table_row])
+            tgt = jnp.where(jnp.logical_and(valid, positions < NB * bs),
+                            positions, NB * bs)  # padded lanes drop
+            kb = kb.reshape(NB * bs, Hkv, Dh).at[tgt].set(
+                k[0].astype(jnp.float32), mode="drop").reshape(NB, bs, Hkv, Dh)
+            vb = vb.reshape(NB * bs, Hkv, Dh).at[tgt].set(
+                v[0].astype(jnp.float32), mode="drop").reshape(NB, bs, Hkv, Dh)
+            start = positions[0]
+            new_total = start + n_valid
+            glob = jnp.arange(NB, dtype=jnp.int32)[:, None] * bs + \
+                jnp.arange(bs, dtype=jnp.int32)[None]
+            live = glob < new_total
+            kq, ksn = quantizer.kv_requantize_blocks(kb, live)
+            vq, vsn = quantizer.kv_requantize_blocks(vb, live)
+            # requantize only the chunk-touched blocks; everything else is
+            # scattered back byte-identical (shared prefix blocks included)
+            j = jnp.arange(NB, dtype=jnp.int32)
+            j0 = start // bs
+            j1 = jnp.maximum(start + n_valid - 1, start) // bs
+            touched = jnp.logical_and(j >= j0, j <= j1)
+            kq = jnp.where(touched[:, None, None, None], kq,
+                           k_pool[table_row])
+            vq = jnp.where(touched[:, None, None, None], vq,
+                           v_pool[table_row])
+            ksn = jnp.where(touched[:, None], ksn, k_scale[table_row])
+            vsn = jnp.where(touched[:, None], vsn, v_scale[table_row])
+            k_pool = k_pool.at[table_row].set(kq)
+            v_pool = v_pool.at[table_row].set(vq)
+            k_scale = k_scale.at[table_row].set(ksn)
+            v_scale = v_scale.at[table_row].set(vsn)
+        with jax.named_scope("kv_gather"):
+            # attend over exactly what the pool now holds
+            kc = quantizer.kv_dequantize_blocks(
+                kq, ksn, dtype=x.dtype).reshape(NB * bs, Hkv, Dh)
+            vc = quantizer.kv_dequantize_blocks(
+                vq, vsn, dtype=x.dtype).reshape(NB * bs, Hkv, Dh)
+    with jax.named_scope("paged_attn"):
+        qg = q[0].reshape(C, Hkv, group, Dh)
+        scores = jnp.einsum("ckgd,skd->ckgs", qg, kc).astype(jnp.float32)
+        scores *= cfg.attn_scale if cfg.attn_scale is not None \
+            else 1.0 / np.sqrt(Dh)
+        sidx = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, NB * bs), 3)
+        qpos = positions[:, None, None, None]
+        scores = jnp.where(sidx <= qpos, scores, -1e30)
+        if cfg.attn_window is not None:
+            scores = jnp.where(sidx > qpos - cfg.attn_window, scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+        attn = jnp.einsum("ckgs,skd->ckgd", probs, vc).reshape(1, C, D)
+    with jax.named_scope("attn_out"):
+        attn = _dense(attn, p["attn_out"], lora=lr("attn_out"))
+    with jax.named_scope("mlp"):
+        if cfg.parallel_residual:
+            y = x + attn + _ffn(h, p, cfg, lora=lora)
+        else:
+            x = x + attn
+            h = _norm(x, p["ln2"], cfg)
+            y = x + _ffn(h, p, cfg, lora=lora)
     if k_scale is None:
         return y, k_pool, v_pool
     return y, k_pool, v_pool, k_scale, v_scale
@@ -719,15 +747,18 @@ class InferenceEngine:
             # paged-serving programs: the steady-state continuous-batching
             # loop is exactly these two compiled programs regardless of
             # arrival pattern; pools are donated so the cache never
-            # doubles in HBM across a step
-            self._prefill_slot = jax.jit(self._prefill_slot_fn,
-                                         donate_argnums=(1, 2))
+            # doubles in HBM across a step. Their module names are
+            # explicit (jit_serve_prefill_slot, jit_serve_decode_slots):
+            # a profile and the provenance table name a program by them
+            self._prefill_slot = jax.jit(
+                _named(self._prefill_slot_fn, "serve_prefill_slot"),
+                donate_argnums=(1, 2))
             # impl is static: each attention path ("gather" | "pallas")
             # is its own compiled program; a serving run pins one impl so
             # steady state remains two programs
-            self._decode_slots = jax.jit(self._decode_slots_fn,
-                                         donate_argnums=(1, 2),
-                                         static_argnums=(7,))
+            self._decode_slots = jax.jit(
+                _named(self._decode_slots_fn, "serve_decode_slots"),
+                donate_argnums=(1, 2), static_argnums=(7,))
             # fused multi-step decode (DS_DECODE_HORIZON > 1,
             # docs/MULTISTEP.md): the SAME donated-pool decode body
             # scanned N times on-device with the stop/length predicates
@@ -818,6 +849,9 @@ class InferenceEngine:
             self._gather_blocks_q = jax.jit(self._gather_blocks_q_fn)
             self._scatter_block_q = jax.jit(self._scatter_block_q_fn,
                                             donate_argnums=(0, 1, 2, 3))
+        # a ProgramCostRegistry that wants the compiled text of each
+        # serving program (a ServingEngine with telemetry on sets it)
+        self.provenance = None
         dev0 = mesh.devices.flat[0]
         log_dist(f"inference engine ready: {config.n_layers}L/"
                  f"{config.d_model}d mp={mp_size} "
@@ -838,13 +872,14 @@ class InferenceEngine:
 
     def _logits(self, params, x):
         from deepspeed_tpu.models.gpt import _kernel_of
-        x = _norm(x, params["ln_f"], self.cfg)
-        if self.cfg.tie_embeddings:
-            return x @ params["wte"]["embedding"].T
-        logits = x @ _kernel_of(params["lm_head"], x.dtype)
-        if "bias" in params["lm_head"]:
-            logits = logits + params["lm_head"]["bias"]
-        return logits
+        with jax.named_scope("logits"):
+            x = _norm(x, params["ln_f"], self.cfg)
+            if self.cfg.tie_embeddings:
+                return x @ params["wte"]["embedding"].T
+            logits = x @ _kernel_of(params["lm_head"], x.dtype)
+            if "bias" in params["lm_head"]:
+                logits = logits + params["lm_head"]["bias"]
+            return logits
 
     def _prefill_fn(self, params, tokens, attn_mask=None):
         """Run the prompt, build the cache, return last-position logits.
@@ -932,10 +967,11 @@ class InferenceEngine:
         cfg = self.cfg
         C = tokens.shape[0]
         positions = start + jnp.arange(C, dtype=jnp.int32)
-        x = params["wte"]["embedding"][tokens][None]
-        if cfg.use_wpe:
-            safe = jnp.clip(positions, 0, self.max_seq_len - 1)
-            x = x + params["wpe"]["embedding"][safe][None]
+        with jax.named_scope("embed"):
+            x = params["wte"]["embedding"][tokens][None]
+            if cfg.use_wpe:
+                safe = jnp.clip(positions, 0, self.max_seq_len - 1)
+                x = x + params["wpe"]["embedding"][safe][None]
 
         def body(x, layer):
             layer_p, kp, vp = layer
@@ -971,10 +1007,11 @@ class InferenceEngine:
         program; the fused sampler emits each slot's next token (and
         its logprob) in the same dispatch as the forward step."""
         cfg = self.cfg
-        x = params["wte"]["embedding"][tokens[:, None]]
-        if cfg.use_wpe:
-            safe = jnp.clip(lengths, 0, self.max_seq_len - 1)
-            x = x + params["wpe"]["embedding"][safe][:, None]
+        with jax.named_scope("embed"):
+            x = params["wte"]["embedding"][tokens[:, None]]
+            if cfg.use_wpe:
+                safe = jnp.clip(lengths, 0, self.max_seq_len - 1)
+                x = x + params["wpe"]["embedding"][safe][:, None]
 
         def body(x, layer):
             layer_p, kp, vp = layer
@@ -1069,10 +1106,11 @@ class InferenceEngine:
         cfg = self.cfg
         C = tokens.shape[0]
         positions = start + jnp.arange(C, dtype=jnp.int32)
-        x = params["wte"]["embedding"][tokens][None]
-        if cfg.use_wpe:
-            safe = jnp.clip(positions, 0, self.max_seq_len - 1)
-            x = x + params["wpe"]["embedding"][safe][None]
+        with jax.named_scope("embed"):
+            x = params["wte"]["embedding"][tokens][None]
+            if cfg.use_wpe:
+                safe = jnp.clip(positions, 0, self.max_seq_len - 1)
+                x = x + params["wpe"]["embedding"][safe][None]
 
         def body(x, layer):
             layer_p, kp, vp, ksp, vsp = layer
@@ -1100,10 +1138,11 @@ class InferenceEngine:
         quantized write path). Carries the same fused sampling lanes as
         the fp program."""
         cfg = self.cfg
-        x = params["wte"]["embedding"][tokens[:, None]]
-        if cfg.use_wpe:
-            safe = jnp.clip(lengths, 0, self.max_seq_len - 1)
-            x = x + params["wpe"]["embedding"][safe][:, None]
+        with jax.named_scope("embed"):
+            x = params["wte"]["embedding"][tokens[:, None]]
+            if cfg.use_wpe:
+                safe = jnp.clip(lengths, 0, self.max_seq_len - 1)
+                x = x + params["wpe"]["embedding"][safe][:, None]
 
         def body(x, layer):
             layer_p, kp, vp, ksp, vsp = layer
@@ -1166,10 +1205,11 @@ class InferenceEngine:
         cfg = self.cfg
         C = tokens.shape[0]
         positions = start + jnp.arange(C, dtype=jnp.int32)
-        x = params["wte"]["embedding"][tokens][None]
-        if cfg.use_wpe:
-            safe = jnp.clip(positions, 0, self.max_seq_len - 1)
-            x = x + params["wpe"]["embedding"][safe][None]
+        with jax.named_scope("embed"):
+            x = params["wte"]["embedding"][tokens][None]
+            if cfg.use_wpe:
+                safe = jnp.clip(positions, 0, self.max_seq_len - 1)
+                x = x + params["wpe"]["embedding"][safe][None]
 
         def body(x, layer):
             layer_p, kp, vp, la, lb = layer
@@ -1198,10 +1238,11 @@ class InferenceEngine:
         any mix of adapters and base-only slots — ``ablocks`` [B, NBa]
         is traced data exactly like the sampling lanes."""
         cfg = self.cfg
-        x = params["wte"]["embedding"][tokens[:, None]]
-        if cfg.use_wpe:
-            safe = jnp.clip(lengths, 0, self.max_seq_len - 1)
-            x = x + params["wpe"]["embedding"][safe][:, None]
+        with jax.named_scope("embed"):
+            x = params["wte"]["embedding"][tokens[:, None]]
+            if cfg.use_wpe:
+                safe = jnp.clip(lengths, 0, self.max_seq_len - 1)
+                x = x + params["wpe"]["embedding"][safe][:, None]
 
         def body(x, layer):
             layer_p, kp, vp, la, lb = layer
@@ -1256,10 +1297,11 @@ class InferenceEngine:
         cfg = self.cfg
         C = tokens.shape[0]
         positions = start + jnp.arange(C, dtype=jnp.int32)
-        x = params["wte"]["embedding"][tokens][None]
-        if cfg.use_wpe:
-            safe = jnp.clip(positions, 0, self.max_seq_len - 1)
-            x = x + params["wpe"]["embedding"][safe][None]
+        with jax.named_scope("embed"):
+            x = params["wte"]["embedding"][tokens][None]
+            if cfg.use_wpe:
+                safe = jnp.clip(positions, 0, self.max_seq_len - 1)
+                x = x + params["wpe"]["embedding"][safe][None]
 
         def body(x, layer):
             layer_p, kp, vp, ksp, vsp, la, lb = layer
@@ -1287,10 +1329,11 @@ class InferenceEngine:
                             seen, lora_a, lora_b, ablocks):
         """int8-pool + LoRA combo twin of _decode_slots_fn."""
         cfg = self.cfg
-        x = params["wte"]["embedding"][tokens[:, None]]
-        if cfg.use_wpe:
-            safe = jnp.clip(lengths, 0, self.max_seq_len - 1)
-            x = x + params["wpe"]["embedding"][safe][:, None]
+        with jax.named_scope("embed"):
+            x = params["wte"]["embedding"][tokens[:, None]]
+            if cfg.use_wpe:
+                safe = jnp.clip(lengths, 0, self.max_seq_len - 1)
+                x = x + params["wpe"]["embedding"][safe][:, None]
 
         def body(x, layer):
             layer_p, kp, vp, ksp, vsp, la, lb = layer
@@ -1560,8 +1603,7 @@ class InferenceEngine:
                                      jnp.asarray(dst, jnp.int32))
 
     def sync(self, *values) -> None:
-        """Barrier on device values (pools, logits): the telemetry
-        step-time breakdown's sampled sync point — same discipline as
+        """Barrier on device values (pools, logits) — same discipline as
         utils/timer's ``_device_sync``, but scoped to the values the
         serving step actually produced so it keys no new programs."""
         jax.block_until_ready(values)
@@ -1599,6 +1641,18 @@ class InferenceEngine:
         a_pool, b_pool, ablocks = lora
         return (a_pool, b_pool, jnp.asarray(ablocks, jnp.int32))
 
+    def _run(self, pid: str, program, *args):
+        """Call a jitted serving program. Under telemetry the FIRST call
+        of each program also hands the text of its compiled module to
+        the provenance table: lowering with the very arguments of the
+        dispatch yields the executable the call below then runs (one
+        compilation, not two), so the table is of what is loaded."""
+        sink = self.provenance
+        if sink is not None and pid not in sink.provenance:
+            sink.add_provenance(
+                pid, program.lower(*args).compile().as_text())
+        return program(*args)
+
     def prefill_into_slot(self, k_pool, v_pool, table_row, tokens, start,
                           n_valid, k_scale=None, v_scale=None,
                           sample_state=None, lora=None):
@@ -1610,7 +1664,8 @@ class InferenceEngine:
         largs = self._lora_operands(lora)
         if k_scale is None:
             pf = self._prefill_slot if lora is None else self._prefill_slot_l
-            out = pf(
+            out = self._run(
+                "prefill_slot" if lora is None else "prefill_slot_l", pf,
                 self.params, k_pool, v_pool,
                 jnp.asarray(table_row, jnp.int32),
                 jnp.asarray(tokens, jnp.int32),
@@ -1642,7 +1697,8 @@ class InferenceEngine:
         largs = self._lora_operands(lora)
         if k_scale is None:
             df = self._decode_slots if lora is None else self._decode_slots_l
-            out = df(
+            out = self._run(
+                "decode_slots" if lora is None else "decode_slots_l", df,
                 self.params, k_pool, v_pool,
                 jnp.asarray(tables, jnp.int32),
                 jnp.asarray(lengths, jnp.int32),

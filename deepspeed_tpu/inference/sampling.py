@@ -121,6 +121,7 @@ def candidate_seed(seed: int, index: int) -> int:
 # ---------------------------------------------------------------------
 # fused slot-vectorized sampler (traced into the slot programs)
 # ---------------------------------------------------------------------
+@jax.named_scope("sample")
 def sample_tokens(logits, keys, positions, temps, top_ks, top_ps,
                   rep_pens, seen):
     """Sample one token per slot from last-position ``logits`` [B, V].

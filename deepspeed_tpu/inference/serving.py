@@ -101,9 +101,9 @@ recompile-free with the cache on.
 Telemetry (``telemetry=True`` / ``DS_TELEMETRY=on``,
 docs/OBSERVABILITY.md): every lifecycle transition (enqueue, admit with
 prefix-hit tags, prefill chunks, evict/requeue, finish/timeout/shed),
-injected faults and a sampled per-phase step-time breakdown stream into
-a :class:`~deepspeed_tpu.telemetry.Telemetry` bundle — ring-buffered
-host-side records plus a metrics registry with Prometheus and
+injected faults and the spans inside a step (``serve.step`` and below)
+stream into a :class:`~deepspeed_tpu.telemetry.Telemetry` bundle —
+ring-buffered host-side records plus a metrics registry with Prometheus and
 Chrome-trace/Perfetto exporters. ``stats`` is now a READ-ONLY mapping
 view over registry counters (same keys, same values as the old dict);
 the scheduler deadline clock is a private field, so mutating a metric
@@ -175,6 +175,12 @@ TERMINAL_STATES = ("done", "timeout", "shed", "error")
 # only the early-freeze optimization is lost for that request
 HORIZON_STOP_WIDTH = 8
 HORIZON_MAX_STOPS = 4
+
+# wall-seconds ladder of the serving_step_*_s histograms: scheduler
+# phases run 10us..1s on CPU/TPU hosts
+_STEP_PHASES = ("admission", "prefill", "decode", "bookkeeping")
+_PHASE_BUCKETS = (1e-5, 2.5e-5, 5e-5, 1e-4, 2.5e-4, 5e-4, 1e-3, 2.5e-3,
+                  5e-3, 1e-2, 2.5e-2, 5e-2, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0)
 
 # the stats contract: same keys (and order) as the pre-telemetry dict,
 # now backed by registry metrics ("c" counter / "g" gauge) and exposed
@@ -462,8 +468,8 @@ class ServingEngine:
       (refcounted block sharing + radix index + copy-on-write). None
       defers to ``DS_PREFIX_CACHE`` (default off — the private-blocks
       allocator stays the bit-reference).
-    - ``telemetry``: lifecycle tracing + metrics registry + step-time
-      breakdown (docs/OBSERVABILITY.md). True/False forces it, a
+    - ``telemetry``: lifecycle tracing + spans + metrics registry
+      (docs/OBSERVABILITY.md). True/False forces it, a
       :class:`~deepspeed_tpu.telemetry.Telemetry` instance is used
       as-is (share one across engines to aggregate), None defers to
       ``DS_TELEMETRY`` (default off — no-op plane, zero overhead).
@@ -722,6 +728,17 @@ class ServingEngine:
             self._h_occ = reg.histogram(
                 "serving_batch_occupancy", "decoding slots per step",
                 buckets=tuple(float(i) for i in range(num_slots + 1)))
+            # wall seconds of every step and of its four phases, from
+            # the step's spans (serve.step and its children)
+            self._h_step = reg.histogram(
+                "serving_step_s", help="total wall seconds per step",
+                buckets=_PHASE_BUCKETS)
+            self._h_phase = {
+                ph: reg.histogram(
+                    f"serving_step_{ph}_s",
+                    help=f"wall seconds per step in the {ph} phase",
+                    buckets=_PHASE_BUCKETS)
+                for ph in _STEP_PHASES}
             self._g_held = reg.gauge(
                 "serving_hbm_blocks_held", "pool blocks with refcount > 0")
             self._g_cached = reg.gauge(
@@ -818,6 +835,7 @@ class ServingEngine:
             self.faults.add_listener(self._fault_listener)
         else:
             self._h_ttft = self._h_tpot = self._h_qwait = self._h_occ = None
+            self._h_step = self._h_phase = None
             self._h_accept = self._h_tps = self._h_temp = None
             self._h_horizon = self._g_horizon = None
             self._h_kv_err = None
@@ -868,6 +886,9 @@ class ServingEngine:
             self.cost_registry.populate(engine, cache=self.cache)
             if self.telemetry.enabled:
                 self.cost_registry.export_gauges(self.metrics)
+                # the first dispatch of each serving program (warm-up)
+                # adds its provenance table to the registry
+                engine.provenance = self.cost_registry
         else:
             self.costs = NOOP_COSTS
             self.cost_registry = None
@@ -1046,16 +1067,65 @@ class ServingEngine:
         else:
             self._token_tick = float(self.token_time_unit)
         self._horizon_ticks = 1
-        bd = self.telemetry.breakdown
-        sampled = bd.begin(self._step_clock, sync=self._sync_devices)
-        self._expire(now)
-        self._admit(now)
-        bd.lap("admission")
-        self._prefill_step(now)
-        bd.lap("prefill")
-        occ = self._decode_step(now)
-        self._spill_step()
-        bd.lap("decode")
+        tracer = self.telemetry.tracer
+        clock = self._step_clock
+        with tracer.span("serve.step", step=clock,
+                         queue=len(self.queue)) as s_step:
+            with tracer.span("serve.expire", step=clock) as s_expire:
+                c0 = self._span_counts()
+                self._expire(now)
+                c1 = self._span_counts()
+                if c1:
+                    s_expire.set(expired=c1[0] - c0[0],
+                                 blocks_freed=c0[3] - c1[3])
+            with tracer.span("serve.admit", step=clock) as s_admit:
+                self._admit(now)
+                c2 = self._span_counts()
+                if c2:
+                    s_admit.set(admitted=c2[1] - c1[1],
+                                blocks_allocated=c2[3] - c1[3])
+            self._prefill_step(now)
+            with tracer.span("serve.decode", step=clock) as s_decode:
+                c3 = self._span_counts()
+                occ = self._decode_step(now)
+                c4 = self._span_counts()
+                if c4:
+                    s_decode.set(live=occ, blocks=c4[3],
+                                 evicted=c4[2] - c3[2])
+            with tracer.span("serve.spill", step=clock) as s_spill:
+                self._spill_step()
+            with tracer.span("serve.bookkeep", step=clock):
+                self._bookkeep(occ, clock)
+        if self._h_step is not None:
+            # the phases tile the step: admission ends with serve.admit,
+            # prefill with the start of serve.decode, decode (the
+            # host-tier tick with it) with serve.spill
+            self._h_step.observe(s_step.dur)
+            self._h_phase["admission"].observe(s_admit.t1 - s_step.t0)
+            self._h_phase["prefill"].observe(s_decode.t0 - s_admit.t1)
+            self._h_phase["decode"].observe(s_spill.t1 - s_decode.t0)
+            self._h_phase["bookkeeping"].observe(s_step.t1 - s_spill.t1)
+        if self._watchdog_msg is not None:
+            msg, self._watchdog_msg = self._watchdog_msg, None
+            self._over_budget = 0
+            self.telemetry.tracer.event("degraded", step=self._step_clock,
+                                        message=msg)
+            raise self._degraded(msg)
+        return occ
+
+    def _span_counts(self):
+        """(timeouts, admitted, evictions, used blocks) for the counts on
+        a step's spans; () with telemetry off."""
+        if not self.telemetry.enabled:
+            return ()
+        st = self._stat
+        return (st["timeouts"].value, st["admitted"].value,
+                st["evictions"].value, self.cache.used_blocks)
+
+    def _bookkeep(self, occ: int, clock: int) -> None:
+        """Everything ``step`` does after the host-tier tick: the
+        deadline clock, KV residency charges, the stats, backpressure
+        and (every ``sample_every``-th step) the sampled gauges."""
         # the deadline clock advances one tick per emitted token: a
         # horizon-N decode that produced p tokens consumed p ticks, so
         # relative deadlines keep their token-count meaning at N > 1
@@ -1078,16 +1148,8 @@ class ServingEngine:
         self._update_backpressure()
         if self._h_occ is not None:
             self._h_occ.observe(occ)
-            if sampled:
+            if clock % self.telemetry.sample_every == 0:
                 self._sample_gauges()
-        bd.finish(occupancy=occ)
-        if self._watchdog_msg is not None:
-            msg, self._watchdog_msg = self._watchdog_msg, None
-            self._over_budget = 0
-            self.telemetry.tracer.event("degraded", step=self._step_clock,
-                                        message=msg)
-            raise self._degraded(msg)
-        return occ
 
     def run(self, requests=None, max_steps: int = 1_000_000,
             wall_clock: bool = False) -> Dict[Any, np.ndarray]:
@@ -1362,66 +1424,76 @@ class ServingEngine:
                 continue
             done = int(self._progress[slot])
             n = min(self.prefill_chunk, len(req._work) - done)
-            chunk = np.zeros((self.prefill_chunk,), np.int32)
-            chunk[:n] = req._work[done:done + n]
-            # the slot's sampling lane rides every chunk (data, not a
-            # signature change); only the FINAL chunk's sample is kept
-            lane = self.sampler.lane(slot, len(req.out))
-            lora = self._lora_args(slot)
-            if self._quant:
-                (logits, tok, lp, self.cache.k, self.cache.v,
-                 self.cache.k_scale, self.cache.v_scale) = self._device_call(
-                    "serving.prefill",
-                    lambda *a: self.engine.prefill_into_slot(
-                        *a, sample_state=lane, lora=lora),
-                    self.cache.k, self.cache.v, self.cache.tables[slot],
-                    chunk, done, n, self.cache.k_scale,
-                    self.cache.v_scale, now=now)
-            else:
-                (logits, tok, lp, self.cache.k,
-                 self.cache.v) = self._device_call(
-                    "serving.prefill",
-                    lambda *a: self.engine.prefill_into_slot(
-                        *a, sample_state=lane, lora=lora),
-                    self.cache.k, self.cache.v, self.cache.tables[slot],
-                    chunk, done, n, now=now)
-            self.cache.advance(slot, n)
-            self._progress[slot] = done + n
-            self._stat["prefill_chunks"].inc()
-            # one prefill-chunk dispatch: n new tokens over `done`
-            # cached context, whole cost owned by this slot's request
-            self.costs.charge_prefill(req, n, done)
-            self.telemetry.tracer.event(
-                "prefill_chunk", rid=req.rid, step=self._step_clock,
-                slot=slot, start=done, n=n)
-            if self._progress[slot] == len(req._work):
-                # prompt fully resident: publish its full blocks to the
-                # prefix index (before _emit, which may free the slot)
-                # so the NEXT request sharing this prefix skips them —
-                # unless this slot decoded under an adapter: its K/V
-                # carries that adapter's weights and must never be
-                # served to another tenant (docs/ADAPTERS.md)
-                if req.adapter_id is None:
-                    self.cache.register_prefix(slot, req._work)
-                self.telemetry.tracer.event(
-                    "prefill_done", rid=req.rid, step=self._step_clock,
-                    slot=slot)
-                # final chunk: its last-position logits yielded the next
-                # token inside the program (== generate()'s prefill
-                # sample on the greedy lane; on resume, the recomputed
-                # position is exactly the pre-eviction one, and the
-                # sampled lane's key fold_in(key, len(out)) replays the
-                # identical draw)
-                self._emit_sampled(
-                    slot, req,
-                    int(np.asarray(tok)[0]),  # dslint: disable=DS001 — final chunk only: ONE pull per prefill completion (the prefill-emitted token), not per-chunk work
-                    float(np.asarray(lp)[0]),  # dslint: disable=DS001 — same single completion-time pull
-                    now)
-                if req.state not in TERMINAL_STATES:
-                    # prefill-only role: park the finished prefill for
-                    # the router's KV migration instead of decoding it
-                    req.state = "handoff" if self.prefill_only \
-                        else "decode"
+            with self.telemetry.tracer.span(
+                    "serve.prefill", rid=req.rid, step=self._step_clock,
+                    slot=slot, start=done, n=n):
+                self._prefill_slot_chunk(slot, req, done, n, now)
+
+    def _prefill_slot_chunk(self, slot: int, req: ServeRequest,
+                            done: int, n: int, now: float) -> None:
+        """One chunk of one slot's prompt: ``n`` tokens from ``done``."""
+        tracer = self.telemetry.tracer
+        chunk = np.zeros((self.prefill_chunk,), np.int32)
+        chunk[:n] = req._work[done:done + n]
+        # the slot's sampling lane rides every chunk (data, not a
+        # signature change); only the FINAL chunk's sample is kept
+        lane = self.sampler.lane(slot, len(req.out))
+        lora = self._lora_args(slot)
+        if self._quant:
+            (logits, tok, lp, self.cache.k, self.cache.v,
+             self.cache.k_scale, self.cache.v_scale) = self._device_call(
+                "serving.prefill",
+                lambda *a: self.engine.prefill_into_slot(
+                    *a, sample_state=lane, lora=lora),
+                self.cache.k, self.cache.v, self.cache.tables[slot],
+                chunk, done, n, self.cache.k_scale,
+                self.cache.v_scale, now=now)
+        else:
+            (logits, tok, lp, self.cache.k,
+             self.cache.v) = self._device_call(
+                "serving.prefill",
+                lambda *a: self.engine.prefill_into_slot(
+                    *a, sample_state=lane, lora=lora),
+                self.cache.k, self.cache.v, self.cache.tables[slot],
+                chunk, done, n, now=now)
+        self.cache.advance(slot, n)
+        self._progress[slot] = done + n
+        self._stat["prefill_chunks"].inc()
+        # one prefill-chunk dispatch: n new tokens over `done`
+        # cached context, whole cost owned by this slot's request
+        self.costs.charge_prefill(req, n, done)
+        tracer.event("prefill_chunk", rid=req.rid, step=self._step_clock,
+                     slot=slot, start=done, n=n)
+        if self._progress[slot] != len(req._work):
+            return
+        # prompt fully resident: publish its full blocks to the
+        # prefix index (before _emit, which may free the slot)
+        # so the NEXT request sharing this prefix skips them —
+        # unless this slot decoded under an adapter: its K/V
+        # carries that adapter's weights and must never be
+        # served to another tenant (docs/ADAPTERS.md)
+        if req.adapter_id is None:
+            self.cache.register_prefix(slot, req._work)
+        tracer.event("prefill_done", rid=req.rid, step=self._step_clock,
+                     slot=slot)
+        # final chunk: its last-position logits yielded the next
+        # token inside the program (== generate()'s prefill
+        # sample on the greedy lane; on resume, the recomputed
+        # position is exactly the pre-eviction one, and the
+        # sampled lane's key fold_in(key, len(out)) replays the
+        # identical draw)
+        with tracer.span("serve.pull", rid=req.rid, step=self._step_clock,
+                         slot=slot, bytes=tok.nbytes + lp.nbytes):
+            tok_h = int(np.asarray(tok)[0])  # dslint: disable=DS001 — final chunk only: ONE pull per prefill completion (the prefill-emitted token), not per-chunk work
+            lp_h = float(np.asarray(lp)[0])  # dslint: disable=DS001 — same single completion-time pull
+        with tracer.span("serve.emit", rid=req.rid, step=self._step_clock,
+                         slot=slot, tokens=1):
+            self._emit_sampled(slot, req, tok_h, lp_h, now)
+        if req.state not in TERMINAL_STATES:
+            # prefill-only role: park the finished prefill for
+            # the router's KV migration instead of decoding it
+            req.state = "handoff" if self.prefill_only \
+                else "decode"
 
     def _decode_step(self, now: float) -> int:
         # every decoding slot needs room for ONE more token; exhaustion
@@ -1528,15 +1600,20 @@ class ServingEngine:
                            for i in live])
         # one host transfer covers every slot's token + logprob (the
         # sampler already ran inside the compiled decode program)
-        t_dev = time.perf_counter()
-        toks = np.asarray(toks)
-        lps = np.asarray(lps)
-        self.device_time_s += time.perf_counter() - t_dev
-        for i in live:
-            self.cache.advance(i, 1)
-            self._emit_sampled(
-                i, self.slots[i], int(toks[i]),
-                float(lps[i]), now)  # dslint: disable=DS001 — lps is host numpy already (the single batched pull above)
+        tracer = self.telemetry.tracer
+        with tracer.span("serve.pull", step=self._step_clock,
+                         bytes=toks.nbytes + lps.nbytes):
+            t_dev = time.perf_counter()
+            toks = np.asarray(toks)
+            lps = np.asarray(lps)
+            self.device_time_s += time.perf_counter() - t_dev
+        with tracer.span("serve.emit", step=self._step_clock,
+                         tokens=len(live)):
+            for i in live:
+                self.cache.advance(i, 1)
+                self._emit_sampled(
+                    i, self.slots[i], int(toks[i]),
+                    float(lps[i]), now)  # dslint: disable=DS001 — lps is host numpy already (the single batched pull above)
         return len(live)
 
     def _horizon_decode_step(self, live: List[int],
@@ -1642,11 +1719,14 @@ class ServingEngine:
         self._stat["decode_steps"].inc()
         # ONE batched host transfer harvests the whole horizon: [N, B]
         # tokens + logprobs and the per-slot produced counts
-        t_dev = time.perf_counter()
-        toks = np.asarray(toks)
-        lps = np.asarray(lps)
-        produced = np.asarray(produced)
-        self.device_time_s += time.perf_counter() - t_dev
+        with self.telemetry.tracer.span(
+                "serve.pull", step=self._step_clock,
+                bytes=toks.nbytes + lps.nbytes + produced.nbytes):
+            t_dev = time.perf_counter()
+            toks = np.asarray(toks)
+            lps = np.asarray(lps)
+            produced = np.asarray(produced)
+            self.device_time_s += time.perf_counter() - t_dev
         if self.costs.enabled:
             # one fused dispatch: each live slot produced its own token
             # count over its own pre-advance context
@@ -1865,8 +1945,9 @@ class ServingEngine:
         """Host-tier daemon tick: runs right AFTER the decode dispatch
         (the gather it queues overlaps the decode program; last tick's
         gather is harvested here, a full step after dispatch — the
-        double buffer) and never on the admission path. Billed inside
-        the decode breakdown lap so the phase set is unchanged. The
+        double buffer) and never on the admission path. Billed to the
+        decode phase of ``serving_step_*_s`` (its own span is
+        ``serve.spill``). The
         tick's host time answers to the step watchdog, but only an
         over-budget tick may strike — an in-budget tick must not reset
         the decode dispatch's own strikes."""
@@ -1955,18 +2036,25 @@ class ServingEngine:
         step."""
         delay = self.retry_backoff_s
         attempt = 0
+        tracer = self.telemetry.tracer
         while True:
             try:
-                self.faults.fire(site)
-                # block inside the timed window: dispatch is async, and
-                # every caller harvests the result immediately anyway —
-                # blocking here makes device_time_s (the bench's
-                # host/device ms-per-token split) and the watchdog's
-                # elapsed measurement cover the actual execution instead
-                # of just the enqueue
-                t_dev = time.perf_counter()
-                out = jax.block_until_ready(fn(*args))
-                self.device_time_s += time.perf_counter() - t_dev
+                with tracer.span("serve.dispatch", step=self._step_clock,
+                                 site=site, attempt=attempt):
+                    # block inside the timed window: dispatch is async,
+                    # and every caller harvests the result immediately
+                    # anyway — blocking here makes device_time_s (the
+                    # bench's host/device ms-per-token split) and the
+                    # watchdog's elapsed measurement cover the actual
+                    # execution instead of just the enqueue
+                    t_dev = time.perf_counter()
+                    with tracer.span("serve.dispatch.enqueue"):
+                        # host: arguments, transfers, launch
+                        self.faults.fire(site)
+                        out = fn(*args)
+                    with tracer.span("serve.dispatch.wait"):
+                        out = jax.block_until_ready(out)
+                    self.device_time_s += time.perf_counter() - t_dev
                 return out
             except TransientDeviceError:
                 if attempt >= self.max_retries:
@@ -1991,23 +2079,10 @@ class ServingEngine:
         else:
             self._stat["backpressure"].set(0.0)
 
-    def _sync_devices(self) -> None:
-        """Sampled-step barrier (utils/timer device-sync discipline):
-        drain pending pool work so a breakdown lap bills device time to
-        the phase that dispatched it. Only the breakdown calls this,
-        and only on sampled steps — the unsampled hot path stays
-        sync-free (dslint DS001)."""
-        if self._quant:
-            jax.block_until_ready((self.cache.k, self.cache.v,
-                                   self.cache.k_scale,
-                                   self.cache.v_scale))
-        else:
-            jax.block_until_ready((self.cache.k, self.cache.v))
-
     def _sample_gauges(self) -> None:
         """Sampled-step gauge refresh: HBM block states + prefix hit
-        rate. Host numpy reductions — cheap, but they ride the
-        breakdown's sampling cadence, not every step."""
+        rate. Host numpy reductions — cheap, but they run every
+        ``telemetry.sample_every``-th step, not every step."""
         self._g_held.set(int(self.cache.held_blocks))
         self._g_cached.set(int(self.cache.cached_blocks))
         self._g_free.set(int(self.cache.free_blocks))

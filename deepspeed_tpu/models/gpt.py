@@ -523,37 +523,46 @@ def _block(x, layer_params, cfg: GPTConfig, dropout_rng=None,
     else:
         dr_attn = dr_mlp = None
 
-    h = _norm(x, p["ln1"], cfg)
-    qkv = _dense(h, p["qkv"])
-    qkv = checkpoint_name(qkv, "qkv")
-    q, k, v = _qkv_split_rotary(qkv, cfg, positions, B, S)
-    attn = _attention(q, k, v, cfg, segment_ids=segment_ids).reshape(B, S, D)
-    attn = checkpoint_name(attn, "attn")
-    attn = _dense(attn, p["attn_out"])
-    if not deterministic and cfg.dropout > 0:
-        attn = _dropout(attn, cfg.dropout, dr_attn)
+    # the scopes name the parts of a block in the compiled program's
+    # metadata (profiles, the provenance table of telemetry/costs.py);
+    # they change nothing that is computed
+    with jax.named_scope("attn_qkv"):
+        h = _norm(x, p["ln1"], cfg)
+        qkv = _dense(h, p["qkv"])
+        qkv = checkpoint_name(qkv, "qkv")
+        q, k, v = _qkv_split_rotary(qkv, cfg, positions, B, S)
+    with jax.named_scope("attn"):
+        attn = _attention(q, k, v, cfg,
+                          segment_ids=segment_ids).reshape(B, S, D)
+        attn = checkpoint_name(attn, "attn")
+    with jax.named_scope("attn_out"):
+        attn = _dense(attn, p["attn_out"])
+        if not deterministic and cfg.dropout > 0:
+            attn = _dropout(attn, cfg.dropout, dr_attn)
 
-    # GPT-J style parallel residual: MLP reads the SAME ln1 output and
-    # both branches add to x (ref: HFGPTJLayerPolicy, replace_policy.py:157)
-    mlp_src = h if cfg.parallel_residual else None
-    if not cfg.parallel_residual:
-        x = x + attn
-        mlp_src = _norm(x, p["ln2"], cfg)
+    with jax.named_scope("mlp"):
+        # GPT-J style parallel residual: MLP reads the SAME ln1 output
+        # and both branches add to x (ref: HFGPTJLayerPolicy,
+        # replace_policy.py:157)
+        mlp_src = h if cfg.parallel_residual else None
+        if not cfg.parallel_residual:
+            x = x + attn
+            mlp_src = _norm(x, p["ln2"], cfg)
 
-    m = _dense(mlp_src, p["mlp_in"])
-    m = checkpoint_name(m, "mlp_pre")
-    if cfg.activation == "swiglu":
-        # gated MLP: silu(x @ gate) * (x @ up) — separate kernels so
-        # column-parallel TP keeps gate/up halves aligned per shard
-        m = jax.nn.silu(_dense(mlp_src, p["mlp_gate"])) * m
-    else:
-        m = jax.nn.gelu(m, approximate=True)
-    m = _dense(m, p["mlp_out"])
-    if not deterministic and cfg.dropout > 0:
-        m = _dropout(m, cfg.dropout, dr_mlp)
-    if cfg.parallel_residual:
-        return x + attn + m
-    return x + m
+        m = _dense(mlp_src, p["mlp_in"])
+        m = checkpoint_name(m, "mlp_pre")
+        if cfg.activation == "swiglu":
+            # gated MLP: silu(x @ gate) * (x @ up) — separate kernels so
+            # column-parallel TP keeps gate/up halves aligned per shard
+            m = jax.nn.silu(_dense(mlp_src, p["mlp_gate"])) * m
+        else:
+            m = jax.nn.gelu(m, approximate=True)
+        m = _dense(m, p["mlp_out"])
+        if not deterministic and cfg.dropout > 0:
+            m = _dropout(m, cfg.dropout, dr_mlp)
+        if cfg.parallel_residual:
+            return x + attn + m
+        return x + m
 
 
 def _dropout(x, rate, rng):
@@ -586,12 +595,13 @@ def forward(params: Dict, tokens: jnp.ndarray, cfg: GPTConfig,
             "sp_layout='zigzag' permutes the token order — pass "
             "positions (the zigzag_perm itself for unpacked batches) so "
             "positional encodings follow the tokens")
-    wte = params["wte"]["embedding"].astype(dtype)
-    x = wte[tokens]
-    if cfg.use_wpe:
-        wpe = params["wpe"]["embedding"].astype(dtype)
-        x = x + (wpe[positions] if positions is not None
-                 else wpe[:S][None])
+    with jax.named_scope("embed"):
+        wte = params["wte"]["embedding"].astype(dtype)
+        x = wte[tokens]
+        if cfg.use_wpe:
+            wpe = params["wpe"]["embedding"].astype(dtype)
+            x = x + (wpe[positions] if positions is not None
+                     else wpe[:S][None])
 
     block = params["block"]
     L = cfg.n_layers
@@ -656,17 +666,18 @@ def forward(params: Dict, tokens: jnp.ndarray, cfg: GPTConfig,
     rng = rng if rng is not None else jax.random.PRNGKey(0)
     (x, _), _ = jax.lax.scan(body, (x, rng), (block, jnp.arange(L)))
 
-    x = _norm(x, params["ln_f"], cfg)
-    if hidden_only:
-        return x
-    if cfg.tie_embeddings:
-        logits = x @ wte.T
-    else:
-        head = params["lm_head"]
-        logits = x @ head["kernel"].astype(dtype)
-        if "bias" in head:   # e.g. GPT-J ships an lm_head bias
-            logits = logits + head["bias"].astype(dtype)
-    return logits
+    with jax.named_scope("logits"):
+        x = _norm(x, params["ln_f"], cfg)
+        if hidden_only:
+            return x
+        if cfg.tie_embeddings:
+            logits = x @ wte.T
+        else:
+            head = params["lm_head"]
+            logits = x @ head["kernel"].astype(dtype)
+            if "bias" in head:   # e.g. GPT-J ships an lm_head bias
+                logits = logits + head["bias"].astype(dtype)
+        return logits
 
 
 def _head_nll(other: Dict, y: jnp.ndarray, targets: jnp.ndarray,

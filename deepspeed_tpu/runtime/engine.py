@@ -36,6 +36,7 @@ from deepspeed_tpu.runtime.config import DeepSpeedConfig
 from deepspeed_tpu.runtime.lr_schedules import get_lr_schedule
 from deepspeed_tpu.runtime.utils import (clip_by_global_norm, count_parameters,
                                          global_norm)
+from deepspeed_tpu.utils.compile_guard import compile_count
 from deepspeed_tpu.utils.logging import log_dist, logger
 from deepspeed_tpu.utils.timer import (NoopTimer, SynchronizedWallClockTimer,
                                        ThroughputTimer, TRAIN_BATCH_TIMER)
@@ -123,6 +124,10 @@ class DeepSpeedEngine:
         self.global_samples = 0
         self.micro_steps = 0
         self.skipped_steps = 0
+        # compilations inside the train step's call since construction
+        # (one for the first step; more means something recompiled)
+        self.train_compiles = 0
+        self._trace_steps_left = 0
         self.client_lr_schedule = lr_schedule
 
         self.dp_world_size = mesh_lib.dp_world_size(self.mesh)
@@ -642,7 +647,8 @@ class DeepSpeedEngine:
                 micro_batch = dict(micro_batch)
                 micro_batch[PLD_THETA_KEY] = theta_schedule(
                     step, pld_cfg.theta, pld_cfg.gamma)
-            out = loss_fn(cparams, micro_batch, rng)
+            with jax.named_scope("loss"):
+                out = loss_fn(cparams, micro_batch, rng)
             if has_aux:
                 loss, aux = out
             else:
@@ -767,9 +773,10 @@ class DeepSpeedEngine:
                 else:
                     overflow = jnp.asarray(False)
 
-            gnorm = global_norm(grads)
-            if clip > 0.0:
-                grads = clip_by_global_norm(grads, clip, norm=gnorm)
+            with jax.named_scope("grad_norm"):
+                gnorm = global_norm(grads)
+                if clip > 0.0:
+                    grads = clip_by_global_norm(grads, clip, norm=gnorm)
 
             # ---- optimizer update with overflow skip (lax.cond) ----
             def do_step(operands):
@@ -789,9 +796,10 @@ class DeepSpeedEngine:
                 _, os_, p = operands
                 return os_, p
 
-            new_opt_state, new_params = jax.lax.cond(
-                overflow, skip_step, do_step,
-                (grads, state.opt_state, state.params))
+            with jax.named_scope("optimizer"):
+                new_opt_state, new_params = jax.lax.cond(
+                    overflow, skip_step, do_step,
+                    (grads, state.opt_state, state.params))
 
             new_scale = ls.update(
                 state.scale_state, overflow,
@@ -829,6 +837,9 @@ class DeepSpeedEngine:
 
         self._state_shardings = state_shardings
         self._batch_shard_leaf = mesh_lib.batch_sharding(self.mesh)
+        # an explicit module name (jit_train_step): profiles and the
+        # provenance table keep it when the function is renamed
+        step_fn.__name__ = step_fn.__qualname__ = "train_step"
         return jax.jit(
             step_fn,
             in_shardings=(state_shardings, None),  # batch: committed by _shard_batch
@@ -911,9 +922,10 @@ class DeepSpeedEngine:
                 overflow = ls.has_overflow(grads)
             else:
                 overflow = jnp.asarray(False)
-            gnorm = global_norm(grads)
-            if clip > 0.0:
-                grads = clip_by_global_norm(grads, clip, norm=gnorm)
+            with jax.named_scope("grad_norm"):
+                gnorm = global_norm(grads)
+                if clip > 0.0:
+                    grads = clip_by_global_norm(grads, clip, norm=gnorm)
             new_scale = ls.update(
                 scale_state, overflow,
                 dynamic=self.dynamic_loss_scale and fp16,
@@ -1013,41 +1025,61 @@ class DeepSpeedEngine:
         """One full optimizer step over a global batch
         (leading dim == train_batch_size). Fuses the reference's
         forward+backward+step triple into one XLA program."""
-        self.tput_timer.start()
-        self.timers(TRAIN_BATCH_TIMER).start()
-        if self.curriculum_scheduler is not None:
-            difficulty = self.curriculum_scheduler.update_difficulty(
-                self.global_steps + 1)
-            batch = self._apply_curriculum(batch, difficulty)
-        if self.progressive_layer_drop is not None:
-            # keyed on applied steps, matching the in-jit theta_schedule
-            # even when fp16 overflow skips steps; computed host-side
-            # (global - skipped) to avoid syncing on state.step
-            self.progressive_layer_drop.update_state(
-                self.global_steps - self.skipped_steps)
-        batch = self._shard_batch(batch)
-        profiling_now = (self.config.flops_profiler.enabled
-                         and not self.offload_enabled
-                         and self.global_steps + 1 ==
-                         self.config.flops_profiler.profile_step)
-        if profiling_now:
-            # drain queued prior steps so the timed window is exactly
-            # this step (set profile_step >= 2 to exclude compile time)
-            jax.block_until_ready(self.state.params)
-        t0 = time.perf_counter()
-        from deepspeed_tpu.utils.trace import annotation
-        # mesh in context: models can pin activation layouts with bare
-        # PartitionSpecs (gpt.py scan-carry constraint) during tracing
-        with annotation("ds.train_batch"), jax.set_mesh(self.mesh):
-            if self.offload_enabled:
-                metrics = self._offload_train_batch(batch)
-            else:
-                self.state, metrics = self._train_step(self.state, batch)
-        if getattr(self, "_trace_steps_left", 0) > 0:
+        # bare profiler annotations (no telemetry bundle here): in a
+        # profile, train.step carries the step number and splits into
+        # batch placement, the jitted call and the host work after it;
+        # with no profiler session each costs tens of nanoseconds
+        with jax.profiler.StepTraceAnnotation(
+                "train.step", step_num=self.global_steps):
+            with jax.profiler.TraceAnnotation("train.input"):
+                self.tput_timer.start()
+                self.timers(TRAIN_BATCH_TIMER).start()
+                if self.curriculum_scheduler is not None:
+                    difficulty = self.curriculum_scheduler.update_difficulty(
+                        self.global_steps + 1)
+                    batch = self._apply_curriculum(batch, difficulty)
+                if self.progressive_layer_drop is not None:
+                    # keyed on applied steps, matching the in-jit
+                    # theta_schedule even when fp16 overflow skips steps;
+                    # computed host-side (global - skipped) to avoid
+                    # syncing on state.step
+                    self.progressive_layer_drop.update_state(
+                        self.global_steps - self.skipped_steps)
+                batch = self._shard_batch(batch)
+            profiling_now = (self.config.flops_profiler.enabled
+                             and not self.offload_enabled
+                             and self.global_steps + 1 ==
+                             self.config.flops_profiler.profile_step)
+            if profiling_now:
+                # drain queued prior steps so the timed window is exactly
+                # this step (set profile_step >= 2 to exclude compile time)
+                jax.block_until_ready(self.state.params)
+            t0 = time.perf_counter()
+            compiles = compile_count()
+            # mesh in context: models can pin activation layouts with bare
+            # PartitionSpecs (gpt.py scan-carry constraint) during tracing
+            with jax.profiler.TraceAnnotation("train.dispatch"), \
+                    jax.set_mesh(self.mesh):
+                if self.offload_enabled:
+                    metrics = self._offload_train_batch(batch)
+                else:
+                    self.state, metrics = self._train_step(self.state, batch)
+            # a step that compiles again is visible from inside (PR 21
+            # found one compiling twice)
+            self.train_compiles += compile_count() - compiles
+            with jax.profiler.TraceAnnotation("train.host"):
+                self._after_dispatch(batch, metrics, profiling_now, t0)
+        if self._trace_steps_left > 0:      # a start_trace window
             self._trace_steps_left -= 1
             if self._trace_steps_left == 0:
                 jax.block_until_ready(metrics["loss"])
                 jax.profiler.stop_trace()
+        return metrics
+
+    def _after_dispatch(self, batch: PyTree, metrics, profiling_now: bool,
+                        t0: float) -> None:
+        """The host work of ``train_batch`` after the jitted call: the
+        flops profile, timers, counters, the monitor."""
         if profiling_now:
             # block only on the profiled step — every other step keeps
             # async dispatch so the host can run ahead
@@ -1084,7 +1116,6 @@ class DeepSpeedEngine:
                 self._flush_monitor_buffer()
         if self.global_steps % self.config.steps_per_print == 0:
             self._report_progress(metrics)
-        return metrics
 
     def _flush_monitor_buffer(self):
         buffered, self._monitor_buffer = self._monitor_buffer, []

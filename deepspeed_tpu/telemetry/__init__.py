@@ -1,5 +1,5 @@
-"""Serving telemetry — request-lifecycle tracing, a metrics registry
-with Prometheus/Perfetto exporters, and a sampled step-time breakdown.
+"""Serving telemetry — request-lifecycle tracing and spans, and a
+metrics registry with Prometheus/Perfetto exporters.
 
 The reproduction's analog of the reference's engine-owned monitoring
 (deepspeed/monitor/* + the flops profiler), at serving granularity: an
@@ -10,17 +10,16 @@ eviction/COW/retry timelines), so this package gives the
 observability plane — see docs/OBSERVABILITY.md for the metric catalog,
 trace schema and overhead notes.
 
-Three pieces, one facade:
+Two pieces, one facade:
 
 - :class:`~deepspeed_tpu.telemetry.metrics.MetricsRegistry` — counters,
   gauges, fixed-bucket histograms; exports Prometheus text exposition
   and Monitor-compatible scalar tuples;
 - :class:`~deepspeed_tpu.telemetry.tracer.RequestTracer` — ring-
-  buffered host-side lifecycle events; exports Chrome-trace/Perfetto
-  JSON (``tools/trace_analyze.py serve <file>`` reads it);
-- :class:`~deepspeed_tpu.telemetry.breakdown.StepBreakdown` — sampled
-  per-phase step timing under the ``utils/timer.py`` device-sync
-  discipline.
+  buffered host-side lifecycle events and spans (the program's one span
+  recorder; a span is also a profiler annotation); exports
+  Chrome-trace/Perfetto JSON (``tools/trace_analyze.py serve <file>``
+  reads it).
 
 Enablement mirrors the prefix-cache knob: explicit ``telemetry=`` on
 ``ServingEngine`` wins, else ``DS_TELEMETRY=on|off`` (default OFF — the
@@ -33,14 +32,13 @@ import time
 from typing import Optional
 
 from deepspeed_tpu.utils.env import resolve_flag
-from deepspeed_tpu.telemetry.breakdown import (NoopBreakdown, PHASES,
-                                               StepBreakdown)
 from deepspeed_tpu.telemetry.metrics import (Counter, DEFAULT_BUCKETS,
                                              Gauge, Histogram,
                                              MetricsRegistry,
                                              RATE_BUCKETS, TEMP_BUCKETS,
                                              merge_registries)
-from deepspeed_tpu.telemetry.tracer import NoopTracer, RequestTracer
+from deepspeed_tpu.telemetry.tracer import (NOOP_SPAN, NoopTracer,
+                                            RequestTracer, span_self_times)
 from deepspeed_tpu.telemetry.costs import (CostAccountant,
                                            NOOP_COSTS,
                                            NoopCostAccountant,
@@ -53,8 +51,8 @@ from deepspeed_tpu.telemetry.flight import (FlightRecorder, NOOP_FLIGHT,
 
 __all__ = ["Telemetry", "NoopTelemetry", "NOOP", "resolve_telemetry",
            "MetricsRegistry", "Counter", "Gauge", "Histogram",
-           "RequestTracer", "NoopTracer", "StepBreakdown",
-           "NoopBreakdown", "PHASES", "DEFAULT_BUCKETS", "RATE_BUCKETS",
+           "RequestTracer", "NoopTracer", "NOOP_SPAN", "span_self_times",
+           "DEFAULT_BUCKETS", "RATE_BUCKETS",
            "TEMP_BUCKETS", "merge_registries",
            "CostAccountant", "NoopCostAccountant", "NOOP_COSTS",
            "ProgramCostRegistry", "device_peak_flops",
@@ -70,10 +68,11 @@ def resolve_telemetry(flag: Optional[bool] = None) -> bool:
 
 
 class Telemetry:
-    """Live bundle: one registry + one tracer + one breakdown, shared
-    by everything a single :class:`ServingEngine` emits. Pass an
-    instance to several engines to aggregate, or one per engine to
-    keep timelines separate."""
+    """Live bundle: one registry + one tracer, shared by everything a
+    single :class:`ServingEngine` emits. Pass an instance to several
+    engines to aggregate, or one per engine to keep timelines separate.
+    ``sample_every`` is the cadence (in scheduler steps) of the gauges
+    that cost a host reduction or a device pull."""
 
     enabled = True
 
@@ -82,8 +81,7 @@ class Telemetry:
                  clock=time.perf_counter):
         self.registry = registry if registry is not None else MetricsRegistry()
         self.tracer = RequestTracer(capacity=trace_capacity, clock=clock)
-        self.breakdown = StepBreakdown(self.registry, self.tracer,
-                                       sample_every=sample_every)
+        self.sample_every = max(1, int(sample_every))
 
     # convenience exporters -------------------------------------------
     def to_prometheus(self) -> str:
@@ -98,14 +96,13 @@ class Telemetry:
 
 class NoopTelemetry:
     """Off-mode bundle: no registry (the engine keeps a private one for
-    the stats view), no recording, no sampling."""
+    the stats view), no recording."""
 
     enabled = False
     registry = None
 
     def __init__(self):
         self.tracer = NoopTracer()
-        self.breakdown = NoopBreakdown()
 
     def to_prometheus(self) -> str:
         return ""

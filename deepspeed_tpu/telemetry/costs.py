@@ -3,7 +3,7 @@ registry, and per-dispatch attribution.
 
 The reproduction's serving-side answer to the reference's
 ``deepspeed/profiling/`` flops profiler: the telemetry plane (metrics/
-tracer/breakdown) can say how *long* a request took, this module says
+tracer and its spans) can say how *long* a request took, this module says
 what it *cost* — FLOPs, HBM bytes, and KV block-seconds — per program,
 per request, and per tenant. Three pieces:
 
@@ -17,7 +17,11 @@ per request, and per tenant. Three pieces:
   compiled twin, XLA's own ``cost_analysis()``/``memory_analysis()``
   numbers when a lowered executable is available, falling back to the
   analytic formulas at a reference shape when XLA declines (so the
-  registry is always populated, CPU included);
+  registry is always populated, CPU included); with telemetry on it
+  also keeps each program's PROVENANCE table, parsed from the compiled
+  module's text (:func:`parse_provenance`): instruction name -> opcode,
+  result shape and layout, ``named_scope`` path, source file:line — so
+  a profile's ``copy.63`` is a lookup, not a hunt;
 - :class:`CostAccountant` — exact integer per-dispatch charges rolled
   into global ``serving_flops_total``/``serving_hbm_bytes_total``/
   ``serving_kv_block_seconds`` counters AND per-request footprints,
@@ -32,13 +36,16 @@ on the charge path, no device sync, zero new compiled programs
 """
 
 import json
+import re
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from deepspeed_tpu.utils.jit_registry import (DISPATCH_CLASSES,
                                               dispatch_class,
                                               engine_programs)
 
-__all__ = ["PEAK_FLOPS", "device_peak_flops", "matmul_params",
+__all__ = ["PEAK_FLOPS", "PEAK_HBM_BYTES_PER_S", "device_peak_flops",
+           "device_peak_hbm_bytes_per_s", "parse_provenance",
+           "matmul_params",
            "model_flops_per_token", "attn_flops", "infer_flops",
            "infer_hbm_bytes", "weight_bytes", "split_even",
            "new_footprint", "merge_footprints", "ProgramCostRegistry",
@@ -60,10 +67,18 @@ PEAK_FLOPS = {
 }
 
 
-def device_peak_flops(device=None) -> Optional[float]:
-    """Peak dense FLOP/s for ``device`` (default: first local device),
-    longest-prefix matched against :data:`PEAK_FLOPS`; None when the
-    platform is unknown (CPU, new TPU generations)."""
+# HBM bytes/s per chip by device_kind prefix — the roofline's other
+# denominator. Only what a source states: "TPU v5 lite" is Google
+# Cloud's documentation, "TPU v5e": 819 GB/s per chip (the figure
+# benchmark/harness/peaks.py carries). An unknown device stays None.
+PEAK_HBM_BYTES_PER_S = {
+    "TPU v5 lite": 819e9,
+}
+
+
+def _device_peak(table: Dict[str, float], device) -> Optional[float]:
+    """``table``'s entry for ``device`` (default: first local device) by
+    the longest matching ``device_kind`` prefix; None when unknown."""
     if device is None:
         import jax
         devices = jax.local_devices()
@@ -73,10 +88,24 @@ def device_peak_flops(device=None) -> Optional[float]:
     kind = getattr(device, "device_kind", "") or ""
     best = None
     best_len = -1
-    for prefix, peak in PEAK_FLOPS.items():
+    for prefix, peak in table.items():
         if kind.startswith(prefix) and len(prefix) > best_len:
             best, best_len = peak, len(prefix)
     return best
+
+
+def device_peak_flops(device=None) -> Optional[float]:
+    """Peak dense FLOP/s for ``device`` (default: first local device),
+    longest-prefix matched against :data:`PEAK_FLOPS`; None when the
+    platform is unknown (CPU, new TPU generations)."""
+    return _device_peak(PEAK_FLOPS, device)
+
+
+def device_peak_hbm_bytes_per_s(device=None) -> Optional[float]:
+    """Peak HBM bytes/s for ``device`` from
+    :data:`PEAK_HBM_BYTES_PER_S`; None when no source is on record —
+    never a default."""
+    return _device_peak(PEAK_HBM_BYTES_PER_S, device)
 
 
 # --------------------------------------------------------------------------
@@ -198,6 +227,197 @@ def footprint_totals(fp: Dict) -> Dict[str, int]:
 # --------------------------------------------------------------------------
 # program cost registry
 # --------------------------------------------------------------------------
+# provenance — which scope and source line a compiled instruction comes from
+# --------------------------------------------------------------------------
+
+_INSTR = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+)\s+=\s+(.*)$")
+_COMPUTATION = re.compile(r"^\s*(ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
+_TABLE_ROW = re.compile(r"^(\d+)\s+(.*)$")
+_CALLED = re.compile(r"\b(calls|to_apply)=%?([\w.\-]+)")
+# never device operations of their own; left out to keep the table small
+_SKIPPED_OPCODES = ("parameter", "constant", "get-tuple-element", "tuple")
+
+
+def _balanced(text: str, start: int) -> int:
+    """Index just past the bracket group that opens at ``text[start]``."""
+    depth = 0
+    for i in range(start, len(text)):
+        c = text[i]
+        if c in "([{":
+            depth += 1
+        elif c in ")]}":
+            depth -= 1
+            if depth == 0:
+                return i + 1
+    return len(text)
+
+
+def _attr(text: str, key: str) -> Optional[str]:
+    m = re.search(key + r'=("([^"]*)"|[^\s}]+)', text)
+    if m is None:
+        return None
+    return m.group(2) if m.group(2) is not None else m.group(1)
+
+
+def _scope_of(op_name: str) -> Tuple[str, str]:
+    """``jit(f)/while/body/kv_write/scatter`` -> (``while/body/kv_write``,
+    ``scatter``): the named-scope path without the program's own
+    ``jit(...)`` and the primitive that ends it."""
+    parts = op_name.split("/")
+    if parts and parts[0].startswith(("jit(", "pjit(")):
+        parts = parts[1:]
+    return "/".join(parts[:-1]), parts[-1] if parts else ""
+
+
+def _nearest_named(name: str, users: Dict[str, List[str]],
+                   operand: Dict[str, List[str]], named,
+                   depth: int = 4) -> Optional[str]:
+    """The nearest instruction of a computation that has metadata:
+    breadth first over users, then the first operand's producer (through
+    tuples and get-tuple-elements), at most ``depth`` steps away."""
+    seen, frontier = {name}, [name]
+    for _ in range(depth):
+        nxt = []
+        for n in frontier:
+            for m in users.get(n, []) + operand.get(n, []):
+                if m in named:
+                    return m
+                if m not in seen and m in operand:
+                    seen.add(m)
+                    nxt.append(m)
+        frontier = nxt
+    return None
+
+
+def parse_provenance(hlo_text: str) -> Dict[str, Dict]:
+    """instruction name -> ``{"opcode", "shape", "scope", "op",
+    "source", "program"?, "inferred"?}`` from a compiled module's text
+    (``compiled.as_text()``).
+
+    ``shape`` is the result shape with its layout as printed; ``scope``
+    the ``jax.named_scope`` path out of ``metadata={op_name=...}``
+    (transform wrappers such as ``jvp(...)``, ``transpose(...)``,
+    ``checkpoint`` and ``rematted_computation`` are part of it);
+    ``source`` is ``file:line`` of the innermost frame, from inline
+    ``source_file``/``source_line`` or from the module's stack-frame
+    tables. A fusion takes the metadata of its fused computation's root
+    when it has none of its own. An instruction the compiler inserted
+    itself (a layout-changing ``copy``) may carry no metadata: it takes
+    the scope of the nearest instruction that has some (users first,
+    then its operand's producer) and is marked ``"inferred": true``.
+    Instructions
+    inside fused computations and reducers are left out: they are not
+    operations of their own on the device."""
+    files: Dict[int, str] = {}
+    locations: Dict[int, Tuple[int, int]] = {}     # id -> (file id, line)
+    frames: Dict[int, int] = {}                    # id -> location id
+    table = None
+    computations: Dict[str, List[Dict]] = {}
+    current = None
+    called = set()
+    for line in hlo_text.splitlines():
+        stripped = line.strip()
+        if current is None:
+            if stripped in ("FileNames", "FunctionNames", "FileLocations",
+                            "StackFrames"):
+                table = stripped
+                continue
+            row = _TABLE_ROW.match(stripped) if table else None
+            if row is not None:
+                key, rest = int(row.group(1)), row.group(2)
+                if table == "FileNames":
+                    files[key] = rest.strip('"')
+                elif table == "FileLocations":
+                    locations[key] = (int(_attr(rest, "file_name_id") or 0),
+                                      int(_attr(rest, "line") or 0))
+                elif table == "StackFrames":
+                    frames[key] = int(_attr(rest, "file_location_id") or 0)
+                continue
+            m = _COMPUTATION.match(line)
+            if m is not None and "=" not in line.split("(", 1)[0]:
+                table = None
+                current = m.group(2)
+                computations[current] = []
+            continue
+        if stripped == "}":
+            current = None
+            continue
+        m = _INSTR.match(line)
+        if m is None:
+            continue
+        name, rest = m.group(2), m.group(3)
+        end = _balanced(rest, 0) if rest.startswith("(") \
+            else (rest.find(" ") if " " in rest else len(rest))
+        shape, rest = rest[:end], rest[end:].lstrip()
+        paren = rest.find("(")
+        if paren < 0:
+            continue
+        opcode = rest[:paren]
+        close = _balanced(rest, paren)
+        operands = re.findall(r"%([\w.\-]+)", rest[paren:close])
+        attrs = rest[close:]
+        meta = re.search(r"(?:^|[\s,])metadata=\{([^}]*)\}", attrs)
+        meta = meta.group(1) if meta else ""
+        for _, target in _CALLED.findall(attrs):
+            called.add(target)
+        fused = re.search(r"\bcalls=%?([\w.\-]+)", attrs)
+        computations[current].append({
+            "name": name, "root": bool(m.group(1)), "opcode": opcode,
+            "shape": shape, "operands": operands, "meta": meta,
+            "calls": fused.group(1) if fused and opcode == "fusion"
+            else None})
+
+    def source_of(meta: str) -> str:
+        f, ln = _attr(meta, "source_file"), _attr(meta, "source_line")
+        if f:
+            return f"{f}:{ln}" if ln else f
+        frame = _attr(meta, "stack_frame_id")
+        if frame is None:
+            return ""
+        fid, ln = locations.get(frames.get(int(frame), 0), (0, 0))
+        return f"{files[fid]}:{ln}" if fid in files else ""
+
+    out: Dict[str, Dict] = {}
+    for comp, instrs in computations.items():
+        if comp in called:
+            continue
+        users: Dict[str, List[str]] = {}
+        for ins in instrs:
+            for o in ins["operands"]:
+                users.setdefault(o, []).append(ins["name"])
+        local: Dict[str, Dict] = {}
+        for ins in instrs:
+            meta = ins["meta"]
+            if ins["calls"] in computations:
+                root = [r["meta"] for r in computations[ins["calls"]]
+                        if r["root"] and _attr(r["meta"], "op_name")]
+                meta = root[0] if root else meta
+            scope, op = _scope_of(_attr(meta, "op_name") or "")
+            local[ins["name"]] = {"opcode": ins["opcode"],
+                                  "shape": ins["shape"], "scope": scope,
+                                  "op": op, "source": source_of(meta)}
+        operand = {ins["name"]: ins["operands"][:1] for ins in instrs}
+        named = {n for n, e in local.items() if e["op"] or e["scope"]}
+        for ins in instrs:
+            if ins["name"] in named:
+                continue
+            donor = _nearest_named(ins["name"], users, operand, named)
+            if donor is not None:
+                local[ins["name"]].update(scope=local[donor]["scope"],
+                                          source=local[donor]["source"],
+                                          inferred=True)
+        out.update((n, e) for n, e in local.items()
+                   if e["opcode"] not in _SKIPPED_OPCODES)
+    return out
+
+
+def provenance_module_name(hlo_text: str) -> str:
+    """``jit_serve_decode_slots`` out of ``HloModule jit_serve_...``."""
+    m = re.match(r"\s*HloModule\s+([\w.\-]+)", hlo_text)
+    return m.group(1) if m else ""
+
+
+# --------------------------------------------------------------------------
 
 class ProgramCostRegistry:
     """Static per-program cost card for every serving executable in the
@@ -214,6 +434,9 @@ class ProgramCostRegistry:
 
     def __init__(self):
         self.entries: Dict[str, Dict] = {}
+        # program id -> {"module", "instructions"}: filled by
+        # add_provenance on a program's first dispatch under telemetry
+        self.provenance: Dict[str, Dict] = {}
 
     # .. population .....................................................
 
@@ -295,9 +518,42 @@ class ProgramCostRegistry:
             registry.gauge(f"program_hbm_bytes_{pid}").set(
                 e.get("bytes_accessed", 0))
 
+    def add_provenance(self, pid: str, hlo_text: str) -> None:
+        """Keep program ``pid``'s provenance table, parsed from the text
+        of the executable that is actually loaded (a program restored
+        from jax's persistent cache carries the metadata it was first
+        compiled with: the cache does not key on it)."""
+        self.provenance[pid] = {
+            "module": provenance_module_name(hlo_text),
+            "instructions": parse_provenance(hlo_text)}
+
+    def roofline(self, pid: str, device=None) -> Optional[Dict]:
+        """The least seconds the chip could take for program ``pid``'s
+        FLOPs and bytes, and which of the two binds; None when either
+        peak of the device is unknown."""
+        e = self.entries.get(pid)
+        peak_f = device_peak_flops(device)
+        peak_b = device_peak_hbm_bytes_per_s(device)
+        if e is None or not peak_f or not peak_b:
+            return None
+        tc = e.get("flops", 0) / peak_f
+        tm = e.get("bytes_accessed", 0) / peak_b
+        return {"peak_flops": peak_f, "peak_hbm_bytes_per_s": peak_b,
+                "min_seconds": max(tc, tm),
+                "bound": "compute" if tc >= tm else "memory"}
+
     def to_json(self) -> Dict:
-        return {"programs": {pid: dict(e)
-                             for pid, e in sorted(self.entries.items())}}
+        programs = {}
+        for pid, e in sorted(self.entries.items()):
+            programs[pid] = dict(e)
+            roof = self.roofline(pid)
+            if roof is not None:
+                programs[pid]["roofline"] = roof
+        out = {"programs": programs}
+        if self.provenance:
+            out["provenance"] = {pid: dict(t) for pid, t
+                                 in sorted(self.provenance.items())}
+        return out
 
     def dumps(self) -> str:
         return json.dumps(self.to_json(), sort_keys=True)

@@ -5,8 +5,8 @@ Every request transition the scheduler makes lands here as one record:
 ``enqueue`` → ``admit`` (tagged with the prefix match and any COW) →
 ``prefill_chunk``* → ``prefill_done`` → ``first_token`` → ``evict`` /
 re-``admit`` → ``finish`` (state done/timeout/shed), plus scheduler-
-lane records (``step_phase`` breakdowns, ``watchdog``, ``degraded``)
-and ``fault`` records streamed in from
+lane records (``watchdog``, ``degraded``) and ``fault`` records
+streamed in from
 :class:`deepspeed_tpu.utils.faults.FaultInjector` listeners — so a
 seeded chaos run replays as a single ordered timeline
 (docs/OBSERVABILITY.md has the schema, docs/ROBUSTNESS.md the chaos
@@ -24,16 +24,31 @@ Export builds per-request lifecycle SPANS from the point records: a
 admit→prefill_done, ``decode`` per prefill_done→(finish|evict); an
 evicted request simply opens a new queued span, so a preempted
 lifecycle shows up as repeated queued/prefill/decode triples on one
-timeline row. Faults and scheduler phases ride along as instant/slice
-events on the scheduler row (tid 0).
+timeline row. Faults ride along as instant events on the scheduler row
+(tid 0).
+
+The same ring is the program's one SPAN recorder: ``span(name, ...)`` is
+a context manager whose record is the point-event tuple plus an end
+time, a span id and the id of the span that was open when it started
+(``serve.step`` > ``serve.prefill`` > ``serve.dispatch`` > ...; names in
+docs/OBSERVABILITY.md). Entering a span also enters a
+``jax.profiler.TraceAnnotation`` of the same name, so whenever a
+profiler trace is running the span sits in the ``.xplane.pb`` on the
+profiler's clock beside the device events, and in the ring on
+``perf_counter`` otherwise. A span's self time is its duration minus its
+children's (:func:`span_self_times`); the Chrome export draws spans as
+nested slices on the scheduler lane.
 """
 
 import json
 import time
 from typing import Any, Callable, Dict, List, Optional
 
-# record layout: (ts, etype, rid, step, slot, data-dict-or-None)
-_TS, _ETYPE, _RID, _STEP, _SLOT, _DATA = range(6)
+import jax
+
+# record layout: (ts, etype, rid, step, slot, data-dict-or-None); a span
+# record carries three more fields: (..., end_ts, span_id, parent_id)
+_TS, _ETYPE, _RID, _STEP, _SLOT, _DATA, _END, _SID, _PARENT = range(9)
 
 # lifecycle phases, in the order a healthy request traverses them
 SPAN_QUEUED = "queued"
@@ -41,9 +56,92 @@ SPAN_PREFILL = "prefill"
 SPAN_DECODE = "decode"
 
 
+def is_span(rec: tuple) -> bool:
+    return len(rec) > _END
+
+
+def span_self_times(records: List[tuple]) -> Dict[int, float]:
+    """span id -> self seconds (duration minus the durations of the spans
+    that name it as parent) for the span records in ``records``. A child
+    whose parent has left the ring takes nothing from anyone."""
+    selfs = {r[_SID]: r[_END] - r[_TS] for r in records if is_span(r)}
+    for r in records:
+        if is_span(r) and r[_PARENT] in selfs:
+            selfs[r[_PARENT]] -= r[_END] - r[_TS]
+    return selfs
+
+
+class _Span:
+    """One open span: reserves its ring slot on entry (records stay in
+    start order), fills it on exit. ``set(**counts)`` adds counts known
+    only at the end (tokens emitted, bytes pulled)."""
+
+    __slots__ = ("_tr", "_ann", "_idx", "name", "rid", "step", "slot",
+                 "counts", "sid", "parent", "t0", "t1")
+
+    def __init__(self, tracer, name, rid, step, slot, counts):
+        self._tr, self.name, self.rid = tracer, name, rid
+        self.step, self.slot, self.counts = step, slot, counts
+        self.t0 = self.t1 = 0.0
+
+    def set(self, **counts) -> None:
+        self.counts.update(counts)
+
+    @property
+    def dur(self) -> float:
+        return self.t1 - self.t0
+
+    def __enter__(self):
+        tr = self._tr
+        tr._last_sid += 1
+        self.sid = tr._last_sid
+        self.parent = tr._open[-1] if tr._open else 0
+        tr._open.append(self.sid)
+        self._idx = tr._n
+        tr._buf[tr._n % tr.capacity] = None
+        tr._n += 1
+        self._ann = jax.profiler.TraceAnnotation(self.name, **self.counts)
+        self._ann.__enter__()
+        self.t0 = tr._clock()
+        return self
+
+    def __exit__(self, *exc):
+        tr = self._tr
+        self.t1 = tr._clock()
+        self._ann.__exit__(*exc)
+        if tr._open and tr._open[-1] == self.sid:
+            tr._open.pop()
+        # unless the ring wrapped over the slot (or was reset) meanwhile
+        if tr._n - tr.capacity <= self._idx < tr._n:
+            tr._buf[self._idx % tr.capacity] = (
+                self.t0, self.name, self.rid, self.step, self.slot,
+                self.counts or None, self.t1, self.sid, self.parent)
+        return False
+
+
+class _NoopSpan:
+    """The off-mode span: one shared object, no clock, no annotation."""
+
+    __slots__ = ()
+    dur = t0 = t1 = 0.0
+
+    def set(self, **counts) -> None:
+        pass
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+NOOP_SPAN = _NoopSpan()
+
+
 class RequestTracer:
-    """Ring-buffered event recorder. ``event()`` is the only hot-path
-    entry point; everything else is export-time."""
+    """Ring-buffered recorder of point events (``event``) and spans
+    (``span``), the hot-path entry points; everything else is
+    export-time."""
 
     enabled = True
 
@@ -55,6 +153,8 @@ class RequestTracer:
         self._clock = clock
         self._buf: List[Optional[tuple]] = [None] * self.capacity
         self._n = 0          # total records ever written
+        self._last_sid = 0   # span ids start at 1; parent 0 = no parent
+        self._open: List[int] = []   # ids of the spans open now
 
     # -- recording (hot path) ------------------------------------------
     def event(self, etype: str, rid: Any = None, step: int = -1,
@@ -63,17 +163,32 @@ class RequestTracer:
             self._clock(), etype, rid, step, slot, data or None)
         self._n += 1
 
+    def span(self, name: str, rid: Any = None, step: int = -1,
+             slot: int = -1, **counts) -> _Span:
+        """Context manager: one span record in the ring and one
+        ``TraceAnnotation`` of the same name (``counts``: integers or
+        short strings known at the boundary)."""
+        return _Span(self, name, rid, step, slot, counts)
+
     # -- inspection ----------------------------------------------------
     @property
     def dropped(self) -> int:
         return max(0, self._n - self.capacity)
 
     def records(self) -> List[tuple]:
-        """Surviving records, oldest first."""
+        """Surviving records, oldest first (a span still open holds its
+        slot empty and is left out)."""
         if self._n <= self.capacity:
-            return [r for r in self._buf[:self._n]]
-        head = self._n % self.capacity
-        return self._buf[head:] + self._buf[:head]
+            recs = self._buf[:self._n]
+        else:
+            head = self._n % self.capacity
+            recs = self._buf[head:] + self._buf[:head]
+        return [r for r in recs if r is not None]
+
+    def spans(self, name: Optional[str] = None) -> List[tuple]:
+        """Closed span records, in start order."""
+        return [r for r in self.records() if is_span(r)
+                and (name is None or r[_ETYPE] == name)]
 
     def events_of(self, rid: Any) -> List[tuple]:
         return [r for r in self.records() if r[_RID] == rid]
@@ -81,15 +196,16 @@ class RequestTracer:
     def reset(self) -> None:
         self._buf = [None] * self.capacity
         self._n = 0
+        self._open = []
 
     # -- export --------------------------------------------------------
     def to_chrome_trace(self) -> Dict:
         """Chrome-trace/Perfetto JSON object. pid 1 is the serving
-        process; tid 0 the scheduler lane (step phases, faults,
-        watchdog); tids 1.. one lane per request in first-seen order.
-        Request lifecycles become ``ph: "X"`` complete events; faults
-        and terminal states become ``ph: "i"`` instants; sampled step
-        occupancy becomes a ``ph: "C"`` counter track."""
+        process; tid 0 the scheduler lane (spans, faults, watchdog);
+        tids 1.. one lane per request in first-seen order. Request
+        lifecycles and spans become ``ph: "X"`` complete events (a
+        span's args carry its counts, parent and self time); faults and
+        terminal states become ``ph: "i"`` instants."""
         recs = self.records()
         events: List[Dict] = [
             {"ph": "M", "pid": 1, "name": "process_name",
@@ -132,9 +248,25 @@ class RequestTracer:
                            "ts": us(start), "dur": us(ts) - us(start),
                            "args": a})
 
-        for ts, etype, rid, step, slot, data in recs:
+        selfs = span_self_times(recs)
+        for rec in recs:
+            ts, etype, rid, step, slot, data = rec[:_END]
             data = data or {}
-            if etype == "enqueue":
+            if is_span(rec):
+                a = {"step": step, "span_id": rec[_SID],
+                     "parent_id": rec[_PARENT],
+                     "self_us": round(selfs[rec[_SID]] * 1e6, 3)}
+                if rid is not None:
+                    a["rid"] = str(rid)
+                if slot >= 0:
+                    a["slot"] = slot
+                a.update(data)
+                events.append({"ph": "X", "pid": 1, "tid": 0,
+                               "cat": "span", "name": etype,
+                               "ts": us(ts),
+                               "dur": round((rec[_END] - ts) * 1e6, 3),
+                               "args": a})
+            elif etype == "enqueue":
                 close(rid, ts)           # defensive: rid reuse
                 open_span[rid] = (SPAN_QUEUED, ts, {})
             elif etype == "admit":
@@ -167,22 +299,6 @@ class RequestTracer:
                                "args": {"rid": str(rid), "step": step,
                                         "generated":
                                             data.get("generated", 0)}})
-            elif etype == "step_phase":
-                # consecutive slices on the scheduler lane, one per phase
-                start = ts - data.get("total_s", 0.0)
-                for ph in ("admission", "prefill", "decode", "bookkeeping"):
-                    d = data.get(f"{ph}_s")
-                    if d is None:
-                        continue
-                    events.append({"ph": "X", "pid": 1, "tid": 0,
-                                   "cat": "step", "name": ph,
-                                   "ts": us(start), "dur": round(d * 1e6, 3),
-                                   "args": {"step": step}})
-                    start += d
-                if "occupancy" in data:
-                    events.append({"ph": "C", "pid": 1, "name": "occupancy",
-                                   "ts": us(ts),
-                                   "args": {"slots": data["occupancy"]}})
             elif etype == "fault":
                 events.append({"ph": "i", "pid": 1, "tid": 0,
                                "cat": "fault",
@@ -205,7 +321,7 @@ class RequestTracer:
                                "cat": "scheduler", "name": etype,
                                "ts": us(ts), "s": "t", "args": a})
         # whatever is still open at export time renders as in-flight
-        last = recs[-1][_TS]
+        last = max(r[_END] if is_span(r) else r[_TS] for r in recs)
         for rid in list(open_span):
             close(rid, last, {"in_flight": True})
         return {"traceEvents": events, "displayTimeUnit": "ms",
@@ -230,10 +346,16 @@ class NoopTracer:
     def event(self, etype, rid=None, step=-1, slot=-1, **data) -> None:
         pass
 
+    def span(self, name, rid=None, step=-1, slot=-1, **counts) -> _NoopSpan:
+        return NOOP_SPAN
+
     def events_of(self, rid) -> List[tuple]:
         return []
 
     def records(self) -> List[tuple]:
+        return []
+
+    def spans(self, name=None) -> List[tuple]:
         return []
 
     def reset(self) -> None:

@@ -162,6 +162,27 @@ class CompileWatch:
         return False
 
 
+_process_compiles = [0]
+_process_listener = [False]
+
+
+def compile_count() -> int:
+    """Backend compilations in this process since the first call (which
+    registers one listener for good: jax has no public way to remove
+    one). A caller attributes compilations to a call by taking the
+    difference round it: jax compiles on the calling thread, inside the
+    call. Without ``jax.monitoring`` the count stays 0."""
+    if not _process_listener[0]:
+        _process_listener[0] = True
+        api = _monitoring_api()
+        if api is not None:
+            def _on_event(event, duration=None, **kw):
+                if is_compile_event(event):
+                    _process_compiles[0] += 1
+            api[0](_on_event)
+    return _process_compiles[0]
+
+
 def cache_size(jitted_fn) -> Optional[int]:
     """Number of compiled programs held by a jitted callable, or None
     when the jax build doesn't expose it.  Use to pin 'exactly N

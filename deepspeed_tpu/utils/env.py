@@ -64,7 +64,7 @@ def _mk(name, kind, default, help, **kw) -> Tuple[str, Flag]:
 # off-state is the behavioral bit-reference (docs/LINT.md DS013).
 FLAGS: Dict[str, Flag] = dict([
     _mk("DS_TELEMETRY", "bool", False,
-        "metrics/tracer/breakdown plane on the serving engine; off is "
+        "metrics/tracer/spans plane on the serving engine; off is "
         "the no-op bit-reference (docs/OBSERVABILITY.md)"),
     _mk("DS_PREFIX_CACHE", "bool", False,
         "shared-prefix KV cache with refcounted blocks + COW; off is "

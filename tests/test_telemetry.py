@@ -28,10 +28,12 @@ import pytest
 from deepspeed_tpu.inference.engine import InferenceEngine
 from deepspeed_tpu.inference.serving import ServeRequest, ServingEngine
 from deepspeed_tpu.models import gpt
-from deepspeed_tpu.telemetry import (Histogram, MetricsRegistry,
-                                     NoopTelemetry, RequestTracer,
-                                     StepBreakdown, Telemetry,
-                                     merge_registries, resolve_telemetry)
+from deepspeed_tpu.inference.serving import _STAT_FIELDS
+from deepspeed_tpu.telemetry import (NOOP_SPAN, Histogram, MetricsRegistry,
+                                     NoopTelemetry, NoopTracer,
+                                     RequestTracer, Telemetry,
+                                     merge_registries, resolve_telemetry,
+                                     span_self_times)
 from deepspeed_tpu.utils import faults as faults_lib
 from deepspeed_tpu.utils.faults import Fault
 from deepspeed_tpu.utils.monitor import Monitor
@@ -262,24 +264,97 @@ def test_tracer_builds_ordered_spans():
     assert spans[5][2]["state"] == "done"
 
 
-def test_step_breakdown_sampling():
-    reg = MetricsRegistry()
-    tr = RequestTracer(capacity=16)
-    synced = []
-    bd = StepBreakdown(reg, tr, sample_every=3)
-    assert bd.begin(0, sync=lambda: synced.append(1)) is True
-    bd.lap("admission")
-    bd.lap("prefill")
-    bd.lap("decode")
-    bd.finish(occupancy=2)
-    assert bd.begin(1) is False          # not a sampled step
-    bd.lap("admission")
-    bd.finish()
-    assert len(synced) == 5              # begin + 3 laps + bookkeeping
-    assert reg.histogram("serving_step_s").count == 1
-    assert reg.histogram("serving_step_decode_s").count == 1
-    phases = [r for r in tr.records() if r[1] == "step_phase"]
-    assert len(phases) == 1 and phases[0][5]["occupancy"] == 2
+def _ticking(capacity=64):
+    clock = iter(float(i) for i in range(10_000))
+    return RequestTracer(capacity=capacity, clock=lambda: next(clock))
+
+
+def test_spans_nest_with_parent_ids_rid_and_self_time():
+    tr = _ticking()
+    with tr.span("serve.step", step=3, queue=2) as step:
+        with tr.span("serve.prefill", rid="a", step=3, slot=1, n=8) as pf:
+            tr.event("prefill_chunk", rid="a", step=3, slot=1)
+            with tr.span("serve.dispatch", site="serving.prefill") as d:
+                pass
+            pf.set(tokens=8)            # a count known only at the end
+        with tr.span("serve.decode", step=3):
+            pass
+    recs = tr.records()
+    # start order, the point event between the spans that surround it
+    assert [r[1] for r in recs] == ["serve.step", "serve.prefill",
+                                    "prefill_chunk", "serve.dispatch",
+                                    "serve.decode"]
+    by = {r[1]: r for r in tr.spans()}
+    assert by["serve.step"][8] == 0                      # no parent
+    assert by["serve.prefill"][8] == by["serve.step"][7] == step.sid
+    assert by["serve.dispatch"][8] == pf.sid
+    assert by["serve.decode"][8] == step.sid
+    assert by["serve.prefill"][2] == "a" and by["serve.prefill"][4] == 1
+    assert by["serve.prefill"][5] == {"n": 8, "tokens": 8}
+    assert tr.events_of("a")[0][1] == "serve.prefill"
+    # children lie inside their parents; self = duration - children
+    for r in tr.spans():
+        parent = next((q for q in tr.spans() if q[7] == r[8]), None)
+        if parent is not None:
+            assert parent[0] <= r[0] and r[6] <= parent[6]
+    selfs = span_self_times(recs)
+    assert selfs[step.sid] == step.dur - pf.dur - (
+        by["serve.decode"][6] - by["serve.decode"][0])
+    assert selfs[pf.sid] == pf.dur - d.dur and selfs[d.sid] == d.dur
+    assert all(v >= 0 for v in selfs.values())
+
+
+@pytest.mark.parametrize("capacity,n_steps", [(4, 3), (8, 5), (64, 5)])
+def test_span_ring_wrap(capacity, n_steps):
+    """Spans and point events share the ring: a wrap drops the oldest of
+    either, a span whose slot was overwritten while it was open is
+    dropped whole, and an orphaned child takes no time from anyone."""
+    tr = _ticking(capacity)
+    for i in range(n_steps):
+        with tr.span("serve.step", step=i):
+            tr.event("tick", step=i)
+            with tr.span("serve.decode", step=i):
+                tr.event("tock", step=i)
+    total = 4 * n_steps
+    recs = tr.records()
+    assert tr.dropped == max(0, total - capacity)
+    assert len(recs) <= capacity
+    assert [r[0] for r in recs] == sorted(r[0] for r in recs)
+    kept = {r[7] for r in tr.spans()}
+    selfs = span_self_times(recs)
+    assert set(selfs) == kept and all(v >= 0 for v in selfs.values())
+    if total <= capacity:
+        assert len(tr.spans("serve.step")) == n_steps
+    # the export survives a wrapped ring and reports what fell out
+    assert tr.to_chrome_trace()["dropped_events"] == tr.dropped
+
+
+def test_chrome_export_renders_spans_with_self_time():
+    tr = _ticking()
+    with tr.span("serve.step", step=0):
+        with tr.span("serve.prefill", rid="r", step=0, slot=0, n=4):
+            pass
+    ev = [e for e in tr.to_chrome_trace()["traceEvents"]
+          if e.get("cat") == "span"]
+    assert [e["name"] for e in ev] == ["serve.step", "serve.prefill"]
+    step, pf = ev
+    assert step["ph"] == "X" and step["tid"] == 0
+    assert pf["args"]["parent_id"] == step["args"]["span_id"]
+    assert pf["args"]["rid"] == "r" and pf["args"]["n"] == 4
+    assert step["args"]["self_us"] == step["dur"] - pf["dur"]
+    assert step["ts"] <= pf["ts"] and \
+        pf["ts"] + pf["dur"] <= step["ts"] + step["dur"]
+
+
+def test_noop_span_is_one_shared_object_and_records_nothing():
+    tr = NoopTracer()
+    a = tr.span("serve.step", step=1, queue=3)
+    b = tr.span("serve.dispatch", rid="x")
+    assert a is b is NOOP_SPAN
+    with a as entered:
+        entered.set(tokens=3)
+    assert entered is NOOP_SPAN and entered.dur == 0.0
+    assert tr.records() == [] and tr.spans() == []
 
 
 def test_resolve_telemetry_env_and_flag(monkeypatch):
@@ -335,13 +410,80 @@ def test_serving_span_ordering_across_evict_requeue(eng):
         assert rid_spans[-1][1] == r.state
 
 
+STEP_CHILDREN = {"serve.expire", "serve.admit", "serve.prefill",
+                 "serve.decode", "serve.spill", "serve.bookkeep"}
+
+
+def test_step_spans_tile_the_step_and_tokens_match_off(eng):
+    """Every step's child spans lie inside it in order, self times are
+    never negative, a dispatch splits into enqueue and wait, request
+    spans carry their rid, the five step histograms see every step, and
+    the tokens are those of the telemetry-off run."""
+    prompts = prompts_of((11, 5, 9), seed=4)
+
+    def drive(telemetry):
+        srv = ServingEngine(eng, num_slots=2, block_size=4, num_blocks=24,
+                            prefill_chunk=8, telemetry=telemetry)
+        return srv, srv.run([ServeRequest(rid=f"r{i}", prompt=p.copy(),
+                                          max_new_tokens=5)
+                             for i, p in enumerate(prompts)])
+
+    _, out_off = drive(False)
+    tel = Telemetry()
+    srv, out_on = drive(tel)
+    for rid in out_off:
+        np.testing.assert_array_equal(out_on[rid], out_off[rid])
+    spans = tel.tracer.spans()
+    by_id = {r[7]: r for r in spans}
+    steps = [r for r in spans if r[1] == "serve.step"]
+    assert len(steps) == srv.stats["steps"]
+    selfs = span_self_times(spans)
+    assert all(v >= 0 for v in selfs.values())
+    for r in spans:
+        if r[8]:
+            parent = by_id[r[8]]
+            assert parent[0] <= r[0] <= r[6] <= parent[6]
+    for st in steps:
+        kids = [r for r in spans if r[8] == st[7]]
+        assert {k[1] for k in kids} <= STEP_CHILDREN
+        assert [k[1] for k in kids if k[1] != "serve.prefill"] == [
+            "serve.expire", "serve.admit", "serve.decode", "serve.spill",
+            "serve.bookkeep"]
+        # consecutive children do not overlap
+        assert all(a[6] <= b[0] for a, b in zip(kids, kids[1:]))
+    prefills = [r for r in spans if r[1] == "serve.prefill"]
+    assert len(prefills) == srv.stats["prefill_chunks"]
+    assert {r[2] for r in prefills} == {"r0", "r1", "r2"}
+    assert sum(r[5]["n"] for r in prefills) == sum(map(len, prompts))
+    dispatches = [r for r in spans if r[1] == "serve.dispatch"]
+    assert len(dispatches) == (srv.stats["prefill_chunks"]
+                               + srv.stats["decode_steps"])
+    for d in dispatches:
+        assert by_id[d[8]][1] in ("serve.prefill", "serve.decode")
+        assert [r[1] for r in spans if r[8] == d[7]] == [
+            "serve.dispatch.enqueue", "serve.dispatch.wait"]
+        assert d[5]["site"] in ("serving.prefill", "serving.decode")
+    emitted = sum(r[5]["tokens"] for r in spans if r[1] == "serve.emit")
+    assert emitted == sum(len(r.out) for r in srv.finished) == 15
+    assert all(r[5]["bytes"] > 0 for r in spans if r[1] == "serve.pull")
+    admitted = sum(r[5]["admitted"] for r in spans
+                   if r[1] == "serve.admit")
+    assert admitted == srv.stats["admitted"] == 3
+    for name in ("serving_step_s", "serving_step_admission_s",
+                 "serving_step_prefill_s", "serving_step_decode_s",
+                 "serving_step_bookkeeping_s"):
+        assert tel.registry.histogram(name).count == srv.stats["steps"]
+    assert not any(r[1] == "step_phase" for r in tel.tracer.records())
+
+
 def test_stats_view_read_only_and_registry_backed(eng):
     p, = prompts_of((6,), seed=3)
     srv = ServingEngine(eng, num_slots=2, block_size=4, num_blocks=24)
     srv.run([ServeRequest(rid="x", prompt=p, max_new_tokens=4)])
     # same keys and values as the old dict contract
     assert srv.stats["completed"] == 1 and srv.stats["admitted"] == 1
-    assert set(dict(srv.stats)) == {
+    assert set(dict(srv.stats)) == {k for k, _, _ in _STAT_FIELDS}
+    assert set(dict(srv.stats)) >= {
         "steps", "occupancy_sum", "peak_occupancy", "evictions",
         "admitted", "completed", "prefill_chunks", "decode_steps",
         "timeouts", "shed", "retries", "evict_capped", "watchdog_trips",
@@ -349,7 +491,10 @@ def test_stats_view_read_only_and_registry_backed(eng):
         "spec_steps", "spec_slot_steps", "spec_proposed",
         "spec_accepted", "spec_emitted", "spec_fallbacks",
         "sampled_tokens", "stop_hits", "spec_k_capped",
-        "horizon_fallbacks"}
+        "horizon_fallbacks", "adapter_hits", "adapter_loads",
+        "adapter_evictions", "adapter_load_errors", "host_blocks",
+        "host_bytes", "host_spills", "host_restores",
+        "host_restore_failures"}
     with pytest.raises(TypeError):
         srv.stats["steps"] = 99          # read-only view
     # the registry is the writable surface
@@ -487,6 +632,7 @@ def test_chaos_acceptance_trace_prometheus_parity_zero_recompiles(
         assert r["state"] == "done"
     assert [(f["site"], f["kind"], f["visit"]) for f in summary["faults"]] \
         == fired_on
-    # the sampled step breakdown made it into the export too
-    assert {"admission", "prefill", "decode", "bookkeeping"} \
-        <= set(summary["phase_us"])
+    # the spans made it into the export too, each with its self time
+    assert {"serve.step", "serve.admit", "serve.prefill", "serve.decode",
+            "serve.dispatch.enqueue", "serve.dispatch.wait",
+            "serve.bookkeep"} <= set(summary["span_self_us"])

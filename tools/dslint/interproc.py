@@ -353,8 +353,9 @@ class TelemetrySchemaDrift(InterprocRule):
                             f"unreadable telemetry schema: {e}")]
         metrics = set(schema.get("metrics", ()))
         events = set(schema.get("events", ()))
+        spans = set(schema.get("spans", ()))
         patterns = list(schema.get("metric_patterns", ()))
-        known = metrics | events
+        known = metrics | events | spans
         out: List[Finding] = []
 
         code_names: Set[str] = set()
@@ -362,7 +363,7 @@ class TelemetrySchemaDrift(InterprocRule):
         for reg in table.metric_regs:
             if self._TEST_PATHS.search(reg.path):
                 continue      # unit tests register throwaway names
-            target = events if reg.kind == "event" else metrics
+            target = {"event": events, "span": spans}.get(reg.kind, metrics)
             if reg.pattern:
                 code_patterns.add(reg.name)
                 if reg.name not in patterns:

@@ -18,7 +18,7 @@ need to see *across* files is collected here in one pass per module:
   ``resolve_flag("DS_...")`` calls, and the declared ``FLAGS`` table
   (name, kind, default) parsed from its AST literal;
 - telemetry registrations: ``<metrics>.counter/gauge/histogram(name)``
-  and ``<tracer>.event(name)`` calls, with f-string names resolved by
+  and ``<tracer>.event(name)`` / ``<tracer>.span(name)`` calls, with f-string names resolved by
   expanding module-level constant tables (the ``for key, ... in
   _STAT_FIELDS`` / ``for ph in PHASES`` idioms) and degraded to ``*``
   wildcard patterns when a piece stays dynamic;
@@ -505,11 +505,11 @@ class _ModuleCollector:
                              or any(r in _REGISTRY_RECV for r in recv)):
                     return func.attr
                 return None
-            if func.attr == "event":
+            if func.attr in ("event", "span"):
                 recv = _dotted(func.value)
                 if recv and ("tracer" in [r.lower() for r in recv]
                              or recv[-1].lower().endswith("tracer")):
-                    return "event"
+                    return func.attr
                 return None
             return None
         if isinstance(func, ast.Name):

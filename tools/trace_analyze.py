@@ -120,12 +120,13 @@ def _load_trace(path: str) -> dict:
 def analyze_serving_trace(path: str, quiet: bool = False) -> dict:
     """Summarize a serving-telemetry Chrome-trace export
     (``RequestTracer.to_chrome_trace``): per-request lifecycle span
-    sequences (queued/prefill/decode, terminal state), scheduler
-    step-phase totals, and the injected-fault timeline. Returns the
+    sequences (queued/prefill/decode, terminal state), the self time of
+    the program's spans by name (``serve.step`` and below: duration
+    minus children), and the injected-fault timeline. Returns the
     summary dict (tests assert on it); prints it unless ``quiet``."""
     trace = _load_trace(path)
     events = trace.get("traceEvents", [])
-    requests, phase_us, faults = {}, collections.Counter(), []
+    requests, span_self_us, faults = {}, collections.Counter(), []
     for e in events:
         ph, cat = e.get("ph"), e.get("cat")
         if ph == "X" and cat == "request":
@@ -137,8 +138,8 @@ def analyze_serving_trace(path: str, quiet: bool = False) -> dict:
             state = e.get("args", {}).get("state")
             if state:
                 requests[rid]["state"] = state
-        elif ph == "X" and cat == "step":
-            phase_us[e["name"]] += e.get("dur", 0.0)
+        elif ph == "X" and cat == "span":
+            span_self_us[e["name"]] += e.get("args", {}).get("self_us", 0.0)
         elif ph == "i" and cat == "fault":
             faults.append(dict(e.get("args", {}), ts=e.get("ts")))
     for r in requests.values():
@@ -148,16 +149,16 @@ def analyze_serving_trace(path: str, quiet: bool = False) -> dict:
         "n_events": len(events),
         "dropped_events": trace.get("dropped_events", 0),
         "requests": requests,
-        "phase_us": {k: round(v, 1) for k, v in phase_us.items()},
+        "span_self_us": {k: round(v, 1) for k, v in span_self_us.items()},
         "faults": faults,
     }
     if not quiet:
         print(json.dumps({"trace": path, "n_events": len(events),
                           "requests": len(requests),
                           "faults": len(faults)}))
-        print("\n-- step phases (sampled) --")
-        total = sum(phase_us.values())
-        for name, us in phase_us.most_common():
+        print("\n-- span self time --")
+        total = sum(span_self_us.values())
+        for name, us in span_self_us.most_common():
             print(f"{us/1e3:10.2f} ms  {100*us/max(total,1e-9):5.1f}%  {name}")
         print("\n-- requests --")
         for rid, r in requests.items():
