@@ -279,10 +279,12 @@ def serve_phase(cfg, params):
         "no paged_decode Mosaic call in the compiled decode-slots program"
 
     # the kernel against its reference at the served shapes
-    L, N, bs, Hkv, Dh = srv.cache.k.shape
+    L, N, bs, row = srv.cache.k.shape
+    Hkv, Dh = cfg.kv_heads, cfg.head_dim
+    assert row == Hkv * Dh, (srv.cache.k.shape, Hkv, Dh)
     nb = srv.cache.tables.shape[1]
     r = np.random.default_rng(2)
-    k, v = (jnp.asarray(r.standard_normal((N, bs, Hkv, Dh)), jnp.bfloat16)
+    k, v = (jnp.asarray(r.standard_normal((N, bs, row)), jnp.bfloat16)
             for _ in range(2))
     q = jnp.asarray(r.standard_normal((srv.num_slots, Hkv, 1, Dh)),
                     jnp.bfloat16)

@@ -34,6 +34,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from deepspeed_tpu.inference import sampling
 from deepspeed_tpu.models import gpt as gpt_lib
 from deepspeed_tpu.ops import quantizer
+from deepspeed_tpu.ops.attention.paged import gather_pool_blocks
 from deepspeed_tpu.models.gpt import (GPTConfig, _dense,
                                       _norm, _qkv_split_rotary)
 from deepspeed_tpu.parallel import mesh as mesh_lib
@@ -260,47 +261,94 @@ def _named(fn, name: str):
     return call
 
 
-def _gather_blocks(pool, tables):
-    """Gather a block pool [N, block, Hkv, Dh] through block tables
-    [B, NB] into the virtual contiguous cache [B, NB*block, Hkv, Dh].
-    Cache position s of row b lives at pool[tables[b, s // block],
-    s % block] — the PagedAttention indirection, done as one XLA gather
-    so the decode einsums below are unchanged from the static path."""
-    g = pool[tables]
-    B, NB, bs = g.shape[0], g.shape[1], g.shape[2]
-    return g.reshape(B, NB * bs, g.shape[3], g.shape[4])
+def _heads(rows, n_kv: int):
+    """``[..., Hkv*Dh]`` rows of the paged pool -> ``[..., Hkv, Dh]``:
+    what was gathered out of the pool is unfolded, never the pool."""
+    return rows.reshape(rows.shape[:-1] + (n_kv, rows.shape[-1] // n_kv))
 
 
-def _block_decode_paged(x, k_pool, v_pool, tables, lengths, active, p,
-                        cfg: GPTConfig, impl: str = "gather",
-                        k_scale=None, v_scale=None, lora=None):
+def _rows(heads):
+    """``[..., Hkv, Dh]`` -> the pool's ``[..., Hkv*Dh]`` rows."""
+    return heads.reshape(heads.shape[:-2] + (-1,))
+
+
+def _scan_layers(block, x, params, pools, lora_ops=None):
+    """Run the layers of a paged program: the ONE layer loop of every
+    prefill, decode, verify and horizon program.
+
+    ``pools`` is the paged cache's state, each ``[L, N, ...]`` stacked
+    over the layers: K and V ``[L, N, block, Hkv*Dh]``, then, for int8
+    pools, their scales ``[L, N, Hkv]``. They ride in the scan's CARRY
+    viewed as ``[L*N, ...]`` (a bitcast: the layout in HBM is row-major
+    already) and layer ``l`` addresses its own blocks at ``base = l*N``:
+    ``tables + base`` to read, ``blk + base`` to write, so the trash
+    block of layer ``l`` is block ``l*N``. The carried buffers are the
+    donated entry parameters themselves and every write is a scatter in
+    place. Passing the pools as the scan's xs and ys instead sliced
+    each layer's pool out, re-laid it for the body, laid it back and
+    wrote it into a stacked output: every byte of the pool moved four
+    times per dispatch (PERF.md, PR 25).
+
+    ``block(x, pools, layer_p, base, lora) -> (y, pools)``; the xs are
+    the stacked block parameters, ``base`` per layer and, with
+    ``lora_ops = (a_pool, b_pool, ablocks)``, the adapter pools, whose
+    per-slot rank blocks are gathered per layer for gpt._dense's hook.
+    Returns ``(x, pools)`` with the pools back in their stacked shapes.
+    """
+    L, N = pools[0].shape[:2]
+    flat = tuple(p.reshape((L * N,) + p.shape[2:]) for p in pools)
+    xs = (params["block"], jnp.arange(L, dtype=jnp.int32) * N)
+    if lora_ops is not None:
+        xs = xs + (lora_ops[0], lora_ops[1])
+
+    def body(carry, layer):
+        x, flat = carry
+        lora = None
+        if lora_ops is not None:
+            lora = {t: (layer[2][t][lora_ops[2]], layer[3][t][lora_ops[2]])
+                    for t in layer[2]}
+        y, flat = block(x, flat, layer[0], layer[1], lora)
+        return (y, flat), None
+
+    (x, flat), _ = jax.lax.scan(body, (x, flat), xs)
+    return x, tuple(f.reshape(p.shape) for f, p in zip(flat, pools))
+
+
+def _block_decode_paged(x, pools, tables, lengths, active, p,
+                        cfg: GPTConfig, impl: str = "gather", lora=None,
+                        base=0):
     """One block for ONE new token per slot, K/V addressed through block
     tables — the paged generalization of _block_decode. x: [B, 1, D];
-    pools [N, block, Hkv, Dh]; tables [B, NB]; lengths [B] per-slot
-    cache positions (each slot decodes at its OWN position — the
+    ``pools`` = (k_pool, v_pool) of [N', block, Hkv*Dh] — ALL layers'
+    blocks, this layer's starting at ``base`` (_scan_layers) — tables
+    [B, NB] of block ids within a layer; lengths [B] per-slot cache
+    positions (each slot decodes at its OWN position — the
     continuous-batching contract); active [B] bool (inactive slots'
-    writes land in trash block 0 and their logits are ignored).
+    writes land in the layer's trash block, block ``base``, and their
+    logits are ignored).
 
-    impl="gather" materializes the virtual cache with _gather_blocks
-    (the bit-reference, portable everywhere); impl="pallas" attends
-    THROUGH the table with the flash-decode kernel (ops/attention/
+    impl="gather" materializes the virtual cache with
+    gather_pool_blocks (the bit-reference, portable everywhere);
+    impl="pallas" attends THROUGH the table with the flash-decode kernel (ops/attention/
     paged.py) — one pool-block DMA per occupied block, no dense copy.
 
-    With ``k_scale``/``v_scale`` (``[N, Hkv]`` fp32) the pools are int8:
-    the write becomes read-modify-requantize of each slot's current
-    block (dequantize, insert the token, zero stale lanes, requantize —
-    ops/quantizer KV helpers), the scales update alongside, and the
-    returns grow to a 5-tuple. ``k_scale=None`` (the default) traces the
-    exact pre-quant program — the bit-reference path is untouched.
+    With ``pools`` = (k_pool, v_pool, k_scale, v_scale) (scales
+    ``[N', Hkv]`` fp32) the pools are int8: the write becomes
+    read-modify-requantize of each slot's current block (dequantize,
+    insert the token, zero stale lanes, requantize — ops/quantizer KV
+    helpers) and the scales update alongside. Two pools trace the exact
+    pre-quant program — the bit-reference path is untouched.
 
     ``lora`` (multi-tenant adapter serving, inference/adapters.py) is a
     dict target -> per-slot gathered rank-block factors handed through
     to :func:`~deepspeed_tpu.models.gpt._dense`; ``lora=None`` (the
-    default) traces the exact base-only program."""
+    default) traces the exact base-only program. Returns (y, pools)."""
     B, _, D = x.shape
     H, Dh = cfg.n_heads, cfg.head_dim
     Hkv = cfg.kv_heads
     group = H // Hkv
+    k_pool, v_pool = pools[:2]
+    k_scale, v_scale = pools[2:] if len(pools) == 4 else (None, None)
     bs = k_pool.shape[1]
     NB = tables.shape[1]
     lr = (lambda t: None) if lora is None else lora.get
@@ -327,14 +375,16 @@ def _block_decode_paged(x, k_pool, v_pool, tables, lengths, active, p,
         in_cap = lengths < NB * bs
         blk = jnp.take_along_axis(
             tables, jnp.clip(lengths // bs, 0, NB - 1)[:, None], axis=1)[:, 0]
-        blk = jnp.where(jnp.logical_and(active, in_cap), blk, 0)
+        blk = jnp.where(jnp.logical_and(active, in_cap), blk, 0) + base
         off = lengths % bs
         if k_scale is None:
-            k_pool = k_pool.at[blk, off].set(k)
-            v_pool = v_pool.at[blk, off].set(v)
+            k_pool = k_pool.at[blk, off].set(_rows(k))
+            v_pool = v_pool.at[blk, off].set(_rows(v))
         else:
-            kb = quantizer.kv_dequantize_blocks(k_pool[blk], k_scale[blk])
-            vb = quantizer.kv_dequantize_blocks(v_pool[blk], v_scale[blk])
+            kb = quantizer.kv_dequantize_blocks(_heads(k_pool[blk], Hkv),
+                                                k_scale[blk])
+            vb = quantizer.kv_dequantize_blocks(_heads(v_pool[blk], Hkv),
+                                                v_scale[blk])
             rows = jnp.arange(B)
             kb = kb.at[rows, off].set(k.astype(jnp.float32))
             vb = vb.at[rows, off].set(v.astype(jnp.float32))
@@ -342,32 +392,26 @@ def _block_decode_paged(x, k_pool, v_pool, tables, lengths, active, p,
             live = jnp.arange(bs)[None, :] <= off[:, None]
             kq, ksn = quantizer.kv_requantize_blocks(kb, live)
             vq, vsn = quantizer.kv_requantize_blocks(vb, live)
-            k_pool = k_pool.at[blk].set(kq)
-            v_pool = v_pool.at[blk].set(vq)
+            k_pool = k_pool.at[blk].set(_rows(kq))
+            v_pool = v_pool.at[blk].set(_rows(vq))
             k_scale = k_scale.at[blk].set(ksn)
             v_scale = v_scale.at[blk].set(vsn)
 
     scale = cfg.attn_scale if cfg.attn_scale is not None \
         else 1.0 / np.sqrt(Dh)
+    tabs = tables + base                 # this layer's blocks
     if impl == "pallas":
         from deepspeed_tpu.ops.attention.paged import paged_decode_attention
         with jax.named_scope("paged_attn"):
             attn = paged_decode_attention(
-                q, k_pool, v_pool, tables, lengths, scale=float(scale),
+                q, k_pool, v_pool, tabs, lengths, scale=float(scale),
                 window=cfg.attn_window, k_scale=k_scale,
                 v_scale=v_scale).reshape(B, 1, D)
     else:
         with jax.named_scope("kv_gather"):
-            if k_scale is None:
-                kc = _gather_blocks(k_pool, tables)  # [B, NB*bs, Hkv, Dh]
-                vc = _gather_blocks(v_pool, tables)
-            else:
-                kc = quantizer.kv_dequantize_blocks(
-                    k_pool[tables], k_scale[tables],
-                    dtype=x.dtype).reshape(B, NB * bs, Hkv, Dh)
-                vc = quantizer.kv_dequantize_blocks(
-                    v_pool[tables], v_scale[tables],
-                    dtype=x.dtype).reshape(B, NB * bs, Hkv, Dh)
+            # [B, NB*bs, Hkv, Dh]
+            kc = gather_pool_blocks(k_pool, tabs, Hkv, k_scale, x.dtype)
+            vc = gather_pool_blocks(v_pool, tabs, Hkv, v_scale, x.dtype)
         with jax.named_scope("paged_attn"):
             scores = jnp.einsum("bkgd,bskd->bkgs", q, kc).astype(jnp.float32)
             scores *= scale
@@ -390,13 +434,13 @@ def _block_decode_paged(x, k_pool, v_pool, tables, lengths, active, p,
             h = _norm(x, p["ln2"], cfg)
             y = x + _ffn(h, p, cfg, lora=lora)
     if k_scale is None:
-        return y, k_pool, v_pool
-    return y, k_pool, v_pool, k_scale, v_scale
+        return y, (k_pool, v_pool)
+    return y, (k_pool, v_pool, k_scale, v_scale)
 
 
-def _block_verify_paged(x, k_pool, v_pool, tables, lengths, active, p,
-                        cfg: GPTConfig, impl: str = "gather",
-                        k_scale=None, v_scale=None, lora=None):
+def _block_verify_paged(x, pools, tables, lengths, active, p,
+                        cfg: GPTConfig, impl: str = "gather", lora=None,
+                        base=0):
     """One block for a G-token SPECULATIVE CHUNK per slot, K/V addressed
     through block tables — the q_len>1 generalization of
     _block_decode_paged for draft/verify serving. x: [B, G, D]; chunk
@@ -412,15 +456,17 @@ def _block_verify_paged(x, k_pool, v_pool, tables, lengths, active, p,
     caps acceptance at the allocated capacity so logits from those
     positions are never used.
 
-    With ``k_scale``/``v_scale`` the pools are int8 and the write is a
+    With four ``pools`` (int8 + scales) the write is a
     read-modify-requantize of the W consecutive blocks the G-token chunk
-    can straddle (W = 1 + ceil((G-1)/block)); returns grow to a 5-tuple.
-    ``k_scale=None`` traces the exact pre-quant program; ``lora=None``
-    the exact base-only program (see _block_decode_paged)."""
+    can straddle (W = 1 + ceil((G-1)/block)). Two pools trace the exact
+    pre-quant program; ``lora=None`` the exact base-only program;
+    ``pools`` / ``base`` and the return as in _block_decode_paged."""
     B, G, D = x.shape
     H, Dh = cfg.n_heads, cfg.head_dim
     Hkv = cfg.kv_heads
     group = H // Hkv
+    k_pool, v_pool = pools[:2]
+    k_scale, v_scale = pools[2:] if len(pools) == 4 else (None, None)
     bs = k_pool.shape[1]
     NB = tables.shape[1]
     lr = (lambda t: None) if lora is None else lora.get
@@ -432,16 +478,17 @@ def _block_verify_paged(x, k_pool, v_pool, tables, lengths, active, p,
     qg = q.reshape(B, G, Hkv, group, Dh)
 
     # scatter the chunk's K/V through the block table; out-of-capacity
-    # or inactive lanes land in trash block 0 (same belt-and-suspender
-    # as the one-token decode scatter)
+    # or inactive lanes land in the layer's trash block (same
+    # belt-and-suspender as the one-token decode scatter)
     in_cap = pos < NB * bs
     if k_scale is None:
         blk = jnp.take_along_axis(tables, jnp.clip(pos // bs, 0, NB - 1),
                                   axis=1)                        # [B, G]
-        blk = jnp.where(jnp.logical_and(active[:, None], in_cap), blk, 0)
+        blk = jnp.where(jnp.logical_and(active[:, None], in_cap), blk,
+                        0) + base
         off = pos % bs
-        k_pool = k_pool.at[blk, off].set(k)
-        v_pool = v_pool.at[blk, off].set(v)
+        k_pool = k_pool.at[blk, off].set(_rows(k))
+        v_pool = v_pool.at[blk, off].set(_rows(v))
     else:
         # read-modify-requantize the W consecutive table entries the
         # chunk can touch, starting at the block holding position
@@ -451,8 +498,10 @@ def _block_verify_paged(x, k_pool, v_pool, tables, lengths, active, p,
         wj = j0[:, None] + jnp.arange(W, dtype=jnp.int32)[None]  # [B, W]
         wjc = jnp.clip(wj, 0, NB - 1)
         blkw = jnp.take_along_axis(tables, wjc, axis=1)          # [B, W]
-        kb = quantizer.kv_dequantize_blocks(k_pool[blkw], k_scale[blkw])
-        vb = quantizer.kv_dequantize_blocks(v_pool[blkw], v_scale[blkw])
+        kb = quantizer.kv_dequantize_blocks(
+            _heads(k_pool[blkw + base], Hkv), k_scale[blkw + base])
+        vb = quantizer.kv_dequantize_blocks(
+            _heads(v_pool[blkw + base], Hkv), v_scale[blkw + base])
         # chunk token i of slot b lands at window-flat lane
         # (pos//bs - j0)*bs + pos%bs; masked lanes drop out of bounds
         tgt = (pos // bs - j0[:, None]) * bs + pos % bs          # [B, G]
@@ -476,31 +525,25 @@ def _block_verify_paged(x, k_pool, v_pool, tables, lengths, active, p,
         # slots entirely) route to the trash block
         jhi = jnp.minimum((lengths + G - 1) // bs, NB - 1)
         touched = jnp.logical_and(wj <= jhi[:, None], active[:, None])
-        blkw = jnp.where(touched, blkw, 0)
-        k_pool = k_pool.at[blkw].set(kq)
-        v_pool = v_pool.at[blkw].set(vq)
+        blkw = jnp.where(touched, blkw, 0) + base
+        k_pool = k_pool.at[blkw].set(_rows(kq))
+        v_pool = v_pool.at[blkw].set(_rows(vq))
         k_scale = k_scale.at[blkw].set(ksn)
         v_scale = v_scale.at[blkw].set(vsn)
 
     scale = cfg.attn_scale if cfg.attn_scale is not None \
         else 1.0 / np.sqrt(Dh)
+    tabs = tables + base                 # this layer's blocks
     if impl == "pallas":
         from deepspeed_tpu.ops.attention.paged import paged_verify_attention
         attn = paged_verify_attention(
-            qg, k_pool, v_pool, tables, lengths, scale=float(scale),
+            qg, k_pool, v_pool, tabs, lengths, scale=float(scale),
             window=cfg.attn_window, k_scale=k_scale,
             v_scale=v_scale).reshape(B, G, D)
     else:
-        if k_scale is None:
-            kc = _gather_blocks(k_pool, tables)  # [B, NB*bs, Hkv, Dh]
-            vc = _gather_blocks(v_pool, tables)
-        else:
-            kc = quantizer.kv_dequantize_blocks(
-                k_pool[tables], k_scale[tables],
-                dtype=x.dtype).reshape(B, NB * bs, Hkv, Dh)
-            vc = quantizer.kv_dequantize_blocks(
-                v_pool[tables], v_scale[tables],
-                dtype=x.dtype).reshape(B, NB * bs, Hkv, Dh)
+        # [B, NB*bs, Hkv, Dh]
+        kc = gather_pool_blocks(k_pool, tabs, Hkv, k_scale, x.dtype)
+        vc = gather_pool_blocks(v_pool, tabs, Hkv, v_scale, x.dtype)
         scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, kc).astype(jnp.float32)
         scores *= scale
         idx = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, 1, NB * bs), 4)
@@ -518,13 +561,12 @@ def _block_verify_paged(x, k_pool, v_pool, tables, lengths, active, p,
         h = _norm(x, p["ln2"], cfg)
         y = x + _ffn(h, p, cfg, lora=lora)
     if k_scale is None:
-        return y, k_pool, v_pool
-    return y, k_pool, v_pool, k_scale, v_scale
+        return y, (k_pool, v_pool)
+    return y, (k_pool, v_pool, k_scale, v_scale)
 
 
-def _block_prefill_paged(x, k_pool, v_pool, table_row, positions, n_valid,
-                         p, cfg: GPTConfig, k_scale=None, v_scale=None,
-                         lora=None):
+def _block_prefill_paged(x, pools, table_row, positions, n_valid, p,
+                         cfg: GPTConfig, lora=None, base=0):
     """Forward one block over a PROMPT CHUNK for one slot, writing the
     chunk's K/V through the slot's block table and attending over the
     slot's full cache so far (history from earlier chunks + this chunk)
@@ -534,19 +576,21 @@ def _block_prefill_paged(x, k_pool, v_pool, table_row, positions, n_valid,
     chunk is padded to a fixed width so ONE compiled program serves
     every chunk).
 
-    With ``k_scale``/``v_scale`` the pools are int8: the slot's whole
-    virtual row (gathered for attention anyway) is dequantized, the
-    chunk inserted, and ONLY the chunk-touched blocks requantized —
-    untouched blocks (including shared prefix blocks mapped read-only)
-    are written back byte-identical, so sharing semantics are
-    preserved. Returns grow to a 5-tuple; ``k_scale=None`` traces the
-    exact pre-quant program; ``lora=None`` the exact base-only program
-    (see _block_decode_paged; here the gathered factors carry the
-    prefill row's B=1 leading dim)."""
+    With four ``pools`` (int8 + scales) the slot's whole virtual row
+    (gathered for attention anyway) is dequantized, the chunk inserted,
+    and ONLY the chunk-touched blocks requantized — untouched blocks
+    (including shared prefix blocks mapped read-only) are written back
+    byte-identical, so sharing semantics are preserved. Two pools trace
+    the exact pre-quant program; ``lora=None`` the exact base-only
+    program (here the gathered factors carry the prefill row's B=1
+    leading dim); ``pools`` / ``base`` and the return as in
+    _block_decode_paged."""
     B, C, D = x.shape
     H, Dh = cfg.n_heads, cfg.head_dim
     Hkv = cfg.kv_heads
     group = H // Hkv
+    k_pool, v_pool = pools[:2]
+    k_scale, v_scale = pools[2:] if len(pools) == 4 else (None, None)
     bs = k_pool.shape[1]
     NB = table_row.shape[0]
     lr = (lambda t: None) if lora is None else lora.get
@@ -557,23 +601,24 @@ def _block_prefill_paged(x, k_pool, v_pool, table_row, positions, n_valid,
         q, k, v = gpt_lib._qkv_split_rotary(qkv, cfg, positions[None], B, C)
 
     valid = jnp.arange(C) < n_valid
+    trow = table_row + base              # this layer's blocks
     if k_scale is None:
         with jax.named_scope("kv_write"):
             blk = table_row[jnp.clip(positions // bs, 0, NB - 1)]
-            blk = jnp.where(valid, blk, 0)       # padded lanes -> trash block
+            blk = jnp.where(valid, blk, 0) + base   # padded lanes -> trash
             off = positions % bs
-            k_pool = k_pool.at[blk, off].set(k[0])
-            v_pool = v_pool.at[blk, off].set(v[0])
+            k_pool = k_pool.at[blk, off].set(_rows(k[0]))
+            v_pool = v_pool.at[blk, off].set(_rows(v[0]))
 
         with jax.named_scope("kv_gather"):
-            kc = k_pool[table_row].reshape(NB * bs, Hkv, Dh)
-            vc = v_pool[table_row].reshape(NB * bs, Hkv, Dh)
+            kc = _heads(k_pool[trow], Hkv).reshape(NB * bs, Hkv, Dh)
+            vc = _heads(v_pool[trow], Hkv).reshape(NB * bs, Hkv, Dh)
     else:
         with jax.named_scope("kv_write"):
-            kb = quantizer.kv_dequantize_blocks(k_pool[table_row],
-                                                k_scale[table_row])
-            vb = quantizer.kv_dequantize_blocks(v_pool[table_row],
-                                                v_scale[table_row])
+            kq0 = _heads(k_pool[trow], Hkv)
+            vq0 = _heads(v_pool[trow], Hkv)
+            kb = quantizer.kv_dequantize_blocks(kq0, k_scale[trow])
+            vb = quantizer.kv_dequantize_blocks(vq0, v_scale[trow])
             tgt = jnp.where(jnp.logical_and(valid, positions < NB * bs),
                             positions, NB * bs)  # padded lanes drop
             kb = kb.reshape(NB * bs, Hkv, Dh).at[tgt].set(
@@ -593,16 +638,14 @@ def _block_prefill_paged(x, k_pool, v_pool, table_row, positions, n_valid,
             j0 = start // bs
             j1 = jnp.maximum(start + n_valid - 1, start) // bs
             touched = jnp.logical_and(j >= j0, j <= j1)
-            kq = jnp.where(touched[:, None, None, None], kq,
-                           k_pool[table_row])
-            vq = jnp.where(touched[:, None, None, None], vq,
-                           v_pool[table_row])
-            ksn = jnp.where(touched[:, None], ksn, k_scale[table_row])
-            vsn = jnp.where(touched[:, None], vsn, v_scale[table_row])
-            k_pool = k_pool.at[table_row].set(kq)
-            v_pool = v_pool.at[table_row].set(vq)
-            k_scale = k_scale.at[table_row].set(ksn)
-            v_scale = v_scale.at[table_row].set(vsn)
+            kq = jnp.where(touched[:, None, None, None], kq, kq0)
+            vq = jnp.where(touched[:, None, None, None], vq, vq0)
+            ksn = jnp.where(touched[:, None], ksn, k_scale[trow])
+            vsn = jnp.where(touched[:, None], vsn, v_scale[trow])
+            k_pool = k_pool.at[trow].set(_rows(kq))
+            v_pool = v_pool.at[trow].set(_rows(vq))
+            k_scale = k_scale.at[trow].set(ksn)
+            v_scale = v_scale.at[trow].set(vsn)
         with jax.named_scope("kv_gather"):
             # attend over exactly what the pool now holds
             kc = quantizer.kv_dequantize_blocks(
@@ -631,8 +674,8 @@ def _block_prefill_paged(x, k_pool, v_pool, table_row, positions, n_valid,
             h = _norm(x, p["ln2"], cfg)
             y = x + _ffn(h, p, cfg, lora=lora)
     if k_scale is None:
-        return y, k_pool, v_pool
-    return y, k_pool, v_pool, k_scale, v_scale
+        return y, (k_pool, v_pool)
+    return y, (k_pool, v_pool, k_scale, v_scale)
 
 
 class InferenceEngine:
@@ -948,6 +991,83 @@ class InferenceEngine:
             out["mask"] = cache_mask
         return logits, out
 
+    def _prefill_slot_core(self, params, pools, table_row, tokens, start,
+                           n_valid, lane, lora_ops=None):
+        """Prefill ONE prompt chunk into one serving slot's paged cache:
+        the body of all four prefill twins. ``pools``: (k_pool, v_pool)
+        or, int8, (k_pool, v_pool, k_scale, v_scale), carried through
+        the layers by _scan_layers; ``lane``: the slot's sampling lane;
+        ``lora_ops``: (a_pool, b_pool, the slot's adapter-table row as
+        [1, NBa])."""
+        cfg = self.cfg
+        C = tokens.shape[0]
+        positions = start + jnp.arange(C, dtype=jnp.int32)
+        with jax.named_scope("embed"):
+            x = params["wte"]["embedding"][tokens][None]
+            if cfg.use_wpe:
+                safe = jnp.clip(positions, 0, self.max_seq_len - 1)
+                x = x + params["wpe"]["embedding"][safe][None]
+
+        def block(x, pools, layer_p, base, lora):
+            return _block_prefill_paged(x, pools, table_row, positions,
+                                        n_valid, layer_p, cfg, lora=lora,
+                                        base=base)
+
+        x, pools = _scan_layers(block, x, params, pools, lora_ops)
+        last = jnp.clip(n_valid - 1, 0, C - 1)
+        x_last = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
+        logits = self._logits(params, x_last)
+        key, gen_count, temp, top_k, top_p, rep_pen, seen_row = lane
+        tok, lp = sampling.sample_tokens(
+            logits[:, -1], key.reshape(1, 2), gen_count.reshape(1),
+            temp.reshape(1), top_k.reshape(1), top_p.reshape(1),
+            rep_pen.reshape(1), seen_row.reshape(1, -1))
+        return (logits, tok, lp) + pools
+
+    def _decode_slots_core(self, params, pools, tables, lengths, tokens,
+                           active, impl, lanes, lora_ops=None):
+        """One decode step for EVERY serving slot at once: the body of
+        all four decode twins (``pools`` / ``lora_ops`` as in
+        _prefill_slot_core, with the per-slot adapter-table rows
+        [B, NBa]; ``lanes``: the slot-indexed sampling arrays)."""
+        cfg = self.cfg
+        with jax.named_scope("embed"):
+            x = params["wte"]["embedding"][tokens[:, None]]
+            if cfg.use_wpe:
+                safe = jnp.clip(lengths, 0, self.max_seq_len - 1)
+                x = x + params["wpe"]["embedding"][safe][:, None]
+
+        def block(x, pools, layer_p, base, lora):
+            return _block_decode_paged(x, pools, tables, lengths, active,
+                                       layer_p, cfg, impl=impl, lora=lora,
+                                       base=base)
+
+        x, pools = _scan_layers(block, x, params, pools, lora_ops)
+        logits = self._logits(params, x)
+        toks, lps = sampling.sample_tokens(logits[:, -1], *lanes)
+        return (logits, toks, lps) + pools
+
+    def _verify_slots_core(self, params, pools, tables, lengths, tokens,
+                           active, impl, lora_ops=None):
+        """One speculative VERIFY step for every serving slot at once:
+        the body of all four verify twins (``pools`` / ``lora_ops`` as
+        in _decode_slots_core)."""
+        cfg = self.cfg
+        B, G = tokens.shape
+        x = params["wte"]["embedding"][tokens]
+        if cfg.use_wpe:
+            pos = lengths[:, None] + jnp.arange(G, dtype=jnp.int32)[None]
+            safe = jnp.clip(pos, 0, self.max_seq_len - 1)
+            x = x + params["wpe"]["embedding"][safe]
+
+        def block(x, pools, layer_p, base, lora):
+            return _block_verify_paged(x, pools, tables, lengths, active,
+                                       layer_p, cfg, impl=impl, lora=lora,
+                                       base=base)
+
+        x, pools = _scan_layers(block, x, params, pools, lora_ops)
+        return (self._logits(params, x),) + pools
+
     def _prefill_slot_fn(self, params, k_pool, v_pool, table_row, tokens,
                          start, n_valid, key, gen_count, temp, top_k,
                          top_p, rep_pen, seen_row):
@@ -964,32 +1084,9 @@ class InferenceEngine:
         meaningful once the final chunk lands. Returns the last-valid-
         position logits, the sampled/greedy token [1], its logprob [1],
         and the updated (donated) pools."""
-        cfg = self.cfg
-        C = tokens.shape[0]
-        positions = start + jnp.arange(C, dtype=jnp.int32)
-        with jax.named_scope("embed"):
-            x = params["wte"]["embedding"][tokens][None]
-            if cfg.use_wpe:
-                safe = jnp.clip(positions, 0, self.max_seq_len - 1)
-                x = x + params["wpe"]["embedding"][safe][None]
-
-        def body(x, layer):
-            layer_p, kp, vp = layer
-            y, kp, vp = _block_prefill_paged(x, kp, vp, table_row,
-                                             positions, n_valid, layer_p,
-                                             cfg)
-            return y, (kp, vp)
-
-        x, (ks, vs) = jax.lax.scan(body, x,
-                                   (params["block"], k_pool, v_pool))
-        last = jnp.clip(n_valid - 1, 0, C - 1)
-        x_last = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
-        logits = self._logits(params, x_last)
-        tok, lp = sampling.sample_tokens(
-            logits[:, -1], key.reshape(1, 2), gen_count.reshape(1),
-            temp.reshape(1), top_k.reshape(1), top_p.reshape(1),
-            rep_pen.reshape(1), seen_row.reshape(1, -1))
-        return logits, tok, lp, ks, vs
+        return self._prefill_slot_core(
+            params, (k_pool, v_pool), table_row, tokens, start, n_valid,
+            (key, gen_count, temp, top_k, top_p, rep_pen, seen_row))
 
     def _decode_slots_fn(self, params, k_pool, v_pool, tables, lengths,
                          tokens, active, impl, keys, gen_counts, temps,
@@ -1006,27 +1103,10 @@ class InferenceEngine:
         so arbitrarily mixed greedy/sampled batches reuse this one
         program; the fused sampler emits each slot's next token (and
         its logprob) in the same dispatch as the forward step."""
-        cfg = self.cfg
-        with jax.named_scope("embed"):
-            x = params["wte"]["embedding"][tokens[:, None]]
-            if cfg.use_wpe:
-                safe = jnp.clip(lengths, 0, self.max_seq_len - 1)
-                x = x + params["wpe"]["embedding"][safe][:, None]
-
-        def body(x, layer):
-            layer_p, kp, vp = layer
-            y, kp, vp = _block_decode_paged(x, kp, vp, tables, lengths,
-                                            active, layer_p, cfg,
-                                            impl=impl)
-            return y, (kp, vp)
-
-        x, (ks, vs) = jax.lax.scan(body, x,
-                                   (params["block"], k_pool, v_pool))
-        logits = self._logits(params, x)
-        toks, lps = sampling.sample_tokens(logits[:, -1], keys, gen_counts,
-                                           temps, top_ks, top_ps, rep_pens,
-                                           seen)
-        return logits, toks, lps, ks, vs
+        return self._decode_slots_core(
+            params, (k_pool, v_pool), tables, lengths, tokens, active,
+            impl,
+            (keys, gen_counts, temps, top_ks, top_ps, rep_pens, seen))
 
     def _verify_slots_fn(self, params, k_pool, v_pool, tables, lengths,
                          tokens, active, impl="gather"):
@@ -1039,24 +1119,9 @@ class InferenceEngine:
         eviction, requeue and prefix-cache hits — reuses this ONE
         program; impl is a static jit argument exactly like
         _decode_slots_fn."""
-        cfg = self.cfg
-        B, G = tokens.shape
-        x = params["wte"]["embedding"][tokens]
-        if cfg.use_wpe:
-            pos = lengths[:, None] + jnp.arange(G, dtype=jnp.int32)[None]
-            safe = jnp.clip(pos, 0, self.max_seq_len - 1)
-            x = x + params["wpe"]["embedding"][safe]
-
-        def body(x, layer):
-            layer_p, kp, vp = layer
-            y, kp, vp = _block_verify_paged(x, kp, vp, tables, lengths,
-                                            active, layer_p, cfg,
-                                            impl=impl)
-            return y, (kp, vp)
-
-        x, (ks, vs) = jax.lax.scan(body, x,
-                                   (params["block"], k_pool, v_pool))
-        return self._logits(params, x), ks, vs
+        return self._verify_slots_core(
+            params, (k_pool, v_pool), tables, lengths, tokens, active,
+            impl)
 
     def _extend_fn(self, params, cache, tokens, pos):
         """G-token chunk verify over the STATIC dense cache (the
@@ -1099,36 +1164,14 @@ class InferenceEngine:
                            gen_count, temp, top_k, top_p, rep_pen,
                            seen_row):
         """int8-pool twin of _prefill_slot_fn: the per-layer scale pools
-        ([L, N, Hkv] fp32) thread through the scan alongside the pools
+        ([L, N, Hkv] fp32) ride through the layers alongside the pools
         and the block write is the read-modify-requantize path of
         _block_prefill_paged. Carries the same fused sampling lane as
         the fp program."""
-        cfg = self.cfg
-        C = tokens.shape[0]
-        positions = start + jnp.arange(C, dtype=jnp.int32)
-        with jax.named_scope("embed"):
-            x = params["wte"]["embedding"][tokens][None]
-            if cfg.use_wpe:
-                safe = jnp.clip(positions, 0, self.max_seq_len - 1)
-                x = x + params["wpe"]["embedding"][safe][None]
-
-        def body(x, layer):
-            layer_p, kp, vp, ksp, vsp = layer
-            y, kp, vp, ksp, vsp = _block_prefill_paged(
-                x, kp, vp, table_row, positions, n_valid, layer_p, cfg,
-                k_scale=ksp, v_scale=vsp)
-            return y, (kp, vp, ksp, vsp)
-
-        x, (ks, vs, kss, vss) = jax.lax.scan(
-            body, x, (params["block"], k_pool, v_pool, k_scale, v_scale))
-        last = jnp.clip(n_valid - 1, 0, C - 1)
-        x_last = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
-        logits = self._logits(params, x_last)
-        tok, lp = sampling.sample_tokens(
-            logits[:, -1], key.reshape(1, 2), gen_count.reshape(1),
-            temp.reshape(1), top_k.reshape(1), top_p.reshape(1),
-            rep_pen.reshape(1), seen_row.reshape(1, -1))
-        return logits, tok, lp, ks, vs, kss, vss
+        return self._prefill_slot_core(
+            params, (k_pool, v_pool, k_scale, v_scale), table_row, tokens,
+            start, n_valid,
+            (key, gen_count, temp, top_k, top_p, rep_pen, seen_row))
 
     def _decode_slots_q_fn(self, params, k_pool, v_pool, k_scale, v_scale,
                            tables, lengths, tokens, active, impl, keys,
@@ -1137,98 +1180,32 @@ class InferenceEngine:
         """int8-pool twin of _decode_slots_fn (see _block_decode_paged's
         quantized write path). Carries the same fused sampling lanes as
         the fp program."""
-        cfg = self.cfg
-        with jax.named_scope("embed"):
-            x = params["wte"]["embedding"][tokens[:, None]]
-            if cfg.use_wpe:
-                safe = jnp.clip(lengths, 0, self.max_seq_len - 1)
-                x = x + params["wpe"]["embedding"][safe][:, None]
-
-        def body(x, layer):
-            layer_p, kp, vp, ksp, vsp = layer
-            y, kp, vp, ksp, vsp = _block_decode_paged(
-                x, kp, vp, tables, lengths, active, layer_p, cfg,
-                impl=impl, k_scale=ksp, v_scale=vsp)
-            return y, (kp, vp, ksp, vsp)
-
-        x, (ks, vs, kss, vss) = jax.lax.scan(
-            body, x, (params["block"], k_pool, v_pool, k_scale, v_scale))
-        logits = self._logits(params, x)
-        toks, lps = sampling.sample_tokens(logits[:, -1], keys, gen_counts,
-                                           temps, top_ks, top_ps, rep_pens,
-                                           seen)
-        return logits, toks, lps, ks, vs, kss, vss
+        return self._decode_slots_core(
+            params, (k_pool, v_pool, k_scale, v_scale), tables, lengths,
+            tokens, active, impl,
+            (keys, gen_counts, temps, top_ks, top_ps, rep_pens, seen))
 
     def _verify_slots_q_fn(self, params, k_pool, v_pool, k_scale, v_scale,
                            tables, lengths, tokens, active, impl="gather"):
         """int8-pool twin of _verify_slots_fn (see _block_verify_paged's
         quantized write path)."""
-        cfg = self.cfg
-        B, G = tokens.shape
-        x = params["wte"]["embedding"][tokens]
-        if cfg.use_wpe:
-            pos = lengths[:, None] + jnp.arange(G, dtype=jnp.int32)[None]
-            safe = jnp.clip(pos, 0, self.max_seq_len - 1)
-            x = x + params["wpe"]["embedding"][safe]
-
-        def body(x, layer):
-            layer_p, kp, vp, ksp, vsp = layer
-            y, kp, vp, ksp, vsp = _block_verify_paged(
-                x, kp, vp, tables, lengths, active, layer_p, cfg,
-                impl=impl, k_scale=ksp, v_scale=vsp)
-            return y, (kp, vp, ksp, vsp)
-
-        x, (ks, vs, kss, vss) = jax.lax.scan(
-            body, x, (params["block"], k_pool, v_pool, k_scale, v_scale))
-        return self._logits(params, x), ks, vs, kss, vss
-
-    @staticmethod
-    def _gather_lora(lora_a, lora_b, ablocks):
-        """Per-layer slice of the adapter pools -> per-slot gathered
-        factors for gpt._dense's lora hook. ``lora_a[t]``: [NB, in, rb]
-        (the scan already consumed the leading L); ``ablocks``:
-        [B, NBa] per-slot pool-block rows (traced data — any adapter
-        mix reuses the one program). Base-only rows are all zeros and
-        gather the permanent trash block."""
-        return {t: (lora_a[t][ablocks], lora_b[t][ablocks])
-                for t in lora_a}
+        return self._verify_slots_core(
+            params, (k_pool, v_pool, k_scale, v_scale), tables, lengths,
+            tokens, active, impl)
 
     def _prefill_slot_l_fn(self, params, k_pool, v_pool, table_row, tokens,
                            start, n_valid, key, gen_count, temp, top_k,
                            top_p, rep_pen, seen_row, lora_a, lora_b,
                            ablock_row):
-        """LoRA twin of _prefill_slot_fn: the adapter pools thread
-        through the scan alongside the block params and the slot's
+        """LoRA twin of _prefill_slot_fn: the adapter pools ride through
+        the layers alongside the block params and the slot's
         adapter-table row selects its rank blocks (inference/
         adapters.py). An all-zeros row gathers the trash block — the
         base-only prefill bit-for-bit."""
-        cfg = self.cfg
-        C = tokens.shape[0]
-        positions = start + jnp.arange(C, dtype=jnp.int32)
-        with jax.named_scope("embed"):
-            x = params["wte"]["embedding"][tokens][None]
-            if cfg.use_wpe:
-                safe = jnp.clip(positions, 0, self.max_seq_len - 1)
-                x = x + params["wpe"]["embedding"][safe][None]
-
-        def body(x, layer):
-            layer_p, kp, vp, la, lb = layer
-            lora = self._gather_lora(la, lb, ablock_row[None])
-            y, kp, vp = _block_prefill_paged(x, kp, vp, table_row,
-                                             positions, n_valid, layer_p,
-                                             cfg, lora=lora)
-            return y, (kp, vp)
-
-        x, (ks, vs) = jax.lax.scan(
-            body, x, (params["block"], k_pool, v_pool, lora_a, lora_b))
-        last = jnp.clip(n_valid - 1, 0, C - 1)
-        x_last = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
-        logits = self._logits(params, x_last)
-        tok, lp = sampling.sample_tokens(
-            logits[:, -1], key.reshape(1, 2), gen_count.reshape(1),
-            temp.reshape(1), top_k.reshape(1), top_p.reshape(1),
-            rep_pen.reshape(1), seen_row.reshape(1, -1))
-        return logits, tok, lp, ks, vs
+        return self._prefill_slot_core(
+            params, (k_pool, v_pool), table_row, tokens, start, n_valid,
+            (key, gen_count, temp, top_k, top_p, rep_pen, seen_row),
+            lora_ops=(lora_a, lora_b, ablock_row[None]))
 
     def _decode_slots_l_fn(self, params, k_pool, v_pool, tables, lengths,
                            tokens, active, impl, keys, gen_counts, temps,
@@ -1237,28 +1214,11 @@ class InferenceEngine:
         """LoRA twin of _decode_slots_fn: one compiled program decodes
         any mix of adapters and base-only slots — ``ablocks`` [B, NBa]
         is traced data exactly like the sampling lanes."""
-        cfg = self.cfg
-        with jax.named_scope("embed"):
-            x = params["wte"]["embedding"][tokens[:, None]]
-            if cfg.use_wpe:
-                safe = jnp.clip(lengths, 0, self.max_seq_len - 1)
-                x = x + params["wpe"]["embedding"][safe][:, None]
-
-        def body(x, layer):
-            layer_p, kp, vp, la, lb = layer
-            lora = self._gather_lora(la, lb, ablocks)
-            y, kp, vp = _block_decode_paged(x, kp, vp, tables, lengths,
-                                            active, layer_p, cfg,
-                                            impl=impl, lora=lora)
-            return y, (kp, vp)
-
-        x, (ks, vs) = jax.lax.scan(
-            body, x, (params["block"], k_pool, v_pool, lora_a, lora_b))
-        logits = self._logits(params, x)
-        toks, lps = sampling.sample_tokens(logits[:, -1], keys, gen_counts,
-                                           temps, top_ks, top_ps, rep_pens,
-                                           seen)
-        return logits, toks, lps, ks, vs
+        return self._decode_slots_core(
+            params, (k_pool, v_pool), tables, lengths, tokens, active,
+            impl,
+            (keys, gen_counts, temps, top_ks, top_ps, rep_pens, seen),
+            lora_ops=(lora_a, lora_b, ablocks))
 
     def _verify_slots_l_fn(self, params, k_pool, v_pool, tables, lengths,
                            tokens, active, impl="gather", lora_a=None,
@@ -1267,25 +1227,9 @@ class InferenceEngine:
         scored under ITS adapter (speculative decode composes with
         multi-tenant serving — the verify distribution is the adapted
         model's, so accept/reject stays lossless per tenant)."""
-        cfg = self.cfg
-        B, G = tokens.shape
-        x = params["wte"]["embedding"][tokens]
-        if cfg.use_wpe:
-            pos = lengths[:, None] + jnp.arange(G, dtype=jnp.int32)[None]
-            safe = jnp.clip(pos, 0, self.max_seq_len - 1)
-            x = x + params["wpe"]["embedding"][safe]
-
-        def body(x, layer):
-            layer_p, kp, vp, la, lb = layer
-            lora = self._gather_lora(la, lb, ablocks)
-            y, kp, vp = _block_verify_paged(x, kp, vp, tables, lengths,
-                                            active, layer_p, cfg,
-                                            impl=impl, lora=lora)
-            return y, (kp, vp)
-
-        x, (ks, vs) = jax.lax.scan(
-            body, x, (params["block"], k_pool, v_pool, lora_a, lora_b))
-        return self._logits(params, x), ks, vs
+        return self._verify_slots_core(
+            params, (k_pool, v_pool), tables, lengths, tokens, active,
+            impl, lora_ops=(lora_a, lora_b, ablocks))
 
     def _prefill_slot_ql_fn(self, params, k_pool, v_pool, k_scale, v_scale,
                             table_row, tokens, start, n_valid, key,
@@ -1294,88 +1238,30 @@ class InferenceEngine:
         """int8-pool + LoRA combo twin (DS_KV_QUANT=int8 with
         DS_LORA_SERVE=on): quantized KV write path, adapted
         projections."""
-        cfg = self.cfg
-        C = tokens.shape[0]
-        positions = start + jnp.arange(C, dtype=jnp.int32)
-        with jax.named_scope("embed"):
-            x = params["wte"]["embedding"][tokens][None]
-            if cfg.use_wpe:
-                safe = jnp.clip(positions, 0, self.max_seq_len - 1)
-                x = x + params["wpe"]["embedding"][safe][None]
-
-        def body(x, layer):
-            layer_p, kp, vp, ksp, vsp, la, lb = layer
-            lora = self._gather_lora(la, lb, ablock_row[None])
-            y, kp, vp, ksp, vsp = _block_prefill_paged(
-                x, kp, vp, table_row, positions, n_valid, layer_p, cfg,
-                k_scale=ksp, v_scale=vsp, lora=lora)
-            return y, (kp, vp, ksp, vsp)
-
-        x, (ks, vs, kss, vss) = jax.lax.scan(
-            body, x, (params["block"], k_pool, v_pool, k_scale, v_scale,
-                      lora_a, lora_b))
-        last = jnp.clip(n_valid - 1, 0, C - 1)
-        x_last = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
-        logits = self._logits(params, x_last)
-        tok, lp = sampling.sample_tokens(
-            logits[:, -1], key.reshape(1, 2), gen_count.reshape(1),
-            temp.reshape(1), top_k.reshape(1), top_p.reshape(1),
-            rep_pen.reshape(1), seen_row.reshape(1, -1))
-        return logits, tok, lp, ks, vs, kss, vss
+        return self._prefill_slot_core(
+            params, (k_pool, v_pool, k_scale, v_scale), table_row, tokens,
+            start, n_valid,
+            (key, gen_count, temp, top_k, top_p, rep_pen, seen_row),
+            lora_ops=(lora_a, lora_b, ablock_row[None]))
 
     def _decode_slots_ql_fn(self, params, k_pool, v_pool, k_scale, v_scale,
                             tables, lengths, tokens, active, impl, keys,
                             gen_counts, temps, top_ks, top_ps, rep_pens,
                             seen, lora_a, lora_b, ablocks):
         """int8-pool + LoRA combo twin of _decode_slots_fn."""
-        cfg = self.cfg
-        with jax.named_scope("embed"):
-            x = params["wte"]["embedding"][tokens[:, None]]
-            if cfg.use_wpe:
-                safe = jnp.clip(lengths, 0, self.max_seq_len - 1)
-                x = x + params["wpe"]["embedding"][safe][:, None]
-
-        def body(x, layer):
-            layer_p, kp, vp, ksp, vsp, la, lb = layer
-            lora = self._gather_lora(la, lb, ablocks)
-            y, kp, vp, ksp, vsp = _block_decode_paged(
-                x, kp, vp, tables, lengths, active, layer_p, cfg,
-                impl=impl, k_scale=ksp, v_scale=vsp, lora=lora)
-            return y, (kp, vp, ksp, vsp)
-
-        x, (ks, vs, kss, vss) = jax.lax.scan(
-            body, x, (params["block"], k_pool, v_pool, k_scale, v_scale,
-                      lora_a, lora_b))
-        logits = self._logits(params, x)
-        toks, lps = sampling.sample_tokens(logits[:, -1], keys, gen_counts,
-                                           temps, top_ks, top_ps, rep_pens,
-                                           seen)
-        return logits, toks, lps, ks, vs, kss, vss
+        return self._decode_slots_core(
+            params, (k_pool, v_pool, k_scale, v_scale), tables, lengths,
+            tokens, active, impl,
+            (keys, gen_counts, temps, top_ks, top_ps, rep_pens, seen),
+            lora_ops=(lora_a, lora_b, ablocks))
 
     def _verify_slots_ql_fn(self, params, k_pool, v_pool, k_scale, v_scale,
                             tables, lengths, tokens, active, impl="gather",
                             lora_a=None, lora_b=None, ablocks=None):
         """int8-pool + LoRA combo twin of _verify_slots_fn."""
-        cfg = self.cfg
-        B, G = tokens.shape
-        x = params["wte"]["embedding"][tokens]
-        if cfg.use_wpe:
-            pos = lengths[:, None] + jnp.arange(G, dtype=jnp.int32)[None]
-            safe = jnp.clip(pos, 0, self.max_seq_len - 1)
-            x = x + params["wpe"]["embedding"][safe]
-
-        def body(x, layer):
-            layer_p, kp, vp, ksp, vsp, la, lb = layer
-            lora = self._gather_lora(la, lb, ablocks)
-            y, kp, vp, ksp, vsp = _block_verify_paged(
-                x, kp, vp, tables, lengths, active, layer_p, cfg,
-                impl=impl, k_scale=ksp, v_scale=vsp, lora=lora)
-            return y, (kp, vp, ksp, vsp)
-
-        x, (ks, vs, kss, vss) = jax.lax.scan(
-            body, x, (params["block"], k_pool, v_pool, k_scale, v_scale,
-                      lora_a, lora_b))
-        return self._logits(params, x), ks, vs, kss, vss
+        return self._verify_slots_core(
+            params, (k_pool, v_pool, k_scale, v_scale), tables, lengths,
+            tokens, active, impl, lora_ops=(lora_a, lora_b, ablocks))
 
     def _decode_horizon_core(self, params, k_pool, v_pool, tables, lengths,
                              tokens, active, impl, n_steps, lanes, preds,
@@ -1397,9 +1283,10 @@ class InferenceEngine:
         bit-for-bit.
 
         Shared by all four twins — quant (``k_scale``/``v_scale``) and
-        LoRA (``lora_ops``) compose by Python-level xs-tuple layout, not
-        new hand-written scan bodies. ``preds``: budgets [B] (tokens
-        this slot may emit this horizon), eos_ids [B] (-1 = none),
+        LoRA (``lora_ops``) compose through _scan_layers, the layer loop
+        of every paged program, which carries the pools; the step scan
+        here carries them between its iterations. ``preds``: budgets [B]
+        (tokens this slot may emit this horizon), eos_ids [B] (-1 = none),
         stop_ids [B, S, W] right-aligned, stop_lens [B, S] (0 = unused
         row), tail [B, W] (the slot's last W emitted tokens, -1
         padded). Returns ([N, B] tokens, [N, B] logprobs, [B] produced,
@@ -1420,23 +1307,12 @@ class InferenceEngine:
                 safe = jnp.clip(lens, 0, self.max_seq_len - 1)
                 x = x + params["wpe"]["embedding"][safe][:, None]
 
-            xs = (params["block"],) + pools
-            if lora_ops is not None:
-                xs = xs + (lora_ops[0], lora_ops[1])
+            def block(x, pools, layer_p, base, lora):
+                return _block_decode_paged(x, pools, tables, lens,
+                                           lane_active, layer_p, cfg,
+                                           impl=impl, lora=lora, base=base)
 
-            def body(x, layer):
-                kw = {}
-                if quant:
-                    kw["k_scale"], kw["v_scale"] = layer[3], layer[4]
-                if lora_ops is not None:
-                    kw["lora"] = self._gather_lora(layer[-2], layer[-1],
-                                                   lora_ops[2])
-                out = _block_decode_paged(x, layer[1], layer[2], tables,
-                                          lens, lane_active, layer[0],
-                                          cfg, impl=impl, **kw)
-                return out[0], tuple(out[1:])
-
-            x, pools = jax.lax.scan(body, x, xs)
+            x, pools = _scan_layers(block, x, params, pools, lora_ops)
             logits = self._logits(params, x)
             toks_i, lps_i = sampling.sample_tokens(
                 logits[:, -1], keys, gen_counts + i, temps, top_ks,
@@ -1642,15 +1518,23 @@ class InferenceEngine:
         return (a_pool, b_pool, jnp.asarray(ablocks, jnp.int32))
 
     def _run(self, pid: str, program, *args):
-        """Call a jitted serving program. Under telemetry the FIRST call
-        of each program also hands the text of its compiled module to
-        the provenance table: lowering with the very arguments of the
-        dispatch yields the executable the call below then runs (one
-        compilation, not two), so the table is of what is loaded."""
+        """Call a jitted serving program (``args[1]`` is its K pool).
+        Under telemetry the FIRST call of each program also hands the
+        text of its compiled module to the provenance table: lowering
+        with the very arguments of the dispatch yields the executable
+        the call below then runs (one compilation, not two), so the
+        table is of what is loaded. The table also says whether the
+        paged pool's one layout held in that executable:
+        ``pool_copy_bytes``, logged here once per program, is 0 when no
+        ``copy`` of a pool-shaped value was compiled in."""
         sink = self.provenance
         if sink is not None and pid not in sink.provenance:
-            sink.add_provenance(
-                pid, program.lower(*args).compile().as_text())
+            L, N = args[1].shape[:2]
+            copied = sink.add_provenance(
+                pid, program.lower(*args).compile().as_text(),
+                pool_blocks=(N, L * N))
+            log_dist(f"serving program {pid}: pool_copy_bytes={copied}",
+                     ranks=[0])
         return program(*args)
 
     def prefill_into_slot(self, k_pool, v_pool, table_row, tokens, start,
@@ -1678,7 +1562,8 @@ class InferenceEngine:
         maybe_fire("cache.quantize")
         pf = (self._prefill_slot_q if lora is None
               else self._prefill_slot_ql)
-        out = pf(
+        out = self._run(
+            "prefill_slot_q" if lora is None else "prefill_slot_ql", pf,
             self.params, k_pool, v_pool, k_scale, v_scale,  # dslint: disable=DS003 — exclusive branch: the fp dispatch above already returned
             jnp.asarray(table_row, jnp.int32),
             jnp.asarray(tokens, jnp.int32),
@@ -1708,7 +1593,8 @@ class InferenceEngine:
         maybe_fire("cache.quantize")
         df = (self._decode_slots_q if lora is None
               else self._decode_slots_ql)
-        out = df(
+        out = self._run(
+            "decode_slots_q" if lora is None else "decode_slots_ql", df,
             self.params, k_pool, v_pool, k_scale, v_scale,  # dslint: disable=DS003 — exclusive branch: the fp dispatch above already returned
             jnp.asarray(tables, jnp.int32),
             jnp.asarray(lengths, jnp.int32),
@@ -1743,8 +1629,9 @@ class InferenceEngine:
         if k_scale is None:
             df = (self._decode_horizon if lora is None
                   else self._decode_horizon_l)
-            return df(
-                self.params, k_pool, v_pool,
+            return self._run(
+                "decode_horizon" if lora is None else "decode_horizon_l",
+                df, self.params, k_pool, v_pool,
                 jnp.asarray(tables, jnp.int32),
                 jnp.asarray(lengths, jnp.int32),
                 jnp.asarray(tokens, jnp.int32), jnp.asarray(active, bool),
@@ -1753,7 +1640,8 @@ class InferenceEngine:
         maybe_fire("cache.quantize")
         df = (self._decode_horizon_q if lora is None
               else self._decode_horizon_ql)
-        return df(
+        return self._run(
+            "decode_horizon_q" if lora is None else "decode_horizon_ql", df,
             self.params, k_pool, v_pool, k_scale, v_scale,  # dslint: disable=DS003 — exclusive branch: the fp dispatch above already returned
             jnp.asarray(tables, jnp.int32),
             jnp.asarray(lengths, jnp.int32),
@@ -1775,7 +1663,8 @@ class InferenceEngine:
         largs = self._lora_operands(lora)
         if k_scale is None:
             vf = self._verify_slots if lora is None else self._verify_slots_l
-            return vf(
+            return self._run(
+                "verify_slots" if lora is None else "verify_slots_l", vf,
                 self.params, k_pool, v_pool,
                 jnp.asarray(tables, jnp.int32),
                 jnp.asarray(lengths, jnp.int32),
@@ -1784,7 +1673,8 @@ class InferenceEngine:
         maybe_fire("cache.quantize")
         vf = (self._verify_slots_q if lora is None
               else self._verify_slots_ql)
-        return vf(
+        return self._run(
+            "verify_slots_q" if lora is None else "verify_slots_ql", vf,
             self.params, k_pool, v_pool, k_scale, v_scale,  # dslint: disable=DS003 — exclusive branch: the fp dispatch above already returned
             jnp.asarray(tables, jnp.int32),
             jnp.asarray(lengths, jnp.int32),

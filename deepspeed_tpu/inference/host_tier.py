@@ -70,7 +70,7 @@ class HostBlockPool:
     """CRC-tagged host-DRAM storage for spilled KV blocks.
 
     One entry holds one pool block's payload as a tuple of contiguous
-    numpy arrays — ``(k_blk, v_blk)`` of shape ``[L, bs, Hkv, Dh]``,
+    numpy arrays — ``(k_blk, v_blk)`` of shape ``[L, bs, Hkv*Dh]``,
     plus the ``(k_scale, v_scale)`` fp32 sidecars ``[L, Hkv]`` when the
     device pool is int8 (the tier composes with ``DS_KV_QUANT=int8`` by
     spilling quantized bytes AND their scales, so a restored block

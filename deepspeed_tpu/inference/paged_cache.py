@@ -6,12 +6,26 @@ memory is ``B * S_max`` tokens regardless of what is actually in flight
 (the reproduction of the reference's global Context workspace, ref:
 ops/transformer/inference/transformer_inference.py:113 softmax_context).
 This module is the PagedAttention answer (Kwon et al., SOSP '23): K/V
-live in a pool of fixed-size blocks ``[L, N_blocks, block, Hkv, Dh]``,
+live in a pool of fixed-size blocks ``[L, N_blocks, block, Hkv*Dh]``,
 each serving slot owns an ordered list of block ids (its block table),
 and a free-list allocator hands blocks out on demand — cache memory
 scales with tokens in flight, fragmentation is bounded by one partial
 block per request, and a finished request's blocks return to the pool
 immediately.
+
+**One layout in HBM.** A cached token is ONE row of ``Hkv*Dh`` lanes,
+its kv heads folded side by side. With the heads a dimension of their
+own the device pads 25 heads to 32 sublanes and 64 lanes to 128, the
+compiler stores the pool compactly with the block index minor, and
+every serving dispatch re-laid every layer's pool out for the scatter
+and the kernel and back (68-74% of device time, PERF.md PR 25). The
+folded row is row-major on the device as it is here (1,600 lanes pad to
+1,664: 4%), so the entry parameter, the layer loop's carried state
+(``[L*N_blocks, block, Hkv*Dh]``, a bitcast; layer ``l`` addresses
+block ``b`` at ``b + l*N_blocks``: inference/engine.py ``_scan_layers``)
+and the Mosaic kernel's operand (ops/attention/paged.py) are the same
+bytes and nothing copies the pool. Everything here indexes dimension 1
+by block id and never looks inside a block.
 
 **Shared-prefix caching** (``prefix_cache=True`` / ``DS_PREFIX_CACHE=on``,
 vLLM automatic prefix caching + SGLang RadixAttention): blocks carry
@@ -264,7 +278,10 @@ class PagedKVCache:
             raise ValueError(
                 f"HBM budget covers {self.num_blocks - 1} blocks; the "
                 f"pool needs at least 1 allocatable block")
-        self.k = jnp.zeros((L, self.num_blocks, self.block_size, Hkv, Dh),
+        # one row per cached token, its kv heads folded side by side:
+        # the layout in HBM that the entry parameter, the layer loop and
+        # the kernel share (module docstring)
+        self.k = jnp.zeros((L, self.num_blocks, self.block_size, Hkv * Dh),
                            self.pool_dtype)
         self.v = jnp.zeros_like(self.k)
         if self.quantized:

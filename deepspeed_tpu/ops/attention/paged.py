@@ -1,7 +1,21 @@
 """Paged-attention decode kernel — Pallas TPU flash-decode through the
 block table.
 
-The serving engine's gather path (`inference/engine.py _gather_blocks`)
+The pool this kernel reads is the one the paged cache stores and the
+layer loop carries: ``[N', block, Hkv*Dh]``, a token's kv heads side by
+side in ONE row of ``Hkv*Dh`` lanes (inference/paged_cache.py). With the
+heads a dimension of their own (``[..., Hkv, Dh]``) the device tiles the
+last two dimensions, pads 25 heads to 32 sublanes and 64 lanes to 128,
+and the compiler stores the pool in a compact layout of its own with
+the block index minor, which neither the Mosaic call nor the scatter
+can use: every dispatch then re-laid every layer's pool out and back.
+One row of ``Hkv*Dh`` lanes is row-major on the device as it is here,
+so the entry parameter, the loop's state and this kernel's operand are
+one layout and nothing copies the pool. ``N'`` is whatever the caller
+stacked: the serving programs hand over all layers' pools as
+``[L*N, ...]`` and address layer ``l`` by ``tables + l*N``.
+
+The serving engine's gather path (`gather_pool_blocks` below)
 materializes the WHOLE virtual cache ``[B, NB*block, Hkv, Dh]`` out of
 the block pool every layer, every decoded token, then masks everything
 past ``lengths``: per token that is O(S_max) HBM reads plus an
@@ -16,7 +30,7 @@ softmax, Dao 2023):
 - block tables and per-slot lengths ride in as scalar-prefetch operands
   (``pltpu.PrefetchScalarGridSpec``), so the K/V BlockSpec index_map
   dereferences ``tables[b, j]`` BEFORE the grid step runs and each step
-  DMAs exactly one pool block ``[block, Hkv, Dh]`` from HBM — no dense
+  DMAs exactly one pool block ``[block, Hkv*Dh]`` from HBM — no dense
   gather copy ever exists;
 - grid ``(B, NB)`` with the KV (block) dimension innermost; fp32
   running max / sum / accumulator live in VMEM scratch across the
@@ -31,9 +45,9 @@ softmax, Dao 2023):
   and small), packing the ``group = H // Hkv`` query heads that share a
   kv head into one MXU matmul per head. Folding the head loop into the
   body — rather than a (B, Hkv, NB) grid — means one pool block fetch
-  serves ALL kv heads (the pool's native layout is
-  ``[N, block, Hkv, Dh]``, so a per-head grid would re-DMA each block
-  Hkv times or force a full-pool relayout);
+  serves ALL kv heads: head ``h`` is the lane slice
+  ``[h*Dh, (h+1)*Dh)`` of the block's rows (a per-head grid would
+  re-DMA each block Hkv times);
 - the final partial block is masked by position exactly like the gather
   path, so the two implementations are numerically interchangeable (the
   gather path stays the bit-reference, see docs/PARITY.md).
@@ -107,7 +121,7 @@ def paged_hbm_bytes_per_token(cfg, num_slots: int, mean_len: float,
 
 
 def _kv_index_map(bs: int, nb: int, window: Optional[int], q_len: int = 1,
-                  rank: int = 4):
+                  per_slot: bool = False):
     """Block index map for the K/V pools when the grid is (b, j) and the
     pools are scalar-prefetch-addressed: step (b, j) fetches pool block
     ``tables[b, clamp(j)]``. Steps past the slot's last occupied block
@@ -118,10 +132,11 @@ def _kv_index_map(bs: int, nb: int, window: Optional[int], q_len: int = 1,
     chunk (``q_len > 1``) the last query sits at ``lengths + q_len - 1``,
     so the high clamp covers that block too.
 
-    ``rank=4`` addresses the K/V pools ``[N, block, Hkv, Dh]``;
-    ``rank=3`` addresses the int8 mode's scale pools (fed as
-    ``[N, Hkv, 1]``) with the SAME table indirection, so each grid
-    step's scale rides the same prefetch discipline as its block."""
+    The default addresses the K/V pools ``[N', block, Hkv*Dh]``;
+    ``per_slot=True`` addresses the int8 mode's scales, already gathered
+    through the tables into ``[B, NB, 1, Hkv]``, at ``[b, clamp(j)]``:
+    the SAME clamp, so each grid step's scales ride the same prefetch
+    discipline as its block."""
     def imap(b, j, tables_ref, lengths_ref):
         pos = lengths_ref[b]
         hi = jnp.minimum((pos + (q_len - 1)) // bs, nb - 1)
@@ -129,7 +144,9 @@ def _kv_index_map(bs: int, nb: int, window: Optional[int], q_len: int = 1,
         if window is not None:
             lo = jnp.clip((pos - window + 1) // bs, 0, nb - 1)
             jj = jnp.maximum(jj, lo)
-        return (tables_ref[b, jj],) + (0,) * (rank - 1)
+        if per_slot:
+            return (b, jj, 0, 0)
+        return (tables_ref[b, jj], 0, 0)
 
     return imap
 
@@ -142,8 +159,9 @@ def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref,
 
     q_ref: [1, H*q_len, Dh] (H = n_kv * group; rows ordered (kv head,
     group member, chunk offset) so each kv head's queries are one
-    contiguous MXU matmul); k_ref / v_ref: [1, bs, Hkv, Dh] — ONE pool
-    block, already table-indirected by the index_map; scratch: running
+    contiguous MXU matmul); k_ref / v_ref: [1, bs, Hkv*Dh] — ONE pool
+    block, already table-indirected by the index_map, kv head h in
+    lanes [h*Dh, (h+1)*Dh); scratch: running
     max / sum / fp32 accumulator per query row, persistent across the j
     (block) iterations of slot b. q_len == 1 is plain decode; q_len > 1
     is the speculative verify chunk — query row with chunk offset g is
@@ -152,13 +170,13 @@ def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref,
     scattered into the pool).
 
     ``quant=True``: k_ref/v_ref hold int8 and two extra refs
-    ks_ref/vs_ref ([1, Hkv, 1] fp32 per-block scales, same table
-    indirection) precede the output — the block is dequantized
-    IN-REGISTER right after its DMA (the ops/int8_matmul.py idiom), so
-    HBM traffic stays the int8 payload + one scale vector per block.
-    The scales sit with Hkv on the sublane axis, as in the block, and
-    multiply it whole as a lane-broadcast vector: Mosaic has no scalar
-    load from VMEM for a per-head ``ks_ref[0, h]``."""
+    ks_ref/vs_ref ([1, 1, 1, Hkv] fp32, this block's per-head scales)
+    precede the output — each head's slice is dequantized IN-REGISTER
+    right after the block's DMA (the ops/int8_matmul.py idiom), so HBM
+    traffic stays the int8 payload + one scale vector per block. Head
+    h's scale is the (1, 1) lane slice ``[:, h:h+1]``, broadcast over
+    the slice: Mosaic has no scalar load from VMEM for a per-head
+    ``ks_ref[..., h]``."""
     if quant:
         ks_ref, vs_ref, o_ref, m_scratch, l_scratch, acc_scratch = rest
     else:
@@ -187,13 +205,13 @@ def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref,
     @pl.when(run)
     def _body():
         q = q_ref[0]                          # [H*q_len, Dh]
-        k = k_ref[0]                          # [bs, Hkv, Dh]
+        k = k_ref[0]                          # [bs, Hkv*Dh]
         v = v_ref[0]
+        Dh = q.shape[-1]
         if quant:
-            # in-register dequantize: int8 block x its [Hkv, 1] scales
             q = q.astype(jnp.float32)
-            k = k.astype(jnp.float32) * ks_ref[0][None]
-            v = v.astype(jnp.float32) * vs_ref[0][None]
+            ks = ks_ref[0, 0]                 # [1, Hkv]
+            vs = vs_ref[0, 0]
         # positions of this block's slots in the slot's virtual cache;
         # the final partial block masks by position exactly like the
         # gather path (idx <= pos + chunk offset, window band below it)
@@ -212,8 +230,12 @@ def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref,
         for h in range(n_kv):                 # static unroll: Hkv is small
             rows = slice(h * R, (h + 1) * R)
             qh = q[rows, :]                   # [R, Dh] — one MXU matmul
-            kh = k[:, h, :]                   # [bs, Dh]     covers the whole
-            vh = v[:, h, :]                   # GQA group of this kv head
+            kh = k[:, h * Dh:(h + 1) * Dh]    # [bs, Dh]     covers the whole
+            vh = v[:, h * Dh:(h + 1) * Dh]    # GQA group of this kv head
+            if quant:
+                # in-register dequantize: int8 slice x its head's scale
+                kh = kh.astype(jnp.float32) * ks[:, h:h + 1]
+                vh = vh.astype(jnp.float32) * vs[:, h:h + 1]
             s = jax.lax.dot_general(
                 qh, kh, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale   # [R, bs]
@@ -252,10 +274,12 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     table — no dense cache materialization.
 
     q: [B, Hkv, group, Dh] post-rotary queries (grouped per shared kv
-    head); k_pool / v_pool: [N, block, Hkv, Dh] pools (the new token's
-    K/V must already be scattered in at position ``lengths[b]``);
-    tables: [B, NB] int32 block tables (trash-block-0 convention for
-    unused entries); lengths: [B] int32 per-slot cache positions (slot b
+    head); k_pool / v_pool: [N, block, Hkv*Dh] pools, heads folded into
+    the rows as the paged cache stores them (the new token's K/V must
+    already be scattered in at position ``lengths[b]``); tables:
+    [B, NB] int32 block tables into dimension 0 (the serving programs
+    pass all layers' pools stacked and ``tables + l*N``; unused entries
+    name a trash block); lengths: [B] int32 per-slot cache positions (slot b
     attends positions <= lengths[b], banded by ``window`` when set).
     ``k_scale``/``v_scale`` ([N, Hkv] fp32): int8 pools, dequantized
     in-register after each block DMA (DS_KV_QUANT=int8).
@@ -304,15 +328,17 @@ def _paged_attention_call(q_rows, k_pool, v_pool, tables, lengths, *,
                           window: Optional[int], interpret: bool,
                           k_scale=None, v_scale=None) -> jnp.ndarray:
     """Shared pallas_call plumbing for decode (q_len=1) and verify
-    (q_len=G). q_rows: [B, n_kv*group*q_len, Dh], head-major rows.
-    ``k_scale``/``v_scale`` ([N, Hkv] fp32) switch the int8 dequantize-
-    in-kernel mode on (pools must then be int8)."""
+    (q_len=G). q_rows: [B, n_kv*group*q_len, Dh], head-major rows;
+    pools [N', block, Hkv*Dh]. ``k_scale``/``v_scale`` ([N', Hkv] fp32)
+    switch the int8 dequantize-in-kernel mode on (pools must then be
+    int8)."""
     B, rows, Dh = q_rows.shape
-    N, bs, Hkv, Dh_p = k_pool.shape
-    assert (n_kv, Dh, rows) == (Hkv, Dh_p, n_kv * group * q_len), \
+    N, bs, row = k_pool.shape
+    assert (row, rows) == (n_kv * Dh, n_kv * group * q_len), \
         (q_rows.shape, k_pool.shape, (n_kv, group, q_len))
     assert v_pool.shape == k_pool.shape, (v_pool.shape, k_pool.shape)
     quant = k_scale is not None
+    tables = jnp.asarray(tables, jnp.int32)
     nb = tables.shape[1]
 
     kvmap = _kv_index_map(bs, nb, window, q_len)
@@ -322,18 +348,21 @@ def _paged_attention_call(q_rows, k_pool, v_pool, tables, lengths, *,
 
     in_specs = [
         pl.BlockSpec((1, rows, Dh), qmap),
-        pl.BlockSpec((1, bs, Hkv, Dh), kvmap),
-        pl.BlockSpec((1, bs, Hkv, Dh), kvmap),
+        pl.BlockSpec((1, bs, row), kvmap),
+        pl.BlockSpec((1, bs, row), kvmap),
     ]
     operands = [q_rows, k_pool, v_pool]
     if quant:
-        # [N, Hkv] scales ride as [N, Hkv, 1]: the last two dims of a
-        # block must divide by (8, 128) or equal the array's, which a
-        # (1, Hkv) block of [N, Hkv] does not
-        smap = _kv_index_map(bs, nb, window, q_len, rank=3)
-        in_specs += [pl.BlockSpec((1, Hkv, 1), smap),
-                     pl.BlockSpec((1, Hkv, 1), smap)]
-        operands += [k_scale[..., None], v_scale[..., None]]
+        # the scales of each slot's blocks, gathered through the tables
+        # out here (B*NB*Hkv floats): the kernel then needs no view of
+        # the scale pool in a layout of its own. They ride as
+        # [B, NB, 1, Hkv] because a block's last two dimensions must
+        # divide by (8, 128) or equal the array's
+        smap = _kv_index_map(bs, nb, window, q_len, per_slot=True)
+        in_specs += [pl.BlockSpec((1, 1, 1, n_kv), smap),
+                     pl.BlockSpec((1, 1, 1, n_kv), smap)]
+        operands += [k_scale[tables][:, :, None, :],
+                     v_scale[tables][:, :, None, :]]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -359,19 +388,28 @@ def _paged_attention_call(q_rows, k_pool, v_pool, tables, lengths, *,
         # passed only when a test asks: the conftest fixture's patched
         # pallas_call keeps its own interpret=True
         **({"interpret": True} if interpret else {}),
-    )(jnp.asarray(tables, jnp.int32), jnp.asarray(lengths, jnp.int32),
-      *operands)
+    )(tables, jnp.asarray(lengths, jnp.int32), *operands)
 
 
-def _gather_dequant(pool, scale_pool, tables, dtype):
-    """Gather pool blocks through the tables and dequantize with the
-    per-(block, kv_head) scales — the quantized twin of the engine's
-    ``_gather_blocks``, shared by both bit-reference paths."""
-    from deepspeed_tpu.ops import quantizer
-    g = quantizer.kv_dequantize_blocks(pool[tables], scale_pool[tables],
-                                       dtype=dtype)
-    B, nb, bs = g.shape[0], g.shape[1], g.shape[2]
-    return g.reshape(B, nb * bs, g.shape[3], g.shape[4])
+def gather_pool_blocks(pool, tables, n_kv: int, scale_pool=None,
+                       dtype=None):
+    """Gather pool blocks ``[N', block, Hkv*Dh]`` through block tables
+    ``[B, NB]`` into the virtual contiguous cache
+    ``[B, NB*block, Hkv, Dh]``: cache position s of row b lives at
+    ``pool[tables[b, s // block], s % block]`` — the PagedAttention
+    indirection as one XLA gather. The heads are unfolded in what was
+    gathered, never in the pool. With ``scale_pool`` ([N', Hkv] fp32)
+    the pool is int8 and the gathered blocks are dequantized to
+    ``dtype`` through the ops/quantizer KV helpers. The engine's gather
+    path and the references below share it."""
+    g = pool[tables]
+    B, nb, bs = g.shape[:3]
+    g = g.reshape(B, nb, bs, n_kv, g.shape[3] // n_kv)
+    if scale_pool is not None:
+        from deepspeed_tpu.ops import quantizer
+        g = quantizer.kv_dequantize_blocks(g, scale_pool[tables],
+                                           dtype=dtype)
+    return g.reshape(B, nb * bs, n_kv, g.shape[4])
 
 
 def paged_decode_reference(q, k_pool, v_pool, tables, lengths, *, scale,
@@ -384,12 +422,8 @@ def paged_decode_reference(q, k_pool, v_pool, tables, lengths, *, scale,
     B, n_kv, group, Dh = q.shape
     bs = k_pool.shape[1]
     nb = tables.shape[1]
-    if k_scale is None:
-        kc = k_pool[tables].reshape(B, nb * bs, n_kv, Dh)
-        vc = v_pool[tables].reshape(B, nb * bs, n_kv, Dh)
-    else:
-        kc = _gather_dequant(k_pool, k_scale, tables, q.dtype)
-        vc = _gather_dequant(v_pool, v_scale, tables, q.dtype)
+    kc = gather_pool_blocks(k_pool, tables, n_kv, k_scale, q.dtype)
+    vc = gather_pool_blocks(v_pool, tables, n_kv, v_scale, q.dtype)
     s = jnp.einsum("bkgd,bskd->bkgs", q, kc).astype(jnp.float32) * scale
     idx = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, nb * bs), 3)
     pos = lengths[:, None, None, None]
@@ -409,12 +443,8 @@ def paged_verify_reference(q, k_pool, v_pool, tables, lengths, *, scale,
     B, G, n_kv, group, Dh = q.shape
     bs = k_pool.shape[1]
     nb = tables.shape[1]
-    if k_scale is None:
-        kc = k_pool[tables].reshape(B, nb * bs, n_kv, Dh)
-        vc = v_pool[tables].reshape(B, nb * bs, n_kv, Dh)
-    else:
-        kc = _gather_dequant(k_pool, k_scale, tables, q.dtype)
-        vc = _gather_dequant(v_pool, v_scale, tables, q.dtype)
+    kc = gather_pool_blocks(k_pool, tables, n_kv, k_scale, q.dtype)
+    vc = gather_pool_blocks(v_pool, tables, n_kv, v_scale, q.dtype)
     s = jnp.einsum("bqkgd,bskd->bkgqs", q, kc).astype(jnp.float32) * scale
     idx = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, 1, nb * bs), 4)
     qpos = lengths[:, None, None, None, None] + jax.lax.broadcasted_iota(

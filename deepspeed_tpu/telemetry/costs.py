@@ -36,6 +36,7 @@ on the charge path, no device sync, zero new compiled programs
 """
 
 import json
+import math
 import re
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -45,6 +46,7 @@ from deepspeed_tpu.utils.jit_registry import (DISPATCH_CLASSES,
 
 __all__ = ["PEAK_FLOPS", "PEAK_HBM_BYTES_PER_S", "device_peak_flops",
            "device_peak_hbm_bytes_per_s", "parse_provenance",
+           "pool_copy_bytes", "shape_dims",
            "matmul_params",
            "model_flops_per_token", "attn_flops", "infer_flops",
            "infer_hbm_bytes", "weight_bytes", "split_even",
@@ -411,6 +413,36 @@ def parse_provenance(hlo_text: str) -> Dict[str, Dict]:
     return out
 
 
+def shape_dims(shape: str) -> Tuple[str, Tuple[int, ...]]:
+    """``bf16[48,1089,16,1600]{3,2,1,0:T(8,128)(2,1)}`` -> ``("bf16",
+    (48, 1089, 16, 1600))``; ``("", ())`` for a tuple or a token."""
+    m = re.match(r"(\w+)\[([\d,]*)\]", shape)
+    if m is None:
+        return "", ()
+    return m.group(1), tuple(int(d) for d in m.group(2).split(",") if d)
+
+
+def pool_copy_bytes(instructions: Dict[str, Dict],
+                    pool_blocks: Sequence[int]) -> int:
+    """Bytes of the ``copy`` instructions of a compiled serving program
+    whose result has the paged KV pool's block count as a dimension
+    (``pool_blocks``: N of one layer and L*N of the stacked layers):
+    each is a re-laying-out of a pool-shaped value that the program
+    runs on EVERY dispatch. 0 is the healthy value: the pool then has
+    one layout from the entry parameter through the layer loop to the
+    kernel (inference/paged_cache.py). ``instructions`` is
+    :func:`parse_provenance`'s table."""
+    blocks = {int(n) for n in pool_blocks}
+    total = 0
+    for ins in instructions.values():
+        dtype, dims = shape_dims(ins["shape"])
+        if ins["opcode"] == "copy" and blocks.intersection(dims):
+            bits = re.search(r"\d+", dtype)       # pred has none: a byte
+            total += math.prod(dims) * (int(bits.group()) // 8
+                                        if bits else 1)
+    return total
+
+
 def provenance_module_name(hlo_text: str) -> str:
     """``jit_serve_decode_slots`` out of ``HloModule jit_serve_...``."""
     m = re.match(r"\s*HloModule\s+([\w.\-]+)", hlo_text)
@@ -437,6 +469,7 @@ class ProgramCostRegistry:
         # program id -> {"module", "instructions"}: filled by
         # add_provenance on a program's first dispatch under telemetry
         self.provenance: Dict[str, Dict] = {}
+        self.metrics = None      # the metrics registry of export_gauges
 
     # .. population .....................................................
 
@@ -513,19 +546,34 @@ class ProgramCostRegistry:
         """Mirror each entry's headline numbers as gauges on a metrics
         registry (``program_flops_<pid>`` / ``program_hbm_bytes_<pid>``
         — declared as wildcard families in the telemetry schema)."""
+        self.metrics = registry
         for pid, e in sorted(self.entries.items()):
             registry.gauge(f"program_flops_{pid}").set(e.get("flops", 0))
             registry.gauge(f"program_hbm_bytes_{pid}").set(
                 e.get("bytes_accessed", 0))
 
-    def add_provenance(self, pid: str, hlo_text: str) -> None:
+    def add_provenance(self, pid: str, hlo_text: str,
+                       pool_blocks: Sequence[int] = ()) -> int:
         """Keep program ``pid``'s provenance table, parsed from the text
         of the executable that is actually loaded (a program restored
         from jax's persistent cache carries the metadata it was first
-        compiled with: the cache does not key on it)."""
+        compiled with: the cache does not key on it). With the paged
+        pool's block counts the program's entry also gains
+        ``pool_copy_bytes`` (:func:`pool_copy_bytes`; also the gauge
+        ``program_pool_copy_bytes_<pid>`` once :meth:`export_gauges`
+        has been given a registry), which is returned."""
+        instructions = parse_provenance(hlo_text)
         self.provenance[pid] = {
             "module": provenance_module_name(hlo_text),
-            "instructions": parse_provenance(hlo_text)}
+            "instructions": instructions}
+        copied = pool_copy_bytes(instructions, pool_blocks)
+        if pool_blocks:
+            self.entries.setdefault(pid, {"program": pid})[
+                "pool_copy_bytes"] = copied
+            if self.metrics is not None:
+                self.metrics.gauge(
+                    f"program_pool_copy_bytes_{pid}").set(copied)
+        return copied
 
     def roofline(self, pid: str, device=None) -> Optional[Dict]:
         """The least seconds the chip could take for program ``pid``'s

@@ -97,21 +97,20 @@ ENGINE_PROGRAM_FAMILIES: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
 #   params : extra positional parameters the twin's signature may add
 #   names  : local/parameter names the feature owns — any statement or
 #            tuple/call element mentioning ONLY these is feature-owned
-#            and stripped before comparison (q: the requantize block's
-#            scale sidecars; l: the gathered-einsum LoRA block)
+#            and stripped before comparison (q: the scale pools beside
+#            the int8 pools; l: the adapter pools and table rows)
 #   kwargs : call keywords the twin may thread through (``k_scale=``,
 #            ``lora_ops=``) that the base never passes
 TWIN_DELTAS = {
     "q": {
         "params": ("k_scale", "v_scale", "ks_blk", "vs_blk"),
-        "names": ("k_scale", "v_scale", "ks_blk", "vs_blk",
-                  "ksp", "vsp", "kss", "vss"),
+        "names": ("k_scale", "v_scale", "ks_blk", "vs_blk"),
         "kwargs": ("k_scale", "v_scale"),
     },
     "l": {
         "params": ("lora_a", "lora_b", "ablocks", "ablock_row"),
         "names": ("lora_a", "lora_b", "ablocks", "ablock_row",
-                  "la", "lb", "lora", "lora_ops"),
+                  "lora", "lora_ops"),
         "kwargs": ("lora", "lora_ops"),
     },
 }

@@ -69,7 +69,7 @@ def test_flash_masks_segments_and_windows_lower():
 @pytest.mark.parametrize("H,Hkv,D,blk", SHAPES)
 def test_paged_attention_lowers(H, Hkv, D, blk, q_len, quant):
     B, bs, nb, N = 8, 16, 32, 257
-    pool = S((N, bs, Hkv, D), jnp.int8 if quant else jnp.bfloat16)
+    pool = S((N, bs, Hkv * D), jnp.int8 if quant else jnp.bfloat16)
     scales = {"k_scale": S((N, Hkv), jnp.float32),
               "v_scale": S((N, Hkv), jnp.float32)} if quant else {}
     tables, lengths = S((B, nb), jnp.int32), S((B,), jnp.int32)

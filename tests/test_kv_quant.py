@@ -197,7 +197,8 @@ def test_paged_hbm_bytes_per_token_dtype_aware():
 def _quant_pool_problem(seed=0, B=3, Hkv=2, group=2, Dh=32, bs=8, NB=4):
     """fp pools + their int8 twins with per-(block, kv_head) scales;
     same distinct-table/trash-block-0 geometry as test_paged_attention's
-    _pool_problem."""
+    _pool_problem. Blocks are quantized per head and then folded to the
+    stored ``[N, block, Hkv*Dh]`` rows."""
     rng = np.random.default_rng(seed)
     N = B * NB + 1
     q = jnp.asarray(rng.normal(size=(B, Hkv, group, Dh)), jnp.float32)
@@ -208,6 +209,7 @@ def _quant_pool_problem(seed=0, B=3, Hkv=2, group=2, Dh=32, bs=8, NB=4):
     lengths = jnp.asarray([bs // 2, bs * 2 + 1, bs * NB - 1], jnp.int32)
     kq, ks = kv_requantize_blocks(kp)
     vq, vs = kv_requantize_blocks(vp)
+    kp, vp, kq, vq = (a.reshape(N, bs, Hkv * Dh) for a in (kp, vp, kq, vq))
     return q, kp, vp, kq, ks, vq, vs, tables, lengths
 
 

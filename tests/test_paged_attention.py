@@ -38,14 +38,15 @@ def prompts_of(lengths, seed=1):
 
 
 def _pool_problem(seed=0, B=3, Hkv=2, group=2, Dh=32, bs=8, NB=4):
-    """Random pools + per-slot DISTINCT block tables (trash block 0 kept
-    out of every table) + lengths hitting a partial block, a mid block
-    and the last slot of the last block."""
+    """Random pools ``[N, block, Hkv*Dh]`` (heads folded into the rows,
+    as the paged cache stores them) + per-slot DISTINCT block tables
+    (trash block 0 kept out of every table) + lengths hitting a partial
+    block, a mid block and the last slot of the last block."""
     rng = np.random.default_rng(seed)
     N = B * NB + 1
     q = jnp.asarray(rng.normal(size=(B, Hkv, group, Dh)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(N, bs, Hkv, Dh)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(N, bs, Hkv, Dh)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(N, bs, Hkv * Dh)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(N, bs, Hkv * Dh)), jnp.float32)
     ids = rng.permutation(np.arange(1, N))
     tables = jnp.asarray(ids.reshape(B, NB), jnp.int32)
     lengths = jnp.asarray([bs // 2, bs * 2 + 1, bs * NB - 1], jnp.int32)
@@ -108,8 +109,8 @@ def test_paged_kernel_no_dense_gather(devices):
     pool[tables] (the reference path's first op)."""
     q, kp, vp, tables, lengths = _pool_problem()
     B, NB = tables.shape
-    bs, Hkv, Dh = kp.shape[1], kp.shape[2], kp.shape[3]
-    dense = (B, NB, bs, Hkv, Dh)
+    bs, row = kp.shape[1], kp.shape[2]
+    dense = (B, NB, bs, row)
 
     def gathers(fn):
         jaxpr = jax.make_jaxpr(fn)(q, kp, vp, tables, lengths)
@@ -240,8 +241,8 @@ def test_engine_masks_capacity_overflow_write(devices, pallas_interpret,
     N = 8
     L, Hkv, Dh = cfg.n_layers, cfg.kv_heads, cfg.head_dim
     rng = np.random.default_rng(0)
-    kp = jnp.asarray(rng.normal(size=(L, N, bs, Hkv, Dh)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(L, N, bs, Hkv, Dh)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(L, N, bs, Hkv * Dh)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(L, N, bs, Hkv * Dh)), jnp.float32)
     tables = np.zeros((2, NB), np.int32)
     tables[0] = [1, 2, 3]
     tables[1] = [4, 5, 6]
@@ -252,9 +253,13 @@ def test_engine_masks_capacity_overflow_write(devices, pallas_interpret,
                                  np.array([3, 4], np.int32), active,
                                  impl=impl)
     # every block slot 0 owns is untouched (the overflow write went to
-    # trash block 0); slot 1's current position DID get written
+    # each layer's trash block 0, and to no other layer's blocks);
+    # slot 1's current position DID get written
     np.testing.assert_array_equal(np.asarray(k2)[:, 1:4],
                                   np.asarray(kp)[:, 1:4])
+    assert not np.array_equal(np.asarray(k2)[:, 0], np.asarray(kp)[:, 0])
+    np.testing.assert_array_equal(np.asarray(k2)[:, 6:],
+                                  np.asarray(kp)[:, 6:])
     assert not np.array_equal(np.asarray(k2)[:, 5, 1],
                               np.asarray(kp)[:, 5, 1])
     assert not np.array_equal(np.asarray(v2)[:, 5, 1],

@@ -1,0 +1,193 @@
+"""One HBM layout for the paged KV pool, read off the compiled programs.
+
+The two serving programs of the benchmark's cells are compiled ahead of
+time for a TPU v5e from this CPU host (libtpu compiles for a described
+topology without a chip), at the GPT-2 XL cell's own sizes with abstract
+arguments, and the executable is asked what the mechanism is: does any
+``copy`` hold a pool-shaped value (``pool_copy_bytes``), does the layer
+loop slice a layer's pool out or write it back, and how large are the
+program's temporaries. Before the pools were folded to ``Hkv*Dh`` rows
+and carried through the layer loop, ``serve_decode_slots`` read 5.9 GB of
+pool copies and 6.24 GB of temporaries here, and the chip spent 68-74% of
+its serving time in them (PERF.md, PR 25).
+"""
+
+import json
+import pathlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from deepspeed_tpu.inference.engine import InferenceEngine, _named
+from deepspeed_tpu.models import gpt
+from deepspeed_tpu.telemetry.costs import (ProgramCostRegistry,
+                                           parse_provenance,
+                                           pool_copy_bytes, probe_compiled,
+                                           shape_dims)
+
+CELL = json.loads((pathlib.Path(__file__).parent.parent / "benchmark"
+                   / "configs" / "gpt2-xl-serve.json").read_text())
+
+# what is left is the token embedding, re-laid once per dispatch for the
+# lookup (161 MB; its stored layout is the logits matmul's), and
+# activations: nothing of the pool's size (one layer's K pool is 56 MB,
+# the stacked pools 5.4 GB)
+TEMP_LIMIT = 256 << 20
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One described (not attached) v5e chip to compile for."""
+    with pytest.MonkeyPatch.context() as mp:
+        # libtpu reads these when the topology is described
+        mp.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+        mp.setenv("TPU_WORKER_HOSTNAMES", "localhost")
+        mp.setenv("TPU_SKIP_MDS_QUERY", "1")
+        try:
+            from jax.experimental import topologies
+            dev = topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:2x2").devices[0]
+        except Exception as e:  # no libtpu, or one that cannot describe it
+            pytest.skip(f"no v5e topology to compile for: {e}")
+    return jax.sharding.SingleDeviceSharding(dev)
+
+
+def _cell_programs(sharding):
+    """(name, jitted program, abstract arguments, (N, L*N)) for the plain
+    prefill and decode programs at the cell's sizes. The engine is a
+    skeleton: the programs read its configuration and take the weights
+    as an argument, so nothing of GPT-2 XL's size is ever allocated."""
+    m, sv = CELL["model"], CELL["serving"]
+    cfg = gpt.GPTConfig(vocab_size=m["vocab_size"], n_layers=m["n_layer"],
+                        n_heads=m["n_head"], d_model=m["n_embd"],
+                        max_seq_len=m["n_positions"],
+                        use_flash_attention=False, remat=False,
+                        dtype=jnp.bfloat16)
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    params = jax.tree_util.tree_map(
+        lambda a: S(a.shape, jnp.bfloat16
+                    if jnp.issubdtype(a.dtype, jnp.floating) else a.dtype),
+        jax.eval_shape(lambda: gpt.init_params(jax.random.PRNGKey(0), cfg)))
+    eng = InferenceEngine.__new__(InferenceEngine)
+    eng.cfg, eng.max_seq_len, eng.dtype = cfg, cfg.max_seq_len, jnp.bfloat16
+
+    L, N, bs = cfg.n_layers, sv["num_blocks"] + 1, sv["block_size"]
+    B, C, V = sv["num_slots"], sv["prefill_chunk"], cfg.vocab_size
+    NB = cfg.max_seq_len // bs
+    pool = S((L, N, bs, cfg.kv_heads * cfg.head_dim), jnp.bfloat16)
+    i32, f32, u32 = jnp.int32, jnp.float32, jnp.uint32
+    prefill = jax.jit(_named(eng._prefill_slot_fn, "serve_prefill_slot"),
+                      donate_argnums=(1, 2))
+    decode = jax.jit(_named(eng._decode_slots_fn, "serve_decode_slots"),
+                     donate_argnums=(1, 2), static_argnums=(7,))
+    return (L, N), [
+        ("prefill_slot", prefill,
+         (params, pool, pool, S((NB,), i32), S((C,), i32), S((), i32),
+          S((), i32), S((2,), u32), S((), i32), S((), f32), S((), i32),
+          S((), f32), S((), f32), S((V,), jnp.bool_))),
+        ("decode_slots", decode,
+         (params, pool, pool, S((B, NB), i32), S((B,), i32), S((B,), i32),
+          S((B,), jnp.bool_), "pallas", S((B, 2), u32), S((B,), i32),
+          S((B,), f32), S((B,), i32), S((B,), f32), S((B,), f32),
+          S((B, V), jnp.bool_)))]
+
+
+@pytest.fixture(scope="module")
+def compiled_cell(v5e):
+    (L, N), programs = _cell_programs(v5e)
+    out = {}
+    for name, fn, args in programs:
+        exe = fn.trace(*args).lower(lowering_platforms=("tpu",)).compile()
+        out[name] = (exe, parse_provenance(exe.as_text()))
+    return (L, N), out
+
+
+@pytest.mark.parametrize("program", ["prefill_slot", "decode_slots"])
+def test_no_copy_of_the_pool_is_compiled_in(compiled_cell, program):
+    (L, N), exes = compiled_cell
+    _, table = exes[program]
+    assert pool_copy_bytes(table, (N, L * N)) == 0
+    # nor is a layer's pool sliced out of the stack or written back
+    pooled = {n: e for n, e in table.items()
+              if {N, L * N} & set(shape_dims(e["shape"])[1])}
+    assert pooled, "no pool-shaped instruction: the shapes moved"
+    moved = [n for n, e in pooled.items()
+             if e["opcode"] in ("copy", "dynamic-slice",
+                                "dynamic-update-slice")
+             or "dynamic-slice" in n or "dynamic-update-slice" in n]
+    assert not moved, moved
+    # parameter ([L, N, ...]), loop state and the kernel's operand
+    # ([L*N, ...]) share ONE layout: row-major, one tiling
+    tilings = set()
+    for e in pooled.values():
+        if not e["shape"].startswith("bf16["):
+            continue
+        order, tiling = e["shape"].split("{", 1)[1].split("}")[0] \
+            .split("S(")[0].split(":")
+        order = [int(d) for d in order.split(",")]
+        assert order == sorted(order, reverse=True), e["shape"]
+        tilings.add(tiling)
+    assert len(tilings) == 1, tilings
+
+
+@pytest.mark.parametrize("program", ["prefill_slot", "decode_slots"])
+def test_no_pool_sized_temporary(compiled_cell, program):
+    exe, _ = compiled_cell[1][program]
+    mem = probe_compiled(exe)
+    assert mem["peak_bytes"] < TEMP_LIMIT, mem
+    # the donated pools are updated in place: the outputs alias them
+    sv = CELL["serving"]            # pool_bytes_logical: K and V, no trash
+    pool_bytes = sv["pool_bytes_logical"] // sv["num_blocks"] \
+        * (sv["num_blocks"] + 1)
+    assert exe.memory_analysis().alias_size_in_bytes >= pool_bytes
+
+
+def test_decode_program_attends_through_the_mosaic_kernel(compiled_cell):
+    _, table = compiled_cell[1]["decode_slots"]
+    assert any(n.startswith("paged_decode") and e["opcode"] == "custom-call"
+               for n, e in table.items())
+
+
+def test_pool_copy_bytes_counts_pool_shaped_copies_only():
+    table = {
+        "copy.40": {"opcode": "copy",
+                    "shape": "bf16[1,1089,16,25,64]{4,3,2,1,0:T(8,128)(2,1)}"},
+        "copy.67": {"opcode": "copy",
+                    "shape": "bf16[48,1089,16,25,64]{1,4,3,2,0:T(8,128)(2,1)}"},
+        "copy.26": {"opcode": "copy", "shape": "f32[52272,25]{0,1:T(8,128)}"},
+        "copy.14": {"opcode": "copy", "shape": "bf16[50257,1600]{1,0}"},
+        "fusion.191": {"opcode": "fusion",
+                       "shape": "bf16[52272,16,1600]{2,1,0}"},
+        "tuple.1": {"opcode": "tuple", "shape": "(s32[], bf16[1089,16])"},
+    }
+    one = 1089 * 16 * 25 * 64 * 2
+    assert pool_copy_bytes(table, (1089, 52272)) \
+        == one + 48 * one + 52272 * 25 * 4
+    assert pool_copy_bytes(table, ()) == 0
+    assert pool_copy_bytes({}, (1089, 52272)) == 0
+
+
+def test_registry_records_pool_copy_bytes_with_the_provenance():
+    from deepspeed_tpu.telemetry.metrics import MetricsRegistry
+    text = """HloModule jit_serve_decode_slots
+
+ENTRY %main.1 (p0: bf16[4,9,4,32]) -> bf16[4,9,4,32] {
+  %p0 = bf16[4,9,4,32]{3,2,1,0} parameter(0)
+  ROOT %copy.1 = bf16[4,9,4,32]{1,3,2,0} copy(%p0)
+}
+"""
+    reg = ProgramCostRegistry()
+    metrics = MetricsRegistry()
+    reg.export_gauges(metrics)
+    assert reg.add_provenance("decode_slots", text, pool_blocks=(9, 36)) \
+        == 4 * 9 * 4 * 32 * 2
+    assert reg.to_json()["programs"]["decode_slots"]["pool_copy_bytes"] \
+        == 9216
+    assert metrics.gauge("program_pool_copy_bytes_decode_slots").value == 9216
+    # without the pool's block counts nothing is claimed
+    assert reg.add_provenance("cow_blocks", text) == 0
+    assert "pool_copy_bytes" not in reg.entries.get("cow_blocks", {})

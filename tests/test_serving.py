@@ -161,8 +161,8 @@ def test_serving_parity_rotary_gqa_window(devices):
                    for i, p in enumerate(prompts)])
     for i, ref in enumerate(refs):
         np.testing.assert_array_equal(out[i], ref)
-    # GQA pool really is grouped: kv-head dim == 2
-    assert srv.cache.k.shape[3] == 2
+    # GQA pool really is grouped: a row holds 2 kv heads, not 4
+    assert srv.cache.k.shape[3] == 2 * cfg.head_dim
 
 
 def test_serving_prefill_chunking_long_prompt(devices):

@@ -203,7 +203,7 @@ def test_spec_serving_parity_rotary_gqa_window(devices):
         np.testing.assert_array_equal(off[i], on[i])
 
 
-def test_spec_serving_parity_pallas(devices):
+def test_spec_serving_parity_pallas(devices, pallas_interpret):
     """Parity holds through the pallas verify kernel (interpret mode on
     CPU): the q_len>1 grid dimension scores the same chunk the gather
     reference does."""

@@ -157,8 +157,10 @@ def ring_block_rows():
 
 
 def _paged_inputs(r, B, Hkv, D, bs, nb, quant):
-    """Pools with every slot's blocks filled, tables that scatter them
-    over the pool, and lengths from one token to the last position."""
+    """Pools ``[N, block, Hkv*D]`` (heads folded into the rows, as the
+    paged cache stores them) with every slot's blocks filled, tables
+    that scatter them over the pool, and lengths from one token to the
+    last position."""
     from deepspeed_tpu.ops import quantizer
     N = B * nb + 1
     k, v = _rand(r, (N, bs, Hkv, D)), _rand(r, (N, bs, Hkv, D))
@@ -166,11 +168,14 @@ def _paged_inputs(r, B, Hkv, D, bs, nb, quant):
         1 + r.permutation(B * nb).reshape(B, nb), jnp.int32)
     lengths = jnp.asarray(
         np.linspace(0, nb * bs - 8, B).astype(np.int32))
+    def fold(a):
+        return a.reshape(N, bs, Hkv * D)
     if not quant:
-        return k, v, tables, lengths, {}
+        return fold(k), fold(v), tables, lengths, {}
     kq, ks = quantizer.kv_requantize_blocks(k)
     vq, vs = quantizer.kv_requantize_blocks(v)
-    return kq, vq, tables, lengths, {"k_scale": ks, "v_scale": vs}
+    return fold(kq), fold(vq), tables, lengths, \
+        {"k_scale": ks, "v_scale": vs}
 
 
 def paged_rows():
