@@ -17,7 +17,7 @@ import numpy as np
 from harness import spans as spans_lib
 from harness import traffic as traffic_lib
 from harness import weights
-from harness.stats import pct
+from harness.stats import binned, pct, request_mean_gaps_ms
 
 TERMINAL = ("done", "timeout", "shed", "error")
 # Served logits (bf16 weights and activations, fp32 accumulation, 48 layers
@@ -28,17 +28,24 @@ TERMINAL = ("done", "timeout", "shed", "error")
 # that on the worst of 50,257 logits. Measured on the chip: see PERF.md.
 LOGIT_TOL_ABS = 0.25
 CHECK_REQUESTS = ((72, 6), (150, 5))     # (prompt, answer) tokens
+# After the window: this many of the requests it finished (the longest and
+# a seeded draw of the others) go through the reference once each, prompt
+# and served tokens together (PERF.md section 4 has the readings the
+# configuration's `check.served_gap_limit` was set from).
+SAMPLE_REQUESTS = 6
 FIRST_TOKEN_DRAIN_S = 15.0
 
 
 class _Req:
     """The benchmark's own record of one request."""
-    __slots__ = ("req", "due", "submitted", "seen", "first", "last")
+    __slots__ = ("req", "due", "submitted", "seen", "first", "last",
+                 "gaps", "done")
 
     def __init__(self, req, due):
         self.req, self.due = req, due
-        self.submitted = self.first = self.last = None
+        self.submitted = self.first = self.last = self.done = None
         self.seen = 0
+        self.gaps = []      # (earlier stamp, later stamp) of its own tokens
 
 
 def _gpt_config(hp, dtype):
@@ -51,7 +58,8 @@ def _gpt_config(hp, dtype):
 
 def build(ctx):
     """Weights, engine, pool, instrumentation and the checked warm-up.
-    Returns (srv, log, counts, setup items, correct)."""
+    Returns (srv, log, counts, setup items, correct, compared, params);
+    ``compared`` is [(name, value, limit)]."""
     t_imp = time.perf_counter()
     import deepspeed_tpu
     from deepspeed_tpu.inference.serving import ServeRequest, ServingEngine
@@ -73,7 +81,10 @@ def build(ctx):
     srv = ServingEngine(eng, num_slots=int(sv["num_slots"]),
                         block_size=int(sv["block_size"]),
                         num_blocks=int(sv["num_blocks"]),
-                        prefill_chunk=int(sv["prefill_chunk"]))
+                        prefill_chunk=int(sv["prefill_chunk"]),
+                        # a traced run carries the program's own spans
+                        # (serve.*) and provenance; end-to-end runs do not
+                        telemetry=bool(ctx.trace))
     jax.block_until_ready((srv.cache.k, srv.cache.v))
     setup["engine_s"] = clock() - t
     say(info="serving_engine", decode_impl=srv.decode_impl,
@@ -141,7 +152,9 @@ def build(ctx):
     say(info="correctness", **detail)
     log.spans.clear()
     counts["prefill_tokens"].clear()
-    return srv, log, counts, setup, correct
+    compared = [("warmup_max_abs_logit_error",
+                 detail["max_abs_logit_error"], LOGIT_TOL_ABS)]
+    return srv, log, counts, setup, correct, compared, params
 
 
 def drive(ctx, srv, log, counts, mix, seconds, rng, trace=False):
@@ -176,7 +189,7 @@ def drive(ctx, srv, log, counts, mix, seconds, rng, trace=False):
                for i, (due, p, a) in enumerate(plan)]
 
     tracked = {}
-    out_tokens, gaps, kv_used = [], [], []
+    out_tokens, kv_used = [], []
     finished = [0]
 
     def harvest(t_now):
@@ -188,10 +201,11 @@ def drive(ctx, srv, log, counts, mix, seconds, rng, trace=False):
                 if rec.last is None:
                     rec.first = t_now
                 else:
-                    gaps.append((rec.last, t_now))
+                    rec.gaps.append((rec.last, t_now))
                 rec.last = t_now
             rec.seen = n
             if rec.req.state in TERMINAL:
+                rec.done = t_now
                 finished[0] += 1
                 del tracked[key]
 
@@ -279,7 +293,8 @@ def drive(ctx, srv, log, counts, mix, seconds, rng, trace=False):
     sub = records[:nxt]
     in_win = [r for r in sub if ws <= t0 + r.due < we] if not closed else sub
     failed = sum(r.req.state in ("timeout", "shed", "error") for r in sub)
-    itl = [(b - a) * 1e3 for a, b in gaps if a >= ws and b <= t_end]
+    itl = [(b - a) * 1e3 for r in sub for a, b in r.gaps
+           if a >= ws and b <= t_end]
     t_drained = clock()
     # a request still without its first token counts with the wait so far
     ttft = [((r.first if r.first is not None else t_drained)
@@ -292,32 +307,78 @@ def drive(ctx, srv, log, counts, mix, seconds, rng, trace=False):
     kv_peak = max([u for t, u in kv_used if ws <= t <= t_end] or [0])
     kv_block_bytes = 2 * int(hp["n_layer"]) * int(hp["n_embd"]) * 2 \
         * srv.cache.block_size
+    tpot = request_mean_gaps_ms([(r.done, r.gaps) for r in sub], ws, t_end)
+    work = counts["prefill_tokens"] + [(x, 1) for x in out_tokens]
+    decodes = [(s[2], s[3][0]) for s in log.named("decode_dispatch",
+                                                  ws, t_end)]
     e2e = {}
     if itl:
         e2e["itl_p95_ms"] = pct(itl, 95)
         # the mean of the same gaps moves with any shift in the mix of
-        # steps, where the percentile moves by a whole chunk or not at all;
-        # it repeats within 1.3% only, so it is listed per layer (PERF.md 2)
+        # steps, where the percentile moves by a whole chunk or not at all
         e2e["itl_mean_ms"] = sum(itl) / len(itl)
+    if tpot:
+        e2e["tpot_p90_ms"] = pct(tpot, 90)
     if not closed and ttft:
         e2e["ttft_p50_ms"] = pct(ttft, 50)
     e2e["serve_tok_s"] = (prefilled + emitted) / (t_end - ws)
-    say(info="window", seconds=t_end - ws, requests_submitted=len(sub),
+    step_s = log.total("step", ws, t_end)
+    disp_s = {n: log.total(n, ws, t_end)
+              for n in ("prefill_dispatch", "decode_dispatch")}
+    # a stall shows here: the longest steps (when, how long, how much of it
+    # inside the dispatches) and the longest stretches between two steps
+    steps = log.named("step", ws, t_end)
+    n_steps = len(steps)
+    longest_steps = [
+        [a - ws, 1e3 * (b - a), 1e3 * sum(
+            s[2] - s[1] for s in log.spans
+            if s[0].endswith("_dispatch") and a <= s[1] and s[2] <= b)]
+        for _, a, b, _ in sorted(steps, key=lambda s: s[1] - s[2])[:3]]
+    between = sorted(((b[1] - a[2], a[2] - ws)
+                      for a, b in zip(steps, steps[1:])), reverse=True)[:3]
+    row = dict(
+        seconds=t_end - ws, requests_submitted=len(sub),
         requests_due_in_window=len(in_win), requests_finished=finished[0],
+        requests_finished_in_window=len(tpot),
         token_gaps=len(itl), prompt_tokens_prefilled=prefilled,
         output_tokens=emitted, compiles_inside=compiled_inside,
+        queued_at_end=queued_at_end, unfinished_at_end=unfinished_at_end,
         itl_mean_ms=e2e.get("itl_mean_ms"),
         itl_p50_ms=pct(itl, 50), itl_p90_ms=pct(itl, 90),
         itl_p95_ms=pct(itl, 95), itl_p99_ms=pct(itl, 99),
         itl_p92_to_p98_ms=[pct(itl, q) for q in range(92, 99)],
+        tpot_p50_ms=pct(tpot, 50), tpot_p90_ms=pct(tpot, 90),
         ttft_p50_ms=pct(ttft, 50) if not closed else None,
         ttft_p95_ms=pct(ttft, 95) if not closed else None,
+        serve_tok_s=e2e["serve_tok_s"],
         output_tok_s=emitted / (t_end - ws),
-        steps=len(log.named("step", ws, t_end)),
+        steps=n_steps,
         gen_late_p99_ms=pct(late, 99) if not closed else None,
+        # slots decoding per decode dispatch: over the window, and per 5 s
+        # of it (the first bin against the rest shows whether the ramp was
+        # long enough)
+        decode_occupancy=(sum(n for _, n in decodes) / len(decodes)
+                          if decodes else None),
+        decode_occupancy_per_5s=binned(decodes, ws, t_end, 5.0, mean=True),
+        # the closed loop's phase (work and admissions over time), and
+        # where a step's time goes
+        tok_s_per_5s=binned(work, ws, t_end, 5.0),
+        submitted_per_5s=binned([(r.submitted, 1) for r in sub], ws, t_end,
+                                5.0),
+        prefill_share_of_step_s=disp_s["prefill_dispatch"] / step_s
+        if step_s else None,
+        prefill_dispatch_ms_p50=pct([(s[2] - s[1]) * 1e3 for s in log.named(
+            "prefill_dispatch", ws, t_end)], 50),
+        decode_dispatch_ms_p50=pct([(s[2] - s[1]) * 1e3 for s in log.named(
+            "decode_dispatch", ws, t_end)], 50),
+        host_outside_dispatch_ms_per_step=1e3 * (
+            step_s - sum(disp_s.values())) / n_steps if n_steps else None,
+        longest_steps_at_s_ms_dispatched_ms=longest_steps,
+        longest_between_steps_ms_at_s=[[1e3 * d, at] for d, at in between],
         kv_blocks_peak=kv_peak, kv_blocks_pool=srv.cache.num_blocks - 1,
         kv_bytes_filled_peak=kv_peak * kv_block_bytes,
         kv_bytes_pool=(srv.cache.num_blocks - 1) * kv_block_bytes)
+    say(info="window", **row)
     if itl:
         hist = np.bincount(np.minimum((np.asarray(itl) / 20.0).astype(int),
                                       60))
@@ -332,6 +393,8 @@ def drive(ctx, srv, log, counts, mix, seconds, rng, trace=False):
         "head_dim": int(hp["n_embd"]) // int(hp["n_head"]),
         "layers": int(hp["n_layer"]),
         "num_slots": srv.num_slots,
+        # the program's span ring (perf_counter), with telemetry on
+        "tracer": srv.telemetry.tracer if srv.telemetry.enabled else None,
     }
     return {
         "correct": bool(compiled_inside == 0),
@@ -339,16 +402,95 @@ def drive(ctx, srv, log, counts, mix, seconds, rng, trace=False):
         "end_to_end": e2e, "setup_items": setup, "window_start": ws,
         "backlog_at_end": queued_at_end, "seconds": t_end - ws,
         "unfinished_at_end": unfinished_at_end,
-        "run": run,
+        "window_row": row, "run": run,
+        "compiles_inside": compiled_inside,
+        # requests the window finished, for the check after it
+        "finished_in_window": [r.req for r in sub if r.done is not None
+                               and ws <= r.done <= t_end
+                               and r.req.state == "done" and r.req.out],
     }
 
 
+def sample_finished(reqs, seed, k=SAMPLE_REQUESTS):
+    """``k`` of the finished requests: the longest (prompt plus answer) and
+    a draw from ``seed`` of the others, in their order."""
+    if not reqs:
+        return []
+    longest = max(range(len(reqs)),
+                  key=lambda i: len(reqs[i].prompt) + len(reqs[i].out))
+    rest = [i for i in range(len(reqs)) if i != longest]
+    rng = np.random.default_rng([int(seed), 2])
+    drawn = rng.choice(len(rest), size=min(k - 1, len(rest)), replace=False)
+    return [reqs[longest]] + [reqs[rest[i]] for i in sorted(drawn)]
+
+
+def served_token_gaps(reqs, params, hp, reference, chosen=None):
+    """For every served token of ``reqs``: how far its logit lies below the
+    reference's best logit at that position (0 where the reference puts the
+    same token first). The reference runs once per request over the prompt
+    with its served tokens, padded to the model's positions (it is causal,
+    so the padding changes nothing before it; one shape, one compile).
+    ``chosen(padded tokens [1, S], first, end) -> tokens [end - first]``
+    puts other tokens in the served ones' place (the control's: see
+    ``tools/serve_check_control.py``).
+    Returns {rid: float32 gaps, one per served token}."""
+    n_head, n_pos = int(hp["n_head"]), int(hp["n_positions"])
+    out = {}
+    for r in reqs:
+        toks = np.concatenate([np.asarray(r.prompt, np.int32),
+                               np.asarray(r.out, np.int32)])
+        padded = np.zeros((1, n_pos), np.int32)
+        padded[0, :len(toks) - 1] = toks[:-1]
+        ref = reference.logits(params, jnp.asarray(padded), n_head)[0]
+        # position j predicts token j + 1: the served ones are the last
+        first = len(r.prompt) - 1
+        at = ref[first:len(toks) - 1]
+        served = jnp.asarray(toks[first + 1:]) if chosen is None \
+            else chosen(padded, first, len(toks) - 1)
+        gap = at.max(-1) - jnp.take_along_axis(at, served[:, None], -1)[:, 0]
+        out[r.rid] = np.asarray(gap, np.float32)
+    return out
+
+
 def run(ctx):
-    srv, log, counts, setup, correct = build(ctx)
+    srv, log, counts, setup, correct, compared, params = build(ctx)
     res = drive(ctx, srv, log, counts, ctx.cell.traffic, ctx.seconds,
                 np.random.default_rng([ctx.seed, 1]), trace=ctx.trace)
+    compared.append(("compiles_inside_window", res["compiles_inside"], 0))
     res["correct"] = bool(res["correct"] and correct)
     res["setup_items"] = dict(setup, **res["setup_items"])
+    res["compared"] = compared
+    cell, hp = ctx.cell, ctx.cell.config["model"]
+    limit = float(cell.config["check"]["served_gap_limit"])
+    sample = sample_finished(res.pop("finished_in_window"), ctx.seed)
+    pools = (srv.cache.k, srv.cache.v)
+    del srv
+
+    def after_window():
+        """Once ``memory_peak_bytes`` has been read: frees the KV pools and
+        holds the sample's served tokens to the reference. Returns (ok,
+        [(name, value, limit)])."""
+        t = time.perf_counter()
+        for a in pools:
+            a.delete()
+        gaps = served_token_gaps(sample, params, hp, cell.reference())
+        tokens = int(sum(len(g) for g in gaps.values()))
+        worst = max([float(g.max()) for g in gaps.values()] or [float("nan")])
+        ok = bool(tokens > 0 and worst <= limit)
+        ctx.say(info="correctness_after_window", requests=len(gaps),
+                served_tokens_compared=tokens,
+                longest_request_tokens=max(
+                    [len(r.prompt) + len(r.out) for r in sample] or [0]),
+                served_gap_max=worst, limit=limit,
+                tokens_not_the_references_first=int(sum(
+                    (g > 0).sum() for g in gaps.values())),
+                gap_max_by_request={str(k): float(g.max())
+                                    for k, g in gaps.items()},
+                reference_s=time.perf_counter() - t, ok=ok)
+        return ok, [("served_tokens_compared", tokens, ">0"),
+                    ("served_gap_max", worst, limit)]
+
+    res["after_window"] = after_window
     return res
 
 

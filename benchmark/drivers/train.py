@@ -222,4 +222,17 @@ def run(ctx):
     return {"correct": bool(correct), "attempted": n_steps,
             "failed": int(sum(not f for f in finite)),
             "end_to_end": e2e, "setup_items": setup, "window_start": ws,
+            # every number compared, beside its limit (run.py prints them)
+            "compared": [
+                ("forward_max_abs_logit_error",
+                 forward_detail["max_abs_logit_error"], LOGIT_TOL_MAX_ABS),
+                ("forward_rms_logit_error",
+                 forward_detail["rms_logit_error"], LOGIT_TOL_RMS),
+                ("forward_max_abs_token_loss_error",
+                 forward_detail["max_abs_token_loss_error"],
+                 TOKEN_LOSS_TOL_ABS),
+                ("first_step_loss_abs_error", loss_err, LOSS_TOL_ABS),
+                ("non_finite_losses", int(sum(not f for f in finite)), 0),
+                ("last_loss_minus_first", losses[-1] - losses[0], "<0"),
+                ("compiles_inside_window", compiled_inside, 0)],
             "run": run}

@@ -453,11 +453,16 @@ def say_breakdown(run, pt: ProgramTrace):
         run["say"](info="program_spans", **dispatch)
 
 
+RELAYOUT_OPCODES = ("copy", "reshape")
+
+
 def is_kv_relayout(entry, pool_dims) -> bool:
-    """A ``copy`` in (or inferred into) the paged cache's write, gather or
-    attention scope, or one whose result is one layer's whole pool (or
-    the layers' pools stacked)."""
-    if entry is None or entry["opcode"] != "copy":
+    """A ``copy`` or a ``reshape`` (one that runs on the device moves every
+    byte, as a copy does: a free one is a bitcast and has no event) in, or
+    inferred into, the paged cache's write, gather or attention scope, or
+    one whose result is one layer's whole pool (or the layers' pools
+    stacked)."""
+    if entry is None or entry["opcode"] not in RELAYOUT_OPCODES:
         return False
     if any(part in KV_SCOPES for part in entry["scope"].split("/")):
         return True
@@ -465,8 +470,8 @@ def is_kv_relayout(entry, pool_dims) -> bool:
 
 
 def kv_relayout_share(run):
-    """Device seconds of the KV pool's relayout copies over busy seconds,
-    %."""
+    """Device seconds of the KV pool's relayout copies and reshapes over
+    busy seconds, %."""
     pt = of_run(run)
     if pt is None or run.get("kind") != "serve" or not pt.tables:
         return None
@@ -509,6 +514,16 @@ def remat_time_share(run):
     run["say"](info="remat", checkpoint_recompute_s=jax_s,
                xla_rematerialised_s=all_s - jax_s, busy_s=busy)
     return 100.0 * all_s / busy
+
+
+def dispatch_idle_ms(run):
+    """Device idle time inside the program's ``serve.dispatch`` spans, per
+    dispatch seen whole in the traced tail, ms: what the host costs the
+    device at every launch. None without a device trace or the spans."""
+    pt = of_run(run)
+    if pt is None or run.get("kind") != "serve":
+        return None
+    return program_span_summary(pt).get("dispatch_idle_ms")
 
 
 def program_span_summary(pt: ProgramTrace):

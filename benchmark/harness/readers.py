@@ -144,3 +144,16 @@ def train_peak_hbm_share(run):
     if run.get("kind") != "train" or not run.get("memory_limit_bytes"):
         return None
     return 100.0 * run["memory_peak_bytes"] / run["memory_limit_bytes"]
+
+
+def program_span_median_ms(run, name):
+    """Median of the program's OWN span ``name`` over the host window, ms,
+    from its tracer's ring (records start with the start stamp and carry
+    the end stamp at index 6, on ``perf_counter``: telemetry/tracer.py).
+    None with telemetry off (an end-to-end run) or with no such span."""
+    tracer = run.get("tracer")
+    if tracer is None:
+        return None
+    h0, h1 = run["host_window"]
+    return median([(r[6] - r[0]) * 1e3 for r in tracer.spans(name)
+                   if r[0] >= h0 and r[6] <= h1])

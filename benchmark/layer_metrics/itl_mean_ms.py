@@ -1,5 +1,5 @@
-"""Mean gap between consecutive output tokens over the whole window: the
-same gaps as `itl_p95_ms`, off the staircase that percentile sits on."""
+"""Mean gap between consecutive output tokens over the whole window: every
+gap of every request, where `tpot_p90_ms` takes the tail over requests."""
 
 
 def read(run):

@@ -33,7 +33,8 @@ ROOT = os.path.dirname(BENCH_DIR)
 sys.path.insert(0, BENCH_DIR)
 sys.path.insert(0, ROOT)
 
-from harness import cells, peaks, rooflines, spans, tracereduce  # noqa: E402
+from harness import (cells, peaks, provenance, rooflines, spans,  # noqa: E402
+                     tracereduce)
 
 TRACE_SECONDS = 6.0     # serving: the traced tail of the window
 TRACE_STEPS = 2         # training: the traced last steps
@@ -82,11 +83,16 @@ def main():
     ap.add_argument("--seconds", type=float, default=10.0)
     ap.add_argument("--trace", type=int, default=0, choices=(0, 1))
     ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--traffic-override", default=None,
+                    help="JSON laid over the cell's traffic mix: for the "
+                    "tools (a neighbouring rate); never part of a check")
     args = ap.parse_args()
 
     cell = cells.Cell(args.workload)
     if args.rehearse:
         cell.use_rehearsal_size()
+    if args.traffic_override:
+        cell.traffic = dict(cell.traffic, **json.loads(args.traffic_override))
 
     def say(**row):
         print(json.dumps(row), flush=True)
@@ -116,6 +122,7 @@ def main():
     compiles = CompileCounter()
     say(info="start", workload=cell.name, seed=args.seed,
         seconds=args.seconds, trace=args.trace,
+        traffic_override=args.traffic_override,
         device={"platform": devs[0].platform, "kind": devs[0].device_kind,
                 "count": len(devs)},
         compile_cache_dir=cache_dir, import_s=time.perf_counter() - T_PROCESS)
@@ -143,7 +150,15 @@ def main():
                end_to_end=res["end_to_end"], trace=None, say=say,
                trace_host_window=getattr(ctx, "host_trace_window", None))
 
-    out = {"correct": res["correct"], "attempted": res["attempted"],
+    # the comparison with the reference that needs the window's results
+    # runs here: after the window, after the peak was read
+    compared = list(res.get("compared", []))
+    correct = res["correct"]
+    if "after_window" in res:
+        ok, more = res["after_window"]()
+        correct = bool(correct and ok)
+        compared += more
+    out = {"correct": correct, "attempted": res["attempted"],
            "failed": res["failed"]}
     if not args.trace:
         values = dict(res["end_to_end"], setup_s=setup_s)
@@ -168,8 +183,19 @@ def main():
         if tr is not None:
             device["busy_s"] = tr.busy_s
             device["window_s"] = tr.window_s
-            out["breakdown"] = {"device_ops": tr.top_ops(10),
-                                "idle_gaps": tr.idle_gaps(10)}
+            # where the trace carries the programs' HLO: the operations as
+            # <op>@<program>/<scope> <file>:<line>, and the idle gaps by the
+            # program's own innermost span (telemetry is on when traced)
+            pt = provenance.of_run(run)
+            if pt is not None and pt.tables:
+                sums, _ = pt.idle_gaps(tr.host_spans)
+                out["breakdown"] = {
+                    "device_ops": pt.top_ops(10),
+                    "idle_gaps": [[k, v] for k, v in sorted(
+                        sums.items(), key=lambda kv: -kv[1])[:10]]}
+            else:
+                out["breakdown"] = {"device_ops": tr.top_ops(10),
+                                    "idle_gaps": tr.idle_gaps(10)}
         metrics = {}
         for m in cell.per_layer:
             reader = cell.layer_reader(m["name"])
@@ -178,6 +204,12 @@ def main():
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     out["metrics"] = metrics
     out["device"] = device
+    # every number compared beside its limit, last on standard error too
+    say(info="compared", correct=correct,
+        compared=[[n, v, lim] for n, v, lim in compared])
+    for n, v, lim in compared:
+        print(f"compared: {n} = {v} (limit {lim})", file=sys.stderr)
+    print(f"correct: {correct}", file=sys.stderr, flush=True)
     if args.rehearse:
         print("REHEARSAL on " + devs[0].platform + " (tiny size, NOT a "
               "measurement of the cell; no result is printed): "

@@ -47,7 +47,7 @@ def test_new_cell_from_new_files_only(tmp_path):
         "name": "new-cell", "config": "new-serve", "traffic": "new-mix",
         "chips": 1, "why": "test"})
     for m in man["end_to_end"]:
-        if m["name"] == "itl_p95_ms":
+        if m["name"] == "tpot_p90_ms":
             m["workloads"].append("new-cell")
     # and an end-to-end metric the accepted benchmark does not list yet
     man["end_to_end"].append({
@@ -55,7 +55,7 @@ def test_new_cell_from_new_files_only(tmp_path):
         "bound": 0.1, "source": "host_clock", "workloads": ["new-cell"]})
     man["per_layer"].append({
         "name": "new_steps", "unit": "steps", "better": "higher",
-        "source": "program_span", "layer": "test", "moves": "itl_p95_ms",
+        "source": "program_span", "layer": "test", "moves": "tpot_p90_ms",
         "workloads": ["new-cell"]})
     json.dump(man, open(os.path.join(root, "BENCHMARK.json"), "w"))
 
@@ -66,6 +66,6 @@ def test_new_cell_from_new_files_only(tmp_path):
     assert out["metrics"]["new_steps"]["value"] > 0
     proc = run_cell(root, "new-cell", "--trace", "0", "--rehearse")
     assert sorted(rehearsed(proc)["metrics"]) == [
-        "itl_p95_ms", "setup_s", "ttft_p50_ms"]
+        "setup_s", "tpot_p90_ms", "ttft_p50_ms"]
     for p, data in before.items():
         assert open(p, "rb").read() == data, f"{p} was edited"

@@ -34,12 +34,25 @@ def test_rehearsal_with_telemetry_on(workload, monkeypatch):
     assert sorted(out["metrics"]) == sorted(want)
 
 
+HOST = {"dispatch_enqueue_ms", "dispatch_enqueue_ms_tput",
+        "dispatch_idle_ms", "dispatch_idle_ms_tput"}
+
+
 def test_new_entries_only_append():
-    """The three metrics of this PR sit at the end of `per_layer`, each with
-    a reader file, on cells that report the metric they move."""
+    """The metrics later PRs added (PR 24: three read from the device
+    trace; PR 27: the host's four, read from the program's own spans) sit
+    at the end of `per_layer` in the order they came, each with a reader
+    file, on cells that report the metric they move."""
     names = [m["name"] for m in MANIFEST["per_layer"]]
-    assert set(names[-3:]) == NEW
-    for m in MANIFEST["per_layer"][-3:]:
+    assert set(names[-7:-4]) == NEW and set(names[-4:]) == HOST
+    for m in MANIFEST["per_layer"][-4:]:
+        assert os.path.exists(os.path.join(
+            ROOT, "benchmark", "layer_metrics", m["name"] + ".py"))
+        moved = next(e for e in MANIFEST["end_to_end"]
+                     if e["name"] == m["moves"])
+        assert set(m["workloads"]) <= set(moved["workloads"])
+        assert m["layer"] == "model step inference/engine.py"
+    for m in MANIFEST["per_layer"][-7:-4]:
         assert os.path.exists(os.path.join(
             ROOT, "benchmark", "layer_metrics", m["name"] + ".py"))
         moved = next(e for e in MANIFEST["end_to_end"]

@@ -41,6 +41,47 @@ def test_open_loop_same_multiset_and_counts_for_every_seed():
     assert abs(med - m["prompt"]["median"]) <= 1
 
 
+def test_chat_cell_rate_ramp_and_window_counts():
+    """The cell as PR 27 re-anchored it: 0.8 of the knee of 2.75 req/s, a
+    ramp of 33 requests (15 s, over four residence times of about 3.5 s),
+    99 requests due inside a 45 s window, and one fixed schedule."""
+    m = mix("chat-open-0p8")
+    assert m["rate_rps"] == 2.2 == round(0.8 * 2.75, 6)
+    assert m["ramp_requests"] == 33
+    plans = [traffic.open_loop_plan(m, 45.0, np.random.default_rng(
+        m["schedule_seed"])) for _ in range(2)]
+    assert plans[0] == plans[1]               # the schedule is the mix's
+    ramp_s, plan = plans[0]
+    assert ramp_s == 33 / 2.2 >= 3 * 3.5
+    due = np.array([d for d, _, _ in plan])
+    assert (due < ramp_s).sum() == 33
+    assert ((due >= ramp_s) & (due < ramp_s + 45.0)).sum() == 99 == len(
+        plan) - 33
+    # the neighbouring rates of the steadiness test keep the multiset's
+    # shape: the same quantile grid at another count
+    for scale, n_win in ((0.9, 89), (1.1, 109)):
+        _, p2 = traffic.open_loop_plan(dict(m, rate_rps=2.2 * scale), 45.0,
+                                       np.random.default_rng(23))
+        assert len(p2) == 33 + n_win
+        assert abs(np.median([p for _, p, _ in p2]) - 192) <= 4
+
+
+def test_docs_backlog_outlasts_a_window_eight_times():
+    """A 45 s window spends about 126 documents (my chip runs, PR 27): the
+    backlog holds eight times that, and building the plan with every
+    prompt's tokens takes well under a second."""
+    import time
+    m = mix("docs-backlog")
+    assert m["backlog"] == 1024 >= 8 * 126
+    t = time.perf_counter()
+    plan = traffic.closed_loop_plan(m, 17, np.random.default_rng(
+        m["schedule_seed"]))
+    rng = np.random.default_rng([2147483659, 1])
+    toks = [traffic.prompt_tokens(p, 50257, rng) for p, _ in plan]
+    assert time.perf_counter() - t < 1.0
+    assert len(toks) == 1024 and all(p + a <= 1024 for p, a in plan)
+
+
 def test_closed_loop_first_generation_is_staggered_and_fixed():
     m = mix("docs-backlog")
     plans = [traffic.closed_loop_plan(m, 17, np.random.default_rng(s))

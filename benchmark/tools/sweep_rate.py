@@ -32,6 +32,9 @@ def main():
     ap.add_argument("--rates", required=True)
     ap.add_argument("--seconds", type=float, default=40.0)
     ap.add_argument("--seed", type=int, default=1)
+    ap.add_argument("--ramp-seconds", type=float, default=None,
+                    help="ramp of this many seconds at each rate (default: "
+                    "the mix's ramp_requests at every rate)")
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args()
 
@@ -53,23 +56,30 @@ def main():
         rehearsal=cell.config if args.rehearse else {}, trace=False,
         trace_seconds=0.0)
     driver = cell.driver()
-    srv, log, counts, _, correct = driver.build(ctx)
+    srv, log, counts, _, correct, _, _ = driver.build(ctx)
     for i, rate in enumerate(float(x) for x in args.rates.split(",")):
         mix = dict(cell.traffic, rate_rps=rate)
+        if args.ramp_seconds is not None:
+            mix["ramp_requests"] = int(round(rate * args.ramp_seconds))
         log.spans.clear()
         counts["prefill_tokens"].clear()
         res = driver.drive(ctx, srv, log, counts, mix, args.seconds,
                            np.random.default_rng([args.seed, i]))
         recs = res["run"]["records"]
-        ws, we = res["run"]["window"]
-        done_in = len(recs) - res["unfinished_at_end"]
+        row = res["window_row"]
         say(sweep_rate_rps=rate, seconds=res["seconds"],
-            arrivals=len(recs), finished_by_end=done_in,
+            ramp_requests=int(mix["ramp_requests"]), arrivals=len(recs),
+            finished_by_end=len(recs) - res["unfinished_at_end"],
             queued_at_end=res["backlog_at_end"],
             unfinished_at_end=res["unfinished_at_end"],
-            itl_p95_ms=res["end_to_end"].get("itl_p95_ms"),
-            ttft_p50_ms=res["end_to_end"].get("ttft_p50_ms"),
-            correct=bool(res["correct"] and correct))
+            failed=res["failed"],
+            correct=bool(res["correct"] and correct),
+            **{k: row[k] for k in (
+                "ttft_p50_ms", "ttft_p95_ms", "itl_p50_ms", "itl_p95_ms",
+                "itl_p99_ms", "itl_mean_ms", "tpot_p50_ms", "tpot_p90_ms",
+                "token_gaps", "requests_finished_in_window", "output_tok_s",
+                "decode_occupancy", "decode_occupancy_per_5s",
+                "gen_late_p99_ms", "kv_blocks_peak")})
         t = time.perf_counter()
         while srv.busy:
             srv.step(time.perf_counter())
