@@ -31,7 +31,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from deepspeed_tpu.inference import sampling
+from deepspeed_tpu.inference import hybrid, sampling
+from deepspeed_tpu.inference.hybrid import _heads, _rows, causal_band
 from deepspeed_tpu.models import gpt as gpt_lib
 from deepspeed_tpu.ops import quantizer
 from deepspeed_tpu.ops.attention.paged import gather_pool_blocks
@@ -124,7 +125,12 @@ def _ffn(h, p, cfg, lora=None):
     serving, inference/adapters.py) applies to the dense MLP targets
     only — MoE expert stacks are not adaptable pool targets.
 
-    The MoE eval path NEVER drops a token (GShard capacity bounds
+    This is the all-experts path of the ``moe_gpt`` configs (a few
+    experts, all of them held here). A chip that holds a SHARE of many
+    experts computes only the (token, expert) pairs that fall on them:
+    moe/expert_share.py, reached through inference/hybrid.py.
+
+    This path NEVER drops a token (GShard capacity bounds
     training dispatch; it must not change eval semantics — the gate's
     1.0-eval-capacity default silently dropped tokens here, caught by
     the Mixtral HF-parity test) and avoids the no-drop dispatch tensors
@@ -261,18 +267,8 @@ def _named(fn, name: str):
     return call
 
 
-def _heads(rows, n_kv: int):
-    """``[..., Hkv*Dh]`` rows of the paged pool -> ``[..., Hkv, Dh]``:
-    what was gathered out of the pool is unfolded, never the pool."""
-    return rows.reshape(rows.shape[:-1] + (n_kv, rows.shape[-1] // n_kv))
-
-
-def _rows(heads):
-    """``[..., Hkv, Dh]`` -> the pool's ``[..., Hkv*Dh]`` rows."""
-    return heads.reshape(heads.shape[:-2] + (-1,))
-
-
-def _scan_layers(block, x, params, pools, lora_ops=None):
+def _scan_layers(block, x, params, pools, lora_ops=None, stack="block",
+                 bases=None):
     """Run the layers of a paged program: the ONE layer loop of every
     prefill, decode, verify and horizon program.
 
@@ -294,10 +290,19 @@ def _scan_layers(block, x, params, pools, lora_ops=None):
     ``lora_ops = (a_pool, b_pool, ablocks)``, the adapter pools, whose
     per-slot rank blocks are gathered per layer for gpt._dense's hook.
     Returns ``(x, pools)`` with the pools back in their stacked shapes.
+
+    A model whose layers differ in SHAPE or kind (inference/hybrid.py)
+    calls this once per stack of same-shaped layers (``stack`` names it in
+    ``params``), with pools of different depths side by side and
+    ``bases`` = each layer's offsets into them (any pytree with a leading
+    layer axis); ``x`` is then whatever the block carries from layer to
+    layer.
     """
-    L, N = pools[0].shape[:2]
-    flat = tuple(p.reshape((L * N,) + p.shape[2:]) for p in pools)
-    xs = (params["block"], jnp.arange(L, dtype=jnp.int32) * N)
+    flat = tuple(p.reshape((-1,) + p.shape[2:]) for p in pools)
+    if bases is None:
+        L, N = pools[0].shape[:2]
+        bases = jnp.arange(L, dtype=jnp.int32) * N
+    xs = (params[stack], bases)
     if lora_ops is not None:
         xs = xs + (lora_ops[0], lora_ops[1])
 
@@ -416,12 +421,10 @@ def _block_decode_paged(x, pools, tables, lengths, active, p,
             scores = jnp.einsum("bkgd,bskd->bkgs", q, kc).astype(jnp.float32)
             scores *= scale
             idx = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, NB * bs), 3)
-            pos = lengths[:, None, None, None]
-            scores = jnp.where(idx <= pos, scores, -1e30)
-            if cfg.attn_window is not None:
-                # block tables keep logical order, so cache-index distance IS
-                # logical distance — same banding as the static decode
-                scores = jnp.where(idx > pos - cfg.attn_window, scores, -1e30)
+            # block tables keep logical order, so cache-index distance IS
+            # logical distance — same banding as the static decode
+            scores = causal_band(scores, idx, lengths[:, None, None, None],
+                                 cfg.attn_window)
             probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
             attn = jnp.einsum("bkgs,bskd->bkgd", probs, vc).reshape(B, 1, D)
     with jax.named_scope("attn_out"):
@@ -547,10 +550,8 @@ def _block_verify_paged(x, pools, tables, lengths, active, p,
         scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, kc).astype(jnp.float32)
         scores *= scale
         idx = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, 1, NB * bs), 4)
-        qpos = pos[:, None, None, :, None]
-        scores = jnp.where(idx <= qpos, scores, -1e30)
-        if cfg.attn_window is not None:
-            scores = jnp.where(idx > qpos - cfg.attn_window, scores, -1e30)
+        scores = causal_band(scores, idx, pos[:, None, None, :, None],
+                             cfg.attn_window)
         probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
         attn = jnp.einsum("bkgqs,bskd->bqkgd", probs, vc).reshape(B, G, D)
     attn = _dense(attn, p["attn_out"], lora=lr("attn_out"))
@@ -658,10 +659,8 @@ def _block_prefill_paged(x, pools, table_row, positions, n_valid, p,
         scores *= cfg.attn_scale if cfg.attn_scale is not None \
             else 1.0 / np.sqrt(Dh)
         sidx = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, NB * bs), 3)
-        qpos = positions[:, None, None, None]
-        scores = jnp.where(sidx <= qpos, scores, -1e30)
-        if cfg.attn_window is not None:
-            scores = jnp.where(sidx > qpos - cfg.attn_window, scores, -1e30)
+        scores = causal_band(scores, sidx, positions[:, None, None, None],
+                             cfg.attn_window)
         probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
         attn = jnp.einsum("ckgs,skd->ckgd", probs, vc).reshape(1, C, D)
     with jax.named_scope("attn_out"):
@@ -892,6 +891,24 @@ class InferenceEngine:
             self._gather_blocks_q = jax.jit(self._gather_blocks_q_fn)
             self._scatter_block_q = jax.jit(self._scatter_block_q_fn,
                                             donate_argnums=(0, 1, 2, 3))
+        if hybrid.is_hybrid(config):
+            # two kinds of attention state: only the two paged serving
+            # programs know them. Everything else raises by name rather
+            # than grow a copy of the dialect (ROADMAP D4)
+            if mp_size > 1:
+                hybrid.refuse(config, "tensor parallelism (mp_size > 1)")
+            for attr, what in (
+                    ("_prefill", "the static-cache prefill (generate)"),
+                    ("_decode", "the static-cache decode (generate)"),
+                    ("_forward", "the cacheless forward"),
+                    ("_extend", "static speculative verify"),
+                    ("_decode_horizon", "the fused decode horizon"),
+                    ("_verify_slots", "speculative verify"),
+                    ("_cow_blocks", "prefix-cache copy-on-write"),
+                    ("_gather_blocks", "the host tier"),
+                    ("_scatter_block", "the host tier")):
+                setattr(self, attr, functools.partial(
+                    lambda what, *a, **k: hybrid.refuse(config, what), what))
         # a ProgramCostRegistry that wants the compiled text of each
         # serving program (a ServingEngine with telemetry on sets it)
         self.provenance = None
@@ -1007,13 +1024,19 @@ class InferenceEngine:
             if cfg.use_wpe:
                 safe = jnp.clip(positions, 0, self.max_seq_len - 1)
                 x = x + params["wpe"]["embedding"][safe][None]
+        if hybrid.is_hybrid(cfg):
+            def hblock(carry, flat, layer_p, base, lora, experts):
+                return hybrid.block_prefill(
+                    carry, flat, table_row, positions, n_valid, layer_p,
+                    cfg, base, self.decode_impl, experts)
+            x, pools = self._hybrid_layers(params, pools, hblock, x, 0)
+        else:
+            def block(x, pools, layer_p, base, lora):
+                return _block_prefill_paged(x, pools, table_row, positions,
+                                            n_valid, layer_p, cfg, lora=lora,
+                                            base=base)
 
-        def block(x, pools, layer_p, base, lora):
-            return _block_prefill_paged(x, pools, table_row, positions,
-                                        n_valid, layer_p, cfg, lora=lora,
-                                        base=base)
-
-        x, pools = _scan_layers(block, x, params, pools, lora_ops)
+            x, pools = _scan_layers(block, x, params, pools, lora_ops)
         last = jnp.clip(n_valid - 1, 0, C - 1)
         x_last = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
         logits = self._logits(params, x_last)
@@ -1036,16 +1059,52 @@ class InferenceEngine:
             if cfg.use_wpe:
                 safe = jnp.clip(lengths, 0, self.max_seq_len - 1)
                 x = x + params["wpe"]["embedding"][safe][:, None]
+        if hybrid.is_hybrid(cfg):
+            def hblock(carry, flat, layer_p, base, lora, experts):
+                return hybrid.block_decode(
+                    carry, flat, tables, lengths, active, layer_p, cfg,
+                    base, impl, experts)
+            x, pools = self._hybrid_layers(params, pools, hblock, x, 1)
+        else:
+            def block(x, pools, layer_p, base, lora):
+                return _block_decode_paged(x, pools, tables, lengths, active,
+                                           layer_p, cfg, impl=impl, lora=lora,
+                                           base=base)
 
-        def block(x, pools, layer_p, base, lora):
-            return _block_decode_paged(x, pools, tables, lengths, active,
-                                       layer_p, cfg, impl=impl, lora=lora,
-                                       base=base)
-
-        x, pools = _scan_layers(block, x, params, pools, lora_ops)
+            x, pools = _scan_layers(block, x, params, pools, lora_ops)
         logits = self._logits(params, x)
         toks, lps = sampling.sample_tokens(logits[:, -1], *lanes)
         return (logits, toks, lps) + pools
+
+    def _hybrid_layers(self, params, pools, block, x, phase: int):
+        """The layers of both serving programs for a model of two
+        attention kinds (inference/hybrid.py): the leading dense layers
+        and then the sparse layers, each ONE scan over _scan_layers with
+        the full pool and the window rings side by side in the carry.
+        ``pools`` = (K state, V state) as PagedState and so is what comes
+        back beside ``x``; ``phase``: the row of the K state's counters
+        this program adds to (0 prefill, 1 decode)."""
+        from deepspeed_tpu.models.exaone_moe import layer_bases
+        cfg = self.cfg
+        ks, vs = pools
+        params, experts = hybrid.split_experts(params)
+        block = functools.partial(block, experts=experts)
+        flat = (ks.full, vs.full, ks.win, vs.win)
+        dense_b, sparse_b = layer_bases(cfg, ks.full.shape[1],
+                                        ks.win.shape[1])
+        aux = {"route": jnp.zeros((cfg.n_sparse_layers, x.shape[0] * x.shape[1],
+                                   cfg.moe_k), jnp.int32),
+               "stats": None if ks.stats is None
+               else jnp.zeros_like(ks.stats[0])}
+        carry, flat = _scan_layers(block, (x, aux), params, flat,
+                                   stack="dense_block", bases=dense_b)
+        (x, aux), flat = _scan_layers(block, carry, params, flat,
+                                      bases=sparse_b)
+        stats = ks.stats
+        if stats is not None:
+            stats = stats.at[phase].add(aux["stats"])
+        return x, (hybrid.PagedState(flat[0], flat[2], stats, aux["route"]),
+                   hybrid.PagedState(flat[1], flat[3]))
 
     def _verify_slots_core(self, params, pools, tables, lengths, tokens,
                            active, impl, lora_ops=None):
@@ -1529,7 +1588,7 @@ class InferenceEngine:
         ``copy`` of a pool-shaped value was compiled in."""
         sink = self.provenance
         if sink is not None and pid not in sink.provenance:
-            L, N = args[1].shape[:2]
+            L, N = getattr(args[1], "full", args[1]).shape[:2]
             copied = sink.add_provenance(
                 pid, program.lower(*args).compile().as_text(),
                 pool_blocks=(N, L * N))
@@ -1542,6 +1601,8 @@ class InferenceEngine:
                           sample_state=None, lora=None):
         from deepspeed_tpu.utils.faults import maybe_fire
         maybe_fire("engine.prefill")
+        if isinstance(k_pool, hybrid.PagedState):
+            k_pool = k_pool._replace(route=None)    # an output only
         legacy = sample_state is None
         lanes = self._samp_lanes(sample_state, 1, self.cfg.vocab_size,
                                  scalar=True)
@@ -1576,6 +1637,8 @@ class InferenceEngine:
                      sample_state=None, lora=None):
         from deepspeed_tpu.utils.faults import maybe_fire
         maybe_fire("engine.decode")
+        if isinstance(k_pool, hybrid.PagedState):
+            k_pool = k_pool._replace(route=None)    # an output only
         legacy = sample_state is None
         lanes = self._samp_lanes(sample_state, len(np.asarray(tokens)),
                                  self.cfg.vocab_size)

@@ -1,0 +1,306 @@
+"""Paged serving blocks for a model whose layers are of two attention
+kinds (models/exaone_moe.py): ``full`` layers keep their history in the
+paged pool behind the slot's block table, exactly as the GPT blocks do;
+``sliding`` layers can never read more than ``attn_window`` tokens back, so
+each slot keeps a bounded RING of ``window_blocks`` blocks per window layer
+(``[L_win, 1 + slots * ring, block, Hkv*Dh]``, block 0 of a layer its trash
+block) whatever the length of its history. Position ``p`` of a slot lives
+in ring block ``(p // block) % ring`` at offset ``p % block``; the slot's
+ring block ids ride in its block-table row, behind the full layers'
+entries, so the two serving programs and the scheduler's calls are the
+same as for GPT.
+
+One compiled body per layer SHAPE: engine._scan_layers runs the leading
+dense layers and then the sparse layers, each as one scan whose body
+branches on the layer's kind (``lax.cond``) for the attention only. Both
+pools ride in the scan's carry and every layer writes to both: the write
+that does not belong to the layer's kind lands in a trash block.
+
+Not served with window state, and refused at construction by name: prefix
+sharing and copy-on-write, the host tier, int8 pools, speculation/verify,
+the fused horizon, LoRA; nor the static-cache paths (generate,
+generate_fused, forward)."""
+
+from typing import NamedTuple, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from deepspeed_tpu.models.exaone_moe import window_blocks
+from deepspeed_tpu.models.gpt import _dense, _norm
+from deepspeed_tpu.moe import expert_share
+from deepspeed_tpu.ops.attention.paged import NEG_INF
+from deepspeed_tpu.ops.attention.rotary import apply_rotary_half
+
+
+class PagedState(NamedTuple):
+    """The device state of one side (K or V) of a two-kind paged cache.
+    ``stats`` (K side, telemetry on): int32 ``[2, len(STAT_FIELDS)]``
+    expert-layer counters, row 0 summed over prefill dispatches and row 1
+    over decode dispatches, kept on the device and pulled only when
+    telemetry is read. ``route`` (K side, an OUTPUT only): the selection
+    ``[L_sparse, T, k]`` of the last dispatch, for the correctness check."""
+    full: jnp.ndarray
+    win: jnp.ndarray
+    stats: Optional[jnp.ndarray] = None
+    route: Optional[jnp.ndarray] = None
+
+    def delete(self):
+        for a in self:
+            if a is not None:
+                a.delete()
+
+
+def is_hybrid(cfg) -> bool:
+    return bool(getattr(cfg, "layer_kinds", ()))
+
+
+def refuse(cfg, feature: str):
+    """Raise for a serving feature that cannot yet live with window state."""
+    if is_hybrid(cfg):
+        raise ValueError(
+            f"{feature} is not supported for a model with sliding-window "
+            f"layers (bounded per-slot window state): see docs/EXPERT_SHARE.md")
+
+
+def causal_band(scores, kpos, qpos, window=None):
+    """Mask ``scores`` to the keys a query may see: key position <= query
+    position and, with ``window``, inside ``(qpos - window, qpos]``. The one
+    copy of the causal and window mask of the paged blocks."""
+    ok = kpos <= qpos
+    if window is not None:
+        ok = jnp.logical_and(ok, kpos > qpos - window)
+    return jnp.where(ok, scores, NEG_INF)
+
+
+def _heads(rows, n):
+    return rows.reshape(rows.shape[:-1] + (n, rows.shape[-1] // n))
+
+
+def _rows(heads):
+    return heads.reshape(heads.shape[:-2] + (-1,))
+
+
+def _qkv(h, p, cfg, positions, sliding):
+    """h ``[..., T, d]`` -> q ``[..., T, H, Dh]``, k, v ``[..., T, Hkv, Dh]``:
+    RMSNorm per head on q and k, rotary (rotate-half, all channels) in
+    sliding layers only."""
+    H, Hkv, Dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    qkv = _dense(h, p["qkv"])
+    q, k, v = jnp.split(qkv, [H * Dh, (H + Hkv) * Dh], axis=-1)
+    q, k, v = _heads(q, H), _heads(k, Hkv), _heads(v, Hkv)
+    if cfg.qk_norm:
+        q = _norm(q, p["q_norm"], cfg)          # over the head's Dh values
+        k = _norm(k, p["k_norm"], cfg)
+    q = jnp.where(sliding, apply_rotary_half(q, positions, cfg.rope_theta), q)
+    k = jnp.where(sliding, apply_rotary_half(k, positions, cfg.rope_theta), k)
+    return q, k, v
+
+
+def _swiglu(h, p):
+    return _dense(jax.nn.silu(_dense(h, p["mlp_gate"]))
+                  * _dense(h, p["mlp_in"]), p["mlp_out"])
+
+
+def split_experts(params):
+    """(params whose sparse stack lacks the expert kernels, those kernels
+    flat over (layer, held expert)): the layer loop scans the first and
+    closes over the second, so no layer's experts are sliced out."""
+    moe = params["block"]["moe"]
+    flat = {n: {"kernel": e["kernel"].reshape((-1,) + e["kernel"].shape[2:])}
+            for n, e in moe["experts"].items()}
+    rest = dict(params["block"],
+                moe={k: v for k, v in moe.items() if k != "experts"})
+    return dict(params, block=rest), flat
+
+
+def _ffn(x2, p, cfg, impl, valid, aux, index, experts):
+    """The block's FFN on ``x2`` [T, d] (after attention): dense SwiGLU or
+    the expert share. Returns (x2 + ffn, aux)."""
+    h = _norm(x2, p["ln2"], cfg)
+    if "moe" not in p:
+        with jax.named_scope("mlp"):
+            return x2 + _swiglu(h, p), aux
+    y, sel, stats = expert_share.sparse_ffn(
+        h, p["moe"], cfg, "gmm" if impl == "pallas" else "ragged_dot",
+        valid=valid, mlp=_swiglu, experts=experts, layer=index)
+    aux = dict(aux, route=aux["route"].at[index].set(sel))
+    if aux["stats"] is not None:
+        # the busiest expert and the touched count add up over layers and
+        # dispatches; their means divide by layer_calls
+        aux["stats"] = aux["stats"] + stats
+    return x2 + y, aux
+
+
+def _softmax_attend(scores, v, dtype, spec):
+    probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
+    return jnp.einsum(spec, probs, v)
+
+
+def block_prefill(carry, pools, table_row, positions, n_valid, p, cfg, base,
+                  impl, experts):
+    """One layer over a PROMPT CHUNK of one slot. ``carry`` = (x ``[1, C,
+    d]``, aux); ``pools`` = (k_full, v_full, k_win, v_win), flat over
+    layers; ``table_row`` = the slot's full-layer block table followed by
+    its ring block ids; ``base`` = this layer's offsets and kind
+    (models/exaone_moe.layer_bases); ``experts`` = every sparse layer's
+    expert kernels (:func:`split_experts`). A window layer attends over the ring's
+    history (read BEFORE the chunk's writes reuse its oldest blocks) and the
+    chunk itself, and keeps the chunk's last ``ring * block`` tokens."""
+    x, aux = carry
+    kf, vf, kw, vw = pools
+    C = x.shape[1]
+    H, Hkv, Dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    group = H // Hkv
+    bs = kf.shape[1]
+    RB = window_blocks(cfg, bs)
+    NB = table_row.shape[0] - RB
+    full_row, ring = table_row[:NB], table_row[NB:]
+    sliding, W = base["sliding"], cfg.attn_window
+    start = positions[0]
+    valid = jnp.arange(C) < n_valid
+    scale = 1.0 / np.sqrt(Dh)
+
+    with jax.named_scope("attn_qkv"):
+        h = _norm(x, p["ln1"], cfg)
+        q, k, v = _qkv(h, p, cfg, positions[None], sliding)
+        q, k, v = q[0], k[0], v[0]                       # [C, heads, Dh]
+
+    with jax.named_scope("kv_gather"):
+        hb = start // bs - (RB - 1) + jnp.arange(RB, dtype=jnp.int32)
+        hblk = ring[hb % RB] + base["win"]
+        hk = _heads(kw[hblk], Hkv).reshape(RB * bs, Hkv, Dh)
+        hv = _heads(vw[hblk], Hkv).reshape(RB * bs, Hkv, Dh)
+        hpos = (hb[:, None] * bs
+                + jnp.arange(bs, dtype=jnp.int32)[None]).reshape(-1)
+
+    with jax.named_scope("kv_write"):
+        off = positions % bs
+        fblk = full_row[jnp.clip(positions // bs, 0, NB - 1)]
+        fblk = jnp.where(jnp.logical_and(valid, ~sliding), fblk, 0) \
+            + base["full"]
+        kf = kf.at[fblk, off].set(_rows(k))
+        vf = vf.at[fblk, off].set(_rows(v))
+        # the ring keeps the chunk's last ring*block tokens: each of its
+        # places is written once
+        keep = jnp.logical_and(valid, positions >= start + n_valid - RB * bs)
+        wblk = ring[(positions // bs) % RB]
+        wblk = jnp.where(jnp.logical_and(keep, sliding), wblk, 0) \
+            + base["win"]
+        kw = kw.at[wblk, off].set(_rows(k))
+        vw = vw.at[wblk, off].set(_rows(v))
+
+    qg = q.reshape(C, Hkv, group, Dh)
+    qpos = positions[:, None, None, None]
+
+    def full_attn(_):
+        with jax.named_scope("attn_full"):
+            trow = full_row + base["full"]
+            kc = _heads(kf[trow], Hkv).reshape(NB * bs, Hkv, Dh)
+            vc = _heads(vf[trow], Hkv).reshape(NB * bs, Hkv, Dh)
+            kpos = jax.lax.broadcasted_iota(jnp.int32, (1, 1, NB * bs), 2)
+
+            def one_kv_head(qkv):
+                # a KV head at a time: [C, group, NB*bs] float32 scores,
+                # an eighth of the chunk's temporaries
+                qh, kh, vh = qkv
+                s = jnp.einsum("cgd,sd->cgs", qh, kh).astype(jnp.float32)
+                s = causal_band(s * scale, kpos, positions[:, None, None])
+                return _softmax_attend(s, vh, x.dtype, "cgs,sd->cgd")
+
+            out = jax.lax.map(one_kv_head, (
+                qg.transpose(1, 0, 2, 3), kc.transpose(1, 0, 2),
+                vc.transpose(1, 0, 2)))                # [Hkv, C, g, Dh]
+            return out.transpose(1, 0, 2, 3)
+
+    def window_attn(_):
+        with jax.named_scope("attn_window"):
+            kc = jnp.concatenate([hk, k], axis=0)
+            vc = jnp.concatenate([hv, v], axis=0)
+            # history a ring place really holds: positions in [0, start)
+            kpos = jnp.concatenate([
+                jnp.where(jnp.logical_and(hpos >= 0, hpos < start), hpos,
+                          jnp.int32(2 ** 30)), positions])
+            s = jnp.einsum("ckgd,skd->ckgs", qg, kc).astype(jnp.float32)
+            s = causal_band(s * scale, kpos[None, None, None, :], qpos, W)
+            return _softmax_attend(s, vc, x.dtype, "ckgs,skd->ckgd")
+
+    with jax.named_scope("paged_attn"):
+        attn = jax.lax.cond(sliding, window_attn, full_attn, None)
+    with jax.named_scope("attn_out"):
+        x2 = x[0] + _dense(attn.reshape(C, H * Dh), p["attn_out"])
+    y, aux = _ffn(x2, p, cfg, impl, valid, aux, base["index"], experts)
+    return (y[None], aux), (kf, vf, kw, vw)
+
+
+def _decode_attend(q, k_pool, v_pool, tables, lengths, window, impl, scale):
+    if impl == "pallas":
+        from deepspeed_tpu.ops.attention.paged import paged_decode_attention
+        return paged_decode_attention(q, k_pool, v_pool, tables, lengths,
+                                      scale=float(scale), window=window)
+    from deepspeed_tpu.ops.attention.paged import paged_decode_reference
+    return paged_decode_reference(q, k_pool, v_pool, tables, lengths,
+                                  scale=scale, window=window)
+
+
+def block_decode(carry, pools, tables, lengths, active, p, cfg, base, impl,
+                 experts):
+    """One layer for ONE new token per slot. ``tables`` ``[B, NB + ring]``;
+    a window layer writes the token into its ring and attends through a
+    table of the ring's blocks in logical order, so the paged kernel reads
+    at most the window whatever the slot's length."""
+    x, aux = carry
+    kf, vf, kw, vw = pools
+    B = x.shape[0]
+    H, Hkv, Dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    group = H // Hkv
+    bs = kf.shape[1]
+    RB = window_blocks(cfg, bs)
+    NB = tables.shape[1] - RB
+    full_tab, ring = tables[:, :NB], tables[:, NB:]
+    sliding, W = base["sliding"], cfg.attn_window
+    scale = 1.0 / np.sqrt(Dh)
+
+    with jax.named_scope("attn_qkv"):
+        h = _norm(x, p["ln1"], cfg)
+        q, k, v = _qkv(h, p, cfg, lengths[:, None], sliding)
+        q = q.reshape(B, Hkv, group, Dh)
+        k, v = k[:, 0], v[:, 0]                          # [B, Hkv, Dh]
+
+    with jax.named_scope("kv_write"):
+        cur = lengths // bs
+        off = lengths % bs
+        fblk = jnp.take_along_axis(
+            full_tab, jnp.clip(cur, 0, NB - 1)[:, None], axis=1)[:, 0]
+        ok = jnp.logical_and(active, lengths < NB * bs)
+        fblk = jnp.where(jnp.logical_and(ok, ~sliding), fblk, 0) \
+            + base["full"]
+        kf = kf.at[fblk, off].set(_rows(k))
+        vf = vf.at[fblk, off].set(_rows(v))
+        wblk = jnp.take_along_axis(ring, (cur % RB)[:, None], axis=1)[:, 0]
+        wblk = jnp.where(jnp.logical_and(active, sliding), wblk, 0) \
+            + base["win"]
+        kw = kw.at[wblk, off].set(_rows(k))
+        vw = vw.at[wblk, off].set(_rows(v))
+
+    def full_attn(_):
+        with jax.named_scope("attn_full"):
+            return _decode_attend(q, kf, vf, full_tab + base["full"],
+                                  lengths, None, impl, scale)
+
+    def window_attn(_):
+        with jax.named_scope("attn_window"):
+            lo = jnp.maximum(cur - (RB - 1), 0)
+            logical = lo[:, None] + jnp.arange(RB, dtype=jnp.int32)[None]
+            tabs = jnp.take_along_axis(ring, logical % RB, axis=1) \
+                + base["win"]
+            return _decode_attend(q, kw, vw, tabs, lengths - lo * bs, W,
+                                  impl, scale)
+
+    with jax.named_scope("paged_attn"):
+        attn = jax.lax.cond(sliding, window_attn, full_attn, None)
+    with jax.named_scope("attn_out"):
+        x2 = x[:, 0] + _dense(attn.reshape(B, H * Dh), p["attn_out"])
+    y, aux = _ffn(x2, p, cfg, impl, active, aux, base["index"], experts)
+    return (y[:, None], aux), (kf, vf, kw, vw)

@@ -81,6 +81,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.utils.env import resolve_flag
+from deepspeed_tpu.inference import hybrid
 from deepspeed_tpu.inference.host_tier import (
     HostBlockPool, HostCorruption, resolve_host_tier)
 from deepspeed_tpu.inference.prefix_index import PrefixIndex, PrefixMatch
@@ -251,11 +252,26 @@ class PagedKVCache:
         # pools ("off" keeps the fp pools bit-identical to before)
         self.kv_quant = resolve_kv_quant(kv_quant)
         self.quantized = self.kv_quant == "int8"
-        L, Hkv, Dh = cfg.n_layers, cfg.kv_heads, cfg.head_dim
+        # a model with sliding-window layers pages only its full layers
+        L = getattr(cfg, "n_full_layers", cfg.n_layers)
+        Hkv, Dh = cfg.kv_heads, cfg.head_dim
+        self.ring_blocks = 0
+        if hybrid.is_hybrid(cfg):
+            for on, what in ((prefix_cache, "prefix sharing (prefix_cache)"),
+                             (self.quantized, "int8 KV pools (kv_quant)"),
+                             (resolve_host_tier(host_tier) and prefix_cache,
+                              "the host tier (host_tier)")):
+                if on:
+                    hybrid.refuse(cfg, what)
+            from deepspeed_tpu.models.exaone_moe import window_blocks
+            self.ring_blocks = window_blocks(cfg, self.block_size)
         self.pool_dtype = jnp.dtype(jnp.int8) if self.quantized \
             else self.dtype
         self.bytes_per_token = gpt_lib.kv_bytes_per_token(
             cfg, self.pool_dtype)
+        # the window layers' rings: held whole from construction on
+        self.window_bytes = self.num_slots * gpt_lib.kv_window_bytes_per_slot(
+            cfg, self.block_size, self.pool_dtype)
         # scale overhead: 2 pools (K and V) × L layers × Hkv heads × fp32
         # per block — amortized it is 2*L*Hkv*4/block_size bytes/token
         self.scale_bytes_per_block = (2 * L * Hkv * 4) if self.quantized \
@@ -264,7 +280,9 @@ class PagedKVCache:
             if hbm_budget_bytes:
                 per_block = (self.bytes_per_token * self.block_size
                              + self.scale_bytes_per_block)
-                num_blocks = int(hbm_budget_bytes // per_block)
+                # the rings come out of the same budget
+                num_blocks = int((hbm_budget_bytes - self.window_bytes)
+                                 // per_block)
             else:
                 # default pool: the static reservation's worth of blocks
                 # (num_slots full sequences) — usage accounting then shows
@@ -284,6 +302,15 @@ class PagedKVCache:
         self.k = jnp.zeros((L, self.num_blocks, self.block_size, Hkv * Dh),
                            self.pool_dtype)
         self.v = jnp.zeros_like(self.k)
+        if self.ring_blocks:
+            # two kinds of state side by side (inference/hybrid.py): the
+            # pool above is the full layers'; each window layer keeps
+            # ring_blocks blocks per slot, block 0 its trash block
+            win = jnp.zeros((cfg.n_window_layers,
+                             1 + self.num_slots * self.ring_blocks,
+                             self.block_size, Hkv * Dh), self.pool_dtype)
+            self.k = hybrid.PagedState(self.k, win)
+            self.v = hybrid.PagedState(self.v, jnp.zeros_like(win))
         if self.quantized:
             self.k_scale = jnp.zeros((L, self.num_blocks, Hkv),
                                      jnp.float32)
@@ -294,7 +321,14 @@ class PagedKVCache:
         self._free: List[int] = list(range(self.num_blocks - 1, 0, -1))
         self._owned: List[List[int]] = [[] for _ in range(num_slots)]
         self._refcount = np.zeros((self.num_blocks,), np.int32)
-        self.tables = np.zeros((num_slots, self.blocks_per_slot), np.int32)
+        # a slot's row: its blocks in the (full layers') pool and, behind
+        # them, the ids of its ring blocks in a window layer, which never
+        # change
+        self.tables = np.zeros(
+            (num_slots, self.blocks_per_slot + self.ring_blocks), np.int32)
+        if self.ring_blocks:
+            self.tables[:, self.blocks_per_slot:] = 1 + np.arange(
+                num_slots * self.ring_blocks).reshape(num_slots, -1)
         self.lengths = np.zeros((num_slots,), np.int32)
         self.active = np.zeros((num_slots,), bool)
         self.watermark = num_slots if watermark is None else int(watermark)
@@ -425,6 +459,7 @@ class PagedKVCache:
             "cache_block_evictions": self.cache_block_evictions,
             "host_blocks": self.host_blocks,
             "host_bytes": self.host_bytes,
+            "window_bytes": self.window_bytes,
             "host_spills": self.host_spills,
             "host_restores": self.host_restores,
             "host_restore_failures": self.host_restore_failures,
@@ -562,7 +597,7 @@ class PagedKVCache:
             self._refcount[bid] = 1
         all_ids = m.block_ids + ids
         self._owned[slot] = list(all_ids)
-        self.tables[slot, :] = 0
+        self.tables[slot, :self.blocks_per_slot] = 0
         self.tables[slot, :len(all_ids)] = all_ids
         self.lengths[slot] = m.matched
         self.active[slot] = True
@@ -765,13 +800,13 @@ class PagedKVCache:
         unless the prefix index holds it, in which case it stays
         resident as reclaimable cache."""
         if not self.active[slot] and not self._owned[slot]:
-            self.tables[slot, :] = 0
+            self.tables[slot, :self.blocks_per_slot] = 0
             self.lengths[slot] = 0
             return
         for bid in reversed(self._owned[slot]):
             self._release(bid)
         self._owned[slot] = []
-        self.tables[slot, :] = 0
+        self.tables[slot, :self.blocks_per_slot] = 0
         self.lengths[slot] = 0
         self.active[slot] = False
 
@@ -1030,7 +1065,7 @@ class PagedKVCache:
         for bid in bids:
             self._refcount[bid] = 1
         self._owned[slot] = list(bids)
-        self.tables[slot, :] = 0
+        self.tables[slot, :self.blocks_per_slot] = 0
         self.tables[slot, :len(bids)] = bids
         self.lengths[slot] = length
         self.active[slot] = True

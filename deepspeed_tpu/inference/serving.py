@@ -144,6 +144,7 @@ import numpy as np
 from deepspeed_tpu.inference import sampling
 from deepspeed_tpu.inference.adapters import (AdapterLoadError, AdapterPool,
                                               resolve_lora_serve)
+from deepspeed_tpu.inference import hybrid
 from deepspeed_tpu.inference.host_tier import resolve_host_tier
 from deepspeed_tpu.inference.paged_cache import (CacheExhausted,
                                                  PagedKVCache,
@@ -578,6 +579,16 @@ class ServingEngine:
         # separate executables, so a run uses EITHER the base set or
         # the lora set, never both (docs/ADAPTERS.md)
         self.lora_serve = resolve_lora_serve(lora_serve)
+        # what cannot yet live with bounded window state raises here, by
+        # name (prefix sharing, the host tier and int8 pools: the cache)
+        for on, what in (
+                (resolve_spec_decode(spec_decode),
+                 "speculative decoding (spec_decode)"),
+                (resolve_decode_horizon(decode_horizon) > 1,
+                 "the fused decode horizon (decode_horizon)"),
+                (self.lora_serve, "LoRA serving (lora_serve)")):
+            if on:
+                hybrid.refuse(engine.cfg, what)
         cow = getattr(engine, "cow_blocks_q" if self._quant
                       else "cow_blocks", None)
         # host-tier transfer programs: like COW, the engine's jitted
@@ -602,6 +613,12 @@ class ServingEngine:
         # the EFFECTIVE switch: the cache gates the tier on the prefix
         # index existing (only indexed blocks ever spill)
         self.host_tier = self.cache.host_tier
+        if self.cache.ring_blocks and self.telemetry.enabled:
+            # expert-layer counters ride with the K state, on the device
+            # (read_expert_counters pulls them)
+            from deepspeed_tpu.moe.expert_share import STAT_FIELDS
+            self.cache.k = self.cache.k._replace(
+                stats=jnp.zeros((2, len(STAT_FIELDS)), jnp.int32))
         mesh = getattr(engine, "mesh", None)
         if mesh is not None:
             # place the fresh pools exactly where the jitted programs
@@ -791,6 +808,17 @@ class ServingEngine:
                 "kv_pool_dtype", "KV pool element width in bits "
                 "(8 = int8 quantized, 16 = bf16, 32 = f32)")
             self._g_kv_dtype.set(self.cache.pool_dtype.itemsize * 8)
+            # two kinds of KV state (inference/hybrid.py): the paged pool
+            # of the full layers and the window layers' per-slot rings
+            reg.gauge("kv_full_pool_bytes",
+                      "device bytes of the paged KV pool (full-attention "
+                      "layers, K+V, trash block included)").set(
+                (self.cache.num_blocks * self.cache.block_size
+                 * self.cache.bytes_per_token))
+            reg.gauge("kv_window_state_bytes",
+                      "device bytes of the sliding-window layers' per-slot "
+                      "rings (K+V, all slots; 0 without such layers)").set(
+                self.cache.window_bytes)
             self._h_kv_err = reg.histogram(
                 "serving_kv_quant_error",
                 "sampled upper bound on the max-abs KV dequantization "
@@ -2071,6 +2099,38 @@ class ServingEngine:
                     f"in {pause * 1e3:.1f}ms")
                 time.sleep(pause)
                 delay *= 2
+
+    def read_expert_counters(self) -> Dict[str, Dict[str, float]]:
+        """Pull the expert-share layers' counters from the device (they
+        accumulate there, one add per dispatch, and cost no transfer
+        until read) into the registry's ``moe_*`` gauges. Returns
+        ``{"prefill": {...}, "decode": {...}}`` by moe/expert_share
+        STAT_FIELDS, or {} when the model has no such layers or telemetry
+        is off."""
+        stats = getattr(self.cache.k, "stats", None)
+        if stats is None:
+            return {}
+        from deepspeed_tpu.moe.expert_share import STAT_FIELDS
+        rows = np.asarray(jax.device_get(stats), np.int64)  # dslint: disable=DS001 — pulled on demand, never per dispatch
+        out = {}
+        for phase, row in zip(("prefill", "decode"), rows):
+            vals = dict(zip(STAT_FIELDS, (int(v) for v in row)))
+            out[phase] = vals
+            calls = max(vals["layer_calls"], 1)
+            for name, value in (
+                    ("pairs_held", vals["pairs_held"]),
+                    ("pairs_total", vals["pairs_total"]),
+                    ("busiest_expert_pairs_mean",
+                     vals["busiest_expert_pairs"] / calls),
+                    ("expert_pairs_mean", vals["pairs_held"] / calls
+                     / max(self.engine.cfg.held[1], 1)),
+                    ("experts_touched_mean",
+                     vals["experts_touched"] / calls)):
+                self.metrics.gauge(
+                    f"moe_{phase}_{name}",
+                    f"expert-share layers, {phase} dispatches: {name} "
+                    f"(moe/expert_share.py)").set(float(value))
+        return out
 
     def _update_backpressure(self) -> None:
         if self.max_queue:

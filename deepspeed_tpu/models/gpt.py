@@ -109,9 +109,13 @@ class GPTConfig:
     activation: str = "gelu"
     use_bias: bool = True
     rope_theta: float = 10000.0            # rotary base (llama-3: 5e5)
+    # a head size that is not d_model / n_heads (None = that quotient)
+    head_size: Optional[int] = None
 
     @property
     def head_dim(self) -> int:
+        if self.head_size:
+            return self.head_size
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
 
@@ -979,9 +983,24 @@ def kv_bytes_per_token(cfg: GPTConfig, dtype=jnp.bfloat16) -> int:
     """Bytes of K+V cache ONE token occupies across all layers — the
     paged-cache allocator's budget unit (inference/paged_cache.py). The
     static engine pays this for `max_batch x S_max` slots up front; the
-    paged cache pays it per token actually in flight."""
-    return int(2 * cfg.n_layers * cfg.kv_heads * cfg.head_dim
+    paged cache pays it per token actually in flight. Counted by layer
+    kind: a sliding-window layer keeps a bounded ring per slot
+    (:func:`kv_window_bytes_per_slot`) and adds nothing per token."""
+    layers = getattr(cfg, "n_full_layers", cfg.n_layers)
+    return int(2 * layers * cfg.kv_heads * cfg.head_dim
                * jnp.dtype(dtype).itemsize)
+
+
+def kv_window_bytes_per_slot(cfg: GPTConfig, block_size: int,
+                             dtype=jnp.bfloat16) -> int:
+    """Bytes of K+V one serving slot holds in the sliding-window layers'
+    rings, whatever its length (0 for a model with no such layers)."""
+    layers = getattr(cfg, "n_window_layers", 0)
+    if not layers:
+        return 0
+    from deepspeed_tpu.models.exaone_moe import window_blocks
+    return int(2 * layers * window_blocks(cfg, block_size) * block_size
+               * cfg.kv_heads * cfg.head_dim * jnp.dtype(dtype).itemsize)
 
 
 def decode_geometry(cfg: GPTConfig, block_size: int,

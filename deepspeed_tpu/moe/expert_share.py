@@ -1,0 +1,126 @@
+"""One chip's share of an expert-parallel sparse FFN, for serving.
+
+The layer is TOLD which routed experts it holds (``held = (first, count)``
+of the published ``num_experts``). It routes every token over ALL experts
+(sigmoid scores, a per-expert bias that only selects, top-k, weights
+renormalised over the k and scaled), computes only the (token, expert)
+pairs that fall on the experts held here, and adds the shared expert, which
+every chip computes alike. What the absent experts would add is left out:
+on one chip the layer runs without its exchange, and nothing stands in for
+the other chips or their traffic (docs/EXPERT_SHARE.md).
+
+Shapes are static and worst-case: ``T * k`` pair rows, sorted by expert
+with the pairs on absent experts (and on padded tokens) last; no token is
+dropped and there is no capacity factor. The grouped product over the held
+experts' stacked weights ``[count, d, f]`` is ``impl="gmm"`` (the Mosaic
+grouped matmul of ``jax.experimental.pallas.ops.tpu.megablox``: it visits
+only the row tiles that hold pairs, so a decode step reads each touched
+expert's weights once and a prefill chunk does the pairs' FLOPs) or
+``impl="ragged_dot"`` (``jax.lax.ragged_dot``, portable; what the CPU
+tests run)."""
+
+from typing import Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+# (tm, tk, tn) of the grouped matmul, chosen on the chip (PERF.md, PR 28)
+GMM_TILING = (128, 1024, 1024)
+STAT_FIELDS = ("pairs_held", "pairs_total", "busiest_expert_pairs",
+               "experts_touched", "layer_calls")
+
+
+def route(h, router: Dict, k: int, scaling: float
+          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """h ``[T, d]`` -> (selected experts ``[T, k]`` int32, their weights
+    ``[T, k]`` float32). Scores and selection in float32 at full matmul
+    precision: a rounded score swaps near-tied experts."""
+    logits = jnp.dot(h.astype(jnp.float32),
+                     router["kernel"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, sel = jax.lax.top_k(scores + router["bias"].astype(jnp.float32), k)
+    w = jnp.take_along_axis(scores, sel, axis=-1)
+    w = w / jnp.sum(w, axis=-1, keepdims=True) * scaling
+    return sel.astype(jnp.int32), w
+
+
+def _grouped(x, w, sizes, impl: str):
+    """Rows of ``x`` [M, a], grouped by ``sizes`` [G], times ``w`` [G, a, b].
+    Rows past the groups come back undefined."""
+    if impl == "ragged_dot":
+        return jax.lax.ragged_dot(x, w, sizes)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    tm, tk, tn = GMM_TILING
+    return gmm(x, w, sizes, preferred_element_type=x.dtype,
+               tiling=(tm, min(tk, w.shape[1]), min(tn, w.shape[2])))
+
+
+def held_experts_ffn(h, experts: Dict, sel, w, held: Tuple[int, int],
+                     impl: str, valid: Optional[jnp.ndarray] = None,
+                     layer=None):
+    """The routed part of the layer that THIS chip's experts give.
+
+    h ``[T, d]``; ``experts``: ``wg`` / ``wi`` / ``wo`` kernels stacked
+    over the held experts; ``sel`` / ``w`` from :func:`route`; ``valid``
+    ``[T]`` bool marks real tokens (a padded lane or an idle slot meets no
+    expert). With ``layer`` (a traced index) the kernels are those of ALL
+    sparse layers, ``[layers * count, ...]``, and the layer's experts are
+    groups ``layer * count ...`` of them: a layer loop hands the kernel
+    the whole stack, because slicing a layer's experts out for a custom
+    call copies them (1.2 GB a layer a dispatch: PERF.md, PR 28).
+    Returns (``[T, d]`` in h's dtype, int32 stats in the order of
+    ``STAT_FIELDS``)."""
+    T, d = h.shape
+    K = sel.shape[1]
+    first, count = held
+    local = sel - first
+    on = jnp.logical_and(local >= 0, local < count)
+    every = jnp.ones((T, 1), bool) if valid is None else valid[:, None]
+    on = jnp.logical_and(on, every)
+    # pairs by held expert, everything else behind them
+    key = jnp.where(on, local, count).reshape(-1)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
+    M = T * K
+    pad = -M % GMM_TILING[0] if impl == "gmm" else 0
+    tok = jnp.pad(order // K, (0, pad))
+    x = h[tok]                                                # [M', d]
+    wg, wi, wo = (experts[n]["kernel"].astype(h.dtype)
+                  for n in ("wg", "wi", "wo"))
+    groups = sizes
+    if layer is not None:
+        groups = jax.lax.dynamic_update_slice(
+            jnp.zeros((wg.shape[0],), jnp.int32), sizes, (layer * count,))
+    a = jax.nn.silu(_grouped(x, wg, groups, impl)) \
+        * _grouped(x, wi, groups, impl)
+    y = _grouped(a, wo, groups, impl)                         # [M', d]
+    # back to (token, k) order; a pair that met no held expert reads a
+    # row nobody wrote, and is masked
+    inv = jnp.zeros((M,), jnp.int32).at[order].set(
+        jnp.arange(M, dtype=jnp.int32))
+    pairs = y[inv].reshape(T, K, d).astype(jnp.float32)
+    out = jnp.sum(jnp.where(on[..., None], pairs * w[..., None], 0.0), axis=1)
+    stats = jnp.stack([
+        jnp.sum(sizes), jnp.sum(every) * K, jnp.max(sizes),
+        jnp.sum(sizes > 0), jnp.int32(1)]).astype(jnp.int32)
+    return out.astype(h.dtype), stats
+
+
+def sparse_ffn(h, moe: Dict, cfg, impl: str, valid=None, mlp=None,
+               experts=None, layer=None):
+    """Router, the held experts' routed part and the shared expert for
+    ``h`` ``[T, d]``. ``mlp(h, p) -> [T, d]`` is the dense SwiGLU the
+    engine uses; ``experts`` / ``layer``: every sparse layer's expert
+    kernels and this layer's index (:func:`held_experts_ffn`), else
+    ``moe["experts"]``. Returns (routed + shared, selection ``[T, k]``,
+    stats)."""
+    with jax.named_scope("moe_router"):
+        sel, w = route(h, moe["router"], cfg.moe_k, cfg.routed_scaling)
+    with jax.named_scope("moe_experts"):
+        routed, stats = held_experts_ffn(
+            h, moe["experts"] if experts is None else experts, sel, w,
+            cfg.held, impl, valid, layer)
+    with jax.named_scope("moe_shared"):
+        shared = mlp(h, moe["shared"])
+    return routed + shared, sel, stats

@@ -54,3 +54,20 @@ def apply_rotary(q: jnp.ndarray, k: jnp.ndarray,
         return jnp.concatenate([t_rot, t[..., rd:]], axis=-1)
 
     return rot(q), rot(k)
+
+
+def apply_rotary_half(x: jnp.ndarray, positions: jnp.ndarray,
+                      base: float = 10000.0) -> jnp.ndarray:
+    """The rotate-half (GPT-NeoX / llama) convention over ALL channels of
+    each head: channel i pairs with channel i + D/2. x ``[..., T, H, D]``
+    with ``positions`` shaped like x's leading dims through T. Computed
+    in float32 and returned in x's dtype."""
+    D = x.shape[-1]
+    inv_freq = 1.0 / (base ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D))
+    ang = positions.astype(jnp.float32)[..., None, None] * inv_freq
+    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], axis=-1)
+    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], axis=-1)
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :D // 2], xf[..., D // 2:]
+    return (xf * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
+            ).astype(x.dtype)
