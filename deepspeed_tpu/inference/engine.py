@@ -35,7 +35,9 @@ from deepspeed_tpu.inference import hybrid, sampling
 from deepspeed_tpu.inference.hybrid import _heads, _rows, causal_band
 from deepspeed_tpu.models import gpt as gpt_lib
 from deepspeed_tpu.ops import quantizer
-from deepspeed_tpu.ops.attention.paged import gather_pool_blocks
+from deepspeed_tpu.ops.attention.paged import (blocks_per_step,
+                                               decode_plan,
+                                               gather_pool_blocks)
 from deepspeed_tpu.models.gpt import (GPTConfig, _dense,
                                       _norm, _qkv_split_rotary)
 from deepspeed_tpu.parallel import mesh as mesh_lib
@@ -319,9 +321,18 @@ def _scan_layers(block, x, params, pools, lora_ops=None, stack="block",
     return x, tuple(f.reshape(p.shape) for f, p in zip(flat, pools))
 
 
+def _paged_plan(pools, tables, lengths, cfg, q_len: int = 1):
+    """The paged kernel's grid for these lengths (ops/attention/paged.py
+    ``decode_plan``), worked out ONCE a dispatch, outside the layer loop,
+    for every layer's call. A few integer operations that the compiler
+    drops from a program on the gather path."""
+    return decode_plan(lengths, tables.shape[1], pools[0].shape[2],
+                       window=cfg.attn_window, q_len=q_len)
+
+
 def _block_decode_paged(x, pools, tables, lengths, active, p,
                         cfg: GPTConfig, impl: str = "gather", lora=None,
-                        base=0):
+                        base=0, plan=None):
     """One block for ONE new token per slot, K/V addressed through block
     tables — the paged generalization of _block_decode. x: [B, 1, D];
     ``pools`` = (k_pool, v_pool) of [N', block, Hkv*Dh] — ALL layers'
@@ -411,7 +422,7 @@ def _block_decode_paged(x, pools, tables, lengths, active, p,
             attn = paged_decode_attention(
                 q, k_pool, v_pool, tabs, lengths, scale=float(scale),
                 window=cfg.attn_window, k_scale=k_scale,
-                v_scale=v_scale).reshape(B, 1, D)
+                v_scale=v_scale, plan=plan).reshape(B, 1, D)
     else:
         with jax.named_scope("kv_gather"):
             # [B, NB*bs, Hkv, Dh]
@@ -443,7 +454,7 @@ def _block_decode_paged(x, pools, tables, lengths, active, p,
 
 def _block_verify_paged(x, pools, tables, lengths, active, p,
                         cfg: GPTConfig, impl: str = "gather", lora=None,
-                        base=0):
+                        base=0, plan=None):
     """One block for a G-token SPECULATIVE CHUNK per slot, K/V addressed
     through block tables — the q_len>1 generalization of
     _block_decode_paged for draft/verify serving. x: [B, G, D]; chunk
@@ -542,7 +553,7 @@ def _block_verify_paged(x, pools, tables, lengths, active, p,
         attn = paged_verify_attention(
             qg, k_pool, v_pool, tabs, lengths, scale=float(scale),
             window=cfg.attn_window, k_scale=k_scale,
-            v_scale=v_scale).reshape(B, G, D)
+            v_scale=v_scale, plan=plan).reshape(B, G, D)
     else:
         # [B, NB*bs, Hkv, Dh]
         kc = gather_pool_blocks(k_pool, tabs, Hkv, k_scale, x.dtype)
@@ -1060,16 +1071,21 @@ class InferenceEngine:
                 safe = jnp.clip(lengths, 0, self.max_seq_len - 1)
                 x = x + params["wpe"]["embedding"][safe][:, None]
         if hybrid.is_hybrid(cfg):
+            plans = hybrid.decode_plans(cfg, pools[0].full.shape[2], tables,
+                                        lengths)
+
             def hblock(carry, flat, layer_p, base, lora, experts):
                 return hybrid.block_decode(
                     carry, flat, tables, lengths, active, layer_p, cfg,
-                    base, impl, experts)
+                    base, impl, experts, plans)
             x, pools = self._hybrid_layers(params, pools, hblock, x, 1)
         else:
+            plan = _paged_plan(pools, tables, lengths, cfg)
+
             def block(x, pools, layer_p, base, lora):
                 return _block_decode_paged(x, pools, tables, lengths, active,
                                            layer_p, cfg, impl=impl, lora=lora,
-                                           base=base)
+                                           base=base, plan=plan)
 
             x, pools = _scan_layers(block, x, params, pools, lora_ops)
         logits = self._logits(params, x)
@@ -1119,10 +1135,12 @@ class InferenceEngine:
             safe = jnp.clip(pos, 0, self.max_seq_len - 1)
             x = x + params["wpe"]["embedding"][safe]
 
+        plan = _paged_plan(pools, tables, lengths, cfg, q_len=G)
+
         def block(x, pools, layer_p, base, lora):
             return _block_verify_paged(x, pools, tables, lengths, active,
                                        layer_p, cfg, impl=impl, lora=lora,
-                                       base=base)
+                                       base=base, plan=plan)
 
         x, pools = _scan_layers(block, x, params, pools, lora_ops)
         return (self._logits(params, x),) + pools
@@ -1366,10 +1384,13 @@ class InferenceEngine:
                 safe = jnp.clip(lens, 0, self.max_seq_len - 1)
                 x = x + params["wpe"]["embedding"][safe][:, None]
 
+            plan = _paged_plan(pools, tables, lens, cfg)
+
             def block(x, pools, layer_p, base, lora):
                 return _block_decode_paged(x, pools, tables, lens,
                                            lane_active, layer_p, cfg,
-                                           impl=impl, lora=lora, base=base)
+                                           impl=impl, lora=lora, base=base,
+                                           plan=plan)
 
             x, pools = _scan_layers(block, x, params, pools, lora_ops)
             logits = self._logits(params, x)
@@ -1576,7 +1597,7 @@ class InferenceEngine:
         a_pool, b_pool, ablocks = lora
         return (a_pool, b_pool, jnp.asarray(ablocks, jnp.int32))
 
-    def _run(self, pid: str, program, *args):
+    def _run(self, pid: str, program, *args, kernel_table=()):
         """Call a jitted serving program (``args[1]`` is its K pool).
         Under telemetry the FIRST call of each program also hands the
         text of its compiled module to the provenance table: lowering
@@ -1585,15 +1606,28 @@ class InferenceEngine:
         table is of what is loaded. The table also says whether the
         paged pool's one layout held in that executable:
         ``pool_copy_bytes``, logged here once per program, is 0 when no
-        ``copy`` of a pool-shaped value was compiled in."""
+        ``copy`` of a pool-shaped value was compiled in. A caller whose
+        program attends through the ``paged_decode`` kernel gives its
+        block tables' shape as ``kernel_table``, and the entry records
+        how the kernel's grid is cut (``paged_blocks_per_step``,
+        ``paged_grid_steps``: of the full table, where a model has a
+        window ring's as well)."""
         sink = self.provenance
         if sink is not None and pid not in sink.provenance:
-            L, N = getattr(args[1], "full", args[1]).shape[:2]
+            L, N, bs = getattr(args[1], "full", args[1]).shape[:3]
+            grid = ()
+            if kernel_table:
+                B, nb = kernel_table
+                if hybrid.is_hybrid(self.cfg):
+                    nb -= hybrid.window_blocks(self.cfg, bs)
+                per_step = blocks_per_step(nb, bs)
+                grid = (per_step, B * -(-nb // per_step))
             copied = sink.add_provenance(
                 pid, program.lower(*args).compile().as_text(),
-                pool_blocks=(N, L * N))
-            log_dist(f"serving program {pid}: pool_copy_bytes={copied}",
-                     ranks=[0])
+                pool_blocks=(N, L * N), paged_grid=grid)
+            log_dist(f"serving program {pid}: pool_copy_bytes={copied}"
+                     + (" paged_blocks_per_step={} paged_grid_steps={}"
+                        .format(*grid) if grid else ""), ranks=[0])
         return program(*args)
 
     def prefill_into_slot(self, k_pool, v_pool, table_row, tokens, start,
@@ -1643,6 +1677,8 @@ class InferenceEngine:
         lanes = self._samp_lanes(sample_state, len(np.asarray(tokens)),
                                  self.cfg.vocab_size)
         largs = self._lora_operands(lora)
+        impl = self.decode_impl if impl is None else impl
+        kernel = np.shape(tables) if impl == "pallas" else ()
         if k_scale is None:
             df = self._decode_slots if lora is None else self._decode_slots_l
             out = self._run(
@@ -1651,7 +1687,7 @@ class InferenceEngine:
                 jnp.asarray(tables, jnp.int32),
                 jnp.asarray(lengths, jnp.int32),
                 jnp.asarray(tokens, jnp.int32), jnp.asarray(active, bool),
-                self.decode_impl if impl is None else impl, *lanes, *largs)
+                impl, *lanes, *largs, kernel_table=kernel)
             return (out[0],) + out[3:] if legacy else out
         maybe_fire("cache.quantize")
         df = (self._decode_slots_q if lora is None
@@ -1662,7 +1698,7 @@ class InferenceEngine:
             jnp.asarray(tables, jnp.int32),
             jnp.asarray(lengths, jnp.int32),
             jnp.asarray(tokens, jnp.int32), jnp.asarray(active, bool),
-            self.decode_impl if impl is None else impl, *lanes, *largs)
+            impl, *lanes, *largs, kernel_table=kernel)
         return (out[0],) + out[3:] if legacy else out
 
     def decode_horizon(self, k_pool, v_pool, tables, lengths, tokens,
@@ -1684,6 +1720,8 @@ class InferenceEngine:
         lanes = self._samp_lanes(sample_state, len(np.asarray(tokens)),
                                  self.cfg.vocab_size)
         largs = self._lora_operands(lora)
+        impl = self.decode_impl if impl is None else impl
+        kernel = np.shape(tables) if impl == "pallas" else ()
         preds = (jnp.asarray(budgets, jnp.int32),
                  jnp.asarray(eos_ids, jnp.int32),
                  jnp.asarray(stop_ids, jnp.int32),
@@ -1698,8 +1736,8 @@ class InferenceEngine:
                 jnp.asarray(tables, jnp.int32),
                 jnp.asarray(lengths, jnp.int32),
                 jnp.asarray(tokens, jnp.int32), jnp.asarray(active, bool),
-                self.decode_impl if impl is None else impl, int(n_steps),
-                *lanes, *preds, *largs)
+                impl, int(n_steps), *lanes, *preds, *largs,
+                kernel_table=kernel)
         maybe_fire("cache.quantize")
         df = (self._decode_horizon_q if lora is None
               else self._decode_horizon_ql)
@@ -1709,8 +1747,8 @@ class InferenceEngine:
             jnp.asarray(tables, jnp.int32),
             jnp.asarray(lengths, jnp.int32),
             jnp.asarray(tokens, jnp.int32), jnp.asarray(active, bool),
-            self.decode_impl if impl is None else impl, int(n_steps),
-            *lanes, *preds, *largs)
+            impl, int(n_steps), *lanes, *preds, *largs,
+            kernel_table=kernel)
 
     def verify_slots(self, k_pool, v_pool, tables, lengths, tokens, active,
                      impl=None, k_scale=None, v_scale=None, lora=None):
@@ -1724,6 +1762,8 @@ class InferenceEngine:
         from deepspeed_tpu.utils.faults import maybe_fire
         maybe_fire("engine.verify")
         largs = self._lora_operands(lora)
+        impl = self.decode_impl if impl is None else impl
+        kernel = np.shape(tables) if impl == "pallas" else ()
         if k_scale is None:
             vf = self._verify_slots if lora is None else self._verify_slots_l
             return self._run(
@@ -1732,7 +1772,7 @@ class InferenceEngine:
                 jnp.asarray(tables, jnp.int32),
                 jnp.asarray(lengths, jnp.int32),
                 jnp.asarray(tokens, jnp.int32), jnp.asarray(active, bool),
-                self.decode_impl if impl is None else impl, *largs)
+                impl, *largs, kernel_table=kernel)
         maybe_fire("cache.quantize")
         vf = (self._verify_slots_q if lora is None
               else self._verify_slots_ql)
@@ -1742,7 +1782,7 @@ class InferenceEngine:
             jnp.asarray(tables, jnp.int32),
             jnp.asarray(lengths, jnp.int32),
             jnp.asarray(tokens, jnp.int32), jnp.asarray(active, bool),
-            self.decode_impl if impl is None else impl, *largs)
+            impl, *largs, kernel_table=kernel)
 
     def _forward_fn(self, params, tokens):
         x = self._embed(params, tokens)

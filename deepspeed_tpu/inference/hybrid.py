@@ -234,18 +234,39 @@ def block_prefill(carry, pools, table_row, positions, n_valid, p, cfg, base,
     return (y[None], aux), (kf, vf, kw, vw)
 
 
-def _decode_attend(q, k_pool, v_pool, tables, lengths, window, impl, scale):
+def _ring_span(cfg, bs: int, lengths):
+    """Where a slot's ring table starts: its first block in the slot's
+    logical order, and the slot's position relative to that block."""
+    lo = jnp.maximum(lengths // bs - (window_blocks(cfg, bs) - 1), 0)
+    return lo, lengths - lo * bs
+
+
+def decode_plans(cfg, bs: int, tables, lengths):
+    """The paged kernel's two grids of one decode dispatch (ops/attention/
+    paged.py ``decode_plan``), worked out once outside the layer loop: the
+    full layers' over the table, the window layers' over the ring in
+    logical order."""
+    from deepspeed_tpu.ops.attention.paged import decode_plan
+    RB = window_blocks(cfg, bs)
+    return (decode_plan(lengths, tables.shape[1] - RB, bs),
+            decode_plan(_ring_span(cfg, bs, lengths)[1], RB, bs,
+                        window=cfg.attn_window))
+
+
+def _decode_attend(q, k_pool, v_pool, tables, lengths, window, impl, scale,
+                   plan=None):
     if impl == "pallas":
         from deepspeed_tpu.ops.attention.paged import paged_decode_attention
         return paged_decode_attention(q, k_pool, v_pool, tables, lengths,
-                                      scale=float(scale), window=window)
+                                      scale=float(scale), window=window,
+                                      plan=plan)
     from deepspeed_tpu.ops.attention.paged import paged_decode_reference
     return paged_decode_reference(q, k_pool, v_pool, tables, lengths,
                                   scale=scale, window=window)
 
 
 def block_decode(carry, pools, tables, lengths, active, p, cfg, base, impl,
-                 experts):
+                 experts, plans=(None, None)):
     """One layer for ONE new token per slot. ``tables`` ``[B, NB + ring]``;
     a window layer writes the token into its ring and attends through a
     table of the ring's blocks in logical order, so the paged kernel reads
@@ -287,16 +308,17 @@ def block_decode(carry, pools, tables, lengths, active, p, cfg, base, impl,
     def full_attn(_):
         with jax.named_scope("attn_full"):
             return _decode_attend(q, kf, vf, full_tab + base["full"],
-                                  lengths, None, impl, scale)
+                                  lengths, None, impl, scale,
+                                  plans[0])
 
     def window_attn(_):
         with jax.named_scope("attn_window"):
-            lo = jnp.maximum(cur - (RB - 1), 0)
+            lo, rel = _ring_span(cfg, bs, lengths)
             logical = lo[:, None] + jnp.arange(RB, dtype=jnp.int32)[None]
             tabs = jnp.take_along_axis(ring, logical % RB, axis=1) \
                 + base["win"]
-            return _decode_attend(q, kw, vw, tabs, lengths - lo * bs, W,
-                                  impl, scale)
+            return _decode_attend(q, kw, vw, tabs, rel, W, impl, scale,
+                                  plans[1])
 
     with jax.named_scope("paged_attn"):
         attn = jax.lax.cond(sliding, window_attn, full_attn, None)

@@ -152,6 +152,7 @@ from deepspeed_tpu.inference.paged_cache import (CacheExhausted,
 from deepspeed_tpu.inference.spec_decode import (make_draft,
                                                  resolve_spec_decode,
                                                  resolve_spec_k)
+from deepspeed_tpu.ops.attention.paged import tiles_run
 from deepspeed_tpu.ops.quantizer import resolve_kv_quant
 from deepspeed_tpu.telemetry import (NOOP, MetricsRegistry, NoopTelemetry,
                                      RATE_BUCKETS, TEMP_BUCKETS, Telemetry,
@@ -694,6 +695,7 @@ class ServingEngine:
         # of a horizon's tokens stamp at dispatch time)
         self._horizon_ticks = 1
         self._token_tick = 0.0
+        self._kv_steps = 0      # the serve.decode span's, telemetry on
         self.last_step_span = 1.0
         self.token_time_unit = 0.0
         # wall seconds spent inside device dispatch/harvest calls — the
@@ -1119,6 +1121,7 @@ class ServingEngine:
                 c4 = self._span_counts()
                 if c4:
                     s_decode.set(live=occ, blocks=c4[3],
+                                 kv_steps=self._kv_steps,
                                  evicted=c4[2] - c3[2])
             with tracer.span("serve.spill", step=clock) as s_spill:
                 self._spill_step()
@@ -1571,6 +1574,15 @@ class ServingEngine:
                 self.costs.charge_cow(req, self.cache.cow_copies - cow0)
         live = [i for i, r in enumerate(self.slots)
                 if r is not None and r.state == "decode"]
+        if self.telemetry.enabled:
+            # grid steps of a paged_decode call that fetch and compute
+            # (of the full table): beside `blocks`, the fill of the tiles
+            cache = self.cache
+            self._kv_steps = sum(
+                tiles_run(int(cache.lengths[i]), cache.blocks_per_slot,
+                          cache.block_size,
+                          None if cache.ring_blocks
+                          else self.engine.cfg.attn_window) for i in live)
         if not live:
             return 0
         if self.spec_decode:

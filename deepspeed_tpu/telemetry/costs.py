@@ -553,7 +553,8 @@ class ProgramCostRegistry:
                 e.get("bytes_accessed", 0))
 
     def add_provenance(self, pid: str, hlo_text: str,
-                       pool_blocks: Sequence[int] = ()) -> int:
+                       pool_blocks: Sequence[int] = (),
+                       paged_grid: Sequence[int] = ()) -> int:
         """Keep program ``pid``'s provenance table, parsed from the text
         of the executable that is actually loaded (a program restored
         from jax's persistent cache carries the metadata it was first
@@ -561,7 +562,13 @@ class ProgramCostRegistry:
         pool's block counts the program's entry also gains
         ``pool_copy_bytes`` (:func:`pool_copy_bytes`; also the gauge
         ``program_pool_copy_bytes_<pid>`` once :meth:`export_gauges`
-        has been given a registry), which is returned."""
+        has been given a registry), which is returned. ``paged_grid``
+        (table entries one grid step attends, steps a call at most),
+        from the caller of a program that attends through the
+        ``paged_decode`` kernel, adds how that kernel's grid is cut:
+        ``paged_blocks_per_step`` and ``paged_grid_steps``, gauges
+        ``program_paged_blocks_per_step_<pid>`` /
+        ``program_paged_grid_steps_<pid>``."""
         instructions = parse_provenance(hlo_text)
         self.provenance[pid] = {
             "module": provenance_module_name(hlo_text),
@@ -573,6 +580,15 @@ class ProgramCostRegistry:
             if self.metrics is not None:
                 self.metrics.gauge(
                     f"program_pool_copy_bytes_{pid}").set(copied)
+        if paged_grid:
+            per_step, steps = paged_grid
+            self.entries.setdefault(pid, {"program": pid}).update(
+                paged_blocks_per_step=per_step, paged_grid_steps=steps)
+            if self.metrics is not None:
+                self.metrics.gauge(
+                    f"program_paged_blocks_per_step_{pid}").set(per_step)
+                self.metrics.gauge(
+                    f"program_paged_grid_steps_{pid}").set(steps)
         return copied
 
     def roofline(self, pid: str, device=None) -> Optional[Dict]:

@@ -64,11 +64,19 @@ def test_flash_masks_segments_and_windows_lower():
         assert "tpu_custom_call" in lower_tpu(fl, q, q, q, m, sg)
 
 
+# the K-EXAONE cell's two tables: (slots, table entries, window)
+KEXAONE_TABLES = {"kexaone-full-table256": (48, 256, None),
+                  "kexaone-ring9-window128": (48, 9, 128)}
+
+
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
 @pytest.mark.parametrize("q_len", [1, 5], ids=["decode", "verify5"])
-@pytest.mark.parametrize("H,Hkv,D,blk", SHAPES)
+@pytest.mark.parametrize(
+    "H,Hkv,D,blk", SHAPES + [pytest.param(64, 8, 128, name, id=name)
+                             for name in KEXAONE_TABLES])
 def test_paged_attention_lowers(H, Hkv, D, blk, q_len, quant):
-    B, bs, nb, N = 8, 16, 32, 257
+    B, nb, window = KEXAONE_TABLES.get(blk, (8, 32, None))
+    bs, N = 16, B * nb + 1
     pool = S((N, bs, Hkv * D), jnp.int8 if quant else jnp.bfloat16)
     scales = {"k_scale": S((N, Hkv), jnp.float32),
               "v_scale": S((N, Hkv), jnp.float32)} if quant else {}
@@ -80,7 +88,7 @@ def test_paged_attention_lowers(H, Hkv, D, blk, q_len, quant):
             S((B, q_len, Hkv, H // Hkv, D))
 
     def call(q, k, v, t, ln, *sc):
-        return fn(q, k, v, t, ln, scale=0.125,
+        return fn(q, k, v, t, ln, scale=0.125, window=window,
                   **dict(zip(scales, sc)))
     assert "tpu_custom_call" in lower_tpu(call, q, pool, pool, tables,
                                           lengths, *scales.values())

@@ -19,9 +19,11 @@ import pytest
 from deepspeed_tpu.inference.engine import InferenceEngine
 from deepspeed_tpu.inference.serving import ServeRequest, ServingEngine
 from deepspeed_tpu.models import gpt
-from deepspeed_tpu.ops.attention.paged import (paged_decode_attention,
+from deepspeed_tpu.ops.attention.paged import (blocks_per_step, decode_plan,
+                                               paged_decode_attention,
                                                paged_decode_reference,
-                                               resolve_decode_impl)
+                                               resolve_decode_impl,
+                                               tiles_run)
 
 
 def tiny(**over):
@@ -101,6 +103,186 @@ def test_paged_kernel_ignores_stale_blocks(devices, pallas_interpret):
     out2 = paged_decode_attention(q, jnp.asarray(kp2), jnp.asarray(vp2),
                                   tables, lengths, scale=0.25)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(out2))
+
+
+# the serving cells' head shapes (heads, kv heads, head size, table
+# entries, window) with small pools, and a table whose length is prime
+CELL_SHAPES = [
+    pytest.param(25, 25, 64, 64, None, id="gpt2-xl-table64"),
+    pytest.param(64, 8, 128, 256, None, id="kexaone-full-table256"),
+    pytest.param(64, 8, 128, 9, 128, id="kexaone-ring9-window128"),
+    pytest.param(25, 25, 64, 13, None, id="gpt2-xl-prime-table13"),
+]
+
+
+def _edge_lengths(nb, bs, window):
+    """Slot lengths at every edge of a block, a tile and the table. A
+    ring table's lengths are relative to its first block, so they stay
+    within the ring and (the band being the caller's whole table) past
+    nothing the window has dropped."""
+    P = blocks_per_step(nb, bs)
+    edges = [0, bs - 1, bs, P * bs - 1, P * bs, P * bs + 1, nb * bs - 1]
+    if window is not None:
+        edges += [window - 1, window, window + bs // 2]
+    return sorted({min(n, nb * bs - 1) for n in edges})
+
+
+def _cell_problem(H, Hkv, Dh, nb, window, seed=0, bs=16):
+    """A slot per edge length plus one whose unused table entries name
+    the trash block 0 (as the paged cache leaves them); the other slots'
+    entries past their length name blocks of their own, poisoned."""
+    rng = np.random.default_rng(seed)
+    lengths = _edge_lengths(nb, bs, window)
+    lengths.append(lengths[len(lengths) // 2])       # the trash-table slot
+    B = len(lengths)
+    N = B * nb + 1
+    q = jnp.asarray(rng.normal(size=(B, Hkv, H // Hkv, Dh)), jnp.float32)
+    kp = rng.normal(size=(N, bs, Hkv * Dh)).astype(np.float32)
+    vp = rng.normal(size=(N, bs, Hkv * Dh)).astype(np.float32)
+    tables = rng.permutation(np.arange(1, N)).reshape(B, nb).astype(np.int32)
+    tables[-1, lengths[-1] // bs + 1:] = 0
+    return q, kp, vp, tables, np.asarray(lengths, np.int32)
+
+
+@pytest.mark.parametrize("H,Hkv,Dh,nb,window", CELL_SHAPES)
+def test_paged_kernel_matches_reference_at_cell_shapes(
+        devices, pallas_interpret, H, Hkv, Dh, nb, window):
+    """The tile walk against the dense gathered softmax at the serving
+    cells' head shapes, every slot at another edge: an empty cache, a
+    block's last and first position, a tile's last, first and second, the
+    table's last; unused entries naming the trash block."""
+    q, kp, vp, tables, lengths = _cell_problem(H, Hkv, Dh, nb, window)
+    args = (q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(tables),
+            jnp.asarray(lengths))
+    out = paged_decode_attention(*args, scale=Dh ** -0.5, window=window)
+    ref = paged_decode_reference(*args, scale=Dh ** -0.5, window=window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("H,Hkv,Dh,nb,window", CELL_SHAPES)
+def test_tile_walk_keeps_its_promises(devices, pallas_interpret, H, Hkv, Dh,
+                                      nb, window):
+    """What the mechanism promises, from shapes alone: a grid step
+    attends 128 to 256 tokens (the whole table where it is shorter), the
+    host's count of the steps that run is the kernel's own arithmetic,
+    and a block past a slot's length or wholly below its band never
+    reaches the output, NaN and all."""
+    bs = 16
+    P = blocks_per_step(nb, bs)
+    assert P * bs == min(nb * bs, 128) or 128 <= P * bs <= 256, P
+    q, kp, vp, tables, lengths = _cell_problem(H, Hkv, Dh, nb, window)
+    for b, n in enumerate(lengths):
+        hi = n // bs
+        lo = 0 if window is None else max(n - window + 1, 0) // bs
+        assert tiles_run(int(n), nb, bs, window) == hi // P - lo // P + 1
+        for e in range(nb):
+            if (e > hi or e < lo) and tables[b, e] != 0:
+                kp[tables[b, e]] = np.nan
+                vp[tables[b, e]] = np.nan
+    assert tiles_run(0, nb, bs, window) == 1
+    assert tiles_run(nb * bs - 1, nb, bs) == -(-nb // P)
+    out = paged_decode_attention(q, jnp.asarray(kp), jnp.asarray(vp),
+                                 jnp.asarray(tables), jnp.asarray(lengths),
+                                 scale=Dh ** -0.5, window=window)
+    assert np.isfinite(np.asarray(out)).all()
+    # and what it does attend is all it should: the poisoned blocks
+    # zeroed, the reference agrees
+    ref = paged_decode_reference(
+        q, jnp.asarray(np.nan_to_num(kp)), jnp.asarray(np.nan_to_num(vp)),
+        jnp.asarray(tables), jnp.asarray(lengths), scale=Dh ** -0.5,
+        window=window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("H,Hkv,Dh,nb,window", CELL_SHAPES)
+def test_decode_plan_fetches_attended_blocks_once(devices, H, Hkv, Dh, nb,
+                                                  window):
+    """The grid worked out from the lengths: as many steps as the slots'
+    tiles that run, in slot order; every attended table entry named by
+    the ref of its place in the tile at its own step; a ref's index
+    changes only to an attended entry, so nothing else is fetched and
+    nothing twice."""
+    bs = 16
+    P = blocks_per_step(nb, bs)
+    lengths = np.asarray(_edge_lengths(nb, bs, window), np.int32)
+    B = len(lengths)
+    plan = decode_plan(jnp.asarray(lengths), nb, bs, window=window)
+    steps = int(plan.steps)
+    slot, tile = np.asarray(plan.slot)[:steps], np.asarray(plan.tile)[:steps]
+    held = np.asarray(plan.held)[:, :steps]
+    assert plan.held.shape == (P, B * -(-nb // P))
+    assert steps == sum(tiles_run(int(n), nb, bs, window) for n in lengths)
+    assert sorted(set(slot)) == list(range(B))
+    assert (np.diff(slot) >= 0).all()
+    attended = set()
+    for b in range(B):
+        hi = lengths[b] // bs
+        lo = 0 if window is None else max(lengths[b] - window + 1, 0) // bs
+        attended |= {b * nb + e for e in range(lo, hi + 1)}
+        assert list(tile[slot == b]) == list(range(lo // P, hi // P + 1))
+    named_at_own_step = set()
+    for w in range(steps):
+        for i in range(P):
+            e = tile[w] * P + i
+            if slot[w] * nb + e in attended and e < nb:
+                assert held[i, w] == slot[w] * nb + e
+                named_at_own_step.add(held[i, w])
+    assert named_at_own_step == attended
+    fetched = [held[i, w] for i in range(P) for w in range(steps)
+               if w == 0 or held[i, w] != held[i, w - 1]]
+    assert sorted(fetched) == sorted(attended)
+
+
+@pytest.mark.parametrize("H,Hkv,Dh,nb,window", CELL_SHAPES)
+def test_a_slots_bad_block_stays_its_own(devices, pallas_interpret, H, Hkv,
+                                         Dh, nb, window):
+    """Fault isolation between slots: a ref whose entry a step's slot
+    does not attend still holds the block it fetched for the slot
+    before, and a probability of 0 times that block's NaN would be a
+    NaN. With every block that every second slot ATTENDS poisoned (K
+    and V, infinities too), the slots between them read what the
+    reference reads."""
+    q, kp, vp, tables, lengths = _cell_problem(H, Hkv, Dh, nb, window)
+    bs, B = 16, len(lengths)
+    bad = np.arange(B) % 2 == 0
+    clean = (q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(tables),
+             jnp.asarray(lengths))
+    for b in np.flatnonzero(bad):
+        hi = lengths[b] // bs
+        lo = 0 if window is None else max(lengths[b] - window + 1, 0) // bs
+        kp[tables[b, lo:hi + 1]] = np.nan
+        vp[tables[b, lo:hi + 1]] = np.where(b % 4 == 0, np.nan, np.inf)
+    out = np.asarray(paged_decode_attention(
+        q, jnp.asarray(kp), jnp.asarray(vp), *clean[3:], scale=Dh ** -0.5,
+        window=window))
+    ref = np.asarray(paged_decode_reference(*clean, scale=Dh ** -0.5,
+                                            window=window))
+    assert not np.isfinite(out[bad]).all(axis=(1, 2, 3)).any()
+    assert np.isfinite(out[~bad]).all()
+    np.testing.assert_allclose(out[~bad], ref[~bad], atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("other", [dict(window=64), dict(q_len=2),
+                                   dict(nb=12)],
+                         ids=["window", "q_len", "table"])
+def test_a_plan_fits_its_call_or_the_call_refuses(devices, pallas_interpret,
+                                                  other):
+    """A plan worked out for another window, chunk or table would fire
+    the kernel's first and last tile at the wrong steps without a word:
+    the call checks what the plan was cut for."""
+    q, kp, vp, tables, lengths = _cell_problem(25, 25, 64, 13, None)
+    args = (q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(tables),
+            jnp.asarray(lengths))
+    good = decode_plan(args[4], 13, 16)
+    np.testing.assert_array_equal(
+        np.asarray(paged_decode_attention(*args, scale=0.125, plan=good)),
+        np.asarray(paged_decode_attention(*args, scale=0.125)))
+    kw = {**dict(window=None, q_len=1, nb=13), **other}
+    wrong = decode_plan(args[4], kw.pop("nb"), 16, **kw)
+    with pytest.raises(AssertionError):
+        paged_decode_attention(*args, scale=0.125, plan=wrong)
 
 
 def test_paged_kernel_no_dense_gather(devices):

@@ -21,6 +21,7 @@ import pytest
 
 from deepspeed_tpu.inference.engine import InferenceEngine, _named
 from deepspeed_tpu.models import gpt
+from deepspeed_tpu.ops.attention.paged import blocks_per_step
 from deepspeed_tpu.telemetry.costs import (ProgramCostRegistry,
                                            parse_provenance,
                                            pool_copy_bytes, probe_compiled,
@@ -147,9 +148,33 @@ def test_no_pool_sized_temporary(compiled_cell, program):
 
 
 def test_decode_program_attends_through_the_mosaic_kernel(compiled_cell):
-    _, table = compiled_cell[1]["decode_slots"]
-    assert any(n.startswith("paged_decode") and e["opcode"] == "custom-call"
-               for n, e in table.items())
+    """ONE ``paged_decode`` call in the layer loop's body (the roofline
+    readers multiply a call's bytes by the calls they count under that
+    name), with the pool's one layout and no pool-sized temporary beside
+    it, and the registry says how its grid is cut."""
+    (L, N), exes = compiled_cell
+    exe, table = exes["decode_slots"]
+    calls = [n for n, e in table.items()
+             if n.startswith("paged_decode") and e["opcode"] == "custom-call"]
+    assert len(calls) == 1, calls
+    assert pool_copy_bytes(table, (N, L * N)) == 0
+    assert probe_compiled(exe)["peak_bytes"] < TEMP_LIMIT
+    sv = CELL["serving"]
+    B, bs = sv["num_slots"], sv["block_size"]
+    NB = CELL["model"]["n_positions"] // bs
+    P = blocks_per_step(NB, bs)
+    assert 128 <= P * bs <= 256
+    reg = ProgramCostRegistry()
+    reg.add_provenance("decode_slots", exe.as_text(), pool_blocks=(N, L * N),
+                       paged_grid=(P, B * -(-NB // P)))
+    entry = reg.to_json()["programs"]["decode_slots"]
+    assert entry["pool_copy_bytes"] == 0
+    assert entry["paged_blocks_per_step"] == P
+    assert entry["paged_grid_steps"] == B * NB // P == 17 * 64 // P
+    # the prefill program attends a gathered chunk: no kernel, no grid
+    reg.add_provenance("prefill_slot", exes["prefill_slot"][0].as_text(),
+                       pool_blocks=(N, L * N))
+    assert "paged_grid_steps" not in reg.entries["prefill_slot"]
 
 
 def test_pool_copy_bytes_counts_pool_shaped_copies_only():

@@ -4,7 +4,8 @@ dimension, pools carried through the layer loop and addressed at
 (plain, int8, LoRA, speculative verify, fused horizon, GQA + rotary +
 window) is served here on the gather path and on the Pallas kernel
 (interpret mode) and held, token for token and byte for byte (one
-rotary case: to one unit in the last place), to what the tree BEFORE
+rotary case, and the kernel's floats since its tile walk: to one unit in
+the last place), to what the tree BEFORE
 the change produced: ``tests/data/pool_layout_parity.npz``
 was recorded from commit 60f8ab9 with ``python
 tests/test_pool_layout_parity.py --record``. Pools are compared whole,
@@ -127,6 +128,16 @@ def test_tokens_and_pools_equal_the_parent(devices, pallas_interpret,
     for name in keys:
         want = recorded[f"{variant}/{impl}/{name}"]
         assert got[name].dtype == want.dtype, name
+        if impl == "pallas" and want.dtype == np.float32:
+            # since PR 29 the kernel walks 128-token tiles with all heads
+            # of a tile in one product (the record's walked a block a
+            # step, a head at a time): its sums associate otherwise, and
+            # through the next layer's input a tenth of the pools' floats
+            # move by a unit in the last place (at most 6e-7 of values up
+            # to 2.2), the trash blocks' with them. Tokens and the int8
+            # payload stay equal to the last bit
+            np.testing.assert_allclose(got[name], want, rtol=0, atol=1e-6)
+            continue
         if variant == "gqa_rotary_window" and name in ("k", "v"):
             # the one case not equal to the last bit: rotary's
             # multiply-adds feed the pool write directly, and the CPU

@@ -15,6 +15,7 @@ import deepspeed_tpu
 from deepspeed_tpu.inference.engine import InferenceEngine
 from deepspeed_tpu.inference.serving import ServeRequest, ServingEngine
 from deepspeed_tpu.models import gpt
+from deepspeed_tpu.telemetry import Telemetry
 from deepspeed_tpu.telemetry.costs import (ProgramCostRegistry,
                                            device_peak_flops,
                                            device_peak_hbm_bytes_per_s,
@@ -139,6 +140,48 @@ def test_serving_programs_have_stable_names_and_scopes(served, pid, module,
                for e in prov["instructions"].values() if e["source"]}
     assert any(s.endswith("inference/engine.py") for s in sources)
     assert pid in json.loads(srv.cost_registry.dumps())["provenance"]
+
+
+def test_kernel_programs_say_how_the_paged_grid_is_cut(devices,
+                                                       pallas_interpret):
+    """With telemetry on, a decode program asked for the kernel records
+    how ``paged_decode``'s grid is cut (registry entry, gauges), and each
+    ``serve.decode`` span the steps of it that run for the live slots:
+    never more than the grid, and (8 blocks of 4 tokens: one tile a
+    slot) one a live slot here. A gather program records neither."""
+    from deepspeed_tpu.ops.attention.paged import blocks_per_step
+    r = np.random.default_rng(1)
+    reqs = [ServeRequest(rid=i, prompt=r.integers(1, 128, n).astype(np.int32),
+                         max_new_tokens=4) for i, n in enumerate((9, 5))]
+    tel = Telemetry()
+    srv = ServingEngine(_tiny_engine(), num_slots=2, block_size=4,
+                        num_blocks=24, prefill_chunk=8, telemetry=tel,
+                        decode_impl="pallas")
+    srv.run(reqs)
+    entry = srv.cost_registry.entries["decode_slots"]
+    nb = srv.cache.blocks_per_slot
+    P = blocks_per_step(nb, 4)
+    assert entry["paged_blocks_per_step"] == P
+    assert entry["paged_grid_steps"] == 2 * -(-nb // P)
+    # (pool_copy_bytes is the compiled chip program's to show: the
+    # interpreter this CPU run takes the kernel through copies its operands)
+    assert "paged_grid_steps" not in srv.cost_registry.entries["prefill_slot"]
+    gauge = srv.telemetry.registry.gauge
+    assert gauge("program_paged_grid_steps_decode_slots").value \
+        == entry["paged_grid_steps"]
+    assert gauge("program_paged_blocks_per_step_decode_slots").value == P
+    decodes = [s[5] for s in tel.tracer.spans() if s[1] == "serve.decode"
+               and s[5].get("live")]
+    assert decodes
+    for attrs in decodes:
+        assert attrs["kv_steps"] == attrs["live"] * 1
+        assert attrs["kv_steps"] <= entry["paged_grid_steps"]
+        assert attrs["blocks"] <= attrs["kv_steps"] * P * 2
+    off = ServingEngine(_tiny_engine(), num_slots=2, block_size=4,
+                        num_blocks=24, prefill_chunk=8, telemetry=Telemetry())
+    off.run([ServeRequest(rid=9, prompt=reqs[0].prompt.copy(),
+                          max_new_tokens=2)])
+    assert "paged_grid_steps" not in off.cost_registry.entries["decode_slots"]
 
 
 def test_provenance_costs_no_compile_and_nothing_when_off(served):

@@ -13,6 +13,7 @@ One JSON line per row, also appended to chiprun_out/kernel_census.jsonl.
 Needs a TPU; one process.
 """
 
+import functools
 import json
 import os
 import sys
@@ -213,6 +214,103 @@ def paged_rows():
                 yield name, run
 
 
+# the serving cells' decode calls: (name, slots, kv heads, group, head
+# size, table entries, window, layers that share the stacked pool,
+# blocks a layer). The ring table holds a window layer's last 9 blocks
+# in logical order, lengths relative to the first
+PAGED_CELL_SHAPES = (
+    ("gpt2-xl table64", 17, 25, 1, 64, 64, None, 48, 1088),
+    ("k-exaone full table256", 48, 8, 8, 128, 256, None, 2, 12288),
+    ("k-exaone ring table9 w128", 48, 8, 8, 128, 9, 128, 6, 432))
+PAGED_REPS = 20         # sweeps over the layers in one timed program
+# (name, share of the slots that decode, tokens a decoding slot holds)
+PAGED_FILLS = (("chat-like", 0.4, 290), ("docs-like", 0.8, 760),
+               ("reason-like", 1.0, 1000))
+
+
+@functools.lru_cache(maxsize=None)
+def _paged_layers(L, N, bs, nb, scale, window):
+    """The decode program's attention, jitted: all the layers' pools
+    stacked, layer l at ``tables + l*N``, the grid worked out once from
+    the lengths, a loop over the layers (each call's result feeding the
+    next one's queries), PAGED_REPS sweeps of it."""
+    def layers(q, k, v, tables, lengths):
+        plan = P.decode_plan(lengths, nb, bs, window=window)
+
+        def layer(q, l):
+            out = P.paged_decode_attention(
+                q, k, v, tables + l * N, lengths, scale=scale,
+                window=window, plan=plan)
+            return (q + out).astype(q.dtype), None
+
+        def sweep(_, q):
+            return jax.lax.scan(layer, q, jnp.arange(L))[0]
+        return jax.lax.fori_loop(0, PAGED_REPS, sweep, q)
+    return jax.jit(layers)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "window"))
+def _paged_decode_jit(q, k, v, tables, lengths, *, scale, window):
+    return P.paged_decode_attention(q, k, v, tables, lengths, scale=scale,
+                                    window=window)
+
+
+def paged_time_rows():
+    """Microseconds a ``paged_decode`` call at the three serving cells'
+    shapes and three fills, timed as the decode program runs it
+    (:func:`_paged_layers`), and the fit a reader needs: a fixed cost a
+    call and a cost per occupied block. Checked against the gather
+    reference at the first fill."""
+    r = np.random.default_rng(5)
+    bs = 16
+    for name, B, Hkv, G, D, nb, window, L, N in PAGED_CELL_SHAPES:
+        def run(B=B, Hkv=Hkv, G=G, D=D, nb=nb, window=window, L=L, N=N):
+            k = _rand(r, (L * N, bs, Hkv * D))
+            v = _rand(r, (L * N, bs, Hkv * D))
+            q = _rand(r, (B, Hkv, G, D))
+            scale = 1.0 / np.sqrt(D)
+            # every slot its own blocks, block 0 the trash block
+            tables = jnp.asarray(
+                1 + (np.arange(B * nb) % (N - 1)).reshape(B, nb), jnp.int32)
+            layers = _paged_layers(L, N, bs, nb, scale, window)
+            fills = []
+            for fill, share, tokens in PAGED_FILLS:
+                live = np.arange(B) < round(share * B)
+                held = min(tokens, nb * bs - 1)
+                if window is not None:      # relative to the ring's start
+                    held = min(tokens, (nb - 1) * bs + tokens % bs)
+                fills.append((fill, np.where(live, held, 0),
+                              int(np.sum(np.where(live, held // bs + 1, 1)))))
+            # jitted: called eagerly, each of the kernel's views of a
+            # pool would be a program argument of the pool's size
+            first = jnp.asarray(fills[0][1], jnp.int32)
+            out = _paged_decode_jit(q, k, v, tables, first, scale=scale,
+                                    window=window)
+            row = {"fwd_err": _err(out, P.paged_decode_reference(
+                *_f32(q, k[:N], v[:N]), tables, first, scale=scale,
+                window=window))}
+            points = []
+            for fill, lengths, blocks in fills:
+                lengths = jnp.asarray(lengths, jnp.int32)
+                layers(q, k, v, tables, lengths).block_until_ready()
+                best = float("inf")
+                for _ in range(3):
+                    t0 = time.perf_counter()
+                    layers(q, k, v, tables, lengths).block_until_ready()
+                    best = min(best, time.perf_counter() - t0)
+                us = best / (PAGED_REPS * L) * 1e6
+                row[f"us_{fill}"] = round(us, 1)
+                row[f"blocks_{fill}"] = blocks
+                points.append((blocks, us))
+            slope, fixed = np.polyfit(*zip(*points), 1)
+            return {**row, "us_fixed": round(float(fixed), 1),
+                    "us_per_block": round(float(slope), 3),
+                    "blocks_per_step": P.blocks_per_step(nb, bs),
+                    "grid_steps": B * -(-nb // P.blocks_per_step(nb, bs)),
+                    "ok": row["fwd_err"] < TOL}
+        yield f"paged decode time {name}", run
+
+
 def int8_matmul_rows():
     from deepspeed_tpu.ops.int8_matmul import (fit_blocks, int8_matmul,
                                                int8_matmul_reference)
@@ -256,7 +354,7 @@ def main():
     failed = 0
     with open(OUT, "a") as out:
         for rows in (flash_rows, ring_block_rows, paged_rows,
-                     int8_matmul_rows, blocksparse_rows):
+                     paged_time_rows, int8_matmul_rows, blocksparse_rows):
             for name, run in rows():
                 if wanted and not any(w in name for w in wanted):
                     continue
