@@ -10,7 +10,8 @@ per-slot adapter table — traced data, never a jit static, so one
 compiled program serves any mix of adapters and base-only slots.
 
 This module is the host-side half of that design (the gathered matmul
-lives in ``models/gpt._dense`` + the engine's ``_l`` program twins):
+lives in ``models/gpt._dense`` + the ``lora=`` operand of the engine's
+serving programs):
 
 - **Registry**: ``register(adapter_id, source)`` parses the
   ``runtime/lora.py`` adapter-only export (an ``.npz`` path or the

@@ -31,7 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from deepspeed_tpu.inference import hybrid, sampling
+from deepspeed_tpu.inference import hybrid, paged_cache, sampling
 from deepspeed_tpu.inference.hybrid import _heads, _rows, causal_band
 from deepspeed_tpu.models import gpt as gpt_lib
 from deepspeed_tpu.ops import quantizer
@@ -42,6 +42,7 @@ from deepspeed_tpu.models.gpt import (GPTConfig, _dense,
                                       _norm, _qkv_split_rotary)
 from deepspeed_tpu.parallel import mesh as mesh_lib
 from deepspeed_tpu.parallel import sharding as sharding_lib
+from deepspeed_tpu.utils.jit_registry import program_id
 from deepspeed_tpu.utils.logging import log_dist, logger
 
 PyTree = Any
@@ -797,111 +798,59 @@ class InferenceEngine:
             self._prefill = jax.jit(self._prefill_fn)
             self._decode = jax.jit(self._decode_fn, donate_argnums=(1,))
             self._forward = jax.jit(self._forward_fn)
-            # paged-serving programs: the steady-state continuous-batching
-            # loop is exactly these two compiled programs regardless of
-            # arrival pattern; pools are donated so the cache never
-            # doubles in HBM across a step. Their module names are
-            # explicit (jit_serve_prefill_slot, jit_serve_decode_slots):
+            # paged-serving programs: ONE jitted callable per family. The
+            # int8 scale pools and the adapter operands come behind the
+            # positional operands as optional pytrees (``scales``, ``lora``:
+            # None when absent), so the fp, int8, adapter and int8+adapter
+            # variants of a family are cache entries of its one callable,
+            # told apart by jax from the structure of what it is handed,
+            # as hybrid.PagedState rides in k_pool's place. A run serves
+            # one variant and compiles one entry per family: steady state
+            # is the same two programs (prefill, and decode or its horizon
+            # or verify form) whatever the variant and the arrival pattern.
+            # Pools and scales are donated, so the cache never doubles in
+            # HBM across a step; the adapter pools are read-only. impl
+            # ("gather" | "pallas") and the horizon's n_steps are static:
+            # a run pins both. The two programs of every run carry explicit
+            # module names (jit_serve_prefill_slot, jit_serve_decode_slots):
             # a profile and the provenance table name a program by them
+            donated = ("k_pool", "v_pool", "scales")
             self._prefill_slot = jax.jit(
                 _named(self._prefill_slot_fn, "serve_prefill_slot"),
-                donate_argnums=(1, 2))
-            # impl is static: each attention path ("gather" | "pallas")
-            # is its own compiled program; a serving run pins one impl so
-            # steady state remains two programs
+                donate_argnames=donated)
             self._decode_slots = jax.jit(
                 _named(self._decode_slots_fn, "serve_decode_slots"),
-                donate_argnums=(1, 2), static_argnums=(7,))
+                donate_argnames=donated, static_argnums=(7,))
             # fused multi-step decode (DS_DECODE_HORIZON > 1,
-            # docs/MULTISTEP.md): the SAME donated-pool decode body
-            # scanned N times on-device with the stop/length predicates
-            # as in-program masks. n_steps joins impl as a static — a
-            # serving run pins one N, so steady state stays at the same
-            # program count, and N=1 serving never compiles this family
+            # docs/MULTISTEP.md); N=1 serving never compiles it
             self._decode_horizon = jax.jit(self._decode_horizon_fn,
-                                           donate_argnums=(1, 2),
+                                           donate_argnames=donated,
                                            static_argnums=(7, 8))
-            # speculative verify: all k+1 chunk positions per slot in
-            # ONE extended-decode program — when serving runs with
-            # spec_decode on, this REPLACES the plain decode program in
-            # steady state (the chunk width G is fixed per serving
-            # engine, so one program serves every step)
+            # speculative verify: with spec_decode on this REPLACES the
+            # plain decode program in steady state (the chunk width G is
+            # fixed per serving engine)
             self._verify_slots = jax.jit(self._verify_slots_fn,
-                                         donate_argnums=(1, 2),
+                                         donate_argnames=donated,
                                          static_argnums=(7,))
             # static-path chunk verify (inference/speculative.py): the
-            # dense-cache twin of _verify_slots, kept here so the
+            # dense-cache counterpart of _verify_slots, kept here so the
             # speculative module shares the engine's compiled program
             # cache instead of duplicating the block math
             self._extend = jax.jit(self._extend_fn, donate_argnums=(1,))
-            # prefix-cache copy-on-write block copy: src/dst are traced
-            # scalars, so every divergence reuses ONE compiled program
-            # (warmed at ServingEngine construction — the steady-state
-            # compile contract stays at zero recompiles with the prefix
-            # cache on)
-            self._cow_blocks = jax.jit(self._cow_blocks_fn,
-                                       donate_argnums=(0, 1))
-            # int8 KV-cache twins (DS_KV_QUANT=int8): same program COUNT
-            # as the fp path — a quantized serving run compiles ONLY
-            # these (the fp programs above stay cold), so the steady-
-            # state compile contract is unchanged. The scale pools are
-            # donated alongside the int8 pools.
-            self._prefill_slot_q = jax.jit(self._prefill_slot_q_fn,
-                                           donate_argnums=(1, 2, 3, 4))
-            self._decode_slots_q = jax.jit(self._decode_slots_q_fn,
-                                           donate_argnums=(1, 2, 3, 4),
-                                           static_argnums=(9,))
-            self._decode_horizon_q = jax.jit(self._decode_horizon_q_fn,
-                                             donate_argnums=(1, 2, 3, 4),
-                                             static_argnums=(9, 10))
-            self._verify_slots_q = jax.jit(self._verify_slots_q_fn,
-                                           donate_argnums=(1, 2, 3, 4),
-                                           static_argnums=(9,))
-            self._cow_blocks_q = jax.jit(self._cow_blocks_q_fn,
-                                         donate_argnums=(0, 1, 2, 3))
-            # multi-tenant LoRA twins (DS_LORA_SERVE=on, inference/
-            # adapters.py): adapter pools + the per-slot adapter-table
-            # rows ride at the END of each signature as traced DATA —
-            # donate/static indices are unchanged, and the pools are
-            # never donated (read-only, shared across steps and slots).
-            # A lora run compiles ONLY these (base-only serving keeps
-            # the fp/_q programs cold and vice versa), so the steady-
-            # state program COUNT contract holds either way, for ANY
-            # number of registered adapters
-            self._prefill_slot_l = jax.jit(self._prefill_slot_l_fn,
-                                           donate_argnums=(1, 2))
-            self._decode_slots_l = jax.jit(self._decode_slots_l_fn,
-                                           donate_argnums=(1, 2),
-                                           static_argnums=(7,))
-            self._decode_horizon_l = jax.jit(self._decode_horizon_l_fn,
-                                             donate_argnums=(1, 2),
-                                             static_argnums=(7, 8))
-            self._verify_slots_l = jax.jit(self._verify_slots_l_fn,
-                                           donate_argnums=(1, 2),
-                                           static_argnums=(7,))
-            self._prefill_slot_ql = jax.jit(self._prefill_slot_ql_fn,
-                                            donate_argnums=(1, 2, 3, 4))
-            self._decode_slots_ql = jax.jit(self._decode_slots_ql_fn,
-                                            donate_argnums=(1, 2, 3, 4),
-                                            static_argnums=(9,))
-            self._decode_horizon_ql = jax.jit(self._decode_horizon_ql_fn,
-                                              donate_argnums=(1, 2, 3, 4),
-                                              static_argnums=(9, 10))
-            self._verify_slots_ql = jax.jit(self._verify_slots_ql_fn,
-                                            donate_argnums=(1, 2, 3, 4),
-                                            static_argnums=(9,))
-            # host-tier transfer programs (DS_KV_HOST_TIER=on): the
-            # spill gather keeps the pools live (the copy rides out
-            # while decode keeps serving), the restore scatter donates
-            # them like COW. ids/dst are traced, widths fixed per cache,
-            # so steady state adds ZERO programs beyond the two warmed
-            # at ServingEngine construction (paged_cache.warm_host_tier)
-            self._gather_blocks = jax.jit(self._gather_blocks_fn)
-            self._scatter_block = jax.jit(self._scatter_block_fn,
-                                          donate_argnums=(0, 1))
-            self._gather_blocks_q = jax.jit(self._gather_blocks_q_fn)
-            self._scatter_block_q = jax.jit(self._scatter_block_q_fn,
-                                            donate_argnums=(0, 1, 2, 3))
+            # block copies over the tuple of pools (scales travel with
+            # their payload): prefix-cache copy-on-write, and the host
+            # tier's spill gather (pools stay live while the copy rides
+            # out) and restore scatter. Block ids are traced and widths
+            # fixed per cache, so each is one entry, warmed at
+            # ServingEngine construction (paged_cache.warm_cow,
+            # warm_host_tier): steady state compiles nothing. Each is a
+            # partial of this engine's own: jax keys a jit's cache by the
+            # function it wraps, and an engine's entries are its alone
+            self._cow_blocks = jax.jit(partial(paged_cache.copy_block),
+                                       donate_argnums=(0,))
+            self._gather_blocks = jax.jit(partial(paged_cache.gather_blocks))
+            self._scatter_block = jax.jit(
+                partial(paged_cache.scatter_block), donate_argnums=(0,))
         if hybrid.is_hybrid(config):
             # two kinds of attention state: only the two paged serving
             # programs know them. Everything else raises by name rather
@@ -1019,15 +968,33 @@ class InferenceEngine:
             out["mask"] = cache_mask
         return logits, out
 
-    def _prefill_slot_core(self, params, pools, table_row, tokens, start,
-                           n_valid, lane, lora_ops=None):
-        """Prefill ONE prompt chunk into one serving slot's paged cache:
-        the body of all four prefill twins. ``pools``: (k_pool, v_pool)
-        or, int8, (k_pool, v_pool, k_scale, v_scale), carried through
-        the layers by _scan_layers; ``lane``: the slot's sampling lane;
-        ``lora_ops``: (a_pool, b_pool, the slot's adapter-table row as
-        [1, NBa])."""
+    def _prefill_slot_fn(self, params, k_pool, v_pool, table_row, tokens,
+                         start, n_valid, key, gen_count, temp, top_k,
+                         top_p, rep_pen, seen_row, scales=None, lora=None):
+        """Prefill ONE prompt chunk into one serving slot's paged cache.
+
+        tokens: [C] fixed-width chunk (padded; n_valid real tokens);
+        start: scalar — tokens already cached for this slot (0 for the
+        first chunk, the resume point for later chunks / requeued
+        requests, the MATCHED BOUNDARY for a prefix-cache hit whose
+        shared blocks are already resident); table_row: [NB] the slot's
+        block table. key..seen_row are the slot's sampling lane
+        (inference/sampling.py — all DATA, so the compile contract is
+        untouched); the fused sampler runs on the last valid position,
+        meaningful once the final chunk lands. ``scales``: None, or the
+        int8 pools' (k_scale, v_scale) of [L, N, Hkv] fp32, which then
+        ride through the layers beside the pools (_scan_layers) and the
+        block write is the read-modify-requantize path of
+        _block_prefill_paged. ``lora``: None, or (a_pool, b_pool, the
+        slot's adapter-table row [NBa]) (inference/adapters.py); an
+        all-zeros row gathers the trash block — the base-only prefill
+        bit-for-bit. Returns the last-valid-position logits, the
+        sampled/greedy token [1], its logprob [1], and the updated
+        (donated) pools, then scales."""
         cfg = self.cfg
+        pools = (k_pool, v_pool) + (scales or ())
+        lora_ops = None if lora is None \
+            else (lora[0], lora[1], lora[2][None])
         C = tokens.shape[0]
         positions = start + jnp.arange(C, dtype=jnp.int32)
         with jax.named_scope("embed"):
@@ -1051,20 +1018,33 @@ class InferenceEngine:
         last = jnp.clip(n_valid - 1, 0, C - 1)
         x_last = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
         logits = self._logits(params, x_last)
-        key, gen_count, temp, top_k, top_p, rep_pen, seen_row = lane
         tok, lp = sampling.sample_tokens(
             logits[:, -1], key.reshape(1, 2), gen_count.reshape(1),
             temp.reshape(1), top_k.reshape(1), top_p.reshape(1),
             rep_pen.reshape(1), seen_row.reshape(1, -1))
         return (logits, tok, lp) + pools
 
-    def _decode_slots_core(self, params, pools, tables, lengths, tokens,
-                           active, impl, lanes, lora_ops=None):
-        """One decode step for EVERY serving slot at once: the body of
-        all four decode twins (``pools`` / ``lora_ops`` as in
-        _prefill_slot_core, with the per-slot adapter-table rows
-        [B, NBa]; ``lanes``: the slot-indexed sampling arrays)."""
+    def _decode_slots_fn(self, params, k_pool, v_pool, tables, lengths,
+                         tokens, active, impl, keys, gen_counts, temps,
+                         top_ks, top_ps, rep_pens, seen, scales=None,
+                         lora=None):
+        """One decode step for EVERY serving slot at once. tokens: [B]
+        (each slot's pending token); lengths: [B] per-slot cache
+        positions; active: [B] (inactive slots run but write to the
+        trash block and their logits are discarded). The slot-batched
+        shape is static, so any mix of requests reuses this one
+        compiled program. impl is a STATIC jit argument ("gather" |
+        "pallas") selecting the attention path per compiled program —
+        see _block_decode_paged. keys..seen are the slot-indexed
+        sampling arrays (inference/sampling.py) — DATA, never statics,
+        so arbitrarily mixed greedy/sampled batches reuse this one
+        program; the fused sampler emits each slot's next token (and
+        its logprob) in the same dispatch as the forward step.
+        ``scales`` / ``lora`` as in _prefill_slot_fn, with the per-slot
+        adapter-table rows [B, NBa]: traced data like the lanes, so one
+        entry decodes any mix of adapters and base-only slots."""
         cfg = self.cfg
+        pools = (k_pool, v_pool) + (scales or ())
         with jax.named_scope("embed"):
             x = params["wte"]["embedding"][tokens[:, None]]
             if cfg.use_wpe:
@@ -1087,9 +1067,11 @@ class InferenceEngine:
                                            layer_p, cfg, impl=impl, lora=lora,
                                            base=base, plan=plan)
 
-            x, pools = _scan_layers(block, x, params, pools, lora_ops)
+            x, pools = _scan_layers(block, x, params, pools, lora)
         logits = self._logits(params, x)
-        toks, lps = sampling.sample_tokens(logits[:, -1], *lanes)
+        toks, lps = sampling.sample_tokens(
+            logits[:, -1], keys, gen_counts, temps, top_ks, top_ps,
+            rep_pens, seen)
         return (logits, toks, lps) + pools
 
     def _hybrid_layers(self, params, pools, block, x, phase: int):
@@ -1122,12 +1104,22 @@ class InferenceEngine:
         return x, (hybrid.PagedState(flat[0], flat[2], stats, aux["route"]),
                    hybrid.PagedState(flat[1], flat[3]))
 
-    def _verify_slots_core(self, params, pools, tables, lengths, tokens,
-                           active, impl, lora_ops=None):
+    def _verify_slots_fn(self, params, k_pool, v_pool, tables, lengths,
+                         tokens, active, impl="gather", scales=None,
+                         lora=None):
         """One speculative VERIFY step for every serving slot at once:
-        the body of all four verify twins (``pools`` / ``lora_ops`` as
-        in _decode_slots_core)."""
+        score all G chunk positions (pending token + G-1 draft tokens)
+        per slot in one compiled program. tokens: [B, G] (chunk token i
+        of slot b sits at cache position lengths[b] + i); returns logits
+        [B, G, V] + updated (donated) pools. The slot-batched shape and
+        the chunk width are static, so any mix of requests — across
+        eviction, requeue and prefix-cache hits — reuses this ONE
+        program; impl is a static jit argument exactly like
+        _decode_slots_fn, and ``scales`` / ``lora`` are its too: each
+        slot's draft chunk is scored under ITS adapter, so accept/reject
+        stays lossless per tenant."""
         cfg = self.cfg
+        pools = (k_pool, v_pool) + (scales or ())
         B, G = tokens.shape
         x = params["wte"]["embedding"][tokens]
         if cfg.use_wpe:
@@ -1142,69 +1134,15 @@ class InferenceEngine:
                                        layer_p, cfg, impl=impl, lora=lora,
                                        base=base, plan=plan)
 
-        x, pools = _scan_layers(block, x, params, pools, lora_ops)
+        x, pools = _scan_layers(block, x, params, pools, lora)
         return (self._logits(params, x),) + pools
 
-    def _prefill_slot_fn(self, params, k_pool, v_pool, table_row, tokens,
-                         start, n_valid, key, gen_count, temp, top_k,
-                         top_p, rep_pen, seen_row):
-        """Prefill ONE prompt chunk into one serving slot's paged cache.
-
-        tokens: [C] fixed-width chunk (padded; n_valid real tokens);
-        start: scalar — tokens already cached for this slot (0 for the
-        first chunk, the resume point for later chunks / requeued
-        requests, the MATCHED BOUNDARY for a prefix-cache hit whose
-        shared blocks are already resident); table_row: [NB] the slot's
-        block table. The trailing args are the slot's sampling lane
-        (inference/sampling.py — all DATA, so the compile contract is
-        untouched); the fused sampler runs on the last valid position,
-        meaningful once the final chunk lands. Returns the last-valid-
-        position logits, the sampled/greedy token [1], its logprob [1],
-        and the updated (donated) pools."""
-        return self._prefill_slot_core(
-            params, (k_pool, v_pool), table_row, tokens, start, n_valid,
-            (key, gen_count, temp, top_k, top_p, rep_pen, seen_row))
-
-    def _decode_slots_fn(self, params, k_pool, v_pool, tables, lengths,
-                         tokens, active, impl, keys, gen_counts, temps,
-                         top_ks, top_ps, rep_pens, seen):
-        """One decode step for EVERY serving slot at once. tokens: [B]
-        (each slot's pending token); lengths: [B] per-slot cache
-        positions; active: [B] (inactive slots run but write to the
-        trash block and their logits are discarded). The slot-batched
-        shape is static, so any mix of requests reuses this one
-        compiled program. impl is a STATIC jit argument ("gather" |
-        "pallas") selecting the attention path per compiled program —
-        see _block_decode_paged. The trailing args are the slot-indexed
-        sampling arrays (inference/sampling.py) — DATA, never statics,
-        so arbitrarily mixed greedy/sampled batches reuse this one
-        program; the fused sampler emits each slot's next token (and
-        its logprob) in the same dispatch as the forward step."""
-        return self._decode_slots_core(
-            params, (k_pool, v_pool), tables, lengths, tokens, active,
-            impl,
-            (keys, gen_counts, temps, top_ks, top_ps, rep_pens, seen))
-
-    def _verify_slots_fn(self, params, k_pool, v_pool, tables, lengths,
-                         tokens, active, impl="gather"):
-        """One speculative VERIFY step for every serving slot at once:
-        score all G chunk positions (pending token + G-1 draft tokens)
-        per slot in one compiled program. tokens: [B, G] (chunk token i
-        of slot b sits at cache position lengths[b] + i); returns logits
-        [B, G, V] + updated (donated) pools. The slot-batched shape and
-        the chunk width are static, so any mix of requests — across
-        eviction, requeue and prefix-cache hits — reuses this ONE
-        program; impl is a static jit argument exactly like
-        _decode_slots_fn."""
-        return self._verify_slots_core(
-            params, (k_pool, v_pool), tables, lengths, tokens, active,
-            impl)
 
     def _extend_fn(self, params, cache, tokens, pos):
         """G-token chunk verify over the STATIC dense cache (the
         speculative.py path): logits [B, G, V] + updated cache.
         tokens: [B, G]; pos: scalar first cache index of the chunk.
-        The paged twin is _verify_slots_fn."""
+        The paged counterpart is _verify_slots_fn."""
         cfg = self.cfg
 
         x = params["wte"]["embedding"][tokens]
@@ -1224,125 +1162,11 @@ class InferenceEngine:
         logits = self._logits(params, x)
         return logits, {"k": ks, "v": vs}
 
-    def _cow_blocks_fn(self, k_pool, v_pool, src, dst):
-        """Copy pool block ``src`` -> ``dst`` across every layer — the
-        device half of prefix-cache copy-on-write (paged_cache._cow).
-        Pools are donated, so the copy is in-place in HBM."""
-        return (k_pool.at[:, dst].set(k_pool[:, src]),
-                v_pool.at[:, dst].set(v_pool[:, src]))
-
-    def cow_blocks(self, k_pool, v_pool, src, dst):
-        return self._cow_blocks(k_pool, v_pool,  # dslint: disable=DS012 — caller paged_cache._cow fires cache.cow before delegating here
-                                jnp.asarray(src, jnp.int32),
-                                jnp.asarray(dst, jnp.int32))
-
-    def _prefill_slot_q_fn(self, params, k_pool, v_pool, k_scale, v_scale,
-                           table_row, tokens, start, n_valid, key,
-                           gen_count, temp, top_k, top_p, rep_pen,
-                           seen_row):
-        """int8-pool twin of _prefill_slot_fn: the per-layer scale pools
-        ([L, N, Hkv] fp32) ride through the layers alongside the pools
-        and the block write is the read-modify-requantize path of
-        _block_prefill_paged. Carries the same fused sampling lane as
-        the fp program."""
-        return self._prefill_slot_core(
-            params, (k_pool, v_pool, k_scale, v_scale), table_row, tokens,
-            start, n_valid,
-            (key, gen_count, temp, top_k, top_p, rep_pen, seen_row))
-
-    def _decode_slots_q_fn(self, params, k_pool, v_pool, k_scale, v_scale,
-                           tables, lengths, tokens, active, impl, keys,
-                           gen_counts, temps, top_ks, top_ps, rep_pens,
-                           seen):
-        """int8-pool twin of _decode_slots_fn (see _block_decode_paged's
-        quantized write path). Carries the same fused sampling lanes as
-        the fp program."""
-        return self._decode_slots_core(
-            params, (k_pool, v_pool, k_scale, v_scale), tables, lengths,
-            tokens, active, impl,
-            (keys, gen_counts, temps, top_ks, top_ps, rep_pens, seen))
-
-    def _verify_slots_q_fn(self, params, k_pool, v_pool, k_scale, v_scale,
-                           tables, lengths, tokens, active, impl="gather"):
-        """int8-pool twin of _verify_slots_fn (see _block_verify_paged's
-        quantized write path)."""
-        return self._verify_slots_core(
-            params, (k_pool, v_pool, k_scale, v_scale), tables, lengths,
-            tokens, active, impl)
-
-    def _prefill_slot_l_fn(self, params, k_pool, v_pool, table_row, tokens,
-                           start, n_valid, key, gen_count, temp, top_k,
-                           top_p, rep_pen, seen_row, lora_a, lora_b,
-                           ablock_row):
-        """LoRA twin of _prefill_slot_fn: the adapter pools ride through
-        the layers alongside the block params and the slot's
-        adapter-table row selects its rank blocks (inference/
-        adapters.py). An all-zeros row gathers the trash block — the
-        base-only prefill bit-for-bit."""
-        return self._prefill_slot_core(
-            params, (k_pool, v_pool), table_row, tokens, start, n_valid,
-            (key, gen_count, temp, top_k, top_p, rep_pen, seen_row),
-            lora_ops=(lora_a, lora_b, ablock_row[None]))
-
-    def _decode_slots_l_fn(self, params, k_pool, v_pool, tables, lengths,
-                           tokens, active, impl, keys, gen_counts, temps,
-                           top_ks, top_ps, rep_pens, seen, lora_a, lora_b,
-                           ablocks):
-        """LoRA twin of _decode_slots_fn: one compiled program decodes
-        any mix of adapters and base-only slots — ``ablocks`` [B, NBa]
-        is traced data exactly like the sampling lanes."""
-        return self._decode_slots_core(
-            params, (k_pool, v_pool), tables, lengths, tokens, active,
-            impl,
-            (keys, gen_counts, temps, top_ks, top_ps, rep_pens, seen),
-            lora_ops=(lora_a, lora_b, ablocks))
-
-    def _verify_slots_l_fn(self, params, k_pool, v_pool, tables, lengths,
-                           tokens, active, impl="gather", lora_a=None,
-                           lora_b=None, ablocks=None):
-        """LoRA twin of _verify_slots_fn: each slot's draft chunk is
-        scored under ITS adapter (speculative decode composes with
-        multi-tenant serving — the verify distribution is the adapted
-        model's, so accept/reject stays lossless per tenant)."""
-        return self._verify_slots_core(
-            params, (k_pool, v_pool), tables, lengths, tokens, active,
-            impl, lora_ops=(lora_a, lora_b, ablocks))
-
-    def _prefill_slot_ql_fn(self, params, k_pool, v_pool, k_scale, v_scale,
-                            table_row, tokens, start, n_valid, key,
-                            gen_count, temp, top_k, top_p, rep_pen,
-                            seen_row, lora_a, lora_b, ablock_row):
-        """int8-pool + LoRA combo twin (DS_KV_QUANT=int8 with
-        DS_LORA_SERVE=on): quantized KV write path, adapted
-        projections."""
-        return self._prefill_slot_core(
-            params, (k_pool, v_pool, k_scale, v_scale), table_row, tokens,
-            start, n_valid,
-            (key, gen_count, temp, top_k, top_p, rep_pen, seen_row),
-            lora_ops=(lora_a, lora_b, ablock_row[None]))
-
-    def _decode_slots_ql_fn(self, params, k_pool, v_pool, k_scale, v_scale,
-                            tables, lengths, tokens, active, impl, keys,
-                            gen_counts, temps, top_ks, top_ps, rep_pens,
-                            seen, lora_a, lora_b, ablocks):
-        """int8-pool + LoRA combo twin of _decode_slots_fn."""
-        return self._decode_slots_core(
-            params, (k_pool, v_pool, k_scale, v_scale), tables, lengths,
-            tokens, active, impl,
-            (keys, gen_counts, temps, top_ks, top_ps, rep_pens, seen),
-            lora_ops=(lora_a, lora_b, ablocks))
-
-    def _verify_slots_ql_fn(self, params, k_pool, v_pool, k_scale, v_scale,
-                            tables, lengths, tokens, active, impl="gather",
-                            lora_a=None, lora_b=None, ablocks=None):
-        """int8-pool + LoRA combo twin of _verify_slots_fn."""
-        return self._verify_slots_core(
-            params, (k_pool, v_pool, k_scale, v_scale), tables, lengths,
-            tokens, active, impl, lora_ops=(lora_a, lora_b, ablocks))
-
-    def _decode_horizon_core(self, params, k_pool, v_pool, tables, lengths,
-                             tokens, active, impl, n_steps, lanes, preds,
-                             k_scale=None, v_scale=None, lora_ops=None):
+    def _decode_horizon_fn(self, params, k_pool, v_pool, tables, lengths,
+                           tokens, active, impl, n_steps, keys, gen_counts,
+                           temps, top_ks, top_ps, rep_pens, seen, budgets,
+                           eos_ids, stop_ids, stop_lens, tail, scales=None,
+                           lora=None):
         """N fused decode iterations in ONE compiled program
         (docs/MULTISTEP.md): the _decode_slots_fn body — paged attention
         with trash-block write routing, the fused sampler with its pure
@@ -1359,21 +1183,20 @@ class InferenceEngine:
         the per-slot emitted count), so token streams match N=1
         bit-for-bit.
 
-        Shared by all four twins — quant (``k_scale``/``v_scale``) and
-        LoRA (``lora_ops``) compose through _scan_layers, the layer loop
-        of every paged program, which carries the pools; the step scan
-        here carries them between its iterations. ``preds``: budgets [B]
+        n_steps joins impl as a STATIC jit argument — a serving run pins
+        one N, so the steady-state program count is unchanged (and N=1
+        serving never compiles this family at all). ``scales`` / ``lora``
+        as in _decode_slots_fn: they compose through _scan_layers, the
+        layer loop of every paged program, which carries the pools; the
+        step scan here carries them between its iterations. budgets [B]
         (tokens this slot may emit this horizon), eos_ids [B] (-1 = none),
         stop_ids [B, S, W] right-aligned, stop_lens [B, S] (0 = unused
         row), tail [B, W] (the slot's last W emitted tokens, -1
         padded). Returns ([N, B] tokens, [N, B] logprobs, [B] produced,
         [B] done, pools...)."""
         cfg = self.cfg
-        keys, gen_counts, temps, top_ks, top_ps, rep_pens, seen = lanes
-        budgets, eos_ids, stop_ids, stop_lens, tail = preds
         B = tokens.shape[0]
         W = tail.shape[1]
-        quant = k_scale is not None
         rows = jnp.arange(B)
 
         def step(carry, i):
@@ -1392,7 +1215,7 @@ class InferenceEngine:
                                            impl=impl, lora=lora, base=base,
                                            plan=plan)
 
-            x, pools = _scan_layers(block, x, params, pools, lora_ops)
+            x, pools = _scan_layers(block, x, params, pools, lora)
             logits = self._logits(params, x)
             toks_i, lps_i = sampling.sample_tokens(
                 logits[:, -1], keys, gen_counts + i, temps, top_ks,
@@ -1428,135 +1251,12 @@ class InferenceEngine:
             return (tok, lens, live, produced, seen_c, tail_c,
                     pools), (toks_i, lps_i)
 
-        pools0 = (k_pool, v_pool) + ((k_scale, v_scale) if quant else ())
         init = (tokens, lengths, active, jnp.zeros_like(lengths), seen,
-                tail, pools0)
+                tail, (k_pool, v_pool) + (scales or ()))
         carry, (toks, lps) = jax.lax.scan(
             step, init, jnp.arange(n_steps, dtype=jnp.int32))
         _, _, live, produced, _, _, pools = carry
         return (toks, lps, produced, jnp.logical_not(live)) + pools
-
-    def _decode_horizon_fn(self, params, k_pool, v_pool, tables, lengths,
-                           tokens, active, impl, n_steps, keys, gen_counts,
-                           temps, top_ks, top_ps, rep_pens, seen, budgets,
-                           eos_ids, stop_ids, stop_lens, tail):
-        """Fused multi-step decode for every serving slot
-        (_decode_horizon_core): n_steps joins impl as a STATIC jit
-        argument — a serving run pins one N, so the steady-state
-        program count is unchanged (and N=1 serving never compiles
-        this family at all)."""
-        return self._decode_horizon_core(
-            params, k_pool, v_pool, tables, lengths, tokens, active,
-            impl, n_steps,
-            (keys, gen_counts, temps, top_ks, top_ps, rep_pens, seen),
-            (budgets, eos_ids, stop_ids, stop_lens, tail))
-
-    def _decode_horizon_q_fn(self, params, k_pool, v_pool, k_scale,
-                             v_scale, tables, lengths, tokens, active,
-                             impl, n_steps, keys, gen_counts, temps,
-                             top_ks, top_ps, rep_pens, seen, budgets,
-                             eos_ids, stop_ids, stop_lens, tail):
-        """int8-pool twin of _decode_horizon_fn: the scale pools thread
-        through the same core's scan carry (see _block_decode_paged's
-        quantized write path)."""
-        return self._decode_horizon_core(
-            params, k_pool, v_pool, tables, lengths, tokens, active,
-            impl, n_steps,
-            (keys, gen_counts, temps, top_ks, top_ps, rep_pens, seen),
-            (budgets, eos_ids, stop_ids, stop_lens, tail),
-            k_scale=k_scale, v_scale=v_scale)
-
-    def _decode_horizon_l_fn(self, params, k_pool, v_pool, tables, lengths,
-                             tokens, active, impl, n_steps, keys,
-                             gen_counts, temps, top_ks, top_ps, rep_pens,
-                             seen, budgets, eos_ids, stop_ids, stop_lens,
-                             tail, lora_a, lora_b, ablocks):
-        """LoRA twin of _decode_horizon_fn: the adapter pools ride the
-        same core's xs layout, gathered per layer per iteration."""
-        return self._decode_horizon_core(
-            params, k_pool, v_pool, tables, lengths, tokens, active,
-            impl, n_steps,
-            (keys, gen_counts, temps, top_ks, top_ps, rep_pens, seen),
-            (budgets, eos_ids, stop_ids, stop_lens, tail),
-            lora_ops=(lora_a, lora_b, ablocks))
-
-    def _decode_horizon_ql_fn(self, params, k_pool, v_pool, k_scale,
-                              v_scale, tables, lengths, tokens, active,
-                              impl, n_steps, keys, gen_counts, temps,
-                              top_ks, top_ps, rep_pens, seen, budgets,
-                              eos_ids, stop_ids, stop_lens, tail, lora_a,
-                              lora_b, ablocks):
-        """int8-pool + LoRA combo twin of _decode_horizon_fn."""
-        return self._decode_horizon_core(
-            params, k_pool, v_pool, tables, lengths, tokens, active,
-            impl, n_steps,
-            (keys, gen_counts, temps, top_ks, top_ps, rep_pens, seen),
-            (budgets, eos_ids, stop_ids, stop_lens, tail),
-            k_scale=k_scale, v_scale=v_scale,
-            lora_ops=(lora_a, lora_b, ablocks))
-
-    def _cow_blocks_q_fn(self, k_pool, v_pool, k_scale, v_scale, src, dst):
-        """Quantized-pool COW: the block's scales travel with its int8
-        payload (paged_cache._cow wires this in when kv_quant=int8)."""
-        return (k_pool.at[:, dst].set(k_pool[:, src]),
-                v_pool.at[:, dst].set(v_pool[:, src]),
-                k_scale.at[:, dst].set(k_scale[:, src]),
-                v_scale.at[:, dst].set(v_scale[:, src]))
-
-    def cow_blocks_q(self, k_pool, v_pool, k_scale, v_scale, src, dst):
-        return self._cow_blocks_q(k_pool, v_pool, k_scale, v_scale,  # dslint: disable=DS012 — caller paged_cache._cow fires cache.cow before delegating here
-                                  jnp.asarray(src, jnp.int32),
-                                  jnp.asarray(dst, jnp.int32))
-
-    def _gather_blocks_fn(self, k_pool, v_pool, ids):
-        """Pull a fixed-width batch of pool blocks (device half of a
-        host-tier spill, paged_cache.spill_tick, and of a replica-to-
-        replica KV migration, paged_cache.migrate_gather — both ride
-        the SAME compiled program). Pools stay live — the gathered
-        copy is what travels to host."""
-        return k_pool[:, ids], v_pool[:, ids]
-
-    def gather_blocks(self, k_pool, v_pool, ids):
-        return self._gather_blocks(k_pool, v_pool,
-                                   jnp.asarray(ids, jnp.int32))
-
-    def _scatter_block_fn(self, k_pool, v_pool, k_blk, v_blk, dst):
-        """Write one restored block back into the donated pools (device
-        half of a host-tier restore, paged_cache._dispatch_restore,
-        and of a migration landing, paged_cache.land_parked — the
-        destination replica reuses this program to place migrated
-        blocks free-list-only)."""
-        return (k_pool.at[:, dst].set(k_blk),
-                v_pool.at[:, dst].set(v_blk))
-
-    def scatter_block(self, k_pool, v_pool, k_blk, v_blk, dst):
-        return self._scatter_block(k_pool, v_pool, k_blk, v_blk,  # dslint: disable=DS012 — caller paged_cache._dispatch_restore fires cache.restore before delegating here
-                                   jnp.asarray(dst, jnp.int32))
-
-    def _gather_blocks_q_fn(self, k_pool, v_pool, k_scale, v_scale, ids):
-        """Quantized-pool spill gather: int8 payload plus fp32 scale
-        sidecars travel together (docs/KV_TIERING.md)."""
-        return (k_pool[:, ids], v_pool[:, ids],
-                k_scale[:, ids], v_scale[:, ids])
-
-    def gather_blocks_q(self, k_pool, v_pool, k_scale, v_scale, ids):
-        return self._gather_blocks_q(k_pool, v_pool, k_scale, v_scale,
-                                     jnp.asarray(ids, jnp.int32))
-
-    def _scatter_block_q_fn(self, k_pool, v_pool, k_scale, v_scale,
-                            k_blk, v_blk, ks_blk, vs_blk, dst):
-        """Quantized-pool restore scatter: payload and scales land
-        together."""
-        return (k_pool.at[:, dst].set(k_blk),
-                v_pool.at[:, dst].set(v_blk),
-                k_scale.at[:, dst].set(ks_blk),
-                v_scale.at[:, dst].set(vs_blk))
-
-    def scatter_block_q(self, k_pool, v_pool, k_scale, v_scale,
-                        k_blk, v_blk, ks_blk, vs_blk, dst):
-        return self._scatter_block_q(k_pool, v_pool, k_scale, v_scale,  # dslint: disable=DS012 — caller paged_cache._dispatch_restore fires cache.restore before delegating here
-                                     k_blk, v_blk, ks_blk, vs_blk,
-                                     jnp.asarray(dst, jnp.int32))
 
     def sync(self, *values) -> None:
         """Barrier on device values (pools, logits) — same discipline as
@@ -1564,10 +1264,13 @@ class InferenceEngine:
         serving step actually produced so it keys no new programs."""
         jax.block_until_ready(values)
 
-    # public wrappers: host-side numpy in, device pools threaded through.
-    # The fault-injection sites fire BEFORE any dispatch touches the
-    # donated pools, so a TransientDeviceError here is retryable by the
-    # serving engine against intact buffers (utils/faults).
+    # public wrappers: host-side numpy in, device pools threaded through
+    # (``scales``: PagedKVCache.scales, None or the int8 pools' (k_scale,
+    # v_scale)); what comes back ends in PagedKVCache.pools, updated: k, v
+    # and then the scales. The fault-injection sites fire BEFORE any
+    # dispatch touches the donated pools, so a TransientDeviceError here
+    # is retryable by the serving engine against intact buffers
+    # (utils/faults).
     @staticmethod
     def _samp_lanes(sample_state, batch, vocab, scalar=False):
         """Coerce a host ``sample_state`` tuple (sampling.SlotSamplerState
@@ -1586,20 +1289,19 @@ class InferenceEngine:
                 jnp.asarray(top_ps, jnp.float32),
                 jnp.asarray(pens, jnp.float32), jnp.asarray(seen, bool))
 
-    @staticmethod
-    def _lora_operands(lora):
-        """Coerce the serving engine's ``lora`` kwarg — ``(a_pool,
-        b_pool, ablocks)`` from AdapterPool.lora_args — to the trailing
-        traced operands of the ``_l``/``_ql`` twins. None selects the
-        base-only program (and keeps the lora twins cold)."""
-        if lora is None:
-            return ()
-        a_pool, b_pool, ablocks = lora
-        return (a_pool, b_pool, jnp.asarray(ablocks, jnp.int32))
+    def _run(self, stem: str, program, k_pool, v_pool, *operands,
+             scales=None, lora=None, kernel_table=()):
+        """The ONE dispatch of a paged serving program: family ``stem``'s
+        jitted callable on ``(params, k_pool, v_pool, *operands, scales,
+        lora)``. ``scales`` and ``lora`` — the serving engine's ``(a_pool,
+        b_pool, ablocks)`` from AdapterPool.lora_args — are None when
+        absent, and decide which cache entry of the callable runs and
+        under which program id it is accounted (jit_registry.program_id).
+        With int8 pools the ``cache.quantize`` site fires here, after the
+        caller's ``engine.*`` site and before the dispatch touches the
+        donated pools or scales.
 
-    def _run(self, pid: str, program, *args, kernel_table=()):
-        """Call a jitted serving program (``args[1]`` is its K pool).
-        Under telemetry the FIRST call of each program also hands the
+        Under telemetry the FIRST call of each program id also hands the
         text of its compiled module to the provenance table: lowering
         with the very arguments of the dispatch yields the executable
         the call below then runs (one compilation, not two), so the
@@ -1612,9 +1314,18 @@ class InferenceEngine:
         how the kernel's grid is cut (``paged_blocks_per_step``,
         ``paged_grid_steps``: of the full table, where a model has a
         window ring's as well)."""
+        if scales is not None:
+            from deepspeed_tpu.utils.faults import maybe_fire
+            maybe_fire("cache.quantize")
+        if isinstance(k_pool, hybrid.PagedState):
+            k_pool = k_pool._replace(route=None)    # an output only
+        if lora is not None:
+            lora = (lora[0], lora[1], jnp.asarray(lora[2], jnp.int32))
+        pid = program_id(stem, scales is not None, lora is not None)
+        args = (self.params, k_pool, v_pool, *operands, scales, lora)
         sink = self.provenance
         if sink is not None and pid not in sink.provenance:
-            L, N, bs = getattr(args[1], "full", args[1]).shape[:3]
+            L, N, bs = getattr(k_pool, "full", k_pool).shape[:3]
             grid = ()
             if kernel_table:
                 B, nb = kernel_table
@@ -1630,85 +1341,53 @@ class InferenceEngine:
                         .format(*grid) if grid else ""), ranks=[0])
         return program(*args)
 
+    def _run_slots(self, stem: str, program, k_pool, v_pool, tables,
+                   lengths, tokens, active, impl, *operands, **kw):
+        """_run for the slot-batched programs (decode, horizon, verify):
+        coerces their four host arrays, resolves ``impl`` (None: the
+        engine's), and names the kernel's table when it attends through
+        the kernel."""
+        impl = self.decode_impl if impl is None else impl
+        return self._run(
+            stem, program, k_pool, v_pool, jnp.asarray(tables, jnp.int32),
+            jnp.asarray(lengths, jnp.int32), jnp.asarray(tokens, jnp.int32),
+            jnp.asarray(active, bool), impl, *operands,
+            kernel_table=np.shape(tables) if impl == "pallas" else (), **kw)
+
     def prefill_into_slot(self, k_pool, v_pool, table_row, tokens, start,
-                          n_valid, k_scale=None, v_scale=None,
-                          sample_state=None, lora=None):
+                          n_valid, scales=None, sample_state=None,
+                          lora=None):
         from deepspeed_tpu.utils.faults import maybe_fire
         maybe_fire("engine.prefill")
-        if isinstance(k_pool, hybrid.PagedState):
-            k_pool = k_pool._replace(route=None)    # an output only
-        legacy = sample_state is None
         lanes = self._samp_lanes(sample_state, 1, self.cfg.vocab_size,
                                  scalar=True)
-        largs = self._lora_operands(lora)
-        if k_scale is None:
-            pf = self._prefill_slot if lora is None else self._prefill_slot_l
-            out = self._run(
-                "prefill_slot" if lora is None else "prefill_slot_l", pf,
-                self.params, k_pool, v_pool,
-                jnp.asarray(table_row, jnp.int32),
-                jnp.asarray(tokens, jnp.int32),
-                jnp.asarray(start, jnp.int32),
-                jnp.asarray(n_valid, jnp.int32), *lanes, *largs)
-            return (out[0],) + out[3:] if legacy else out
-        # ``cache.quantize`` fires before the dispatch touches the
-        # donated pools OR scale pools: a TransientDeviceError here is
-        # retryable against intact buffers
-        maybe_fire("cache.quantize")
-        pf = (self._prefill_slot_q if lora is None
-              else self._prefill_slot_ql)
         out = self._run(
-            "prefill_slot_q" if lora is None else "prefill_slot_ql", pf,
-            self.params, k_pool, v_pool, k_scale, v_scale,  # dslint: disable=DS003 — exclusive branch: the fp dispatch above already returned
+            "prefill_slot", self._prefill_slot, k_pool, v_pool,
             jnp.asarray(table_row, jnp.int32),
-            jnp.asarray(tokens, jnp.int32),
-            jnp.asarray(start, jnp.int32), jnp.asarray(n_valid, jnp.int32),
-            *lanes, *largs)
-        return (out[0],) + out[3:] if legacy else out
+            jnp.asarray(tokens, jnp.int32), jnp.asarray(start, jnp.int32),
+            jnp.asarray(n_valid, jnp.int32), *lanes, scales=scales,
+            lora=lora)
+        return (out[0],) + out[3:] if sample_state is None else out
 
     def decode_slots(self, k_pool, v_pool, tables, lengths, tokens, active,
-                     impl=None, k_scale=None, v_scale=None,
-                     sample_state=None, lora=None):
+                     impl=None, scales=None, sample_state=None, lora=None):
         from deepspeed_tpu.utils.faults import maybe_fire
         maybe_fire("engine.decode")
-        if isinstance(k_pool, hybrid.PagedState):
-            k_pool = k_pool._replace(route=None)    # an output only
-        legacy = sample_state is None
         lanes = self._samp_lanes(sample_state, len(np.asarray(tokens)),
                                  self.cfg.vocab_size)
-        largs = self._lora_operands(lora)
-        impl = self.decode_impl if impl is None else impl
-        kernel = np.shape(tables) if impl == "pallas" else ()
-        if k_scale is None:
-            df = self._decode_slots if lora is None else self._decode_slots_l
-            out = self._run(
-                "decode_slots" if lora is None else "decode_slots_l", df,
-                self.params, k_pool, v_pool,
-                jnp.asarray(tables, jnp.int32),
-                jnp.asarray(lengths, jnp.int32),
-                jnp.asarray(tokens, jnp.int32), jnp.asarray(active, bool),
-                impl, *lanes, *largs, kernel_table=kernel)
-            return (out[0],) + out[3:] if legacy else out
-        maybe_fire("cache.quantize")
-        df = (self._decode_slots_q if lora is None
-              else self._decode_slots_ql)
-        out = self._run(
-            "decode_slots_q" if lora is None else "decode_slots_ql", df,
-            self.params, k_pool, v_pool, k_scale, v_scale,  # dslint: disable=DS003 — exclusive branch: the fp dispatch above already returned
-            jnp.asarray(tables, jnp.int32),
-            jnp.asarray(lengths, jnp.int32),
-            jnp.asarray(tokens, jnp.int32), jnp.asarray(active, bool),
-            impl, *lanes, *largs, kernel_table=kernel)
-        return (out[0],) + out[3:] if legacy else out
+        out = self._run_slots(
+            "decode_slots", self._decode_slots, k_pool, v_pool, tables,
+            lengths, tokens, active, impl, *lanes, scales=scales, lora=lora)
+        return (out[0],) + out[3:] if sample_state is None else out
 
     def decode_horizon(self, k_pool, v_pool, tables, lengths, tokens,
                        active, n_steps, budgets, eos_ids, stop_ids,
-                       stop_lens, tail, impl=None, k_scale=None,
-                       v_scale=None, sample_state=None, lora=None):
+                       stop_lens, tail, impl=None, scales=None,
+                       sample_state=None, lora=None):
         """Fused multi-step decode for every serving slot: n_steps
         iterations of the decode body in ONE dispatch, with per-slot
         emission budgets and eos/stop predicates freezing finished
-        lanes in-program (_decode_horizon_core, docs/MULTISTEP.md).
+        lanes in-program (_decode_horizon_fn, docs/MULTISTEP.md).
         Returns ([n_steps, B] tokens, [n_steps, B] logprobs, [B]
         produced counts, [B] done flags, updated pools). The
         ``engine.decode`` site (and ``cache.quantize`` with int8 pools)
@@ -1719,39 +1398,16 @@ class InferenceEngine:
         maybe_fire("engine.decode")
         lanes = self._samp_lanes(sample_state, len(np.asarray(tokens)),
                                  self.cfg.vocab_size)
-        largs = self._lora_operands(lora)
-        impl = self.decode_impl if impl is None else impl
-        kernel = np.shape(tables) if impl == "pallas" else ()
-        preds = (jnp.asarray(budgets, jnp.int32),
-                 jnp.asarray(eos_ids, jnp.int32),
-                 jnp.asarray(stop_ids, jnp.int32),
-                 jnp.asarray(stop_lens, jnp.int32),
-                 jnp.asarray(tail, jnp.int32))
-        if k_scale is None:
-            df = (self._decode_horizon if lora is None
-                  else self._decode_horizon_l)
-            return self._run(
-                "decode_horizon" if lora is None else "decode_horizon_l",
-                df, self.params, k_pool, v_pool,
-                jnp.asarray(tables, jnp.int32),
-                jnp.asarray(lengths, jnp.int32),
-                jnp.asarray(tokens, jnp.int32), jnp.asarray(active, bool),
-                impl, int(n_steps), *lanes, *preds, *largs,
-                kernel_table=kernel)
-        maybe_fire("cache.quantize")
-        df = (self._decode_horizon_q if lora is None
-              else self._decode_horizon_ql)
-        return self._run(
-            "decode_horizon_q" if lora is None else "decode_horizon_ql", df,
-            self.params, k_pool, v_pool, k_scale, v_scale,  # dslint: disable=DS003 — exclusive branch: the fp dispatch above already returned
-            jnp.asarray(tables, jnp.int32),
-            jnp.asarray(lengths, jnp.int32),
-            jnp.asarray(tokens, jnp.int32), jnp.asarray(active, bool),
-            impl, int(n_steps), *lanes, *preds, *largs,
-            kernel_table=kernel)
+        return self._run_slots(
+            "decode_horizon", self._decode_horizon, k_pool, v_pool, tables,
+            lengths, tokens, active, impl, int(n_steps), *lanes,
+            jnp.asarray(budgets, jnp.int32), jnp.asarray(eos_ids, jnp.int32),
+            jnp.asarray(stop_ids, jnp.int32),
+            jnp.asarray(stop_lens, jnp.int32), jnp.asarray(tail, jnp.int32),
+            scales=scales, lora=lora)
 
     def verify_slots(self, k_pool, v_pool, tables, lengths, tokens, active,
-                     impl=None, k_scale=None, v_scale=None, lora=None):
+                     impl=None, scales=None, lora=None):
         """Speculative chunk verify for every serving slot (tokens:
         [B, G] — each slot's pending token followed by its draft
         proposals). The ``engine.verify`` fault site (and
@@ -1761,28 +1417,23 @@ class InferenceEngine:
         buffers."""
         from deepspeed_tpu.utils.faults import maybe_fire
         maybe_fire("engine.verify")
-        largs = self._lora_operands(lora)
-        impl = self.decode_impl if impl is None else impl
-        kernel = np.shape(tables) if impl == "pallas" else ()
-        if k_scale is None:
-            vf = self._verify_slots if lora is None else self._verify_slots_l
-            return self._run(
-                "verify_slots" if lora is None else "verify_slots_l", vf,
-                self.params, k_pool, v_pool,
-                jnp.asarray(tables, jnp.int32),
-                jnp.asarray(lengths, jnp.int32),
-                jnp.asarray(tokens, jnp.int32), jnp.asarray(active, bool),
-                impl, *largs, kernel_table=kernel)
-        maybe_fire("cache.quantize")
-        vf = (self._verify_slots_q if lora is None
-              else self._verify_slots_ql)
-        return self._run(
-            "verify_slots_q" if lora is None else "verify_slots_ql", vf,
-            self.params, k_pool, v_pool, k_scale, v_scale,  # dslint: disable=DS003 — exclusive branch: the fp dispatch above already returned
-            jnp.asarray(tables, jnp.int32),
-            jnp.asarray(lengths, jnp.int32),
-            jnp.asarray(tokens, jnp.int32), jnp.asarray(active, bool),
-            impl, *largs, kernel_table=kernel)
+        return self._run_slots(
+            "verify_slots", self._verify_slots, k_pool, v_pool, tables,
+            lengths, tokens, active, impl, scales=scales, lora=lora)
+
+    # the block-copy hooks PagedKVCache is wired with (copy_fn, gather_fn,
+    # scatter_fn): pools in, pools (or the gathered blocks) out
+    def cow_blocks(self, pools, src, dst):
+        return self._cow_blocks(pools,  # dslint: disable=DS012 — caller paged_cache._cow fires cache.cow before delegating here
+                                jnp.asarray(src, jnp.int32),
+                                jnp.asarray(dst, jnp.int32))
+
+    def gather_blocks(self, pools, ids):
+        return self._gather_blocks(pools, jnp.asarray(ids, jnp.int32))
+
+    def scatter_block(self, pools, blocks, dst):
+        return self._scatter_block(pools, blocks,  # dslint: disable=DS012 — caller paged_cache._dispatch_restore fires cache.restore before delegating here
+                                   jnp.asarray(dst, jnp.int32))
 
     def _forward_fn(self, params, tokens):
         x = self._embed(params, tokens)

@@ -104,79 +104,42 @@ def resolve_prefix_cache(flag: Optional[bool] = None) -> bool:
     return resolve_flag("DS_PREFIX_CACHE", flag)
 
 
-def _cow_copy_fn(k_pool, v_pool, src, dst):
+# The three block-copy programs, each over the TUPLE of pools — (k, v),
+# or with int8 pools (k, v, k_scale, v_scale) — so a block's per-(block,
+# kv_head) scales travel with its payload and a shared, spilled or
+# restored block dequantizes to the values it was written with. Block ids
+# are traced, so each program is one compiled entry per cache shape. The
+# serving engine wires its model engine's own jits of these functions in
+# (``copy_fn`` / ``gather_fn`` / ``scatter_fn``); a standalone cache runs
+# the module-level ones.
+def copy_block(pools, src, dst):
     """Copy ONE pool block (every layer) ``src`` -> ``dst``: the device
-    half of copy-on-write. Pools are donated so the copy is in-place in
-    HBM; ``src``/``dst`` are traced scalars, so every (src, dst) pair
-    reuses one compiled program."""
-    return (k_pool.at[:, dst].set(k_pool[:, src]),
-            v_pool.at[:, dst].set(v_pool[:, src]))
+    half of copy-on-write. Pools are donated, so the copy is in place in
+    HBM."""
+    return tuple(p.at[:, dst].set(p[:, src]) for p in pools)
 
 
-_default_cow = jax.jit(_cow_copy_fn, donate_argnums=(0, 1))
+def gather_blocks(pools, ids):
+    """Pull ``len(ids)`` blocks out of the pools: the device half of a
+    host-tier spill (spill_tick) and of a replica-to-replica migration
+    (migrate_gather). ``ids`` is a FIXED-width vector — short batches pad
+    with the trash block (its lanes are gathered and then simply not
+    stored). Pools are NOT donated: the gathered copy rides out
+    asynchronously while the pools keep serving decode."""
+    return tuple(p[:, ids] for p in pools)
 
 
-def _cow_copy_fn_q(k_pool, v_pool, k_scale, v_scale, src, dst):
-    """Quantized-pool COW: the block's per-(block, kv_head) scales travel
-    with its int8 payload — a shared block and its copy dequantize to the
-    same values."""
-    return (k_pool.at[:, dst].set(k_pool[:, src]),
-            v_pool.at[:, dst].set(v_pool[:, src]),
-            k_scale.at[:, dst].set(k_scale[:, src]),
-            v_scale.at[:, dst].set(v_scale[:, src]))
+def scatter_block(pools, blocks, dst):
+    """Write ONE restored block, ``blocks`` = its slice of every pool,
+    back at ``dst``: the device half of a host→device restore
+    (_dispatch_restore) and of a migration landing (land_parked). Pools
+    are donated: the write is in place in HBM, mirroring the COW copy."""
+    return tuple(p.at[:, dst].set(b) for p, b in zip(pools, blocks))
 
 
-_default_cow_q = jax.jit(_cow_copy_fn_q, donate_argnums=(0, 1, 2, 3))
-
-
-def _gather_blocks_fn(k_pool, v_pool, ids):
-    """Pull ``len(ids)`` blocks out of the pools (device side of a
-    spill). ``ids`` is a FIXED-width traced vector — every spill batch
-    reuses one compiled program, short batches pad with the trash block
-    (its lanes are gathered and then simply not stored). Pools are NOT
-    donated: the gathered copy rides out asynchronously while the pools
-    keep serving decode."""
-    return k_pool[:, ids], v_pool[:, ids]
-
-
-_default_gather = jax.jit(_gather_blocks_fn)
-
-
-def _gather_blocks_fn_q(k_pool, v_pool, k_scale, v_scale, ids):
-    """Quantized-pool spill gather: the int8 payload travels WITH its
-    fp32 per-(block, kv_head) scale sidecars, so a restored block
-    dequantizes to exactly what was spilled."""
-    return (k_pool[:, ids], v_pool[:, ids],
-            k_scale[:, ids], v_scale[:, ids])
-
-
-_default_gather_q = jax.jit(_gather_blocks_fn_q)
-
-
-def _scatter_block_fn(k_pool, v_pool, k_blk, v_blk, dst):
-    """Write ONE restored block back into the pools (device side of a
-    host→device restore). ``dst`` is a traced scalar — one compiled
-    program for every restore. Pools are donated: the write is in-place
-    in HBM, mirroring the COW copy."""
-    return (k_pool.at[:, dst].set(k_blk),
-            v_pool.at[:, dst].set(v_blk))
-
-
-_default_scatter = jax.jit(_scatter_block_fn, donate_argnums=(0, 1))
-
-
-def _scatter_block_fn_q(k_pool, v_pool, k_scale, v_scale,
-                        k_blk, v_blk, ks_blk, vs_blk, dst):
-    """Quantized-pool restore scatter: payload and scale sidecars land
-    together."""
-    return (k_pool.at[:, dst].set(k_blk),
-            v_pool.at[:, dst].set(v_blk),
-            k_scale.at[:, dst].set(ks_blk),
-            v_scale.at[:, dst].set(vs_blk))
-
-
-_default_scatter_q = jax.jit(_scatter_block_fn_q,
-                             donate_argnums=(0, 1, 2, 3))
+_default_cow = jax.jit(copy_block, donate_argnums=(0,))
+_default_gather = jax.jit(gather_blocks)
+_default_scatter = jax.jit(scatter_block, donate_argnums=(0,))
 
 
 class PagedKVCache:
@@ -192,16 +155,16 @@ class PagedKVCache:
     (shared prefix blocks count once per slot mapping them); a block is
     in exactly ONE of three states: on the free list, held (refcount >
     0), or cached (indexed, refcount 0, reclaimable in LRU order).
-    ``copy_fn(k, v, src, dst) -> (k, v)`` performs the COW block copy —
+    ``copy_fn(pools, src, dst) -> pools`` performs the COW block copy —
     the serving engine wires the engine's donated program in; standalone
     caches fall back to a module-level jitted copy.
 
     With ``kv_quant="int8"`` (or ``DS_KV_QUANT=int8``) the pools store
     int8 with fp32 per-(block, kv_head) scales in parallel ``k_scale`` /
-    ``v_scale`` pools ``[L, N_blocks, Hkv]``; ``copy_fn`` then takes and
-    returns the scale pools too (``(k, v, ks, vs, src, dst) -> 4-tuple``)
-    so scales travel with blocks on COW. ``"off"`` (default) keeps the
-    fp pools byte-identical to the unquantized cache — the bit-reference.
+    ``v_scale`` pools ``[L, N_blocks, Hkv]``, which ride in ``pools``
+    behind k and v, so scales travel with blocks on every copy. ``"off"``
+    (default) keeps the fp pools byte-identical to the unquantized cache
+    — the bit-reference.
 
     With ``host_tier=True`` (or ``DS_KV_HOST_TIER=on``) refcount-zero
     indexed blocks spill to host DRAM under HBM pressure instead of
@@ -210,10 +173,11 @@ class PagedKVCache:
     docs/KV_TIERING.md). The tier requires the prefix cache — only
     indexed blocks are worth keeping on ANY tier — so with
     ``prefix_cache=False`` the flag is inert and the device-only
-    allocator stays the bit-reference. ``gather_fn`` / ``scatter_fn``
-    override the transfer programs (the serving engine wires the
-    engine's jitted, correctly-sharded ones in); standalone caches fall
-    back to module-level jitted defaults.
+    allocator stays the bit-reference. ``gather_fn(pools, ids) ->
+    blocks`` / ``scatter_fn(pools, blocks, dst) -> pools`` override the
+    transfer programs (the serving engine wires the engine's jitted
+    ones in); standalone caches fall back to module-level jitted
+    defaults.
     """
 
     def __init__(self, cfg: GPTConfig, *, num_slots: int,
@@ -1103,44 +1067,42 @@ class PagedKVCache:
         self._restore_ms = []
         return out
 
+    @property
+    def scales(self) -> Optional[tuple]:
+        """``(k_scale, v_scale)`` of int8 pools, else None: the serving
+        programs' ``scales`` operand."""
+        return None if self.k_scale is None else (self.k_scale, self.v_scale)
+
+    @property
+    def pools(self) -> tuple:
+        """The cache's device state as ONE value, what every block copy
+        takes and every serving program hands back: ``(k, v)``, with
+        int8 pools ``(k, v, k_scale, v_scale)``; for a model of two
+        attention kinds k and v are hybrid.PagedState."""
+        return (self.k, self.v) + (self.scales or ())
+
+    @pools.setter
+    def pools(self, pools) -> None:
+        self.k, self.v, *scales = pools
+        if scales:
+            self.k_scale, self.v_scale = scales
+
     def _run_gather(self, ids: np.ndarray):
-        """Dispatch the (quant-aware) fixed-width spill gather."""
-        if self.quantized:
-            fn = self.gather_fn if self.gather_fn is not None \
-                else _default_gather_q
-            return fn(self.k, self.v, self.k_scale, self.v_scale, ids)
-        fn = self.gather_fn if self.gather_fn is not None \
-            else _default_gather
-        return fn(self.k, self.v, ids)
+        """Dispatch the fixed-width spill gather."""
+        return (self.gather_fn or _default_gather)(self.pools, ids)
 
     def _run_scatter(self, payload: tuple, bid: int) -> None:
-        """Dispatch the (quant-aware) restore scatter, rebinding pools
-        from its donated outputs."""
-        dev_arrays = tuple(jax.device_put(a) for a in payload)
-        if self.quantized:
-            fn = self.scatter_fn if self.scatter_fn is not None \
-                else _default_scatter_q
-            (self.k, self.v, self.k_scale, self.v_scale) = fn(
-                self.k, self.v, self.k_scale, self.v_scale,
-                *dev_arrays, np.int32(bid))
-        else:
-            fn = self.scatter_fn if self.scatter_fn is not None \
-                else _default_scatter
-            self.k, self.v = fn(self.k, self.v, *dev_arrays,
-                                np.int32(bid))
+        """Dispatch the restore scatter, rebinding the pools from its
+        donated outputs."""
+        blocks = tuple(jax.device_put(a) for a in payload)
+        self.pools = (self.scatter_fn or _default_scatter)(
+            self.pools, blocks, np.int32(bid))
 
     # -- internals -----------------------------------------------------
     def _run_cow(self, src, dst) -> None:
-        """Dispatch the (quant-aware) COW copy program, rebinding pools
-        (and scale pools when quantized) from its donated outputs."""
-        if self.quantized:
-            fn = self.copy_fn if self.copy_fn is not None \
-                else _default_cow_q
-            (self.k, self.v, self.k_scale, self.v_scale) = fn(
-                self.k, self.v, self.k_scale, self.v_scale, src, dst)
-        else:
-            fn = self.copy_fn if self.copy_fn is not None else _default_cow
-            self.k, self.v = fn(self.k, self.v, src, dst)
+        """Dispatch the COW copy program, rebinding the pools from its
+        donated outputs."""
+        self.pools = (self.copy_fn or _default_cow)(self.pools, src, dst)
 
     def _cow(self, src: int, dst: int) -> None:
         self._run_cow(np.int32(src), np.int32(dst))

@@ -98,7 +98,7 @@ slot, and the router migrates the finished KV prefix to a decode
 replica through a CRC-verified host-DRAM staging pool — the host
 tier's gather/scatter transfer path generalized replica-to-replica
 (per-array CRC32 at put, free-list-only landing at the destination,
-int8 ``_q`` twins carrying their scale sidecars). The request itself
+int8 pools carrying their scale sidecars). The request itself
 rides the snapshot envelope (``snapshot_entry`` extended with a
 ``kv_handle``) and resumes decode WITHOUT re-prefilling: admission
 adopts the parked chain. Three chaos sites guard the channel —

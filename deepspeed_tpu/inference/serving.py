@@ -570,15 +570,14 @@ class ServingEngine:
         self.faults = faults if faults is not None else faults_lib.active()
         self.prefix_cache = resolve_prefix_cache(prefix_cache)
         # int8 KV-cache pools with per-block scales (DS_KV_QUANT=int8):
-        # resolved once here, pinned for the run — the quantized slot
-        # programs are separate executables, so a run uses EITHER the fp
-        # set or the int8 set, never both
+        # resolved once here, pinned for the run — the cache then holds
+        # scale pools beside k and v (``cache.pools``), and every
+        # program compiles its int8 entry and no other
         self.kv_quant = resolve_kv_quant(kv_quant)
-        self._quant = self.kv_quant == "int8"
         # multi-tenant LoRA serving (inference/adapters.py): resolved
-        # once here, pinned for the run — the lora program twins are
-        # separate executables, so a run uses EITHER the base set or
-        # the lora set, never both (docs/ADAPTERS.md)
+        # once here, pinned for the run — every dispatch then carries
+        # the adapter operands, so a run compiles EITHER the base
+        # entries or the adapter entries, never both (docs/ADAPTERS.md)
         self.lora_serve = resolve_lora_serve(lora_serve)
         # what cannot yet live with bounded window state raises here, by
         # name (prefix sharing, the host tier and int8 pools: the cache)
@@ -590,25 +589,21 @@ class ServingEngine:
                 (self.lora_serve, "LoRA serving (lora_serve)")):
             if on:
                 hybrid.refuse(engine.cfg, what)
-        cow = getattr(engine, "cow_blocks_q" if self._quant
-                      else "cow_blocks", None)
-        # host-tier transfer programs: like COW, the engine's jitted
-        # (and correctly-sharded) gather/scatter are wired in when
-        # present; the quantized pair moves the scale sidecars too
-        gather = getattr(engine, "gather_blocks_q" if self._quant
-                         else "gather_blocks", None)
-        scatter = getattr(engine, "scatter_block_q" if self._quant
-                          else "scatter_block", None)
+        # the engine's own jits of the three block copies (COW, and the
+        # host tier's gather and scatter) are wired in when present:
+        # each takes the cache's pools whole, scales included
         self.cache = PagedKVCache(
             engine.cfg, num_slots=num_slots, block_size=block_size,
             num_blocks=num_blocks, hbm_budget_bytes=hbm_budget_bytes,
             dtype=engine.dtype, max_seq_len=engine.max_seq_len,
             faults=self.faults, prefix_cache=self.prefix_cache,
-            copy_fn=cow, kv_quant=self.kv_quant,
+            copy_fn=getattr(engine, "cow_blocks", None),
+            kv_quant=self.kv_quant,
             host_tier=resolve_host_tier(host_tier),
             host_budget_bytes=host_budget_bytes,
             spill_watermark=spill_watermark,
-            gather_fn=gather, scatter_fn=scatter,
+            gather_fn=getattr(engine, "gather_blocks", None),
+            scatter_fn=getattr(engine, "scatter_block", None),
             tracer=self.telemetry.tracer
             if self.telemetry.enabled else None)
         # the EFFECTIVE switch: the cache gates the tier on the prefix
@@ -629,13 +624,7 @@ class ServingEngine:
             # cold start (caught by test_serving_compile_count_contract)
             from jax.sharding import NamedSharding, PartitionSpec
             pool_sh = NamedSharding(mesh, PartitionSpec())
-            self.cache.k = jax.device_put(self.cache.k, pool_sh)
-            self.cache.v = jax.device_put(self.cache.v, pool_sh)
-            if self._quant:
-                self.cache.k_scale = jax.device_put(self.cache.k_scale,
-                                                    pool_sh)
-                self.cache.v_scale = jax.device_put(self.cache.v_scale,
-                                                    pool_sh)
+            self.cache.pools = jax.device_put(self.cache.pools, pool_sh)
         # compile the COW copy program now (after pool placement, so the
         # warmed executable matches steady-state shardings): the first
         # mid-block divergence must not add a compile inside the
@@ -827,7 +816,7 @@ class ServingEngine:
                 "error (half the hottest block's quantization step)",
                 buckets=(1e-4, 2.5e-4, 5e-4, 1e-3, 2.5e-3, 5e-3,
                          1e-2, 2.5e-2, 5e-2, 1e-1, 2.5e-1)) \
-                if self._quant else None
+                if self.cache.quantized else None
             # host-tier plane (docs/KV_TIERING.md): DRAM footprint gauge
             # plus per-restore latency histogram — restores sit on the
             # admission path, so their tail IS the warm-hit TTFT tax
@@ -1470,23 +1459,12 @@ class ServingEngine:
         # signature change); only the FINAL chunk's sample is kept
         lane = self.sampler.lane(slot, len(req.out))
         lora = self._lora_args(slot)
-        if self._quant:
-            (logits, tok, lp, self.cache.k, self.cache.v,
-             self.cache.k_scale, self.cache.v_scale) = self._device_call(
-                "serving.prefill",
-                lambda *a: self.engine.prefill_into_slot(
-                    *a, sample_state=lane, lora=lora),
-                self.cache.k, self.cache.v, self.cache.tables[slot],
-                chunk, done, n, self.cache.k_scale,
-                self.cache.v_scale, now=now)
-        else:
-            (logits, tok, lp, self.cache.k,
-             self.cache.v) = self._device_call(
-                "serving.prefill",
-                lambda *a: self.engine.prefill_into_slot(
-                    *a, sample_state=lane, lora=lora),
-                self.cache.k, self.cache.v, self.cache.tables[slot],
-                chunk, done, n, now=now)
+        logits, tok, lp, *self.cache.pools = self._device_call(
+            "serving.prefill",
+            lambda *a: self.engine.prefill_into_slot(
+                *a, scales=self.cache.scales, sample_state=lane, lora=lora),
+            self.cache.k, self.cache.v, self.cache.tables[slot], chunk,
+            done, n, now=now)
         self.cache.advance(slot, n)
         self._progress[slot] = done + n
         self._stat["prefill_chunks"].inc()
@@ -1611,24 +1589,12 @@ class ServingEngine:
         budget = self.step_time_budget_s
         t0 = time.perf_counter() if budget is not None else 0.0
         lora = self._lora_args()
-        if self._quant:
-            (logits, toks, lps, self.cache.k, self.cache.v,
-             self.cache.k_scale, self.cache.v_scale) = self._device_call(
-                "serving.decode",
-                lambda *a: self.engine.decode_slots(
-                    *a, sample_state=lanes, lora=lora),
-                self.cache.k, self.cache.v, self.cache.tables,
-                self.cache.lengths, tokens, active, self.decode_impl,
-                self.cache.k_scale, self.cache.v_scale, now=now)
-        else:
-            (logits, toks, lps, self.cache.k,
-             self.cache.v) = self._device_call(
-                "serving.decode",
-                lambda *a: self.engine.decode_slots(
-                    *a, sample_state=lanes, lora=lora),
-                self.cache.k, self.cache.v, self.cache.tables,
-                self.cache.lengths, tokens, active, self.decode_impl,
-                now=now)
+        logits, toks, lps, *self.cache.pools = self._device_call(
+            "serving.decode",
+            lambda *a: self.engine.decode_slots(
+                *a, scales=self.cache.scales, sample_state=lanes, lora=lora),
+            self.cache.k, self.cache.v, self.cache.tables,
+            self.cache.lengths, tokens, active, self.decode_impl, now=now)
         if budget is not None:
             self._watchdog_note(time.perf_counter() - t0)
         self._stat["decode_steps"].inc()
@@ -1734,25 +1700,13 @@ class ServingEngine:
         budget = self.step_time_budget_s
         t0 = time.perf_counter() if budget is not None else 0.0
         lora = self._lora_args()
-        if self._quant:
-            (toks, lps, produced, done, self.cache.k, self.cache.v,
-             self.cache.k_scale, self.cache.v_scale) = self._device_call(
-                "serving.decode",
-                lambda *a: self.engine.decode_horizon(
-                    *a, sample_state=lanes, lora=lora),
-                self.cache.k, self.cache.v, self.cache.tables,
-                self.cache.lengths, tokens, active, N, budgets, eos_ids,
-                stop_ids, stop_lens, tail, self.decode_impl,
-                self.cache.k_scale, self.cache.v_scale, now=now)
-        else:
-            (toks, lps, produced, done, self.cache.k,
-             self.cache.v) = self._device_call(
-                "serving.decode",
-                lambda *a: self.engine.decode_horizon(
-                    *a, sample_state=lanes, lora=lora),
-                self.cache.k, self.cache.v, self.cache.tables,
-                self.cache.lengths, tokens, active, N, budgets, eos_ids,
-                stop_ids, stop_lens, tail, self.decode_impl, now=now)
+        toks, lps, produced, done, *self.cache.pools = self._device_call(
+            "serving.decode",
+            lambda *a: self.engine.decode_horizon(
+                *a, scales=self.cache.scales, sample_state=lanes, lora=lora),
+            self.cache.k, self.cache.v, self.cache.tables,
+            self.cache.lengths, tokens, active, N, budgets, eos_ids,
+            stop_ids, stop_lens, tail, self.decode_impl, now=now)
         if budget is not None:
             self._watchdog_note(time.perf_counter() - t0,
                                 scale=int(budgets[live].max()))
@@ -1868,19 +1822,10 @@ class ServingEngine:
             # no retry wrapper: a verify fault degrades to the plain
             # path (which retries) instead of re-speculating — the fault
             # fires before dispatch, so the donated pools are intact
-            lora = self._lora_args()
-            if self._quant:
-                (logits, self.cache.k, self.cache.v, self.cache.k_scale,
-                 self.cache.v_scale) = self.engine.verify_slots(
-                    self.cache.k, self.cache.v, self.cache.tables,
-                    self.cache.lengths, tokens, active, self.decode_impl,
-                    self.cache.k_scale, self.cache.v_scale, lora=lora)
-            else:
-                logits, self.cache.k, self.cache.v = \
-                    self.engine.verify_slots(
-                        self.cache.k, self.cache.v, self.cache.tables,
-                        self.cache.lengths, tokens, active,
-                        self.decode_impl, lora=lora)
+            logits, *self.cache.pools = self.engine.verify_slots(
+                self.cache.k, self.cache.v, self.cache.tables,
+                self.cache.lengths, tokens, active, self.decode_impl,
+                scales=self.cache.scales, lora=self._lora_args())
         except TransientDeviceError:
             self._stat["spec_fallbacks"].inc()
             logger.warning("serving: verify fault; degrading this step "
@@ -2073,7 +2018,10 @@ class ServingEngine:
         (``now`` is the scheduler-clock step stamp): a backoff can
         never sleep a live request past its deadline — with no margin
         left, retries spin immediately and expiry decides at the next
-        step."""
+        step. ``args`` lead with the K and V pools and the program's host
+        operands in the wrapper's order: the benchmark wraps this method
+        and reads them by position (``benchmark/harness/spans.py``,
+        ``benchmark/drivers/serve.py`` ``on_dispatch``)."""
         delay = self.retry_backoff_s
         attempt = 0
         tracer = self.telemetry.tracer

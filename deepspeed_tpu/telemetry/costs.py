@@ -14,7 +14,7 @@ per request, and per tenant. Three pieces:
   here so the two sides can never disagree;
 - :class:`ProgramCostRegistry` — walks the shared
   ``utils/jit_registry.py`` engine program catalog and records, per
-  compiled twin, XLA's own ``cost_analysis()``/``memory_analysis()``
+  program id, XLA's own ``cost_analysis()``/``memory_analysis()``
   numbers when a lowered executable is available, falling back to the
   analytic formulas at a reference shape when XLA declines (so the
   registry is always populated, CPU included); with telemetry on it
@@ -41,7 +41,6 @@ import re
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from deepspeed_tpu.utils.jit_registry import (DISPATCH_CLASSES,
-                                              dispatch_class,
                                               engine_programs)
 
 __all__ = ["PEAK_FLOPS", "PEAK_HBM_BYTES_PER_S", "device_peak_flops",
@@ -474,7 +473,7 @@ class ProgramCostRegistry:
     # .. population .....................................................
 
     def populate(self, engine, cache=None, compiled=None) -> None:
-        """Fill one entry per registered twin present on ``engine``.
+        """Fill one entry per program id whose callable ``engine`` has.
 
         ``compiled`` optionally maps program id -> an object exposing
         ``cost_analysis()``/``memory_analysis()`` (an AOT

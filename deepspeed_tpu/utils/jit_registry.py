@@ -68,83 +68,44 @@ def cache_size(jitted_fn) -> Optional[int]:
     return int(probe())
 
 
-# Serving-side program catalog: every jitted entry point the paged
-# engine dispatches in steady state, by family stem and precision/LoRA
-# twin suffix ("" fp, "_q" int8 KV, "_l" LoRA, "_ql" both). The cost
-# registry (telemetry/costs.py) walks this table to probe
-# ``cost_analysis()``/``memory_analysis()`` per program, and the
-# per-dispatch accountant keys its charges on the same program ids —
-# one table so the two planes can never disagree about what exists.
-# ``cow_blocks`` and the host-tier transfer programs have no LoRA
-# variant (they move cache bytes, not weights).
-ENGINE_PROGRAM_FAMILIES: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
-    ("prefill_slot", ("", "_q", "_l", "_ql")),
-    ("decode_slots", ("", "_q", "_l", "_ql")),
-    ("decode_horizon", ("", "_q", "_l", "_ql")),
-    ("verify_slots", ("", "_q", "_l", "_ql")),
-    ("cow_blocks", ("", "_q")),
-    ("gather_blocks", ("", "_q")),
-    ("scatter_block", ("", "_q")),
-)
-
-# Declared per-feature twin deltas: what a feature suffix is ALLOWED to
-# change relative to the base program. dslint's DS015 normalizes each
-# twin's AST modulo this spec and flags any other divergence, so an edit
-# to ``_decode_slots_fn`` that misses ``_decode_slots_q_fn`` is a lint
-# error instead of a silent parity bug. Suffix characters compose:
-# ``_ql`` owns the union of the "q" and "l" deltas.
-#
-#   params : extra positional parameters the twin's signature may add
-#   names  : local/parameter names the feature owns — any statement or
-#            tuple/call element mentioning ONLY these is feature-owned
-#            and stripped before comparison (q: the scale pools beside
-#            the int8 pools; l: the adapter pools and table rows)
-#   kwargs : call keywords the twin may thread through (``k_scale=``,
-#            ``lora_ops=``) that the base never passes
-TWIN_DELTAS = {
-    "q": {
-        "params": ("k_scale", "v_scale", "ks_blk", "vs_blk"),
-        "names": ("k_scale", "v_scale", "ks_blk", "vs_blk"),
-        "kwargs": ("k_scale", "v_scale"),
-    },
-    "l": {
-        "params": ("lora_a", "lora_b", "ablocks", "ablock_row"),
-        "names": ("lora_a", "lora_b", "ablocks", "ablock_row",
-                  "lora", "lora_ops"),
-        "kwargs": ("lora", "lora_ops"),
-    },
-}
-
-
-# program family stem -> dispatch class the accountant rolls it into
+# Serving-side program catalog. The paged engine holds ONE jitted
+# callable per family (``InferenceEngine._<stem>``). The int8 scale pools
+# and the adapter operands reach it as optional pytree operands, so a
+# family's variants are cache entries of that one callable, told apart
+# by jax from the structure of what it is handed. The cost registry
+# (telemetry/costs.py), the accountant and the ``program_*_<pid>`` gauges
+# still name a variant by its own id: a cost card differs by variant, and
+# the ids are a documented contract (tools/dslint/telemetry_schema.json,
+# docs/OBSERVABILITY.md). ``program_id`` is the one place an id is made.
+# Rows: (family stem, dispatch class, takes adapter operands); the block
+# copies move cache bytes, not weights, and have no adapter variant.
 DISPATCH_CLASSES: Tuple[str, ...] = (
     "prefill", "decode", "verify", "cow", "spill")
-_FAMILY_CLASS = {
-    "prefill_slot": "prefill",
-    "decode_slots": "decode",
-    "decode_horizon": "decode",
-    "verify_slots": "verify",
-    "cow_blocks": "cow",
-    "gather_blocks": "spill",
-    "scatter_block": "spill",
-}
+ENGINE_PROGRAM_FAMILIES: Tuple[Tuple[str, str, bool], ...] = (
+    ("prefill_slot", "prefill", True),
+    ("decode_slots", "decode", True),
+    ("decode_horizon", "decode", True),
+    ("verify_slots", "verify", True),
+    ("cow_blocks", "cow", False),
+    ("gather_blocks", "spill", False),
+    ("scatter_block", "spill", False),
+)
+
+
+def program_id(stem: str, scales: bool = False, adapter: bool = False) -> str:
+    """The id of one variant of family ``stem``: the stem itself for the
+    fp program, ``_q`` with int8 scale pools, ``_l`` with adapter
+    operands, ``_ql`` with both."""
+    mark = ("q" if scales else "") + ("l" if adapter else "")
+    return f"{stem}_{mark}" if mark else stem
 
 
 def engine_programs() -> Tuple[Tuple[str, str, str], ...]:
-    """``(program_id, engine_attr, dispatch_class)`` for every serving
-    program: ``("decode_slots_ql", "_decode_slots_ql", "decode")``."""
-    out = []
-    for stem, suffixes in ENGINE_PROGRAM_FAMILIES:
-        for suf in suffixes:
-            out.append((stem + suf, "_" + stem + suf, _FAMILY_CLASS[stem]))
-    return tuple(out)
-
-
-def dispatch_class(program_id: str) -> str:
-    """Dispatch class for a program id (``decode_horizon_q`` →
-    ``decode``); raises ``KeyError`` on an unknown id."""
-    for stem, suffixes in ENGINE_PROGRAM_FAMILIES:
-        for suf in suffixes:
-            if program_id == stem + suf:
-                return _FAMILY_CLASS[stem]
-    raise KeyError(f"unknown engine program id: {program_id!r}")
+    """``(program_id, engine_attr, dispatch_class)`` for every variant of
+    every family, a family's variants all naming its one engine
+    attribute: ``("decode_slots_ql", "_decode_slots", "decode")``."""
+    return tuple(
+        (program_id(stem, scales, adapter), "_" + stem, cls)
+        for stem, cls, adapters in ENGINE_PROGRAM_FAMILIES
+        for adapter in ((False, True) if adapters else (False,))
+        for scales in (False, True))

@@ -1,18 +1,17 @@
 """dslint v3 tests: the CFG + dataflow core and the flow-sensitive
-rules DS015–DS018.
+rules DS016–DS018.
 
 Same three-layer shape as tests/test_dslint_interproc.py:
   1. dataflow machinery — CFG construction units (if/else, while,
      for-else, try/except/finally, early return), gen/kill fixpoint
      convergence on loops, interprocedural pair summaries, and the
      hash-keyed import-graph cache invalidation;
-  2. per-rule fixtures — for each of DS015–DS018 at least one
+  2. per-rule fixtures — for each of DS016–DS018 at least one
      true-positive package that MUST flag and one clean twin that MUST
-     NOT, plus the seeded engine mutation (delete one statement from
-     ``_decode_slots_q_fn`` → DS015 catches it);
-  3. regressions + self-scan — the real findings this PR fixed stay
-     fixed (verify-twin ``impl`` default), and the whole tree lints
-     clean under DS015–DS018 in under 15s.
+     NOT;
+  3. regressions + self-scan — the real findings the rules' PR fixed
+     stay fixed, and the whole tree lints clean under DS016–DS018 in
+     under 15s.
 """
 
 import ast
@@ -24,7 +23,7 @@ import textwrap
 from tools.dslint import build_symbol_table
 from tools.dslint.core import REPO_ROOT, analyze_package, link_parents
 from tools.dslint.dataflow import (DEFAULT_PAIRS, EXC, GenKill,
-                                   JitTwinDrift, ResourcePairing,
+                                   ResourcePairing,
                                    SnapshotRoundTrip, TracedValueEscape,
                                    build_cfg, build_pair_summaries,
                                    dataflow_rules, solve_forward,
@@ -336,119 +335,10 @@ def test_editing_the_registry_changes_the_cache_key(tmp_path):
     assert load_callgraph_cache(p, inputs=cache_input_hashes(files))
 
     reg.write_text(reg.read_text()
-                   + "\nTWIN_DELTAS['q']['names'] += ('extra',)\n")
+                   + "\nJIT_WRAPPER_CHAINS += ((\"xjit\",),)\n")
     after = cache_input_hashes(files)
     assert after != before
     assert load_callgraph_cache(p, inputs=after) == {}
-
-
-# ---------------------------------------------------------------------------
-# DS015: jit-twin drift
-# ---------------------------------------------------------------------------
-
-_TOY_SPEC = (
-    (("toy", ("", "_q")),),
-    {"q": {"params": ("k_scale",), "names": ("k_scale", "kss"),
-           "kwargs": ("k_scale",)}},
-)
-
-_TOY_BASE = """\
-    def _toy_fn(params, k_pool, tokens):
-        x = params + tokens
-        y = combine(x, k_pool)
-        return y, k_pool
-"""
-
-
-def _toy_pkg(twin):
-    # dedent each half separately — concatenating differently-indented
-    # literals would nest the twin inside the base function
-    return (textwrap.dedent(_TOY_BASE) + "\n\n" + textwrap.dedent(twin))
-
-
-def test_ds015_clean_twin_collapses_modulo_declared_delta():
-    twin = """\
-        def _toy_q_fn(params, k_pool, k_scale, tokens):
-            x = params + tokens
-            kss = rescale(k_scale)
-            y = combine(x, k_pool, k_scale=kss)
-            return y, k_pool, kss
-    """
-    hits = rule_hits(JitTwinDrift(spec=_TOY_SPEC), {
-        "deepspeed_tpu/inference/engine.py": _toy_pkg(twin)})
-    assert hits == []
-
-
-def test_ds015_statement_drift_outside_delta_flags():
-    twin = """\
-        def _toy_q_fn(params, k_pool, k_scale, tokens):
-            x = params - tokens
-            kss = rescale(k_scale)
-            y = combine(x, k_pool, k_scale=kss)
-            return y, k_pool, kss
-    """
-    hits = rule_hits(JitTwinDrift(spec=_TOY_SPEC), {
-        "deepspeed_tpu/inference/engine.py": _toy_pkg(twin)})
-    assert len(hits) == 1
-    assert hits[0].rule == "DS015"
-    assert "_toy_q_fn" in hits[0].message
-    assert "statement 1" in hits[0].message
-
-
-def test_ds015_missing_statement_flags():
-    twin = """\
-        def _toy_q_fn(params, k_pool, k_scale, tokens):
-            x = params + tokens
-            return combine(x, k_pool, k_scale=k_scale), k_pool
-    """
-    hits = rule_hits(JitTwinDrift(spec=_TOY_SPEC), {
-        "deepspeed_tpu/inference/engine.py": _toy_pkg(twin)})
-    assert len(hits) == 1
-    assert "_toy_q_fn" in hits[0].message
-
-
-def test_ds015_signature_drift_flags():
-    twin = """\
-        def _toy_q_fn(params, k_pool, k_scale, tokens, extra):
-            x = params + tokens
-            y = combine(x, k_pool, k_scale=k_scale)
-            return y, k_pool
-    """
-    hits = rule_hits(JitTwinDrift(spec=_TOY_SPEC), {
-        "deepspeed_tpu/inference/engine.py": _toy_pkg(twin)})
-    assert len(hits) == 1
-    assert "signature" in hits[0].message
-
-
-def test_ds015_registered_twin_missing_is_a_completeness_finding():
-    files = {"deepspeed_tpu/inference/engine.py": _TOY_BASE}
-    hits = rule_hits(JitTwinDrift(spec=_TOY_SPEC), files)
-    assert len(hits) == 1 and "_toy_q_fn" in hits[0].message
-    # targeted/closure runs can't see absence
-    assert rule_hits(JitTwinDrift(spec=_TOY_SPEC), files,
-                     partial=True) == []
-
-
-def test_ds015_seeded_mutation_of_decode_slots_q_is_caught():
-    """The acceptance bar: delete ONE statement from the real
-    ``_decode_slots_q_fn`` body and DS015 must flag the twin."""
-    src = (REPO_ROOT / "deepspeed_tpu" / "inference"
-           / "engine.py").read_text()
-    tree = ast.parse(src)
-    fn = next(n for n in ast.walk(tree)
-              if isinstance(n, ast.FunctionDef)
-              and n.name == "_decode_slots_q_fn")
-    # drop the first non-docstring statement (`cfg = self.cfg`)
-    del fn.body[1]
-    mutated = ast.unparse(tree)
-    hits = rule_hits(JitTwinDrift(), {
-        "deepspeed_tpu/inference/engine.py": mutated}, partial=True)
-    assert any("_decode_slots_q_fn" in h.message for h in hits), \
-        [h.message for h in hits]
-    # ...and the unmutated engine is clean (the clean-twin direction
-    # against the real tree)
-    assert rule_hits(JitTwinDrift(), {
-        "deepspeed_tpu/inference/engine.py": src}, partial=True) == []
 
 
 # ---------------------------------------------------------------------------
@@ -745,28 +635,8 @@ def test_ds018_module_without_snapshot_contract_is_ignored():
 
 
 # ---------------------------------------------------------------------------
-# regressions: the real findings this PR fixed stay fixed
+# regressions: the real findings the rules' PR fixed stay fixed
 # ---------------------------------------------------------------------------
-
-def test_verify_twins_share_the_impl_default():
-    """DS015's first real catch: `_verify_slots_l_fn`/`_verify_slots_ql_fn`
-    had dropped the `impl="gather"` default the base (and q twin)
-    carry — all four twins must agree."""
-    src = (REPO_ROOT / "deepspeed_tpu" / "inference"
-           / "engine.py").read_text()
-    expected = {"_verify_slots_fn", "_verify_slots_q_fn",
-                "_verify_slots_l_fn", "_verify_slots_ql_fn"}
-    seen = {}
-    for node in ast.walk(ast.parse(src)):
-        if isinstance(node, ast.FunctionDef) and node.name in expected:
-            args = node.args.args
-            defaults = [None] * (len(args) - len(node.args.defaults)) \
-                + list(node.args.defaults)
-            impl = dict(zip((a.arg for a in args), defaults))["impl"]
-            seen[node.name] = getattr(impl, "value", None)
-    assert set(seen) == expected
-    assert all(v == "gather" for v in seen.values()), seen
-
 
 def test_serving_snapshot_ephemeral_matches_request_fields():
     """The DS018 allowlist only names real ServeRequest fields (the
@@ -814,16 +684,16 @@ def test_sarif_rules_carry_lintmd_help_anchors():
     log = to_sarif([], [])
     rules = log["runs"][0]["tool"]["driver"]["rules"]
     by_id = {r["id"]: r for r in rules}
-    assert by_id["DS015"]["helpUri"].endswith(
+    assert by_id["DS016"]["helpUri"].endswith(
         "#the-flow-sensitive-rules-phase-3")
     assert by_id["DS011"]["helpUri"].endswith(
         "#the-interprocedural-rules-phase-2")
     assert by_id["DS001"]["helpUri"].endswith("#the-rules")
-    assert {"DS015", "DS016", "DS017", "DS018"} <= set(by_id)
+    assert {"DS016", "DS017", "DS018"} <= set(by_id)
 
 
 # ---------------------------------------------------------------------------
-# self-scan: the whole tree lints clean under DS015–DS018, fast
+# self-scan: the whole tree lints clean under DS016–DS018, fast
 # ---------------------------------------------------------------------------
 
 def test_v3_self_scan_clean_and_under_budget():

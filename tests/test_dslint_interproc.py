@@ -485,7 +485,7 @@ def test_cli_rules_filter_reaches_interproc():
         [sys.executable, "-m", "tools.dslint", "--list-rules"],
         capture_output=True, text=True, cwd=REPO_ROOT)
     for rid in ("DS011", "DS012", "DS013", "DS014",
-                "DS015", "DS016", "DS017", "DS018"):
+                "DS016", "DS017", "DS018"):
         assert rid in r.stdout
 
 
@@ -511,7 +511,7 @@ def test_two_phase_self_scan_zero_new_findings():
 def test_interproc_catalog_complete():
     cat = interproc_catalog()
     assert [r["id"] for r in cat] == ["DS011", "DS012", "DS013", "DS014",
-                                      "DS015", "DS016", "DS017", "DS018"]
+                                      "DS016", "DS017", "DS018"]
     assert all(r["rationale"] for r in cat)
     assert len(interproc_rules()) == len(cat)
     # combined catalogs don't collide

@@ -316,12 +316,12 @@ def test_horizon_load_gen_stamps_exact(eng):
 
 
 # ---------------------------------------------------------------------------
-# composition: kv-quant / LoRA twins, spec precedence
+# composition: kv-quant / LoRA variants, spec precedence
 # ---------------------------------------------------------------------------
 
 @pytest.mark.slow
 def test_horizon_kv_quant_parity(eng):
-    """The int8 pool rides the ``_decode_horizon_q`` twin: the horizon
+    """The int8 pool rides ``_decode_horizon``'s int8 entry: the horizon
     must be bit-identical to the N=1 run ON THE SAME quantized layout
     (int8-vs-fp tolerance is test_kv_quant_serving's business)."""
     prompts = prompts_of((5, 9, 12, 3))
@@ -332,15 +332,15 @@ def test_horizon_kv_quant_parity(eng):
     for i in range(len(prompts)):
         np.testing.assert_array_equal(out[i], ref[i])
     from deepspeed_tpu.utils.compile_guard import cache_size
-    n_q = cache_size(eng._decode_horizon_q)
-    if n_q is not None:                  # the quant twin really served
+    n_q = cache_size(eng._decode_horizon)
+    if n_q is not None:                  # the horizon really served
         assert n_q >= 1
 
 
 @pytest.mark.slow
 def test_horizon_lora_parity(eng):
     """Heterogeneous base+adapter batches decode through the
-    ``_decode_horizon_l`` twin bit-identically to N=1."""
+    ``_decode_horizon`` adapter entry bit-identically to N=1."""
     from deepspeed_tpu.runtime.lora import add_lora, adapter_state_dict
     cfg, params = tiny()
     e = InferenceEngine(config=cfg, params=params, dtype=jnp.float32)

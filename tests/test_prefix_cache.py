@@ -368,13 +368,9 @@ def test_serving_compile_contract_with_prefix_cache(devices):
     assert srv.cache.cow_copies >= 1                  # COW ran inside watch
     assert srv.stats["prefix_hits"] >= 2
     # under DS_KV_QUANT=int8 / DS_LORA_SERVE=on the active set is the
-    # _q / _l / _ql jit twin family — the per-program count contract is
-    # the same (COW copies blocks, not adapters: no _l twin there)
-    quant = srv.kv_quant == "int8"
-    sfx = ("_q" if quant else "") + ("_l" if srv.lora_serve else "")
-    pf = getattr(eng, "_prefill_slot" + sfx)
-    dc = getattr(eng, "_decode_slots" + sfx)
-    cw = eng._cow_blocks_q if quant else eng._cow_blocks
+    # int8 / adapter entry of the same callables — the per-program count
+    # contract is the same (COW copies blocks, not adapters)
+    pf, dc, cw = eng._prefill_slot, eng._decode_slots, eng._cow_blocks
     n_prefill = cache_size(pf)
     if n_prefill is not None:
         assert n_prefill == 1

@@ -387,9 +387,7 @@ def test_router_compile_contract_under_chaos():
         return router, out
 
     run_workload([])                        # warmup: compile everything
-    quant = mk_fleet(eng, n=1)[0].kv_quant == "int8"
-    pf = eng._prefill_slot_q if quant else eng._prefill_slot
-    dc = eng._decode_slots_q if quant else eng._decode_slots
+    pf, dc = eng._prefill_slot, eng._decode_slots
     n_prefill, n_decode = cache_size(pf), cache_size(dc)
     if n_prefill is not None:
         assert (n_prefill, n_decode) == (1, 1), (

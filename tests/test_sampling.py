@@ -434,9 +434,7 @@ def test_sampling_compile_contract_mixed_lanes(devices):
     sampled = dict(temperature=0.9, top_k=20, top_p=0.9, seed=3)
     srv, _ = workload(sampled, {})               # warmup: mixed batch
     assert srv.stats["evictions"] >= 1
-    quant = srv.kv_quant == "int8"
-    pf = eng._prefill_slot_q if quant else eng._prefill_slot
-    dc = eng._decode_slots_q if quant else eng._decode_slots
+    pf, dc = eng._prefill_slot, eng._decode_slots
     n_prefill, n_decode = cache_size(pf), cache_size(dc)
     if n_prefill is not None:
         assert (n_prefill, n_decode) == (1, 1), (
@@ -475,9 +473,7 @@ def test_spec_sampled_compile_contract(devices):
     sampled = dict(temperature=0.8, seed=5)
     srv, _ = workload(sampled, {})               # warmup
     assert srv.stats["spec_steps"] > 0
-    quant = srv.kv_quant == "int8"
-    pf = eng._prefill_slot_q if quant else eng._prefill_slot
-    vf = eng._verify_slots_q if quant else eng._verify_slots
+    pf, vf = eng._prefill_slot, eng._verify_slots
     watch = CompileWatch(max_compiles=0, label="sampled spec serving")
     watch.wrap(pf)
     watch.wrap(vf)

@@ -6,6 +6,7 @@ vLLM's PagedAttention + Orca iteration-level scheduling over the
 reference's static KV-cache workspace)."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -301,7 +302,7 @@ def test_serving_compile_count_contract(devices):
         # tight pool + zero watermark: both requests admit, decode
         # growth exhausts the free list, the youngest evicts + requeues.
         # spec and the decode horizon pinned off: this pins the PLAIN
-        # decode program contract (the spec twin lives in
+        # decode program contract (the spec form lives in
         # test_spec_serving.py, the _decode_horizon family in
         # test_horizon.py)
         srv = ServingEngine(eng, num_slots=2, block_size=4, num_blocks=7,
@@ -317,12 +318,9 @@ def test_serving_compile_count_contract(devices):
     # exactly two compiled serving programs after warmup — one prefill
     # (chunks are padded to prefill_chunk, so ONE shape) and one decode.
     # Under DS_KV_QUANT=int8 / DS_LORA_SERVE=on the active set is the
-    # _q / _l / _ql jit twin family; the program COUNT contract is
-    # identical in every mode
-    sfx = ("_q" if srv.kv_quant == "int8" else "") + \
-          ("_l" if srv.lora_serve else "")
-    pf = getattr(eng, "_prefill_slot" + sfx)
-    dc = getattr(eng, "_decode_slots" + sfx)
+    # int8 / adapter entry of the same two callables; the program COUNT
+    # contract is identical in every mode
+    pf, dc = eng._prefill_slot, eng._decode_slots
     n_prefill = cache_size(pf)
     n_decode = cache_size(dc)
     if n_prefill is not None:
@@ -341,6 +339,134 @@ def test_serving_compile_count_contract(devices):
     if n_prefill is not None:
         assert cache_size(pf) == 1
         assert cache_size(dc) == 1
+
+
+# One callable per program family; the int8 and adapter variants are
+# cache entries of it (inference/engine.py). ``mode`` is the serving
+# configuration that runs a family in steady state.
+_FAMILIES = ("prefill_slot", "decode_slots", "verify_slots",
+             "decode_horizon")
+_MODE_OF = {"prefill_slot": "plain", "decode_slots": "plain",
+            "verify_slots": "spec", "decode_horizon": "horizon"}
+# cache entries of each family's callable after a run in a mode: a run is
+# prefill plus ONE decode form (verify and the horizon REPLACE decode)
+_ENTRIES = {"plain": (1, 1, 0, 0), "spec": (1, 0, 1, 0),
+            "horizon": (1, 0, 0, 1)}
+
+
+@functools.lru_cache(maxsize=None)
+def _variant_run(variant, mode):
+    """Serve one workload twice on a fresh engine under ``variant``
+    ("fp", "q" int8 pools, "l" adapters, "ql" both) in ``mode``; returns
+    ({family: cache entries after the first run, or None where jax does
+    not say}, compiles counted during the second run)."""
+    from deepspeed_tpu.runtime.lora import add_lora, adapter_state_dict
+    from deepspeed_tpu.utils.compile_guard import CompileWatch, cache_size
+    cfg, params = tiny()
+    eng = InferenceEngine(config=cfg, params=params, dtype=jnp.float32)
+    kw = dict(num_slots=2, block_size=4, num_blocks=16, prefill_chunk=8,
+              kv_quant="int8" if "q" in variant else "off",
+              spec_decode=mode == "spec",
+              decode_horizon=4 if mode == "horizon" else 1)
+    lora = "l" in variant
+    if lora:
+        kw.update(lora_serve=True, lora_pool_blocks=2, lora_max_rank=4,
+                  lora_rank_block=4)
+    else:
+        kw["lora_serve"] = False
+    p1, p2 = prompts_of((10, 9), seed=9)
+    if mode == "spec":                  # repetitive: the drafter proposes
+        p1, p2 = np.tile(p1[:3], 4), np.tile(p2[:3], 3)
+
+    def run_workload():
+        srv = ServingEngine(eng, **kw)
+        extra = {}
+        if lora:
+            srv.register_adapter("t", adapter_state_dict(add_lora(
+                params, rng=jax.random.PRNGKey(3), rank=4, alpha=8.0)))
+            extra["adapter_id"] = "t"   # request b stays base-only
+        return srv.run([
+            ServeRequest(rid="a", prompt=p1, max_new_tokens=9, **extra),
+            ServeRequest(rid="b", prompt=p2, max_new_tokens=7)])
+
+    warm = run_workload()
+    programs = {f: getattr(eng, "_" + f) for f in _FAMILIES}
+    entries = {f: cache_size(fn) for f, fn in programs.items()}
+    watch = CompileWatch(max_compiles=None,
+                         label=f"{variant} {mode} steady state")
+    for fn in programs.values():
+        watch.wrap(fn)
+    with watch:
+        again = run_workload()
+    for rid in warm:
+        np.testing.assert_array_equal(again[rid], warm[rid])
+    return entries, watch.compiles
+
+
+@pytest.mark.parametrize("family", _FAMILIES)
+@pytest.mark.parametrize("variant", ["fp", "q", "l", "ql"])
+def test_variant_is_one_cache_entry_of_its_family(devices, variant, family):
+    """Serving under a variant adds exactly ONE cache entry to the
+    family's single callable, none to a family the run does not use, and
+    the steady state compiles nothing: the int8 scale pools and the
+    adapter operands are pytree operands of one program, not programs of
+    their own."""
+    mode = _MODE_OF[family]
+    entries, steady_compiles = _variant_run(variant, mode)
+    assert steady_compiles == 0
+    if entries[family] is None:         # this jax does not expose it
+        return
+    assert entries[family] == 1, entries
+    assert tuple(entries[f] for f in _FAMILIES) == _ENTRIES[mode], entries
+
+
+@pytest.mark.parametrize("program", ["cow_blocks", "gather_blocks",
+                                     "scatter_block"])
+def test_block_copy_moves_the_scales_with_the_payload(devices, program):
+    """Each block copy is ONE program over the tuple of pools: handed an
+    int8 cache's four pools, it moves a block's scales with its payload
+    (a copied, spilled or restored block dequantizes as it was written),
+    and the fp call is a second entry of the same callable."""
+    from deepspeed_tpu.utils.compile_guard import cache_size
+    cfg, params = tiny()
+    eng = InferenceEngine(config=cfg, params=params, dtype=jnp.float32)
+    c = PagedKVCache(cfg, num_slots=1, block_size=4, num_blocks=6,
+                     dtype=jnp.float32, kv_quant="int8")
+    r = np.random.default_rng(0)
+    c.pools = tuple(
+        jnp.asarray(r.integers(-100, 100, p.shape), p.dtype)
+        if p.dtype == jnp.int8
+        else jnp.asarray(r.uniform(0.1, 1.0, p.shape), p.dtype)
+        for p in c.pools)
+    assert len(c.pools) == 4
+    before = [np.asarray(p) for p in c.pools]
+    if program == "cow_blocks":
+        after = eng.cow_blocks(c.pools, 2, 5)
+        for a, b in zip(after, before):
+            np.testing.assert_array_equal(np.asarray(a)[:, 5], b[:, 2])
+            np.testing.assert_array_equal(np.asarray(a)[:, :5], b[:, :5])
+    elif program == "gather_blocks":
+        got = eng.gather_blocks(c.pools, np.array([3, 1], np.int32))
+        assert len(got) == 4
+        for g, b in zip(got, before):
+            np.testing.assert_array_equal(np.asarray(g), b[:, [3, 1]])
+    else:
+        blocks = tuple(jnp.asarray(b[:, 4]) for b in before)
+        after = eng.scatter_block(c.pools, blocks, 1)
+        for a, b in zip(after, before):
+            np.testing.assert_array_equal(np.asarray(a)[:, 1], b[:, 4])
+            np.testing.assert_array_equal(np.asarray(a)[:, 2:], b[:, 2:])
+    fn = getattr(eng, "_" + program)
+    if cache_size(fn) is not None:
+        assert cache_size(fn) == 1
+        # the same callable on an fp cache's two pools: one more entry
+        f = PagedKVCache(cfg, num_slots=1, block_size=4, num_blocks=6,
+                         dtype=jnp.float32)
+        args = {"cow_blocks": (0, 0),
+                "gather_blocks": (np.zeros(2, np.int32),),
+                "scatter_block": (tuple(p[:, 0] for p in f.pools), 0)}
+        out = getattr(eng, program)(f.pools, *args[program])
+        assert len(out) == 2 and cache_size(fn) == 2
 
 
 def test_serving_rejects_oversized_request(devices):

@@ -326,13 +326,9 @@ def test_spec_compile_count_contract(devices):
     srv, warm_out = run_workload()
     assert srv.stats["evictions"] >= 1   # the workload really preempts
     # under DS_KV_QUANT=int8 / DS_LORA_SERVE=on the active set is the
-    # _q / _l / _ql jit twin family; the verify-replaces-decode count
-    # contract is identical in every mode
-    sfx = ("_q" if srv.kv_quant == "int8" else "") + \
-          ("_l" if srv.lora_serve else "")
-    pf = getattr(eng, "_prefill_slot" + sfx)
-    vf = getattr(eng, "_verify_slots" + sfx)
-    dc = getattr(eng, "_decode_slots" + sfx)
+    # int8 / adapter entry of the same callables; the
+    # verify-replaces-decode count contract is identical in every mode
+    pf, vf, dc = eng._prefill_slot, eng._verify_slots, eng._decode_slots
     n_prefill = cache_size(pf)
     n_verify = cache_size(vf)
     n_decode = cache_size(dc)

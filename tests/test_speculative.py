@@ -87,28 +87,57 @@ def test_speculative_llama_dialect(devices):
     np.testing.assert_array_equal(got, ref)
 
 
-def test_sampled_path_tokens_pinned_across_refactor(devices):
-    """Parity pin for the accept/resample dedup: moving the fp64
-    Leviathan math into inference/sampling.py left the static sampled
-    path bit-for-bit unchanged. The golden token ids below were
-    captured from the pre-refactor implementation; any drift in the
-    dist/accept/residual arithmetic shows up here as a token change."""
-    target, draft = _engines()
-    toks = np.random.default_rng(0).integers(0, 128, (2, 7)).astype(np.int32)
-    goldens = {
+# (temperature, top_k, seed, gamma) -> the 10 tokens generated per row
+_SAMPLED_GOLDENS = {
+    # captured from the implementation BEFORE the accept/resample math
+    # moved into inference/sampling.py, under a jax whose threefry
+    # generator was not yet partitionable (the default until jax 0.5.0)
+    "legacy": {
         (0.9, 0, 7, 3): [[79, 67, 69, 100, 126, 117, 66, 31, 24, 111],
                          [114, 29, 127, 79, 27, 80, 63, 1, 87, 66]],
         (0.7, 8, 11, 4): [[9, 107, 107, 20, 92, 20, 20, 20, 97, 97],
                           [61, 57, 20, 4, 20, 81, 50, 74, 6, 85]],
-    }
-    for (temp, top_k, seed, gamma), want in goldens.items():
-        got = generate_speculative(target, draft, toks, max_new_tokens=10,
-                                   gamma=gamma, temperature=temp,
-                                   top_k=top_k, seed=seed)
-        np.testing.assert_array_equal(
-            got[:, 7:], np.asarray(want, np.int32),
-            err_msg=f"sampled static path drifted at temp={temp} "
-                    f"top_k={top_k} seed={seed} gamma={gamma}")
+    },
+    # re-recorded from this implementation under jax 0.9.0's defaults
+    "partitionable": {
+        (0.9, 0, 7, 3): [[80, 99, 38, 0, 63, 127, 80, 27, 67, 31],
+                         [114, 29, 112, 105, 70, 101, 126, 96, 64, 107]],
+        (0.7, 8, 11, 4): [[9, 16, 16, 2, 2, 7, 7, 7, 7, 74],
+                          [54, 98, 116, 100, 123, 25, 70, 49, 54, 11]],
+    },
+}
+
+
+@pytest.mark.parametrize("stream", ["legacy", "partitionable"])
+def test_sampled_path_tokens_pinned_across_refactor(devices, stream):
+    """Parity pin for the accept/resample dedup: moving the fp64
+    Leviathan math into inference/sampling.py left the static sampled
+    path bit-for-bit unchanged; any drift in the dist/accept/residual
+    arithmetic shows up here as a token change.
+
+    What a key draws is jax's, not the path's: jax 0.5.0 made the
+    threefry generator partitionable by default, which changed the bits
+    of every draw of more than one value, in the initialiser's weights
+    and in the sampler alike, and moved every golden (the test failed
+    from the seed of this repo on, under jax 0.9.0). ``legacy`` turns
+    that generator back and holds the path to the ORIGINAL goldens, so
+    the arithmetic is still pinned to the pre-refactor implementation;
+    ``partitionable`` holds it to goldens re-recorded under the
+    defaults that every other test and the serving path run with."""
+    with jax.threefry_partitionable(stream == "partitionable"):
+        target, draft = _engines()
+        toks = np.random.default_rng(0).integers(0, 128, (2, 7)) \
+            .astype(np.int32)
+        for (temp, top_k, seed, gamma), want in \
+                _SAMPLED_GOLDENS[stream].items():
+            got = generate_speculative(target, draft, toks,
+                                       max_new_tokens=10, gamma=gamma,
+                                       temperature=temp, top_k=top_k,
+                                       seed=seed)
+            np.testing.assert_array_equal(
+                got[:, 7:], np.asarray(want, np.int32),
+                err_msg=f"sampled static path drifted at temp={temp} "
+                        f"top_k={top_k} seed={seed} gamma={gamma}")
 
 
 def test_sampled_identical_engines_always_accept(devices):

@@ -6,7 +6,7 @@ baseline from the current tree (visible debt, non-blocking).
 
 Two phases: the per-file rules (DS001–DS010) and the package-wide
 rules over a shared symbol table — interprocedural (DS011–DS014) and
-flow-sensitive dataflow (DS015–DS018). ``--closure`` switches to quick
+flow-sensitive dataflow (DS016–DS018). ``--closure`` switches to quick
 mode: the positional paths are treated as *changed files* and the lint
 runs over them plus their direct importers (from the cached import
 graph), with the whole-tree completeness checks disabled; the cache

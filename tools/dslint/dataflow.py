@@ -1,5 +1,5 @@
 """dslint v3: per-function control-flow graphs, forward dataflow, and
-the flow-sensitive rules DS015–DS018.
+the flow-sensitive rules DS016–DS018.
 
 The v2 interprocedural layer (:mod:`interproc`) sees *across* modules
 but not *through* control flow — it cannot tell "released on every
@@ -20,12 +20,6 @@ layer:
 
 The rules on top:
 
-DS015  jit-twin drift: every registered twin family
-       (``jit_registry.ENGINE_PROGRAM_FAMILIES``) must match its base
-       program statement-for-statement after normalizing away the
-       feature's DECLARED delta (``jit_registry.TWIN_DELTAS``) — an
-       edit to ``_decode_slots_fn`` that misses ``_decode_slots_q_fn``
-       is a lint error, not a silent parity bug.
 DS016  resource pairing: path-sensitive acquire/release balance for
        the repo's paired APIs (block allocate/free, adapter
        acquire/release, ``_in_transfer`` add/discard, host-tier
@@ -45,19 +39,16 @@ DS018  snapshot round-trip completeness: every dataclass field of a
        cost footprints each had to be retrofitted in separate PRs;
        this makes the next field a lint error instead).
 
-Like every dslint rule, these never import the code under analysis:
-the twin delta spec is loaded from ``utils/jit_registry.py`` by file
-path, exactly like the jit wrapper chains in :mod:`symbols`.
+Like every dslint rule, these never import the code under analysis.
 """
 
 import ast
 from collections import deque
 from dataclasses import dataclass, field
-from pathlib import Path
 from typing import (Dict, FrozenSet, Iterable, List, Optional, Sequence,
                     Set, Tuple)
 
-from tools.dslint.core import REPO_ROOT, Finding
+from tools.dslint.core import Finding
 from tools.dslint.interproc import InterprocRule, _dedupe
 from tools.dslint.rules import FUNC_TYPES, TracedPythonBranch, _dotted
 from tools.dslint.symbols import FuncInfo, SymbolTable
@@ -369,293 +360,6 @@ def _call_chain(call: ast.Call) -> List[str]:
 def _fn_params(fn: ast.AST) -> List[str]:
     return [a.arg for a in (list(fn.args.posonlyargs) + list(fn.args.args)
                             + list(fn.args.kwonlyargs))]
-
-
-# ==========================================================================
-# DS015 — jit-twin drift
-# ==========================================================================
-
-_FALLBACK_FAMILIES: Tuple[Tuple[str, Tuple[str, ...]], ...] = ()
-_FALLBACK_DELTAS: Dict[str, Dict[str, Tuple[str, ...]]] = {}
-_TWIN_SPEC_CACHE: Optional[Tuple[tuple, dict]] = None
-
-
-def load_twin_spec() -> Tuple[tuple, dict]:
-    """(ENGINE_PROGRAM_FAMILIES, TWIN_DELTAS) from utils/jit_registry.py,
-    loaded from the FILE path (dslint never imports the code under
-    analysis). Cached; empty spec when the registry is absent or
-    predates TWIN_DELTAS (fixture trees)."""
-    global _TWIN_SPEC_CACHE
-    if _TWIN_SPEC_CACHE is not None:
-        return _TWIN_SPEC_CACHE
-    path = REPO_ROOT / "deepspeed_tpu" / "utils" / "jit_registry.py"
-    try:
-        import importlib.util
-        spec = importlib.util.spec_from_file_location("_ds_jit_registry_v3",
-                                                      path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        _TWIN_SPEC_CACHE = (
-            tuple((stem, tuple(sufs))
-                  for stem, sufs in mod.ENGINE_PROGRAM_FAMILIES),
-            {k: {kk: tuple(vv) for kk, vv in v.items()}
-             for k, v in mod.TWIN_DELTAS.items()})
-    except Exception:
-        _TWIN_SPEC_CACHE = (_FALLBACK_FAMILIES, _FALLBACK_DELTAS)
-    return _TWIN_SPEC_CACHE
-
-
-def _delta_union(features: Sequence[str],
-                 deltas: Dict[str, Dict[str, Tuple[str, ...]]]
-                 ) -> Tuple[Set[str], Set[str], Set[str]]:
-    """(owned params, owned names, owned kwargs) for a twin suffix's
-    feature characters (``"_ql"`` → features ``("q", "l")``)."""
-    params: Set[str] = set()
-    names: Set[str] = set()
-    kwargs: Set[str] = set()
-    for f in features:
-        d = deltas.get(f, {})
-        params |= set(d.get("params", ()))
-        names |= set(d.get("params", ())) | set(d.get("names", ()))
-        kwargs |= set(d.get("kwargs", ()))
-    return params, names, kwargs
-
-
-class _TwinNormalizer:
-    """Renders a function AST to per-statement fingerprints with the
-    feature-owned delta stripped: owned parameters disappear from the
-    signature, owned tuple/call elements and keywords disappear from
-    expressions, and statements that only bind owned names disappear
-    entirely. A base program normalizes with an empty delta, so base
-    and twin compare statement-for-statement."""
-
-    _POS_FIELDS = ("lineno", "col_offset", "end_lineno", "end_col_offset",
-                   "type_comment")
-
-    def __init__(self, owned_names: Set[str], owned_kwargs: Set[str]):
-        self.names = owned_names
-        self.kwargs = owned_kwargs
-
-    def _owned(self, node: ast.AST) -> bool:
-        used = _names_in(node)
-        return bool(used & self.names)
-
-    def signature(self, fn: ast.AST, owned_params: Set[str]) -> str:
-        args = [a for a in (list(fn.args.posonlyargs) + list(fn.args.args))
-                if a.arg not in owned_params]
-        # align defaults to their params before filtering
-        all_args = list(fn.args.posonlyargs) + list(fn.args.args)
-        defaults = [None] * (len(all_args) - len(fn.args.defaults)) \
-            + list(fn.args.defaults)
-        by_name = {a.arg: d for a, d in zip(all_args, defaults)}
-        parts = []
-        for a in args:
-            d = by_name.get(a.arg)
-            parts.append(a.arg + ("=" + self.render(d)
-                                  if d is not None else ""))
-        return "(" + ", ".join(parts) + ")"
-
-    def body_fps(self, fn: ast.AST) -> List[Tuple[str, int]]:
-        """(fingerprint, lineno) per surviving top-level statement;
-        the leading docstring never counts."""
-        out: List[Tuple[str, int]] = []
-        for i, stmt in enumerate(fn.body):
-            if i == 0 and isinstance(stmt, ast.Expr) \
-                    and isinstance(stmt.value, ast.Constant) \
-                    and isinstance(stmt.value.value, str):
-                continue
-            fp = self.render_stmt(stmt)
-            if fp is not None:
-                out.append((fp, stmt.lineno))
-        return out
-
-    # -- rendering ------------------------------------------------------
-
-    def render_stmt(self, stmt: ast.stmt) -> Optional[str]:
-        """Fingerprint of one statement, or None when the whole
-        statement is feature-owned (all its bound names are owned)."""
-        if isinstance(stmt, (ast.Assign, ast.AnnAssign, ast.AugAssign)):
-            targets = stmt.targets if isinstance(stmt, ast.Assign) \
-                else [stmt.target]
-            kept = [self._clean_target(t) for t in targets]
-            if all(k is None for k in kept):
-                return None
-            tgt = ",".join(k for k in kept if k is not None)
-            val = self.render(stmt.value) if stmt.value is not None else ""
-            op = type(stmt.op).__name__ if isinstance(
-                stmt, ast.AugAssign) else "="
-            return f"Assign[{tgt} {op} {val}]"
-        return self.render(stmt)
-
-    def _clean_target(self, t: ast.AST) -> Optional[str]:
-        """Render an assignment target with owned names dropped at any
-        tuple-nesting depth; None when nothing survives."""
-        if isinstance(t, ast.Name):
-            return None if t.id in self.names else t.id
-        if isinstance(t, (ast.Tuple, ast.List)):
-            kept = [self._clean_target(e) for e in t.elts]
-            kept = [k for k in kept if k is not None]
-            if not kept:
-                return None
-            return "(" + ",".join(kept) + ")"
-        if isinstance(t, ast.Starred):
-            inner = self._clean_target(t.value)
-            return None if inner is None else "*" + inner
-        return self.render(t)
-
-    def _clean_elts(self, elts: Sequence[ast.AST]) -> List[str]:
-        """Container elements / call arguments with feature-owned ones
-        dropped. Containers recurse (a mixed scan-operand tuple keeps
-        its shared elements); a non-container element that mentions ANY
-        owned name is feature-owned and dropped — safe, because a base
-        body by construction never mentions an owned name, so nothing
-        is ever dropped from the base side."""
-        out: List[str] = []
-        for e in elts:
-            if isinstance(e, (ast.Tuple, ast.List, ast.Set)):
-                out.append(self.render(e))
-            elif not self._owned(e):
-                out.append(self.render(e))
-        return out
-
-    def render(self, node) -> str:
-        if node is None:
-            return "None"
-        if isinstance(node, (ast.Tuple, ast.List, ast.Set)):
-            return (type(node).__name__ + "["
-                    + ",".join(self._clean_elts(node.elts)) + "]")
-        if isinstance(node, ast.Call):
-            kws = [k for k in node.keywords
-                   if not (k.arg in self.kwargs
-                           or (k.arg is None and self._owned(k.value)))]
-            return ("Call[" + self.render(node.func) + "]("
-                    + ",".join(self._clean_elts(node.args)) + ")("
-                    + ",".join(f"{k.arg}={self.render(k.value)}"
-                               for k in kws) + ")")
-        if isinstance(node, ast.Constant):
-            return f"Const[{node.value!r}]"
-        if isinstance(node, ast.Name):
-            return f"Name[{node.id}]"
-        if isinstance(node, ast.AST):
-            parts = []
-            for fname, val in ast.iter_fields(node):
-                if fname in self._POS_FIELDS or fname == "ctx":
-                    continue
-                parts.append(fname + "=" + self._render_field(val))
-            return type(node).__name__ + "(" + ",".join(parts) + ")"
-        return repr(node)
-
-    def _render_field(self, val) -> str:
-        if isinstance(val, list):
-            if val and isinstance(val[0], ast.stmt):
-                fps = [self.render_stmt(s) for s in val]
-                return "[" + ";".join(f for f in fps if f is not None) + "]"
-            return "[" + ";".join(self._render_field(v) for v in val) + "]"
-        if isinstance(val, ast.AST):
-            return self.render(val)
-        return repr(val)
-
-
-class JitTwinDrift(InterprocRule):
-    id = "DS015"
-    name = "jit-twin-drift"
-    autofixable = False
-    rationale = ("the engine hand-maintains a 2^n family of jit twins "
-                 "(_q/_l/_ql per program); an edit to the base body that "
-                 "misses a twin is a silent numerics/parity bug — twins "
-                 "must match the base statement-for-statement modulo the "
-                 "feature delta DECLARED in jit_registry.TWIN_DELTAS")
-
-    def __init__(self, spec: Optional[Tuple[tuple, dict]] = None):
-        self._spec = spec       # (families, deltas) override for tests
-
-    def check_package(self, table, docs_root=None, schema_path=None,
-                      partial=False):
-        families, deltas = self._spec if self._spec is not None \
-            else load_twin_spec()
-        if not families:
-            return []
-        by_name: Dict[str, List[FuncInfo]] = {}
-        for fn in table.functions:
-            by_name.setdefault(fn.name, []).append(fn)
-        out: List[Finding] = []
-        for stem, suffixes in families:
-            bases = by_name.get(f"_{stem}_fn", ())
-            for base in bases:
-                if base.node is None:
-                    continue
-                norm0 = _TwinNormalizer(set(), set())
-                base_sig = norm0.signature(base.node, {"self", "cls"})
-                base_fps = norm0.body_fps(base.node)
-                for suf in suffixes:
-                    if not suf:
-                        continue
-                    # both twin spellings in use: engine methods say
-                    # `_decode_slots_q_fn`, paged_cache module-level
-                    # defaults say `_gather_blocks_fn_q`
-                    twin_name = f"_{stem}{suf}_fn"
-                    twins = [t for t in (list(by_name.get(twin_name, ()))
-                                         + list(by_name.get(
-                                             f"_{stem}_fn{suf}", ())))
-                             if t.path == base.path and t.node is not None]
-                    if not twins:
-                        if not partial:
-                            out.append(self._f(
-                                base.path, base.line,
-                                f"twin family '{stem}' registers suffix "
-                                f"'{suf}' in ENGINE_PROGRAM_FAMILIES but "
-                                f"`{twin_name}` is not defined — the "
-                                f"program catalog and the engine "
-                                f"disagree"))
-                        continue
-                    features = list(suf.lstrip("_"))
-                    owned_p, owned_n, owned_k = _delta_union(features,
-                                                             deltas)
-                    norm = _TwinNormalizer(owned_n, owned_k)
-                    for twin in twins:
-                        out.extend(self._compare(
-                            base, base_sig, base_fps, twin,
-                            norm.signature(twin.node,
-                                           owned_p | {"self", "cls"}),
-                            norm.body_fps(twin.node), suf))
-        return _dedupe(out)
-
-    def _compare(self, base: FuncInfo, base_sig: str,
-                 base_fps: List[Tuple[str, int]], twin: FuncInfo,
-                 twin_sig: str, twin_fps: List[Tuple[str, int]],
-                 suf: str) -> List[Finding]:
-        what = (f"`{twin.name}` drifts from `{base.name}` outside the "
-                f"declared '{suf.lstrip('_')}' delta")
-        fix = ("edit base and twin together, or extend "
-               "jit_registry.TWIN_DELTAS if the divergence is a new "
-               "feature-owned shape")
-        if twin_sig != base_sig:
-            return [self._f(
-                twin.path, twin.line,
-                f"{what}: signature {twin_sig} != base {base_sig} after "
-                f"stripping feature-owned parameters — {fix}")]
-        out: List[Finding] = []
-        for i, ((bfp, bline), (tfp, tline)) in enumerate(
-                zip(base_fps, twin_fps)):
-            if bfp != tfp:
-                out.append(self._f(
-                    twin.path, tline,
-                    f"{what}: statement {i + 1} does not match the base "
-                    f"statement at {base.path}:{bline} — {fix}"))
-                return out
-        if len(twin_fps) < len(base_fps):
-            bline = base_fps[len(twin_fps)][1]
-            out.append(self._f(
-                twin.path, twin.line,
-                f"{what}: base statement at {base.path}:{bline} has no "
-                f"counterpart in the twin — {fix}"))
-        elif len(twin_fps) > len(base_fps):
-            tline = twin_fps[len(base_fps)][1]
-            out.append(self._f(
-                twin.path, tline,
-                f"{what}: twin statement at line {tline} has no "
-                f"counterpart in the base — {fix}"))
-        return out
 
 
 # ==========================================================================
@@ -1368,7 +1072,7 @@ class SnapshotRoundTrip(InterprocRule):
 # ==========================================================================
 
 def dataflow_rules() -> List[InterprocRule]:
-    return [JitTwinDrift(), ResourcePairing(), TracedValueEscape(),
+    return [ResourcePairing(), TracedValueEscape(),
             SnapshotRoundTrip()]
 
 

@@ -71,20 +71,6 @@ impl = os.environ.get("DS_NEW_KNOB")   # DS013: flag read but never
     "DS014": """\
 self._m = Counter("serving_new_metric")   # DS014: registered metric
 # missing from tools/dslint/telemetry_schema.json""",
-    "DS015": """\
-def _decode_slots_fn(self, params, k_pool, v_pool, tokens):
-    x = embed(params, tokens)
-    x = x + positional(params, tokens)      # <- edited in base only
-    return project(params, x), k_pool, v_pool
-
-
-def _decode_slots_q_fn(self, params, k_pool, v_pool, k_scale, v_scale,
-                       tokens):
-    x = embed(params, tokens)
-    # DS015: the positional-embedding statement above is missing here
-    # and `k_scale`/`v_scale` don't excuse it — the q delta
-    # (jit_registry.TWIN_DELTAS["q"]) only owns the scale sidecars
-    return project(params, x), k_pool, v_pool, k_scale, v_scale""",
     "DS016": """\
 def admit(self, rid, n):
     slot = self.cache.allocate(rid, n)

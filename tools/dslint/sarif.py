@@ -22,7 +22,7 @@ def _help_anchor(rule_id: str) -> str:
     """LINT.md section anchor for a rule id — SARIF viewers surface it
     as the rule's documentation link."""
     n = int(rule_id[2:])
-    if n >= 15:
+    if n >= 16:
         return "#the-flow-sensitive-rules-phase-3"
     if n >= 11:
         return "#the-interprocedural-rules-phase-2"

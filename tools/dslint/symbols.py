@@ -773,10 +773,10 @@ def _propagate_helper_donation(table: SymbolTable, fn_calls) -> None:
 CALLGRAPH_CACHE = REPO_ROOT / "build" / "dslint_callgraph.json"
 
 # Shared analysis INPUTS whose content changes rule behaviour without
-# changing any analyzed .py file's import graph: the jit-wrapper/twin
+# changing any analyzed .py file's import graph: the jit-wrapper
 # spec and the telemetry schema. Their hashes ride the cache so a
 # `--closure` run after editing one of them misses the cache and falls
-# back to a full pass (a stale cache here means DS002/DS011/DS014/DS015
+# back to a full pass (a stale cache here means DS002/DS011/DS014
 # silently lint against yesterday's contract).
 CACHE_INPUT_FILES: Tuple[Tuple[str, Path], ...] = (
     ("jit_registry", REPO_ROOT / "deepspeed_tpu" / "utils"

@@ -186,8 +186,8 @@ elif [[ "${1:-}" == "quick" ]]; then
     # lint the changed .py files PLUS their direct importers (--closure
     # quick mode, cached import graph from the last full run) so the
     # interprocedural rules (DS011-DS014) and the flow-sensitive v3
-    # rules (DS015-DS018: jit-twin drift, resource pairing, traced
-    # escape, snapshot round-trip) see cross-module breakage a change
+    # rules (DS016-DS018: resource pairing, traced escape, snapshot
+    # round-trip) see cross-module breakage a change
     # introduces; whole-tree completeness checks are the full gate's
     # job. Falls back to a full two-phase pass (which seeds the cache)
     # when no cache exists yet — also when jit_registry.py or
