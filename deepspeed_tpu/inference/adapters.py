@@ -382,8 +382,9 @@ class AdapterPool:
         """Package the pools + a slot table for the engine's ``lora=``
         kwarg: ``(a_pool, b_pool, rows)`` with ``rows`` ``[B, NBa]``
         (decode/verify) or ``[NBa]`` (one prefill slot) — traced data,
-        so any adapter mix reuses the same compiled program."""
-        return (self.a_pool, self.b_pool, jnp.asarray(rows, jnp.int32))
+        so any adapter mix reuses the same compiled program; host rows,
+        which ride in the dispatch's packed operand."""
+        return (self.a_pool, self.b_pool, rows)
 
     def stats(self) -> Dict[str, int]:
         return {

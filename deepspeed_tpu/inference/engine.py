@@ -270,6 +270,74 @@ def _named(fn, name: str):
     return call
 
 
+# A dispatch crosses the host link once: everything that changes at step
+# cadence (block tables, lengths, tokens, the active mask, the sampler's
+# small lanes) travels as ONE int32 buffer, handed to the jitted call as
+# numpy, so jit's own argument path is the only transfer and nothing is
+# launched ahead of the program (PERF.md, PR 31: a jnp.asarray per operand
+# was the serve.dispatch.enqueue span). A section's kind says how its
+# words were written and how the program reads them back.
+_WORDS = {"i": np.int32, "b": np.int32, "row": np.int32, "lora": np.int32,
+          "u": np.uint32, "f": np.float32}
+
+
+def pack_operands(*parts):
+    """``(kind, value)`` pairs, in the order the program takes them, as
+    ``(buffer, layout)``: one flat host int32 array holding every
+    section's words (floats and key bits as their bit patterns, booleans
+    as 0 / 1) and the static tuple :func:`_packed` unpacks it by. Kinds:
+    ``i`` int32, ``u`` uint32, ``f`` float32, ``b`` bool; ``row``, a slot
+    index for which the program takes that row of the resident sampler
+    mask; ``lora``, the adapter-table rows that complete ``lora``. Two
+    kinds put nothing in the buffer: ``static`` (a value the program is
+    compiled for) and ``seen`` (the resident mask, whole). The layout
+    depends on shapes alone, so a run has one per program."""
+    layout, words = [], []
+    for kind, value in parts:
+        if kind in _WORDS:
+            value = np.asarray(value, _WORDS[kind])
+            words.append(value.reshape(-1).view(np.int32))
+            value = value.shape
+        layout.append((kind, value))
+    return np.concatenate(words), tuple(layout)
+
+
+def _packed(fn, name: str):
+    """``fn`` (one of the paged serving programs, with the signature it
+    has) behind the packed operand of :func:`pack_operands`, under the
+    module name ``jit_<name>``: a shell of static slices and bitcasts
+    inside the SAME program. ``seen`` is the sampler's resident [B, V]
+    mask (None for a program that does not sample)."""
+    def call(params, k_pool, v_pool, packed, layout, seen=None, scales=None,
+             lora=None):
+        operands, at = [], 0
+        for kind, what in layout:
+            if kind == "static":
+                operands.append(what)
+                continue
+            if kind == "seen":
+                operands.append(seen)
+                continue
+            n = int(np.prod(what))
+            words = jax.lax.slice_in_dim(packed, at, at + n).reshape(what)
+            at += n
+            if kind == "lora":
+                lora = (*lora, words)
+            elif kind == "row":
+                operands.append(jax.lax.dynamic_index_in_dim(
+                    seen, words, keepdims=False))
+            elif kind == "b":
+                operands.append(words != 0)
+            elif kind == "i":
+                operands.append(words)
+            else:
+                operands.append(jax.lax.bitcast_convert_type(
+                    words, _WORDS[kind]))
+        return fn(params, k_pool, v_pool, *operands, scales=scales,
+                  lora=lora)
+    return _named(call, name)
+
+
 def _scan_layers(block, x, params, pools, lora_ops=None, stack="block",
                  bases=None):
     """Run the layers of a paged program: the ONE layer loop of every
@@ -813,25 +881,24 @@ class InferenceEngine:
             # ("gather" | "pallas") and the horizon's n_steps are static:
             # a run pins both. The two programs of every run carry explicit
             # module names (jit_serve_prefill_slot, jit_serve_decode_slots):
-            # a profile and the provenance table name a program by them
-            donated = ("k_pool", "v_pool", "scales")
-            self._prefill_slot = jax.jit(
-                _named(self._prefill_slot_fn, "serve_prefill_slot"),
-                donate_argnames=donated)
-            self._decode_slots = jax.jit(
-                _named(self._decode_slots_fn, "serve_decode_slots"),
-                donate_argnames=donated, static_argnums=(7,))
-            # fused multi-step decode (DS_DECODE_HORIZON > 1,
-            # docs/MULTISTEP.md); N=1 serving never compiles it
-            self._decode_horizon = jax.jit(self._decode_horizon_fn,
-                                           donate_argnames=donated,
-                                           static_argnums=(7, 8))
-            # speculative verify: with spec_decode on this REPLACES the
-            # plain decode program in steady state (the chunk width G is
-            # fixed per serving engine)
-            self._verify_slots = jax.jit(self._verify_slots_fn,
-                                         donate_argnames=donated,
-                                         static_argnums=(7,))
+            # a profile and the provenance table name a program by them.
+            # Each family is jitted behind ONE packed host operand
+            # (_packed): its layout, static, also carries impl and n_steps.
+            # The fused multi-step decode (DS_DECODE_HORIZON > 1,
+            # docs/MULTISTEP.md) is never compiled by N=1 serving; with
+            # spec_decode on, verify REPLACES the plain decode program in
+            # steady state (the chunk width G is fixed per serving engine)
+            def program(fn, name):
+                return jax.jit(_packed(fn, name), static_argnames=("layout",),
+                               donate_argnames=("k_pool", "v_pool", "scales"))
+            self._prefill_slot = program(self._prefill_slot_fn,
+                                         "serve_prefill_slot")
+            self._decode_slots = program(self._decode_slots_fn,
+                                         "serve_decode_slots")
+            self._decode_horizon = program(self._decode_horizon_fn,
+                                           "_decode_horizon_fn")
+            self._verify_slots = program(self._verify_slots_fn,
+                                         "_verify_slots_fn")
             # static-path chunk verify (inference/speculative.py): the
             # dense-cache counterpart of _verify_slots, kept here so the
             # speculative module shares the engine's compiled program
@@ -872,6 +939,8 @@ class InferenceEngine:
         # a ProgramCostRegistry that wants the compiled text of each
         # serving program (a ServingEngine with telemetry on sets it)
         self.provenance = None
+        # (host operands, their bytes) of the last paged dispatch (_run)
+        self.h2d = (0, 0)
         dev0 = mesh.devices.flat[0]
         log_dist(f"inference engine ready: {config.n_layers}L/"
                  f"{config.d_model}d mp={mp_size} "
@@ -1267,39 +1336,47 @@ class InferenceEngine:
     # public wrappers: host-side numpy in, device pools threaded through
     # (``scales``: PagedKVCache.scales, None or the int8 pools' (k_scale,
     # v_scale)); what comes back ends in PagedKVCache.pools, updated: k, v
-    # and then the scales. The fault-injection sites fire BEFORE any
-    # dispatch touches the donated pools, so a TransientDeviceError here
-    # is retryable by the serving engine against intact buffers
-    # (utils/faults).
-    @staticmethod
-    def _samp_lanes(sample_state, batch, vocab, scalar=False):
-        """Coerce a host ``sample_state`` tuple (sampling.SlotSamplerState
-        ``lanes()``/``lane()``) to traced arrays; None synthesizes the
-        all-greedy lanes so legacy callers keep their behavior (and the
-        one compiled program — greedy lanes are values, not a different
-        signature). ``scalar`` selects the single-slot (prefill) lane
-        shape."""
+    # and then the scales. Each packs its host operands into the ONE buffer
+    # its program is jitted behind (pack_operands, _packed). The
+    # fault-injection sites fire BEFORE any dispatch touches the donated
+    # pools, so a TransientDeviceError here is retryable by the serving
+    # engine against intact buffers (utils/faults).
+    _LANE_KINDS = ("u", "i", "f", "i", "f", "f")
+
+    @classmethod
+    def _samp_lanes(cls, sample_state, batch, vocab, scalar=False):
+        """A ``sample_state`` tuple (sampling.SlotSamplerState ``lanes()`` /
+        ``lane()``) as packed sections: keys, gen_counts, temps, top_ks,
+        top_ps and rep_pens by their words, and the mask not at all: it is
+        resident on the device, and a prefill (``scalar``) names its row by
+        the slot. Returns (sections, the mask). None synthesizes the
+        all-greedy lanes so legacy callers keep their behavior (and the one
+        compiled program — greedy lanes are values, not a different
+        signature); their mask is host zeros."""
         if sample_state is None:
             st = sampling.greedy_state(batch, vocab)
-            sample_state = tuple(a[0] for a in st) if scalar else st
-        keys, gens, temps, top_ks, top_ps, pens, seen = sample_state
-        return (jnp.asarray(keys, jnp.uint32), jnp.asarray(gens, jnp.int32),
-                jnp.asarray(temps, jnp.float32),
-                jnp.asarray(top_ks, jnp.int32),
-                jnp.asarray(top_ps, jnp.float32),
-                jnp.asarray(pens, jnp.float32), jnp.asarray(seen, bool))
+            sample_state = (*(a[0] for a in st[:-1]), 0, st[-1]) \
+                if scalar else st
+        *lanes, seen = sample_state
+        parts = list(zip(cls._LANE_KINDS, lanes))   # the six small lanes
+        parts.append(("row", lanes[-1]) if scalar else ("seen", None))
+        return parts, seen
 
-    def _run(self, stem: str, program, k_pool, v_pool, *operands,
+    def _run(self, stem: str, program, k_pool, v_pool, parts, seen=None,
              scales=None, lora=None, kernel_table=()):
         """The ONE dispatch of a paged serving program: family ``stem``'s
-        jitted callable on ``(params, k_pool, v_pool, *operands, scales,
-        lora)``. ``scales`` and ``lora`` — the serving engine's ``(a_pool,
-        b_pool, ablocks)`` from AdapterPool.lora_args — are None when
+        jitted callable on ``(params, k_pool, v_pool, packed, layout, seen,
+        scales, lora)``, ``parts`` being the program's operands as
+        pack_operands takes them. ``scales`` and ``lora`` — the serving
+        engine's ``(a_pool, b_pool, ablocks)`` from AdapterPool.lora_args,
+        whose per-dispatch ``ablocks`` joins the buffer — are None when
         absent, and decide which cache entry of the callable runs and
         under which program id it is accounted (jit_registry.program_id).
         With int8 pools the ``cache.quantize`` site fires here, after the
         caller's ``engine.*`` site and before the dispatch touches the
-        donated pools or scales.
+        donated pools or scales. ``self.h2d`` keeps what this dispatch
+        handed over from the host: (operands, bytes); (1, the buffer's)
+        with a resident mask.
 
         Under telemetry the FIRST call of each program id also hands the
         text of its compiled module to the provenance table: lowering
@@ -1320,9 +1397,14 @@ class InferenceEngine:
         if isinstance(k_pool, hybrid.PagedState):
             k_pool = k_pool._replace(route=None)    # an output only
         if lora is not None:
-            lora = (lora[0], lora[1], jnp.asarray(lora[2], jnp.int32))
+            parts = (*parts, ("lora", lora[2]))
+            lora = lora[:2]
+        packed, layout = pack_operands(*parts)
+        host = [a for a in (packed, seen) if isinstance(a, np.ndarray)]
+        self.h2d = (len(host), sum(a.nbytes for a in host))
         pid = program_id(stem, scales is not None, lora is not None)
-        args = (self.params, k_pool, v_pool, *operands, scales, lora)
+        args = (self.params, k_pool, v_pool, packed, layout, seen, scales,
+                lora)
         sink = self.provenance
         if sink is not None and pid not in sink.provenance:
             L, N, bs = getattr(k_pool, "full", k_pool).shape[:3]
@@ -1342,16 +1424,17 @@ class InferenceEngine:
         return program(*args)
 
     def _run_slots(self, stem: str, program, k_pool, v_pool, tables,
-                   lengths, tokens, active, impl, *operands, **kw):
+                   lengths, tokens, active, impl, parts=(), seen=None,
+                   **kw):
         """_run for the slot-batched programs (decode, horizon, verify):
-        coerces their four host arrays, resolves ``impl`` (None: the
-        engine's), and names the kernel's table when it attends through
-        the kernel."""
+        leads ``parts`` with their four host arrays and ``impl`` (None:
+        the engine's), and names the kernel's table when it attends
+        through the kernel."""
         impl = self.decode_impl if impl is None else impl
         return self._run(
-            stem, program, k_pool, v_pool, jnp.asarray(tables, jnp.int32),
-            jnp.asarray(lengths, jnp.int32), jnp.asarray(tokens, jnp.int32),
-            jnp.asarray(active, bool), impl, *operands,
+            stem, program, k_pool, v_pool,
+            (("i", tables), ("i", lengths), ("i", tokens), ("b", active),
+             ("static", impl), *parts), seen,
             kernel_table=np.shape(tables) if impl == "pallas" else (), **kw)
 
     def prefill_into_slot(self, k_pool, v_pool, table_row, tokens, start,
@@ -1359,25 +1442,24 @@ class InferenceEngine:
                           lora=None):
         from deepspeed_tpu.utils.faults import maybe_fire
         maybe_fire("engine.prefill")
-        lanes = self._samp_lanes(sample_state, 1, self.cfg.vocab_size,
-                                 scalar=True)
+        lanes, seen = self._samp_lanes(sample_state, 1, self.cfg.vocab_size,
+                                       scalar=True)
         out = self._run(
             "prefill_slot", self._prefill_slot, k_pool, v_pool,
-            jnp.asarray(table_row, jnp.int32),
-            jnp.asarray(tokens, jnp.int32), jnp.asarray(start, jnp.int32),
-            jnp.asarray(n_valid, jnp.int32), *lanes, scales=scales,
-            lora=lora)
+            (("i", table_row), ("i", tokens), ("i", start), ("i", n_valid),
+             *lanes), seen, scales=scales, lora=lora)
         return (out[0],) + out[3:] if sample_state is None else out
 
     def decode_slots(self, k_pool, v_pool, tables, lengths, tokens, active,
                      impl=None, scales=None, sample_state=None, lora=None):
         from deepspeed_tpu.utils.faults import maybe_fire
         maybe_fire("engine.decode")
-        lanes = self._samp_lanes(sample_state, len(np.asarray(tokens)),
-                                 self.cfg.vocab_size)
+        lanes, seen = self._samp_lanes(sample_state, len(tokens),
+                                       self.cfg.vocab_size)
         out = self._run_slots(
             "decode_slots", self._decode_slots, k_pool, v_pool, tables,
-            lengths, tokens, active, impl, *lanes, scales=scales, lora=lora)
+            lengths, tokens, active, impl, lanes, seen, scales=scales,
+            lora=lora)
         return (out[0],) + out[3:] if sample_state is None else out
 
     def decode_horizon(self, k_pool, v_pool, tables, lengths, tokens,
@@ -1396,15 +1478,14 @@ class InferenceEngine:
         decode against intact buffers."""
         from deepspeed_tpu.utils.faults import maybe_fire
         maybe_fire("engine.decode")
-        lanes = self._samp_lanes(sample_state, len(np.asarray(tokens)),
-                                 self.cfg.vocab_size)
+        lanes, seen = self._samp_lanes(sample_state, len(tokens),
+                                       self.cfg.vocab_size)
         return self._run_slots(
             "decode_horizon", self._decode_horizon, k_pool, v_pool, tables,
-            lengths, tokens, active, impl, int(n_steps), *lanes,
-            jnp.asarray(budgets, jnp.int32), jnp.asarray(eos_ids, jnp.int32),
-            jnp.asarray(stop_ids, jnp.int32),
-            jnp.asarray(stop_lens, jnp.int32), jnp.asarray(tail, jnp.int32),
-            scales=scales, lora=lora)
+            lengths, tokens, active, impl,
+            (("static", int(n_steps)), *lanes, ("i", budgets),
+             ("i", eos_ids), ("i", stop_ids), ("i", stop_lens),
+             ("i", tail)), seen, scales=scales, lora=lora)
 
     def verify_slots(self, k_pool, v_pool, tables, lengths, tokens, active,
                      impl=None, scales=None, lora=None):

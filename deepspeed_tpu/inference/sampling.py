@@ -203,9 +203,11 @@ class SlotSamplerState:
     """Slot-indexed host mirrors of the sampling arrays.
 
     The scheduler owns one instance; rows are (re)written at admission
-    and cleared at release. ``lanes()`` packages them as the
+    and cleared at release. ``lanes()`` / ``lane()`` package them as the
     ``sample_state`` tuple the engine wrappers thread into the compiled
-    slot programs."""
+    slot programs: the small lanes as host values, which ride in the
+    dispatch's one packed operand, and the ``seen`` mask as its copy on
+    the device, uploaded again only after a row's content changed."""
 
     def __init__(self, num_slots: int, vocab_size: int):
         self.num_slots = num_slots
@@ -216,11 +218,26 @@ class SlotSamplerState:
         self.top_ps = np.ones(num_slots, np.float32)
         self.rep_pens = np.ones(num_slots, np.float32)
         self.seen = np.zeros((num_slots, vocab_size), bool)
-        # device mirror of the per-slot knobs, rebuilt lazily after a
-        # mutation: the decode hot path re-uploads only the [B]
-        # gen_counts each step instead of all seven arrays (the rest
-        # change at admission/release cadence, not step cadence)
-        self._device_lanes = None
+        # which rows of ``seen`` hold a mark, and the mask as the device
+        # holds it (None: a row changed since). A greedy or unpenalized
+        # request never marks its row, so admitting and releasing it
+        # leaves the device's copy good
+        self._marked = np.zeros(num_slots, bool)
+        self._seen_device = None
+        # called once per upload (ServingEngine: ``sampler_mask_uploads``)
+        self.on_mask_upload = None
+
+    def _set_row(self, slot: int, tokens=()) -> None:
+        """Row ``slot`` of the mask becomes exactly ``tokens``."""
+        marks = len(tokens) > 0
+        if not (marks or self._marked[slot]):
+            return
+        self.seen[slot] = False
+        if marks:
+            self.seen[slot, np.asarray(tokens, np.int64) % self.vocab_size] \
+                = True
+        self._marked[slot] = marks
+        self._seen_device = None
 
     def admit(self, slot: int, params: SamplingParams,
               tokens: Optional[Sequence[int]] = None) -> None:
@@ -229,11 +246,8 @@ class SlotSamplerState:
         self.top_ks[slot] = params.top_k
         self.top_ps[slot] = params.top_p
         self.rep_pens[slot] = params.repetition_penalty
-        self.seen[slot] = False
-        if tokens is not None and params.repetition_penalty != 1.0:
-            self.seen[slot, np.asarray(tokens, np.int64) % self.vocab_size] \
-                = True
-        self._device_lanes = None
+        self._set_row(slot, tokens if tokens is not None
+                      and params.repetition_penalty != 1.0 else ())
 
     def release(self, slot: int) -> None:
         self.keys[slot] = 0
@@ -241,34 +255,35 @@ class SlotSamplerState:
         self.top_ks[slot] = 0
         self.top_ps[slot] = 1.0
         self.rep_pens[slot] = 1.0
-        self.seen[slot] = False
-        self._device_lanes = None
+        self._set_row(slot)
 
     def observe(self, slot: int, token: int) -> None:
-        if self.rep_pens[slot] != 1.0:
-            self.seen[slot, int(token) % self.vocab_size] = True
-            self._device_lanes = None
+        token = int(token) % self.vocab_size
+        if self.rep_pens[slot] != 1.0 and not self.seen[slot, token]:
+            self.seen[slot, token] = True
+            self._marked[slot] = True
+            self._seen_device = None
+
+    def _mask(self):
+        if self._seen_device is None:
+            self._seen_device = jax.device_put(self.seen)
+            if self.on_mask_upload is not None:
+                self.on_mask_upload()
+        return self._seen_device
 
     def lanes(self, gen_counts) -> Tuple:
         """The slot-batched ``sample_state`` tuple: gen_counts [B] is
         each slot's tokens-generated-so-far (the key-chain counter)."""
-        if self._device_lanes is None:
-            self._device_lanes = (
-                jnp.asarray(self.keys, jnp.uint32),
-                jnp.asarray(self.temps, jnp.float32),
-                jnp.asarray(self.top_ks, jnp.int32),
-                jnp.asarray(self.top_ps, jnp.float32),
-                jnp.asarray(self.rep_pens, jnp.float32),
-                jnp.asarray(self.seen, bool))
-        keys, temps, top_ks, top_ps, pens, seen = self._device_lanes
-        return (keys, np.asarray(gen_counts, np.int32), temps,
-                top_ks, top_ps, pens, seen)
+        return (self.keys, np.asarray(gen_counts, np.int32), self.temps,
+                self.top_ks, self.top_ps, self.rep_pens, self._mask())
 
     def lane(self, slot: int, gen_count: int) -> Tuple:
-        """Single-slot ``sample_state`` (the prefill-emit path)."""
-        return (self.keys[slot], np.int32(gen_count), self.temps[slot],
+        """Single-slot ``sample_state`` (the prefill-emit path): the
+        slot's small lanes, then the slot, by which the program reads its
+        row of the resident mask."""
+        return (self.keys[slot], gen_count, self.temps[slot],
                 self.top_ks[slot], self.top_ps[slot], self.rep_pens[slot],
-                self.seen[slot])
+                slot, self._mask())
 
 
 def greedy_state(batch: int, vocab_size: int) -> Tuple:

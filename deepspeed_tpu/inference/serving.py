@@ -799,6 +799,11 @@ class ServingEngine:
                 "kv_pool_dtype", "KV pool element width in bits "
                 "(8 = int8 quantized, 16 = bf16, 32 = f32)")
             self._g_kv_dtype.set(self.cache.pool_dtype.itemsize * 8)
+            self.sampler.on_mask_upload = reg.counter(
+                "sampler_mask_uploads",
+                "uploads of the sampler's seen mask to the device: one "
+                "when a penalized request marks or clears its row, none "
+                "for greedy or unpenalized traffic").inc
             # two kinds of KV state (inference/hybrid.py): the paged pool
             # of the full layers and the window layers' per-slot rings
             reg.gauge("kv_full_pool_bytes",
@@ -1492,12 +1497,11 @@ class ServingEngine:
         # sampled lane's key fold_in(key, len(out)) replays the
         # identical draw)
         with tracer.span("serve.pull", rid=req.rid, step=self._step_clock,
-                         slot=slot, bytes=tok.nbytes + lp.nbytes):
-            tok_h = int(np.asarray(tok)[0])  # dslint: disable=DS001 — final chunk only: ONE pull per prefill completion (the prefill-emitted token), not per-chunk work
-            lp_h = float(np.asarray(lp)[0])  # dslint: disable=DS001 — same single completion-time pull
+                         slot=slot, bytes=tok.nbytes + lp.nbytes, d2h=1):
+            tok_h, lp_h = jax.device_get((tok, lp))  # dslint: disable=DS001 — final chunk only: ONE pull per prefill completion (the prefill-emitted token and its logprob, both copies started before either is awaited), not per-chunk work
         with tracer.span("serve.emit", rid=req.rid, step=self._step_clock,
                          slot=slot, tokens=1):
-            self._emit_sampled(slot, req, tok_h, lp_h, now)
+            self._emit_sampled(slot, req, int(tok_h[0]), float(lp_h[0]), now)
         if req.state not in TERMINAL_STATES:
             # prefill-only role: park the finished prefill for
             # the router's KV migration instead of decoding it
@@ -1608,10 +1612,9 @@ class ServingEngine:
         # sampler already ran inside the compiled decode program)
         tracer = self.telemetry.tracer
         with tracer.span("serve.pull", step=self._step_clock,
-                         bytes=toks.nbytes + lps.nbytes):
+                         bytes=toks.nbytes + lps.nbytes, d2h=1):
             t_dev = time.perf_counter()
-            toks = np.asarray(toks)
-            lps = np.asarray(lps)
+            toks, lps = jax.device_get((toks, lps))
             self.device_time_s += time.perf_counter() - t_dev
         with tracer.span("serve.emit", step=self._step_clock,
                          tokens=len(live)):
@@ -1714,12 +1717,10 @@ class ServingEngine:
         # ONE batched host transfer harvests the whole horizon: [N, B]
         # tokens + logprobs and the per-slot produced counts
         with self.telemetry.tracer.span(
-                "serve.pull", step=self._step_clock,
+                "serve.pull", step=self._step_clock, d2h=1,
                 bytes=toks.nbytes + lps.nbytes + produced.nbytes):
             t_dev = time.perf_counter()
-            toks = np.asarray(toks)
-            lps = np.asarray(lps)
-            produced = np.asarray(produced)
+            toks, lps, produced = jax.device_get((toks, lps, produced))
             self.device_time_s += time.perf_counter() - t_dev
         if self.costs.enabled:
             # one fused dispatch: each live slot produced its own token
@@ -2036,10 +2037,12 @@ class ServingEngine:
                     # watchdog's elapsed measurement cover the actual
                     # execution instead of just the enqueue
                     t_dev = time.perf_counter()
-                    with tracer.span("serve.dispatch.enqueue"):
-                        # host: arguments, transfers, launch
+                    with tracer.span("serve.dispatch.enqueue") as enqueue:
+                        # host: arguments, the one transfer, launch
                         self.faults.fire(site)
                         out = fn(*args)
+                        h2d, h2d_bytes = self.engine.h2d
+                        enqueue.set(h2d=h2d, h2d_bytes=h2d_bytes)
                     with tracer.span("serve.dispatch.wait"):
                         out = jax.block_until_ready(out)
                     self.device_time_s += time.perf_counter() - t_dev

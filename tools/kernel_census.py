@@ -311,6 +311,128 @@ def paged_time_rows():
         yield f"paged decode time {name}", run
 
 
+# the two serving configurations' dispatch shapes: (configuration, slots,
+# table entries a slot (kexaone: 256 full + the ring's 9), prefill chunk,
+# vocabulary as served)
+DISPATCH_SHAPES = (("gpt2-xl-serve", 17, 64, 64, 50257),
+                   ("k-exaone-236b-a23b-serve-ep8", 48, 265, 256, 19200))
+DISPATCH_REPS = 200
+
+
+def _host_us(fn, reps=DISPATCH_REPS):
+    """Median microseconds of HOST time ``fn()`` takes to return; what it
+    returns is waited for outside the timing, as a serving dispatch's
+    enqueue does not wait for the device."""
+    jax.block_until_ready(fn())
+    times = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        out = fn()
+        times.append(time.perf_counter() - t0)
+        jax.block_until_ready(out)
+    return round(float(np.median(times)) * 1e6, 1)
+
+
+def dispatch_operand_rows():
+    """What a serving dispatch pays on the host for its operands, at the
+    two serving configurations' shapes (PERF.md, PR 31): each
+    ``jnp.asarray`` the wrappers made per dispatch before PR 31 (eleven a
+    prefill chunk, five a decode step) and the launch on the converted
+    operands, against ``pack_operands`` and the launch on the one packed
+    numpy buffer; and the two ``np.asarray`` pulls of the sampled tokens
+    and log-probabilities against one ``jax.device_get`` of the pair. The
+    programs are stand-ins that read every operand (thirty small device
+    arrays in ``params``' place), so the times are the host's: argument
+    handling, transfers, launch."""
+    from deepspeed_tpu.inference.engine import _packed, pack_operands
+    r = np.random.default_rng(7)
+    for name, B, NB, C, V in DISPATCH_SHAPES:
+        def run(B=B, NB=NB, C=C, V=V):
+            seen = np.zeros((B, V), bool)
+            i32, f32, u32 = jnp.int32, jnp.float32, jnp.uint32
+            tables = r.integers(1, 1000, (B, NB)).astype(np.int32)
+            # (name, host value as the scheduler holds it, the dtype the
+            # old wrapper converted it to, its kind in the packed buffer)
+            prefill = (
+                ("table_row", tables[0], i32, "i"),
+                ("tokens", r.integers(0, V, C).astype(np.int32), i32, "i"),
+                ("start", 128, i32, "i"), ("n_valid", C, i32, "i"),
+                ("key", np.array([0, 11], np.uint32), u32, "u"),
+                ("gen_count", 3, i32, "i"),
+                ("temp", np.float32(0.0), f32, "f"),
+                ("top_k", np.int32(0), i32, "i"),
+                ("top_p", np.float32(1.0), f32, "f"),
+                ("rep_pen", np.float32(1.0), f32, "f"),
+                ("seen_row", seen[0], jnp.bool_, None))
+            decode = (
+                ("tables", tables, i32, "i"),
+                ("lengths", r.integers(1, 900, B).astype(np.int32), i32, "i"),
+                ("tokens", r.integers(0, V, B).astype(np.int32), i32, "i"),
+                ("active", np.arange(B) % 2 == 0, jnp.bool_, "b"),
+                ("gen_counts", np.arange(B, dtype=np.int32), i32, "i"))
+            params = {f"w{i}": jnp.ones((8, 128), f32) for i in range(30)}
+            pool = jnp.zeros((4, 8, 128), jnp.bfloat16)
+            seen_dev = jax.device_put(seen)
+
+            def programs(phase, n_out):
+                def fn(params, k_pool, v_pool, *ops, scales=None, lora=None):
+                    acc = sum(jnp.sum(o.astype(f32)) for o in ops) \
+                        + sum(jnp.sum(w) for w in params.values())
+                    lps = jnp.full((n_out,), acc, f32)
+                    return lps.astype(i32), lps
+                return jax.jit(fn), jax.jit(_packed(fn, f"census_{phase}"),
+                                            static_argnames=("layout",))
+
+            row = {}
+            for phase, ops, n_out in (("prefill", prefill, 1),
+                                      ("decode", decode, B)):
+                each = {k: _host_us(lambda v=v, d=d: jnp.asarray(v, d))
+                        for k, v, d, _ in ops}
+                old, new = programs(phase, n_out)
+                dev = [jnp.asarray(v, d) for _, v, d, _ in ops]
+                parts = [(kind, v) for _, v, _, kind in ops if kind]
+                if phase == "prefill":      # its row of the resident mask
+                    parts.append(("row", 0))
+
+                def old_path():
+                    return old(params, pool, pool,
+                               *(jnp.asarray(v, d) for _, v, d, _ in ops))
+
+                def new_path():
+                    packed, layout = pack_operands(*parts)
+                    return new(params, pool, pool, packed, layout, seen_dev)
+
+                np.testing.assert_array_equal(old_path()[1], new_path()[1])
+                row.update({
+                    f"us_{phase}_asarray": each,
+                    f"us_{phase}_asarray_sum": round(sum(each.values()), 1),
+                    f"us_{phase}_launch_converted": _host_us(
+                        lambda: old(params, pool, pool, *dev)),
+                    f"us_{phase}_old": _host_us(old_path),
+                    f"us_{phase}_pack": _host_us(
+                        lambda: pack_operands(*parts)[0]),
+                    f"us_{phase}_packed": _host_us(new_path),
+                    f"{phase}_packed_bytes": pack_operands(*parts)[0].nbytes})
+                two, one = [], []
+                for _ in range(DISPATCH_REPS):
+                    for times, pull in (
+                            (two, lambda o: (np.asarray(o[0]),
+                                             np.asarray(o[1]))),
+                            (one, jax.device_get)):
+                        out = jax.block_until_ready(old(params, pool, pool,
+                                                        *dev))
+                        t0 = time.perf_counter()
+                        pull(out)
+                        times.append(time.perf_counter() - t0)
+                row[f"us_{phase}_pull_two_asarray"] = round(
+                    float(np.median(two)) * 1e6, 1)
+                row[f"us_{phase}_pull_device_get"] = round(
+                    float(np.median(one)) * 1e6, 1)
+            return {**row, "ok": row["us_prefill_packed"]
+                    < row["us_prefill_old"]}
+        yield f"dispatch operands {name}", run
+
+
 def int8_matmul_rows():
     from deepspeed_tpu.ops.int8_matmul import (fit_blocks, int8_matmul,
                                                int8_matmul_reference)
@@ -354,7 +476,8 @@ def main():
     failed = 0
     with open(OUT, "a") as out:
         for rows in (flash_rows, ring_block_rows, paged_rows,
-                     paged_time_rows, int8_matmul_rows, blocksparse_rows):
+                     paged_time_rows, dispatch_operand_rows,
+                     int8_matmul_rows, blocksparse_rows):
             for name, run in rows():
                 if wanted and not any(w in name for w in wanted):
                     continue
