@@ -31,7 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from deepspeed_tpu.inference import hybrid, paged_cache, sampling
+from deepspeed_tpu.inference import hybrid, latent, paged_cache, sampling
 from deepspeed_tpu.inference.hybrid import _heads, _rows, causal_band
 from deepspeed_tpu.models import gpt as gpt_lib
 from deepspeed_tpu.ops import quantizer
@@ -918,12 +918,16 @@ class InferenceEngine:
             self._gather_blocks = jax.jit(partial(paged_cache.gather_blocks))
             self._scatter_block = jax.jit(
                 partial(paged_cache.scatter_block), donate_argnums=(0,))
-        if hybrid.is_hybrid(config):
-            # two kinds of attention state: only the two paged serving
-            # programs know them. Everything else raises by name rather
-            # than grow a copy of the dialect (ROADMAP D4)
+        if hybrid.is_hybrid(config) or latent.is_latent(config):
+            # two kinds of attention state, or a latent pool: only the
+            # two paged serving programs know them. Everything else
+            # raises by name rather than grow a copy of the dialect
+            # (ROADMAP D4)
+            def refuse(what, *a, **k):
+                hybrid.refuse(config, what)
+                latent.refuse(config, what)
             if mp_size > 1:
-                hybrid.refuse(config, "tensor parallelism (mp_size > 1)")
+                refuse("tensor parallelism (mp_size > 1)")
             for attr, what in (
                     ("_prefill", "the static-cache prefill (generate)"),
                     ("_decode", "the static-cache decode (generate)"),
@@ -934,8 +938,7 @@ class InferenceEngine:
                     ("_cow_blocks", "prefix-cache copy-on-write"),
                     ("_gather_blocks", "the host tier"),
                     ("_scatter_block", "the host tier")):
-                setattr(self, attr, functools.partial(
-                    lambda what, *a, **k: hybrid.refuse(config, what), what))
+                setattr(self, attr, functools.partial(refuse, what))
         # a ProgramCostRegistry that wants the compiled text of each
         # serving program (a ServingEngine with telemetry on sets it)
         self.provenance = None
@@ -1077,6 +1080,12 @@ class InferenceEngine:
                     carry, flat, table_row, positions, n_valid, layer_p,
                     cfg, base, self.decode_impl, experts)
             x, pools = self._hybrid_layers(params, pools, hblock, x, 0)
+        elif latent.is_latent(cfg):
+            def lblock(carry, flat, layer_p, base, lora, experts):
+                return latent.block_prefill(
+                    carry, flat, table_row, positions, n_valid, layer_p,
+                    cfg, base, self.decode_impl, experts)
+            x, pools = self._latent_layers(params, pools, lblock, x, 0)
         else:
             def block(x, pools, layer_p, base, lora):
                 return _block_prefill_paged(x, pools, table_row, positions,
@@ -1128,6 +1137,15 @@ class InferenceEngine:
                     carry, flat, tables, lengths, active, layer_p, cfg,
                     base, impl, experts, plans)
             x, pools = self._hybrid_layers(params, pools, hblock, x, 1)
+        elif latent.is_latent(cfg):
+            plan = decode_plan(lengths, tables.shape[1],
+                               pools[0].rows.shape[2])
+
+            def lblock(carry, flat, layer_p, base, lora, experts):
+                return latent.block_decode(
+                    carry, flat, tables, lengths, active, layer_p, cfg,
+                    base, impl, experts, plan)
+            x, pools = self._latent_layers(params, pools, lblock, x, 1)
         else:
             plan = _paged_plan(pools, tables, lengths, cfg)
 
@@ -1145,33 +1163,54 @@ class InferenceEngine:
 
     def _hybrid_layers(self, params, pools, block, x, phase: int):
         """The layers of both serving programs for a model of two
-        attention kinds (inference/hybrid.py): the leading dense layers
-        and then the sparse layers, each ONE scan over _scan_layers with
-        the full pool and the window rings side by side in the carry.
-        ``pools`` = (K state, V state) as PagedState and so is what comes
-        back beside ``x``; ``phase``: the row of the K state's counters
-        this program adds to (0 prefill, 1 decode)."""
+        attention kinds (inference/hybrid.py), with the full pool and the
+        window rings side by side in the carry. ``pools`` = (K state, V
+        state) as PagedState and so is what comes back beside ``x``;
+        ``phase``: the row of the K state's counters this program adds to
+        (0 prefill, 1 decode)."""
         from deepspeed_tpu.models.exaone_moe import layer_bases
-        cfg = self.cfg
         ks, vs = pools
+        x, flat, stats, route = self._dense_then_sparse(
+            params, (ks.full, vs.full, ks.win, vs.win),
+            layer_bases(self.cfg, ks.full.shape[1], ks.win.shape[1]),
+            block, x, ks.stats, phase)
+        return x, (hybrid.PagedState(flat[0], flat[2], stats, route),
+                   hybrid.PagedState(flat[1], flat[3]))
+
+    def _latent_layers(self, params, pools, block, x, phase: int):
+        """The same for a model with latent attention (inference/
+        latent.py): ``pools`` = (LatentState, None), one pool of rows."""
+        from deepspeed_tpu.models.dots_vlm import layer_bases
+        state, none = pools
+        x, (rows,), stats, route = self._dense_then_sparse(
+            params, (state.rows,),
+            layer_bases(self.cfg, state.rows.shape[1]), block, x,
+            state.stats, phase)
+        return x, (latent.LatentState(rows, stats, route), none)
+
+    def _dense_then_sparse(self, params, flat, bases, block, x, stats,
+                           phase: int):
+        """The leading dense layers and then the sparse layers, each ONE
+        scan over _scan_layers with ``flat`` (the pools, stacked over
+        layers) in the carry and ``bases`` = (dense, sparse) per-layer
+        offsets into them. Beside ``x`` the block carries the dispatch's
+        routing record and, with ``stats`` (telemetry on), the expert
+        layers' counters, added to row ``phase`` of it. Returns (x, flat,
+        stats, route)."""
+        cfg = self.cfg
         params, experts = hybrid.split_experts(params)
         block = functools.partial(block, experts=experts)
-        flat = (ks.full, vs.full, ks.win, vs.win)
-        dense_b, sparse_b = layer_bases(cfg, ks.full.shape[1],
-                                        ks.win.shape[1])
+        dense_b, sparse_b = bases
         aux = {"route": jnp.zeros((cfg.n_sparse_layers, x.shape[0] * x.shape[1],
                                    cfg.moe_k), jnp.int32),
-               "stats": None if ks.stats is None
-               else jnp.zeros_like(ks.stats[0])}
+               "stats": None if stats is None else jnp.zeros_like(stats[0])}
         carry, flat = _scan_layers(block, (x, aux), params, flat,
                                    stack="dense_block", bases=dense_b)
         (x, aux), flat = _scan_layers(block, carry, params, flat,
                                       bases=sparse_b)
-        stats = ks.stats
         if stats is not None:
             stats = stats.at[phase].add(aux["stats"])
-        return x, (hybrid.PagedState(flat[0], flat[2], stats, aux["route"]),
-                   hybrid.PagedState(flat[1], flat[3]))
+        return x, flat, stats, aux["route"]
 
     def _verify_slots_fn(self, params, k_pool, v_pool, tables, lengths,
                          tokens, active, impl="gather", scales=None,
@@ -1394,7 +1433,7 @@ class InferenceEngine:
         if scales is not None:
             from deepspeed_tpu.utils.faults import maybe_fire
             maybe_fire("cache.quantize")
-        if isinstance(k_pool, hybrid.PagedState):
+        if isinstance(k_pool, (hybrid.PagedState, latent.LatentState)):
             k_pool = k_pool._replace(route=None)    # an output only
         if lora is not None:
             parts = (*parts, ("lora", lora[2]))
@@ -1407,7 +1446,7 @@ class InferenceEngine:
                 lora)
         sink = self.provenance
         if sink is not None and pid not in sink.provenance:
-            L, N, bs = getattr(k_pool, "full", k_pool).shape[:3]
+            L, N, bs = paged_cache.paged_pool(k_pool).shape[:3]
             grid = ()
             if kernel_table:
                 B, nb = kernel_table

@@ -1,0 +1,230 @@
+"""Paged serving blocks for a model with latent attention (MLA;
+models/dots_vlm.py): a THIRD kind of cache state. A token's row in a layer
+is ``[c_kv | k_r | 0...]``, the normalised latent of ``kv_lora_rank``
+values, the one rotated key of ``qk_rope_head_dim`` values that all heads
+share, and zeros up to whole lane tiles: ONE pool ``[L, N, block, lanes]``
+behind the slot's block table, no V pool, no KV heads
+(:class:`LatentState` rides in ``k_pool``'s place as hybrid.PagedState
+does; ``v_pool`` is None). Block tables, the allocator and the two serving
+programs are the GPT blocks'.
+
+Two attention paths over the same rows, the same numbers:
+
+- DECODE, absorbed: the up-projection's key half is folded into the query
+  (``q_n_j W^K_j^T``) and its value half applied after the attention, so a
+  step reads the latent rows THROUGH the block table
+  (ops/attention/mla.py) and never expands them to per-head keys and
+  values;
+- PREFILL, expanded: a chunk's queries attend per-head keys and values
+  re-expanded from the cached latents of the slot's OCCUPIED history, a
+  block at a time under a running max and sum, then the chunk itself,
+  causal. Temporaries are a block's, not the table's: the loop's trip count
+  follows ``start``.
+
+One compiled body per layer SHAPE: the leading dense layers and then the
+sparse layers, each one scan of engine._scan_layers with the pool in the
+carry. The FFN is inference/hybrid.py's (a dense SwiGLU or the expert
+share, with the dispatch's routing record and counters).
+
+Not served with a latent pool, and refused at construction by name: int8
+pools (a scale per KV head has no meaning here), prefix sharing and
+copy-on-write, the host tier, speculation/verify, the fused horizon, LoRA,
+tensor parallelism; nor the static-cache paths (generate, generate_fused,
+forward). docs/LATENT_ATTENTION.md."""
+
+from typing import NamedTuple, Optional
+
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.inference.hybrid import _ffn
+from deepspeed_tpu.models.gpt import _dense, _norm
+from deepspeed_tpu.ops.attention.paged import NEG_INF
+from deepspeed_tpu.ops.attention.rotary import apply_rotary_freqs
+
+
+class LatentState(NamedTuple):
+    """The device state of a latent paged cache. ``rows`` ``[L, N, block,
+    lanes]``; ``stats`` / ``route``: the expert layers' counters and the
+    last dispatch's selection, as hybrid.PagedState's."""
+    rows: jnp.ndarray
+    stats: Optional[jnp.ndarray] = None
+    route: Optional[jnp.ndarray] = None
+
+    def delete(self):
+        for a in self:
+            if a is not None:
+                a.delete()
+
+
+def is_latent(cfg) -> bool:
+    return bool(getattr(cfg, "kv_lora_rank", 0))
+
+
+def refuse(cfg, feature: str):
+    """Raise for a serving feature that cannot yet live with a latent pool."""
+    if is_latent(cfg):
+        raise ValueError(
+            f"{feature} is not supported for a model with a latent (MLA) "
+            f"cache row (one pool of latents, no K/V heads): see "
+            f"docs/LATENT_ATTENTION.md")
+
+
+def _project(h, p, cfg, positions):
+    """h ``[T, d]`` at ``positions`` ``[T]`` -> the heads' queries ``q_n``
+    ``[T, H, d_n]`` and ``q_r`` ``[T, H, d_r]`` (rotated), and the tokens'
+    cache rows ``[T, lanes]``."""
+    H, dn, dr = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    rkv = cfg.kv_lora_rank
+    freqs = cfg.rope_inv_freq
+    with jax.named_scope("mla_q"):
+        c_q = _norm(_dense(h, p["q_a"]), p["q_a_norm"], cfg)
+        q = _dense(c_q, p["q_b"]).reshape(-1, H, dn + dr)
+        q_n = q[..., :dn]
+        q_r = apply_rotary_freqs(q[..., dn:], positions, freqs)
+    with jax.named_scope("mla_kv_down"):
+        ckv = _dense(h, p["kv_a"])
+        c = _norm(ckv[:, :rkv], p["kv_a_norm"], cfg)
+        k_r = apply_rotary_freqs(ckv[:, rkv:], positions, freqs)
+        rows = jnp.concatenate(
+            [c, k_r, jnp.zeros((c.shape[0], cfg.latent_lanes
+                                - cfg.latent_row), c.dtype)], axis=-1)
+    return q_n, q_r, rows
+
+
+def _expand(rows, p, cfg):
+    """Cache rows ``[S, lanes]`` -> per-head keys ``[H, S, d_n + d_r]``
+    (the shared rotated key behind each head's own) and values ``[H, S,
+    d_v]``: the up-projection, as the prefill path attends."""
+    rkv, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    c = rows[:, :rkv]
+    k_n = jnp.einsum("sc,hdc->hsd", c, p["k_up"]["kernel"].astype(c.dtype))
+    v = jnp.einsum("sc,hcd->hsd", c, p["v_up"]["kernel"].astype(c.dtype))
+    k_r = jnp.broadcast_to(rows[None, :, rkv:rkv + dr],
+                           (k_n.shape[0], rows.shape[0], dr))
+    return jnp.concatenate([k_n, k_r], axis=-1), v
+
+
+def _attend_tile(carry, q, k, v, kpos, qpos, scale):
+    """One flash step: queries ``q`` ``[H, C, d]`` at ``qpos`` ``[C]``
+    against a tile's ``k`` ``[H, S, d]`` / ``v`` ``[H, S, d_v]`` at ``kpos``
+    ``[S]`` (a key a query may not see: ``kpos > qpos``). ``carry`` = the
+    running (max ``[H, C]``, sum ``[H, C]``, accumulator ``[H, C, d_v]``),
+    float32."""
+    m, l, acc = carry
+    s = jnp.einsum("hcd,hsd->hcs", q, k).astype(jnp.float32) * scale
+    s = jnp.where(kpos[None, None, :] <= qpos[None, :, None], s, NEG_INF)
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+    pr = jnp.exp(s - m_new[..., None])
+    alpha = jnp.exp(m - m_new)
+    l = alpha * l + jnp.sum(pr, axis=-1)
+    acc = acc * alpha[..., None] + jnp.einsum(
+        "hcs,hsd->hcd", pr.astype(v.dtype), v).astype(jnp.float32)
+    return m_new, l, acc
+
+
+def block_prefill(carry, pools, table_row, positions, n_valid, p, cfg, base,
+                  impl, experts):
+    """One layer over a PROMPT CHUNK of one slot, the expanded path.
+    ``carry`` = (x ``[1, C, d]``, aux); ``pools`` = (rows,), flat over
+    layers; ``table_row`` the slot's block table; ``base`` this layer's
+    offset into the pool and its sparse index (models/dots_vlm.layer_bases);
+    ``experts``: every sparse layer's expert kernels
+    (hybrid.split_experts)."""
+    x, aux = carry
+    (pool,) = pools
+    C = x.shape[1]
+    H, dv = cfg.n_heads, cfg.v_head_dim
+    bs = pool.shape[1]
+    NB = table_row.shape[0]
+    start = positions[0]
+    valid = jnp.arange(C) < n_valid
+    scale = cfg.softmax_scale
+
+    with jax.named_scope("attn_qkv"):
+        h = _norm(x[0], p["ln1"], cfg)
+        q_n, q_r, rows = _project(h, p, cfg, positions)
+        q = jnp.concatenate([q_n, q_r], axis=-1).transpose(1, 0, 2)
+
+    with jax.named_scope("kv_write"):
+        blk = table_row[jnp.clip(positions // bs, 0, NB - 1)]
+        blk = jnp.where(valid, blk, 0) + base["rows"]
+        pool = pool.at[blk, positions % bs].set(rows)
+
+    with jax.named_scope("paged_attn"), jax.named_scope("attn_mla"):
+        init = (jnp.full((H, C), NEG_INF, jnp.float32),
+                jnp.zeros((H, C), jnp.float32),
+                jnp.zeros((H, C, dv), jnp.float32))
+        # the chunk itself, causal, from the rows it has just made
+        with jax.named_scope("mla_expand"):
+            k, v = _expand(rows, p, cfg)
+        state = _attend_tile(init, q, k, v, positions, positions, scale)
+
+        def history(j, state):
+            # block j of the slot's OCCUPIED history, re-expanded as it
+            # is attended; what of it lies at or past ``start`` (the
+            # chunk's own rows, a block's unwritten tail) is masked
+            tile = pool[table_row[j] + base["rows"]]
+            kpos = j * bs + jnp.arange(bs, dtype=jnp.int32)
+            kpos = jnp.where(kpos < start, kpos, jnp.int32(2 ** 30))
+            with jax.named_scope("mla_expand"):
+                k, v = _expand(tile, p, cfg)
+            return _attend_tile(state, q, k, v, kpos, positions, scale)
+
+        _, l, acc = jax.lax.fori_loop(0, (start + bs - 1) // bs, history,
+                                      state)
+        attn = (acc / l[..., None]).astype(x.dtype)          # [H, C, d_v]
+    with jax.named_scope("attn_out"):
+        x2 = x[0] + _dense(attn.transpose(1, 0, 2).reshape(C, H * dv),
+                           p["attn_out"])
+    y, aux = _ffn(x2, p, cfg, impl, valid, aux, base["index"], experts)
+    return (y[None], aux), (pool,)
+
+
+def block_decode(carry, pools, tables, lengths, active, p, cfg, base, impl,
+                 experts, plan=None):
+    """One layer for ONE new token per slot, the absorbed path: the
+    token's row is written at its position and every head attends the
+    slot's rows through ``tables`` ``[B, NB]``."""
+    x, aux = carry
+    (pool,) = pools
+    B = x.shape[0]
+    H, dv, rkv = cfg.n_heads, cfg.v_head_dim, cfg.kv_lora_rank
+    bs = pool.shape[1]
+    NB = tables.shape[1]
+
+    with jax.named_scope("attn_qkv"):
+        h = _norm(x[:, 0], p["ln1"], cfg)
+        q_n, q_r, rows = _project(h, p, cfg, lengths)
+
+    with jax.named_scope("kv_write"):
+        blk = jnp.take_along_axis(
+            tables, jnp.clip(lengths // bs, 0, NB - 1)[:, None], axis=1)[:, 0]
+        ok = jnp.logical_and(active, lengths < NB * bs)
+        blk = jnp.where(ok, blk, 0) + base["rows"]
+        pool = pool.at[blk, lengths % bs].set(rows)
+
+    with jax.named_scope("paged_attn"), jax.named_scope("attn_mla"):
+        with jax.named_scope("mla_absorb"):
+            q_abs = jnp.einsum("bhd,hdc->bhc", q_n,
+                               p["k_up"]["kernel"].astype(q_n.dtype))
+            q = jnp.concatenate(
+                [q_abs, q_r, jnp.zeros((B, H, cfg.latent_lanes
+                                        - cfg.latent_row), q_n.dtype)], -1)
+        if impl == "pallas":
+            from deepspeed_tpu.ops.attention.mla import mla_decode_attention
+            lat = mla_decode_attention(
+                q, pool, tables + base["rows"], lengths, value_width=rkv,
+                scale=cfg.softmax_scale, plan=plan)
+        else:
+            from deepspeed_tpu.ops.attention.mla import mla_decode_reference
+            lat = mla_decode_reference(
+                q, pool, tables + base["rows"], lengths, value_width=rkv,
+                scale=cfg.softmax_scale)
+        with jax.named_scope("mla_absorb"):
+            attn = jnp.einsum("bhc,hcd->bhd", lat,
+                              p["v_up"]["kernel"].astype(lat.dtype))
+    with jax.named_scope("attn_out"):
+        x2 = x[:, 0] + _dense(attn.reshape(B, H * dv), p["attn_out"])
+    y, aux = _ffn(x2, p, cfg, impl, active, aux, base["index"], experts)
+    return (y[:, None], aux), (pool,)

@@ -81,7 +81,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.utils.env import resolve_flag
-from deepspeed_tpu.inference import hybrid
+from deepspeed_tpu.inference import hybrid, latent
 from deepspeed_tpu.inference.host_tier import (
     HostBlockPool, HostCorruption, resolve_host_tier)
 from deepspeed_tpu.inference.prefix_index import PrefixIndex, PrefixMatch
@@ -112,6 +112,12 @@ def resolve_prefix_cache(flag: Optional[bool] = None) -> bool:
 # serving engine wires its model engine's own jits of these functions in
 # (``copy_fn`` / ``gather_fn`` / ``scatter_fn``); a standalone cache runs
 # the module-level ones.
+def paged_pool(k):
+    """The pool behind the block tables in a K-side state: the array
+    itself, a two-kind state's full layers' pool, a latent state's rows."""
+    return getattr(k, "full", getattr(k, "rows", k))
+
+
 def copy_block(pools, src, dst):
     """Copy ONE pool block (every layer) ``src`` -> ``dst``: the device
     half of copy-on-write. Pools are donated, so the copy is in place in
@@ -219,16 +225,20 @@ class PagedKVCache:
         # a model with sliding-window layers pages only its full layers
         L = getattr(cfg, "n_full_layers", cfg.n_layers)
         Hkv, Dh = cfg.kv_heads, cfg.head_dim
+        # what cannot yet live with bounded window state or with a
+        # latent pool raises here, by name
+        for on, what in ((prefix_cache, "prefix sharing (prefix_cache)"),
+                         (self.quantized, "int8 KV pools (kv_quant)"),
+                         (resolve_host_tier(host_tier) and prefix_cache,
+                          "the host tier (host_tier)")):
+            if on:
+                hybrid.refuse(cfg, what)
+                latent.refuse(cfg, what)
         self.ring_blocks = 0
         if hybrid.is_hybrid(cfg):
-            for on, what in ((prefix_cache, "prefix sharing (prefix_cache)"),
-                             (self.quantized, "int8 KV pools (kv_quant)"),
-                             (resolve_host_tier(host_tier) and prefix_cache,
-                              "the host tier (host_tier)")):
-                if on:
-                    hybrid.refuse(cfg, what)
             from deepspeed_tpu.models.exaone_moe import window_blocks
             self.ring_blocks = window_blocks(cfg, self.block_size)
+        self.latent = latent.is_latent(cfg)
         self.pool_dtype = jnp.dtype(jnp.int8) if self.quantized \
             else self.dtype
         self.bytes_per_token = gpt_lib.kv_bytes_per_token(
@@ -263,9 +273,18 @@ class PagedKVCache:
         # one row per cached token, its kv heads folded side by side:
         # the layout in HBM that the entry parameter, the layer loop and
         # the kernel share (module docstring)
-        self.k = jnp.zeros((L, self.num_blocks, self.block_size, Hkv * Dh),
-                           self.pool_dtype)
-        self.v = jnp.zeros_like(self.k)
+        if self.latent:
+            # a third kind of state (inference/latent.py): one pool of
+            # latent rows, no V pool
+            self.k = latent.LatentState(jnp.zeros(
+                (L, self.num_blocks, self.block_size, cfg.latent_lanes),
+                self.pool_dtype))
+            self.v = None
+        else:
+            self.k = jnp.zeros(
+                (L, self.num_blocks, self.block_size, Hkv * Dh),
+                self.pool_dtype)
+            self.v = jnp.zeros_like(self.k)
         if self.ring_blocks:
             # two kinds of state side by side (inference/hybrid.py): the
             # pool above is the full layers'; each window layer keeps
@@ -1078,7 +1097,8 @@ class PagedKVCache:
         """The cache's device state as ONE value, what every block copy
         takes and every serving program hands back: ``(k, v)``, with
         int8 pools ``(k, v, k_scale, v_scale)``; for a model of two
-        attention kinds k and v are hybrid.PagedState."""
+        attention kinds k and v are hybrid.PagedState, for one with
+        latent attention ``(latent.LatentState, None)``."""
         return (self.k, self.v) + (self.scales or ())
 
     @pools.setter

@@ -144,7 +144,7 @@ import numpy as np
 from deepspeed_tpu.inference import sampling
 from deepspeed_tpu.inference.adapters import (AdapterLoadError, AdapterPool,
                                               resolve_lora_serve)
-from deepspeed_tpu.inference import hybrid
+from deepspeed_tpu.inference import hybrid, latent
 from deepspeed_tpu.inference.host_tier import resolve_host_tier
 from deepspeed_tpu.inference.paged_cache import (CacheExhausted,
                                                  PagedKVCache,
@@ -589,6 +589,7 @@ class ServingEngine:
                 (self.lora_serve, "LoRA serving (lora_serve)")):
             if on:
                 hybrid.refuse(engine.cfg, what)
+                latent.refuse(engine.cfg, what)
         # the engine's own jits of the three block copies (COW, and the
         # host tier's gather and scatter) are wired in when present:
         # each takes the cache's pools whole, scales included
@@ -609,7 +610,7 @@ class ServingEngine:
         # the EFFECTIVE switch: the cache gates the tier on the prefix
         # index existing (only indexed blocks ever spill)
         self.host_tier = self.cache.host_tier
-        if self.cache.ring_blocks and self.telemetry.enabled:
+        if hasattr(self.cache.k, "stats") and self.telemetry.enabled:
             # expert-layer counters ride with the K state, on the device
             # (read_expert_counters pulls them)
             from deepspeed_tpu.moe.expert_share import STAT_FIELDS
@@ -685,6 +686,7 @@ class ServingEngine:
         self._horizon_ticks = 1
         self._token_tick = 0.0
         self._kv_steps = 0      # the serve.decode span's, telemetry on
+        self._kv_tokens = 0
         self.last_step_span = 1.0
         self.token_time_unit = 0.0
         # wall seconds spent inside device dispatch/harvest calls — the
@@ -815,6 +817,19 @@ class ServingEngine:
                       "device bytes of the sliding-window layers' per-slot "
                       "rings (K+V, all slots; 0 without such layers)").set(
                 self.cache.window_bytes)
+            if self.cache.latent:
+                # a third kind of state (inference/latent.py)
+                reg.gauge("kv_latent_pool_bytes",
+                          "device bytes of the latent (MLA) pool: one row "
+                          "a token a layer as stored (padded to whole "
+                          "lane tiles), trash block included").set(
+                    (self.cache.num_blocks * self.cache.block_size
+                     * self.cache.bytes_per_token))
+                reg.gauge("kv_latent_row_bytes",
+                          "bytes of one token's latent row in one layer "
+                          "as computed: the latent and the shared rotated "
+                          "key, without the padding").set(
+                    engine.cfg.latent_row * self.cache.pool_dtype.itemsize)
             self._h_kv_err = reg.histogram(
                 "serving_kv_quant_error",
                 "sampled upper bound on the max-abs KV dequantization "
@@ -1116,6 +1131,7 @@ class ServingEngine:
                 if c4:
                     s_decode.set(live=occ, blocks=c4[3],
                                  kv_steps=self._kv_steps,
+                                 kv_tokens=self._kv_tokens,
                                  evicted=c4[2] - c3[2])
             with tracer.span("serve.spill", step=clock) as s_spill:
                 self._spill_step()
@@ -1451,7 +1467,7 @@ class ServingEngine:
             n = min(self.prefill_chunk, len(req._work) - done)
             with self.telemetry.tracer.span(
                     "serve.prefill", rid=req.rid, step=self._step_clock,
-                    slot=slot, start=done, n=n):
+                    slot=slot, start=done, n=n, history=done):
                 self._prefill_slot_chunk(slot, req, done, n, now)
 
     def _prefill_slot_chunk(self, slot: int, req: ServeRequest,
@@ -1565,6 +1581,9 @@ class ServingEngine:
                           cache.block_size,
                           None if cache.ring_blocks
                           else self.engine.cfg.attn_window) for i in live)
+            # cached rows the step reads in a layer that pages its whole
+            # history: each live slot's tokens and the one it writes
+            self._kv_tokens = int(sum(cache.lengths[i] + 1 for i in live))
         if not live:
             return 0
         if self.spec_decode:

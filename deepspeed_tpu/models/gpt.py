@@ -985,7 +985,13 @@ def kv_bytes_per_token(cfg: GPTConfig, dtype=jnp.bfloat16) -> int:
     static engine pays this for `max_batch x S_max` slots up front; the
     paged cache pays it per token actually in flight. Counted by layer
     kind: a sliding-window layer keeps a bounded ring per slot
-    (:func:`kv_window_bytes_per_slot`) and adds nothing per token."""
+    (:func:`kv_window_bytes_per_slot`) and adds nothing per token; a
+    latent (MLA) layer keeps ONE row of ``latent_lanes`` values a token
+    (the latent and the shared rotated key, padded to whole lane tiles as
+    the pool stores it) and no K or V heads."""
+    if getattr(cfg, "kv_lora_rank", 0):
+        return int(cfg.n_layers * cfg.latent_lanes
+                   * jnp.dtype(dtype).itemsize)
     layers = getattr(cfg, "n_full_layers", cfg.n_layers)
     return int(2 * layers * cfg.kv_heads * cfg.head_dim
                * jnp.dtype(dtype).itemsize)

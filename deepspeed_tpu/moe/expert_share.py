@@ -2,8 +2,9 @@
 
 The layer is TOLD which routed experts it holds (``held = (first, count)``
 of the published ``num_experts``). It routes every token over ALL experts
-(sigmoid scores, a per-expert bias that only selects, top-k, weights
-renormalised over the k and scaled), computes only the (token, expert)
+(sigmoid scores, a per-expert bias that only selects, top-k (inside the
+best groups where the config has a group limit), weights renormalised over
+the k and scaled), computes only the (token, expert)
 pairs that fall on the experts held here, and adds the shared expert, which
 every chip computes alike. What the absent experts would add is left out:
 on one chip the layer runs without its exchange, and nothing stands in for
@@ -30,16 +31,27 @@ STAT_FIELDS = ("pairs_held", "pairs_total", "busiest_expert_pairs",
                "experts_touched", "layer_calls")
 
 
-def route(h, router: Dict, k: int, scaling: float
-          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+def route(h, router: Dict, k: int, scaling: float, n_group: int = 1,
+          topk_group: int = 1) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """h ``[T, d]`` -> (selected experts ``[T, k]`` int32, their weights
     ``[T, k]`` float32). Scores and selection in float32 at full matmul
-    precision: a rounded score swaps near-tied experts."""
+    precision: a rounded score swaps near-tied experts. With ``n_group``
+    > 1 the experts lie in that many equal groups in order, and a token
+    selects only inside the ``topk_group`` groups whose two best biased
+    scores sum highest (DeepSeek-V3's ``noaux_tc``)."""
     logits = jnp.dot(h.astype(jnp.float32),
                      router["kernel"].astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     scores = jax.nn.sigmoid(logits)
-    _, sel = jax.lax.top_k(scores + router["bias"].astype(jnp.float32), k)
+    biased = scores + router["bias"].astype(jnp.float32)
+    if n_group > 1:
+        T, E = biased.shape
+        per = biased.reshape(T, n_group, E // n_group)
+        best2 = jnp.sum(jax.lax.top_k(per, 2)[0], axis=-1)      # [T, G]
+        _, groups = jax.lax.top_k(best2, topk_group)
+        kept = jnp.any(groups[:, :, None] == jnp.arange(n_group), axis=1)
+        biased = jnp.where(kept[:, :, None], per, -jnp.inf).reshape(T, E)
+    _, sel = jax.lax.top_k(biased, k)
     w = jnp.take_along_axis(scores, sel, axis=-1)
     w = w / jnp.sum(w, axis=-1, keepdims=True) * scaling
     return sel.astype(jnp.int32), w
@@ -116,7 +128,9 @@ def sparse_ffn(h, moe: Dict, cfg, impl: str, valid=None, mlp=None,
     ``moe["experts"]``. Returns (routed + shared, selection ``[T, k]``,
     stats)."""
     with jax.named_scope("moe_router"):
-        sel, w = route(h, moe["router"], cfg.moe_k, cfg.routed_scaling)
+        sel, w = route(h, moe["router"], cfg.moe_k, cfg.routed_scaling,
+                       getattr(cfg, "n_group", 1),
+                       getattr(cfg, "topk_group", 1))
     with jax.named_scope("moe_experts"):
         routed, stats = held_experts_ffn(
             h, moe["experts"] if experts is None else experts, sel, w,

@@ -120,6 +120,10 @@ def paged_hbm_bytes_per_token(cfg, num_slots: int, mean_len: float,
     per-block fp32 scale overhead into the per-token cost."""
     per_tok = 2.0 * cfg.n_layers * cfg.kv_heads * cfg.head_dim \
         * jnp.dtype(dtype).itemsize
+    if getattr(cfg, "kv_lora_rank", 0):
+        # latent attention: one row a token a layer, no K or V heads
+        from deepspeed_tpu.models.gpt import kv_bytes_per_token
+        per_tok = float(kv_bytes_per_token(cfg, dtype))
     if scale_bytes_per_block and block_size:
         # the scale pools are read alongside every block DMA
         per_tok += scale_bytes_per_block / float(block_size)

@@ -10,9 +10,11 @@ GPT-J uses the interleaved ("rotate every two") layout on the first
 ``rotary_dim`` channels of each head; remaining channels pass through.
 """
 
+import math
 from typing import Optional, Tuple
 
 import jax.numpy as jnp
+import numpy as np
 
 
 def _rotate_every_two(x: jnp.ndarray) -> jnp.ndarray:
@@ -71,3 +73,50 @@ def apply_rotary_half(x: jnp.ndarray, positions: jnp.ndarray,
     x1, x2 = xf[..., :D // 2], xf[..., D // 2:]
     return (xf * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
             ).astype(x.dtype)
+
+
+def yarn_inv_freq(rotary_dim: int, base: float, factor: float,
+                  original_max: int, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0) -> np.ndarray:
+    """YaRN's per-pair frequencies (Peng et al. 2023, as DeepSeek-V3's
+    published modelling code computes them): pair ``i`` of ``rotary_dim / 2``
+    keeps its frequency ``f_i = base^(-2i/dim)`` where it turns more than
+    ``beta_fast`` times within the original context, is divided by
+    ``factor`` where it turns fewer than ``beta_slow`` times, and is
+    ramped linearly in between. float64 on the host: the table is a
+    constant of the program."""
+    half = rotary_dim // 2
+    f = base ** (-np.arange(half, dtype=np.float64) * 2.0 / rotary_dim)
+
+    def turns_at(n_rot):
+        return rotary_dim * math.log(original_max / (n_rot * 2 * math.pi)) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(turns_at(beta_fast)), 0)
+    high = min(math.ceil(turns_at(beta_slow)), rotary_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half) - low) / (high - low), 0.0, 1.0)
+    return (f / factor) * ramp + f * (1.0 - ramp)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature ``0.1 * mscale * ln(factor) + 1``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def apply_rotary_freqs(x: jnp.ndarray, positions: jnp.ndarray,
+                       inv_freq) -> jnp.ndarray:
+    """Rotate the interleaved pairs ``(x_2i, x_2i+1)`` of x's last
+    dimension by ``positions * inv_freq[i]`` (a table of given
+    frequencies: :func:`yarn_inv_freq`). ``positions`` is shaped like x's
+    leading dimensions, or like those before a heads axis (it is then
+    broadcast over the heads). float32 inside, x's dtype out."""
+    ang = positions.astype(jnp.float32)[..., None] \
+        * jnp.asarray(inv_freq, jnp.float32)
+    if ang.ndim < x.ndim:
+        ang = ang[..., None, :]
+    sin = jnp.repeat(jnp.sin(ang), 2, axis=-1)
+    cos = jnp.repeat(jnp.cos(ang), 2, axis=-1)
+    xf = x.astype(jnp.float32)
+    return (xf * cos + _rotate_every_two(xf) * sin).astype(x.dtype)

@@ -1,0 +1,395 @@
+"""The dots_vlm dialect (dots.vlm1's language model: latent attention,
+experts routed inside groups, YaRN) on the paged serving path, held to the
+benchmark's plain reference at small sizes: the latent pool, the absorbed
+and the expanded path, the kernel, the router, the share of 16, the
+controls and what raises."""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import dots_vlm_util as U
+from deepspeed_tpu.inference import latent
+from deepspeed_tpu.models import dots_vlm
+from deepspeed_tpu.moe import expert_share
+from deepspeed_tpu.ops.attention import mla, rotary
+
+SOUND = 2e-4        # float32 program against the float32 reference
+WRONG = 2e-2        # every control moves the logits by more than this
+
+
+@pytest.fixture(scope="module")
+def served():
+    cfg = U.tiny_config()
+    params = U.tiny_params(cfg)
+    rng = np.random.default_rng(0)
+    # past YaRN's original range (16), across chunk boundaries (16), one
+    # of them not a multiple of the block (4)
+    prompts = [rng.integers(1, 96, 37), rng.integers(1, 96, 21),
+               rng.integers(1, 96, 48)]
+    srv, got = U.serve_logits(cfg, params, prompts, 7)
+    return cfg, params, prompts, srv, got
+
+
+def _worst(ref, cfg, params, prompts, got, **kw):
+    worst = 0.0
+    for rid, (toks, lg) in got.items():
+        want, _ = ref.logits(params, toks[:-1], U.hp_of(cfg), **kw)
+        want = np.asarray(want)[len(prompts[rid]) - 1:]
+        worst = max(worst, float(np.abs(lg - want).max()))
+    return worst
+
+
+def test_prefill_then_decode_matches_the_reference(served):
+    cfg, params, prompts, srv, got = served
+    assert _worst(U.reference(), cfg, params, prompts, got) < SOUND
+    # one pool of padded latent rows, no V pool
+    assert srv.cache.latent and srv.cache.v is None
+    assert srv.cache.k.rows.shape == (4, srv.cache.num_blocks, 4, 128)
+    assert cfg.latent_row == 20 and cfg.latent_lanes == 128
+    assert srv.cache.bytes_per_token == 4 * 128 * 4
+
+
+@pytest.mark.parametrize("variant", [
+    "no_group_limit", "no_bias", "no_scale", "unnormalised", "wrong_held",
+    "no_yarn", "no_mscale", "rotate_half", "no_q_norm", "no_kv_norm",
+    "fp8_up"])
+def test_each_wrong_router_and_attention_fails(served, variant):
+    cfg, params, prompts, _, got = served
+    err = _worst(U.reference(), cfg, params, prompts, got, variant=(variant,))
+    assert err > WRONG, (variant, err)
+
+
+def test_precision_control_fails(served):
+    cfg, params, prompts, _, got = served
+    assert _worst(U.reference(), cfg, params, prompts, got, fp8=True) > WRONG
+
+
+def test_last_dispatch_routing_is_kept_with_the_state(served):
+    cfg, params, prompts, srv, _ = served
+    route = np.asarray(srv.cache.k.route)
+    assert route.shape == (cfg.n_sparse_layers, 2, cfg.moe_k)   # a decode
+    assert route.min() >= 0 and route.max() < cfg.num_experts
+    assert srv.cache.k.stats is None          # telemetry off: no counters
+
+
+def _layer(cfg, params, stack="block", index=0):
+    return jax.tree_util.tree_map(lambda a: a[index], params[stack])
+
+
+def test_absorbed_and_expanded_paths_agree_on_the_same_cache():
+    """A token decoded over a slot's cached rows (absorbed: the query
+    folded through ``k_up``, the latent mean through ``v_up``) equals the
+    same token prefilled as a one-token chunk (expanded: the history
+    re-expanded to per-head keys and values), layer for layer."""
+    cfg = U.tiny_config()
+    params = U.tiny_params(cfg)
+    p = _layer(cfg, params)
+    bs, NB, T = 4, 8, 13
+    rng = jax.random.PRNGKey(5)
+    hist = jax.random.normal(rng, (1, T, cfg.d_model))
+    pool = jnp.zeros((1 + NB, bs, cfg.latent_lanes))
+    table = jnp.arange(1, NB + 1, dtype=jnp.int32)
+    base = {"rows": jnp.int32(0), "index": jnp.int32(0)}
+    aux = {"route": jnp.zeros((cfg.n_sparse_layers, 16, cfg.moe_k),
+                              jnp.int32), "stats": None}
+    experts = {n: {"kernel": e["kernel"]}
+               for n, e in p["moe"]["experts"].items()}
+    pad = jnp.zeros((1, 16 - T, cfg.d_model))
+    (_, _), (pool,) = latent.block_prefill(
+        (jnp.concatenate([hist, pad], 1), aux), (pool,), table,
+        jnp.arange(16, dtype=jnp.int32), T, p, cfg, base, "gather", experts)
+    x = jax.random.normal(jax.random.PRNGKey(6), (1, 1, cfg.d_model))
+    aux1 = dict(aux, route=aux["route"][:, :1])
+    (y_dec, _), (pool_d,) = latent.block_decode(
+        (x, aux1), (pool,), table[None], jnp.asarray([T], jnp.int32),
+        jnp.asarray([True]), p, cfg, base, "gather", experts)
+    (y_pre, _), (pool_p,) = latent.block_prefill(
+        (x, aux1), (pool,), table, jnp.asarray([T], jnp.int32), 1, p, cfg,
+        base, "gather", experts)
+    np.testing.assert_allclose(np.asarray(y_dec[:, 0]),
+                               np.asarray(y_pre[0]), atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(pool_d), np.asarray(pool_p))
+    # the padding lanes of a written row stay zero
+    assert float(jnp.abs(pool_d[..., cfg.latent_row:]).max()) == 0.0
+    assert float(jnp.abs(pool_d[1, 0, :cfg.latent_row]).max()) > 0.0
+
+
+@functools.partial(jax.jit, static_argnames=("vw", "plan_given"))
+def _kernel_interpreted(q, pool, tables, lengths, *, vw, plan_given=False):
+    from deepspeed_tpu.ops.attention.paged import decode_plan
+    plan = decode_plan(lengths, tables.shape[1], pool.shape[1]) \
+        if plan_given else None
+    return mla.mla_decode_attention(
+        q, pool, tables, lengths, value_width=vw, scale=0.11,
+        interpret=True, plan=plan)
+
+
+@pytest.mark.parametrize("bs,nb", [(16, 24), (128, 3), (8, 4)])
+def test_kernel_in_interpret_mode_equals_the_plain_latent_decode(bs, nb):
+    """Ragged lengths, an idle slot on the trash block, table entries past
+    a slot's length that name trash; tiles of several blocks (16 x 8), of
+    one block (128), and a whole small table in one tile."""
+    B, H, row, vw = 5, 8, 256, 128
+    N = 1 + B * nb
+    ks = jax.random.split(jax.random.PRNGKey(bs), 3)
+    pool = jax.random.normal(ks[0], (N, bs, row))
+    pool = pool.at[0].set(1e4)                   # trash: loud if attended
+    q = jax.random.normal(ks[1], (B, H, row))
+    cap = nb * bs
+    lengths = np.asarray([0, cap - 1, cap // 2, 3, 0], np.int32)
+    tables = 1 + np.arange(B * nb, dtype=np.int32).reshape(B, nb)
+    for b in range(B):                           # unused entries: trash
+        tables[b, lengths[b] // bs + 1:] = 0
+    tables[4] = 0                                # an idle slot
+    args = (q, pool, jnp.asarray(tables), jnp.asarray(lengths))
+    want = mla.mla_decode_reference(*args, value_width=vw, scale=0.11)
+    got = _kernel_interpreted(*args, vw=vw)
+    np.testing.assert_allclose(np.asarray(got[:4]), np.asarray(want[:4]),
+                               atol=2e-5, rtol=2e-5)
+    assert np.isfinite(np.asarray(got)).all()
+    # a plan worked out by the caller is the call's own
+    again = _kernel_interpreted(*args, vw=vw, plan_given=True)
+    np.testing.assert_array_equal(np.asarray(again), np.asarray(got))
+
+
+def _reference_selection(ref, cfg, h, router):
+    """The reference's own group-limited selection for pre-normed ``h``."""
+    hp = U.hp_of(cfg)
+    p = {"ln2": {"scale": jnp.ones((cfg.d_model,))},
+         "moe": {"router": router,
+                 "experts": {"wg": {"kernel": jnp.zeros((1, cfg.d_model, 1))},
+                             "wi": {"kernel": jnp.zeros((1, cfg.d_model, 1))},
+                             "wo": {"kernel": jnp.zeros((1, 1, cfg.d_model))}},
+                 "shared": {n: {"kernel": jnp.zeros((cfg.d_model, 1))
+                                if n != "mlp_out"
+                                else jnp.zeros((1, cfg.d_model))}
+                            for n in ("mlp_gate", "mlp_in", "mlp_out")}}}
+    hp = dict(hp, held=(0, 1))
+    free = -jnp.ones((h.shape[0], cfg.moe_k), jnp.int32)
+    with jax.default_matmul_precision("highest"):
+        _, (own, biased, group) = ref._sparse_ffn(
+            h, p, hp, frozenset(), False, free)
+    return np.asarray(own), np.asarray(biased), np.asarray(group)
+
+
+def test_group_limited_router_equals_the_reference():
+    cfg = U.tiny_config()
+    router = _layer(cfg, U.tiny_params(cfg))["moe"]["router"]
+    x = jax.random.normal(jax.random.PRNGKey(3), (64, cfg.d_model)) * 3.0
+    h = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + cfg.norm_eps)
+    sel, w = expert_share.route(h, router, cfg.moe_k, cfg.routed_scaling,
+                                cfg.n_group, cfg.topk_group)
+    own, biased, group = _reference_selection(U.reference(), cfg, x, router)
+    np.testing.assert_array_equal(np.sort(np.asarray(sel), -1),
+                                  np.sort(own, -1))
+    # every token chose inside its two best groups, and the limit binds
+    per = cfg.num_experts // cfg.n_group
+    kept = np.argsort(-group, -1)[:, :cfg.topk_group]
+    for t in range(64):
+        assert set(np.asarray(sel[t]) // per) <= set(kept[t])
+    free_sel, _ = expert_share.route(h, router, cfg.moe_k,
+                                     cfg.routed_scaling)
+    assert (np.sort(np.asarray(free_sel), -1) != np.sort(own, -1)).any()
+    np.testing.assert_allclose(np.asarray(w).sum(-1), cfg.routed_scaling,
+                               rtol=1e-5)
+
+
+def _route_text(h, router, *groups):
+    def routed(h, router):
+        return expert_share.route(h, router, 3, 2.5, *groups)
+    return jax.jit(routed).lower(h, router).as_text()
+
+
+def test_one_group_is_todays_route():
+    cfg = U.tiny_config()
+    router = _layer(cfg, U.tiny_params(cfg))["moe"]["router"]
+    h = jax.random.normal(jax.random.PRNGKey(4), (32, cfg.d_model))
+    sel, w = expert_share.route(h, router, 3, 2.5)
+    one, w1 = expert_share.route(h, router, 3, 2.5, 1, 1)
+    scores = jax.nn.sigmoid(jnp.dot(
+        h, router["kernel"], precision=jax.lax.Precision.HIGHEST))
+    want = jax.lax.top_k(scores + router["bias"], 3)[1]
+    np.testing.assert_array_equal(np.asarray(sel), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(sel), np.asarray(one))
+    np.testing.assert_array_equal(np.asarray(w), np.asarray(w1))
+    # and the programs are the same: the group limit is a Python branch
+    assert _route_text(h, router) == _route_text(h, router, 1, 1)
+
+
+def test_yarn_frequencies_and_scale_are_the_published_numbers():
+    cfg = dots_vlm.DotsVLMConfig(n_layers=2, n_heads=128, d_model=7168)
+    f = cfg.rope_inv_freq
+    assert f.shape == (32,)
+    # low = floor(64 ln(4096 / (32 * 2 pi)) / (2 ln 1e4)) = 10,
+    # high = ceil(64 ln(4096 / (2 pi)) / (2 ln 1e4)) = 23
+    plain = 10000.0 ** (-np.arange(32) / 32.0)
+    np.testing.assert_allclose(f[:11], plain[:11], rtol=1e-12)
+    np.testing.assert_allclose(f[23:], plain[23:] / 40.0, rtol=1e-12)
+    np.testing.assert_allclose(f[11], 0.03900693, rtol=1e-6)
+    np.testing.assert_allclose(f[22], plain[22] * (1 / 13)
+                               + plain[22] / 40 * (12 / 13), rtol=1e-9)
+    np.testing.assert_allclose(f[22], 1.77827941e-04, rtol=1e-6)
+    np.testing.assert_allclose(f[31], 3.33380358e-06, rtol=1e-6)
+    assert abs(rotary.yarn_mscale(40.0, 1.0) - 1.3688879454) < 1e-9
+    assert abs(cfg.softmax_scale - 192 ** -0.5 * 1.3688879454 ** 2) < 1e-9
+    assert cfg.latent_row == 576 and cfg.latent_lanes == 640
+    # the reference computes the same table by its own code
+    ref_f, amp, scale = U.reference().yarn_frequencies(U.hp_of(cfg))
+    np.testing.assert_allclose(ref_f, f, rtol=1e-12)
+    assert amp == 1.0 and abs(scale - cfg.softmax_scale) < 1e-12
+    # plain rotary where no factor is given
+    flat = dots_vlm.DotsVLMConfig(n_layers=2, n_heads=4, d_model=32,
+                                  rope_factor=1.0)
+    np.testing.assert_allclose(flat.rope_inv_freq, plain, rtol=1e-12)
+    assert abs(flat.softmax_scale - 192 ** -0.5) < 1e-12
+
+
+def test_rotary_pairs_are_interleaved():
+    x = jnp.arange(8, dtype=jnp.float32).reshape(1, 8) + 1.0
+    f = np.asarray([0.5, 0.25, 0.125, 1.0])
+    y = np.asarray(rotary.apply_rotary_freqs(x, jnp.asarray([3]), f))[0]
+    for i in range(4):
+        a, b, ang = x[0, 2 * i], x[0, 2 * i + 1], 3 * f[i]
+        np.testing.assert_allclose(
+            y[2 * i:2 * i + 2], [a * np.cos(ang) - b * np.sin(ang),
+                                 b * np.cos(ang) + a * np.sin(ang)],
+            rtol=1e-5)
+    # over a heads axis the position is shared
+    xh = jnp.stack([x, 2 * x], axis=1)                     # [1, 2, 8]
+    yh = np.asarray(rotary.apply_rotary_freqs(xh, jnp.asarray([3]), f))
+    np.testing.assert_allclose(yh[0, 0], y, rtol=1e-6)
+    np.testing.assert_allclose(yh[0, 1], 2 * y, rtol=1e-6)
+
+
+def test_sixteen_shares_add_up_to_the_whole_layer():
+    """256 experts cut... at this size 16 experts in 16 shares of one: the
+    routed parts of all shares plus the shared expert counted once are the
+    uncut reference's whole layer."""
+    ref = U.reference()
+    whole = U.tiny_config(held=None)
+    p = _layer(whole, U.tiny_params(whole), index=1)
+    x = jax.random.normal(jax.random.PRNGKey(3), (24, whole.d_model)) * 3.0
+    hp = U.hp_of(whole)
+    free = -jnp.ones((x.shape[0], whole.moe_k), jnp.int32)
+    pr = dict(p, ln2={"scale": jnp.ones_like(p["ln2"]["scale"])})
+    with jax.default_matmul_precision("highest"):
+        want, _ = ref._sparse_ffn(x, pr, hp, frozenset(), False, free)
+    xn = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + hp["eps"])
+    sel, w = expert_share.route(xn, p["moe"]["router"], whole.moe_k,
+                                whole.routed_scaling, whole.n_group,
+                                whole.topk_group)
+    from deepspeed_tpu.inference.hybrid import _swiglu
+    got = _swiglu(xn, p["moe"]["shared"])
+    held_pairs = 0
+    for first in range(16):
+        share = {n: {"kernel": p["moe"]["experts"][n]["kernel"]
+                     [first:first + 1]} for n in ("wg", "wi", "wo")}
+        part, stats = expert_share.held_experts_ffn(
+            xn, share, sel, w, (first, 1), "ragged_dot")
+        got = got + part
+        held_pairs += int(stats[0])
+    assert held_pairs == x.shape[0] * whole.moe_k
+    np.testing.assert_allclose(np.asarray(x + got), np.asarray(want),
+                               atol=1e-4)
+
+
+def test_counters_gauges_and_spans_with_telemetry():
+    cfg = U.tiny_config()
+    params = U.tiny_params(cfg)
+    rng = np.random.default_rng(1)
+    srv, _ = U.serve_logits(cfg, params, [rng.integers(1, 96, 30)], 5,
+                            telemetry=True)
+    got = srv.read_expert_counters()
+    pre, dec = got["prefill"], got["decode"]
+    assert pre["pairs_total"] == 30 * cfg.moe_k * cfg.n_sparse_layers
+    assert dec["pairs_total"] == 4 * cfg.moe_k * cfg.n_sparse_layers
+    assert 0 < pre["pairs_held"] < pre["pairs_total"]
+    snap = srv.metrics.snapshot()
+    text = str(snap)
+    assert "kv_latent_pool_bytes" in text and "kv_latent_row_bytes" in text
+    assert "moe_decode_pairs_held" in text
+    tracer = srv.telemetry.tracer
+    assert [s[5]["history"] for s in tracer.spans("serve.prefill")] == [0, 16]
+    assert [s[5]["kv_tokens"] for s in tracer.spans("serve.decode")
+            if s[5].get("live")] == [31, 32, 33, 34]
+
+
+@pytest.mark.parametrize("kwargs,name", [
+    (dict(prefix_cache=True), "prefix sharing"),
+    (dict(prefix_cache=True, host_tier=True), "prefix sharing"),
+    (dict(kv_quant="int8"), "int8 KV pools"),
+    (dict(spec_decode=True), "speculative decoding"),
+    (dict(decode_horizon=4), "fused decode horizon"),
+    (dict(lora_serve=True), "LoRA serving"),
+])
+def test_unsupported_serving_options_raise_by_name(kwargs, name):
+    import deepspeed_tpu
+    from deepspeed_tpu.inference.serving import ServingEngine
+    cfg = U.tiny_config()
+    eng = deepspeed_tpu.init_inference((cfg, U.tiny_params(cfg)),
+                                       dtype=jnp.float32)
+    with pytest.raises(ValueError, match=name + ".*latent"):
+        ServingEngine(eng, num_slots=2, block_size=4, **kwargs)
+
+
+@pytest.mark.parametrize("call,name", [
+    (lambda e: e.generate(np.ones((1, 4), np.int32), max_new_tokens=2),
+     "static-cache prefill"),
+    (lambda e: e.generate_fused(np.ones((1, 4), np.int32), max_new_tokens=2),
+     "static-cache prefill"),
+    (lambda e: e.forward(np.ones((1, 4), np.int32)), "cacheless forward"),
+])
+def test_static_cache_paths_raise_by_name(call, name):
+    import deepspeed_tpu
+    cfg = U.tiny_config()
+    eng = deepspeed_tpu.init_inference((cfg, U.tiny_params(cfg)),
+                                       dtype=jnp.float32)
+    with pytest.raises(ValueError, match=name + ".*latent"):
+        call(eng)
+
+
+def test_tensor_parallel_raises_by_name():
+    import deepspeed_tpu
+    cfg = U.tiny_config()
+    with pytest.raises(ValueError, match="tensor parallelism.*latent"):
+        deepspeed_tpu.init_inference((cfg, U.tiny_params(cfg)),
+                                     dtype=jnp.float32, mp_size=2)
+
+
+def test_no_recompile_in_steady_state():
+    from deepspeed_tpu.inference.serving import ServeRequest
+    from deepspeed_tpu.utils.compile_guard import CompileWatch
+    cfg = U.tiny_config()
+    params = U.tiny_params(cfg)
+    rng = np.random.default_rng(2)
+    srv, _ = U.serve_logits(cfg, params, [rng.integers(1, 96, 20),
+                                          rng.integers(1, 96, 9)], 3)
+    with CompileWatch(max_compiles=0, label="latent steady state"):
+        for i, n in enumerate((33, 5, 17, 40)):
+            srv.submit(ServeRequest(rid=f"s{i}", max_new_tokens=4,
+                                    prompt=rng.integers(1, 96, n).astype(
+                                        np.int32)))
+        guard = 0
+        while srv.busy:
+            srv.step()
+            guard += 1
+            assert guard < 500
+
+
+def test_kv_accounting_counts_the_latent_row():
+    from deepspeed_tpu.models import gpt
+    from deepspeed_tpu.ops.attention.paged import paged_hbm_bytes_per_token
+    cfg = U.tiny_config()
+    assert gpt.kv_bytes_per_token(cfg, jnp.bfloat16) == 4 * 128 * 2
+    assert gpt.kv_window_bytes_per_slot(cfg, 4, jnp.bfloat16) == 0
+    assert gpt.decode_geometry(cfg, 4) == (24, 96)
+    assert paged_hbm_bytes_per_token(cfg, 2, 10.0, 96) == 20 * 4 * 128 * 2
+    real = dots_vlm.DotsVLMConfig(n_layers=6, n_heads=128, d_model=7168)
+    assert gpt.kv_bytes_per_token(real, jnp.bfloat16) == 6 * 640 * 2
+    assert not latent.is_latent(gpt.GPTConfig())
+    with pytest.raises(AssertionError):
+        U.tiny_config(n_group=3)

@@ -311,6 +311,88 @@ def paged_time_rows():
         yield f"paged decode time {name}", run
 
 
+# the latent-attention cell's decode shape: (slots, heads, row lanes,
+# value lanes, layers, tokens a slot can reach); block = tile sizes tried
+MLA_SHAPE = (16, 128, 640, 512, 6, 24576)
+MLA_BLOCKS = (256, 512, 1024)
+MLA_FILLS = (("short", 2048), ("mean", 8704), ("long", 22016))
+
+
+@functools.lru_cache(maxsize=None)
+def _mla_layers(L, N, bs, nb, vw, scale):
+    """The latent decode program's attention, jitted: layer l's rows at
+    ``tables + l*N``, the grid worked out once, PAGED_REPS sweeps over the
+    layers (:func:`_paged_layers`' shape)."""
+    from deepspeed_tpu.ops.attention import mla
+
+    def layers(q, pool, tables, lengths):
+        plan = P.decode_plan(lengths, nb, bs)
+
+        def layer(q, l):
+            out = mla.mla_decode_attention(
+                q, pool, tables + l * N, lengths, value_width=vw,
+                scale=scale, plan=plan)
+            return q.at[..., :vw].add(out).astype(q.dtype), None
+
+        def sweep(_, q):
+            return jax.lax.scan(layer, q, jnp.arange(L))[0]
+        return jax.lax.fori_loop(0, PAGED_REPS, sweep, q)
+    return jax.jit(layers)
+
+
+@functools.partial(jax.jit, static_argnames=("vw", "scale", "kernel"))
+def _mla_decode_jit(q, pool, tables, lengths, *, vw, scale, kernel):
+    from deepspeed_tpu.ops.attention import mla
+    fn = mla.mla_decode_attention if kernel else mla.mla_decode_reference
+    return fn(q, pool, tables, lengths, value_width=vw, scale=scale)
+
+
+def mla_time_rows():
+    """Microseconds an ``mla_decode`` call (ops/attention/mla.py) at the
+    latent-attention cell's shape, per block size (a grid step attends one
+    block of 256 or more tokens) and fill, timed as the decode program
+    runs it (:func:`_mla_layers`), beside the least the call could take by
+    either side of its roofline. Checked against the plain latent decode
+    at ragged lengths."""
+    r = np.random.default_rng(7)
+    B, H, row, vw, L, cap = MLA_SHAPE
+    scale = 192 ** -0.5
+    for bs in MLA_BLOCKS:
+        def run(bs=bs):
+            nb = cap // bs
+            N = 1 + B * nb
+            pool = _rand(r, (L * N, bs, row))
+            q = _rand(r, (B, H, row))
+            tables = jnp.asarray(
+                1 + np.arange(B * nb).reshape(B, nb), jnp.int32)
+            layers = _mla_layers(L, N, bs, nb, vw, scale)
+            ragged = jnp.asarray(
+                [0, cap - 1, 3, bs, bs - 1] + [1000 + 777 * i
+                                               for i in range(B - 5)],
+                jnp.int32)
+            got = _mla_decode_jit(q, pool[:N], tables, ragged, vw=vw,
+                                  scale=scale, kernel=True)
+            want = _mla_decode_jit(*_f32(q, pool[:N]), tables, ragged,
+                                   vw=vw, scale=scale, kernel=False)
+            row_ = {"block": bs, "fwd_err": _err(got, want)}
+            for fill, tokens in MLA_FILLS:
+                lengths = jnp.full((B,), tokens, jnp.int32)
+                layers(q, pool, tables, lengths).block_until_ready()
+                best = float("inf")
+                for _ in range(3):
+                    t0 = time.perf_counter()
+                    layers(q, pool, tables, lengths).block_until_ready()
+                    best = min(best, time.perf_counter() - t0)
+                us = best / (PAGED_REPS * L) * 1e6
+                rows = B * (tokens + 1)
+                least = max(2.0 * rows * H * (2 * 512 + 64) / 197e12,
+                            rows * 576 * 2 / 819e9) * 1e6
+                row_[f"us_{fill}"] = round(us, 1)
+                row_[f"roofline_{fill}"] = round(100 * least / us, 1)
+            return {**row_, "ok": row_["fwd_err"] < TOL}
+        yield f"mla decode time block {bs}", run
+
+
 # the two serving configurations' dispatch shapes: (configuration, slots,
 # table entries a slot (kexaone: 256 full + the ring's 9), prefill chunk,
 # vocabulary as served)
@@ -476,7 +558,7 @@ def main():
     failed = 0
     with open(OUT, "a") as out:
         for rows in (flash_rows, ring_block_rows, paged_rows,
-                     paged_time_rows, dispatch_operand_rows,
+                     paged_time_rows, mla_time_rows, dispatch_operand_rows,
                      int8_matmul_rows, blocksparse_rows):
             for name, run in rows():
                 if wanted and not any(w in name for w in wanted):
