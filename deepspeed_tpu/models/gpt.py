@@ -748,7 +748,9 @@ def loss_fn(params: Dict, batch: Dict, rng: jax.Array, cfg: GPTConfig,
             f"permuting so every per-token array stays aligned")
     if cfg.loss_chunk:
         # fused vocab-projection + loss: never materializes [B, S, V]
-        # (ops/cross_entropy.py — frees ~3GB+ at GPT-2-1.5B scale)
+        # (ops/cross_entropy.py — frees ~3GB+ at GPT-2-1.5B scale); under
+        # a mesh that splits the batch it scans each shard's own tokens
+        # on a projection gathered once
         from deepspeed_tpu.ops.cross_entropy import chunked_softmax_xent
         x = forward(params, tokens, cfg, rng, deterministic=deterministic,
                     pld_theta=batch.get("pld_theta"), hidden_only=True,
@@ -769,10 +771,15 @@ def loss_fn(params: Dict, batch: Dict, rng: jax.Array, cfg: GPTConfig,
 
 def make_loss_fn(cfg: GPTConfig):
     """Engine-contract loss: (params, batch, rng) -> loss. ``describe``
-    feeds the engine's "engine ready" line."""
+    feeds the engine's "engine ready" line (called under the engine's
+    mesh, which the chunked loss's layout follows)."""
+    from deepspeed_tpu.ops.cross_entropy import loss_layout
+
     def _loss(params, batch, rng):
         return loss_fn(params, batch, rng, cfg)
-    _loss.describe = lambda: {"attention": attention_impl(cfg)}
+    _loss.describe = lambda: {
+        "attention": attention_impl(cfg),
+        "loss": loss_layout(cfg.loss_chunk) if cfg.loss_chunk else "dense"}
     return _loss
 
 

@@ -395,10 +395,13 @@ class DeepSpeedEngine:
 
         n_params = count_parameters(params)
         # what the model says of itself (models/gpt.py make_loss_fn: the
-        # attention implementation its step compiles on this platform)
+        # attention implementation its step compiles on this platform, and
+        # the loss's layout on this mesh)
         describe = getattr(loss_fn, "describe", None)
-        model_says = "".join(f", {k}={v}" for k, v in describe().items()) \
-            if describe is not None else ""
+        with jax.set_mesh(self.mesh):
+            model_says = "".join(
+                f", {k}={v}" for k, v in describe().items()) \
+                if describe is not None else ""
         log_dist(
             f"engine ready: {n_params / 1e6:.2f}M params, zero_stage="
             f"{config.zero.stage}, precision={config.precision_name}, "

@@ -157,6 +157,122 @@ def test_bert_mlm_loss_chunked_parity():
 
 
 # ---------------------------------------------------------------------------
+# under a mesh that splits the batch: each shard scans its own tokens on a
+# projection gathered once, and nothing changes in value
+# ---------------------------------------------------------------------------
+
+def _mesh(**axes):
+    from deepspeed_tpu.parallel import mesh as mesh_lib
+    spec = mesh_lib.MeshSpec(**{"data": 1, **axes})
+    n = spec.pipe * spec.data * spec.fsdp * spec.sequence * spec.model
+    return mesh_lib.make_mesh(spec, jax.devices()[:n])
+
+
+def _loss_case(case, V=257):
+    """(loss(x, *weights, t, mask), [x, *weights, t, mask], the weights'
+    PartitionSpecs) for one head layout. 8 sequences: 2 a shard on four
+    shards."""
+    from jax.sharding import PartitionSpec as P
+    r = np.random.default_rng(11)
+    B, H = 8, 32
+    S = 13 if case == "ragged_chunk" else 16    # 26 tokens a shard pad to 32
+    x = jnp.asarray(r.normal(size=(B, S, H)), jnp.float32)
+    t = jnp.asarray(r.integers(0, V, (B, S)), jnp.int32)
+    keep = np.ones((B, S), np.float32)
+    if case == "uneven_mask":
+        # 1, 3, 20 and 32 counted tokens on the four shards: a mean of
+        # per-shard means would be far off the global masked mean
+        keep[:] = 0.0
+        for row, n in enumerate((1, 0, 3, 0, 16, 4, 16, 16)):
+            keep[row, :n] = 1.0
+    mask = jnp.asarray(keep)
+    if case == "untied_bias":
+        kernel = jnp.asarray(r.normal(size=(H, V)), jnp.float32) * 0.1
+        bias = jnp.asarray(r.normal(size=(V,)), jnp.float32) * 0.1
+        return (lambda x, k, b, t, m: chunked_softmax_xent(
+            x, k.T, t, bias=b, chunk=8, loss_mask=m),
+            [x, kernel, bias, t, mask], [P("fsdp", None), P()])
+    w = jnp.asarray(r.normal(size=(V, H)), jnp.float32) * 0.1
+    spec = P("model", "fsdp") if case == "vocab_parallel" else P(None, "fsdp")
+    return (lambda x, w, t, m: chunked_softmax_xent(
+        x, w, t, chunk=8, loss_mask=m), [x, w, t, mask], [spec])
+
+
+def _on_mesh(mesh, args, weight_specs):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    batch = P(("data", "fsdp"))
+    specs = [batch] + list(weight_specs) + [batch, batch]
+    return [jax.device_put(a, NamedSharding(mesh, s))
+            for a, s in zip(args, specs)]
+
+
+def _assert_same(got, want):
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("case", ["tied", "untied_bias", "uneven_mask",
+                                  "ragged_chunk"])
+@pytest.mark.parametrize("axes", [{"fsdp": 4}, {"data": 2, "fsdp": 2}],
+                         ids=["fsdp4", "data2xfsdp2"])
+def test_sharded_loss_matches_single_device(axes, case, devices):
+    from deepspeed_tpu.ops.cross_entropy import loss_layout
+    loss, args, specs = _loss_case(case)
+    grad = jax.value_and_grad(loss, tuple(range(1 + len(specs))))
+    want = grad(*args)
+    mesh = _mesh(**axes)
+    with jax.set_mesh(mesh):
+        assert "projection gathered" in loss_layout(8)
+        got = jax.jit(grad)(*_on_mesh(mesh, args, specs))
+    _assert_same(got, want)
+
+
+def test_sharded_loss_leaves_the_vocabulary_parallel_axis_to_xla(devices):
+    """'model' cuts the vocabulary (``megatron_rules``) and is not a batch
+    axis: the map takes 'fsdp' only and the class statistics are still
+    reduced over 'model' by the partitioner."""
+    from deepspeed_tpu.ops.cross_entropy import loss_layout
+    loss, args, specs = _loss_case("vocab_parallel", V=256)
+    grad = jax.value_and_grad(loss, (0, 1))
+    want = grad(*args)
+    mesh = _mesh(fsdp=2, model=2)
+    with jax.set_mesh(mesh):
+        assert loss_layout(8) == \
+            "chunked(8)/shard over fsdp=2, projection gathered"
+        got = jax.jit(grad)(*_on_mesh(mesh, args, specs))
+    _assert_same(got, want)
+
+
+def test_sharded_loss_inside_a_manual_data_map(devices):
+    """The compressed-collective path maps the loss over 'data' by hand
+    (runtime/engine.py compressed_grads): inside it the loss's own map
+    takes only 'fsdp', and a replica's loss is its own tokens' mean."""
+    from jax.sharding import PartitionSpec as P
+    from deepspeed_tpu.ops import cross_entropy
+    loss, args, specs = _loss_case("tied")
+    want = jax.value_and_grad(loss, (0, 1))(*args)
+    mesh = _mesh(data=2, fsdp=2)
+    took = []
+
+    def replica(x, w, t, m):
+        took.append(cross_entropy._token_axes(x.shape[0]))
+        val, (dx, dw) = jax.value_and_grad(loss, (0, 1))(x, w, t, m)
+        # a replica holds half the tokens: the global mean is the mean of
+        # the replicas', and dx is a share of the global loss's
+        return (jax.lax.pmean(val, "data"),
+                (dx / 2, jax.lax.pmean(dw, "data")))
+
+    with jax.set_mesh(mesh):
+        got = jax.jit(jax.shard_map(
+            replica, in_specs=(P("data"), P(), P("data"), P("data")),
+            out_specs=(P(), (P("data"), P())), axis_names={"data"},
+            check_vma=False))(*_on_mesh(mesh, args, specs))
+    assert took == [("fsdp",)]
+    _assert_same(got, want)
+
+
+# ---------------------------------------------------------------------------
 # property-based chunked-CE invariants (hypothesis)
 # ---------------------------------------------------------------------------
 
