@@ -22,6 +22,7 @@ KV-cache management via the global Context workspace). TPU-native design:
 
 import dataclasses
 import functools
+import inspect
 from dataclasses import dataclass
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
@@ -31,9 +32,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from deepspeed_tpu.inference import hybrid, latent, paged_cache, sampling
+from deepspeed_tpu.inference import (cca, hybrid, latent, paged_cache,
+                                     sampling)
 from deepspeed_tpu.inference.hybrid import _heads, _rows, causal_band
 from deepspeed_tpu.models import gpt as gpt_lib
+from deepspeed_tpu.moe import expert_share
 from deepspeed_tpu.ops import quantizer
 from deepspeed_tpu.ops.attention.paged import (blocks_per_step,
                                                decode_plan,
@@ -307,10 +310,14 @@ def _packed(fn, name: str):
     has) behind the packed operand of :func:`pack_operands`, under the
     module name ``jit_<name>``: a shell of static slices and bitcasts
     inside the SAME program. ``seen`` is the sampler's resident [B, V]
-    mask (None for a program that does not sample)."""
+    mask (None for a program that does not sample). A program that keeps
+    per-slot state beside the pools (``fn`` has a ``slot`` parameter) is
+    also handed the ``row`` section's slot index itself."""
+    wants_slot = "slot" in inspect.signature(fn).parameters
+
     def call(params, k_pool, v_pool, packed, layout, seen=None, scales=None,
              lora=None):
-        operands, at = [], 0
+        operands, at, extra = [], 0, {}
         for kind, what in layout:
             if kind == "static":
                 operands.append(what)
@@ -326,6 +333,8 @@ def _packed(fn, name: str):
             elif kind == "row":
                 operands.append(jax.lax.dynamic_index_in_dim(
                     seen, words, keepdims=False))
+                if wants_slot:
+                    extra["slot"] = words
             elif kind == "b":
                 operands.append(words != 0)
             elif kind == "i":
@@ -334,7 +343,7 @@ def _packed(fn, name: str):
                 operands.append(jax.lax.bitcast_convert_type(
                     words, _WORDS[kind]))
         return fn(params, k_pool, v_pool, *operands, scales=scales,
-                  lora=lora)
+                  lora=lora, **extra)
     return _named(call, name)
 
 
@@ -918,14 +927,14 @@ class InferenceEngine:
             self._gather_blocks = jax.jit(partial(paged_cache.gather_blocks))
             self._scatter_block = jax.jit(
                 partial(paged_cache.scatter_block), donate_argnums=(0,))
-        if hybrid.is_hybrid(config) or latent.is_latent(config):
-            # two kinds of attention state, or a latent pool: only the
-            # two paged serving programs know them. Everything else
-            # raises by name rather than grow a copy of the dialect
-            # (ROADMAP D4)
+        if hybrid.is_hybrid(config) or latent.is_latent(config) \
+                or cca.is_cca(config):
+            # two kinds of attention state, a latent pool, or per-slot
+            # tails beside the pools: only the two paged serving programs
+            # know them. Everything else raises by name rather than grow
+            # a copy of the dialect (ROADMAP D4)
             def refuse(what, *a, **k):
-                hybrid.refuse(config, what)
-                latent.refuse(config, what)
+                paged_cache.refuse(config, what)
             if mp_size > 1:
                 refuse("tensor parallelism (mp_size > 1)")
             for attr, what in (
@@ -1042,7 +1051,8 @@ class InferenceEngine:
 
     def _prefill_slot_fn(self, params, k_pool, v_pool, table_row, tokens,
                          start, n_valid, key, gen_count, temp, top_k,
-                         top_p, rep_pen, seen_row, scales=None, lora=None):
+                         top_p, rep_pen, seen_row, scales=None, lora=None,
+                         slot=None):
         """Prefill ONE prompt chunk into one serving slot's paged cache.
 
         tokens: [C] fixed-width chunk (padded; n_valid real tokens);
@@ -1060,7 +1070,10 @@ class InferenceEngine:
         _block_prefill_paged. ``lora``: None, or (a_pool, b_pool, the
         slot's adapter-table row [NBa]) (inference/adapters.py); an
         all-zeros row gathers the trash block — the base-only prefill
-        bit-for-bit. Returns the last-valid-position logits, the
+        bit-for-bit. ``slot``: the slot's index, which only a model with
+        per-slot state beside the pools reads (inference/cca.py; the
+        packed operand carries it anyway, for the sampler's row). Returns
+        the last-valid-position logits, the
         sampled/greedy token [1], its logprob [1], and the updated
         (donated) pools, then scales."""
         cfg = self.cfg
@@ -1086,6 +1099,12 @@ class InferenceEngine:
                     carry, flat, table_row, positions, n_valid, layer_p,
                     cfg, base, self.decode_impl, experts)
             x, pools = self._latent_layers(params, pools, lblock, x, 0)
+        elif cca.is_cca(cfg):
+            def cblock(carry, flat, layer_p, base, lora, experts):
+                return cca.block_prefill(
+                    carry, flat, table_row, positions, n_valid, slot,
+                    layer_p, cfg, base, self.decode_impl, experts)
+            x, pools = self._cca_layers(params, pools, cblock, x, 0)
         else:
             def block(x, pools, layer_p, base, lora):
                 return _block_prefill_paged(x, pools, table_row, positions,
@@ -1146,6 +1165,15 @@ class InferenceEngine:
                     carry, flat, tables, lengths, active, layer_p, cfg,
                     base, impl, experts, plan)
             x, pools = self._latent_layers(params, pools, lblock, x, 1)
+        elif cca.is_cca(cfg):
+            plan = decode_plan(lengths, tables.shape[1],
+                               pools[0].rows.shape[2])
+
+            def cblock(carry, flat, layer_p, base, lora, experts):
+                return cca.block_decode(
+                    carry, flat, tables, lengths, active, layer_p, cfg,
+                    base, impl, experts, plan)
+            x, pools = self._cca_layers(params, pools, cblock, x, 1)
         else:
             plan = _paged_plan(pools, tables, lengths, cfg)
 
@@ -1188,6 +1216,18 @@ class InferenceEngine:
             state.stats, phase)
         return x, (latent.LatentState(rows, stats, route), none)
 
+    def _cca_layers(self, params, pools, block, x, phase: int):
+        """The same for a model with convolutional attention (inference/
+        cca.py): ``pools`` = (CCAState, the V pool), the per-slot tails in
+        the carry beside the two pools; no leading dense layers."""
+        from deepspeed_tpu.models.zaya import layer_bases
+        state, v = pools
+        x, (k, v, tail, vtail), stats, route = self._dense_then_sparse(
+            params, (state.rows, v, state.tail, state.vtail),
+            layer_bases(self.cfg, state.rows.shape[1], state.tail.shape[1]),
+            block, x, state.stats, phase)
+        return x, (cca.CCAState(k, tail, vtail, stats, route), v)
+
     def _dense_then_sparse(self, params, flat, bases, block, x, stats,
                            phase: int):
         """The leading dense layers and then the sparse layers, each ONE
@@ -1195,8 +1235,11 @@ class InferenceEngine:
         layers) in the carry and ``bases`` = (dense, sparse) per-layer
         offsets into them. Beside ``x`` the block carries the dispatch's
         routing record and, with ``stats`` (telemetry on), the expert
-        layers' counters, added to row ``phase`` of it. Returns (x, flat,
-        stats, route)."""
+        layers' counters, added to row ``phase`` of it; where the router
+        has a state (moe/expert_share.py ``route_mlp``) that rides there
+        too, ``r`` ``[T, R]`` float32, zeros under the first layer. A
+        model with no leading dense layer runs the one scan. Returns (x,
+        flat, stats, route)."""
         cfg = self.cfg
         params, experts = hybrid.split_experts(params)
         block = functools.partial(block, experts=experts)
@@ -1204,8 +1247,13 @@ class InferenceEngine:
         aux = {"route": jnp.zeros((cfg.n_sparse_layers, x.shape[0] * x.shape[1],
                                    cfg.moe_k), jnp.int32),
                "stats": None if stats is None else jnp.zeros_like(stats[0])}
-        carry, flat = _scan_layers(block, (x, aux), params, flat,
-                                   stack="dense_block", bases=dense_b)
+        if expert_share.has_router_state(cfg):
+            aux["r"] = jnp.zeros((x.shape[0] * x.shape[1],
+                                  cfg.router_hidden), jnp.float32)
+        carry = (x, aux)
+        if cfg.n_dense_layers:
+            carry, flat = _scan_layers(block, carry, params, flat,
+                                       stack="dense_block", bases=dense_b)
         (x, aux), flat = _scan_layers(block, carry, params, flat,
                                       bases=sparse_b)
         if stats is not None:
@@ -1433,7 +1481,8 @@ class InferenceEngine:
         if scales is not None:
             from deepspeed_tpu.utils.faults import maybe_fire
             maybe_fire("cache.quantize")
-        if isinstance(k_pool, (hybrid.PagedState, latent.LatentState)):
+        if isinstance(k_pool, (hybrid.PagedState, latent.LatentState,
+                               cca.CCAState)):
             k_pool = k_pool._replace(route=None)    # an output only
         if lora is not None:
             parts = (*parts, ("lora", lora[2]))
@@ -1481,6 +1530,11 @@ class InferenceEngine:
                           lora=None):
         from deepspeed_tpu.utils.faults import maybe_fire
         maybe_fire("engine.prefill")
+        if sample_state is None and cca.is_cca(self.cfg):
+            # the program finds the slot's tail by the lane's slot index
+            raise ValueError("a prefill for a model with convolutional "
+                             "(CCA) attention needs the slot's sampling "
+                             "lane (sample_state): it names the slot")
         lanes, seen = self._samp_lanes(sample_state, 1, self.cfg.vocab_size,
                                        scalar=True)
         out = self._run(

@@ -122,7 +122,7 @@ def _ffn(x2, p, cfg, impl, valid, aux, index, experts):
     if "moe" not in p:
         with jax.named_scope("mlp"):
             return x2 + _swiglu(h, p), aux
-    y, sel, stats = expert_share.sparse_ffn(
+    y, sel, stats, _ = expert_share.sparse_ffn(
         h, p["moe"], cfg, "gmm" if impl == "pallas" else "ragged_dot",
         valid=valid, mlp=_swiglu, experts=experts, layer=index)
     aux = dict(aux, route=aux["route"].at[index].set(sel))

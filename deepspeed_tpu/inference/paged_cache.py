@@ -81,7 +81,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.utils.env import resolve_flag
-from deepspeed_tpu.inference import hybrid, latent
+from deepspeed_tpu.inference import cca, hybrid, latent
 from deepspeed_tpu.inference.host_tier import (
     HostBlockPool, HostCorruption, resolve_host_tier)
 from deepspeed_tpu.inference.prefix_index import PrefixIndex, PrefixMatch
@@ -112,9 +112,19 @@ def resolve_prefix_cache(flag: Optional[bool] = None) -> bool:
 # serving engine wires its model engine's own jits of these functions in
 # (``copy_fn`` / ``gather_fn`` / ``scatter_fn``); a standalone cache runs
 # the module-level ones.
+def refuse(cfg, what: str):
+    """Raise by name for a serving feature that a model whose cache state
+    is more than K and V blocks cannot yet live with: bounded window state
+    (inference/hybrid.py), a latent pool (latent.py), per-slot tails
+    (cca.py). The one list of those dialects."""
+    for dialect in (hybrid, latent, cca):
+        dialect.refuse(cfg, what)
+
+
 def paged_pool(k):
     """The pool behind the block tables in a K-side state: the array
-    itself, a two-kind state's full layers' pool, a latent state's rows."""
+    itself, a two-kind state's full layers' pool, a latent state's rows, a
+    CCA state's K rows."""
     return getattr(k, "full", getattr(k, "rows", k))
 
 
@@ -225,20 +235,23 @@ class PagedKVCache:
         # a model with sliding-window layers pages only its full layers
         L = getattr(cfg, "n_full_layers", cfg.n_layers)
         Hkv, Dh = cfg.kv_heads, cfg.head_dim
-        # what cannot yet live with bounded window state or with a
-        # latent pool raises here, by name
+        # what cannot yet live with bounded window state, with a latent
+        # pool or with per-slot tails raises here, by name
         for on, what in ((prefix_cache, "prefix sharing (prefix_cache)"),
                          (self.quantized, "int8 KV pools (kv_quant)"),
                          (resolve_host_tier(host_tier) and prefix_cache,
                           "the host tier (host_tier)")):
             if on:
-                hybrid.refuse(cfg, what)
-                latent.refuse(cfg, what)
+                refuse(cfg, what)
         self.ring_blocks = 0
         if hybrid.is_hybrid(cfg):
             from deepspeed_tpu.models.exaone_moe import window_blocks
             self.ring_blocks = window_blocks(cfg, self.block_size)
         self.latent = latent.is_latent(cfg)
+        # per-slot tails beside the pools (inference/cca.py): held whole
+        # from construction on, like the window rings
+        self.cca_tail_bytes = self.num_slots \
+            * gpt_lib.kv_cca_tail_bytes_per_slot(cfg, self.dtype)
         self.pool_dtype = jnp.dtype(jnp.int8) if self.quantized \
             else self.dtype
         self.bytes_per_token = gpt_lib.kv_bytes_per_token(
@@ -280,6 +293,12 @@ class PagedKVCache:
                 (L, self.num_blocks, self.block_size, cfg.latent_lanes),
                 self.pool_dtype))
             self.v = None
+        elif cca.is_cca(cfg):
+            # a fourth: K and V pools as below, and every slot's tail of
+            # the previous token, zero until a sequence leaves one
+            self.k, self.v = cca.new_state(
+                cfg, self.num_blocks, self.block_size, self.num_slots,
+                self.pool_dtype)
         else:
             self.k = jnp.zeros(
                 (L, self.num_blocks, self.block_size, Hkv * Dh),
@@ -1098,7 +1117,8 @@ class PagedKVCache:
         takes and every serving program hands back: ``(k, v)``, with
         int8 pools ``(k, v, k_scale, v_scale)``; for a model of two
         attention kinds k and v are hybrid.PagedState, for one with
-        latent attention ``(latent.LatentState, None)``."""
+        latent attention ``(latent.LatentState, None)``, for one with
+        convolutional attention ``(cca.CCAState, v)``."""
         return (self.k, self.v) + (self.scales or ())
 
     @pools.setter

@@ -144,7 +144,7 @@ import numpy as np
 from deepspeed_tpu.inference import sampling
 from deepspeed_tpu.inference.adapters import (AdapterLoadError, AdapterPool,
                                               resolve_lora_serve)
-from deepspeed_tpu.inference import hybrid, latent
+from deepspeed_tpu.inference import paged_cache
 from deepspeed_tpu.inference.host_tier import resolve_host_tier
 from deepspeed_tpu.inference.paged_cache import (CacheExhausted,
                                                  PagedKVCache,
@@ -588,8 +588,7 @@ class ServingEngine:
                  "the fused decode horizon (decode_horizon)"),
                 (self.lora_serve, "LoRA serving (lora_serve)")):
             if on:
-                hybrid.refuse(engine.cfg, what)
-                latent.refuse(engine.cfg, what)
+                paged_cache.refuse(engine.cfg, what)
         # the engine's own jits of the three block copies (COW, and the
         # host tier's gather and scatter) are wired in when present:
         # each takes the cache's pools whole, scales included
@@ -613,9 +612,10 @@ class ServingEngine:
         if hasattr(self.cache.k, "stats") and self.telemetry.enabled:
             # expert-layer counters ride with the K state, on the device
             # (read_expert_counters pulls them)
-            from deepspeed_tpu.moe.expert_share import STAT_FIELDS
+            from deepspeed_tpu.moe.expert_share import stat_fields
             self.cache.k = self.cache.k._replace(
-                stats=jnp.zeros((2, len(STAT_FIELDS)), jnp.int32))
+                stats=jnp.zeros((2, len(stat_fields(engine.cfg))),
+                                jnp.int32))
         mesh = getattr(engine, "mesh", None)
         if mesh is not None:
             # place the fresh pools exactly where the jitted programs
@@ -830,6 +830,15 @@ class ServingEngine:
                           "as computed: the latent and the shared rotated "
                           "key, without the padding").set(
                     engine.cfg.latent_row * self.cache.pool_dtype.itemsize)
+            if self.cache.cca_tail_bytes:
+                # per-slot state that is not blocks (inference/cca.py)
+                reg.gauge("kv_cca_tail_bytes",
+                          "device bytes of the per-slot tails of "
+                          "convolutional (CCA) attention: per layer and "
+                          "slot the previous token's compressed row, its "
+                          "first convolution's output and its half of the "
+                          "next value, whatever the slot's length").set(
+                    self.cache.cca_tail_bytes)
             self._h_kv_err = reg.histogram(
                 "serving_kv_quant_error",
                 "sampled upper bound on the max-abs KV dequantization "
@@ -1467,7 +1476,8 @@ class ServingEngine:
             n = min(self.prefill_chunk, len(req._work) - done)
             with self.telemetry.tracer.span(
                     "serve.prefill", rid=req.rid, step=self._step_clock,
-                    slot=slot, start=done, n=n, history=done):
+                    slot=slot, start=done, n=n, history=done,
+                    tail=int(done > 0 and self.cache.cca_tail_bytes > 0)):
                 self._prefill_slot_chunk(slot, req, done, n, now)
 
     def _prefill_slot_chunk(self, slot: int, req: ServeRequest,
@@ -2087,16 +2097,17 @@ class ServingEngine:
         accumulate there, one add per dispatch, and cost no transfer
         until read) into the registry's ``moe_*`` gauges. Returns
         ``{"prefill": {...}, "decode": {...}}`` by moe/expert_share
-        STAT_FIELDS, or {} when the model has no such layers or telemetry
+        ``stat_fields``, or {} when the model has no such layers or telemetry
         is off."""
         stats = getattr(self.cache.k, "stats", None)
         if stats is None:
             return {}
-        from deepspeed_tpu.moe.expert_share import STAT_FIELDS
+        from deepspeed_tpu.moe.expert_share import stat_fields
         rows = np.asarray(jax.device_get(stats), np.int64)  # dslint: disable=DS001 — pulled on demand, never per dispatch
         out = {}
         for phase, row in zip(("prefill", "decode"), rows):
-            vals = dict(zip(STAT_FIELDS, (int(v) for v in row)))
+            vals = dict(zip(stat_fields(self.engine.cfg),
+                            (int(v) for v in row)))
             out[phase] = vals
             calls = max(vals["layer_calls"], 1)
             for name, value in (
@@ -2107,7 +2118,11 @@ class ServingEngine:
                     ("expert_pairs_mean", vals["pairs_held"] / calls
                      / max(self.engine.cfg.held[1], 1)),
                     ("experts_touched_mean",
-                     vals["experts_touched"] / calls)):
+                     vals["experts_touched"] / calls),
+                    # only a router with a skip output counts these
+                    ("pairs_skipped", vals.get("pairs_skipped"))):
+                if value is None:
+                    continue
                 self.metrics.gauge(
                     f"moe_{phase}_{name}",
                     f"expert-share layers, {phase} dispatches: {name} "

@@ -1016,6 +1016,16 @@ def kv_window_bytes_per_slot(cfg: GPTConfig, block_size: int,
                * cfg.kv_heads * cfg.head_dim * jnp.dtype(dtype).itemsize)
 
 
+def kv_cca_tail_bytes_per_slot(cfg: GPTConfig, dtype=jnp.bfloat16) -> int:
+    """Bytes one serving slot holds beside its blocks where keys and
+    values are made by convolutions over time (models/zaya.py,
+    inference/cca.py): per layer the previous token's compressed row, its
+    first convolution's output and its half of the next value, whatever
+    the slot's length (0 for a model with no such attention)."""
+    return int(cfg.n_layers * getattr(cfg, "cca_tail_values", 0)
+               * jnp.dtype(dtype).itemsize)
+
+
 def decode_geometry(cfg: GPTConfig, block_size: int,
                     max_seq_len: Optional[int] = None) -> Tuple[int, int]:
     """(blocks_per_slot, tokens_per_slot) for a block-paged KV cache over

@@ -6,7 +6,10 @@ of the published ``num_experts``). It routes every token over ALL experts
 best groups where the config has a group limit), weights renormalised over
 the k and scaled), computes only the (token, expert)
 pairs that fall on the experts held here, and adds the shared expert, which
-every chip computes alike. What the absent experts would add is left out:
+every chip computes alike. The router is data of the config: with
+``router_hidden`` it is an MLP with a state carried from layer to layer, a
+top-1 choice over the experts and a skip, and no shared expert
+(``route_mlp``; models/zaya.py). What the absent experts would add is left out:
 on one chip the layer runs without its exchange, and nothing stands in for
 the other chips or their traffic (docs/EXPERT_SHARE.md).
 
@@ -29,6 +32,19 @@ import jax.numpy as jnp
 GMM_TILING = (128, 1024, 1024)
 STAT_FIELDS = ("pairs_held", "pairs_total", "busiest_expert_pairs",
                "experts_touched", "layer_calls")
+
+
+def has_router_state(cfg) -> bool:
+    """Whether the config's router is the MLP that carries a state from
+    layer to layer and has a skip output (:func:`route_mlp`)."""
+    return bool(getattr(cfg, "router_hidden", 0))
+
+
+def stat_fields(cfg) -> Tuple[str, ...]:
+    """The counters a config's expert layers keep: ``STAT_FIELDS`` and,
+    where the router can choose no expert, ``pairs_skipped``."""
+    return STAT_FIELDS + (("pairs_skipped",) if has_router_state(cfg)
+                          else ())
 
 
 def route(h, router: Dict, k: int, scaling: float, n_group: int = 1,
@@ -55,6 +71,44 @@ def route(h, router: Dict, k: int, scaling: float, n_group: int = 1,
     w = jnp.take_along_axis(scores, sel, axis=-1)
     w = w / jnp.sum(w, axis=-1, keepdims=True) * scaling
     return sel.astype(jnp.int32), w
+
+
+def _rms(x, scale, eps):
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) \
+        * scale.astype(jnp.float32)
+
+
+def route_mlp(h, router: Dict, r_prev, eps: float):
+    """The router that is an MLP with a state (ZAYA1). h ``[T, d]``,
+    ``r_prev`` ``[T, R]`` float32 (the layer below's state; zeros under the
+    first layer) -> (selected ``[T, 1]`` int32, its weight ``[T, 1]``
+    float32, this layer's state ``[T, R]`` for the layer above).
+
+    ``r = h W_d + b_d + mix * r_prev`` is the state handed on; the logits
+    are a three-matrix gelu MLP of RMSNorm(r), with ONE output more than
+    there are experts: index ``E`` is the skip, a pair on no expert. The
+    choice is the argmax of softmax + the selection bias, and the weight is
+    the chosen probability itself, unscaled. float32 at full matmul
+    precision throughout, as :func:`route`."""
+    hi = jax.lax.Precision.HIGHEST
+    f32 = jnp.float32
+
+    def lin(x, p):
+        y = jnp.dot(x, p["kernel"].astype(f32), precision=hi)
+        return y + p["bias"].astype(f32) if "bias" in p else y
+
+    with jax.named_scope("router_down"):
+        r = lin(h.astype(f32), router["down"])
+    with jax.named_scope("router_mix"):
+        r = r + router["mix"].astype(f32) * r_prev
+    with jax.named_scope("router_mlp"):
+        z = _rms(r, router["norm"]["scale"], eps)
+        z = jax.nn.gelu(lin(z, router["w1"]), approximate=False)
+        z = jax.nn.gelu(lin(z, router["w2"]), approximate=False)
+        probs = jax.nn.softmax(lin(z, router["w3"]), axis=-1)
+        sel = jnp.argmax(probs + router["bias"].astype(f32), axis=-1)
+        w = jnp.take_along_axis(probs, sel[:, None], axis=-1)
+    return sel[:, None].astype(jnp.int32), w, r
 
 
 def _grouped(x, w, sizes, impl: str):
@@ -120,21 +174,38 @@ def held_experts_ffn(h, experts: Dict, sel, w, held: Tuple[int, int],
 
 
 def sparse_ffn(h, moe: Dict, cfg, impl: str, valid=None, mlp=None,
-               experts=None, layer=None):
+               experts=None, layer=None, state=None):
     """Router, the held experts' routed part and the shared expert for
     ``h`` ``[T, d]``. ``mlp(h, p) -> [T, d]`` is the dense SwiGLU the
     engine uses; ``experts`` / ``layer``: every sparse layer's expert
     kernels and this layer's index (:func:`held_experts_ffn`), else
-    ``moe["experts"]``. Returns (routed + shared, selection ``[T, k]``,
-    stats)."""
+    ``moe["experts"]``. Which router runs is the config's: the linear
+    sigmoid one (:func:`route`), or, with ``router_hidden``, the MLP that
+    takes the layer below's ``state`` and hands its own on
+    (:func:`route_mlp`); a selection past the last expert (its skip) is a
+    pair on no expert, sorted last with the absent ones, and is counted.
+    No shared expert where the config has none. Returns (routed + shared,
+    selection ``[T, k]``, stats as :func:`stat_fields`, state)."""
+    stateful = has_router_state(cfg)
     with jax.named_scope("moe_router"):
-        sel, w = route(h, moe["router"], cfg.moe_k, cfg.routed_scaling,
-                       getattr(cfg, "n_group", 1),
-                       getattr(cfg, "topk_group", 1))
+        if stateful:
+            sel, w, state = route_mlp(h, moe["router"], state, cfg.norm_eps)
+        else:
+            sel, w = route(h, moe["router"], cfg.moe_k, cfg.routed_scaling,
+                           getattr(cfg, "n_group", 1),
+                           getattr(cfg, "topk_group", 1))
     with jax.named_scope("moe_experts"):
         routed, stats = held_experts_ffn(
             h, moe["experts"] if experts is None else experts, sel, w,
             cfg.held, impl, valid, layer)
+        if stateful:
+            skipped = sel >= cfg.num_experts
+            if valid is not None:
+                skipped = jnp.logical_and(skipped, valid[:, None])
+            stats = jnp.concatenate(
+                [stats, jnp.sum(skipped, dtype=jnp.int32)[None]])
+    if not cfg.n_shared_experts:
+        return routed, sel, stats, state
     with jax.named_scope("moe_shared"):
         shared = mlp(h, moe["shared"])
-    return routed + shared, sel, stats
+    return routed + shared, sel, stats, state

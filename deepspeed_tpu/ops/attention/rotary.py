@@ -75,6 +75,19 @@ def apply_rotary_half(x: jnp.ndarray, positions: jnp.ndarray,
             ).astype(x.dtype)
 
 
+def apply_rotary_half_partial(x: jnp.ndarray, positions: jnp.ndarray,
+                              rotary_dim: int,
+                              base: float = 10000.0) -> jnp.ndarray:
+    """Rotate-half on the FIRST ``rotary_dim`` channels of each head
+    (channel i pairs with channel i + rotary_dim/2; ``partial_rotary_factor``
+    = rotary_dim / D), the rest passing through. x ``[..., T, H, D]``."""
+    if rotary_dim == x.shape[-1]:
+        return apply_rotary_half(x, positions, base)
+    return jnp.concatenate(
+        [apply_rotary_half(x[..., :rotary_dim], positions, base),
+         x[..., rotary_dim:]], axis=-1)
+
+
 def yarn_inv_freq(rotary_dim: int, base: float, factor: float,
                   original_max: int, beta_fast: float = 32.0,
                   beta_slow: float = 1.0) -> np.ndarray:
