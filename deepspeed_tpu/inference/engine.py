@@ -399,13 +399,15 @@ def _scan_layers(block, x, params, pools, lora_ops=None, stack="block",
     return x, tuple(f.reshape(p.shape) for f, p in zip(flat, pools))
 
 
-def _paged_plan(pools, tables, lengths, cfg, q_len: int = 1):
+def _paged_plan(pools, tables, lengths, active, cfg, q_len: int = 1):
     """The paged kernel's grid for these lengths (ops/attention/paged.py
     ``decode_plan``), worked out ONCE a dispatch, outside the layer loop,
-    for every layer's call. A few integer operations that the compiler
-    drops from a program on the gather path."""
+    for every layer's call, from the slots that are ``active``: the
+    tiles of a slot with no request, or of one still in prefill, are not
+    in it. A few integer operations that the compiler drops from a
+    program on the gather path."""
     return decode_plan(lengths, tables.shape[1], pools[0].shape[2],
-                       window=cfg.attn_window, q_len=q_len)
+                       window=cfg.attn_window, q_len=q_len, active=active)
 
 
 def _block_decode_paged(x, pools, tables, lengths, active, p,
@@ -1149,7 +1151,7 @@ class InferenceEngine:
                 x = x + params["wpe"]["embedding"][safe][:, None]
         if hybrid.is_hybrid(cfg):
             plans = hybrid.decode_plans(cfg, pools[0].full.shape[2], tables,
-                                        lengths)
+                                        lengths, active)
 
             def hblock(carry, flat, layer_p, base, lora, experts):
                 return hybrid.block_decode(
@@ -1158,7 +1160,7 @@ class InferenceEngine:
             x, pools = self._hybrid_layers(params, pools, hblock, x, 1)
         elif latent.is_latent(cfg):
             plan = decode_plan(lengths, tables.shape[1],
-                               pools[0].rows.shape[2])
+                               pools[0].rows.shape[2], active=active)
 
             def lblock(carry, flat, layer_p, base, lora, experts):
                 return latent.block_decode(
@@ -1167,7 +1169,7 @@ class InferenceEngine:
             x, pools = self._latent_layers(params, pools, lblock, x, 1)
         elif cca.is_cca(cfg):
             plan = decode_plan(lengths, tables.shape[1],
-                               pools[0].rows.shape[2])
+                               pools[0].rows.shape[2], active=active)
 
             def cblock(carry, flat, layer_p, base, lora, experts):
                 return cca.block_decode(
@@ -1175,7 +1177,7 @@ class InferenceEngine:
                     base, impl, experts, plan)
             x, pools = self._cca_layers(params, pools, cblock, x, 1)
         else:
-            plan = _paged_plan(pools, tables, lengths, cfg)
+            plan = _paged_plan(pools, tables, lengths, active, cfg)
 
             def block(x, pools, layer_p, base, lora):
                 return _block_decode_paged(x, pools, tables, lengths, active,
@@ -1283,7 +1285,7 @@ class InferenceEngine:
             safe = jnp.clip(pos, 0, self.max_seq_len - 1)
             x = x + params["wpe"]["embedding"][safe]
 
-        plan = _paged_plan(pools, tables, lengths, cfg, q_len=G)
+        plan = _paged_plan(pools, tables, lengths, active, cfg, q_len=G)
 
         def block(x, pools, layer_p, base, lora):
             return _block_verify_paged(x, pools, tables, lengths, active,
@@ -1363,7 +1365,7 @@ class InferenceEngine:
                 safe = jnp.clip(lens, 0, self.max_seq_len - 1)
                 x = x + params["wpe"]["embedding"][safe][:, None]
 
-            plan = _paged_plan(pools, tables, lens, cfg)
+            plan = _paged_plan(pools, tables, lens, lane_active, cfg)
 
             def block(x, pools, layer_p, base, lora):
                 return _block_decode_paged(x, pools, tables, lens,

@@ -241,16 +241,16 @@ def _ring_span(cfg, bs: int, lengths):
     return lo, lengths - lo * bs
 
 
-def decode_plans(cfg, bs: int, tables, lengths):
+def decode_plans(cfg, bs: int, tables, lengths, active):
     """The paged kernel's two grids of one decode dispatch (ops/attention/
     paged.py ``decode_plan``), worked out once outside the layer loop: the
     full layers' over the table, the window layers' over the ring in
-    logical order."""
+    logical order, both from the slots that are ``active``."""
     from deepspeed_tpu.ops.attention.paged import decode_plan
     RB = window_blocks(cfg, bs)
-    return (decode_plan(lengths, tables.shape[1] - RB, bs),
+    return (decode_plan(lengths, tables.shape[1] - RB, bs, active=active),
             decode_plan(_ring_span(cfg, bs, lengths)[1], RB, bs,
-                        window=cfg.attn_window))
+                        window=cfg.attn_window, active=active))
 
 
 def _decode_attend(q, k_pool, v_pool, tables, lengths, window, impl, scale,

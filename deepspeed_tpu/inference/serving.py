@@ -686,6 +686,7 @@ class ServingEngine:
         self._horizon_ticks = 1
         self._token_tick = 0.0
         self._kv_steps = 0      # the serve.decode span's, telemetry on
+        self._idle_tiles = 0    # beside it: the steps the plan left out
         self._kv_tokens = 0
         self.last_step_span = 1.0
         self.token_time_unit = 0.0
@@ -1140,6 +1141,7 @@ class ServingEngine:
                 if c4:
                     s_decode.set(live=occ, blocks=c4[3],
                                  kv_steps=self._kv_steps,
+                                 idle_tiles=self._idle_tiles,
                                  kv_tokens=self._kv_tokens,
                                  evicted=c4[2] - c3[2])
             with tracer.span("serve.spill", step=clock) as s_spill:
@@ -1583,14 +1585,18 @@ class ServingEngine:
         live = [i for i, r in enumerate(self.slots)
                 if r is not None and r.state == "decode"]
         if self.telemetry.enabled:
-            # grid steps of a paged_decode call that fetch and compute
-            # (of the full table): beside `blocks`, the fill of the tiles
+            # grid steps of a paged_decode call (of the full table): the
+            # live slots' run, beside `blocks` the fill of the tiles; the
+            # other slots' (a trash-block tile of a slot with no request,
+            # the progress of one in prefill) are the ones the plan drops
             cache = self.cache
-            self._kv_steps = sum(
-                tiles_run(int(cache.lengths[i]), cache.blocks_per_slot,
-                          cache.block_size,
-                          None if cache.ring_blocks
-                          else self.engine.cfg.attn_window) for i in live)
+            tiles = [tiles_run(int(n), cache.blocks_per_slot,
+                               cache.block_size,
+                               None if cache.ring_blocks
+                               else self.engine.cfg.attn_window)
+                     for n in cache.lengths]
+            self._kv_steps = sum(tiles[i] for i in live)
+            self._idle_tiles = sum(tiles) - self._kv_steps
             # cached rows the step reads in a layer that pages its whole
             # history: each live slot's tokens and the one it writes
             self._kv_tokens = int(sum(cache.lengths[i] + 1 for i in live))

@@ -28,7 +28,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops.attention.paged import (
-    LANES, NEG_INF, DecodePlan, _band, blocks_per_step, decode_plan)
+    LANES, NEG_INF, DecodePlan, _band, blocks_per_step, decode_plan,
+    zero_idle_rows)
 
 
 def _mla_decode_kernel(tables_ref, held_ref, slots_ref, tiles_ref,
@@ -136,7 +137,7 @@ def mla_decode_attention(q: jnp.ndarray, pool: jnp.ndarray,
                         pltpu.VMEM((H, value_width), jnp.float32)])
     kernel = functools.partial(_mla_decode_kernel, bs=bs, P=P, nb=nb,
                                vw=value_width, scale=float(scale))
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel, name="mla_decode", grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, value_width), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -144,6 +145,7 @@ def mla_decode_attention(q: jnp.ndarray, pool: jnp.ndarray,
         **({"interpret": True} if interpret else {}),
     )(tables.reshape(-1), plan.held, plan.slot, plan.tile, lengths, q,
       *([pool] * P))
+    return zero_idle_rows(out, plan)
 
 
 def mla_decode_reference(q, pool, tables, lengths, *, value_width: int,

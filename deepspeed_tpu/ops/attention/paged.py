@@ -24,7 +24,10 @@ online softmax, Dao 2023):
   slot's tiles from the one that holds its band's first block to the
   one that holds position ``lengths[b]``, slot after slot), and the
   grid's bound is their number, a dynamic one. A caller with many layers at the same lengths works
-  the plan out once and passes it to every call;
+  the plan out once and passes it to every call. A caller that knows
+  which slots decode says so (``active=``): the others (no request, or
+  one still in prefill, whose length is its progress) have NO step, and
+  the call gives their rows back as zeros;
 - the pools stay where they are and are handed over ``P`` times each,
   every view a BlockSpec of ONE block whose index map reads the block
   table (scalar-prefetched, with the plan): Pallas pipelines the ``2P``
@@ -334,16 +337,20 @@ class DecodePlan(NamedTuple):
     ``held`` [P, B*nt] where in the flattened ``[B*NB]`` block table the
     block stands that ref i of a step names; ``cut`` the static
     (table entries, block size, window, q_len) it was worked out for,
-    which the call it is handed to checks against its own."""
+    which the call it is handed to checks against its own; ``live`` [B]
+    bool the slots the list was cut from (None: all of them), by which
+    the call zeroes the rows no step writes (:func:`zero_idle_rows`)."""
     steps: jnp.ndarray
     slot: jnp.ndarray
     tile: jnp.ndarray
     held: jnp.ndarray
     cut: tuple
+    live: Optional[jnp.ndarray]
 
 
 def decode_plan(lengths, num_entries: int, block_size: int, *,
-                window: Optional[int] = None, q_len: int = 1) -> DecodePlan:
+                window: Optional[int] = None, q_len: int = 1,
+                active=None) -> DecodePlan:
     """Work the grid of a call out from the lengths, in XLA. It depends
     on nothing else, so a program that attends many layers at the same
     lengths works it out ONCE, outside its layer loop, and hands it to
@@ -358,7 +365,15 @@ def decode_plan(lengths, num_entries: int, block_size: int, *,
     that does not change is not fetched again, so only attended blocks
     are ever read, each once (the kernel reads a ref that waits as
     zeros). Before ref i's first attended entry it names that one
-    (fetched early, once)."""
+    (fetched early, once).
+
+    ``active`` ([B] bool): the slots that decode. A slot that does not
+    has no step at all (``searchsorted`` passes over a slot whose end is
+    its predecessor's, first and last slot included), so neither the
+    trash block of a slot with no request nor the real blocks of one
+    still in prefill are fetched; with no slot active the grid is empty.
+    The kernel then never writes such a slot's output row: the call
+    does, with zeros. None is the plan of every slot."""
     nb, bs = num_entries, block_size
     lengths = jnp.asarray(lengths, jnp.int32)
     B = lengths.shape[0]
@@ -368,6 +383,9 @@ def decode_plan(lengths, num_entries: int, block_size: int, *,
     lo, hi = _band(lengths, bs, nb, window, q_len)
     lo = jnp.broadcast_to(lo, hi.shape).astype(jnp.int32)
     steps = hi // P - lo // P + 1
+    if active is not None:
+        active = jnp.asarray(active, bool)
+        steps = jnp.where(active, steps, 0)
     ends = jnp.cumsum(steps)
     w = jnp.arange(W, dtype=jnp.int32)
     slot = jnp.minimum(jnp.searchsorted(
@@ -381,7 +399,17 @@ def decode_plan(lengths, num_entries: int, block_size: int, *,
     src = jnp.where(last >= 0, last, first)            # [P, W]: a step
     held = jnp.minimum(jnp.take_along_axis(entry, src, axis=1), nb - 1)
     return DecodePlan(ends[-1], slot, tile, slot[src] * nb + held,
-                      (nb, bs, window, q_len))
+                      (nb, bs, window, q_len), active)
+
+
+def zero_idle_rows(out, plan: DecodePlan):
+    """``out`` [B, ...] of a call over ``plan``'s grid with the rows of
+    the slots the plan left out as zeros: no step visited them, so they
+    hold whatever the buffer held."""
+    if plan.live is None:
+        return out
+    return jnp.where(plan.live.reshape((-1,) + (1,) * (out.ndim - 1)),
+                     out, 0)
 
 
 def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
@@ -551,7 +579,7 @@ def _paged_attention_call(q_rows, k_pool, v_pool, tables, lengths, *,
         **({"interpret": True} if interpret else {}),
     )(tables.reshape(-1), plan.held, plan.slot, plan.tile, lengths,
       *operands)
-    return chunked(out, inverse=True)
+    return chunked(zero_idle_rows(out, plan), inverse=True)
 
 
 def gather_pool_blocks(pool, tables, n_kv: int, scale_pool=None,

@@ -94,6 +94,52 @@ def test_paged_attention_lowers(H, Hkv, D, blk, q_len, quant):
                                           lengths, *scales.values())
 
 
+# the serving cells' decode calls: (heads, kv heads, head size, slots,
+# table entries, block, window)
+MASKED_CALLS = [
+    pytest.param(25, 25, 64, 17, 64, 16, None, id="gpt2-xl-chat"),
+    pytest.param(64, 8, 128, 48, 256, 16, None, id="kexaone-full-table256"),
+    pytest.param(64, 8, 128, 48, 9, 16, 128, id="kexaone-ring9-window128"),
+    pytest.param(8, 2, 128, 40, 6, 1024, None, id="zaya1-block1024"),
+]
+
+
+@pytest.mark.parametrize("H,Hkv,D,B,nb,bs,window", MASKED_CALLS)
+def test_paged_attention_lowers_with_a_plan_of_the_active_slots(
+        H, Hkv, D, B, nb, bs, window):
+    """The work list cut from the slots that decode (a traced mask), and
+    the zeroing of the rows it leaves out, through the TPU lowering at
+    the cells' shapes."""
+    pool = S((B * nb + 1, bs, Hkv * D))
+    args = (S((B, Hkv, H // Hkv, D)), pool, pool, S((B, nb), jnp.int32),
+            S((B,), jnp.int32))
+
+    def call(q, k, v, t, ln, active=None):
+        plan = paged.decode_plan(ln, nb, bs, window=window, active=active)
+        return paged.paged_decode_attention(q, k, v, t, ln, scale=0.125,
+                                            window=window, plan=plan)
+    masked = lower_tpu(call, *args, S((B,), jnp.bool_))
+    assert masked.count("tpu_custom_call") == 1
+    # the mask costs the plan one select and the output one
+    assert masked.count("stablehlo.select") \
+        == lower_tpu(call, *args).count("stablehlo.select") + 2
+
+
+def test_mla_decode_lowers_with_a_plan_of_the_active_slots():
+    """The same for the latent kernel at the dots.vlm1 cell's shape: 16
+    slots, 128 heads over rows of 640 lanes, 48 blocks of 512."""
+    from deepspeed_tpu.ops.attention import mla
+    B, H, row, nb, bs = 16, 128, 640, 48, 512
+    args = (S((B, H, row)), S((B * nb + 1, bs, row)), S((B, nb), jnp.int32),
+            S((B,), jnp.int32), S((B,), jnp.bool_))
+
+    def call(q, pool, t, ln, active):
+        plan = paged.decode_plan(ln, nb, bs, active=active)
+        return mla.mla_decode_attention(q, pool, t, ln, value_width=512,
+                                        scale=0.07, plan=plan)
+    assert lower_tpu(call, *args).count("tpu_custom_call") == 1
+
+
 @pytest.mark.parametrize("M,K,N", [(8, 1024, 3072), (256, 4096, 4096)])
 def test_int8_matmul_lowers(M, K, N):
     bk, bn = fit_blocks(K, N)

@@ -119,10 +119,11 @@ def test_absorbed_and_expanded_paths_agree_on_the_same_cache():
 
 
 @functools.partial(jax.jit, static_argnames=("vw", "plan_given"))
-def _kernel_interpreted(q, pool, tables, lengths, *, vw, plan_given=False):
+def _kernel_interpreted(q, pool, tables, lengths, active=None, *, vw,
+                        plan_given=False):
     from deepspeed_tpu.ops.attention.paged import decode_plan
-    plan = decode_plan(lengths, tables.shape[1], pool.shape[1]) \
-        if plan_given else None
+    plan = decode_plan(lengths, tables.shape[1], pool.shape[1],
+                       active=active) if plan_given else None
     return mla.mla_decode_attention(
         q, pool, tables, lengths, value_width=vw, scale=0.11,
         interpret=True, plan=plan)
@@ -154,6 +155,47 @@ def test_kernel_in_interpret_mode_equals_the_plain_latent_decode(bs, nb):
     # a plan worked out by the caller is the call's own
     again = _kernel_interpreted(*args, vw=vw, plan_given=True)
     np.testing.assert_array_equal(np.asarray(again), np.asarray(got))
+
+
+@pytest.mark.parametrize("live", ["some-live", "none-live"])
+@pytest.mark.parametrize("bs,nb", [(16, 24), (128, 3)])
+def test_kernel_does_not_visit_a_slot_that_does_not_decode(bs, nb, live):
+    """``decode_plan(active=)`` through ``mla_decode``: a slot with no
+    request and one in mid-prefill (inactive, rows of its own) have no
+    grid step. NaN in the trash block and in all of the prefilling slot's
+    blocks moves no live slot's output by a bit, the rows of the slots
+    that do not decode are exactly zero, and with no slot live the call
+    gives zeros."""
+    B, H, row, vw = 5, 8, 256, 128
+    N = 1 + B * nb
+    ks = jax.random.split(jax.random.PRNGKey(bs), 2)
+    pool = np.asarray(jax.random.normal(ks[0], (N, bs, row))).copy()
+    pool[0] = 0.0
+    q = jax.random.normal(ks[1], (B, H, row))
+    cap = nb * bs
+    lengths = np.asarray([0, cap - 1, min(300, cap - 5), 3, cap // 2],
+                         np.int32)
+    tables = 1 + np.arange(B * nb, dtype=np.int32).reshape(B, nb)
+    tables[0] = 0                                # no request
+    active = np.asarray([False, True, False, True, True])  # 2: in prefill
+    if live == "none-live":
+        active[:] = False
+
+    def call(pool):
+        return _kernel_interpreted(q, pool, jnp.asarray(tables),
+                                   jnp.asarray(lengths), jnp.asarray(active),
+                                   vw=vw, plan_given=True)
+    clean = np.asarray(call(jnp.asarray(pool)))
+    want = np.asarray(mla.mla_decode_reference(
+        q, jnp.asarray(pool), jnp.asarray(tables), jnp.asarray(lengths),
+        value_width=vw, scale=0.11))
+    pool[0] = np.nan
+    pool[tables[2]] = np.nan
+    got = np.asarray(call(jnp.asarray(pool)))
+    np.testing.assert_array_equal(got, clean)
+    assert (got[~active] == 0).all()
+    np.testing.assert_allclose(got[active], want[active], atol=2e-5,
+                               rtol=2e-5)
 
 
 def _reference_selection(ref, cfg, h, router):

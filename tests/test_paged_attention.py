@@ -10,6 +10,7 @@ reduction), serving-level tests assert token-for-token EQUALITY of the
 greedy stream, including across an eviction/requeue."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -235,6 +236,141 @@ def test_decode_plan_fetches_attended_blocks_once(devices, H, Hkv, Dh, nb,
     assert sorted(fetched) == sorted(attended)
 
 
+# which slots decode, of B: the work list is cut from these
+MASKS = {
+    "first-idle": lambda B: np.arange(B) != 0,
+    "last-idle": lambda B: np.arange(B) != B - 1,
+    "every-other-idle": lambda B: np.arange(B) % 2 == 1,
+    "single-live": lambda B: np.arange(B) == B // 2,
+    "none-live": lambda B: np.zeros(B, bool),
+    "all-live": lambda B: np.ones(B, bool),
+}
+# (table entries, window, q_len): the full table, the window ring, a
+# windowed table of several tiles, a verify chunk
+PLAN_CUTS = [pytest.param(64, None, 1, id="table64"),
+             pytest.param(9, 128, 1, id="ring9-window128"),
+             pytest.param(32, 100, 1, id="table32-window100"),
+             pytest.param(64, None, 5, id="table64-verify5")]
+
+
+@pytest.mark.parametrize("mask", MASKS)
+@pytest.mark.parametrize("nb,window,q_len", PLAN_CUTS)
+def test_decode_plan_of_the_active_slots(devices, nb, window, q_len, mask):
+    """``decode_plan(active=)`` is the plan of the live slots alone, slot
+    indices mapped back: a slot that does not decode has no step, first
+    and last slot included, every attended block of a live slot is still
+    fetched once and nothing else is; with every slot live, and with no
+    mask, the arrays are the parent's number for number."""
+    bs = 16
+    P = blocks_per_step(nb, bs)
+    lengths = np.asarray(_edge_lengths(nb, bs, window), np.int32)
+    lengths = np.minimum(lengths, nb * bs - q_len)
+    B = len(lengths)
+    active = MASKS[mask](B)
+    kw = dict(window=window, q_len=q_len)
+    plan = decode_plan(jnp.asarray(lengths), nb, bs, active=active, **kw)
+    assert plan.cut == (nb, bs, window, q_len)
+    np.testing.assert_array_equal(np.asarray(plan.live), active)
+    steps = int(plan.steps)
+    per_slot = [tiles_run(int(n), nb, bs, window, q_len) if a else 0
+                for n, a in zip(lengths, active)]
+    assert steps == sum(per_slot)
+    slot = np.asarray(plan.slot)
+    assert [int((slot[:steps] == b).sum()) for b in range(B)] == per_slot
+    held = np.asarray(plan.held)
+    assert held.shape == (P, B * -(-nb // P))
+    assert held.min() >= 0 and held.max() < B * nb     # padding too
+    parent = decode_plan(jnp.asarray(lengths), nb, bs, **kw)
+    assert parent.live is None
+    if active.all():
+        for a, b in zip(plan[:4], parent[:4]):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    if not active.any():
+        return
+    live = np.flatnonzero(active)
+    alone = decode_plan(jnp.asarray(lengths[live]), nb, bs, **kw)
+    assert int(alone.steps) == steps
+    np.testing.assert_array_equal(slot[:steps],
+                                  live[np.asarray(alone.slot)[:steps]])
+    np.testing.assert_array_equal(np.asarray(plan.tile)[:steps],
+                                  np.asarray(alone.tile)[:steps])
+    attended = set()
+    for b in live:
+        hi = min((lengths[b] + q_len - 1) // bs, nb - 1)
+        lo = 0 if window is None else max(lengths[b] - window + 1, 0) // bs
+        attended |= {b * nb + e for e in range(lo, hi + 1)}
+    h = np.asarray(alone.held)[:, :steps]
+    for i in range(P):
+        fetched = [held[i, w] for w in range(steps)
+                   if w == 0 or held[i, w] != held[i, w - 1]]
+        mine = sorted(a for a in attended if a % nb % P == i)
+        if mine:
+            assert sorted(fetched) == mine
+            np.testing.assert_array_equal(held[i, :steps],
+                                          live[h[i] // nb] * nb + h[i] % nb)
+        else:
+            # a ref no live slot's band reaches names one block all
+            # through (the kernel reads it as zeros), as in the parent
+            assert len(fetched) == 1
+
+
+# ZAYA1's attention (8 query / 2 KV heads of 128, a table of 6 blocks)
+# beside the older cells'; its block is 1,024 on the chip, 128 here
+MASKED_SHAPES = CELL_SHAPES[:3] + [
+    pytest.param(8, 2, 128, 6, None, id="zaya1-table6")]
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "window"))
+def _attend_live_slots(q, kp, vp, tables, lengths, active, *, scale, window):
+    plan = decode_plan(lengths, tables.shape[1], kp.shape[1], window=window,
+                       active=active)
+    return paged_decode_attention(q, kp, vp, tables, lengths, scale=scale,
+                                  window=window, plan=plan)
+
+
+@pytest.mark.parametrize("live", ["some-live", "none-live"])
+@pytest.mark.parametrize("H,Hkv,Dh,nb,window", MASKED_SHAPES)
+def test_slots_that_do_not_decode_are_not_visited(devices, pallas_interpret,
+                                                  H, Hkv, Dh, nb, window,
+                                                  live):
+    """A slot with no request (length 0, its table the trash block) and a
+    slot in mid-prefill (inactive, its progress as its length, blocks of
+    its own) have no grid step: with NaN in the trash block and in every
+    block of the prefilling slot the live slots read, to the bit, what
+    they read without the poison, and the rows of the slots that do not
+    decode are exactly zero. With no slot live the call returns zeros."""
+    bs = 128 if nb == 6 else 16
+    q, kp, vp, tables, lengths = _cell_problem(H, Hkv, Dh, nb, window, bs=bs)
+    B = len(lengths)
+    idle, prefilling = 0, B // 2
+    lengths[idle] = 0
+    tables[idle] = 0
+    lengths[prefilling] = min(300, nb * bs - 5)
+    active = np.ones(B, bool)
+    active[[idle, prefilling]] = False
+    if live == "none-live":
+        active[:] = False
+    kp[0] = vp[0] = 0.0
+
+    def call(kp, vp):
+        return _attend_live_slots(q, kp, vp, jnp.asarray(tables),
+                                  jnp.asarray(lengths), jnp.asarray(active),
+                                  scale=Dh ** -0.5, window=window)
+    clean = np.asarray(call(jnp.asarray(kp), jnp.asarray(vp)))
+    kp[0] = vp[0] = np.nan
+    kp[tables[prefilling]] = vp[tables[prefilling]] = np.nan
+    out = np.asarray(call(jnp.asarray(kp), jnp.asarray(vp)))
+    np.testing.assert_array_equal(out, clean)
+    assert (out[~active] == 0).all()
+    if active.any():
+        ref = np.asarray(paged_decode_reference(
+            q, jnp.asarray(np.nan_to_num(kp)), jnp.asarray(np.nan_to_num(vp)),
+            jnp.asarray(tables), jnp.asarray(lengths), scale=Dh ** -0.5,
+            window=window))
+        np.testing.assert_allclose(out[active], ref[active], atol=2e-5,
+                                   rtol=2e-5)
+
+
 @pytest.mark.parametrize("H,Hkv,Dh,nb,window", CELL_SHAPES)
 def test_a_slots_bad_block_stays_its_own(devices, pallas_interpret, H, Hkv,
                                          Dh, nb, window):
@@ -344,6 +480,41 @@ def test_serving_parity_pallas_vs_gather(devices, pallas_interpret):
     for i in ref:
         np.testing.assert_array_equal(out[i], ref[i])
     assert srv.stats["peak_occupancy"] > 1    # batched decode really ran
+
+
+@pytest.mark.parametrize("impl", ["gather", "pallas"])
+def test_a_neighbour_in_prefill_changes_nothing(devices, pallas_interpret,
+                                                impl):
+    """A request decodes in a 4-slot engine while another is in
+    mid-prefill (inactive in the decode dispatch, its progress as its
+    length) and two slots hold nothing: its tokens and log-probabilities
+    are those of the same request served alone, and the span says how
+    many tiles the dispatch did not take."""
+    from deepspeed_tpu.telemetry import Telemetry
+    cfg, params = tiny()
+    short, long_ = prompts_of((5, 41), seed=11)
+    kw = dict(num_slots=4, block_size=4, num_blocks=40, prefill_chunk=4,
+              decode_impl=impl)
+
+    def run(prompts, telemetry=None):
+        eng = InferenceEngine(config=cfg, params=params, dtype=jnp.float32)
+        srv = ServingEngine(eng, telemetry=telemetry, **kw)
+        reqs = [ServeRequest(rid=i, prompt=p, max_new_tokens=12,
+                             logprobs=True) for i, p in enumerate(prompts)]
+        out = srv.run(reqs)
+        return out[0], list(reqs[0].out_logprobs)
+
+    alone_tok, alone_lp = run([short])
+    tel = Telemetry()
+    tok, lp = run([short, long_], tel)
+    np.testing.assert_array_equal(tok, alone_tok)
+    assert lp == alone_lp and len(lp) == 12
+    spans = [s[5] for s in tel.tracer.spans() if s[1] == "serve.decode"
+             and s[5].get("live") == 1]
+    # one live slot; 2 idle slots a tile each, and the prefilling slot's
+    # progress (4 to 40 tokens of a 64-token table: one tile of 16 blocks)
+    assert spans and all(a["kv_steps"] == 1 for a in spans)
+    assert {a["idle_tiles"] for a in spans} == {3}
 
 
 def test_serving_parity_pallas_across_eviction(devices, pallas_interpret):

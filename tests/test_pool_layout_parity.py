@@ -8,10 +8,19 @@ rotary case, and the kernel's floats since its tile walk: to one unit in
 the last place), to what the tree BEFORE
 the change produced: ``tests/data/pool_layout_parity.npz``
 was recorded from commit 60f8ab9 with ``python
-tests/test_pool_layout_parity.py --record``. Pools are compared whole,
-trash blocks and stale lanes included, through a
-``[L, N, block, Hkv, Dh]`` view, so a write that lands in another
-layer's block or another head's lanes shows even where no token moves.
+tests/test_pool_layout_parity.py --record``. Pools are compared through a
+``[L, N, block, Hkv, Dh]`` view, stale lanes included, so a write that
+lands in another layer's block or another head's lanes shows even where
+no token moves.
+
+Every block but each layer's TRASH block (block 0 of the layer, ``l*N``
+of the stack) is held to the record as before. The trash blocks are held
+only to "finite" since PR 36: the kernel's work list is cut from the
+slots that decode, so an inactive slot's row enters the next layer from
+a zero attention output and not from attention over the trash block, and
+what such a row then writes (always to a trash block, which no grid step
+of any slot reads any more) differs from the record's. Nothing a request
+owns or emits may move for that.
 """
 
 import functools
@@ -128,6 +137,11 @@ def test_tokens_and_pools_equal_the_parent(devices, pallas_interpret,
     for name in keys:
         want = recorded[f"{variant}/{impl}/{name}"]
         assert got[name].dtype == want.dtype, name
+        if name != "tokens":
+            # [L, N, ...]: block 0 of a layer is its trash block
+            assert got[name].shape == want.shape, name
+            assert np.isfinite(got[name][:, 0]).all(), name
+            got[name], want = got[name][:, 1:], want[:, 1:]
         if impl == "pallas" and want.dtype == np.float32:
             # since PR 29 the kernel walks 128-token tiles with all heads
             # of a tile in one product (the record's walked a block a

@@ -234,8 +234,8 @@ def _paged_layers(L, N, bs, nb, scale, window):
     stacked, layer l at ``tables + l*N``, the grid worked out once from
     the lengths, a loop over the layers (each call's result feeding the
     next one's queries), PAGED_REPS sweeps of it."""
-    def layers(q, k, v, tables, lengths):
-        plan = P.decode_plan(lengths, nb, bs, window=window)
+    def layers(q, k, v, tables, lengths, active=None):
+        plan = P.decode_plan(lengths, nb, bs, window=window, active=active)
 
         def layer(q, l):
             out = P.paged_decode_attention(
@@ -250,9 +250,25 @@ def _paged_layers(L, N, bs, nb, scale, window):
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "window"))
-def _paged_decode_jit(q, k, v, tables, lengths, *, scale, window):
+def _paged_decode_jit(q, k, v, tables, lengths, active=None, *, scale,
+                      window):
+    plan = P.decode_plan(lengths, tables.shape[1], k.shape[1], window=window,
+                         active=active)
     return P.paged_decode_attention(q, k, v, tables, lengths, scale=scale,
-                                    window=window)
+                                    window=window, plan=plan)
+
+
+def _time_layers(layers, L, *args):
+    """Microseconds a call inside :func:`_paged_layers`' (or
+    :func:`_mla_layers`') program: the best of three runs after one that
+    compiles."""
+    layers(*args).block_until_ready()
+    best = float("inf")
+    for _ in range(3):
+        t0 = time.perf_counter()
+        layers(*args).block_until_ready()
+        best = min(best, time.perf_counter() - t0)
+    return best / (PAGED_REPS * L) * 1e6
 
 
 def paged_time_rows():
@@ -291,14 +307,8 @@ def paged_time_rows():
                 window=window))}
             points = []
             for fill, lengths, blocks in fills:
-                lengths = jnp.asarray(lengths, jnp.int32)
-                layers(q, k, v, tables, lengths).block_until_ready()
-                best = float("inf")
-                for _ in range(3):
-                    t0 = time.perf_counter()
-                    layers(q, k, v, tables, lengths).block_until_ready()
-                    best = min(best, time.perf_counter() - t0)
-                us = best / (PAGED_REPS * L) * 1e6
+                us = _time_layers(layers, L, q, k, v, tables,
+                                  jnp.asarray(lengths, jnp.int32))
                 row[f"us_{fill}"] = round(us, 1)
                 row[f"blocks_{fill}"] = blocks
                 points.append((blocks, us))
@@ -309,6 +319,61 @@ def paged_time_rows():
                     "grid_steps": B * -(-nb // P.blocks_per_step(nb, bs)),
                     "ok": row["fwd_err"] < TOL}
         yield f"paged decode time {name}", run
+
+
+# the GPT-2 XL cells' decode dispatches as the scheduler leaves the
+# slots: (name, slots that decode, tokens each holds, slots in prefill,
+# tokens each has prefilled so far); the rest hold no request
+PAGED_MASKED = (("chat 3 live of 17", 3, 290, 0, 0),
+                ("docs 14 live 3 in prefill at 370", 14, 760, 3, 370),
+                ("no slot live", 0, 0, 3, 370))
+
+
+def paged_masked_time_rows():
+    """Microseconds a ``paged_decode`` call at the GPT-2 XL cells' shape
+    with the work list cut from the slots that decode, against the list of
+    every slot (the plan before PR 36). Checked with NaN in the trash
+    block and in the prefilling slots' occupied blocks: the live rows are the
+    unmasked call's to the bit, the others exactly zero."""
+    r = np.random.default_rng(6)
+    bs = 16
+    _, B, Hkv, G, D, nb, window, L, N = PAGED_CELL_SHAPES[0]
+    for name, n_live, held, n_pre, done in PAGED_MASKED:
+        def run(n_live=n_live, held=held, n_pre=n_pre, done=done):
+            k = _rand(r, (L * N, bs, Hkv * D))
+            v = _rand(r, (L * N, bs, Hkv * D))
+            q = _rand(r, (B, Hkv, G, D))
+            scale = 1.0 / np.sqrt(D)
+            slots = np.arange(B)
+            live = slots < n_live
+            pre = (slots >= n_live) & (slots < n_live + n_pre)
+            lengths = np.where(live, held, np.where(pre, done, 0))
+            tables = 1 + (np.arange(B * nb) % (N - 1)).reshape(B, nb)
+            tables[~(live | pre)] = 0        # no request: the trash block
+            args = (q, k, v, jnp.asarray(tables, jnp.int32),
+                    jnp.asarray(lengths, jnp.int32))
+            active = jnp.asarray(live)
+            layers = _paged_layers(L, N, bs, nb, scale, window)
+            row = {"us_every_slot": round(_time_layers(layers, L, *args), 1),
+                   "us_live_slots": round(
+                       _time_layers(layers, L, *args, active), 1)}
+            tiles = [P.tiles_run(int(n), nb, bs, window) for n in lengths]
+            row["steps_every_slot"] = sum(tiles)
+            row["steps_live_slots"] = int(np.dot(tiles, live))
+            # one layer's pools: a poisoned copy of all 48 would not fit
+            bad = np.concatenate(
+                [[0], tables[pre][:, :done // bs + 1].ravel()])
+            k0, v0 = k[:N], v[:N]
+            want = np.asarray(_paged_decode_jit(
+                q, k0, v0, *args[3:], scale=scale, window=window), np.float32)
+            got = np.asarray(_paged_decode_jit(
+                q, k0.at[bad].set(jnp.nan), v0.at[bad].set(jnp.nan),
+                *args[3:], active, scale=scale, window=window), np.float32)
+            row["live_rows_equal"] = bool((got[live] == want[live]).all())
+            row["other_rows_zero"] = bool((got[~live] == 0).all())
+            return {**row, "ok": row["live_rows_equal"]
+                    and row["other_rows_zero"]}
+        yield f"paged decode time masked {name}", run
 
 
 # the latent-attention cell's decode shape: (slots, heads, row lanes,
@@ -376,14 +441,8 @@ def mla_time_rows():
                                    vw=vw, scale=scale, kernel=False)
             row_ = {"block": bs, "fwd_err": _err(got, want)}
             for fill, tokens in MLA_FILLS:
-                lengths = jnp.full((B,), tokens, jnp.int32)
-                layers(q, pool, tables, lengths).block_until_ready()
-                best = float("inf")
-                for _ in range(3):
-                    t0 = time.perf_counter()
-                    layers(q, pool, tables, lengths).block_until_ready()
-                    best = min(best, time.perf_counter() - t0)
-                us = best / (PAGED_REPS * L) * 1e6
+                us = _time_layers(layers, L, q, pool, tables,
+                                  jnp.full((B,), tokens, jnp.int32))
                 rows = B * (tokens + 1)
                 least = max(2.0 * rows * H * (2 * 512 + 64) / 197e12,
                             rows * 576 * 2 / 819e9) * 1e6
@@ -558,7 +617,8 @@ def main():
     failed = 0
     with open(OUT, "a") as out:
         for rows in (flash_rows, ring_block_rows, paged_rows,
-                     paged_time_rows, mla_time_rows, dispatch_operand_rows,
+                     paged_time_rows, paged_masked_time_rows, mla_time_rows,
+                     dispatch_operand_rows,
                      int8_matmul_rows, blocksparse_rows):
             for name, run in rows():
                 if wanted and not any(w in name for w in wanted):
