@@ -690,9 +690,16 @@ class ServingEngine:
         self._kv_tokens = 0
         self.last_step_span = 1.0
         self.token_time_unit = 0.0
-        # wall seconds spent inside device dispatch/harvest calls — the
-        # bench's host/device ms-per-token split (tools/infer_bench.py)
-        self.device_time_s = 0.0
+        # the account of the host time between two dispatches
+        # (_account_gap, telemetry on): the stamp at which the previous
+        # dispatch's wait returned and its site, the seconds outside
+        # every serve.step since then, the end of the last serve.step,
+        # and whether the engine held no request at some moment since
+        self._gap_prev_t: Optional[float] = None
+        self._gap_prev_site = ""
+        self._gap_caller = 0.0
+        self._gap_step_t1: Optional[float] = None
+        self._gap_empty = True
         # per-request sampling: engine-wide ctor knobs are DEFAULTS a
         # request's own fields override (sampling.resolve_params); the
         # resolved knobs live as slot-indexed arrays the fused sampler
@@ -750,6 +757,32 @@ class ServingEngine:
                     help=f"wall seconds per step in the {ph} phase",
                     buckets=_PHASE_BUCKETS)
                 for ph in _STEP_PHASES}
+            # the host seconds between two dispatches and their parts,
+            # from the dispatch's and the step's spans (_account_gap)
+            self._h_gap = reg.histogram(
+                "serving_dispatch_gap_s",
+                help="host seconds in which no program was enqueued: from "
+                "the return of one dispatch's wait to the return of the "
+                "next one's enqueue (a gap after an empty engine left out)",
+                buckets=_PHASE_BUCKETS)
+            self._c_gap_sched = reg.counter(
+                "serving_gap_sched_seconds_total",
+                "seconds of those gaps inside serve.step and outside a "
+                "dispatch: the scheduler's own")
+            self._c_gap_caller = reg.counter(
+                "serving_gap_caller_seconds_total",
+                "seconds of those gaps outside every serve.step: the loop "
+                "that calls step()")
+            self._c_gap_enqueue = reg.counter(
+                "serving_gap_enqueue_seconds_total",
+                "seconds of those gaps inside serve.dispatch.enqueue")
+            self._c_wait = reg.counter(
+                "serving_dispatch_wait_seconds_total",
+                "seconds blocked in serve.dispatch.wait, every dispatch")
+            self._c_empty = reg.counter(
+                "serving_engine_empty_seconds_total",
+                "seconds of the gaps that follow an empty engine: pauses, "
+                "in no other gap metric")
             self._g_held = reg.gauge(
                 "serving_hbm_blocks_held", "pool blocks with refcount > 0")
             self._g_cached = reg.gauge(
@@ -1120,6 +1153,9 @@ class ServingEngine:
         clock = self._step_clock
         with tracer.span("serve.step", step=clock,
                          queue=len(self.queue)) as s_step:
+            if self._gap_step_t1 is not None:
+                # what the caller's loop took between two steps
+                self._gap_caller += s_step.t0 - self._gap_step_t1
             with tracer.span("serve.expire", step=clock) as s_expire:
                 c0 = self._span_counts()
                 self._expire(now)
@@ -1157,6 +1193,9 @@ class ServingEngine:
             self._h_phase["prefill"].observe(s_decode.t0 - s_admit.t1)
             self._h_phase["decode"].observe(s_spill.t1 - s_decode.t0)
             self._h_phase["bookkeeping"].observe(s_step.t1 - s_spill.t1)
+            self._gap_step_t1 = s_step.t1
+            if not self.busy:
+                self._gap_empty = True
         if self._watchdog_msg is not None:
             msg, self._watchdog_msg = self._watchdog_msg, None
             self._over_budget = 0
@@ -1648,9 +1687,7 @@ class ServingEngine:
         tracer = self.telemetry.tracer
         with tracer.span("serve.pull", step=self._step_clock,
                          bytes=toks.nbytes + lps.nbytes, d2h=1):
-            t_dev = time.perf_counter()
             toks, lps = jax.device_get((toks, lps))
-            self.device_time_s += time.perf_counter() - t_dev
         with tracer.span("serve.emit", step=self._step_clock,
                          tokens=len(live)):
             for i in live:
@@ -1754,9 +1791,7 @@ class ServingEngine:
         with self.telemetry.tracer.span(
                 "serve.pull", step=self._step_clock, d2h=1,
                 bytes=toks.nbytes + lps.nbytes + produced.nbytes):
-            t_dev = time.perf_counter()
             toks, lps, produced = jax.device_get((toks, lps, produced))
-            self.device_time_s += time.perf_counter() - t_dev
         if self.costs.enabled:
             # one fused dispatch: each live slot produced its own token
             # count over its own pre-advance context
@@ -2064,23 +2099,23 @@ class ServingEngine:
         while True:
             try:
                 with tracer.span("serve.dispatch", step=self._step_clock,
-                                 site=site, attempt=attempt):
-                    # block inside the timed window: dispatch is async,
-                    # and every caller harvests the result immediately
-                    # anyway — blocking here makes device_time_s (the
-                    # bench's host/device ms-per-token split) and the
-                    # watchdog's elapsed measurement cover the actual
-                    # execution instead of just the enqueue
-                    t_dev = time.perf_counter()
+                                 site=site, attempt=attempt,
+                                 prev=self._gap_prev_site,
+                                 after_empty=int(self._gap_empty)) \
+                        as dispatch:
                     with tracer.span("serve.dispatch.enqueue") as enqueue:
                         # host: arguments, the one transfer, launch
                         self.faults.fire(site)
                         out = fn(*args)
                         h2d, h2d_bytes = self.engine.h2d
                         enqueue.set(h2d=h2d, h2d_bytes=h2d_bytes)
-                    with tracer.span("serve.dispatch.wait"):
+                    # dispatch is async and every caller harvests the
+                    # result at once: the block makes the watchdog's
+                    # elapsed time cover the execution, not the enqueue
+                    with tracer.span("serve.dispatch.wait") as wait:
                         out = jax.block_until_ready(out)
-                    self.device_time_s += time.perf_counter() - t_dev
+                    if self._h_step is not None:
+                        self._account_gap(site, dispatch, enqueue, wait)
                 return out
             except TransientDeviceError:
                 if attempt >= self.max_retries:
@@ -2097,6 +2132,33 @@ class ServingEngine:
                     f"in {pause * 1e3:.1f}ms")
                 time.sleep(pause)
                 delay *= 2
+
+    def _account_gap(self, site: str, dispatch, enqueue, wait) -> None:
+        """The gap before a dispatch that went through: the host seconds
+        in which no program was enqueued, from the return of the previous
+        dispatch's ``serve.dispatch.wait`` to the return of this one's
+        ``serve.dispatch.enqueue``, on the stamps those spans and
+        ``serve.step`` took. Its parts: ``enqueue`` (this enqueue span),
+        ``caller`` (what lay outside every ``serve.step``: the loop that
+        calls ``step``) and ``sched`` (the rest: a retried attempt and
+        its backoff with it). A gap after an empty engine is a pause:
+        it goes to ``serving_engine_empty_seconds_total`` alone."""
+        prev_t, self._gap_prev_t = self._gap_prev_t, wait.t1
+        caller, self._gap_caller = self._gap_caller, 0.0
+        empty, self._gap_empty = self._gap_empty, False
+        self._gap_prev_site = site
+        self._c_wait.inc(wait.dur)
+        if prev_t is None:
+            return
+        gap = enqueue.t1 - prev_t
+        dispatch.set(gap_us=round(gap * 1e6), caller_us=round(caller * 1e6))
+        if empty:
+            self._c_empty.inc(gap)
+            return
+        self._h_gap.observe(gap)
+        self._c_gap_enqueue.inc(enqueue.dur)
+        self._c_gap_caller.inc(caller)
+        self._c_gap_sched.inc(gap - enqueue.dur - caller)
 
     def read_expert_counters(self) -> Dict[str, Dict[str, float]]:
         """Pull the expert-share layers' counters from the device (they
@@ -2173,14 +2235,6 @@ class ServingEngine:
             finished=list(self.finished),
             pending=self.pending_snapshot(),
             stats=dict(self.stats))
-
-    def device_time_snapshot(self) -> float:
-        """Monotonic snapshot of cumulative device dispatch+harvest wall
-        seconds. ``device_time_s`` accumulates for the engine's whole
-        lifetime; a bench timing one drive among many must take a
-        before/after delta of THIS value instead of reading the raw
-        accumulator (tools/infer_bench.py min-of-k loops)."""
-        return float(self.device_time_s)
 
     def capture_profile(self, steps: int, outdir: str,
                         now: Optional[float] = None) -> str:

@@ -35,7 +35,9 @@ docs/OBSERVABILITY.md). Entering a span also enters a
 ``jax.profiler.TraceAnnotation`` of the same name, so whenever a
 profiler trace is running the span sits in the ``.xplane.pb`` on the
 profiler's clock beside the device events, and in the ring on
-``perf_counter`` otherwise. A span's self time is its duration minus its
+``perf_counter`` otherwise. The annotation carries the span id (``sid``)
+beside the counts given at construction, so a reader of the trace finds
+an event's ring record, and with it the counts ``set()`` at exit. A span's self time is its duration minus its
 children's (:func:`span_self_times`); the Chrome export draws spans as
 nested slices on the scheduler lane.
 """
@@ -100,7 +102,8 @@ class _Span:
         self._idx = tr._n
         tr._buf[tr._n % tr.capacity] = None
         tr._n += 1
-        self._ann = jax.profiler.TraceAnnotation(self.name, **self.counts)
+        self._ann = jax.profiler.TraceAnnotation(self.name, sid=self.sid,
+                                                 **self.counts)
         self._ann.__enter__()
         self.t0 = tr._clock()
         return self

@@ -13,9 +13,7 @@ Layers:
      fleet draining a killed replica onto survivors;
   3. off-mode — telemetry off is bit-identical with zero recompiles
      and registers none of the cost metrics;
-  4. device-time snapshot/delta regression — reusing one engine for a
-     second drive must not double-bill the first drive's device time;
-  5. flight recorder — the chaos-induced DegradedError writes a
+  4. flight recorder — the chaos-induced DegradedError writes a
      versioned, CRC-stamped artifact from which tools/postmortem.py
      reconstructs the request timeline, fired faults and per-tenant
      cost summary with ZERO live objects.
@@ -332,34 +330,6 @@ def test_cost_accounting_knob_without_telemetry(eng):
     assert srv.costs.enabled
     _assert_conserved(srv)
     assert srv.metrics.counter("serving_flops_total").value > 0
-
-
-# ---------------------------------------------------------------------------
-# device-time snapshot/delta regression
-# ---------------------------------------------------------------------------
-
-def test_device_time_snapshot_delta_not_double_billed(eng):
-    """``device_time_s`` accumulates for the engine's lifetime; the
-    satellite fix is the snapshot/delta idiom — a second drive on the
-    SAME engine must be billable as its own delta, not the running
-    total (which double-bills drive one, the old infer_bench min-of-k
-    bug)."""
-    p, = prompts_of((8,), seed=4)
-    srv = ServingEngine(eng, num_slots=2, block_size=4, num_blocks=24,
-                        prefill_chunk=8, spec_decode=False)
-    d0 = srv.device_time_snapshot()
-    assert d0 == 0.0
-    srv.run([ServeRequest(rid="a", prompt=p.copy(), max_new_tokens=6)])
-    d1 = srv.device_time_snapshot()
-    srv.run([ServeRequest(rid="b", prompt=p.copy(), max_new_tokens=6)])
-    d2 = srv.device_time_snapshot()
-    assert 0 < d1 < d2                      # monotonic accumulator
-    delta2 = d2 - d1
-    assert delta2 > 0
-    # the regression: billing drive two the running total would claim
-    # strictly more device time than the drive used
-    assert delta2 < d2
-    assert srv.device_time_s == d2          # snapshot IS the accumulator
 
 
 # ---------------------------------------------------------------------------

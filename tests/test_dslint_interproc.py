@@ -18,6 +18,7 @@ import ast
 import json
 import subprocess
 import sys
+import time
 
 import pytest
 
@@ -495,17 +496,22 @@ def test_cli_rules_filter_reaches_interproc():
 
 def test_two_phase_self_scan_zero_new_findings():
     stats = {}
+    cpu0 = time.process_time()
     findings = analyze_package(
         [str(REPO_ROOT / "deepspeed_tpu"), str(REPO_ROOT / "tools"),
          str(REPO_ROOT / "tests")], stats=stats)
+    cpu_s = time.process_time() - cpu0
     new, _ = apply_baseline(findings, load_baseline())
     assert new == [], "non-baselined dslint findings:\n" + "\n".join(
         f.format() for f in new)
     # the acceptance budget scales with the tree (a fixed wall-clock
     # cap flakes as the repo grows and with machine load): 100ms of
     # CPU per scanned file keeps the lint interactive — the original
-    # 10s cap at ~150 files, carried forward per-file
-    assert stats["total_s"] < 0.1 * stats["files"], stats
+    # 10s cap at ~150 files, carried forward per-file. CPU seconds of
+    # this process, as the budget says: the scan's wall clock
+    # (`total_s`, 16 s alone) passed 23 s beside five other workers
+    # on the driver's machine and failed PR 37's first run
+    assert cpu_s < 0.1 * stats["files"], (cpu_s, stats)
 
 
 def test_interproc_catalog_complete():

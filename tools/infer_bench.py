@@ -229,8 +229,9 @@ def bench_serving(name, preset=None, num_requests=16, mean_gap_steps=2.0,
     ``decode_horizon`` pins the fused multi-step decode horizon N
     (None = ``DS_DECODE_HORIZON``, docs/MULTISTEP.md); rows split
     ``ms_per_token`` into ``host_ms_per_token`` vs
-    ``device_ms_per_token`` (device = wall seconds the engine spent
-    inside device dispatch + harvest, host = the scheduler-loop rest)
+    ``device_ms_per_token`` (device = the seconds the engine was blocked
+    on a program, its ``serving_dispatch_wait_seconds_total``; host =
+    the rest of the wall time: enqueue, pull and the scheduler's loop)
     so the ~N× host amortization is visible even on CPU.
 
     ``temperature``/``top_p`` > defaults turn the drive into a SAMPLED
@@ -321,11 +322,11 @@ def bench_serving(name, preset=None, num_requests=16, mean_gap_steps=2.0,
     w.run([ServeRequest(rid="w", prompt=reqs[0].prompt.copy(),
                         max_new_tokens=2)])
 
-    # device-time via the snapshot/delta idiom: device_time_s is a
-    # monotonic accumulator over the engine's lifetime, so a drive must
-    # bill itself the DELTA, not the running total — reusing one engine
-    # for k repeats would otherwise double-bill every repeat
-    dev0 = srv.device_time_snapshot()
+    # device time is the registry's count of the seconds blocked in
+    # serve.dispatch.wait (telemetry is on): a counter over the engine's
+    # lifetime, so a drive bills itself the DELTA
+    waited = srv.metrics.counter("serving_dispatch_wait_seconds_total")
+    dev0 = waited.value
     t0 = time.perf_counter()
     step = 0
     nxt = 0
@@ -336,7 +337,7 @@ def bench_serving(name, preset=None, num_requests=16, mean_gap_steps=2.0,
         srv.step(now=time.perf_counter())
         step += 1
     wall_s = time.perf_counter() - t0
-    device_s = srv.device_time_snapshot() - dev0
+    device_s = waited.value - dev0
 
     ttft_h = srv.metrics.histogram("serving_ttft")
     tpot_h = srv.metrics.histogram("serving_tpot")
@@ -451,7 +452,7 @@ def bench_serving(name, preset=None, num_requests=16, mean_gap_steps=2.0,
         "ms_per_token": round(tpot_h.sum / tpot_h.count * 1e3, 3)
         if tpot_h.count else 0.0,
         # host/device wall split (docs/MULTISTEP.md): device is the
-        # wall time spent inside device dispatch + harvest pulls, host
+        # wall time blocked on a program (serve.dispatch.wait), host
         # is everything else the scheduler loop did — the horizon
         # amortizes the host share ~N×
         "decode_horizon": srv.decode_horizon,
