@@ -261,6 +261,60 @@ def _block_extend(x, k_cache, v_cache, pos, p, cfg: GPTConfig):
     return x + _ffn(h, p, cfg), k_cache, v_cache
 
 
+# A table that is looked up by row keeps whole rows of 128 lanes. The TPU
+# stores a ``[V, d]`` bf16 parameter in the layout that pads less: for
+# ``d % 128 != 0`` (GPT-2 XL's 1,600 is 12.5 x 128) that is COLUMN-major,
+# ``{0,1:T(8,128)(2,1)}``, which the tied head's product reads as it is
+# and a row gather cannot, so every program that looks a token up first
+# copied the whole table into the row-major layout, on every dispatch
+# (161 MB read and 166 MB written, 0.5 ms of a 7.6 ms decode step on a
+# v5e: PERF.md, PR 39). With ``d`` a multiple of 128 (or padded to one)
+# the table is stored row-major and both uses read that one layout. The
+# engine therefore pads such a table ONCE, where it places the parameters
+# (whole_lane_tables), with zero lanes that :func:`table_lanes` cuts off
+# again before anything reads them: the mathematics is the unpadded
+# table's to the bit. ``param_copy_bytes`` (telemetry/costs.py) is the
+# check on a compiled program: 0 where no parameter is re-laid.
+_LANES = 128
+
+
+def whole_lane_tables(params, pspecs=None):
+    """``params`` as the engine stores them: the looked-up tables (``wte``,
+    ``wpe``; ``[rows, d]``) with zero lanes behind each row up to the next
+    multiple of 128, unless a partition rule cuts a table's lanes over the
+    mesh (``pspecs``, the parameters' PartitionSpecs; none does today):
+    padding would move the shards' boundaries, and the table stays as it
+    is. Returns (params, what was done, for the engine's log line: empty
+    where every table already has whole lanes, and ``params`` is then the
+    tree it was handed)."""
+    note = ""
+    for name in ("wte", "wpe"):
+        table = params.get(name, {}).get("embedding")
+        if table is None or table.shape[-1] % _LANES == 0:
+            continue
+        d = table.shape[-1]
+        if pspecs is not None and pspecs[name]["embedding"][-1] is not None:
+            note += f", {name} lanes {d} left as they are: cut over the mesh"
+            continue
+        table = jnp.pad(table, ((0, 0), (0, -d % _LANES)))
+        params = {**params, name: {"embedding": table}}
+        note += f", {name} lanes {d}->{table.shape[-1]}"
+    return params, note
+
+
+def table_lanes(rows, d: int):
+    """The first ``d`` lanes of ``rows`` taken from a stored table (the
+    table itself included): what the unpadded table holds there. For a
+    table that was never padded this is ``rows``, and traces nothing."""
+    return rows if rows.shape[-1] == d else rows[..., :d]
+
+
+def tied_logits(x, table):
+    """``x @ wte.T`` against a stored ``wte``: ``x``'s lanes are the
+    unpadded table's."""
+    return x @ table_lanes(table, x.shape[-1]).T
+
+
 def _named(fn, name: str):
     """``fn`` under an explicit ``__name__``: jax names a compiled module
     ``jit_<name>``, and profiles, the persistent cache's entries and the
@@ -867,6 +921,7 @@ class InferenceEngine:
             rules = []
         pspecs = sharding_lib.param_specs(params, mesh, zero_stage=0,
                                           rules=rules)
+        params, tables = whole_lane_tables(params, pspecs)
         self.params = jax.device_put(
             params, sharding_lib.to_named(pspecs, mesh))
 
@@ -961,16 +1016,27 @@ class InferenceEngine:
                  f"dtype={jnp.dtype(dtype).name} "
                  f"{'encoder' if self.is_encoder else 'decoder'}, "
                  f"platform={dev0.platform}, devices={mesh.devices.size}, "
-                 f"decode_impl={self.decode_impl}", ranks=[0])
+                 f"decode_impl={self.decode_impl}{tables}", ranks=[0])
 
     # ------------------------------------------------------------------
     # params are threaded explicitly (never via self) so jit treats the
     # weights as arguments, not baked-in constants
+    # the two tables are read through _wte / _wpe / tied_logits alone: the
+    # engine may hold them with padded lanes (whole_lane_tables)
+    def _wte(self, params, tokens):
+        return table_lanes(params["wte"]["embedding"][tokens],
+                           self.cfg.d_model)
+
+    def _wpe(self, params, positions):
+        """``positions``: an index array or a slice."""
+        return table_lanes(params["wpe"]["embedding"][positions],
+                           self.cfg.d_model)
+
     def _embed(self, params, tokens):
         S = tokens.shape[1]
-        x = params["wte"]["embedding"][tokens]
+        x = self._wte(params, tokens)
         if self.cfg.use_wpe:
-            x = x + params["wpe"]["embedding"][:S][None]
+            x = x + self._wpe(params, slice(None, S))[None]
         return x
 
     def _logits(self, params, x):
@@ -978,7 +1044,7 @@ class InferenceEngine:
         with jax.named_scope("logits"):
             x = _norm(x, params["ln_f"], self.cfg)
             if self.cfg.tie_embeddings:
-                return x @ params["wte"]["embedding"].T
+                return tied_logits(x, params["wte"]["embedding"])
             logits = x @ _kernel_of(params["lm_head"], x.dtype)
             if "bias" in params["lm_head"]:
                 logits = logits + params["lm_head"]["bias"]
@@ -1001,9 +1067,9 @@ class InferenceEngine:
             positions = jnp.clip(
                 jnp.cumsum(attn_mask.astype(jnp.int32), axis=1) - 1,
                 0, None)
-            x = params["wte"]["embedding"][tokens]
+            x = self._wte(params, tokens)
             if cfg.use_wpe:
-                x = x + params["wpe"]["embedding"][positions]
+                x = x + self._wpe(params, positions)
 
         def body(x, layer_p):
             y, k, v = _block_prefill(x, layer_p, cfg, kv_mask=attn_mask,
@@ -1027,13 +1093,13 @@ class InferenceEngine:
         row_pos: optional [B] per-row LOGICAL positions (left-padded
         batches, where real lengths differ from the cache index)."""
         cfg = self.cfg
-        x = params["wte"]["embedding"][token]
+        x = self._wte(params, token)
         if cfg.use_wpe:
-            wpe = params["wpe"]["embedding"]
             if row_pos is not None:
-                x = x + wpe[row_pos][:, None]
+                x = x + self._wpe(params, row_pos)[:, None]
             else:
-                x = x + jax.lax.dynamic_slice_in_dim(wpe, pos, 1)[None]
+                x = x + table_lanes(jax.lax.dynamic_slice_in_dim(
+                    params["wpe"]["embedding"], pos, 1), cfg.d_model)[None]
         cache_mask = cache.get("mask")
 
         def body(x, layer):
@@ -1085,10 +1151,10 @@ class InferenceEngine:
         C = tokens.shape[0]
         positions = start + jnp.arange(C, dtype=jnp.int32)
         with jax.named_scope("embed"):
-            x = params["wte"]["embedding"][tokens][None]
+            x = self._wte(params, tokens)[None]
             if cfg.use_wpe:
                 safe = jnp.clip(positions, 0, self.max_seq_len - 1)
-                x = x + params["wpe"]["embedding"][safe][None]
+                x = x + self._wpe(params, safe)[None]
         if hybrid.is_hybrid(cfg):
             def hblock(carry, flat, layer_p, base, lora, experts):
                 return hybrid.block_prefill(
@@ -1145,10 +1211,10 @@ class InferenceEngine:
         cfg = self.cfg
         pools = (k_pool, v_pool) + (scales or ())
         with jax.named_scope("embed"):
-            x = params["wte"]["embedding"][tokens[:, None]]
+            x = self._wte(params, tokens[:, None])
             if cfg.use_wpe:
                 safe = jnp.clip(lengths, 0, self.max_seq_len - 1)
-                x = x + params["wpe"]["embedding"][safe][:, None]
+                x = x + self._wpe(params, safe)[:, None]
         if hybrid.is_hybrid(cfg):
             plans = hybrid.decode_plans(cfg, pools[0].full.shape[2], tables,
                                         lengths, active)
@@ -1279,11 +1345,11 @@ class InferenceEngine:
         cfg = self.cfg
         pools = (k_pool, v_pool) + (scales or ())
         B, G = tokens.shape
-        x = params["wte"]["embedding"][tokens]
+        x = self._wte(params, tokens)
         if cfg.use_wpe:
             pos = lengths[:, None] + jnp.arange(G, dtype=jnp.int32)[None]
             safe = jnp.clip(pos, 0, self.max_seq_len - 1)
-            x = x + params["wpe"]["embedding"][safe]
+            x = x + self._wpe(params, safe)
 
         plan = _paged_plan(pools, tables, lengths, active, cfg, q_len=G)
 
@@ -1303,11 +1369,11 @@ class InferenceEngine:
         The paged counterpart is _verify_slots_fn."""
         cfg = self.cfg
 
-        x = params["wte"]["embedding"][tokens]
+        x = self._wte(params, tokens)
         if cfg.use_wpe:
             G = tokens.shape[1]
-            x = x + jax.lax.dynamic_slice_in_dim(
-                params["wpe"]["embedding"], pos, G)[None]
+            x = x + table_lanes(jax.lax.dynamic_slice_in_dim(
+                params["wpe"]["embedding"], pos, G), cfg.d_model)[None]
 
         def body(x, layer):
             layer_p, kc, vc = layer
@@ -1360,10 +1426,10 @@ class InferenceEngine:
         def step(carry, i):
             tok, lens, live, produced, seen_c, tail_c, pools = carry
             lane_active = jnp.logical_and(active, live)
-            x = params["wte"]["embedding"][tok[:, None]]
+            x = self._wte(params, tok[:, None])
             if cfg.use_wpe:
                 safe = jnp.clip(lens, 0, self.max_seq_len - 1)
-                x = x + params["wpe"]["embedding"][safe][:, None]
+                x = x + self._wpe(params, safe)[:, None]
 
             plan = _paged_plan(pools, tables, lens, lane_active, cfg)
 
@@ -1474,7 +1540,9 @@ class InferenceEngine:
         table is of what is loaded. The table also says whether the
         paged pool's one layout held in that executable:
         ``pool_copy_bytes``, logged here once per program, is 0 when no
-        ``copy`` of a pool-shaped value was compiled in. A caller whose
+        ``copy`` of a pool-shaped value was compiled in, and
+        ``param_copy_bytes`` beside it 0 when none of a weight was
+        (whole_lane_tables). A caller whose
         program attends through the ``paged_decode`` kernel gives its
         block tables' shape as ``kernel_table``, and the entry records
         how the kernel's grid is cut (``paged_blocks_per_step``,
@@ -1508,7 +1576,9 @@ class InferenceEngine:
             copied = sink.add_provenance(
                 pid, program.lower(*args).compile().as_text(),
                 pool_blocks=(N, L * N), paged_grid=grid)
-            log_dist(f"serving program {pid}: pool_copy_bytes={copied}"
+            log_dist(f"serving program {pid}: pool_copy_bytes={copied} "
+                     f"param_copy_bytes="
+                     f"{sink.entries[pid]['param_copy_bytes']}"
                      + (" paged_blocks_per_step={} paged_grid_steps={}"
                         .format(*grid) if grid else ""), ranks=[0])
         return program(*args)

@@ -45,7 +45,7 @@ from deepspeed_tpu.utils.jit_registry import (DISPATCH_CLASSES,
 
 __all__ = ["PEAK_FLOPS", "PEAK_HBM_BYTES_PER_S", "device_peak_flops",
            "device_peak_hbm_bytes_per_s", "parse_provenance",
-           "pool_copy_bytes", "shape_dims",
+           "pool_copy_bytes", "param_copy_bytes", "shape_dims",
            "matmul_params",
            "model_flops_per_token", "attn_flops", "infer_flops",
            "infer_hbm_bytes", "weight_bytes", "split_even",
@@ -292,8 +292,8 @@ def _nearest_named(name: str, users: Dict[str, List[str]],
 
 def parse_provenance(hlo_text: str) -> Dict[str, Dict]:
     """instruction name -> ``{"opcode", "shape", "scope", "op",
-    "source", "program"?, "inferred"?}`` from a compiled module's text
-    (``compiled.as_text()``).
+    "source", "param"?, "program"?, "inferred"?}`` from a compiled module's
+    text (``compiled.as_text()``).
 
     ``shape`` is the result shape with its layout as printed; ``scope``
     the ``jax.named_scope`` path out of ``metadata={op_name=...}``
@@ -302,7 +302,10 @@ def parse_provenance(hlo_text: str) -> Dict[str, Dict]:
     ``source`` is ``file:line`` of the innermost frame, from inline
     ``source_file``/``source_line`` or from the module's stack-frame
     tables. A fusion takes the metadata of its fused computation's root
-    when it has none of its own. An instruction the compiler inserted
+    when it has none of its own. ``param``, on an instruction whose first
+    operand is a parameter of the entry computation, is that argument's
+    path as jax names it (``params['wte']['embedding']``). An instruction
+    the compiler inserted
     itself (a layout-changing ``copy``) may carry no metadata: it takes
     the scope of the nearest instruction that has some (users first,
     then its operand's producer) and is marked ``"inferred": true``.
@@ -398,6 +401,11 @@ def parse_provenance(hlo_text: str) -> Dict[str, Dict]:
                                   "shape": ins["shape"], "scope": scope,
                                   "op": op, "source": source_of(meta)}
         operand = {ins["name"]: ins["operands"][:1] for ins in instrs}
+        args = {ins["name"]: _attr(ins["meta"], "op_name") for ins in instrs
+                if ins["opcode"] == "parameter"}
+        for name, first in operand.items():
+            if first and args.get(first[0]):
+                local[name]["param"] = args[first[0]].replace("\\", "")
         named = {n for n, e in local.items() if e["op"] or e["scope"]}
         for ins in instrs:
             if ins["name"] in named:
@@ -421,6 +429,12 @@ def shape_dims(shape: str) -> Tuple[str, Tuple[int, ...]]:
     return m.group(1), tuple(int(d) for d in m.group(2).split(",") if d)
 
 
+def _shape_bytes(shape: str) -> int:
+    dtype, dims = shape_dims(shape)
+    bits = re.search(r"\d+", dtype)                 # pred has none: a byte
+    return math.prod(dims) * (int(bits.group()) // 8 if bits else 1)
+
+
 def pool_copy_bytes(instructions: Dict[str, Dict],
                     pool_blocks: Sequence[int]) -> int:
     """Bytes of the ``copy`` instructions of a compiled serving program
@@ -432,14 +446,26 @@ def pool_copy_bytes(instructions: Dict[str, Dict],
     kernel (inference/paged_cache.py). ``instructions`` is
     :func:`parse_provenance`'s table."""
     blocks = {int(n) for n in pool_blocks}
-    total = 0
-    for ins in instructions.values():
-        dtype, dims = shape_dims(ins["shape"])
-        if ins["opcode"] == "copy" and blocks.intersection(dims):
-            bits = re.search(r"\d+", dtype)       # pred has none: a byte
-            total += math.prod(dims) * (int(bits.group()) // 8
-                                        if bits else 1)
-    return total
+    return sum(_shape_bytes(ins["shape"]) for ins in instructions.values()
+               if ins["opcode"] == "copy"
+               and blocks.intersection(shape_dims(ins["shape"])[1]))
+
+
+def param_copy_bytes(instructions: Dict[str, Dict]) -> int:
+    """Bytes of the ``copy`` instructions of a compiled serving program
+    that re-lay a weight: the operand is an entry parameter under the
+    program's argument ``params`` (``param``), or the copy carries such
+    a parameter's name as its own (the compiler hands it on when the
+    parameter reaches the copy through a prefetch: ``op``). Like a pool's
+    copy it runs on EVERY dispatch. 0 is the healthy value: every weight
+    is then read in the layout it is stored in. What read 164 MB a
+    program until PR 39 was GPT-2 XL's two embedding tables, stored
+    column-major because 1,600 lanes are not whole tiles of 128 and copied
+    for the row gather (inference/engine.py ``whole_lane_tables``).
+    ``instructions`` is :func:`parse_provenance`'s table."""
+    return sum(_shape_bytes(ins["shape"]) for ins in instructions.values()
+               if ins["opcode"] == "copy" and (
+                   ins.get("param") or ins.get("op", "")).startswith("params["))
 
 
 def provenance_module_name(hlo_text: str) -> str:
@@ -557,8 +583,10 @@ class ProgramCostRegistry:
         """Keep program ``pid``'s provenance table, parsed from the text
         of the executable that is actually loaded (a program restored
         from jax's persistent cache carries the metadata it was first
-        compiled with: the cache does not key on it). With the paged
-        pool's block counts the program's entry also gains
+        compiled with: the cache does not key on it). The program's
+        entry gains ``param_copy_bytes`` (:func:`param_copy_bytes`; the
+        gauge ``program_param_copy_bytes_<pid>``), and with the paged
+        pool's block counts also
         ``pool_copy_bytes`` (:func:`pool_copy_bytes`; also the gauge
         ``program_pool_copy_bytes_<pid>`` once :meth:`export_gauges`
         has been given a registry), which is returned. ``paged_grid``
@@ -573,16 +601,20 @@ class ProgramCostRegistry:
             "module": provenance_module_name(hlo_text),
             "instructions": instructions}
         copied = pool_copy_bytes(instructions, pool_blocks)
+        entry = self.entries.setdefault(pid, {"program": pid})
+        entry["param_copy_bytes"] = param_copy_bytes(instructions)
+        if self.metrics is not None:
+            self.metrics.gauge(f"program_param_copy_bytes_{pid}").set(
+                entry["param_copy_bytes"])
         if pool_blocks:
-            self.entries.setdefault(pid, {"program": pid})[
-                "pool_copy_bytes"] = copied
+            entry["pool_copy_bytes"] = copied
             if self.metrics is not None:
                 self.metrics.gauge(
                     f"program_pool_copy_bytes_{pid}").set(copied)
         if paged_grid:
             per_step, steps = paged_grid
-            self.entries.setdefault(pid, {"program": pid}).update(
-                paged_blocks_per_step=per_step, paged_grid_steps=steps)
+            entry.update(paged_blocks_per_step=per_step,
+                         paged_grid_steps=steps)
             if self.metrics is not None:
                 self.metrics.gauge(
                     f"program_paged_blocks_per_step_{pid}").set(per_step)

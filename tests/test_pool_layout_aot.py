@@ -9,7 +9,11 @@ loop slice a layer's pool out or write it back, and how large are the
 program's temporaries. Before the pools were folded to ``Hkv*Dh`` rows
 and carried through the layer loop, ``serve_decode_slots`` read 5.9 GB of
 pool copies and 6.24 GB of temporaries here, and the chip spent 68-74% of
-its serving time in them (PERF.md, PR 25).
+its serving time in them (PERF.md, PR 25). The same reading for the
+weights (``param_copy_bytes``): until the engine stored its looked-up
+tables with whole rows of 128 lanes, both programs copied the 161 MB token
+embedding and the 3.3 MB positional table on every dispatch (PERF.md,
+PR 39).
 """
 
 import json
@@ -19,10 +23,12 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from deepspeed_tpu.inference.engine import InferenceEngine, _named
+from deepspeed_tpu.inference.engine import (InferenceEngine, _named,
+                                            whole_lane_tables)
 from deepspeed_tpu.models import gpt
 from deepspeed_tpu.ops.attention.paged import blocks_per_step
 from deepspeed_tpu.telemetry.costs import (ProgramCostRegistry,
+                                           param_copy_bytes,
                                            parse_provenance,
                                            pool_copy_bytes, probe_compiled,
                                            shape_dims)
@@ -30,11 +36,11 @@ from deepspeed_tpu.telemetry.costs import (ProgramCostRegistry,
 CELL = json.loads((pathlib.Path(__file__).parent.parent / "benchmark"
                    / "configs" / "gpt2-xl-serve.json").read_text())
 
-# what is left is the token embedding, re-laid once per dispatch for the
-# lookup (161 MB; its stored layout is the logits matmul's), and
-# activations: nothing of the pool's size (one layer's K pool is 56 MB,
-# the stacked pools 5.4 GB)
-TEMP_LIMIT = 256 << 20
+# what is left is activations and the sampler's work on the logits (a
+# [17, 50257] float32 array is 3.4 MB): the decode program needs 16.2 MB,
+# the prefill program 1.0 MB. Nothing of a table's size (161 MB) or the
+# pool's (one layer's K pool is 56 MB, the stacked 5.4 GB)
+TEMP_LIMIT = 32 << 20
 
 
 @pytest.fixture(scope="module")
@@ -69,10 +75,13 @@ def _cell_programs(sharding):
     def S(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
+    # the weights as the engine stores them: bf16, the tables' lanes whole
     params = jax.tree_util.tree_map(
         lambda a: S(a.shape, jnp.bfloat16
                     if jnp.issubdtype(a.dtype, jnp.floating) else a.dtype),
-        jax.eval_shape(lambda: gpt.init_params(jax.random.PRNGKey(0), cfg)))
+        jax.eval_shape(lambda: whole_lane_tables(
+            gpt.init_params(jax.random.PRNGKey(0), cfg))[0]))
+    assert params["wte"]["embedding"].shape == (m["vocab_size"], 1664)
     eng = InferenceEngine.__new__(InferenceEngine)
     eng.cfg, eng.max_seq_len, eng.dtype = cfg, cfg.max_seq_len, jnp.bfloat16
 
@@ -136,6 +145,17 @@ def test_no_copy_of_the_pool_is_compiled_in(compiled_cell, program):
 
 
 @pytest.mark.parametrize("program", ["prefill_slot", "decode_slots"])
+def test_no_weight_is_copied(compiled_cell, program):
+    """Every weight is read in the layout it is stored in: the token
+    embedding by the row gather and by the tied head alike."""
+    _, table = compiled_cell[1][program]
+    assert param_copy_bytes(table) == 0
+    # the reading is not blind: the parameters are there to be named
+    assert any(e.get("param", "").startswith("params['wte']")
+               for e in table.values()), "no user of the embedding found"
+
+
+@pytest.mark.parametrize("program", ["prefill_slot", "decode_slots"])
 def test_no_pool_sized_temporary(compiled_cell, program):
     exe, _ = compiled_cell[1][program]
     mem = probe_compiled(exe)
@@ -196,6 +216,53 @@ def test_pool_copy_bytes_counts_pool_shaped_copies_only():
     assert pool_copy_bytes({}, (1089, 52272)) == 0
 
 
+WTE, WPE = 50257 * 1600 * 2, 1024 * 1600 * 2
+
+
+@pytest.mark.parametrize("entry,counted", [
+    # the operand is an entry parameter under ``params``
+    ({"opcode": "copy", "shape": "bf16[50257,1600]{1,0:T(8,128)(2,1)}",
+      "param": "params['wte']['embedding']", "op": ""}, WTE),
+    # the parameter reached the copy through a prefetch: its name rode along
+    ({"opcode": "copy", "shape": "bf16[1024,1600]{1,0:T(8,128)(2,1)S(1)}",
+      "op": "params[\\'wpe\\'][\\'embedding\\']"}, WPE),
+    # another argument of the program (a pool, an operand) is not a weight
+    ({"opcode": "copy", "shape": "bf16[48,1089,16,1600]{3,2,1,0}",
+      "param": "k_pool", "op": ""}, 0),
+    ({"opcode": "copy", "shape": "s32[]{:T(128)}", "param": "start",
+      "op": ""}, 0),
+    # a copy of an activation
+    ({"opcode": "copy", "shape": "bf16[17,1,1,1600]{3,0,1,2}",
+      "op": "transpose"}, 0),
+    # a weight that is read, not copied
+    ({"opcode": "fusion", "shape": "bf16[17,1,1664]{2,1,0}",
+      "param": "params['wte']['embedding']", "op": "gather"}, 0),
+], ids=["parameter", "named-after-parameter", "pool", "operand", "activation",
+        "read"])
+def test_param_copy_bytes_counts_copies_of_weights_only(entry, counted):
+    assert param_copy_bytes({"x.1": entry}) == counted
+    both = {"x.1": entry, "copy.9": {
+        "opcode": "copy", "shape": "pred[8,2]{1,0}", "op": "",
+        "param": "params['block']['mask']"}}
+    assert param_copy_bytes(both) == counted + 16
+
+
+def test_parse_provenance_names_the_parameter_an_instruction_reads():
+    text = """HloModule jit_serve_decode_slots
+
+ENTRY %main.1 (p0: bf16[64,192], p1: s32[4]) -> bf16[4,192] {
+  %p0 = bf16[64,192]{0,1} parameter(0), metadata={op_name="params[\\'wte\\'][\\'embedding\\']"}
+  %p1 = s32[4]{0} parameter(1), metadata={op_name="tokens"}
+  %copy.1 = bf16[64,192]{1,0} copy(%p0), metadata={op_name="params[\\'wte\\'][\\'embedding\\']"}
+  ROOT %gather.1 = bf16[4,192]{1,0} gather(%copy.1, %p1), metadata={op_name="jit(serve_decode_slots)/embed/gather"}
+}
+"""
+    table = parse_provenance(text)
+    assert table["copy.1"]["param"] == "params['wte']['embedding']"
+    assert "param" not in table["gather.1"]
+    assert param_copy_bytes(table) == 64 * 192 * 2
+
+
 def test_registry_records_pool_copy_bytes_with_the_provenance():
     from deepspeed_tpu.telemetry.metrics import MetricsRegistry
     text = """HloModule jit_serve_decode_slots
@@ -213,6 +280,16 @@ ENTRY %main.1 (p0: bf16[4,9,4,32]) -> bf16[4,9,4,32] {
     assert reg.to_json()["programs"]["decode_slots"]["pool_copy_bytes"] \
         == 9216
     assert metrics.gauge("program_pool_copy_bytes_decode_slots").value == 9216
-    # without the pool's block counts nothing is claimed
+    # p0 is no weight: it is not under the program's ``params``
+    assert reg.entries["decode_slots"]["param_copy_bytes"] == 0
+    # without the pool's block counts nothing is claimed of the pool
     assert reg.add_provenance("cow_blocks", text) == 0
     assert "pool_copy_bytes" not in reg.entries.get("cow_blocks", {})
+    weight = text.replace("parameter(0)", "parameter(0), metadata={"
+                          "op_name=\"params[\\'wte\\'][\\'embedding\\']\"}")
+    reg.add_provenance("prefill_slot", weight, pool_blocks=(9, 36))
+    assert reg.to_json()["programs"]["prefill_slot"]["param_copy_bytes"] \
+        == 9216
+    assert metrics.gauge("program_param_copy_bytes_prefill_slot").value \
+        == 9216
+    assert metrics.gauge("program_param_copy_bytes_decode_slots").value == 0
