@@ -1,0 +1,130 @@
+"""Sizes a ``serve_kimi_linear`` configuration without the chip: compiles both
+serving programs ahead of time for one described TPU v5e (libtpu compiles
+for a topology it is told about) at the configuration's own sizes with
+abstract arguments, and prints, per program, the compiler's
+``memory_analysis`` (arguments, outputs, aliased, temporaries, peak) and
+whether any copy of a value shaped like the latent pool OR like the
+recurrent state was compiled in (both are donated and updated in place: a
+copy of the state alone is 1.7 GB). The configuration's ``serving.sizing``
+entry is this tool's output:
+
+    python3 benchmark/tools/size_kimi_linear.py \\
+        --config benchmark/configs/kimi-linear-48b-a3b-serve-ep16.json \\
+        [--slots 40] [--chunk 512] [--block 512]
+
+Run on the CPU host (``JAX_PLATFORMS=cpu``). Not part of a cell's run."""
+
+import argparse
+import json
+import os
+import sys
+
+os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+os.environ.setdefault("TPU_WORKER_HOSTNAMES", "localhost")
+os.environ.setdefault("TPU_SKIP_MDS_QUERY", "1")
+
+BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, BENCH_DIR)
+sys.path.insert(0, os.path.dirname(BENCH_DIR))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+from harness import cells  # noqa: E402
+
+GIB = float(1 << 30)
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--config", required=True)
+    ap.add_argument("--slots", type=int, default=None)
+    ap.add_argument("--chunk", type=int, default=None)
+    ap.add_argument("--block", type=int, default=None)
+    ap.add_argument("--impl", default="pallas")
+    args = ap.parse_args()
+
+    from jax.experimental import topologies
+    from deepspeed_tpu.inference import linear
+    from deepspeed_tpu.inference.engine import InferenceEngine, _named
+    from deepspeed_tpu.models import kimi_linear
+    from deepspeed_tpu.telemetry.costs import (parse_provenance,
+                                               pool_copy_bytes)
+    dev = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0]
+    sh = jax.sharding.SingleDeviceSharding(dev)
+    conf = json.load(open(args.config))
+    sv = conf["serving"]
+    driver = cells.load_module(os.path.join(
+        BENCH_DIR, "drivers", conf["kind"] + ".py"), "size_driver")
+    cfg = driver.model_config(conf, jnp.bfloat16)
+    B = args.slots or int(sv["num_slots"])
+    C = args.chunk or int(sv["prefill_chunk"])
+    bs = args.block or int(sv["block_size"])
+    NB = -(-cfg.max_seq_len // bs)
+    N = B * NB + 1
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    params = jax.tree_util.tree_map(
+        lambda a: S(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: kimi_linear.init_params(
+            jax.random.PRNGKey(0), cfg)))
+    weight_bytes = sum(a.size * 2 for a in jax.tree_util.tree_leaves(params))
+    Lm, Lk = cfg.n_full_layers, cfg.n_kda_layers
+    H, Dh = cfg.linear_heads, cfg.linear_head_dim
+    state = linear.LinearState(
+        S((Lm, N, bs, cfg.latent_lanes), jnp.bfloat16),
+        S((Lk, B, H, Dh, Dh), jnp.float32),
+        S((Lk, B, (cfg.conv_kernel - 1) * cfg.kda_channels), jnp.bfloat16))
+    eng = InferenceEngine.__new__(InferenceEngine)
+    eng.cfg, eng.max_seq_len, eng.dtype = cfg, cfg.max_seq_len, jnp.bfloat16
+    eng.decode_impl = args.impl
+    i32, f32, u32, V = jnp.int32, jnp.float32, jnp.uint32, cfg.vocab_size
+    prefill = jax.jit(_named(eng._prefill_slot_fn, "serve_prefill_slot"),
+                      donate_argnums=(1, 2))
+    decode = jax.jit(_named(eng._decode_slots_fn, "serve_decode_slots"),
+                     donate_argnums=(1, 2), static_argnums=(7,))
+    programs = [
+        ("prefill_slot", prefill,
+         (params, state, None, S((NB,), i32), S((C,), i32), S((), i32),
+          S((), i32), S((2,), u32), S((), i32), S((), f32), S((), i32),
+          S((), f32), S((), f32), S((V,), jnp.bool_), None, None,
+          S((), i32))),
+        ("decode_slots", decode,
+         (params, state, None, S((B, NB), i32), S((B,), i32),
+          S((B,), i32), S((B,), jnp.bool_), args.impl, S((B, 2), u32),
+          S((B,), i32), S((B,), f32), S((B,), i32), S((B,), f32),
+          S((B,), f32), S((B, V), jnp.bool_)))]
+    print(json.dumps({"slots": B, "chunk": C, "block": bs,
+                      "weights_gib": weight_bytes / GIB,
+                      "parameters": weight_bytes // 2,
+                      "latent_pool_gib": 2 * state.rows.size / GIB,
+                      "recurrent_state_gib": 4 * state.state.size / GIB,
+                      "conv_tails_gib": 2 * state.tail.size / GIB}))
+    for name, fn, a in programs:
+        exe = fn.trace(*a).lower(lowering_platforms=("tpu",)).compile()
+        m = exe.memory_analysis()
+        text = exe.as_text()
+        table = parse_provenance(text)
+        print(json.dumps({
+            "program": name,
+            "argument_gib": m.argument_size_in_bytes / GIB,
+            "output_gib": m.output_size_in_bytes / GIB,
+            "alias_gib": m.alias_size_in_bytes / GIB,
+            "temp_gib": m.temp_size_in_bytes / GIB,
+            "peak_gib": (m.argument_size_in_bytes + m.output_size_in_bytes
+                         - m.alias_size_in_bytes + m.temp_size_in_bytes)
+            / GIB,
+            "pool_copy_bytes": pool_copy_bytes(table, (N, Lm * N)),
+            # the state's leading dimensions: slots of one layer (where
+            # they differ from the heads), of all layers
+            "state_copy_bytes": pool_copy_bytes(
+                table, {Lk * B} | ({B} - {H})),
+            "kernels_in_program": [k for k in ("mla_decode", "kda_step")
+                                   if k in text]}))
+
+
+if __name__ == "__main__":
+    main()

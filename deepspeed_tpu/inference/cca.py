@@ -1,7 +1,8 @@
 """Paged serving blocks for a model whose attention runs inside a
-compressed latent with convolutions over time (CCA; models/zaya.py): a
-FOURTH dialect, and the first whose cache state is not only blocks of keys
-and values. What a layer attends is plain grouped-query attention over K
+compressed latent with convolutions over time (CCA; models/zaya.py): the
+fourth of the five dialects (paged_cache.refuse lists them), and the first
+whose cache state is not only blocks of keys and values. What a layer
+attends is plain grouped-query attention over K
 and V pools ``[L, N, block, Hkv * Dh]`` behind the slot's block table, the
 GPT blocks' layout, allocator, ``decode_plan`` and ``paged_decode`` kernel.
 But the key and value ROWS are made from more than the token itself: two

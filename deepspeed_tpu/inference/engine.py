@@ -32,8 +32,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from deepspeed_tpu.inference import (cca, hybrid, latent, paged_cache,
-                                     sampling)
+from deepspeed_tpu.inference import (cca, hybrid, latent, linear,
+                                     paged_cache, sampling)
 from deepspeed_tpu.inference.hybrid import _heads, _rows, causal_band
 from deepspeed_tpu.models import gpt as gpt_lib
 from deepspeed_tpu.moe import expert_share
@@ -985,11 +985,11 @@ class InferenceEngine:
             self._scatter_block = jax.jit(
                 partial(paged_cache.scatter_block), donate_argnums=(0,))
         if hybrid.is_hybrid(config) or latent.is_latent(config) \
-                or cca.is_cca(config):
-            # two kinds of attention state, a latent pool, or per-slot
-            # tails beside the pools: only the two paged serving programs
-            # know them. Everything else raises by name rather than grow
-            # a copy of the dialect (ROADMAP D4)
+                or cca.is_cca(config) or linear.is_linear(config):
+            # two kinds of attention state, a latent pool, per-slot tails
+            # or a recurrent state beside the pools: only the two paged
+            # serving programs know them. Everything else raises by name
+            # rather than grow a copy of the dialect (ROADMAP D4)
             def refuse(what, *a, **k):
                 paged_cache.refuse(config, what)
             if mp_size > 1:
@@ -1161,6 +1161,11 @@ class InferenceEngine:
                     carry, flat, table_row, positions, n_valid, layer_p,
                     cfg, base, self.decode_impl, experts)
             x, pools = self._hybrid_layers(params, pools, hblock, x, 0)
+        elif linear.is_linear(cfg):
+            x, pools = self._linear_layers(
+                params, pools, linear.prefill_attends(
+                    cfg, table_row, positions, n_valid, slot), x,
+                jnp.arange(C) < n_valid, self.decode_impl, 0)
         elif latent.is_latent(cfg):
             def lblock(carry, flat, layer_p, base, lora, experts):
                 return latent.block_prefill(
@@ -1224,6 +1229,13 @@ class InferenceEngine:
                     carry, flat, tables, lengths, active, layer_p, cfg,
                     base, impl, experts, plans)
             x, pools = self._hybrid_layers(params, pools, hblock, x, 1)
+        elif linear.is_linear(cfg):
+            plan = decode_plan(lengths, tables.shape[1],
+                               pools[0].rows.shape[2], active=active)
+            x, pools = self._linear_layers(
+                params, pools, linear.decode_attends(
+                    cfg, tables, lengths, active, impl, plan), x, active,
+                impl, 1)
         elif latent.is_latent(cfg):
             plan = decode_plan(lengths, tables.shape[1],
                                pools[0].rows.shape[2], active=active)
@@ -1296,6 +1308,44 @@ class InferenceEngine:
             block, x, state.stats, phase)
         return x, (cca.CCAState(k, tail, vtail, stats, route), v)
 
+    def _linear_layers(self, params, pools, attends, x, valid, impl: str,
+                       phase: int):
+        """The layers of both serving programs for a model whose layers are
+        linear attention or latent attention by a list in its config
+        (inference/linear.py ``run_layers``, whose loop this feeds):
+        ``pools`` = (LinearState, None), the latent layers' pool, the
+        linear layers' recurrent state and their convolution tails side by
+        side in the carry, each stacked over its OWN kind's layers;
+        ``attends``: the two attention sublayers; ``valid`` ``[T]``: the
+        rows that are tokens. ``x`` ``[1, C, d]`` or ``[B, 1, d]``."""
+        from deepspeed_tpu.models.kimi_linear import layer_bases
+        cfg = self.cfg
+        st, none = pools
+        shapes = (st.rows, st.state, st.tail)
+        flat = tuple(p.reshape((-1,) + p.shape[2:]) for p in shapes)
+        params, experts = hybrid.split_experts(params)
+        T = x.shape[0] * x.shape[1]
+        (y, aux), flat = linear.run_layers(
+            cfg, params, experts,
+            (x.reshape(T, -1), self._dispatch_record(T, st.stats)), flat,
+            layer_bases(cfg, st.rows.shape[1], st.state.shape[1]), attends,
+            valid, impl)
+        stats = st.stats
+        if stats is not None:
+            stats = stats.at[phase].add(aux["stats"])
+        rows, state, tail = (f.reshape(p.shape) for f, p in zip(flat, shapes))
+        return y.reshape(x.shape), (linear.LinearState(
+            rows, state, tail, stats, aux["route"]), none)
+
+    def _dispatch_record(self, tokens: int, stats):
+        """What a sparse model's layer loop carries beside ``x``: the
+        dispatch's routing record ``[L_sparse, tokens, k]`` and, with
+        ``stats`` (telemetry on), this dispatch's expert-layer counters."""
+        cfg = self.cfg
+        return {"route": jnp.zeros((cfg.n_sparse_layers, tokens, cfg.moe_k),
+                                   jnp.int32),
+                "stats": None if stats is None else jnp.zeros_like(stats[0])}
+
     def _dense_then_sparse(self, params, flat, bases, block, x, stats,
                            phase: int):
         """The leading dense layers and then the sparse layers, each ONE
@@ -1312,9 +1362,7 @@ class InferenceEngine:
         params, experts = hybrid.split_experts(params)
         block = functools.partial(block, experts=experts)
         dense_b, sparse_b = bases
-        aux = {"route": jnp.zeros((cfg.n_sparse_layers, x.shape[0] * x.shape[1],
-                                   cfg.moe_k), jnp.int32),
-               "stats": None if stats is None else jnp.zeros_like(stats[0])}
+        aux = self._dispatch_record(x.shape[0] * x.shape[1], stats)
         if expert_share.has_router_state(cfg):
             aux["r"] = jnp.zeros((x.shape[0] * x.shape[1],
                                   cfg.router_hidden), jnp.float32)
@@ -1552,7 +1600,7 @@ class InferenceEngine:
             from deepspeed_tpu.utils.faults import maybe_fire
             maybe_fire("cache.quantize")
         if isinstance(k_pool, (hybrid.PagedState, latent.LatentState,
-                               cca.CCAState)):
+                               cca.CCAState, linear.LinearState)):
             k_pool = k_pool._replace(route=None)    # an output only
         if lora is not None:
             parts = (*parts, ("lora", lora[2]))
@@ -1602,11 +1650,14 @@ class InferenceEngine:
                           lora=None):
         from deepspeed_tpu.utils.faults import maybe_fire
         maybe_fire("engine.prefill")
-        if sample_state is None and cca.is_cca(self.cfg):
-            # the program finds the slot's tail by the lane's slot index
-            raise ValueError("a prefill for a model with convolutional "
-                             "(CCA) attention needs the slot's sampling "
-                             "lane (sample_state): it names the slot")
+        if sample_state is None and (cca.is_cca(self.cfg)
+                                     or linear.is_linear(self.cfg)):
+            # the program finds the slot's tail or recurrent state by the
+            # lane's slot index
+            raise ValueError("a prefill for a model with per-slot state "
+                             "beside the pools (convolutional or linear "
+                             "attention) needs the slot's sampling lane "
+                             "(sample_state): it names the slot")
         lanes, seen = self._samp_lanes(sample_state, 1, self.cfg.vocab_size,
                                        scalar=True)
         out = self._run(

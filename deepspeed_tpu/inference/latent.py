@@ -1,5 +1,6 @@
 """Paged serving blocks for a model with latent attention (MLA;
-models/dots_vlm.py): a THIRD kind of cache state. A token's row in a layer
+models/dots_vlm.py): the third of the five kinds of cache state
+(paged_cache.refuse lists them). A token's row in a layer
 is ``[c_kv | k_r | 0...]``, the normalised latent of ``kv_lora_rank``
 values, the one rotated key of ``qk_rope_head_dim`` values that all heads
 share, and zeros up to whole lane tiles: ONE pool ``[L, N, block, lanes]``
@@ -24,7 +25,11 @@ Two attention paths over the same rows, the same numbers:
 One compiled body per layer SHAPE: the leading dense layers and then the
 sparse layers, each one scan of engine._scan_layers with the pool in the
 carry. The FFN is inference/hybrid.py's (a dense SwiGLU or the expert
-share, with the dispatch's routing record and counters).
+share, with the dispatch's routing record and counters). The attention
+sublayer stands by itself (:func:`attend_prefill`, :func:`attend_decode`):
+a model whose latent layers lie between layers of another kind
+(inference/linear.py) calls it for those, with the pool's offset by its own
+count of latent layers.
 
 Not served with a latent pool, and refused at construction by name: int8
 pools (a scale per KV head has no meaning here), prefix sharing and
@@ -72,20 +77,33 @@ def refuse(cfg, feature: str):
 
 def _project(h, p, cfg, positions):
     """h ``[T, d]`` at ``positions`` ``[T]`` -> the heads' queries ``q_n``
-    ``[T, H, d_n]`` and ``q_r`` ``[T, H, d_r]`` (rotated), and the tokens'
-    cache rows ``[T, lanes]``."""
+    ``[T, H, d_n]`` and ``q_r`` ``[T, H, d_r]``, and the tokens' cache rows
+    ``[T, lanes]``. Two things are the config's: with ``q_lora_rank`` the
+    query goes through a low rank with its own norm (``q_a``, ``q_a_norm``,
+    ``q_b``), without it through ONE projection ``q``; and with
+    ``mla_use_nope`` the ``d_r`` values of the query and of the cached key
+    are NOT rotated (no positions enter: models/kimi_linear.py)."""
     H, dn, dr = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     rkv = cfg.kv_lora_rank
-    freqs = cfg.rope_inv_freq
+
+    def rotate(x):
+        if getattr(cfg, "mla_use_nope", False):
+            return x
+        return apply_rotary_freqs(x, positions, cfg.rope_inv_freq)
+
     with jax.named_scope("mla_q"):
-        c_q = _norm(_dense(h, p["q_a"]), p["q_a_norm"], cfg)
-        q = _dense(c_q, p["q_b"]).reshape(-1, H, dn + dr)
+        if getattr(cfg, "q_lora_rank", None):
+            c_q = _norm(_dense(h, p["q_a"]), p["q_a_norm"], cfg)
+            q = _dense(c_q, p["q_b"])
+        else:
+            q = _dense(h, p["q"])
+        q = q.reshape(-1, H, dn + dr)
         q_n = q[..., :dn]
-        q_r = apply_rotary_freqs(q[..., dn:], positions, freqs)
+        q_r = rotate(q[..., dn:])
     with jax.named_scope("mla_kv_down"):
         ckv = _dense(h, p["kv_a"])
         c = _norm(ckv[:, :rkv], p["kv_a_norm"], cfg)
-        k_r = apply_rotary_freqs(ckv[:, rkv:], positions, freqs)
+        k_r = rotate(ckv[:, rkv:])
         rows = jnp.concatenate(
             [c, k_r, jnp.zeros((c.shape[0], cfg.latent_lanes
                                 - cfg.latent_row), c.dtype)], axis=-1)
@@ -123,17 +141,12 @@ def _attend_tile(carry, q, k, v, kpos, qpos, scale):
     return m_new, l, acc
 
 
-def block_prefill(carry, pools, table_row, positions, n_valid, p, cfg, base,
-                  impl, experts):
-    """One layer over a PROMPT CHUNK of one slot, the expanded path.
-    ``carry`` = (x ``[1, C, d]``, aux); ``pools`` = (rows,), flat over
-    layers; ``table_row`` the slot's block table; ``base`` this layer's
-    offset into the pool and its sparse index (models/dots_vlm.layer_bases);
-    ``experts``: every sparse layer's expert kernels
-    (hybrid.split_experts)."""
-    x, aux = carry
-    (pool,) = pools
-    C = x.shape[1]
+def attend_prefill(x, pool, table_row, positions, n_valid, p, cfg, rows_at):
+    """The attention sublayer over a PROMPT CHUNK of one slot, the expanded
+    path: ``x`` ``[C, d]`` -> (x + attention, the pool with the chunk's rows
+    written). ``pool``: every latent layer's blocks, this layer's starting
+    at ``rows_at``."""
+    C = x.shape[0]
     H, dv = cfg.n_heads, cfg.v_head_dim
     bs = pool.shape[1]
     NB = table_row.shape[0]
@@ -142,13 +155,13 @@ def block_prefill(carry, pools, table_row, positions, n_valid, p, cfg, base,
     scale = cfg.softmax_scale
 
     with jax.named_scope("attn_qkv"):
-        h = _norm(x[0], p["ln1"], cfg)
+        h = _norm(x, p["ln1"], cfg)
         q_n, q_r, rows = _project(h, p, cfg, positions)
         q = jnp.concatenate([q_n, q_r], axis=-1).transpose(1, 0, 2)
 
     with jax.named_scope("kv_write"):
         blk = table_row[jnp.clip(positions // bs, 0, NB - 1)]
-        blk = jnp.where(valid, blk, 0) + base["rows"]
+        blk = jnp.where(valid, blk, 0) + rows_at
         pool = pool.at[blk, positions % bs].set(rows)
 
     with jax.named_scope("paged_attn"), jax.named_scope("attn_mla"):
@@ -164,7 +177,7 @@ def block_prefill(carry, pools, table_row, positions, n_valid, p, cfg, base,
             # block j of the slot's OCCUPIED history, re-expanded as it
             # is attended; what of it lies at or past ``start`` (the
             # chunk's own rows, a block's unwritten tail) is masked
-            tile = pool[table_row[j] + base["rows"]]
+            tile = pool[table_row[j] + rows_at]
             kpos = j * bs + jnp.arange(bs, dtype=jnp.int32)
             kpos = jnp.where(kpos < start, kpos, jnp.int32(2 ** 30))
             with jax.named_scope("mla_expand"):
@@ -175,33 +188,45 @@ def block_prefill(carry, pools, table_row, positions, n_valid, p, cfg, base,
                                       state)
         attn = (acc / l[..., None]).astype(x.dtype)          # [H, C, d_v]
     with jax.named_scope("attn_out"):
-        x2 = x[0] + _dense(attn.transpose(1, 0, 2).reshape(C, H * dv),
-                           p["attn_out"])
+        return x + _dense(attn.transpose(1, 0, 2).reshape(C, H * dv),
+                          p["attn_out"]), pool
+
+
+def block_prefill(carry, pools, table_row, positions, n_valid, p, cfg, base,
+                  impl, experts):
+    """One layer over a PROMPT CHUNK of one slot. ``carry`` = (x ``[1, C,
+    d]``, aux); ``pools`` = (rows,), flat over layers; ``table_row`` the
+    slot's block table; ``base`` this layer's offset into the pool and its
+    sparse index (models/dots_vlm.layer_bases); ``experts``: every sparse
+    layer's expert kernels (hybrid.split_experts)."""
+    x, aux = carry
+    x2, pool = attend_prefill(x[0], pools[0], table_row, positions, n_valid,
+                              p, cfg, base["rows"])
+    valid = jnp.arange(x.shape[1]) < n_valid
     y, aux = _ffn(x2, p, cfg, impl, valid, aux, base["index"], experts)
     return (y[None], aux), (pool,)
 
 
-def block_decode(carry, pools, tables, lengths, active, p, cfg, base, impl,
-                 experts, plan=None):
-    """One layer for ONE new token per slot, the absorbed path: the
-    token's row is written at its position and every head attends the
-    slot's rows through ``tables`` ``[B, NB]``."""
-    x, aux = carry
-    (pool,) = pools
+def attend_decode(x, pool, tables, lengths, active, p, cfg, rows_at, impl,
+                  plan=None):
+    """The attention sublayer for ONE new token per slot, the absorbed
+    path: ``x`` ``[B, d]`` -> (x + attention, the pool): the token's row is
+    written at its position and every head attends the slot's rows through
+    ``tables`` ``[B, NB]``."""
     B = x.shape[0]
     H, dv, rkv = cfg.n_heads, cfg.v_head_dim, cfg.kv_lora_rank
     bs = pool.shape[1]
     NB = tables.shape[1]
 
     with jax.named_scope("attn_qkv"):
-        h = _norm(x[:, 0], p["ln1"], cfg)
+        h = _norm(x, p["ln1"], cfg)
         q_n, q_r, rows = _project(h, p, cfg, lengths)
 
     with jax.named_scope("kv_write"):
         blk = jnp.take_along_axis(
             tables, jnp.clip(lengths // bs, 0, NB - 1)[:, None], axis=1)[:, 0]
         ok = jnp.logical_and(active, lengths < NB * bs)
-        blk = jnp.where(ok, blk, 0) + base["rows"]
+        blk = jnp.where(ok, blk, 0) + rows_at
         pool = pool.at[blk, lengths % bs].set(rows)
 
     with jax.named_scope("paged_attn"), jax.named_scope("attn_mla"):
@@ -214,17 +239,26 @@ def block_decode(carry, pools, tables, lengths, active, p, cfg, base, impl,
         if impl == "pallas":
             from deepspeed_tpu.ops.attention.mla import mla_decode_attention
             lat = mla_decode_attention(
-                q, pool, tables + base["rows"], lengths, value_width=rkv,
+                q, pool, tables + rows_at, lengths, value_width=rkv,
                 scale=cfg.softmax_scale, plan=plan)
         else:
             from deepspeed_tpu.ops.attention.mla import mla_decode_reference
             lat = mla_decode_reference(
-                q, pool, tables + base["rows"], lengths, value_width=rkv,
+                q, pool, tables + rows_at, lengths, value_width=rkv,
                 scale=cfg.softmax_scale)
         with jax.named_scope("mla_absorb"):
             attn = jnp.einsum("bhc,hcd->bhd", lat,
                               p["v_up"]["kernel"].astype(lat.dtype))
     with jax.named_scope("attn_out"):
-        x2 = x[:, 0] + _dense(attn.reshape(B, H * dv), p["attn_out"])
+        return x + _dense(attn.reshape(B, H * dv), p["attn_out"]), pool
+
+
+def block_decode(carry, pools, tables, lengths, active, p, cfg, base, impl,
+                 experts, plan=None):
+    """One layer for ONE new token per slot (:func:`attend_decode`, then
+    the FFN)."""
+    x, aux = carry
+    x2, pool = attend_decode(x[:, 0], pools[0], tables, lengths, active, p,
+                             cfg, base["rows"], impl, plan)
     y, aux = _ffn(x2, p, cfg, impl, active, aux, base["index"], experts)
     return (y[:, None], aux), (pool,)

@@ -1,0 +1,308 @@
+"""Paged serving blocks for a model whose layers are of two kinds of
+attention STATE (models/kimi_linear.py): a FIFTH dialect, and the first
+whose slot costs memory before it holds a token.
+
+- The latent (MLA) layers keep a row a token in ONE paged pool behind the
+  slot's block table, exactly latent.py's (its two attention paths are
+  called from here, :func:`latent.attend_prefill` / ``attend_decode``).
+- The linear-attention (KDA) layers keep, per slot and layer, a RECURRENT
+  STATE that summarises the slot's whole history: ``linear_heads`` float32
+  matrices ``[Dv, Dk]`` (ops/attention/kda.py keeps them transposed), read
+  AND rewritten by every token, whatever the sequence's length, and not
+  recomputable from any block; beside it the un-convolved ``[q | k | v]``
+  rows of the last ``conv_kernel - 1`` tokens, the left context of the
+  depthwise convolution. No block table reaches either.
+
+:class:`LinearState` rides in ``k_pool``'s place (``v_pool`` is None):
+``rows`` ``[L_mla, N, block, lanes]``, ``state`` ``[L_kda, slots, H, Dv,
+Dk]`` float32, ``tail`` ``[L_kda, slots, (taps - 1) * 3 H Dh]`` (a slot's
+rows side by side, oldest first: with ``taps - 1 = 3`` rows a dimension of
+their own the device pads them to a tile of 8 or 16 and both programs
+re-laid the buffer out on every dispatch). Each kind's
+buffers are indexed by the kind's OWN layer counter
+(models/kimi_linear.layer_bases): which layer is of which kind is a list in
+the config and follows no period.
+
+A prefill chunk carries its slot's state from chunk to chunk THROUGH the
+state buffer: it starts from the slot's state and tail when ``start > 0``
+and from zeros when ``start = 0`` (a reused slot starts clean without
+anything being cleared), runs the chunkwise-parallel rule (``kda_chunk``)
+and leaves the state after its last valid token. A decode dispatch is one
+recurrent step (``kda_step``) over the ACTIVE slots, in place; the state of
+an idle slot, or of one still in prefill, is not touched. A preempted
+request recomputes from position 0, as any other: the replay rebuilds the
+state.
+
+One compiled body per KIND of layer (:func:`run_layers`): the leading
+dense layers inline (linear attention, by the config), then ONE scan over
+the runs the list cuts the sparse layers into, each run an inner loop over
+its linear layers and the latent layer that ends it, all three buffers in
+the loops' carries.
+
+Not served by this dialect, and refused at construction by name (no
+program of theirs carries the state): prefix sharing and copy-on-write, the
+host tier, int8 pools, speculation/verify, the fused horizon, LoRA, tensor
+parallelism; nor the static-cache paths. docs/LINEAR_ATTENTION.md."""
+
+from typing import NamedTuple, Optional
+
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.inference import latent
+from deepspeed_tpu.inference.hybrid import _ffn, _heads, _rows
+from deepspeed_tpu.models.gpt import _dense, _norm
+from deepspeed_tpu.ops.attention import kda
+
+
+class LinearState(NamedTuple):
+    """The device state of this dialect's cache (module docstring).
+    ``stats`` / ``route``: the expert layers' counters and the last
+    dispatch's selection, as hybrid.PagedState's."""
+    rows: jnp.ndarray
+    state: jnp.ndarray
+    tail: jnp.ndarray
+    stats: Optional[jnp.ndarray] = None
+    route: Optional[jnp.ndarray] = None
+
+    def delete(self):
+        for a in self:
+            if a is not None:
+                a.delete()
+
+
+def is_linear(cfg) -> bool:
+    return bool(getattr(cfg, "kda_layers", ()))
+
+
+def refuse(cfg, feature: str):
+    """Raise for a serving feature whose programs do not carry the state."""
+    if is_linear(cfg):
+        raise ValueError(
+            f"{feature} is not supported for a model with linear-attention "
+            f"layers (a per-slot recurrent state that summarises the whole "
+            f"history rides beside the paged pool): see "
+            f"docs/LINEAR_ATTENTION.md")
+
+
+def new_state(cfg, num_blocks: int, block_size: int, num_slots: int, dtype):
+    """Zeroed LinearState for ``num_blocks`` blocks a latent layer."""
+    H, Dh = cfg.linear_heads, cfg.linear_head_dim
+    Lk = cfg.n_kda_layers
+    return LinearState(
+        jnp.zeros((cfg.n_full_layers, num_blocks, block_size,
+                   cfg.latent_lanes), dtype),
+        jnp.zeros((Lk, num_slots, H, Dh, Dh), jnp.float32),
+        jnp.zeros((Lk, num_slots, (cfg.conv_kernel - 1) * cfg.kda_channels),
+                  dtype))
+
+
+def step_plan(active):
+    """(batch rows with the active ones first, how many are active): the
+    work list of every layer's ``kda_step`` call, made once a dispatch."""
+    order = jnp.argsort(jnp.logical_not(active), stable=True)
+    return order.astype(jnp.int32), jnp.sum(active, dtype=jnp.int32)[None]
+
+
+def _layer(stack, i):
+    """Layer ``i`` (traced) of a parameter stack."""
+    return jax.tree_util.tree_map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
+        stack)
+
+
+def _unit(x, eps):
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+
+
+def _project(h, p):
+    """h ``[T, d]`` (normed) -> the tokens' un-convolved ``[q | k | v]``
+    rows ``[T, C]`` and what the decay, the output gate and the write
+    strength are made from."""
+    with jax.named_scope("kda_proj"):
+        return (_dense(h, p["qkv"]), _dense(_dense(h, p["f_a"]), p["f_b"]),
+                _dense(_dense(h, p["g_a"]), p["g_b"]), _dense(h, p["b"]))
+
+
+def _mix(proj, left, p, cfg):
+    """:func:`_project`'s rows with the ``taps - 1`` rows before each
+    (``left``: that many ``[T, C]`` arrays, the oldest first: a token's own
+    left context) -> what the rule takes, float32: (q, k, v, g ``[T, H,
+    Dh]``, b ``[T, H]``), and the output gate ``[T, H, Dh]``."""
+    H, Dh = cfg.linear_heads, cfg.linear_head_dim
+    f32 = jnp.float32
+    xs, decay, gate, write = proj
+    with jax.named_scope("kda_mix"):
+        # the depthwise convolution: tap j meets the token taps - 1 - j back
+        w = p["conv"]["kernel"].astype(f32)                    # [taps, C]
+        y = xs.astype(f32) * w[-1] + sum(
+            rows.astype(f32) * w[j] for j, rows in enumerate(left))
+        q, k, v = (_heads(a, H) for a in jnp.split(jax.nn.silu(y), 3, -1))
+        q = _unit(q, cfg.l2_eps) * Dh ** -0.5
+        k = _unit(k, cfg.l2_eps)
+        g = -jnp.exp(p["A_log"].astype(f32))[:, None] * jax.nn.softplus(
+            _heads(decay.astype(f32) + p["dt_bias"].astype(f32), H))
+        b = jax.nn.sigmoid(write.astype(f32))
+        gate = jax.nn.sigmoid(_heads(gate.astype(f32), H))
+    return (q, k, v, g, b), gate
+
+
+def _output(x, o, gate, p, cfg):
+    """The rule's output ``[T, H, Dh]`` float32, normalised per head, gated
+    and projected back onto the stream ``x``."""
+    with jax.named_scope("kda_out"):
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
+                              + cfg.norm_eps) \
+            * p["o_norm"]["scale"].astype(jnp.float32)
+        return x + _dense(_rows(o * gate).astype(x.dtype), p["attn_out"])
+
+
+def kda_prefill(x, state, tails, slot, positions, n_valid, p, cfg, at):
+    """The linear-attention sublayer over a PROMPT CHUNK of slot ``slot``:
+    ``x`` ``[C, d]`` -> (x + attention, state, tails); the slot's state and
+    tail lie at ``at + slot`` of the flat buffers."""
+    C = x.shape[0]
+    taps = cfg.conv_kernel
+    at = at + slot
+    resumed = positions[0] > 0
+    valid = jnp.arange(C) < n_valid
+    with jax.named_scope("paged_attn"), jax.named_scope("attn_kda"):
+        h = _norm(x, p["ln1"], cfg)
+        # position 0 is left-padded with zeros and starts from a zero
+        # state: a reused slot starts clean without anything being cleared
+        t0 = jnp.where(resumed, tails[at], 0).reshape(taps - 1, -1)
+        s0 = jnp.where(resumed, state[at], 0.0)
+        proj = _project(h, p)
+        rows = jnp.concatenate([t0, proj[0]], axis=0)          # [taps-1+C, C]
+        left = [rows[j:j + C] for j in range(taps - 1)]
+        (q, k, v, g, b), gate = _mix(proj, left, p, cfg)
+        # a padding token leaves the state alone
+        g = jnp.where(valid[:, None, None], g, 0.0)
+        b = jnp.where(valid[:, None], b, 0.0)
+        o, s = kda.kda_chunk(q, k, v, g, b, s0)
+        state = state.at[at].set(s)
+        # what the next chunk (or the first decode step) resumes from: the
+        # rows of the last VALID tokens; with none, the tail as it was
+        tails = tails.at[at].set(jax.lax.dynamic_slice_in_dim(
+            rows, n_valid, taps - 1).reshape(-1))
+        return _output(x, o, gate, p, cfg), state, tails
+
+
+def kda_decode(x, state, tails, active, p, cfg, at, impl, plan):
+    """The linear-attention sublayer for ONE new token per slot: ``x``
+    ``[B, d]`` -> (x + attention, state, tails). Slot ``s``'s state and
+    tail lie at ``at + s``; only the ACTIVE slots' are rewritten."""
+    B = x.shape[0]
+    with jax.named_scope("paged_attn"), jax.named_scope("attn_kda"):
+        h = _norm(x, p["ln1"], cfg)
+        # [B, 3 C], read out BEFORE the update below is formed: fused into
+        # it, the shifted read kept the update from running in place and
+        # the whole buffer was copied in and out of the program
+        t0 = jax.lax.optimization_barrier(
+            jax.lax.dynamic_slice_in_dim(tails, at, B))
+        proj = _project(h, p)
+        C = proj[0].shape[-1]
+        (q, k, v, g, b), gate = _mix(
+            proj, jnp.split(t0, cfg.conv_kernel - 1, axis=1), p, cfg)
+        own = jnp.concatenate([t0[:, C:], proj[0]], axis=1)
+        tails = jax.lax.dynamic_update_slice_in_dim(
+            tails, jnp.where(active[:, None], own, t0), at, 0)
+        if impl == "pallas":
+            order, count = plan
+            state, o = kda.kda_step(state, kda.pack_step(q, k, g, v, b),
+                                    at + order, order, count)
+            # rows that did not decode hold whatever was in the buffer
+            o = jnp.where(active[:, None, None], o, 0.0)
+        else:
+            with jax.named_scope("kda_step"):
+                state, o = kda.kda_step_reference(state, q, k, v, g, b, at,
+                                                  active)
+        return _output(x, o, gate, p, cfg), state, tails
+
+
+def prefill_attends(cfg, table_row, positions, n_valid, slot):
+    """The two attention sublayers of a PROMPT CHUNK of slot ``slot``, as
+    :func:`run_layers` calls them: ``attend(x [C, d], flat, p, base) -> (x +
+    attention, flat)`` with ``flat`` = (rows, state, tails)."""
+    def linear_attn(x, flat, p, base):
+        rows, state, tails = flat
+        y, state, tails = kda_prefill(x, state, tails, slot, positions,
+                                      n_valid, p, cfg, base["state"])
+        return y, (rows, state, tails)
+
+    def latent_attn(x, flat, p, base):
+        y, rows = latent.attend_prefill(x, flat[0], table_row, positions,
+                                        n_valid, p, cfg, base["rows"])
+        return y, (rows,) + flat[1:]
+    return linear_attn, latent_attn
+
+
+def decode_attends(cfg, tables, lengths, active, impl, mla_plan):
+    """The same for ONE new token per slot (``x`` ``[B, d]``);
+    ``mla_plan``: the latent kernel's grid for these lengths."""
+    kda_plan = step_plan(active)
+
+    def linear_attn(x, flat, p, base):
+        rows, state, tails = flat
+        y, state, tails = kda_decode(x, state, tails, active, p, cfg,
+                                     base["state"], impl, kda_plan)
+        return y, (rows, state, tails)
+
+    def latent_attn(x, flat, p, base):
+        y, rows = latent.attend_decode(x, flat[0], tables, lengths, active,
+                                       p, cfg, base["rows"], impl, mla_plan)
+        return y, (rows,) + flat[1:]
+    return linear_attn, latent_attn
+
+
+def run_layers(cfg, params, experts, carry, flat, bases, attends, valid,
+               impl):
+    """Every layer of one serving program. ``carry`` = (x ``[T, d]``, aux
+    as engine._dense_then_sparse makes it); ``flat`` = (rows, state,
+    tails), each flat over its OWN kind's layers; ``bases``: per layer, by
+    layer index (models/kimi_linear.layer_bases); ``attends``:
+    :func:`prefill_attends` or :func:`decode_attends`; ``params`` without
+    the expert kernels, which are ``experts`` (hybrid.split_experts).
+
+    The kinds follow a LIST, so the loop is cut where the list says: the
+    leading dense layers inline, then ONE scan over the runs of sparse
+    layers, each run ``n`` linear layers (an inner loop whose trip count is
+    the run's own) and the latent layer that ends it, then the linear
+    layers behind the last latent one, if any. One compiled body per kind:
+    every buffer rides in the loops' carries and is updated in place. (A
+    ``lax.cond`` on the kind inside one scan compiled a copy of the
+    recurrent state, 1.7 GB, into the latent branch: PERF.md, PR 40.)"""
+    from deepspeed_tpu.models.kimi_linear import layer_runs
+    nd = cfg.n_dense_layers
+    linear_attn, latent_attn = attends
+
+    def layer(l, stack, attend, loop):
+        (x, aux), flat = loop
+        base = {k: v[l] for k, v in bases.items()}
+        x2, flat = attend(x, flat, _layer(params[stack], base["attn"]), base)
+        # only the inline leading layers come with a Python index
+        p = _layer(params["dense_block"], l) if isinstance(l, int) \
+            else _layer(params["block"], l - nd)
+        y, aux = _ffn(x2, p, cfg, impl, valid, aux, base["index"], experts)
+        return (y, aux), flat
+
+    def linear_run(start, n, loop):
+        return jax.lax.fori_loop(
+            0, n, lambda i, loop: layer(start + i, "kda", linear_attn, loop),
+            loop)
+
+    loop = (carry, flat)
+    for l in range(nd):
+        loop = layer(l, "kda", linear_attn, loop)
+    starts, counts, behind = layer_runs(cfg)
+
+    def run(loop, r):
+        start, n = r
+        loop = linear_run(start, n, loop)
+        return layer(start + n, "mla", latent_attn, loop), None
+
+    loop, _ = jax.lax.scan(run, loop, (jnp.asarray(starts),
+                                       jnp.asarray(counts)))
+    if behind[1]:
+        loop = linear_run(jnp.int32(behind[0]), jnp.int32(behind[1]), loop)
+    return loop

@@ -49,6 +49,19 @@ thread functionally through the engine's donated ``prefill_into_slot``
 issues is the one COW block copy (a single compiled program, warmed at
 serving startup).
 
+**Five kinds of cache state** share this allocator, the block tables and
+the two serving programs' calls (:func:`refuse` lists them; each raises by
+name for what cannot yet live with it): K and V pools (the GPT blocks); a
+full layers' pool beside a bounded window RING per slot
+(inference/hybrid.py); ONE pool of latent rows and no V pool (latent.py);
+K and V pools beside a per-slot TAIL of the previous token (cca.py); and a
+latent pool for some layers beside a per-slot RECURRENT STATE for the
+others (linear.py). The last is the first whose slot costs memory before
+it holds a token (41.9 MB a slot for Kimi-Linear against 8,960 bytes a
+token): ``recurrent_state_bytes`` / ``conv_tail_bytes`` are held whole from
+construction on, and a byte budget buys the slots first and blocks with
+what is left (``slot_state_bytes``).
+
 Block id 0 is RESERVED as the trash block: the slot programs route
 writes for masked-out lanes (chunk padding, inactive slots) there, so
 the compiled scatter needs no branch.
@@ -81,7 +94,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.utils.env import resolve_flag
-from deepspeed_tpu.inference import cca, hybrid, latent
+from deepspeed_tpu.inference import cca, hybrid, latent, linear
 from deepspeed_tpu.inference.host_tier import (
     HostBlockPool, HostCorruption, resolve_host_tier)
 from deepspeed_tpu.inference.prefix_index import PrefixIndex, PrefixMatch
@@ -114,17 +127,21 @@ def resolve_prefix_cache(flag: Optional[bool] = None) -> bool:
 # the module-level ones.
 def refuse(cfg, what: str):
     """Raise by name for a serving feature that a model whose cache state
-    is more than K and V blocks cannot yet live with: bounded window state
-    (inference/hybrid.py), a latent pool (latent.py), per-slot tails
-    (cca.py). The one list of those dialects."""
-    for dialect in (hybrid, latent, cca):
+    is more than K and V blocks cannot yet live with. The one list of
+    those dialects (the plain K and V pools of the GPT blocks are the
+    first of five): bounded window state beside the pool
+    (inference/hybrid.py), a latent pool (latent.py), per-slot tails of
+    the previous token (cca.py), a per-slot recurrent state beside a
+    latent pool (linear.py; asked first, its latent layers would answer
+    with latent.py's line)."""
+    for dialect in (linear, hybrid, latent, cca):
         dialect.refuse(cfg, what)
 
 
 def paged_pool(k):
     """The pool behind the block tables in a K-side state: the array
     itself, a two-kind state's full layers' pool, a latent state's rows, a
-    CCA state's K rows."""
+    CCA state's K rows, a linear-attention state's latent rows."""
     return getattr(k, "full", getattr(k, "rows", k))
 
 
@@ -236,7 +253,8 @@ class PagedKVCache:
         L = getattr(cfg, "n_full_layers", cfg.n_layers)
         Hkv, Dh = cfg.kv_heads, cfg.head_dim
         # what cannot yet live with bounded window state, with a latent
-        # pool or with per-slot tails raises here, by name
+        # pool, with per-slot tails or a recurrent state raises here, by
+        # name
         for on, what in ((prefix_cache, "prefix sharing (prefix_cache)"),
                          (self.quantized, "int8 KV pools (kv_quant)"),
                          (resolve_host_tier(host_tier) and prefix_cache,
@@ -252,6 +270,12 @@ class PagedKVCache:
         # from construction on, like the window rings
         self.cca_tail_bytes = self.num_slots \
             * gpt_lib.kv_cca_tail_bytes_per_slot(cfg, self.dtype)
+        # the linear-attention layers' recurrent state and convolution
+        # tails (inference/linear.py): a slot costs these before it holds
+        # a token, so slots, not blocks, are what this memory buys
+        state, tail = gpt_lib.kv_recurrent_bytes_per_slot(cfg, self.dtype)
+        self.recurrent_state_bytes = self.num_slots * state
+        self.conv_tail_bytes = self.num_slots * tail
         self.pool_dtype = jnp.dtype(jnp.int8) if self.quantized \
             else self.dtype
         self.bytes_per_token = gpt_lib.kv_bytes_per_token(
@@ -259,6 +283,10 @@ class PagedKVCache:
         # the window layers' rings: held whole from construction on
         self.window_bytes = self.num_slots * gpt_lib.kv_window_bytes_per_slot(
             cfg, self.block_size, self.pool_dtype)
+        # what the slots cost whatever they hold, out of the same budget
+        # as the blocks
+        self.slot_state_bytes = self.window_bytes \
+            + self.recurrent_state_bytes + self.conv_tail_bytes
         # scale overhead: 2 pools (K and V) × L layers × Hkv heads × fp32
         # per block — amortized it is 2*L*Hkv*4/block_size bytes/token
         self.scale_bytes_per_block = (2 * L * Hkv * 4) if self.quantized \
@@ -267,8 +295,9 @@ class PagedKVCache:
             if hbm_budget_bytes:
                 per_block = (self.bytes_per_token * self.block_size
                              + self.scale_bytes_per_block)
-                # the rings come out of the same budget
-                num_blocks = int((hbm_budget_bytes - self.window_bytes)
+                # the rings, the tails and the recurrent state come out
+                # of the same budget: what the slots cost is spent first
+                num_blocks = int((hbm_budget_bytes - self.slot_state_bytes)
                                  // per_block)
             else:
                 # default pool: the static reservation's worth of blocks
@@ -286,7 +315,14 @@ class PagedKVCache:
         # one row per cached token, its kv heads folded side by side:
         # the layout in HBM that the entry parameter, the layer loop and
         # the kernel share (module docstring)
-        if self.latent:
+        if linear.is_linear(cfg):
+            # a fifth: a latent pool for the latent layers (L counts
+            # those alone) and every slot's recurrent state and
+            # convolution tail for the linear layers, zero until used
+            self.k = linear.new_state(cfg, self.num_blocks, self.block_size,
+                                      self.num_slots, self.pool_dtype)
+            self.v = None
+        elif self.latent:
             # a third kind of state (inference/latent.py): one pool of
             # latent rows, no V pool
             self.k = latent.LatentState(jnp.zeros(
@@ -462,6 +498,7 @@ class PagedKVCache:
             "host_blocks": self.host_blocks,
             "host_bytes": self.host_bytes,
             "window_bytes": self.window_bytes,
+            "recurrent_state_bytes": self.recurrent_state_bytes,
             "host_spills": self.host_spills,
             "host_restores": self.host_restores,
             "host_restore_failures": self.host_restore_failures,
@@ -1118,7 +1155,8 @@ class PagedKVCache:
         int8 pools ``(k, v, k_scale, v_scale)``; for a model of two
         attention kinds k and v are hybrid.PagedState, for one with
         latent attention ``(latent.LatentState, None)``, for one with
-        convolutional attention ``(cca.CCAState, v)``."""
+        convolutional attention ``(cca.CCAState, v)``, for one with
+        linear-attention layers ``(linear.LinearState, None)``."""
         return (self.k, self.v) + (self.scales or ())
 
     @pools.setter

@@ -732,6 +732,22 @@ class ServingEngine:
                     else self.metrics.gauge)
             self._stat[key] = make(f"serving_{key}", help_)
         self.stats = _StatsView(self._stat)
+        # a model with linear-attention layers (inference/linear.py): how
+        # often a slot's recurrent state was started from zeros, and how
+        # often that was a preempted request's replay rebuilding it
+        self._state_resets = self._state_replays = None
+        if self.cache.recurrent_state_bytes:
+            self._state_resets = self.metrics.counter(
+                "serving_state_resets",
+                "prefill chunks at position 0 of a model with "
+                "linear-attention layers: the slot's recurrent state and "
+                "convolution tail start from zeros (nothing is cleared: "
+                "the chunk does not read what the slot held)")
+            self._state_replays = self.metrics.counter(
+                "serving_state_replays",
+                "of those, re-prefills of a preempted request (prompt + "
+                "generated from position 0): the replay rebuilds the "
+                "state, which no block holds")
         if self.telemetry.enabled:
             reg = self.metrics
             self._h_ttft = reg.histogram(
@@ -873,6 +889,22 @@ class ServingEngine:
                           "first convolution's output and its half of the "
                           "next value, whatever the slot's length").set(
                     self.cache.cca_tail_bytes)
+            if self.cache.recurrent_state_bytes:
+                # state that summarises a whole history (inference/
+                # linear.py): bought per SLOT, before a token is held
+                reg.gauge("kv_recurrent_state_bytes",
+                          "device bytes of the linear-attention layers' "
+                          "per-slot recurrent state: per layer, slot and "
+                          "head one float32 matrix of head_dim x head_dim, "
+                          "read and rewritten by every token whatever the "
+                          "slot's length").set(
+                    self.cache.recurrent_state_bytes)
+                reg.gauge("kv_conv_tail_bytes",
+                          "device bytes of the linear-attention layers' "
+                          "per-slot convolution tails: per layer and slot "
+                          "the un-convolved [q | k | v] rows of the last "
+                          "conv_kernel - 1 tokens").set(
+                    self.cache.conv_tail_bytes)
             self._h_kv_err = reg.histogram(
                 "serving_kv_quant_error",
                 "sampled upper bound on the max-abs KV dequantization "
@@ -1176,6 +1208,8 @@ class ServingEngine:
                 c4 = self._span_counts()
                 if c4:
                     s_decode.set(live=occ, blocks=c4[3],
+                                 state_slots=occ if
+                                 self.cache.recurrent_state_bytes else 0,
                                  kv_steps=self._kv_steps,
                                  idle_tiles=self._idle_tiles,
                                  kv_tokens=self._kv_tokens,
@@ -1518,7 +1552,13 @@ class ServingEngine:
             with self.telemetry.tracer.span(
                     "serve.prefill", rid=req.rid, step=self._step_clock,
                     slot=slot, start=done, n=n, history=done,
-                    tail=int(done > 0 and self.cache.cca_tail_bytes > 0)):
+                    tail=int(done > 0 and self.cache.cca_tail_bytes > 0),
+                    state=int(done > 0
+                              and self.cache.recurrent_state_bytes > 0)):
+                if done == 0 and self._state_resets is not None:
+                    self._state_resets.inc()
+                    if req.evictions:
+                        self._state_replays.inc()
                 self._prefill_slot_chunk(slot, req, done, n, now)
 
     def _prefill_slot_chunk(self, slot: int, req: ServeRequest,
@@ -2354,7 +2394,11 @@ class ServingEngine:
     def _preempt(self, slot: int) -> None:
         """Free the slot and requeue its request for recompute-on-resume:
         the new working prompt is prompt+generated, whose re-prefill
-        reproduces the pre-eviction cache and next-token logits exactly."""
+        reproduces the pre-eviction cache and next-token logits exactly.
+        That holds for state no block holds too (a window ring, a
+        convolution tail, a linear-attention layer's recurrent state): the
+        replay starts at position 0, where a chunk reads nothing of what
+        the slot held, and rebuilds it."""
         req = self.slots[slot]
         logger.info(f"serving: evicting request {req.rid} from slot {slot} "
                     f"({self.cache.free_blocks} blocks free)")

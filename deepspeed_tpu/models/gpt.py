@@ -994,12 +994,13 @@ def kv_bytes_per_token(cfg: GPTConfig, dtype=jnp.bfloat16) -> int:
     kind: a sliding-window layer keeps a bounded ring per slot
     (:func:`kv_window_bytes_per_slot`) and adds nothing per token; a
     latent (MLA) layer keeps ONE row of ``latent_lanes`` values a token
-    (the latent and the shared rotated key, padded to whole lane tiles as
-    the pool stores it) and no K or V heads."""
-    if getattr(cfg, "kv_lora_rank", 0):
-        return int(cfg.n_layers * cfg.latent_lanes
-                   * jnp.dtype(dtype).itemsize)
+    (the latent and the shared key, padded to whole lane tiles as the pool
+    stores it) and no K or V heads; a linear-attention layer keeps a state
+    per slot (:func:`kv_recurrent_bytes_per_slot`) and adds nothing per
+    token (``n_full_layers`` then counts the layers that do)."""
     layers = getattr(cfg, "n_full_layers", cfg.n_layers)
+    if getattr(cfg, "kv_lora_rank", 0):
+        return int(layers * cfg.latent_lanes * jnp.dtype(dtype).itemsize)
     return int(2 * layers * cfg.kv_heads * cfg.head_dim
                * jnp.dtype(dtype).itemsize)
 
@@ -1024,6 +1025,18 @@ def kv_cca_tail_bytes_per_slot(cfg: GPTConfig, dtype=jnp.bfloat16) -> int:
     the slot's length (0 for a model with no such attention)."""
     return int(cfg.n_layers * getattr(cfg, "cca_tail_values", 0)
                * jnp.dtype(dtype).itemsize)
+
+
+def kv_recurrent_bytes_per_slot(cfg: GPTConfig,
+                                dtype=jnp.bfloat16) -> Tuple[int, int]:
+    """(recurrent state, convolution tails): bytes one serving slot holds
+    beside its blocks, whatever its length, where some layers are linear
+    attention (models/kimi_linear.py, inference/linear.py): per such layer
+    a float32 matrix a head, and the last tokens' un-convolved rows in the
+    pools' type ((0, 0) for a model with no such layers)."""
+    return (4 * int(getattr(cfg, "recurrent_state_values", 0)),
+            int(getattr(cfg, "conv_tail_values", 0))
+            * jnp.dtype(dtype).itemsize)
 
 
 def decode_geometry(cfg: GPTConfig, block_size: int,

@@ -293,3 +293,69 @@ ENTRY %main.1 (p0: bf16[4,9,4,32]) -> bf16[4,9,4,32] {
     assert metrics.gauge("program_param_copy_bytes_prefill_slot").value \
         == 9216
     assert metrics.gauge("program_param_copy_bytes_decode_slots").value == 0
+
+
+@pytest.mark.parametrize("program", ["prefill_slot", "decode_slots"])
+def test_no_copy_of_the_state_or_the_pool_is_compiled_in(v5e, program):
+    """The same reading for the linear-attention dialect's three buffers
+    (inference/linear.py): the serving program compiled ahead of time for a v5e, with the
+    Mosaic kernels, at the published head sizes (the tiling is theirs) and
+    few layers, slots and experts, runs of 1 and 3 linear layers and one
+    behind the last latent layer. No ``copy`` holds a value shaped like the
+    latent pool, the recurrent state or the convolution tails, and all
+    three are updated in place (a ``lax.cond`` on the layer's kind copied
+    the whole state in the latent branch; a fused shifted read of the tail
+    copied the tails in and out: PERF.md, PR 40)."""
+    from deepspeed_tpu.inference import linear
+    from deepspeed_tpu.models import kimi_linear
+    cfg = kimi_linear.KimiLinearConfig(
+        vocab_size=512, n_layers=8, n_heads=4, d_model=256, d_ff=512,
+        max_seq_len=512, dtype=jnp.bfloat16, kda_layers=(1, 2, 4, 5, 6, 8),
+        full_attn_layers=(3, 7), linear_heads=16, num_experts=8, moe_k=2,
+        moe_d_ff=128, experts_held=(0, 4), use_flash_attention=False,
+        remat=False)
+    B, C, bs = 8, 128, 128
+    NB = cfg.max_seq_len // bs
+    N = B * NB + 1
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = jax.tree_util.tree_map(
+        lambda a: S(a.shape, jnp.bfloat16), jax.eval_shape(
+            lambda: kimi_linear.init_params(jax.random.PRNGKey(0), cfg)))
+    Lm, Lk, H, Dh = 2, 6, 16, 128
+    state = linear.LinearState(
+        S((Lm, N, bs, cfg.latent_lanes), jnp.bfloat16),
+        S((Lk, B, H, Dh, Dh), jnp.float32),
+        S((Lk, B, 3 * cfg.kda_channels), jnp.bfloat16))
+    eng = InferenceEngine.__new__(InferenceEngine)
+    eng.cfg, eng.max_seq_len, eng.dtype = cfg, cfg.max_seq_len, jnp.bfloat16
+    eng.decode_impl = "pallas"
+    i32, f32, u32, V = jnp.int32, jnp.float32, jnp.uint32, cfg.vocab_size
+    if program == "prefill_slot":
+        fn = jax.jit(_named(eng._prefill_slot_fn, "serve_prefill_slot"),
+                     donate_argnums=(1, 2))
+        args = (params, state, None, S((NB,), i32), S((C,), i32), S((), i32),
+                S((), i32), S((2,), u32), S((), i32), S((), f32), S((), i32),
+                S((), f32), S((), f32), S((V,), jnp.bool_), None, None,
+                S((), i32))
+    else:
+        fn = jax.jit(_named(eng._decode_slots_fn, "serve_decode_slots"),
+                     donate_argnums=(1, 2), static_argnums=(7,))
+        args = (params, state, None, S((B, NB), i32), S((B,), i32),
+                S((B,), i32), S((B,), jnp.bool_), "pallas", S((B, 2), u32),
+                S((B,), i32), S((B,), f32), S((B,), i32), S((B,), f32),
+                S((B,), f32), S((B, V), jnp.bool_))
+    exe = fn.trace(*args).lower(lowering_platforms=("tpu",)).compile()
+    text = exe.as_text()
+    table = parse_provenance(text)
+    # the pool's blocks (one layer's, all layers'), the state's and the
+    # tails' leading dimension (all layers' slots: 48)
+    assert pool_copy_bytes(table, (N, Lm * N)) == 0
+    assert pool_copy_bytes(table, (Lk * B,)) == 0
+    buffers = sum(a.size * a.dtype.itemsize for a in state[:3])
+    assert exe.memory_analysis().alias_size_in_bytes >= buffers
+    assert exe.memory_analysis().temp_size_in_bytes < buffers // 4
+    if program == "decode_slots":
+        assert "kda_step" in text and "mla_decode" in text
