@@ -711,16 +711,104 @@ def _block_verify_paged(x, pools, tables, lengths, active, p,
     return y, (k_pool, v_pool, k_scale, v_scale)
 
 
+# the lengths a prefill chunk's read is compiled for: at most this many
+# prefixes of a slot's row, each a whole number of tiles
+PREFILL_READ_LENGTHS = 8
+
+
+def attended_tiles(start, n, bs: int, nb: int, window: Optional[int] = None):
+    """The part of its slot's row that a prefill chunk of ``n`` tokens at
+    position ``start`` reads from the pool, in tiles of ``P`` table entries:
+    (first tile, end tile, ``P``). The chunk's queries sit at ``[start,
+    start + n)`` and see nothing past themselves, so the end is the tile
+    past position ``start + n - 1``; with ``window`` the first is the tile
+    of the oldest key the chunk's FIRST query sees. ``P`` follows from the
+    shapes: the decode kernel's tile (``blocks_per_step``: 128 positions,
+    or a small table whole), widened until ``PREFILL_READ_LENGTHS`` tiles
+    cover the table. Integer arithmetic that holds for traced ``start`` and
+    ``n`` (the program's choice of a length) and for Python ones (the
+    scheduler's ``attended`` count)."""
+    P = blocks_per_step(nb, bs)
+    P *= -(-nb // (P * PREFILL_READ_LENGTHS))
+    lo = 0
+    if window is not None:
+        lo = (start - window + 1) // (P * bs)
+        lo = lo * (lo > 0)        # max(lo, 0), for a tracer and an int alike
+    return lo, (start + n + P * bs - 1) // (P * bs), P
+
+
+def _attend_rows(q, kc, vc, positions, first, cfg: GPTConfig):
+    """One softmax of a prompt chunk's queries ``q`` ``[C, H, Dh]`` at
+    ``positions`` over gathered rows ``kc``, ``vc`` ``[S, Hkv, Dh]`` that
+    sit at positions ``first + [0, S)`` of the slot's row; the causal band
+    (and ``cfg.attn_window``) masks what a query may not see. Returns
+    ``[C, H * Dh]``."""
+    C, H, Dh = q.shape
+    S, Hkv = kc.shape[:2]
+    with jax.named_scope("paged_attn"):
+        qg = q.reshape(C, Hkv, H // Hkv, Dh)
+        scores = jnp.einsum("ckgd,skd->ckgs", qg, kc).astype(jnp.float32)
+        scores *= cfg.attn_scale if cfg.attn_scale is not None \
+            else 1.0 / np.sqrt(Dh)
+        sidx = first + jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, S), 3)
+        scores = causal_band(scores, sidx, positions[:, None, None, None],
+                             cfg.attn_window)
+        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+        return jnp.einsum("ckgs,skd->ckgd", probs, vc).reshape(C, H * Dh)
+
+
+def _attend_occupied(q, k_pool, v_pool, trow, positions, n_valid,
+                     cfg: GPTConfig):
+    """Attention of one slot's prompt chunk over the OCCUPIED part of its
+    row. ``q`` ``[C, H, Dh]`` at ``positions``; the pools hold the chunk's
+    own K and V already; ``trow`` ``[NB]`` this layer's block ids. One dense
+    pass (gather, unfold to heads, ``_attend_rows``) over the shortest run
+    of whole tiles that holds every key a valid query may see
+    (``attended_tiles``), picked by ``lax.switch`` from the lengths the
+    table's shape allows: a chunk at ``start`` 0 of a 1,024-position row
+    reads 128 positions, not 1,024. What the run holds past a query's
+    position (a block's unwritten tail, stale rows of the blocks' earlier
+    owner) the causal band masks.
+    The branches take the pools as read-only operands and return the
+    attention only: a branch that returns a pool copies it (PERF.md, PR 40).
+    Returns ``[C, H * Dh]``."""
+    Hkv, Dh = cfg.kv_heads, cfg.head_dim
+    bs, NB = k_pool.shape[1], trow.shape[0]
+    lo, hi, P = attended_tiles(positions[0], n_valid, bs, NB,
+                               cfg.attn_window)
+    tiles = -(-NB // P)
+    # whole tiles of table entries: past the table's end the last entry
+    # again, at positions no query reaches
+    if tiles * P > NB:
+        trow = jnp.concatenate(
+            [trow, jnp.broadcast_to(trow[-1:], (tiles * P - NB,))])
+
+    def dense(n, q, k_pool, v_pool, trow, positions, lo):
+        blocks = jax.lax.dynamic_slice_in_dim(trow, lo * P, n * P)
+        with jax.named_scope("kv_gather"):
+            kc = _heads(k_pool[blocks], Hkv).reshape(n * P * bs, Hkv, Dh)
+            vc = _heads(v_pool[blocks], Hkv).reshape(n * P * bs, Hkv, Dh)
+        return _attend_rows(q, kc, vc, positions, lo * P * bs, cfg)
+
+    return jax.lax.switch(
+        jnp.clip(hi - lo, 1, tiles) - 1,
+        [partial(dense, n) for n in range(1, tiles + 1)],
+        q, k_pool, v_pool, trow, positions, jnp.int32(lo))
+
+
 def _block_prefill_paged(x, pools, table_row, positions, n_valid, p,
                          cfg: GPTConfig, lora=None, base=0):
     """Forward one block over a PROMPT CHUNK for one slot, writing the
     chunk's K/V through the slot's block table and attending over the
-    slot's full cache so far (history from earlier chunks + this chunk)
-    — the prefill-chunking path that keeps decode latency bounded for
-    long prompts. x: [1, C, D]; positions: [C] global cache positions of
-    the chunk tokens; n_valid: how many of the C lanes are real (the
-    chunk is padded to a fixed width so ONE compiled program serves
-    every chunk).
+    OCCUPIED part of the slot's row (history from earlier chunks + this
+    chunk, in whole tiles: ``_attend_occupied``; nothing past the tile of
+    the chunk's last token is gathered, unfolded or scored) — the
+    prefill-chunking path that keeps decode latency bounded for long
+    prompts. x: [1, C, D]; positions: [C] global cache positions of the
+    chunk tokens; n_valid: how many of the C lanes are real (the chunk is
+    padded to a fixed width so ONE compiled program serves every chunk;
+    the length read follows from ``positions[0]`` and ``n_valid`` inside
+    it).
 
     With four ``pools`` (int8 + scales) the slot's whole virtual row
     (gathered for attention anyway) is dequantized, the chunk inserted,
@@ -731,10 +819,8 @@ def _block_prefill_paged(x, pools, table_row, positions, n_valid, p,
     program (here the gathered factors carry the prefill row's B=1
     leading dim); ``pools`` / ``base`` and the return as in
     _block_decode_paged."""
-    B, C, D = x.shape
-    H, Dh = cfg.n_heads, cfg.head_dim
-    Hkv = cfg.kv_heads
-    group = H // Hkv
+    B, C, _ = x.shape
+    Dh, Hkv = cfg.head_dim, cfg.kv_heads
     k_pool, v_pool = pools[:2]
     k_scale, v_scale = pools[2:] if len(pools) == 4 else (None, None)
     bs = k_pool.shape[1]
@@ -755,10 +841,8 @@ def _block_prefill_paged(x, pools, table_row, positions, n_valid, p,
             off = positions % bs
             k_pool = k_pool.at[blk, off].set(_rows(k[0]))
             v_pool = v_pool.at[blk, off].set(_rows(v[0]))
-
-        with jax.named_scope("kv_gather"):
-            kc = _heads(k_pool[trow], Hkv).reshape(NB * bs, Hkv, Dh)
-            vc = _heads(v_pool[trow], Hkv).reshape(NB * bs, Hkv, Dh)
+        attn = _attend_occupied(q[0], k_pool, v_pool, trow, positions,
+                                n_valid, cfg)[None]
     else:
         with jax.named_scope("kv_write"):
             kq0 = _heads(k_pool[trow], Hkv)
@@ -798,16 +882,7 @@ def _block_prefill_paged(x, pools, table_row, positions, n_valid, p,
                 kq, ksn, dtype=x.dtype).reshape(NB * bs, Hkv, Dh)
             vc = quantizer.kv_dequantize_blocks(
                 vq, vsn, dtype=x.dtype).reshape(NB * bs, Hkv, Dh)
-    with jax.named_scope("paged_attn"):
-        qg = q[0].reshape(C, Hkv, group, Dh)
-        scores = jnp.einsum("ckgd,skd->ckgs", qg, kc).astype(jnp.float32)
-        scores *= cfg.attn_scale if cfg.attn_scale is not None \
-            else 1.0 / np.sqrt(Dh)
-        sidx = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, NB * bs), 3)
-        scores = causal_band(scores, sidx, positions[:, None, None, None],
-                             cfg.attn_window)
-        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-        attn = jnp.einsum("ckgs,skd->ckgd", probs, vc).reshape(1, C, D)
+        attn = _attend_rows(q[0], kc, vc, positions, 0, cfg)[None]
     with jax.named_scope("attn_out"):
         attn = _dense(attn, p["attn_out"], lora=lr("attn_out"))
     with jax.named_scope("mlp"):
@@ -1644,6 +1719,24 @@ class InferenceEngine:
             (("i", tables), ("i", lengths), ("i", tokens), ("b", active),
              ("static", impl), *parts), seen,
             kernel_table=np.shape(tables) if impl == "pallas" else (), **kw)
+
+    def prefill_attended(self, start: int, n: int, bs: int, nb: int,
+                         quantized: bool = False) -> int:
+        """Positions of its slot's row (``nb`` blocks of ``bs``) that a
+        prefill chunk of ``n`` tokens at ``start`` reads from the pool, in
+        a layer that pages its history: the program's own count, on the
+        host (the ``attended`` field of ``serve.prefill``). The GPT dialect
+        reads whole ``attended_tiles``, its own rows among them; the
+        latent, convolutional and linear dialects walk the occupied blocks
+        of ``[0, start)`` one by one; the windowed dialect's full layers
+        and the int8 pools' requantising write read the whole row."""
+        cfg = self.cfg
+        if quantized or hybrid.is_hybrid(cfg):
+            return nb * bs
+        if linear.is_linear(cfg) or latent.is_latent(cfg) or cca.is_cca(cfg):
+            return (start + bs - 1) // bs * bs
+        lo, hi, P = attended_tiles(start, n, bs, nb, cfg.attn_window)
+        return min(max(hi - lo, 1) * P * bs, nb * bs)
 
     def prefill_into_slot(self, k_pool, v_pool, table_row, tokens, start,
                           n_valid, scales=None, sample_state=None,

@@ -202,6 +202,12 @@ _STAT_FIELDS = (
     ("evict_capped", "c", "evictions refused by the storm guard"),
     ("watchdog_trips", "c", "over-budget decode dispatches"),
     ("backpressure", "g", "queue fullness in [0, 1]"),
+    ("prefill_attended_tokens_total", "c",
+     "positions of their slots' rows that prefill chunks read from the "
+     "pool (InferenceEngine.prefill_attended: the occupied part)"),
+    ("prefill_row_tokens_total", "c",
+     "positions of a whole row per prefill chunk; attended / row is the "
+     "share of the row a chunk still reads"),
     ("prefix_hits", "c", "admissions that matched a cached prefix"),
     ("prefix_tokens_saved", "c", "prompt tokens served from shared blocks"),
     ("spec_steps", "c", "speculative verify dispatches"),
@@ -1549,9 +1555,16 @@ class ServingEngine:
                 continue
             done = int(self._progress[slot])
             n = min(self.prefill_chunk, len(req._work) - done)
+            attended = self.engine.prefill_attended(
+                done, n, self.cache.block_size, self.cache.blocks_per_slot,
+                self.cache.quantized)
+            self._stat["prefill_attended_tokens_total"].inc(attended)
+            self._stat["prefill_row_tokens_total"].inc(
+                self.cache.tokens_per_slot)
             with self.telemetry.tracer.span(
                     "serve.prefill", rid=req.rid, step=self._step_clock,
                     slot=slot, start=done, n=n, history=done,
+                    attended=attended,
                     tail=int(done > 0 and self.cache.cca_tail_bytes > 0),
                     state=int(done > 0
                               and self.cache.recurrent_state_bytes > 0)):

@@ -17,10 +17,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deepspeed_tpu.inference import engine as engine_lib
 from deepspeed_tpu.inference.engine import InferenceEngine
+from deepspeed_tpu.inference.hybrid import _rows, causal_band
 from deepspeed_tpu.inference.serving import ServeRequest, ServingEngine
 from deepspeed_tpu.models import gpt
 from deepspeed_tpu.ops.attention.paged import (blocks_per_step, decode_plan,
+                                               gather_pool_blocks,
                                                paged_decode_attention,
                                                paged_decode_reference,
                                                resolve_decode_impl,
@@ -617,3 +620,153 @@ def test_engine_masks_capacity_overflow_write(devices, pallas_interpret,
                               np.asarray(kp)[:, 5, 1])
     assert not np.array_equal(np.asarray(v2)[:, 5, 1],
                               np.asarray(vp)[:, 5, 1])
+
+
+# ---------------------------------------------------------------------------
+# the prefill chunk's read: the occupied part of the slot's row only
+# ---------------------------------------------------------------------------
+
+def _whole_row_block(x, pools, table_row, positions, n_valid, p, cfg, lora,
+                     base):
+    """The plain reference: one block over a prompt chunk that writes the
+    chunk's K and V, gathers the slot's WHOLE row, whatever is occupied,
+    and lets the causal band mask the rest (the engine's two-pool read
+    until PR 41)."""
+    B, C, D = x.shape
+    H, Dh, Hkv = cfg.n_heads, cfg.head_dim, cfg.kv_heads
+    k_pool, v_pool = pools
+    bs, NB = k_pool.shape[1], table_row.shape[0]
+    lr = (lambda t: None) if lora is None else lora.get
+    h = gpt._norm(x, p["ln1"], cfg)
+    qkv = gpt._dense(h, p["qkv"], lora=lr("qkv"))
+    q, k, v = gpt._qkv_split_rotary(qkv, cfg, positions[None], B, C)
+    valid = jnp.arange(C) < n_valid
+    trow = table_row + base
+    blk = table_row[jnp.clip(positions // bs, 0, NB - 1)]
+    blk = jnp.where(valid, blk, 0) + base
+    k_pool = k_pool.at[blk, positions % bs].set(_rows(k[0]))
+    v_pool = v_pool.at[blk, positions % bs].set(_rows(v[0]))
+    kc = gather_pool_blocks(k_pool, trow[None], Hkv)[0]   # [NB*bs, Hkv, Dh]
+    vc = gather_pool_blocks(v_pool, trow[None], Hkv)[0]
+    qg = q[0].reshape(C, Hkv, H // Hkv, Dh)
+    scores = jnp.einsum("ckgd,skd->ckgs", qg, kc).astype(jnp.float32)
+    scores *= cfg.attn_scale if cfg.attn_scale is not None \
+        else 1.0 / np.sqrt(Dh)
+    sidx = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, NB * bs), 3)
+    scores = causal_band(scores, sidx, positions[:, None, None, None],
+                         cfg.attn_window)
+    probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+    attn = jnp.einsum("ckgs,skd->ckgd", probs, vc).reshape(1, C, D)
+    x = x + gpt._dense(attn, p["attn_out"], lora=lr("attn_out"))
+    h = gpt._norm(x, p["ln2"], cfg)
+    return x + engine_lib._ffn(h, p, cfg, lora=lora), (k_pool, v_pool)
+
+
+_CHUNK = 64
+_READ_VARIANTS = {
+    # GPT-2's own shape of attention, and everything the read must carry
+    # at once: grouped KV heads, a window, a scale of its own, a LoRA row,
+    # and a table whose first blocks another slot's table holds too
+    "plain": dict(over={}, lora=False, shared=False),
+    "gqa_window_lora_shared": dict(
+        over=dict(n_kv_heads=2, attn_window=200, attn_scale=0.2),
+        lora=True, shared=True),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _read_problem(variant):
+    spec = _READ_VARIANTS[variant]
+    cfg = gpt.GPTConfig(vocab_size=128, n_layers=2, n_heads=4, d_model=64,
+                        max_seq_len=1024, use_flash_attention=False,
+                        remat=False, dtype=jnp.float32, **spec["over"])
+    params = gpt.init_params(jax.random.PRNGKey(0), cfg)
+    layer = 1                       # not the first: ``base`` is not 0
+    p = jax.tree_util.tree_map(lambda a: a[layer], params["block"])
+    bs, NB = 16, 64                 # the cells' block and table: 1,024
+    N = 2 * NB + 1                  # two slots' blocks and the trash block
+    r = np.random.default_rng(7)
+    D, row = cfg.d_model, cfg.kv_heads * cfg.head_dim
+    # stale floats in every lane no chunk has written
+    pools = tuple(jnp.asarray(r.normal(size=(cfg.n_layers * N, bs, row)),
+                              jnp.float32) for _ in range(2))
+    ids = r.permutation(np.arange(1, N))
+    other, own = ids[:NB], ids[NB:]
+    lora = None
+    if spec["lora"]:
+        def factors(i, o, rb=4, nba=2):
+            return (jnp.asarray(r.normal(size=(1, nba, i, rb)) * 0.1,
+                                jnp.float32),
+                    jnp.asarray(r.normal(size=(1, nba, rb, o)) * 0.1,
+                                jnp.float32))
+        lora = {"qkv": factors(D, cfg.qkv_dim), "attn_out": factors(D, D)}
+    x = jnp.asarray(r.normal(size=(1, _CHUNK, D)), jnp.float32)
+
+    def table_for(start):
+        # the blocks below the matched boundary are the other slot's
+        shared = start // bs if spec["shared"] else 0
+        return jnp.asarray(np.concatenate([other[:shared], own[shared:]]),
+                           jnp.int32)
+
+    def run(block):
+        def chunk(pools, table_row, start, n_valid):
+            positions = start + jnp.arange(_CHUNK, dtype=jnp.int32)
+            return block(x, pools, table_row, positions, n_valid, p, cfg,
+                         lora=lora, base=layer * N)
+        return jax.jit(chunk)
+    return (pools, table_for, run(engine_lib._block_prefill_paged),
+            run(_whole_row_block), other, layer * N)
+
+
+@pytest.mark.parametrize("variant", sorted(_READ_VARIANTS))
+@pytest.mark.parametrize("n_valid", [1, _CHUNK - 1, _CHUNK])
+@pytest.mark.parametrize("start", [0, 16, 48, 64, 127, 128, 192, 512, 960])
+def test_prefill_chunk_reads_the_occupied_part_of_its_row(devices, start,
+                                                          n_valid, variant):
+    """The dense pass over the shortest run of tiles that holds what the
+    chunk's valid queries see is the whole-row softmax: the same output on
+    every valid lane, and the same pools to the last bit (the write is
+    what it was, and a shared block is read, never written)."""
+    pools, table_for, new, ref, other, base = _read_problem(variant)
+    table_row = table_for(start)
+    y, got = new(pools, table_row, start, n_valid)
+    y_ref, want = ref(pools, table_row, start, n_valid)
+    np.testing.assert_allclose(np.asarray(y)[0, :n_valid],
+                               np.asarray(y_ref)[0, :n_valid],
+                               atol=2e-5, rtol=2e-5)
+    assert np.isfinite(np.asarray(y)).all()
+    for a, b, before in zip(got, want, pools):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        if _READ_VARIANTS[variant]["shared"]:
+            np.testing.assert_array_equal(np.asarray(a)[other + base],
+                                          np.asarray(before)[other + base])
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4))
+def _traced_tiles(start, n, bs, nb, window):
+    return engine_lib.attended_tiles(start, n, bs, nb, window)[:2]
+
+
+@pytest.mark.parametrize("bs,nb,window", [(16, 64, None), (16, 64, 200),
+                                          (4, 16, None), (512, 48, None),
+                                          (16, 100, 1), (16, 512, 300)])
+def test_attended_tiles_cover_what_a_chunk_sees_and_little_more(bs, nb,
+                                                                window):
+    """The length the program picks from ``(start, n)`` and the count the
+    scheduler makes of it are ONE function: every key a valid query may
+    see lies in ``[lo, hi)``, the run is no longer than that needs, the
+    table is cut into at most ``PREFILL_READ_LENGTHS`` tiles, and a
+    traced ``start`` gives what a Python one gives."""
+    attended_tiles = engine_lib.attended_tiles
+    for start in range(0, nb * bs - 64, max(1, nb * bs // 97)):
+        for n in (1, 63, 64):
+            lo, hi, P = attended_tiles(start, n, bs, nb, window)
+            W = P * bs
+            assert P % blocks_per_step(nb, bs) == 0
+            assert -(-nb // P) <= engine_lib.PREFILL_READ_LENGTHS
+            oldest = 0 if window is None else max(start - window + 1, 0)
+            assert lo * W <= oldest < (lo + 1) * W
+            assert (hi - 1) * W < start + n <= hi * W
+            tlo, thi = _traced_tiles(jnp.int32(start), jnp.int32(n), bs, nb,
+                                     window)
+            assert (int(tlo), int(thi)) == (lo, hi)

@@ -17,6 +17,7 @@ PR 39).
 """
 
 import json
+import math
 import pathlib
 
 import jax
@@ -195,6 +196,37 @@ def test_decode_program_attends_through_the_mosaic_kernel(compiled_cell):
     reg.add_provenance("prefill_slot", exes["prefill_slot"][0].as_text(),
                        pool_blocks=(N, L * N))
     assert "paged_grid_steps" not in reg.entries["prefill_slot"]
+
+
+def test_prefill_program_gathers_the_occupied_part_of_a_row(compiled_cell):
+    """Read off the compiled prefill program: every gather of pool rows
+    lies in a branch of the ``lax.switch`` on the chunk's position, the
+    branch taken at ``start`` 0 yields ONE tile of positions (128 of the
+    row's 1,024), the lengths grow by a tile a branch, and only the last
+    yields the whole row (PERF.md, PR 41: until then every chunk gathered
+    and unfolded all 1,024, whatever was occupied)."""
+    from deepspeed_tpu.inference.engine import attended_tiles
+    _, table = compiled_cell[1]["prefill_slot"]
+    m, sv = CELL["model"], CELL["serving"]
+    bs, NB = sv["block_size"], m["n_positions"] // sv["block_size"]
+    lo, hi, P = attended_tiles(0, sv["prefill_chunk"], bs, NB)
+    assert (lo, hi, P * bs) == (0, 1, 128)
+    lanes = m["n_embd"]                      # Hkv * Dh: a pool row
+    gathered = {}
+    for name, e in table.items():
+        if "kv_gather" not in e.get("scope", "") \
+                or not e["shape"].startswith("bf16["):
+            continue
+        branch = [part for part in e["scope"].split("/")
+                  if part.startswith("branch_")]
+        assert len(branch) == 1, (name, e["scope"])
+        size = math.prod(shape_dims(e["shape"])[1])
+        assert size % lanes == 0, (name, e["shape"])
+        index = int(branch[0].split("_")[1])
+        gathered[index] = max(gathered.get(index, 0), size // lanes)
+    tiles = NB // P
+    assert gathered == {n: (n + 1) * P * bs for n in range(tiles)}, gathered
+    assert gathered[0] * 8 == gathered[tiles - 1] == NB * bs
 
 
 def test_pool_copy_bytes_counts_pool_shaped_copies_only():

@@ -476,6 +476,47 @@ def test_step_spans_tile_the_step_and_tokens_match_off(eng):
     assert not any(r[1] == "step_phase" for r in tel.tracer.records())
 
 
+@pytest.mark.parametrize("window", [None, 160])
+def test_prefill_span_counts_the_positions_its_chunk_reads(devices, window):
+    """``attended`` on ``serve.prefill`` is what the program's own function
+    of ``(start, n)`` says (engine.attended_tiles: whole tiles up to the
+    chunk's last token, from the window's first), the two counters add it
+    up beside the whole row, and after prompts of two and more chunks
+    their ratio is below 1."""
+    from deepspeed_tpu.inference.engine import attended_tiles
+    cfg = gpt.GPTConfig(vocab_size=128, n_layers=2, n_heads=4, d_model=32,
+                        max_seq_len=512, use_flash_attention=False,
+                        remat=False, dtype=jnp.float32, attn_window=window)
+    params = gpt.init_params(jax.random.PRNGKey(0), cfg)
+    eng = InferenceEngine(config=cfg, params=params, dtype=jnp.float32)
+    tel = Telemetry()
+    bs, chunk = 4, 96
+    srv = ServingEngine(eng, num_slots=2, block_size=bs, num_blocks=160,
+                        prefill_chunk=chunk, telemetry=tel)
+    nb = srv.cache.blocks_per_slot
+    prompts = prompts_of((2 * chunk - 5, 3 * chunk + 1), seed=9)
+    srv.run([ServeRequest(rid=f"r{i}", prompt=p, max_new_tokens=2)
+             for i, p in enumerate(prompts)])
+    prefills = [r[5] for r in tel.tracer.spans() if r[1] == "serve.prefill"]
+    assert sorted((f["start"], f["n"]) for f in prefills) == [
+        (0, 96), (0, 96), (96, 91), (96, 96), (192, 96), (288, 1)]
+    for f in prefills:
+        lo, hi, P = attended_tiles(f["start"], f["n"], bs, nb, window)
+        assert P * bs == 128
+        assert f["attended"] == (hi - lo) * P * bs
+        assert f["start"] + f["n"] <= lo * P * bs + f["attended"] \
+            < f["start"] + f["n"] + P * bs
+    attended = srv.stats["prefill_attended_tokens_total"]
+    row = srv.stats["prefill_row_tokens_total"]
+    assert attended == sum(f["attended"] for f in prefills)
+    assert row == len(prefills) * nb * bs == 6 * 512
+    # 128 + 128 + 256 + 256 + 384 + 384 of 6 x 512; under the window the
+    # chunk at 288 sees nothing below 129 and drops the first tile (the
+    # one at 192 still sees position 33)
+    assert attended == (1536 if window is None else 1536 - 128)
+    assert attended / row <= 0.5
+
+
 def test_stats_view_read_only_and_registry_backed(eng):
     p, = prompts_of((6,), seed=3)
     srv = ServingEngine(eng, num_slots=2, block_size=4, num_blocks=24)
