@@ -467,8 +467,31 @@ def _paged_plan(pools, tables, lengths, active, cfg, q_len: int = 1):
 def _block_decode_paged(x, pools, tables, lengths, active, p,
                         cfg: GPTConfig, impl: str = "gather", lora=None,
                         base=0, plan=None):
-    """One block for ONE new token per slot, K/V addressed through block
-    tables — the paged generalization of _block_decode. x: [B, 1, D];
+    """One block for ONE new token per slot: :func:`_attn_decode_paged`
+    and the block's FFN behind it. Returns (y, pools)."""
+    h, attn, pools = _attn_decode_paged(x, pools, tables, lengths, active,
+                                        p, cfg, impl=impl, lora=lora,
+                                        base=base, plan=plan)
+    return _mlp_behind(x, h, attn, p, cfg, lora), pools
+
+
+def _mlp_behind(x, h, attn, p, cfg: GPTConfig, lora=None):
+    """The rest of a GPT block behind its attention: ``x`` the stream,
+    ``h`` its first norm, ``attn`` the projected attention."""
+    with jax.named_scope("mlp"):
+        if cfg.parallel_residual:
+            return x + attn + _ffn(h, p, cfg, lora=lora)
+        x = x + attn
+        return x + _ffn(_norm(x, p["ln2"], cfg), p, cfg, lora=lora)
+
+
+def _attn_decode_paged(x, pools, tables, lengths, active, p,
+                       cfg: GPTConfig, impl: str = "gather", lora=None,
+                       base=0, plan=None):
+    """The attention of one block for ONE new token per slot, K/V addressed
+    through block tables — the paged generalization of _block_decode's,
+    and what a model whose other layers are not attention (inference/
+    ssm.py) calls by itself. x: [B, 1, D];
     ``pools`` = (k_pool, v_pool) of [N', block, Hkv*Dh] — ALL layers'
     blocks, this layer's starting at ``base`` (_scan_layers) — tables
     [B, NB] of block ids within a layer; lengths [B] per-slot cache
@@ -492,7 +515,8 @@ def _block_decode_paged(x, pools, tables, lengths, active, p,
     ``lora`` (multi-tenant adapter serving, inference/adapters.py) is a
     dict target -> per-slot gathered rank-block factors handed through
     to :func:`~deepspeed_tpu.models.gpt._dense`; ``lora=None`` (the
-    default) traces the exact base-only program. Returns (y, pools)."""
+    default) traces the exact base-only program. Returns (``ln1(x)``, the
+    projected attention ``[B, 1, D]``, pools)."""
     B, _, D = x.shape
     H, Dh = cfg.n_heads, cfg.head_dim
     Hkv = cfg.kv_heads
@@ -574,16 +598,9 @@ def _block_decode_paged(x, pools, tables, lengths, active, p,
             attn = jnp.einsum("bkgs,bskd->bkgd", probs, vc).reshape(B, 1, D)
     with jax.named_scope("attn_out"):
         attn = _dense(attn, p["attn_out"], lora=lr("attn_out"))
-    with jax.named_scope("mlp"):
-        if cfg.parallel_residual:
-            y = x + attn + _ffn(h, p, cfg, lora=lora)
-        else:
-            x = x + attn
-            h = _norm(x, p["ln2"], cfg)
-            y = x + _ffn(h, p, cfg, lora=lora)
     if k_scale is None:
-        return y, (k_pool, v_pool)
-    return y, (k_pool, v_pool, k_scale, v_scale)
+        return h, attn, (k_pool, v_pool)
+    return h, attn, (k_pool, v_pool, k_scale, v_scale)
 
 
 def _block_verify_paged(x, pools, tables, lengths, active, p,
@@ -798,7 +815,19 @@ def _attend_occupied(q, k_pool, v_pool, trow, positions, n_valid,
 
 def _block_prefill_paged(x, pools, table_row, positions, n_valid, p,
                          cfg: GPTConfig, lora=None, base=0):
-    """Forward one block over a PROMPT CHUNK for one slot, writing the
+    """One block over a PROMPT CHUNK for one slot:
+    :func:`_attn_prefill_paged` and the block's FFN behind it. Returns (y,
+    pools)."""
+    h, attn, pools = _attn_prefill_paged(x, pools, table_row, positions,
+                                         n_valid, p, cfg, lora=lora,
+                                         base=base)
+    return _mlp_behind(x, h, attn, p, cfg, lora), pools
+
+
+def _attn_prefill_paged(x, pools, table_row, positions, n_valid, p,
+                        cfg: GPTConfig, lora=None, base=0):
+    """The attention of one block over a PROMPT CHUNK for one slot (what
+    inference/ssm.py calls by itself, as _attn_decode_paged), writing the
     chunk's K/V through the slot's block table and attending over the
     OCCUPIED part of the slot's row (history from earlier chunks + this
     chunk, in whole tiles: ``_attend_occupied``; nothing past the tile of
@@ -818,7 +847,7 @@ def _block_prefill_paged(x, pools, table_row, positions, n_valid, p,
     the exact pre-quant program; ``lora=None`` the exact base-only
     program (here the gathered factors carry the prefill row's B=1
     leading dim); ``pools`` / ``base`` and the return as in
-    _block_decode_paged."""
+    _attn_decode_paged."""
     B, C, _ = x.shape
     Dh, Hkv = cfg.head_dim, cfg.kv_heads
     k_pool, v_pool = pools[:2]
@@ -885,16 +914,9 @@ def _block_prefill_paged(x, pools, table_row, positions, n_valid, p,
         attn = _attend_rows(q[0], kc, vc, positions, 0, cfg)[None]
     with jax.named_scope("attn_out"):
         attn = _dense(attn, p["attn_out"], lora=lr("attn_out"))
-    with jax.named_scope("mlp"):
-        if cfg.parallel_residual:
-            y = x + attn + _ffn(h, p, cfg, lora=lora)
-        else:
-            x = x + attn
-            h = _norm(x, p["ln2"], cfg)
-            y = x + _ffn(h, p, cfg, lora=lora)
     if k_scale is None:
-        return y, (k_pool, v_pool)
-    return y, (k_pool, v_pool, k_scale, v_scale)
+        return h, attn, (k_pool, v_pool)
+    return h, attn, (k_pool, v_pool, k_scale, v_scale)
 
 
 class InferenceEngine:
@@ -1239,7 +1261,8 @@ class InferenceEngine:
         elif linear.is_linear(cfg):
             x, pools = self._linear_layers(
                 params, pools, linear.prefill_attends(
-                    cfg, table_row, positions, n_valid, slot), x,
+                    cfg, table_row, positions, n_valid, slot,
+                    self.decode_impl), x,
                 jnp.arange(C) < n_valid, self.decode_impl, 0)
         elif latent.is_latent(cfg):
             def lblock(carry, flat, layer_p, base, lora, experts):
@@ -1385,32 +1408,37 @@ class InferenceEngine:
 
     def _linear_layers(self, params, pools, attends, x, valid, impl: str,
                        phase: int):
-        """The layers of both serving programs for a model whose layers are
-        linear attention or latent attention by a list in its config
-        (inference/linear.py ``run_layers``, whose loop this feeds):
-        ``pools`` = (LinearState, None), the latent layers' pool, the
-        linear layers' recurrent state and their convolution tails side by
-        side in the carry, each stacked over its OWN kind's layers;
-        ``attends``: the two attention sublayers; ``valid`` ``[T]``: the
-        rows that are tokens. ``x`` ``[1, C, d]`` or ``[B, 1, d]``."""
-        from deepspeed_tpu.models.kimi_linear import layer_bases
+        """The layers of both serving programs for a model whose layers
+        keep a per-slot recurrent state or page their history, by its
+        config (inference/linear.py ``run_layers``, whose loop this
+        feeds): ``pools`` = (LinearState, the V pool or None), the paged
+        layers' pool or pools, the recurrent layers' state and their
+        convolution tails side by side in the carry, each stacked over its
+        OWN kind's layers; ``attends``: the two attention sublayers;
+        ``valid`` ``[T]``: the rows that are tokens. ``x`` ``[1, C, d]`` or
+        ``[B, 1, d]``."""
+        from deepspeed_tpu.models.recurrent import layer_bases
         cfg = self.cfg
-        st, none = pools
-        shapes = (st.rows, st.state, st.tail)
+        st, v = pools
+        shapes = (st.rows,) + (() if v is None else (v,)) \
+            + (st.state, st.tail)
         flat = tuple(p.reshape((-1,) + p.shape[2:]) for p in shapes)
-        params, experts = hybrid.split_experts(params)
         T = x.shape[0] * x.shape[1]
+        experts, aux = None, {"stats": None, "route": None}
+        if "moe" in params["block"]:
+            params, experts = hybrid.split_experts(params)
+            aux = self._dispatch_record(T, st.stats)
         (y, aux), flat = linear.run_layers(
-            cfg, params, experts,
-            (x.reshape(T, -1), self._dispatch_record(T, st.stats)), flat,
+            cfg, params, experts, (x.reshape(T, -1), aux), flat,
             layer_bases(cfg, st.rows.shape[1], st.state.shape[1]), attends,
             valid, impl)
         stats = st.stats
         if stats is not None:
             stats = stats.at[phase].add(aux["stats"])
-        rows, state, tail = (f.reshape(p.shape) for f, p in zip(flat, shapes))
+        flat = [f.reshape(p.shape) for f, p in zip(flat, shapes)]
         return y.reshape(x.shape), (linear.LinearState(
-            rows, state, tail, stats, aux["route"]), none)
+            flat[0], flat[-2], flat[-1], stats, aux["route"]),
+            None if v is None else flat[1])
 
     def _dispatch_record(self, tokens: int, stats):
         """What a sparse model's layer loop carries beside ``x``: the
@@ -1726,14 +1754,16 @@ class InferenceEngine:
         prefill chunk of ``n`` tokens at ``start`` reads from the pool, in
         a layer that pages its history: the program's own count, on the
         host (the ``attended`` field of ``serve.prefill``). The GPT dialect
-        reads whole ``attended_tiles``, its own rows among them; the
-        latent, convolutional and linear dialects walk the occupied blocks
-        of ``[0, start)`` one by one; the windowed dialect's full layers
+        reads whole ``attended_tiles``, its own rows among them (so do the
+        attention layers beside a state-space state); the latent and
+        convolutional dialects, and the latent layers beside a
+        linear-attention state, walk the occupied blocks of ``[0, start)``
+        one by one; the windowed dialect's full layers
         and the int8 pools' requantising write read the whole row."""
         cfg = self.cfg
         if quantized or hybrid.is_hybrid(cfg):
             return nb * bs
-        if linear.is_linear(cfg) or latent.is_latent(cfg) or cca.is_cca(cfg):
+        if latent.is_latent(cfg) or cca.is_cca(cfg):
             return (start + bs - 1) // bs * bs
         lo, hi, P = attended_tiles(start, n, bs, nb, cfg.attn_window)
         return min(max(hi - lo, 1) * P * bs, nb * bs)
@@ -1748,8 +1778,8 @@ class InferenceEngine:
             # the program finds the slot's tail or recurrent state by the
             # lane's slot index
             raise ValueError("a prefill for a model with per-slot state "
-                             "beside the pools (convolutional or linear "
-                             "attention) needs the slot's sampling lane "
+                             "beside the pools (convolutional attention, a "
+                             "recurrent state) needs the slot's sampling lane "
                              "(sample_state): it names the slot")
         lanes, seen = self._samp_lanes(sample_state, 1, self.cfg.vocab_size,
                                        scalar=True)

@@ -1,57 +1,67 @@
 """Paged serving blocks for a model whose layers are of two kinds of
-attention STATE (models/kimi_linear.py): a FIFTH dialect, and the first
-whose slot costs memory before it holds a token.
+cache STATE: a FIFTH dialect, and the first whose slot costs memory before
+it holds a token.
 
-- The latent (MLA) layers keep a row a token in ONE paged pool behind the
-  slot's block table, exactly latent.py's (its two attention paths are
-  called from here, :func:`latent.attend_prefill` / ``attend_decode``).
-- The linear-attention (KDA) layers keep, per slot and layer, a RECURRENT
-  STATE that summarises the slot's whole history: ``linear_heads`` float32
-  matrices ``[Dv, Dk]`` (ops/attention/kda.py keeps them transposed), read
-  AND rewritten by every token, whatever the sequence's length, and not
-  recomputable from any block; beside it the un-convolved ``[q | k | v]``
-  rows of the last ``conv_kernel - 1`` tokens, the left context of the
-  depthwise convolution. No block table reaches either.
+- The RECURRENT layers keep, per slot and layer, a float32 state that
+  summarises the slot's whole history, read AND rewritten by every token
+  whatever the sequence's length and not recomputable from any block, and
+  beside it the un-convolved rows of the last ``conv_kernel - 1`` tokens,
+  the left context of a depthwise convolution. No block table reaches
+  either. Two RULES write such a state today, and the store, the layer
+  loop and the resume rule below are the state's, not a rule's:
+  gated delta-rule linear attention (KDA, models/kimi_linear.py: this
+  file's ``kda_*`` blocks over ops/attention/kda.py; ``linear_heads``
+  matrices ``[Dv, Dk]`` a layer, its tail the ``[q | k | v]`` rows) and a
+  Mamba-1 state-space mixer (models/jamba.py: inference/ssm.py over
+  ops/attention/ssm.py; ``[d_state, d_inner]`` a layer, its tail the ``x``
+  rows).
+- The PAGED layers keep rows behind the slot's block table: ONE pool of
+  latent rows (Kimi-Linear's MLA layers, latent.py's two attention paths
+  called from here) or the GPT blocks' K and V pools (Jamba's attention
+  layers, the engine's own two paths called from ssm.py).
 
-:class:`LinearState` rides in ``k_pool``'s place (``v_pool`` is None):
-``rows`` ``[L_mla, N, block, lanes]``, ``state`` ``[L_kda, slots, H, Dv,
-Dk]`` float32, ``tail`` ``[L_kda, slots, (taps - 1) * 3 H Dh]`` (a slot's
-rows side by side, oldest first: with ``taps - 1 = 3`` rows a dimension of
+:class:`LinearState` rides in ``k_pool``'s place (``v_pool`` is None, or
+the V pool where the paged layers keep two): ``rows`` ``[L_paged, N,
+block, lanes]``, ``state`` ``[L_rec, slots, *cfg.recurrent_state_shape]``
+float32, ``tail`` ``[L_rec, slots, cfg.conv_tail_width]`` (a slot's rows
+side by side, oldest first: with ``taps - 1 = 3`` rows a dimension of
 their own the device pads them to a tile of 8 or 16 and both programs
 re-laid the buffer out on every dispatch). Each kind's
 buffers are indexed by the kind's OWN layer counter
-(models/kimi_linear.layer_bases): which layer is of which kind is a list in
-the config and follows no period.
+(models/recurrent.layer_bases): which layer is of which kind is the
+config's to say (a list, a period) and the loop follows any order.
 
 A prefill chunk carries its slot's state from chunk to chunk THROUGH the
 state buffer: it starts from the slot's state and tail when ``start > 0``
 and from zeros when ``start = 0`` (a reused slot starts clean without
-anything being cleared), runs the chunkwise-parallel rule (``kda_chunk``)
-and leaves the state after its last valid token. A decode dispatch is one
-recurrent step (``kda_step``) over the ACTIVE slots, in place; the state of
-an idle slot, or of one still in prefill, is not touched. A preempted
-request recomputes from position 0, as any other: the replay rebuilds the
-state.
+anything being cleared), runs the rule's chunk form (``kda_chunk``,
+``ssm_scan``) and leaves the state after its last valid token. A decode
+dispatch is one recurrent step (``kda_step``, ``ssm_step``) over the
+ACTIVE slots, in place; the state of an idle slot, or of one still in
+prefill, is not touched. A preempted request recomputes from position 0,
+as any other: the replay rebuilds the state.
 
 One compiled body per KIND of layer (:func:`run_layers`): the leading
-dense layers inline (linear attention, by the config), then ONE scan over
-the runs the list cuts the sparse layers into, each run an inner loop over
-its linear layers and the latent layer that ends it, all three buffers in
-the loops' carries.
+dense layers inline (recurrent, by the config), then ONE scan over the
+runs the kinds cut the other layers into, each run an inner loop over its
+recurrent layers and the paged layer that ends it, every buffer in the
+loops' carries.
 
 Not served by this dialect, and refused at construction by name (no
 program of theirs carries the state): prefix sharing and copy-on-write, the
 host tier, int8 pools, speculation/verify, the fused horizon, LoRA, tensor
-parallelism; nor the static-cache paths. docs/LINEAR_ATTENTION.md."""
+parallelism; nor the static-cache paths. docs/LINEAR_ATTENTION.md,
+docs/STATE_SPACE.md."""
 
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference import latent
+from deepspeed_tpu.inference import latent, ssm
 from deepspeed_tpu.inference.hybrid import _ffn, _heads, _rows
 from deepspeed_tpu.models.gpt import _dense, _norm
+from deepspeed_tpu.models.recurrent import layer_runs
 from deepspeed_tpu.ops.attention import kda
 
 
@@ -72,29 +82,42 @@ class LinearState(NamedTuple):
 
 
 def is_linear(cfg) -> bool:
-    return bool(getattr(cfg, "kda_layers", ()))
+    """A model that keeps a per-slot recurrent state, whichever rule
+    writes it."""
+    return bool(getattr(cfg, "recurrent_state_values", 0))
 
 
 def refuse(cfg, feature: str):
     """Raise for a serving feature whose programs do not carry the state."""
     if is_linear(cfg):
+        rule, doc = ("state-space", "STATE_SPACE") if ssm.is_ssm(cfg) \
+            else ("linear-attention", "LINEAR_ATTENTION")
         raise ValueError(
-            f"{feature} is not supported for a model with linear-attention "
-            f"layers (a per-slot recurrent state that summarises the whole "
-            f"history rides beside the paged pool): see "
-            f"docs/LINEAR_ATTENTION.md")
+            f"{feature} is not supported for a model with a per-slot "
+            f"recurrent state (written by its {rule} layers: it summarises "
+            f"the whole history and rides beside the paged pool): see "
+            f"docs/{doc}.md")
 
 
 def new_state(cfg, num_blocks: int, block_size: int, num_slots: int, dtype):
-    """Zeroed LinearState for ``num_blocks`` blocks a latent layer."""
-    H, Dh = cfg.linear_heads, cfg.linear_head_dim
-    Lk = cfg.n_kda_layers
+    """Zeroed LinearState for ``num_blocks`` blocks a paged layer: ``rows``
+    one pool of latent rows, or the K pool of the K/V heads' rows (the V
+    pool beside it is :func:`paged_v_pool`)."""
+    Lr = cfg.n_recurrent_layers
     return LinearState(
         jnp.zeros((cfg.n_full_layers, num_blocks, block_size,
-                   cfg.latent_lanes), dtype),
-        jnp.zeros((Lk, num_slots, H, Dh, Dh), jnp.float32),
-        jnp.zeros((Lk, num_slots, (cfg.conv_kernel - 1) * cfg.kda_channels),
-                  dtype))
+                   cfg.latent_lanes if latent.is_latent(cfg)
+                   else cfg.kv_heads * cfg.head_dim), dtype),
+        jnp.zeros((Lr, num_slots) + tuple(cfg.recurrent_state_shape),
+                  jnp.float32),
+        jnp.zeros((Lr, num_slots, cfg.conv_tail_width), dtype))
+
+
+def paged_v_pool(cfg, state: LinearState):
+    """What rides in ``v_pool``'s place beside ``state``: nothing where the
+    paged layers keep one pool of latent rows, a zeroed V pool where they
+    keep K and V."""
+    return None if latent.is_latent(cfg) else jnp.zeros_like(state.rows)
 
 
 def step_plan(active):
@@ -220,10 +243,16 @@ def kda_decode(x, state, tails, active, p, cfg, at, impl, plan):
         return _output(x, o, gate, p, cfg), state, tails
 
 
-def prefill_attends(cfg, table_row, positions, n_valid, slot):
+def prefill_attends(cfg, table_row, positions, n_valid, slot, impl):
     """The two attention sublayers of a PROMPT CHUNK of slot ``slot``, as
-    :func:`run_layers` calls them: ``attend(x [C, d], flat, p, base) -> (x +
-    attention, flat)`` with ``flat`` = (rows, state, tails)."""
+    :func:`run_layers` calls them, the recurrent kind's first:
+    ``attend(x [C, d], flat, p, base) -> (x + attention, flat)`` with
+    ``flat`` = (rows, state, tails), or the state-space rule's pair
+    (inference/ssm.py) over (K pool, V pool, state, tails)."""
+    if ssm.is_ssm(cfg):
+        return ssm.prefill_attends(cfg, table_row, positions, n_valid, slot,
+                                   impl)
+
     def linear_attn(x, flat, p, base):
         rows, state, tails = flat
         y, state, tails = kda_prefill(x, state, tails, slot, positions,
@@ -237,20 +266,23 @@ def prefill_attends(cfg, table_row, positions, n_valid, slot):
     return linear_attn, latent_attn
 
 
-def decode_attends(cfg, tables, lengths, active, impl, mla_plan):
+def decode_attends(cfg, tables, lengths, active, impl, paged_plan):
     """The same for ONE new token per slot (``x`` ``[B, d]``);
-    ``mla_plan``: the latent kernel's grid for these lengths."""
-    kda_plan = step_plan(active)
+    ``paged_plan``: the paged layers' kernel's grid for these lengths."""
+    plan = step_plan(active)
+    if ssm.is_ssm(cfg):
+        return ssm.decode_attends(cfg, tables, lengths, active, impl,
+                                  paged_plan, plan)
 
     def linear_attn(x, flat, p, base):
         rows, state, tails = flat
         y, state, tails = kda_decode(x, state, tails, active, p, cfg,
-                                     base["state"], impl, kda_plan)
+                                     base["state"], impl, plan)
         return y, (rows, state, tails)
 
     def latent_attn(x, flat, p, base):
         y, rows = latent.attend_decode(x, flat[0], tables, lengths, active,
-                                       p, cfg, base["rows"], impl, mla_plan)
+                                       p, cfg, base["rows"], impl, paged_plan)
         return y, (rows,) + flat[1:]
     return linear_attn, latent_attn
 
@@ -258,23 +290,25 @@ def decode_attends(cfg, tables, lengths, active, impl, mla_plan):
 def run_layers(cfg, params, experts, carry, flat, bases, attends, valid,
                impl):
     """Every layer of one serving program. ``carry`` = (x ``[T, d]``, aux
-    as engine._dense_then_sparse makes it); ``flat`` = (rows, state,
-    tails), each flat over its OWN kind's layers; ``bases``: per layer, by
-    layer index (models/kimi_linear.layer_bases); ``attends``:
-    :func:`prefill_attends` or :func:`decode_attends`; ``params`` without
-    the expert kernels, which are ``experts`` (hybrid.split_experts).
+    as engine._dense_then_sparse makes it); ``flat``: the cache's buffers
+    as the ``attends`` take them, each flat over its OWN kind's layers;
+    ``bases``: per layer, by layer index (models/recurrent.layer_bases);
+    ``attends``: :func:`prefill_attends` or :func:`decode_attends`, the
+    recurrent kind's sublayer and the paged kind's; ``params`` without the
+    expert kernels, which are ``experts`` (hybrid.split_experts; None
+    where every FFN is dense).
 
-    The kinds follow a LIST, so the loop is cut where the list says: the
-    leading dense layers inline, then ONE scan over the runs of sparse
-    layers, each run ``n`` linear layers (an inner loop whose trip count is
-    the run's own) and the latent layer that ends it, then the linear
-    layers behind the last latent one, if any. One compiled body per kind:
-    every buffer rides in the loops' carries and is updated in place. (A
-    ``lax.cond`` on the kind inside one scan compiled a copy of the
-    recurrent state, 1.7 GB, into the latent branch: PERF.md, PR 40.)"""
-    from deepspeed_tpu.models.kimi_linear import layer_runs
+    The kinds follow the config, so the loop is cut where it says: the
+    leading dense layers inline, then ONE scan over the runs of the other
+    layers, each run ``n`` recurrent layers (an inner loop whose trip
+    count is the run's own) and the paged layer that ends it, then the
+    recurrent layers behind the last paged one, if any. One compiled body
+    per kind: every buffer rides in the loops' carries and is updated in
+    place. (A ``lax.cond`` on the kind inside one scan compiled a copy of
+    the recurrent state, 1.7 GB, into the paged branch: PERF.md, PR 40.)"""
     nd = cfg.n_dense_layers
-    linear_attn, latent_attn = attends
+    recurrent_attn, paged_attn = attends
+    recurrent, paged = cfg.recurrent_stacks
 
     def layer(l, stack, attend, loop):
         (x, aux), flat = loop
@@ -286,23 +320,24 @@ def run_layers(cfg, params, experts, carry, flat, bases, attends, valid,
         y, aux = _ffn(x2, p, cfg, impl, valid, aux, base["index"], experts)
         return (y, aux), flat
 
-    def linear_run(start, n, loop):
+    def recurrent_run(start, n, loop):
         return jax.lax.fori_loop(
-            0, n, lambda i, loop: layer(start + i, "kda", linear_attn, loop),
-            loop)
+            0, n, lambda i, loop: layer(start + i, recurrent,
+                                        recurrent_attn, loop), loop)
 
     loop = (carry, flat)
     for l in range(nd):
-        loop = layer(l, "kda", linear_attn, loop)
+        loop = layer(l, recurrent, recurrent_attn, loop)
     starts, counts, behind = layer_runs(cfg)
 
     def run(loop, r):
         start, n = r
-        loop = linear_run(start, n, loop)
-        return layer(start + n, "mla", latent_attn, loop), None
+        loop = recurrent_run(start, n, loop)
+        return layer(start + n, paged, paged_attn, loop), None
 
     loop, _ = jax.lax.scan(run, loop, (jnp.asarray(starts),
                                        jnp.asarray(counts)))
     if behind[1]:
-        loop = linear_run(jnp.int32(behind[0]), jnp.int32(behind[1]), loop)
+        loop = recurrent_run(jnp.int32(behind[0]), jnp.int32(behind[1]),
+                             loop)
     return loop

@@ -55,10 +55,12 @@ name for what cannot yet live with it): K and V pools (the GPT blocks); a
 full layers' pool beside a bounded window RING per slot
 (inference/hybrid.py); ONE pool of latent rows and no V pool (latent.py);
 K and V pools beside a per-slot TAIL of the previous token (cca.py); and a
-latent pool for some layers beside a per-slot RECURRENT STATE for the
-others (linear.py). The last is the first whose slot costs memory before
-it holds a token (41.9 MB a slot for Kimi-Linear against 8,960 bytes a
-token): ``recurrent_state_bytes`` / ``conv_tail_bytes`` are held whole from
+paged pool for some layers (latent rows, or K and V) beside a per-slot
+RECURRENT STATE for the others, whichever rule writes it (linear.py:
+linear attention, a state-space mixer). The last is the first whose slot
+costs memory before it holds a token (41.9 MB a slot for Kimi-Linear
+against 8,960 bytes a token; 8.5 MB against 1,024 for Jamba2-3B):
+``recurrent_state_bytes`` / ``conv_tail_bytes`` are held whole from
 construction on, and a byte budget buys the slots first and blocks with
 what is left (``slot_state_bytes``).
 
@@ -132,8 +134,8 @@ def refuse(cfg, what: str):
     first of five): bounded window state beside the pool
     (inference/hybrid.py), a latent pool (latent.py), per-slot tails of
     the previous token (cca.py), a per-slot recurrent state beside a
-    latent pool (linear.py; asked first, its latent layers would answer
-    with latent.py's line)."""
+    paged pool (linear.py, for either rule that writes one; asked first,
+    a model's latent layers would answer with latent.py's line)."""
     for dialect in (linear, hybrid, latent, cca):
         dialect.refuse(cfg, what)
 
@@ -141,7 +143,7 @@ def refuse(cfg, what: str):
 def paged_pool(k):
     """The pool behind the block tables in a K-side state: the array
     itself, a two-kind state's full layers' pool, a latent state's rows, a
-    CCA state's K rows, a linear-attention state's latent rows."""
+    CCA state's K rows, the paged layers' rows beside a recurrent state."""
     return getattr(k, "full", getattr(k, "rows", k))
 
 
@@ -270,9 +272,9 @@ class PagedKVCache:
         # from construction on, like the window rings
         self.cca_tail_bytes = self.num_slots \
             * gpt_lib.kv_cca_tail_bytes_per_slot(cfg, self.dtype)
-        # the linear-attention layers' recurrent state and convolution
-        # tails (inference/linear.py): a slot costs these before it holds
-        # a token, so slots, not blocks, are what this memory buys
+        # the recurrent layers' state and convolution tails
+        # (inference/linear.py): a slot costs these before it holds a
+        # token, so slots, not blocks, are what this memory buys
         state, tail = gpt_lib.kv_recurrent_bytes_per_slot(cfg, self.dtype)
         self.recurrent_state_bytes = self.num_slots * state
         self.conv_tail_bytes = self.num_slots * tail
@@ -316,12 +318,12 @@ class PagedKVCache:
         # the layout in HBM that the entry parameter, the layer loop and
         # the kernel share (module docstring)
         if linear.is_linear(cfg):
-            # a fifth: a latent pool for the latent layers (L counts
-            # those alone) and every slot's recurrent state and
-            # convolution tail for the linear layers, zero until used
+            # a fifth: the paged layers' pool or pools (L counts those
+            # layers alone) and every slot's recurrent state and
+            # convolution tail for the others, zero until used
             self.k = linear.new_state(cfg, self.num_blocks, self.block_size,
                                       self.num_slots, self.pool_dtype)
-            self.v = None
+            self.v = linear.paged_v_pool(cfg, self.k)
         elif self.latent:
             # a third kind of state (inference/latent.py): one pool of
             # latent rows, no V pool
@@ -1155,8 +1157,9 @@ class PagedKVCache:
         int8 pools ``(k, v, k_scale, v_scale)``; for a model of two
         attention kinds k and v are hybrid.PagedState, for one with
         latent attention ``(latent.LatentState, None)``, for one with
-        convolutional attention ``(cca.CCAState, v)``, for one with
-        linear-attention layers ``(linear.LinearState, None)``."""
+        convolutional attention ``(cca.CCAState, v)``, for one with a
+        per-slot recurrent state ``(linear.LinearState, None)`` beside a
+        latent pool and ``(linear.LinearState, v)`` beside K and V."""
         return (self.k, self.v) + (self.scales or ())
 
     @pools.setter

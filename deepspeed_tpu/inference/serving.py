@@ -615,9 +615,11 @@ class ServingEngine:
         # the EFFECTIVE switch: the cache gates the tier on the prefix
         # index existing (only indexed blocks ever spill)
         self.host_tier = self.cache.host_tier
-        if hasattr(self.cache.k, "stats") and self.telemetry.enabled:
+        if hasattr(self.cache.k, "stats") and self.telemetry.enabled \
+                and hasattr(engine.cfg, "moe_k"):
             # expert-layer counters ride with the K state, on the device
-            # (read_expert_counters pulls them)
+            # (read_expert_counters pulls them); a dense model beside a
+            # recurrent state has none
             from deepspeed_tpu.moe.expert_share import stat_fields
             self.cache.k = self.cache.k._replace(
                 stats=jnp.zeros((2, len(stat_fields(engine.cfg))),
@@ -738,17 +740,19 @@ class ServingEngine:
                     else self.metrics.gauge)
             self._stat[key] = make(f"serving_{key}", help_)
         self.stats = _StatsView(self._stat)
-        # a model with linear-attention layers (inference/linear.py): how
-        # often a slot's recurrent state was started from zeros, and how
-        # often that was a preempted request's replay rebuilding it
+        # a model with a per-slot recurrent state (inference/linear.py,
+        # whichever rule writes it): how often a slot's state was started
+        # from zeros, and how often that was a preempted request's replay
+        # rebuilding it
         self._state_resets = self._state_replays = None
         if self.cache.recurrent_state_bytes:
             self._state_resets = self.metrics.counter(
                 "serving_state_resets",
-                "prefill chunks at position 0 of a model with "
-                "linear-attention layers: the slot's recurrent state and "
-                "convolution tail start from zeros (nothing is cleared: "
-                "the chunk does not read what the slot held)")
+                "prefill chunks at position 0 of a model with a per-slot "
+                "recurrent state (linear-attention or state-space layers): "
+                "the slot's state and convolution tail start from zeros "
+                "(nothing is cleared: the chunk does not read what the "
+                "slot held)")
             self._state_replays = self.metrics.counter(
                 "serving_state_replays",
                 "of those, re-prefills of a preempted request (prompt + "
@@ -899,17 +903,19 @@ class ServingEngine:
                 # state that summarises a whole history (inference/
                 # linear.py): bought per SLOT, before a token is held
                 reg.gauge("kv_recurrent_state_bytes",
-                          "device bytes of the linear-attention layers' "
-                          "per-slot recurrent state: per layer, slot and "
-                          "head one float32 matrix of head_dim x head_dim, "
-                          "read and rewritten by every token whatever the "
-                          "slot's length").set(
+                          "device bytes of the per-slot recurrent state: "
+                          "float32, per recurrent layer and slot one matrix "
+                          "of head_dim x head_dim a head (linear attention) "
+                          "or d_state x d_inner (a state-space mixer), read "
+                          "and rewritten by every token whatever the slot's "
+                          "length").set(
                     self.cache.recurrent_state_bytes)
                 reg.gauge("kv_conv_tail_bytes",
-                          "device bytes of the linear-attention layers' "
-                          "per-slot convolution tails: per layer and slot "
-                          "the un-convolved [q | k | v] rows of the last "
-                          "conv_kernel - 1 tokens").set(
+                          "device bytes of the recurrent layers' per-slot "
+                          "convolution tails: per layer and slot the "
+                          "un-convolved rows ([q | k | v], or a state-space "
+                          "mixer's x) of the last conv_kernel - 1 "
+                          "tokens").set(
                     self.cache.conv_tail_bytes)
             self._h_kv_err = reg.histogram(
                 "serving_kv_quant_error",
@@ -2409,7 +2415,7 @@ class ServingEngine:
         the new working prompt is prompt+generated, whose re-prefill
         reproduces the pre-eviction cache and next-token logits exactly.
         That holds for state no block holds too (a window ring, a
-        convolution tail, a linear-attention layer's recurrent state): the
+        convolution tail, a recurrent layer's state): the
         replay starts at position 0, where a chunk reads nothing of what
         the slot held, and rebuilds it."""
         req = self.slots[slot]

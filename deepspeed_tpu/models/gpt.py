@@ -1030,10 +1030,11 @@ def kv_cca_tail_bytes_per_slot(cfg: GPTConfig, dtype=jnp.bfloat16) -> int:
 def kv_recurrent_bytes_per_slot(cfg: GPTConfig,
                                 dtype=jnp.bfloat16) -> Tuple[int, int]:
     """(recurrent state, convolution tails): bytes one serving slot holds
-    beside its blocks, whatever its length, where some layers are linear
-    attention (models/kimi_linear.py, inference/linear.py): per such layer
-    a float32 matrix a head, and the last tokens' un-convolved rows in the
-    pools' type ((0, 0) for a model with no such layers)."""
+    beside its blocks, whatever its length, where some layers keep a
+    recurrent state (linear attention, models/kimi_linear.py; a state-space
+    mixer, models/jamba.py; inference/linear.py): per such layer the
+    float32 state, and the last tokens' un-convolved rows in the pools'
+    type ((0, 0) for a model with no such layers)."""
     return (4 * int(getattr(cfg, "recurrent_state_values", 0)),
             int(getattr(cfg, "conv_tail_values", 0))
             * jnp.dtype(dtype).itemsize)

@@ -44,6 +44,8 @@ import numpy as np
 
 from deepspeed_tpu.models.dots_vlm import LANES
 from deepspeed_tpu.models.gpt import GPTConfig
+# what the layer loop asks of the two lists (its tests' names)
+from deepspeed_tpu.models.recurrent import layer_bases, layer_runs  # noqa: F401
 
 
 @dataclass
@@ -119,6 +121,28 @@ class KimiLinearConfig(GPTConfig):
     def n_full_layers(self) -> int:
         """The layers whose history is rows of the paged pool."""
         return len(self.full_attn_layers)
+
+    @property
+    def recurrent_stacks(self) -> Tuple[str, str]:
+        """The parameter stacks of the two kinds (recurrent, paged)."""
+        return "kda", "mla"
+
+    @property
+    def n_recurrent_layers(self) -> int:
+        return self.n_kda_layers
+
+    @property
+    def recurrent_state_shape(self) -> Tuple[int, ...]:
+        """One slot's state in one linear layer (ops/attention/kda.py
+        keeps it transposed, the key channels on the lanes)."""
+        return (self.linear_heads, self.linear_head_dim,
+                self.linear_head_dim)
+
+    @property
+    def conv_tail_width(self) -> int:
+        """One slot's tail in one linear layer: the last ``conv_kernel -
+        1`` tokens' un-convolved rows side by side."""
+        return (self.conv_kernel - 1) * self.kda_channels
 
     @property
     def kda_channels(self) -> int:
@@ -216,40 +240,3 @@ def init_params(rng: jax.Array, cfg: KimiLinearConfig, std: float = 0.02,
                                 **swiglu(Ld, cfg.ffn_dim)),
             "block": sparse, "ln_f": {"scale": jnp.ones((d,))},
             "lm_head": {"kernel": normal((d, cfg.vocab_size))}}
-
-
-def layer_bases(cfg: KimiLinearConfig, n_blocks: int, n_slots: int):
-    """Per layer, by layer index: its index ``attn`` in its kind's
-    parameter stack, where its rows start in the flat latent pool
-    (``rows``; ``n_blocks`` blocks a latent layer) and its slots in the
-    flat recurrent state and convolution tails (``state``; ``n_slots`` a
-    linear layer), each by the kind's OWN layer counter (the other kind's
-    entry is 0 and unread), and a sparse layer's row ``index`` in the
-    dispatch's routing record."""
-    kinds = cfg.attn_kinds
-    # a layer's index among the layers of its own kind
-    own = np.where(kinds == 1, np.cumsum(kinds == 1) - 1,
-                   np.cumsum(kinds == 0) - 1).astype(np.int32)
-    layers = np.arange(cfg.n_layers)
-    bases = {"attn": own,
-             "rows": np.where(kinds == 1, own * n_blocks, 0),
-             "state": np.where(kinds == 0, own * n_slots, 0),
-             "index": np.maximum(layers - cfg.n_dense_layers, 0)}
-    return {k: jnp.asarray(v.astype(np.int32)) for k, v in bases.items()}
-
-
-def layer_runs(cfg: KimiLinearConfig):
-    """How the list of kinds cuts the sparse layers (inference/linear.py
-    ``run_layers``): (``starts``, ``counts``, ``behind``). Run ``r`` is the
-    ``counts[r]`` linear layers from layer ``starts[r]`` on and the latent
-    layer that ends them; ``behind`` = (first layer, count) of the linear
-    layers behind the last latent one. Layer indices from 0."""
-    starts, counts = [], []
-    at = cfg.n_dense_layers
-    for l in range(at, cfg.n_layers):
-        if cfg.attn_kinds[l] == 1:
-            starts.append(at)
-            counts.append(l - at)
-            at = l + 1
-    return (np.asarray(starts, np.int32), np.asarray(counts, np.int32),
-            (at, cfg.n_layers - at))

@@ -391,3 +391,86 @@ def test_no_copy_of_the_state_or_the_pool_is_compiled_in(v5e, program):
     assert exe.memory_analysis().temp_size_in_bytes < buffers // 4
     if program == "decode_slots":
         assert "kda_step" in text and "mla_decode" in text
+
+
+@pytest.mark.parametrize("program", ["prefill_slot", "decode_slots"])
+def test_state_space_state_is_stored_unpadded_and_never_copied(v5e, program):
+    """The same reading for the state-space rule's four buffers
+    (inference/ssm.py through linear.py's loop): the serving program
+    compiled ahead of time for a v5e, with the Mosaic kernels, at the
+    published widths of the mixer (5,120 channels, a state of 16: the
+    tiling is theirs) and few layers and slots, runs of 1 and 2 state-space
+    layers and one behind the last attention layer. The state is stored
+    ``[16, 5120]`` a slot a layer in tiles of (8, 128), which those sizes
+    fill: it costs the bytes it holds (as published, ``[5120, 16]``, the 16
+    would pad to 128 lanes: 8 times as much). No ``copy`` holds a value
+    shaped like a pool, the state or the tails, and all four are updated in
+    place."""
+    import re
+    from deepspeed_tpu.inference import linear
+    from deepspeed_tpu.models import jamba
+    cfg = jamba.JambaConfig(
+        vocab_size=512, n_layers=6, n_heads=20, n_kv_heads=1, d_model=2560,
+        d_ff=512, max_seq_len=12288, dtype=jnp.bfloat16,
+        attn_layer_period=3, attn_layer_offset=1, use_flash_attention=False,
+        remat=False)
+    # the cell's own chunk, block and row of 24 blocks (8 read lengths)
+    B, C, bs = 8, 512, 512
+    NB = cfg.max_seq_len // bs
+    N = B * NB + 1
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = jax.tree_util.tree_map(
+        lambda a: S(a.shape, jnp.bfloat16), jax.eval_shape(
+            lambda: jamba.init_params(jax.random.PRNGKey(0), cfg)))
+    La, Ls = 2, 4
+    assert cfg.recurrent_state_shape == (16, 5120)
+    pool = S((La, N, bs, 128), jnp.bfloat16)
+    state = linear.LinearState(
+        pool, S((Ls, B, 16, 5120), jnp.float32),
+        S((Ls, B, 3 * 5120), jnp.bfloat16))
+    eng = InferenceEngine.__new__(InferenceEngine)
+    eng.cfg, eng.max_seq_len, eng.dtype = cfg, cfg.max_seq_len, jnp.bfloat16
+    eng.decode_impl = "pallas"
+    i32, f32, u32, V = jnp.int32, jnp.float32, jnp.uint32, cfg.vocab_size
+    if program == "prefill_slot":
+        fn = jax.jit(_named(eng._prefill_slot_fn, "serve_prefill_slot"),
+                     donate_argnums=(1, 2))
+        args = (params, state, pool, S((NB,), i32), S((C,), i32), S((), i32),
+                S((), i32), S((2,), u32), S((), i32), S((), f32), S((), i32),
+                S((), f32), S((), f32), S((V,), jnp.bool_), None, None,
+                S((), i32))
+    else:
+        fn = jax.jit(_named(eng._decode_slots_fn, "serve_decode_slots"),
+                     donate_argnums=(1, 2), static_argnums=(7,))
+        args = (params, state, pool, S((B, NB), i32), S((B,), i32),
+                S((B,), i32), S((B,), jnp.bool_), "pallas", S((B, 2), u32),
+                S((B,), i32), S((B,), f32), S((B,), i32), S((B,), f32),
+                S((B,), f32), S((B, V), jnp.bool_))
+    exe = fn.trace(*args).lower(lowering_platforms=("tpu",)).compile()
+    text = exe.as_text()
+    # the state as the program takes it: minor dimensions (16, 5120) in
+    # tiles of (8, 128), which they fill (no padding)
+    layouts = set(re.findall(r"f32\[4,8,16,5120\]\{([^}]*)\}", text))
+    assert layouts and all(l.startswith("3,2,1,0:T(8,128)")
+                           for l in layouts), layouts
+    table = parse_provenance(text)
+    assert pool_copy_bytes(table, (N, La * N)) == 0
+    assert pool_copy_bytes(table, (Ls * B,)) == 0
+    buffers = 2 * pool.size * 2 + state.state.size * 4 + state.tail.size * 2
+    mem = exe.memory_analysis()
+    assert mem.alias_size_in_bytes >= buffers
+    # the arguments are the weights and the buffers at the bytes they hold
+    weights = sum(a.size * 2 for a in jax.tree_util.tree_leaves(params))
+    assert mem.argument_size_in_bytes < 1.02 * (weights + buffers) + (1 << 20)
+    if program == "decode_slots":
+        assert mem.temp_size_in_bytes < buffers // 4
+        assert "ssm_step" in text and "paged_decode" in text
+    else:
+        # the dense read's scores at the longest of its 8 lengths, 20 heads
+        # x 512 queries x 12,288 keys (503 MB in float32), are the largest
+        # temporary: nothing of a buffer's size beside them
+        assert mem.temp_size_in_bytes < 20 * C * cfg.max_seq_len * 4
+        assert "ssm_scan" in text

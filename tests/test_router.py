@@ -305,11 +305,14 @@ def test_router_drain_parity_watchdog_degraded(eng):
     slow decode) is absorbed by the router: break, drain, parity."""
     # grace=1: serving.decode visits are fleet-global (shared injector),
     # so consecutive slow visits can straddle two replicas and a grace
-    # of 2 would never accumulate on either
+    # of 2 would never accumulate on either. The budget is wall time: a
+    # 10 ms one was crossed by an undisturbed decode step when the other
+    # workers of a whole run loaded the host (a second replica broke);
+    # 50 ms under a 250 ms fault keeps the same five-fold margin
     router = _parity_run(
         eng,
-        [Fault("serving.decode", "slow", step=5, param=0.05)],
-        step_time_budget_s=0.01, watchdog_grace=1)
+        [Fault("serving.decode", "slow", step=5, param=0.25)],
+        step_time_budget_s=0.05, watchdog_grace=1)
     assert router.health().count(BROKEN) == 1
     assert router.stats["drained_requests"] >= 1
 
