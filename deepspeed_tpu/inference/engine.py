@@ -1768,6 +1768,19 @@ class InferenceEngine:
         lo, hi, P = attended_tiles(start, n, bs, nb, cfg.attn_window)
         return min(max(hi - lo, 1) * P * bs, nb * bs)
 
+    def mla_prefill_tiles(self, start: int, bs: int) -> int:
+        """Flash steps a prefill chunk at ``start`` takes in the latent
+        layers (latent.attend_prefill: every occupied history block of
+        ``bs`` and the chunk's own tile, a layer), on the host; 0 for a
+        model without latent rows. Each is one ``mla_prefill`` kernel
+        block where ``decode_impl`` is "pallas", one plain flash step
+        otherwise."""
+        cfg = self.cfg
+        if not latent.is_latent(cfg):
+            return 0
+        layers = getattr(cfg, "n_full_layers", cfg.n_layers)
+        return layers * ((start + bs - 1) // bs + 1)
+
     def prefill_into_slot(self, k_pool, v_pool, table_row, tokens, start,
                           n_valid, scales=None, sample_state=None,
                           lora=None):

@@ -16,11 +16,18 @@ Two attention paths over the same rows, the same numbers:
   step reads the latent rows THROUGH the block table
   (ops/attention/mla.py) and never expands them to per-head keys and
   values;
-- PREFILL, expanded: a chunk's queries attend per-head keys and values
-  re-expanded from the cached latents of the slot's OCCUPIED history, a
-  block at a time under a running max and sum, then the chunk itself,
-  causal. Temporaries are a block's, not the table's: the loop's trip count
-  follows ``start``.
+- PREFILL, expanded: a chunk's queries attend the chunk itself, causal,
+  then per-head keys and values re-expanded from the cached latents of the
+  slot's OCCUPIED history, a tile at a time under a running max and sum.
+  Temporaries are a tile's, not the table's: the loops' trip counts follow
+  ``start``. With ``impl`` "pallas" a flash step is ONE Mosaic call
+  (ops/attention/mla.py ``mla_prefill_step``): the up-projection stays
+  XLA's under ``mla_expand``, the shared rotated key goes in once as the
+  rows hold it, the scores live in VMEM only, the queries lie in the lanes
+  from the projection to the output, and a call attends
+  :func:`blocks_per_call` history blocks (from the shapes: 2 at 128 heads,
+  4 at 32). Otherwise :func:`_attend_tile`, a block a turn: the portable
+  path, the parity reference, and what inference/cca.py imports.
 
 One compiled body per layer SHAPE: the leading dense layers and then the
 sparse layers, each one scan of engine._scan_layers with the pool in the
@@ -110,16 +117,25 @@ def _project(h, p, cfg, positions):
     return q_n, q_r, rows
 
 
-def _expand(rows, p, cfg):
-    """Cache rows ``[S, lanes]`` -> per-head keys ``[H, S, d_n + d_r]``
-    (the shared rotated key behind each head's own) and values ``[H, S,
-    d_v]``: the up-projection, as the prefill path attends."""
+def _up(rows, p, cfg, values="hsd"):
+    """Cache rows ``[S, lanes]`` -> each head's own keys ``k_n`` ``[H, S,
+    d_n]``, the ONE rotated key ``[S, d_r]`` that all heads share, as the
+    rows hold it, and each head's values ``[H, S, d_v]`` (``values``
+    "hds": ``[H, d_v, S]``): the up-projection."""
     rkv, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
     c = rows[:, :rkv]
     k_n = jnp.einsum("sc,hdc->hsd", c, p["k_up"]["kernel"].astype(c.dtype))
-    v = jnp.einsum("sc,hcd->hsd", c, p["v_up"]["kernel"].astype(c.dtype))
-    k_r = jnp.broadcast_to(rows[None, :, rkv:rkv + dr],
-                           (k_n.shape[0], rows.shape[0], dr))
+    v = jnp.einsum(f"sc,hcd->{values}", c,
+                   p["v_up"]["kernel"].astype(c.dtype))
+    return k_n, rows[:, rkv:rkv + dr], v
+
+
+def _expand(rows, p, cfg):
+    """Cache rows ``[S, lanes]`` -> per-head keys ``[H, S, d_n + d_r]``
+    (the shared rotated key behind each head's own) and values ``[H, S,
+    d_v]``, as :func:`_attend_tile` attends them."""
+    k_n, k_r, v = _up(rows, p, cfg)
+    k_r = jnp.broadcast_to(k_r[None], (k_n.shape[0],) + k_r.shape)
     return jnp.concatenate([k_n, k_r], axis=-1), v
 
 
@@ -141,11 +157,36 @@ def _attend_tile(carry, q, k, v, kpos, qpos, scale):
     return m_new, l, acc
 
 
-def attend_prefill(x, pool, table_row, positions, n_valid, p, cfg, rows_at):
+# expanded keys and values (bytes) a kernel call may hold at once: what
+# bounds how many history blocks one call attends
+_EXPANDED_BYTES = 80 << 20
+
+
+def blocks_per_call(cfg, bs: int, itemsize: int) -> int:
+    """History blocks one ``mla_prefill_step`` call attends, from the
+    shapes: 4, 2 or 1, the most whose expanded keys and values (``H x bs x
+    (d_n + d_v)`` values a block) stay inside ``_EXPANDED_BYTES``: 2 at 128
+    heads and blocks of 512, 4 at 32. More blocks a call spread the
+    accumulator's round trip through HBM, the query's read and a grid
+    step's fixed cost over more keys (262 / 280 / 293 us a tile at 4 / 2 /
+    1 and 128 heads, 345 plain); each costs its expansion, 33.6 MB there,
+    in temporaries."""
+    block = cfg.n_heads * bs * (cfg.qk_nope_head_dim
+                                + cfg.v_head_dim) * itemsize
+    return next((g for g in (4, 2) if g * block <= _EXPANDED_BYTES), 1)
+
+
+def attend_prefill(x, pool, table_row, positions, n_valid, p, cfg, rows_at,
+                   impl):
     """The attention sublayer over a PROMPT CHUNK of one slot, the expanded
     path: ``x`` ``[C, d]`` -> (x + attention, the pool with the chunk's rows
     written). ``pool``: every latent layer's blocks, this layer's starting
-    at ``rows_at``."""
+    at ``rows_at``. With ``impl`` "pallas" every flash step (the chunk's own
+    tile, then the history :func:`blocks_per_call` blocks a call and what is
+    left a block a call) is ONE Mosaic kernel over the expanded tile
+    (ops/attention/mla.py ``mla_prefill_step``: the scores stay in VMEM,
+    the queries lie in the lanes from the projection to the output);
+    otherwise :func:`_attend_tile`, a block a turn."""
     C = x.shape[0]
     H, dv = cfg.n_heads, cfg.v_head_dim
     bs = pool.shape[1]
@@ -153,43 +194,66 @@ def attend_prefill(x, pool, table_row, positions, n_valid, p, cfg, rows_at):
     start = positions[0]
     valid = jnp.arange(C) < n_valid
     scale = cfg.softmax_scale
+    kernel = impl == "pallas"
 
     with jax.named_scope("attn_qkv"):
         h = _norm(x, p["ln1"], cfg)
         q_n, q_r, rows = _project(h, p, cfg, positions)
-        q = jnp.concatenate([q_n, q_r], axis=-1).transpose(1, 0, 2)
+        if kernel:                          # [H, d, C]: queries in the lanes
+            q_n, q_r = q_n.transpose(1, 2, 0), q_r.transpose(1, 2, 0)
+        else:
+            q = jnp.concatenate([q_n, q_r], axis=-1).transpose(1, 0, 2)
 
     with jax.named_scope("kv_write"):
         blk = table_row[jnp.clip(positions // bs, 0, NB - 1)]
         blk = jnp.where(valid, blk, 0) + rows_at
         pool = pool.at[blk, positions % bs].set(rows)
 
-    with jax.named_scope("paged_attn"), jax.named_scope("attn_mla"):
-        init = (jnp.full((H, C), NEG_INF, jnp.float32),
-                jnp.zeros((H, C), jnp.float32),
-                jnp.zeros((H, C, dv), jnp.float32))
-        # the chunk itself, causal, from the rows it has just made
-        with jax.named_scope("mla_expand"):
-            k, v = _expand(rows, p, cfg)
-        state = _attend_tile(init, q, k, v, positions, positions, scale)
-
-        def history(j, state):
-            # block j of the slot's OCCUPIED history, re-expanded as it
-            # is attended; what of it lies at or past ``start`` (the
-            # chunk's own rows, a block's unwritten tail) is masked
-            tile = pool[table_row[j] + rows_at]
-            kpos = j * bs + jnp.arange(bs, dtype=jnp.int32)
-            kpos = jnp.where(kpos < start, kpos, jnp.int32(2 ** 30))
+    def attend(state, tile, kpos):
+        if kernel:
+            from deepspeed_tpu.ops.attention.mla import mla_prefill_step
             with jax.named_scope("mla_expand"):
-                k, v = _expand(tile, p, cfg)
-            return _attend_tile(state, q, k, v, kpos, positions, scale)
+                k_n, k_r, v = _up(tile, p, cfg, "hds")
+            return mla_prefill_step(state, q_n, q_r, k_n, k_r, v, kpos,
+                                    positions, scale)
+        with jax.named_scope("mla_expand"):
+            k, v = _expand(tile, p, cfg)
+        return _attend_tile(state, q, k, v, kpos, positions, scale)
 
-        _, l, acc = jax.lax.fori_loop(0, (start + bs - 1) // bs, history,
-                                      state)
-        attn = (acc / l[..., None]).astype(x.dtype)          # [H, C, d_v]
-    with jax.named_scope("attn_out"):
-        return x + _dense(attn.transpose(1, 0, 2).reshape(C, H * dv),
-                          p["attn_out"]), pool
+    def history(blocks):
+        def turn(i, state):
+            # blocks [i * blocks, (i + 1) * blocks) of the slot's OCCUPIED
+            # history, re-expanded as they are attended; what of the last
+            # lies at or past ``start`` (the chunk's own rows, a block's
+            # unwritten tail) is masked. A block a slice: ONE gather of
+            # them compiles to a temporary that grows with the pool
+            tile = jnp.concatenate([pool[table_row[i * blocks + b] + rows_at]
+                                    for b in range(blocks)])
+            kpos = i * blocks * bs + jnp.arange(blocks * bs, dtype=jnp.int32)
+            kpos = jnp.where(kpos < start, kpos, jnp.int32(2 ** 30))
+            return attend(state, tile, kpos)
+        return turn
+
+    with jax.named_scope("paged_attn"), jax.named_scope("attn_mla"):
+        # max, sum, accumulator: the kernel's with the queries in the lanes
+        shapes = ((H, 1, C), (H, 1, C), (H, dv, C)) if kernel \
+            else ((H, C), (H, C), (H, C, dv))
+        init = tuple(jnp.full(shape, fill, jnp.float32)
+                     for shape, fill in zip(shapes, (NEG_INF, 0.0, 0.0)))
+        # the chunk itself, causal, from the rows it has just made
+        state = attend(init, rows, positions)
+        n, done = (start + bs - 1) // bs, 0
+        G = blocks_per_call(cfg, bs, rows.dtype.itemsize) if kernel else 1
+        if G > 1:
+            state = jax.lax.fori_loop(0, n // G, history(G), state)
+            done = n // G * G
+        _, l, acc = jax.lax.fori_loop(done, n, history(1), state)
+        if kernel:
+            attn = (acc / l).astype(x.dtype).transpose(2, 0, 1)
+        else:
+            attn = (acc / l[..., None]).astype(x.dtype).transpose(1, 0, 2)
+    with jax.named_scope("attn_out"):                    # attn [C, H, d_v]
+        return x + _dense(attn.reshape(C, H * dv), p["attn_out"]), pool
 
 
 def block_prefill(carry, pools, table_row, positions, n_valid, p, cfg, base,
@@ -201,7 +265,7 @@ def block_prefill(carry, pools, table_row, positions, n_valid, p, cfg, base,
     layer's expert kernels (hybrid.split_experts)."""
     x, aux = carry
     x2, pool = attend_prefill(x[0], pools[0], table_row, positions, n_valid,
-                              p, cfg, base["rows"])
+                              p, cfg, base["rows"], impl)
     valid = jnp.arange(x.shape[1]) < n_valid
     y, aux = _ffn(x2, p, cfg, impl, valid, aux, base["index"], experts)
     return (y[None], aux), (pool,)

@@ -261,7 +261,7 @@ def prefill_attends(cfg, table_row, positions, n_valid, slot, impl):
 
     def latent_attn(x, flat, p, base):
         y, rows = latent.attend_prefill(x, flat[0], table_row, positions,
-                                        n_valid, p, cfg, base["rows"])
+                                        n_valid, p, cfg, base["rows"], impl)
         return y, (rows,) + flat[1:]
     return linear_attn, latent_attn
 

@@ -208,6 +208,13 @@ _STAT_FIELDS = (
     ("prefill_row_tokens_total", "c",
      "positions of a whole row per prefill chunk; attended / row is the "
      "share of the row a chunk still reads"),
+    ("mla_prefill_tiles_kernel_total", "c",
+     "flash steps of prefill chunks' latent layers taken by the "
+     "mla_prefill kernel (InferenceEngine.mla_prefill_tiles: latent "
+     "layers x (occupied history blocks + the chunk's own tile))"),
+    ("mla_prefill_tiles_plain_total", "c",
+     "the same steps where the engine's decode_impl is not pallas: "
+     "plain flash steps (latent._attend_tile)"),
     ("prefix_hits", "c", "admissions that matched a cached prefix"),
     ("prefix_tokens_saved", "c", "prompt tokens served from shared blocks"),
     ("spec_steps", "c", "speculative verify dispatches"),
@@ -740,6 +747,10 @@ class ServingEngine:
                     else self.metrics.gauge)
             self._stat[key] = make(f"serving_{key}", help_)
         self.stats = _StatsView(self._stat)
+        # a prefill chunk's latent layers run the engine's decode_impl
+        self._mla_tiles = self._stat[
+            "mla_prefill_tiles_kernel_total" if engine.decode_impl == "pallas"
+            else "mla_prefill_tiles_plain_total"]
         # a model with a per-slot recurrent state (inference/linear.py,
         # whichever rule writes it): how often a slot's state was started
         # from zeros, and how often that was a preempted request's replay
@@ -1567,6 +1578,8 @@ class ServingEngine:
             self._stat["prefill_attended_tokens_total"].inc(attended)
             self._stat["prefill_row_tokens_total"].inc(
                 self.cache.tokens_per_slot)
+            self._mla_tiles.inc(self.engine.mla_prefill_tiles(
+                done, self.cache.block_size))
             with self.telemetry.tracer.span(
                     "serve.prefill", rid=req.rid, step=self._step_clock,
                     slot=slot, start=done, n=n, history=done,

@@ -1,5 +1,6 @@
-"""Latent (MLA) decode attention through the block table: the absorbed
-form over a pool of latent rows.
+"""Latent (MLA) attention kernels: the absorbed decode through the block
+table over a pool of latent rows (:func:`mla_decode_attention`), and one
+flash step of the expanded prefill (:func:`mla_prefill_step`, at the end).
 
 A token's cache row is ``[c_kv | k_r | 0...]``: the normalised latent, the
 one rotated key all heads share, zero padding to whole lane tiles
@@ -160,3 +161,109 @@ def mla_decode_reference(q, pool, tables, lengths, *, value_width: int,
     s = jnp.where(idx <= lengths[:, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.einsum("bhs,bsv->bhv", p, rows[..., :value_width])
+
+
+def _mla_prefill_kernel(qpos_ref, kpos_ref, qn_ref, qr_ref, kn_ref, kr_ref,
+                        v_ref, m_ref, l_ref, acc_ref, mo_ref, lo_ref, o_ref,
+                        *, scale: float):
+    """One grid step: head ``h``'s ``C`` queries against key block ``j`` of
+    the call, everything with the QUERIES IN THE LANES. qn_ref ``[1, d_n,
+    C]``, qr_ref ``[1, d_r, C]``; kn_ref ``[1, T, d_n]``, kr_ref ``[T,
+    d_r]`` (the ONE shared key), v_ref ``[1, d_v, T]``; qpos_ref ``[1,
+    C]``, kpos_ref ``[T, 1]``. The carry's blocks are the head's max and sum
+    ``[1, 1, C]`` and accumulator ``[1, d_v, C]``, held in the output
+    blocks across the head's key blocks. The scores ``[T, C]`` live here
+    only; their max and sum over the keys run down the sublanes and meet
+    the carry's rows as they are, so no step moves a vector between rows
+    and columns."""
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        mo_ref[...] = m_ref[...]
+        lo_ref[...] = l_ref[...]
+        o_ref[...] = acc_ref[...]
+
+    nn = (((1,), (0,)), ((), ()))
+    s = (jax.lax.dot_general(kn_ref[0], qn_ref[0], nn,
+                             preferred_element_type=jnp.float32)
+         + jax.lax.dot_general(kr_ref[...], qr_ref[0], nn,
+                               preferred_element_type=jnp.float32)) * scale
+    s = jnp.where(kpos_ref[...] <= qpos_ref[...], s, NEG_INF)    # [T, C]
+    m_prev = mo_ref[0]                                           # [1, C]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    lo_ref[0] = alpha * lo_ref[0] + jnp.sum(p, axis=0, keepdims=True)
+    v = v_ref[0]                                                 # [d_v, T]
+    o_ref[0] = o_ref[0] * alpha + jax.lax.dot_general(
+        v, p.astype(v.dtype), nn, preferred_element_type=jnp.float32)
+    mo_ref[0] = m_new
+
+
+# float32 scores and probabilities and their cast, bytes a (key, query)
+# pair, and what of VMEM a step's may fill
+_STEP_PAIR_BYTES = 12
+_STEP_VMEM = 24 << 20
+
+
+def mla_prefill_step(carry, q_n, q_r, k_n, k_r, v, kpos, qpos, scale, *,
+                     blocks: Optional[int] = None, interpret: bool = False):
+    """One flash step of the EXPANDED prefill for all heads of a chunk:
+    inference/latent.py ``_attend_tile``'s arithmetic with the queries in
+    the minor dimension. Queries ``q_n`` ``[H, d_n, C]`` / ``q_r`` ``[H,
+    d_r, C]`` at ``qpos`` ``[C]`` against a tile's per-head keys ``k_n``
+    ``[H, S, d_n]`` and values ``v`` ``[H, d_v, S]`` and the ONE key
+    ``k_r`` ``[S, d_r]`` all heads share, at ``kpos`` ``[S]`` (a key a
+    query may not see: ``kpos > qpos``); ``carry`` = float32 (max ``[H, 1,
+    C]``, sum ``[H, 1, C]``, accumulator ``[H, d_v, C]``), returned
+    advanced. The score is ``k_n q_n + k_r q_r`` in float32; probabilities
+    are cast to ``v.dtype`` for the value product, which accumulates in
+    float32: nothing narrower anywhere. The grid is (heads, ``blocks`` key
+    blocks of ``S / blocks``): a head's carry stays in VMEM over its key
+    blocks and the score tile never leaves it. A grid step costs about a
+    microsecond whatever it attends, so ``blocks`` is by default the
+    fewest whose score tile fits ``_STEP_VMEM`` (ONE up to 2,048 keys
+    under 512 queries). The carry is updated in place: call it under
+    ``jax.jit`` with the carry donated or dead."""
+    m, l, acc = carry
+    H, dn, C = q_n.shape
+    S, dr = k_r.shape
+    dv = v.shape[1]
+    if blocks is None:
+        blocks = next(b for b in range(1, S + 1) if S % b == 0
+                      and _STEP_PAIR_BYTES * (S // b) * C <= _STEP_VMEM)
+    assert S % blocks == 0, (S, blocks)
+    T = S // blocks
+    assert k_n.shape == (H, S, dn) and v.shape == (H, dv, S) \
+        and q_r.shape == (H, dr, C) and acc.shape == (H, dv, C) \
+        and m.shape == l.shape == (H, 1, C), \
+        (q_n.shape, q_r.shape, k_n.shape, k_r.shape, v.shape, acc.shape)
+
+    def head(h, j):
+        return (h, 0, 0)
+
+    row = pl.BlockSpec((1, 1, C), head)
+    # the step's score tile beside the double-buffered operands: more than
+    # Mosaic's default scope at 1,024 keys and up
+    vmem = _STEP_PAIR_BYTES * T * C + 8 * (
+        T * (dn + dr + dv) + C * (dn + dr + 4 * dv))
+    kernel = functools.partial(_mla_prefill_kernel, scale=float(scale))
+    return tuple(pl.pallas_call(
+        kernel, name="mla_prefill", grid=(H, blocks),
+        in_specs=[pl.BlockSpec((1, C), lambda h, j: (0, 0)),
+                  pl.BlockSpec((T, 1), lambda h, j: (j, 0)),
+                  pl.BlockSpec((1, dn, C), head),
+                  pl.BlockSpec((1, dr, C), head),
+                  pl.BlockSpec((1, T, dn), lambda h, j: (h, j, 0)),
+                  pl.BlockSpec((T, dr), lambda h, j: (j, 0)),
+                  pl.BlockSpec((1, dv, T), lambda h, j: (h, 0, j)),
+                  row, row, pl.BlockSpec((1, dv, C), head)],
+        out_specs=[row, row, pl.BlockSpec((1, dv, C), head)],
+        out_shape=[jax.ShapeDtypeStruct(a.shape, jnp.float32) for a in carry],
+        input_output_aliases={7: 0, 8: 1, 9: 2},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=max(32 << 20, 2 * vmem)),
+        **({"interpret": True} if interpret else {}),
+    )(jnp.asarray(qpos, jnp.int32)[None, :],
+      jnp.asarray(kpos, jnp.int32)[:, None], q_n, q_r, k_n, k_r, v, m, l,
+      acc))

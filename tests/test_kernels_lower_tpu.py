@@ -140,6 +140,31 @@ def test_mla_decode_lowers_with_a_plan_of_the_active_slots():
     assert lower_tpu(call, *args).count("tpu_custom_call") == 1
 
 
+@pytest.mark.parametrize("H,keys", [(128, 512), (128, 1024), (32, 2048)])
+def test_mla_prefill_step_lowers(H, keys):
+    """The expanded prefill's flash step at both latent cells' shapes (a
+    chunk of 512 queries in the lanes, 1, 2 and 4 key blocks of 512, 128 +
+    64 score dimensions): one Mosaic call of ONE grid step a head, its
+    carry updated in place."""
+    from deepspeed_tpu.ops.attention import mla
+    C, f32 = 512, jnp.float32
+    carry = (S((H, 1, C), f32), S((H, 1, C), f32), S((H, 128, C), f32))
+    args = (S((H, 128, C)), S((H, 64, C)), S((H, keys, 128)), S((keys, 64)),
+            S((H, 128, keys)), S((keys,), jnp.int32), S((C,), jnp.int32))
+
+    def call(carry, *a):
+        return mla.mla_prefill_step(carry, *a, 0.11)
+    text = lower_tpu(call, carry, *args)
+    assert text.count("tpu_custom_call") == 1
+    for out, operand in ((0, 7), (1, 8), (2, 9)):
+        assert f"output_tuple_indices = [{out}], operand_index = {operand}" \
+            in text
+    grids = [e.params["grid_mapping"].grid
+             for e in jax.make_jaxpr(call)(carry, *args).eqns
+             if e.primitive.name == "pallas_call"]
+    assert grids == [(H, 1)]
+
+
 @pytest.mark.parametrize("M,K,N", [(8, 1024, 3072), (256, 4096, 4096)])
 def test_int8_matmul_lowers(M, K, N):
     bk, bn = fit_blocks(K, N)

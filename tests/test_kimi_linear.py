@@ -334,7 +334,7 @@ def test_direct_query_and_unrotated_row_through_latent_py(model):
     def prefill(x, pool):
         return latent.attend_prefill(
             x, pool, table, jnp.arange(T, dtype=jnp.int32), T, p, cfg,
-            jnp.int32(0))
+            jnp.int32(0), "gather")
 
     def decode(x, pool):
         return latent.attend_decode(
@@ -358,6 +358,35 @@ def test_direct_query_and_unrotated_row_through_latent_py(model):
                                        False)
     np.testing.assert_allclose(got, np.asarray(want), atol=2e-5)
     assert float(np.abs(got - np.asarray(turned)).max()) > 1e-2
+
+
+def test_unrotated_prefill_through_the_kernel_equals_the_plain_path(
+        model, pallas_interpret):
+    """The same three-chunk prompt as the dots.vlm1 dialect's test through
+    a latent layer with ONE query projection and nothing rotated:
+    ``attend_prefill`` with every flash step in the ``mla_prefill`` kernel
+    against the plain path."""
+    from test_latent_dots_vlm import prefill_three_chunks
+    cfg, params = model
+    p = jax.tree_util.tree_map(lambda a: a[1], params["mla"])
+    got, got_pool = prefill_three_chunks(cfg, p, "pallas")
+    want, want_pool = prefill_three_chunks(cfg, p, "gather")
+    np.testing.assert_array_equal(got_pool, want_pool)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=2e-5)
+
+
+def test_prefill_tile_counters_count_the_latent_layers_only(served):
+    """``serving_mla_prefill_tiles_{kernel,plain}_total`` beside a
+    recurrent state: the two latent layers' flash steps (occupied history
+    blocks + the own tile) over the three prompts' eight chunks, none by
+    the kernel off a TPU; the five linear-attention layers take none."""
+    cfg, _, prompts, srv, _ = served
+    assert cfg.n_full_layers == 2
+    want = 2 * sum(-(-start // 4) + 1 for p in prompts
+                   for start in range(0, len(p), 16))
+    assert srv.stats["mla_prefill_tiles_plain_total"] == want == 72
+    assert srv.stats["mla_prefill_tiles_kernel_total"] == 0
 
 
 def test_shares_add_up_to_the_whole_layer(model):

@@ -435,3 +435,207 @@ def test_kv_accounting_counts_the_latent_row():
     assert not latent.is_latent(gpt.GPTConfig())
     with pytest.raises(AssertionError):
         U.tiny_config(n_group=3)
+
+
+# ---- the expanded prefill's flash step as ONE kernel (mla_prefill_step) ----
+
+def _step_case(H, C, T, blocks, start, seed=0, dn=16, dr=8, dv=16,
+               dtype=jnp.bfloat16):
+    """A carry that has attended the chunk's own tile, and a history tile
+    of ``blocks`` blocks of ``T`` keys of which ``start`` are occupied."""
+    ks = jax.random.split(jax.random.key(seed), 8)
+    S = T * blocks
+
+    def rnd(k, *shape):
+        return jax.random.normal(k, shape, jnp.float32).astype(dtype)
+    q_n, q_r = rnd(ks[0], H, C, dn), rnd(ks[1], H, C, dr)
+    qpos = start + jnp.arange(C, dtype=jnp.int32)
+    init = (jnp.full((H, C), latent.NEG_INF, jnp.float32),
+            jnp.zeros((H, C), jnp.float32), jnp.zeros((H, C, dv), jnp.float32))
+    carry = latent._attend_tile(
+        init, jnp.concatenate([q_n, q_r], -1), rnd(ks[2], H, C, dn + dr),
+        rnd(ks[3], H, C, dv), qpos, qpos, 0.2)
+    kpos = jnp.arange(S, dtype=jnp.int32)
+    kpos = jnp.where(kpos < start, kpos, jnp.int32(2 ** 30))
+    return carry, q_n, q_r, rnd(ks[4], H, S, dn), rnd(ks[5], S, dr), \
+        rnd(ks[6], H, S, dv), kpos, qpos
+
+
+def _lanes(carry):
+    """The plain step's carry (max ``[H, C]``, sum ``[H, C]``, accumulator
+    ``[H, C, d_v]``) as the kernel holds it, the queries in the lanes."""
+    m, l, acc = carry
+    return m[:, None], l[:, None], acc.transpose(0, 2, 1)
+
+
+def _rows(carry):
+    m, l, acc = carry
+    return m[:, 0], l[:, 0], acc.transpose(0, 2, 1)
+
+
+@functools.partial(jax.jit, static_argnames="blocks")
+def _step_kernel(carry, q_n, q_r, k_n, k_r, v, kpos, qpos, blocks=None):
+    """``mla_prefill_step`` (interpreted) on the plain step's operands."""
+    return _rows(mla.mla_prefill_step(
+        _lanes(carry), q_n.transpose(0, 2, 1), q_r.transpose(0, 2, 1), k_n,
+        k_r, v.transpose(0, 2, 1), kpos, qpos, 0.2, blocks=blocks,
+        interpret=True))
+
+
+def _step_plain(carry, q_n, q_r, k_n, k_r, v, kpos, qpos, scale):
+    k_r = jnp.broadcast_to(k_r[None], (k_n.shape[0],) + k_r.shape)
+    return latent._attend_tile(
+        carry, jnp.concatenate([q_n, q_r], -1),
+        jnp.concatenate([k_n, k_r], -1), v, kpos, qpos, scale)
+
+
+@pytest.mark.parametrize("occupied", ["none", "mid_block", "whole"])
+@pytest.mark.parametrize("T,blocks", [(16, 1), (16, 2), (16, 4), (64, 1),
+                                      (64, 2), (64, 4)])
+@pytest.mark.parametrize("H,C", [(4, 8), (4, 64), (32, 8)])
+def test_prefill_step_kernel_equals_the_plain_flash_step(H, C, T, blocks,
+                                                         occupied):
+    """``mla_prefill_step`` (interpreted; its operands the plain step's
+    with the queries turned into the lanes) against ``_attend_tile`` on the
+    same carry: a tile of 1, 2 or 4 blocks whose keys are all masked
+    (``kpos`` 2**30: the carry comes back as it went in), occupied up to
+    the middle of its last block, or whole."""
+    S = T * blocks
+    start = {"none": 0, "mid_block": S - T // 2 - 1, "whole": S}[occupied]
+    args = _step_case(H, C, T, blocks, start, seed=H + C + S)
+    got = _step_kernel(*args, blocks=blocks)
+    want = _step_plain(*args, 0.2)
+    if occupied == "none":
+        for g, c in zip(got, args[0]):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(c))
+    # bf16 operands: the plain step rounds its scores and its value product
+    # to bf16, the kernel keeps both in float32
+    for g, w in zip(got, want):
+        scale = float(jnp.abs(w).max())
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   atol=1.5e-2 * max(scale, 1.0))
+
+
+@jax.jit
+def _step_same_order(carry, q_n, q_r, k_n, k_r, v, kpos, qpos, scale=0.2):
+    """``_attend_tile`` in the kernel's order of operations: the queries in
+    the lanes, the score each head's own product plus the shared key's,
+    both products left in float32."""
+    m, l, acc = _lanes(carry)
+    f32 = jnp.float32
+    s = (jnp.einsum("hsd,hcd->hsc", k_n, q_n, preferred_element_type=f32)
+         + jnp.einsum("sd,hcd->hsc", k_r, q_r,
+                      preferred_element_type=f32)) * scale
+    s = jnp.where(kpos[None, :, None] <= qpos[None, None, :], s,
+                  latent.NEG_INF)
+    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+    pr = jnp.exp(s - m_new)
+    alpha = jnp.exp(m - m_new)
+    l = alpha * l + jnp.sum(pr, axis=1, keepdims=True)
+    acc = acc * alpha + jnp.einsum(
+        "hsd,hsc->hdc", v, pr.astype(v.dtype), preferred_element_type=f32)
+    return _rows((m_new, l, acc))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("H,C,T", [(4, 8, 16), (32, 64, 64)])
+def test_prefill_step_kernel_is_the_same_arithmetic_to_the_bit(H, C, T, dtype):
+    """One block a call is the plain step's order of operations once the
+    score is taken as the kernel takes it and nothing is rounded below
+    float32 but the probabilities: the max and the sum equal to the bit,
+    the accumulator to the last bit of a product summed in another order
+    (a head's ``[d_v, T] x [T, C]`` here, one batched product there)."""
+    args = _step_case(H, C, T, 1, T - 3, seed=3, dtype=dtype)
+    got = _step_kernel(*args, blocks=1)
+    want = _step_same_order(*args)
+    for g, w in zip(got[:2], want[:2]):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    np.testing.assert_allclose(np.asarray(got[2]), np.asarray(want[2]),
+                               rtol=1e-6, atol=1e-6)
+
+
+def prefill_three_chunks(cfg, p, impl, seed=11, bs=4, C=10, last=7, NB=10):
+    """A prompt of ``2 C + last`` tokens through ``attend_prefill`` in three
+    chunks (the second starts mid-block, the third past four whole blocks
+    and is not full): every chunk's output and the pool after it."""
+    x = jax.random.normal(jax.random.key(seed), (3, C, cfg.d_model))
+    pool = jnp.zeros((1 + NB, bs, cfg.latent_lanes))
+    table = jnp.arange(1, NB + 1, dtype=jnp.int32)
+
+    def step(x, pool, start, n):
+        return latent.attend_prefill(
+            x, pool, table, start + jnp.arange(C, dtype=jnp.int32), n, p,
+            cfg, jnp.int32(0), impl)
+    step = jax.jit(step)
+    ys = []
+    for i, n in enumerate((C, C, last)):
+        y, pool = step(x[i], pool, jnp.int32(i * C), jnp.int32(n))
+        ys.append(np.asarray(y[:n]))
+    return ys, np.asarray(pool)
+
+
+def test_expanded_prefill_through_the_kernel_equals_the_plain_path(
+        pallas_interpret):
+    """``attend_prefill`` with ``impl`` "pallas" (every flash step one
+    ``mla_prefill`` call: the chunk's own tile, four history blocks a call,
+    what is left one a call) against the plain path over a three-chunk
+    prompt with a rotated shared key."""
+    cfg = U.tiny_config()
+    p = _layer(cfg, U.tiny_params(cfg))
+    assert latent.blocks_per_call(cfg, 4, 4) == 4
+    got, got_pool = prefill_three_chunks(cfg, p, "pallas")
+    want, want_pool = prefill_three_chunks(cfg, p, "gather")
+    np.testing.assert_array_equal(got_pool, want_pool)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=2e-5)
+
+
+def test_blocks_per_call_follow_the_shapes():
+    """dots.vlm1's 128 heads expand a 512-token block to 33.6 MB of keys
+    and values, Kimi-Linear's 32 to 8.4 MB: two blocks a call, and four;
+    one where a block alone passes the bound."""
+    class Shape:
+        qk_nope_head_dim = v_head_dim = 128
+
+        def __init__(self, heads):
+            self.n_heads = heads
+    assert latent.blocks_per_call(Shape(128), 512, 2) == 2
+    assert latent.blocks_per_call(Shape(64), 512, 2) == 4
+    assert latent.blocks_per_call(Shape(32), 512, 2) == 4
+    assert latent.blocks_per_call(Shape(128), 1024, 2) == 1
+    assert latent.blocks_per_call(Shape(128), 512, 4) == 1
+
+
+def _tiles_of(prompts, layers, bs=4, chunk=16):
+    """Latent layers x (occupied history blocks + the own tile), summed
+    over the chunks of every prompt."""
+    return layers * sum(-(-start // bs) + 1 for p in prompts
+                        for start in range(0, len(p), chunk))
+
+
+def test_prefill_tile_counters_follow_the_chunks_plan(served):
+    """``serving_mla_prefill_tiles_{kernel,plain}_total``: off a TPU every
+    flash step of the three prompts' eight chunks is a plain one."""
+    cfg, _, prompts, srv, _ = served
+    assert _tiles_of(prompts, cfg.n_layers) == 4 * (15 + 6 + 15)
+    assert srv.stats["mla_prefill_tiles_plain_total"] == 144
+    assert srv.stats["mla_prefill_tiles_kernel_total"] == 0
+    assert srv.engine.mla_prefill_tiles(37, 4) == 4 * 11
+
+
+def test_the_kernels_serve_what_the_portable_path_serves(
+        served, pallas_interpret, monkeypatch):
+    """``decode_impl`` "pallas" off a TPU, every Mosaic kernel interpreted
+    (``mla_prefill`` in the prefill program, ``mla_decode`` in the decode
+    program, the grouped products): the reference's logits, and the flash
+    steps counted under ``kernel``."""
+    from jax.experimental.pallas.ops.tpu import megablox
+    cfg, params, prompts, _, _ = served
+    monkeypatch.setattr(megablox, "gmm",
+                        functools.partial(megablox.gmm, interpret=True))
+    monkeypatch.setenv("DS_PAGED_DECODE_IMPL", "pallas")
+    srv, got = U.serve_logits(cfg, params, prompts, 7)
+    assert srv.engine.decode_impl == "pallas"
+    assert _worst(U.reference(), cfg, params, prompts, got) < SOUND
+    assert srv.stats["mla_prefill_tiles_kernel_total"] == 144
+    assert srv.stats["mla_prefill_tiles_plain_total"] == 0

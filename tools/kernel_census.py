@@ -452,6 +452,112 @@ def mla_time_rows():
         yield f"mla decode time block {bs}", run
 
 
+# the latent prefill's two callers: (name, heads, model width, query rank
+# (None: one projection), rotated, table entries a slot), and the histories
+# (tokens before the chunk) timed; chunk and block are 512 in both cells
+MLA_PREFILL_SHAPES = (
+    ("dotsvlm1 H128", 128, 7168, 1536, True, 48,
+     (0, 512, 2048, 8192, 22016)),
+    ("kimilinear H32", 32, 2304, None, False, 16, (0, 512, 1536)))
+MLA_PREFILL_REPS = 20
+
+
+@functools.lru_cache(maxsize=None)
+def _mla_prefill_config(H, d, rq, rotated):
+    """A cell's latent layer as its config class has it."""
+    from deepspeed_tpu.models import dots_vlm, kimi_linear
+    kw = dict(vocab_size=256, n_heads=H, d_model=d, d_ff=256,
+              max_seq_len=24576, dtype=jnp.bfloat16, moe_d_ff=256,
+              use_flash_attention=False)
+    if rotated:
+        return dots_vlm.DotsVLMConfig(n_layers=2, q_lora_rank=rq, **kw)
+    return kimi_linear.KimiLinearConfig(
+        n_layers=2, kda_layers=(1,), full_attn_layers=(2,), **kw)
+
+
+def _mla_prefill_weights(cfg):
+    """The layer's bf16 weights at the cell's widths."""
+    r = np.random.default_rng(11)
+    H, d, rq = cfg.n_heads, cfg.d_model, cfg.q_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    rkv = cfg.kv_lora_rank
+
+    def w(*shape):
+        return {"kernel": _rand(r, shape) * shape[-2] ** -0.5}
+    p = {"ln1": {"scale": jnp.ones((d,), jnp.bfloat16)},
+         "kv_a": w(d, rkv + dr),
+         "kv_a_norm": {"scale": jnp.ones((rkv,), jnp.bfloat16)},
+         "k_up": w(H, dn, rkv), "v_up": w(H, rkv, dv),
+         "attn_out": w(H * dv, d)}
+    if rq:
+        p.update(q_a=w(d, rq), q_b=w(rq, H * (dn + dr)),
+                 q_a_norm={"scale": jnp.ones((rq,), jnp.bfloat16)})
+    else:
+        p["q"] = w(d, H * (dn + dr))
+    return p
+
+
+@functools.lru_cache(maxsize=None)
+def _mla_prefill_program(shape, impl, reps):
+    """``reps`` calls of ``attend_prefill`` over one chunk at ``start``,
+    jitted: each call's output the next one's input."""
+    from deepspeed_tpu.inference import latent
+    cfg = _mla_prefill_config(*shape)
+
+    def program(x, pool, table, start, p):
+        C = x.shape[0]
+
+        def one(_, x):
+            y, _ = latent.attend_prefill(
+                x, pool, table, start + jnp.arange(C), C, p, cfg,
+                jnp.int32(0), impl)
+            return y.astype(x.dtype)
+        return jax.lax.fori_loop(0, reps, one, x)
+    return jax.jit(program)
+
+
+def mla_prefill_time_rows():
+    """Milliseconds a latent layer's attention sublayer takes over one
+    512-token prefill chunk (inference/latent.py ``attend_prefill``: the
+    projections, the write, the chunk's own tile and every history tile),
+    plain and through the ``mla_prefill`` kernel, at both cells' widths and
+    a row of histories; ``us_tile``: what one more history tile costs
+    between the longest history and none. The kernel path is checked
+    against the plain one at the second history."""
+    from deepspeed_tpu.inference import latent
+    C = bs = 512
+    for name, H, d, rq, rotated, nb, starts in MLA_PREFILL_SHAPES:
+        def run(shape=(H, d, rq, rotated), nb=nb, starts=starts):
+            cfg = _mla_prefill_config(*shape)
+            p = _mla_prefill_weights(cfg)
+            r = np.random.default_rng(13)
+            x = _rand(r, (C, shape[1]))
+            pool = _rand(r, (1 + nb, bs, cfg.latent_lanes)) * 0.5
+            table = jnp.arange(1, nb + 1, dtype=jnp.int32)
+            row, one = {}, []
+            for impl, tag in (("gather", "plain"), ("pallas", "kernel")):
+                reps = _mla_prefill_program(shape, impl, MLA_PREFILL_REPS)
+                ms = []
+                for start in starts:
+                    args = (x, pool, table, jnp.int32(start), p)
+                    reps(*args).block_until_ready()
+                    best = float("inf")
+                    for _ in range(3):
+                        t0 = time.perf_counter()
+                        reps(*args).block_until_ready()
+                        best = min(best, time.perf_counter() - t0)
+                    ms.append(round(best / MLA_PREFILL_REPS * 1e3, 3))
+                row[f"ms_{tag}"] = ms
+                row[f"us_tile_{tag}"] = round(
+                    (ms[-1] - ms[0]) * 1e3 / (starts[-1] // bs), 1)
+                one.append(_mla_prefill_program(shape, impl, 1)(
+                    x, pool, table, jnp.int32(starts[1]), p))
+            row.update(starts=list(starts), fwd_err=_err(one[1], one[0]),
+                       blocks_per_call=latent.blocks_per_call(cfg, bs, 2))
+            return {**row, "ok": row["fwd_err"] < TOL}
+        yield f"mla prefill step time {name}", run
+
+
 # the two serving configurations' dispatch shapes: (configuration, slots,
 # table entries a slot (kexaone: 256 full + the ring's 9), prefill chunk,
 # vocabulary as served)
@@ -618,6 +724,7 @@ def main():
     with open(OUT, "a") as out:
         for rows in (flash_rows, ring_block_rows, paged_rows,
                      paged_time_rows, paged_masked_time_rows, mla_time_rows,
+                     mla_prefill_time_rows,
                      dispatch_operand_rows,
                      int8_matmul_rows, blocksparse_rows):
             for name, run in rows():
