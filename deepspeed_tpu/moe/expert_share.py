@@ -28,7 +28,14 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-# (tm, tk, tn) of the grouped matmul, chosen on the chip (PERF.md, PR 28)
+# The PREFERRED (tm, tk, tn) of the grouped matmul, chosen on the chip at
+# K-EXAONE's 6144 x 2048 experts, 48 and 512 tokens (PERF.md, PR 28). What a
+# product is handed is :func:`grouped_tiling`'s: for k and for n the largest
+# whole-lane tile within the preferred one that divides the dimension,
+# because megablox pays for a tile the shape does not fill (PERF.md, PR 44:
+# Kimi-Linear's 2,304 took three 1,024-tiles for 2.25 tiles of weights and a
+# float32 mask over the last k-tile of every visited expert; 3 x 768 is 12%
+# faster a decode call there, 2 x 1,152 the same within 1%).
 GMM_TILING = (128, 1024, 1024)
 STAT_FIELDS = ("pairs_held", "pairs_total", "busiest_expert_pairs",
                "experts_touched", "layer_calls")
@@ -111,15 +118,41 @@ def route_mlp(h, router: Dict, r_prev, eps: float):
     return sel[:, None].astype(jnp.int32), w, r
 
 
+def _whole_tile(dim: int, pref: int) -> int:
+    """The largest multiple of 128 lanes that divides ``dim`` exactly and
+    is no larger than ``pref`` (so a tile never outgrows the VMEM the
+    preferred one was measured at); ``min(pref, dim)`` where no multiple
+    of 128 does (megablox then masks the ragged last tile)."""
+    top = min(pref, dim)
+    return next((t for t in range(top // 128 * 128, 0, -128)
+                 if dim % t == 0), top)
+
+
+def grouped_tiling(k: int, n: int) -> Tuple[int, int, int]:
+    """The (tm, tk, tn) handed to megablox for a product ``[M, k] x [G, k,
+    n]``: ``GMM_TILING``'s row tile, and for each of ``k`` and ``n`` the
+    largest whole-lane tile that divides it and is not above
+    ``GMM_TILING``'s. ``GMM_TILING`` itself wherever it divides both."""
+    tm, tk, tn = GMM_TILING
+    return tm, _whole_tile(k, tk), _whole_tile(n, tn)
+
+
+def ragged_tile_share(k: int, n: int, tiling: Tuple[int, int, int]) -> float:
+    """The share of the weight-tile area a product fetches for one
+    ``[k, n]`` matrix that lies outside the matrix: 0 where ``tk`` divides
+    ``k`` and ``tn`` divides ``n``."""
+    _, tk, tn = tiling
+    return 1.0 - (k * n) / (-(-k // tk) * tk * -(-n // tn) * tn)
+
+
 def _grouped(x, w, sizes, impl: str):
     """Rows of ``x`` [M, a], grouped by ``sizes`` [G], times ``w`` [G, a, b].
     Rows past the groups come back undefined."""
     if impl == "ragged_dot":
         return jax.lax.ragged_dot(x, w, sizes)
     from jax.experimental.pallas.ops.tpu.megablox import gmm
-    tm, tk, tn = GMM_TILING
     return gmm(x, w, sizes, preferred_element_type=x.dtype,
-               tiling=(tm, min(tk, w.shape[1]), min(tn, w.shape[2])))
+               tiling=grouped_tiling(w.shape[1], w.shape[2]))
 
 
 def held_experts_ffn(h, experts: Dict, sel, w, held: Tuple[int, int],

@@ -258,17 +258,21 @@ def _paged_decode_jit(q, k, v, tables, lengths, active=None, *, scale,
                                     window=window, plan=plan)
 
 
-def _time_layers(layers, L, *args):
-    """Microseconds a call inside :func:`_paged_layers`' (or
-    :func:`_mla_layers`') program: the best of three runs after one that
-    compiles."""
-    layers(*args).block_until_ready()
+def _best_seconds(fn, *args):
+    """The best of three runs of ``fn(*args)`` after one that compiles."""
+    jax.block_until_ready(fn(*args))
     best = float("inf")
     for _ in range(3):
         t0 = time.perf_counter()
-        layers(*args).block_until_ready()
+        jax.block_until_ready(fn(*args))
         best = min(best, time.perf_counter() - t0)
-    return best / (PAGED_REPS * L) * 1e6
+    return best
+
+
+def _time_layers(layers, L, *args):
+    """Microseconds a call inside :func:`_paged_layers`' (or
+    :func:`_mla_layers`') program."""
+    return _best_seconds(layers, *args) / (PAGED_REPS * L) * 1e6
 
 
 def paged_time_rows():
@@ -558,6 +562,130 @@ def mla_prefill_time_rows():
         yield f"mla prefill step time {name}", run
 
 
+# the four sparse cells' expert layers: (cell, d, f, experts held, experts
+# routed over, experts a token, sparse layers stacked behind the ``layer``
+# index, tokens of a decode dispatch, tokens of a prefill chunk, the tiles
+# to time for a dimension that the preferred tile does not divide)
+GROUPED_SHAPES = (
+    ("kexaone", 6144, 2048, 16, 128, 8, 7, 48, 256, ()),
+    ("dotsvlm1", 7168, 2048, 16, 256, 8, 5, 16, 512, ()),
+    ("zaya1", 2048, 2048, 16, 16, 1, 20, 40, 512, ()),
+    ("kimilinear", 2304, 1024, 16, 256, 8, 26, 40, 512,
+     (1024, 768, 1152, 2304)))
+GROUPED_REPS = 104      # calls in one timed program, the layers in turn
+
+
+def grouped_time_rows():
+    """Microseconds a ``held_experts_ffn`` call takes (moe/expert_share.py:
+    the sort, the gather, three megablox ``gmm`` products, the un-sort and
+    the weighted sum; the scope ``moe_experts``) at the four sparse cells'
+    expert shapes, for a decode dispatch's tokens and a prefill chunk's,
+    every sparse layer's experts stacked behind the ``layer`` index as the
+    serving programs hand them over. ``tiling_up`` / ``tiling_down`` are
+    what ``grouped_tiling`` hands megablox for ``wg`` / ``wi`` ``[d, f]``
+    and ``wo`` ``[f, d]``, ``ragged_tile_share`` the share of the fetched
+    tile area outside the matrix, ``us_up`` / ``us_down`` one product
+    alone, ``least_us`` the touched experts' weights over 819 GB/s. Where
+    a cell lists tiles, each is also timed FORCED on the dimension the
+    preferred tile does not divide (1024 there is ``min(preferred, dim)``:
+    what every product was handed until PR 44). The kernel path is checked
+    against ``ragged_dot``."""
+    for (cell, *shape, t_dec, t_chunk, forced) in GROUPED_SHAPES:
+        for phase, T in (("decode", t_dec), ("chunk", t_chunk)):
+            for tile in (None,) + forced:
+                tag = "" if tile is None else f" forced {tile}"
+                yield (f"grouped product time {cell} {phase} T{T}{tag}",
+                       functools.partial(_grouped_time_row, *shape, T, tile))
+
+
+@functools.lru_cache(maxsize=None)
+def _grouped_programs(held, L, tile):
+    """The jitted programs of one :func:`grouped_time_rows` row. ``tile``
+    is only a key: a program traced under one forced tiling is not
+    another's."""
+    from deepspeed_tpu.moe import expert_share as ES
+
+    def stack(a):
+        return jnp.broadcast_to(a[None], (L,) + a.shape).reshape(
+            (-1,) + a.shape[1:])
+
+    def ffn(h, experts, sel, w):
+        def call(i, h):
+            y, _ = ES.held_experts_ffn(h, experts, sel, w, (0, held), "gmm",
+                                       layer=i % L)
+            return h + y * jnp.asarray(1e-3, h.dtype)
+        return jax.lax.fori_loop(0, GROUPED_REPS, call, h)
+
+    def product(x, kernel, groups):
+        def call(i, x):
+            lay = jax.lax.dynamic_update_slice(
+                jnp.zeros((L * held,), jnp.int32), groups, (i % L * held,))
+            y = ES._grouped(x, kernel, lay, "gmm")
+            return x.at[0, 0].add(y[0, 0] * jnp.asarray(1e-6, x.dtype))
+        return jax.lax.fori_loop(0, GROUPED_REPS, call, x)
+
+    def last_layer(h, experts, sel, w):
+        return ES.held_experts_ffn(h, experts, sel, w, (0, held), "gmm",
+                                   layer=jnp.int32(L - 1))
+
+    def plain(h, experts, sel, w):
+        return ES.held_experts_ffn(h, experts, sel, w, (0, held),
+                                   "ragged_dot")
+    return (jax.jit(stack), jax.jit(ffn), jax.jit(product),
+            jax.jit(last_layer), jax.jit(plain))
+
+
+def _grouped_time_row(d, f, held, total, k, L, T, tile):
+    """One row of :func:`grouped_time_rows`; ``tile`` None: the rule's."""
+    from deepspeed_tpu.moe import expert_share as ES
+    pref = ES.GMM_TILING[1]
+
+    def us(fn, *args):
+        return round(_best_seconds(fn, *args) / GROUPED_REPS * 1e6, 2)
+
+    def forced(a, b):
+        return (ES.GMM_TILING[0], tile if a % pref else min(pref, a),
+                tile if b % pref else min(pref, b))
+    rule = ES.grouped_tiling
+    if tile is not None:
+        ES.grouped_tiling = forced
+    try:
+        stack, ffn, product, last_layer, plain = _grouped_programs(
+            held, L, tile)
+        up, down = ES.grouped_tiling(d, f), ES.grouped_tiling(f, d)
+        r = np.random.default_rng(17)
+        one = {n: {"kernel": _rand(r, (held,) + s) * s[0] ** -0.5}
+               for n, s in (("wg", (d, f)), ("wi", (d, f)), ("wo", (f, d)))}
+        experts = {n: {"kernel": stack(e["kernel"])} for n, e in one.items()}
+        h = _rand(r, (T, d))
+        sel = jnp.asarray(np.stack([r.choice(total, k, replace=False)
+                                    for _ in range(T)]), jnp.int32)
+        w = jnp.full((T, k), 1.0 / k, jnp.float32)
+        got, stats = last_layer(h, experts, sel, w)
+        want, _ = plain(h, one, sel, w)
+        local = np.asarray(sel).reshape(-1)
+        groups = jnp.asarray(np.bincount(local[local < held],
+                                         minlength=held), jnp.int32)
+        M = -(-T * k // 128) * 128
+        touched = int(stats[3])
+        row = {"d": d, "f": f, "held": held, "experts": total, "k": k,
+               "layers": L, "tokens": T, "tiling_up": list(up),
+               "tiling_down": list(down),
+               "ragged_tile_share": [ES.ragged_tile_share(d, f, up),
+                                     ES.ragged_tile_share(f, d, down)],
+               "pairs_held": int(stats[0]), "experts_touched": touched,
+               "us_ffn": us(ffn, h, experts, sel, w),
+               "us_up": us(product, _rand(r, (M, d)),
+                           experts["wg"]["kernel"], groups),
+               "us_down": us(product, _rand(r, (M, f)),
+                             experts["wo"]["kernel"], groups),
+               "least_us": round(touched * 3 * d * f * 2 / 819e9 * 1e6, 2),
+               "fwd_err": _err(got, want)}
+        return {**row, "ok": row["fwd_err"] < TOL}
+    finally:
+        ES.grouped_tiling = rule
+
+
 # the two serving configurations' dispatch shapes: (configuration, slots,
 # table entries a slot (kexaone: 256 full + the ring's 9), prefill chunk,
 # vocabulary as served)
@@ -724,7 +852,7 @@ def main():
     with open(OUT, "a") as out:
         for rows in (flash_rows, ring_block_rows, paged_rows,
                      paged_time_rows, paged_masked_time_rows, mla_time_rows,
-                     mla_prefill_time_rows,
+                     mla_prefill_time_rows, grouped_time_rows,
                      dispatch_operand_rows,
                      int8_matmul_rows, blocksparse_rows):
             for name, run in rows():
