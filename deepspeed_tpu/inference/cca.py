@@ -1,6 +1,6 @@
 """Paged serving blocks for a model whose attention runs inside a
 compressed latent with convolutions over time (CCA; models/zaya.py): the
-fourth of the five dialects (paged_cache.refuse lists them), and the first
+fourth of the five dialects (inference/dialect.py lists them), and the first
 whose cache state is not only blocks of keys and values. What a layer
 attends is plain grouped-query attention over K
 and V pools ``[L, N, block, Hkv * Dh]`` behind the slot's block table, the
@@ -45,9 +45,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deepspeed_tpu.inference import dialect
 from deepspeed_tpu.inference.hybrid import _decode_attend, _heads, _rows
 from deepspeed_tpu.inference.latent import _attend_tile
 from deepspeed_tpu.models.gpt import _dense, _norm
+from deepspeed_tpu.models.zaya import layer_bases
 from deepspeed_tpu.moe import expert_share
 from deepspeed_tpu.ops.attention.paged import NEG_INF
 from deepspeed_tpu.ops.attention.rotary import apply_rotary_half_partial
@@ -72,15 +74,6 @@ class CCAState(NamedTuple):
 
 def is_cca(cfg) -> bool:
     return bool(getattr(cfg, "cca_time0", 0))
-
-
-def refuse(cfg, feature: str):
-    """Raise for a serving feature whose programs do not carry the tail."""
-    if is_cca(cfg):
-        raise ValueError(
-            f"{feature} is not supported for a model with convolutional "
-            f"(CCA) attention (a per-slot tail of the previous token rides "
-            f"beside the K and V pools): see docs/CCA_ATTENTION.md")
 
 
 def new_state(cfg, num_blocks: int, block_size: int, num_slots: int, dtype):
@@ -295,3 +288,39 @@ def block_decode(carry, pools, tables, lengths, active, p, cfg, base, impl,
     y, r, aux = _ffn(x2, aux["r"], p, cfg, impl, active, aux, base["index"],
                      experts)
     return (y[:, None], dict(aux, r=r)), (k_pool, v_pool, tails, vtails)
+
+
+def slot_bytes(cfg, block_size: int, dtype=jnp.bfloat16):
+    """Per layer the previous token's compressed row, its first
+    convolution's output and its half of the next value."""
+    return dialect.SlotBytes(cca_tail=int(
+        cfg.n_layers * cfg.cca_tail_values * jnp.dtype(dtype).itemsize))
+
+
+def gauges(reg, cache):
+    reg.gauge("kv_cca_tail_bytes",
+              "device bytes of the per-slot tails of convolutional (CCA) "
+              "attention: per layer and slot the previous token's "
+              "compressed row, its first convolution's output and its half "
+              "of the next value, whatever the slot's length").set(
+        cache.cca_tail_bytes)
+
+
+# the per-slot tails in the carry beside the two pools; no leading dense
+# layers
+DIALECT = dialect.Dialect(
+    owns=is_cca, new_state=new_state, pool=lambda k: k.rows,
+    prefill_reads=dialect.occupied_reads,
+    refusal=lambda cfg: ("convolutional (CCA) attention (a per-slot tail of "
+                         "the previous token rides beside the K and V "
+                         "pools)", "CCA_ATTENTION"),
+    state=CCAState, slot_bytes=slot_bytes, gauges=gauges,
+    **dialect.carried_layers(
+        block_prefill, block_decode, plan=dialect.rows_plan,
+        flat=lambda pools: ((pools[0].rows, pools[1], pools[0].tail,
+                             pools[0].vtail), pools[0].stats),
+        layer_bases=lambda cfg, bufs: layer_bases(cfg, bufs[0].shape[1],
+                                                  bufs[2].shape[1]),
+        pack=lambda bufs, stats, route: (
+            CCAState(bufs[0], bufs[2], bufs[3], stats, route), bufs[1]),
+        needs_slot=True))

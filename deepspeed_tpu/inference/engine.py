@@ -32,9 +32,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from deepspeed_tpu.inference import (cca, hybrid, latent, linear,
-                                     paged_cache, sampling)
-from deepspeed_tpu.inference.hybrid import _heads, _rows, causal_band
+from deepspeed_tpu.inference import dialect, paged_cache, sampling
+from deepspeed_tpu.inference.hybrid import (_heads, _rows, causal_band,
+                                            split_experts)
 from deepspeed_tpu.models import gpt as gpt_lib
 from deepspeed_tpu.moe import expert_share
 from deepspeed_tpu.ops import quantizer
@@ -919,6 +919,49 @@ def _attn_prefill_paged(x, pools, table_row, positions, n_valid, p,
     return h, attn, (k_pool, v_pool, k_scale, v_scale)
 
 
+def tile_reads(cfg, start: int, n: int, bs: int, nb: int) -> int:
+    """Positions of its slot's row a prefill chunk of the blocks above
+    reads: whole :func:`attended_tiles`, its own rows among them."""
+    lo, hi, P = attended_tiles(start, n, bs, nb, cfg.attn_window)
+    return min(max(hi - lo, 1) * P * bs, nb * bs)
+
+
+def _plain_state(cfg, num_blocks: int, block_size: int, num_slots: int,
+                 dtype):
+    # one row per cached token, its kv heads folded side by side
+    # (paged_cache's module docstring: one layout in HBM)
+    k = jnp.zeros((cfg.n_layers, num_blocks, block_size,
+                   cfg.kv_heads * cfg.head_dim), dtype)
+    return k, jnp.zeros_like(k)
+
+
+def _plain_prefill(eng, params, pools, x, table_row, positions, n_valid,
+                   slot, lora):
+    def block(x, pools, layer_p, base, lora):
+        return _block_prefill_paged(x, pools, table_row, positions, n_valid,
+                                    layer_p, eng.cfg, lora=lora, base=base)
+    return _scan_layers(block, x, params, pools, lora)
+
+
+def _plain_decode(eng, params, pools, x, tables, lengths, active, impl,
+                  lora):
+    plan = _paged_plan(pools, tables, lengths, active, eng.cfg)
+
+    def block(x, pools, layer_p, base, lora):
+        return _block_decode_paged(x, pools, tables, lengths, active,
+                                   layer_p, eng.cfg, impl=impl, lora=lora,
+                                   base=base, plan=plan)
+    return _scan_layers(block, x, params, pools, lora)
+
+
+# the plain K and V pools: owns every config no other dialect does, refuses
+# nothing and carries int8 scales and LoRA
+DIALECT = dialect.Dialect(
+    owns=lambda cfg: True, new_state=_plain_state, pool=lambda k: k,
+    prefill_layers=_plain_prefill, decode_layers=_plain_decode,
+    prefill_reads=tile_reads)
+
+
 class InferenceEngine:
     """Generation engine over a GPT-layout parameter pytree.
 
@@ -1035,7 +1078,7 @@ class InferenceEngine:
             # None when absent), so the fp, int8, adapter and int8+adapter
             # variants of a family are cache entries of its one callable,
             # told apart by jax from the structure of what it is handed,
-            # as hybrid.PagedState rides in k_pool's place. A run serves
+            # as a dialect's state rides in k_pool's place. A run serves
             # one variant and compiles one entry per family: steady state
             # is the same two programs (prefill, and decode or its horizon
             # or verify form) whatever the variant and the arrival pattern.
@@ -1081,16 +1124,15 @@ class InferenceEngine:
             self._gather_blocks = jax.jit(partial(paged_cache.gather_blocks))
             self._scatter_block = jax.jit(
                 partial(paged_cache.scatter_block), donate_argnums=(0,))
-        if hybrid.is_hybrid(config) or latent.is_latent(config) \
-                or cca.is_cca(config) or linear.is_linear(config):
+        if self.dialect.refusal is not None:
             # two kinds of attention state, a latent pool, per-slot tails
             # or a recurrent state beside the pools: only the two paged
             # serving programs know them. Everything else raises by name
             # rather than grow a copy of the dialect (ROADMAP D4)
-            def refuse(what, *a, **k):
-                paged_cache.refuse(config, what)
+            def refused(what, *a, **k):
+                dialect.refuse(config, what)
             if mp_size > 1:
-                refuse("tensor parallelism (mp_size > 1)")
+                refused("tensor parallelism (mp_size > 1)")
             for attr, what in (
                     ("_prefill", "the static-cache prefill (generate)"),
                     ("_decode", "the static-cache decode (generate)"),
@@ -1101,7 +1143,7 @@ class InferenceEngine:
                     ("_cow_blocks", "prefix-cache copy-on-write"),
                     ("_gather_blocks", "the host tier"),
                     ("_scatter_block", "the host tier")):
-                setattr(self, attr, functools.partial(refuse, what))
+                setattr(self, attr, functools.partial(refused, what))
         # a ProgramCostRegistry that wants the compiled text of each
         # serving program (a ServingEngine with telemetry on sets it)
         self.provenance = None
@@ -1252,37 +1294,9 @@ class InferenceEngine:
             if cfg.use_wpe:
                 safe = jnp.clip(positions, 0, self.max_seq_len - 1)
                 x = x + self._wpe(params, safe)[None]
-        if hybrid.is_hybrid(cfg):
-            def hblock(carry, flat, layer_p, base, lora, experts):
-                return hybrid.block_prefill(
-                    carry, flat, table_row, positions, n_valid, layer_p,
-                    cfg, base, self.decode_impl, experts)
-            x, pools = self._hybrid_layers(params, pools, hblock, x, 0)
-        elif linear.is_linear(cfg):
-            x, pools = self._linear_layers(
-                params, pools, linear.prefill_attends(
-                    cfg, table_row, positions, n_valid, slot,
-                    self.decode_impl), x,
-                jnp.arange(C) < n_valid, self.decode_impl, 0)
-        elif latent.is_latent(cfg):
-            def lblock(carry, flat, layer_p, base, lora, experts):
-                return latent.block_prefill(
-                    carry, flat, table_row, positions, n_valid, layer_p,
-                    cfg, base, self.decode_impl, experts)
-            x, pools = self._latent_layers(params, pools, lblock, x, 0)
-        elif cca.is_cca(cfg):
-            def cblock(carry, flat, layer_p, base, lora, experts):
-                return cca.block_prefill(
-                    carry, flat, table_row, positions, n_valid, slot,
-                    layer_p, cfg, base, self.decode_impl, experts)
-            x, pools = self._cca_layers(params, pools, cblock, x, 0)
-        else:
-            def block(x, pools, layer_p, base, lora):
-                return _block_prefill_paged(x, pools, table_row, positions,
-                                            n_valid, layer_p, cfg, lora=lora,
-                                            base=base)
-
-            x, pools = _scan_layers(block, x, params, pools, lora_ops)
+        x, pools = self.dialect.prefill_layers(
+            self, params, pools, x, table_row, positions, n_valid, slot,
+            lora_ops)
         last = jnp.clip(n_valid - 1, 0, C - 1)
         x_last = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
         logits = self._logits(params, x_last)
@@ -1318,127 +1332,18 @@ class InferenceEngine:
             if cfg.use_wpe:
                 safe = jnp.clip(lengths, 0, self.max_seq_len - 1)
                 x = x + self._wpe(params, safe)[:, None]
-        if hybrid.is_hybrid(cfg):
-            plans = hybrid.decode_plans(cfg, pools[0].full.shape[2], tables,
-                                        lengths, active)
-
-            def hblock(carry, flat, layer_p, base, lora, experts):
-                return hybrid.block_decode(
-                    carry, flat, tables, lengths, active, layer_p, cfg,
-                    base, impl, experts, plans)
-            x, pools = self._hybrid_layers(params, pools, hblock, x, 1)
-        elif linear.is_linear(cfg):
-            plan = decode_plan(lengths, tables.shape[1],
-                               pools[0].rows.shape[2], active=active)
-            x, pools = self._linear_layers(
-                params, pools, linear.decode_attends(
-                    cfg, tables, lengths, active, impl, plan), x, active,
-                impl, 1)
-        elif latent.is_latent(cfg):
-            plan = decode_plan(lengths, tables.shape[1],
-                               pools[0].rows.shape[2], active=active)
-
-            def lblock(carry, flat, layer_p, base, lora, experts):
-                return latent.block_decode(
-                    carry, flat, tables, lengths, active, layer_p, cfg,
-                    base, impl, experts, plan)
-            x, pools = self._latent_layers(params, pools, lblock, x, 1)
-        elif cca.is_cca(cfg):
-            plan = decode_plan(lengths, tables.shape[1],
-                               pools[0].rows.shape[2], active=active)
-
-            def cblock(carry, flat, layer_p, base, lora, experts):
-                return cca.block_decode(
-                    carry, flat, tables, lengths, active, layer_p, cfg,
-                    base, impl, experts, plan)
-            x, pools = self._cca_layers(params, pools, cblock, x, 1)
-        else:
-            plan = _paged_plan(pools, tables, lengths, active, cfg)
-
-            def block(x, pools, layer_p, base, lora):
-                return _block_decode_paged(x, pools, tables, lengths, active,
-                                           layer_p, cfg, impl=impl, lora=lora,
-                                           base=base, plan=plan)
-
-            x, pools = _scan_layers(block, x, params, pools, lora)
+        x, pools = self.dialect.decode_layers(
+            self, params, pools, x, tables, lengths, active, impl, lora)
         logits = self._logits(params, x)
         toks, lps = sampling.sample_tokens(
             logits[:, -1], keys, gen_counts, temps, top_ks, top_ps,
             rep_pens, seen)
         return (logits, toks, lps) + pools
 
-    def _hybrid_layers(self, params, pools, block, x, phase: int):
-        """The layers of both serving programs for a model of two
-        attention kinds (inference/hybrid.py), with the full pool and the
-        window rings side by side in the carry. ``pools`` = (K state, V
-        state) as PagedState and so is what comes back beside ``x``;
-        ``phase``: the row of the K state's counters this program adds to
-        (0 prefill, 1 decode)."""
-        from deepspeed_tpu.models.exaone_moe import layer_bases
-        ks, vs = pools
-        x, flat, stats, route = self._dense_then_sparse(
-            params, (ks.full, vs.full, ks.win, vs.win),
-            layer_bases(self.cfg, ks.full.shape[1], ks.win.shape[1]),
-            block, x, ks.stats, phase)
-        return x, (hybrid.PagedState(flat[0], flat[2], stats, route),
-                   hybrid.PagedState(flat[1], flat[3]))
-
-    def _latent_layers(self, params, pools, block, x, phase: int):
-        """The same for a model with latent attention (inference/
-        latent.py): ``pools`` = (LatentState, None), one pool of rows."""
-        from deepspeed_tpu.models.dots_vlm import layer_bases
-        state, none = pools
-        x, (rows,), stats, route = self._dense_then_sparse(
-            params, (state.rows,),
-            layer_bases(self.cfg, state.rows.shape[1]), block, x,
-            state.stats, phase)
-        return x, (latent.LatentState(rows, stats, route), none)
-
-    def _cca_layers(self, params, pools, block, x, phase: int):
-        """The same for a model with convolutional attention (inference/
-        cca.py): ``pools`` = (CCAState, the V pool), the per-slot tails in
-        the carry beside the two pools; no leading dense layers."""
-        from deepspeed_tpu.models.zaya import layer_bases
-        state, v = pools
-        x, (k, v, tail, vtail), stats, route = self._dense_then_sparse(
-            params, (state.rows, v, state.tail, state.vtail),
-            layer_bases(self.cfg, state.rows.shape[1], state.tail.shape[1]),
-            block, x, state.stats, phase)
-        return x, (cca.CCAState(k, tail, vtail, stats, route), v)
-
-    def _linear_layers(self, params, pools, attends, x, valid, impl: str,
-                       phase: int):
-        """The layers of both serving programs for a model whose layers
-        keep a per-slot recurrent state or page their history, by its
-        config (inference/linear.py ``run_layers``, whose loop this
-        feeds): ``pools`` = (LinearState, the V pool or None), the paged
-        layers' pool or pools, the recurrent layers' state and their
-        convolution tails side by side in the carry, each stacked over its
-        OWN kind's layers; ``attends``: the two attention sublayers;
-        ``valid`` ``[T]``: the rows that are tokens. ``x`` ``[1, C, d]`` or
-        ``[B, 1, d]``."""
-        from deepspeed_tpu.models.recurrent import layer_bases
-        cfg = self.cfg
-        st, v = pools
-        shapes = (st.rows,) + (() if v is None else (v,)) \
-            + (st.state, st.tail)
-        flat = tuple(p.reshape((-1,) + p.shape[2:]) for p in shapes)
-        T = x.shape[0] * x.shape[1]
-        experts, aux = None, {"stats": None, "route": None}
-        if "moe" in params["block"]:
-            params, experts = hybrid.split_experts(params)
-            aux = self._dispatch_record(T, st.stats)
-        (y, aux), flat = linear.run_layers(
-            cfg, params, experts, (x.reshape(T, -1), aux), flat,
-            layer_bases(cfg, st.rows.shape[1], st.state.shape[1]), attends,
-            valid, impl)
-        stats = st.stats
-        if stats is not None:
-            stats = stats.at[phase].add(aux["stats"])
-        flat = [f.reshape(p.shape) for f, p in zip(flat, shapes)]
-        return y.reshape(x.shape), (linear.LinearState(
-            flat[0], flat[-2], flat[-1], stats, aux["route"]),
-            None if v is None else flat[1])
+    @functools.cached_property
+    def dialect(self) -> dialect.Dialect:
+        """The model's cache dialect (inference/dialect.py), asked once."""
+        return dialect.of(self.cfg)
 
     def _dispatch_record(self, tokens: int, stats):
         """What a sparse model's layer loop carries beside ``x``: the
@@ -1462,7 +1367,7 @@ class InferenceEngine:
         model with no leading dense layer runs the one scan. Returns (x,
         flat, stats, route)."""
         cfg = self.cfg
-        params, experts = hybrid.split_experts(params)
+        params, experts = split_experts(params)
         block = functools.partial(block, experts=experts)
         dense_b, sparse_b = bases
         aux = self._dispatch_record(x.shape[0] * x.shape[1], stats)
@@ -1633,12 +1538,6 @@ class InferenceEngine:
         _, _, live, produced, _, _, pools = carry
         return (toks, lps, produced, jnp.logical_not(live)) + pools
 
-    def sync(self, *values) -> None:
-        """Barrier on device values (pools, logits) — same discipline as
-        utils/timer's ``_device_sync``, but scoped to the values the
-        serving step actually produced so it keys no new programs."""
-        jax.block_until_ready(values)
-
     # public wrappers: host-side numpy in, device pools threaded through
     # (``scales``: PagedKVCache.scales, None or the int8 pools' (k_scale,
     # v_scale)); what comes back ends in PagedKVCache.pools, updated: k, v
@@ -1702,8 +1601,7 @@ class InferenceEngine:
         if scales is not None:
             from deepspeed_tpu.utils.faults import maybe_fire
             maybe_fire("cache.quantize")
-        if isinstance(k_pool, (hybrid.PagedState, latent.LatentState,
-                               cca.CCAState, linear.LinearState)):
+        if self.dialect.state is not None:
             k_pool = k_pool._replace(route=None)    # an output only
         if lora is not None:
             parts = (*parts, ("lora", lora[2]))
@@ -1716,12 +1614,11 @@ class InferenceEngine:
                 lora)
         sink = self.provenance
         if sink is not None and pid not in sink.provenance:
-            L, N, bs = paged_cache.paged_pool(k_pool).shape[:3]
+            L, N, bs = self.dialect.pool(k_pool).shape[:3]
             grid = ()
             if kernel_table:
                 B, nb = kernel_table
-                if hybrid.is_hybrid(self.cfg):
-                    nb -= hybrid.window_blocks(self.cfg, bs)
+                nb -= self.dialect.ring_blocks(self.cfg, bs)
                 per_step = blocks_per_step(nb, bs)
                 grid = (per_step, B * -(-nb // per_step))
             copied = sink.add_provenance(
@@ -1753,41 +1650,23 @@ class InferenceEngine:
         """Positions of its slot's row (``nb`` blocks of ``bs``) that a
         prefill chunk of ``n`` tokens at ``start`` reads from the pool, in
         a layer that pages its history: the program's own count, on the
-        host (the ``attended`` field of ``serve.prefill``). The GPT dialect
-        reads whole ``attended_tiles``, its own rows among them (so do the
-        attention layers beside a state-space state); the latent and
-        convolutional dialects, and the latent layers beside a
-        linear-attention state, walk the occupied blocks of ``[0, start)``
-        one by one; the windowed dialect's full layers
-        and the int8 pools' requantising write read the whole row."""
-        cfg = self.cfg
-        if quantized or hybrid.is_hybrid(cfg):
+        host (the ``attended`` field of ``serve.prefill``). The int8
+        pools' requantising write reads the whole row."""
+        if quantized:
             return nb * bs
-        if latent.is_latent(cfg) or cca.is_cca(cfg):
-            return (start + bs - 1) // bs * bs
-        lo, hi, P = attended_tiles(start, n, bs, nb, cfg.attn_window)
-        return min(max(hi - lo, 1) * P * bs, nb * bs)
+        return self.dialect.prefill_reads(self.cfg, start, n, bs, nb)
 
     def mla_prefill_tiles(self, start: int, bs: int) -> int:
         """Flash steps a prefill chunk at ``start`` takes in the latent
-        layers (latent.attend_prefill: every occupied history block of
-        ``bs`` and the chunk's own tile, a layer), on the host; 0 for a
-        model without latent rows. Each is one ``mla_prefill`` kernel
-        block where ``decode_impl`` is "pallas", one plain flash step
-        otherwise."""
-        cfg = self.cfg
-        if not latent.is_latent(cfg):
-            return 0
-        layers = getattr(cfg, "n_full_layers", cfg.n_layers)
-        return layers * ((start + bs - 1) // bs + 1)
+        layers, on the host; 0 for a model without latent rows."""
+        return self.dialect.flash_steps(self.cfg, start, bs)
 
     def prefill_into_slot(self, k_pool, v_pool, table_row, tokens, start,
                           n_valid, scales=None, sample_state=None,
                           lora=None):
         from deepspeed_tpu.utils.faults import maybe_fire
         maybe_fire("engine.prefill")
-        if sample_state is None and (cca.is_cca(self.cfg)
-                                     or linear.is_linear(self.cfg)):
+        if sample_state is None and self.dialect.needs_slot:
             # the program finds the slot's tail or recurrent state by the
             # lane's slot index
             raise ValueError("a prefill for a model with per-slot state "

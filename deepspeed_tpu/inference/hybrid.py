@@ -27,7 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deepspeed_tpu.models.exaone_moe import window_blocks
+from deepspeed_tpu.inference import dialect
+from deepspeed_tpu.models.exaone_moe import layer_bases, window_blocks
 from deepspeed_tpu.models.gpt import _dense, _norm
 from deepspeed_tpu.moe import expert_share
 from deepspeed_tpu.ops.attention.paged import NEG_INF
@@ -54,14 +55,6 @@ class PagedState(NamedTuple):
 
 def is_hybrid(cfg) -> bool:
     return bool(getattr(cfg, "layer_kinds", ()))
-
-
-def refuse(cfg, feature: str):
-    """Raise for a serving feature that cannot yet live with window state."""
-    if is_hybrid(cfg):
-        raise ValueError(
-            f"{feature} is not supported for a model with sliding-window "
-            f"layers (bounded per-slot window state): see docs/EXPERT_SHARE.md")
 
 
 def causal_band(scores, kpos, qpos, window=None):
@@ -326,3 +319,46 @@ def block_decode(carry, pools, tables, lengths, active, p, cfg, base, impl,
         x2 = x[:, 0] + _dense(attn.reshape(B, H * Dh), p["attn_out"])
     y, aux = _ffn(x2, p, cfg, impl, active, aux, base["index"], experts)
     return (y[:, None], aux), (kf, vf, kw, vw)
+
+
+def new_state(cfg, num_blocks: int, block_size: int, num_slots: int, dtype):
+    """Zeroed (K, V) PagedState: ``num_blocks`` blocks a full layer and,
+    per window layer, each slot's ring behind block 0, its trash block."""
+    rows = cfg.kv_heads * cfg.head_dim
+    full = jnp.zeros((cfg.n_full_layers, num_blocks, block_size, rows), dtype)
+    win = jnp.zeros((cfg.n_window_layers,
+                     1 + num_slots * window_blocks(cfg, block_size),
+                     block_size, rows), dtype)
+    return (PagedState(full, win),
+            PagedState(jnp.zeros_like(full), jnp.zeros_like(win)))
+
+
+def slot_bytes(cfg, block_size: int, dtype=jnp.bfloat16):
+    """K+V of one slot's rings in the sliding-window layers."""
+    return dialect.SlotBytes(window=int(
+        2 * cfg.n_window_layers * window_blocks(cfg, block_size) * block_size
+        * cfg.kv_heads * cfg.head_dim * jnp.dtype(dtype).itemsize))
+
+
+# the full pool and the window rings side by side in the layer loop's carry
+DIALECT = dialect.Dialect(
+    owns=is_hybrid, new_state=new_state, pool=lambda k: k.full,
+    # the full layers read the whole row
+    prefill_reads=lambda cfg, start, n, bs, nb: nb * bs,
+    refusal=lambda cfg: ("sliding-window layers (bounded per-slot window "
+                         "state)", "EXPERT_SHARE"),
+    state=PagedState, bytes_per_token=dialect.full_layers_kv_bytes,
+    slot_bytes=slot_bytes,
+    # looked up when called: a test swaps this module's window_blocks
+    ring_blocks=lambda cfg, block_size: window_blocks(cfg, block_size),
+    **dialect.carried_layers(
+        block_prefill, block_decode,
+        plan=lambda cfg, pools, tables, lengths, active: decode_plans(
+            cfg, pools[0].full.shape[2], tables, lengths, active),
+        flat=lambda pools: ((pools[0].full, pools[1].full, pools[0].win,
+                             pools[1].win), pools[0].stats),
+        layer_bases=lambda cfg, bufs: layer_bases(cfg, bufs[0].shape[1],
+                                                  bufs[2].shape[1]),
+        pack=lambda bufs, stats, route: (
+            PagedState(bufs[0], bufs[2], stats, route),
+            PagedState(bufs[1], bufs[3]))))

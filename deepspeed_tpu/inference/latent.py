@@ -1,6 +1,6 @@
 """Paged serving blocks for a model with latent attention (MLA;
 models/dots_vlm.py): the third of the five kinds of cache state
-(paged_cache.refuse lists them). A token's row in a layer
+(inference/dialect.py lists them). A token's row in a layer
 is ``[c_kv | k_r | 0...]``, the normalised latent of ``kv_lora_rank``
 values, the one rotated key of ``qk_rope_head_dim`` values that all heads
 share, and zeros up to whole lane tiles: ONE pool ``[L, N, block, lanes]``
@@ -49,7 +49,9 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.inference import dialect
 from deepspeed_tpu.inference.hybrid import _ffn
+from deepspeed_tpu.models.dots_vlm import layer_bases
 from deepspeed_tpu.models.gpt import _dense, _norm
 from deepspeed_tpu.ops.attention.paged import NEG_INF
 from deepspeed_tpu.ops.attention.rotary import apply_rotary_freqs
@@ -71,15 +73,6 @@ class LatentState(NamedTuple):
 
 def is_latent(cfg) -> bool:
     return bool(getattr(cfg, "kv_lora_rank", 0))
-
-
-def refuse(cfg, feature: str):
-    """Raise for a serving feature that cannot yet live with a latent pool."""
-    if is_latent(cfg):
-        raise ValueError(
-            f"{feature} is not supported for a model with a latent (MLA) "
-            f"cache row (one pool of latents, no K/V heads): see "
-            f"docs/LATENT_ATTENTION.md")
 
 
 def _project(h, p, cfg, positions):
@@ -326,3 +319,52 @@ def block_decode(carry, pools, tables, lengths, active, p, cfg, base, impl,
                              cfg, base["rows"], impl, plan)
     y, aux = _ffn(x2, p, cfg, impl, active, aux, base["index"], experts)
     return (y[:, None], aux), (pool,)
+
+
+def new_state(cfg, num_blocks: int, block_size: int, num_slots: int, dtype):
+    """Zeroed (LatentState, None): one pool of latent rows, no V pool."""
+    return LatentState(jnp.zeros((cfg.n_layers, num_blocks, block_size,
+                                  cfg.latent_lanes), dtype)), None
+
+
+def kv_bytes_per_token(cfg, dtype=jnp.bfloat16) -> int:
+    """One row of ``latent_lanes`` values a latent layer (the latent and
+    the shared key, padded to whole lane tiles), no K or V heads."""
+    return int(getattr(cfg, "n_full_layers", cfg.n_layers)
+               * cfg.latent_lanes * jnp.dtype(dtype).itemsize)
+
+
+def flash_steps(cfg, start: int, bs: int) -> int:
+    """Flash steps of a prefill chunk at ``start`` (:func:`attend_prefill`:
+    every occupied history block of ``bs`` and the chunk's own tile, a
+    layer): ``mla_prefill`` kernel blocks where ``decode_impl`` is
+    "pallas", plain flash steps otherwise."""
+    return getattr(cfg, "n_full_layers", cfg.n_layers) \
+        * ((start + bs - 1) // bs + 1)
+
+
+def gauges(reg, cache):
+    reg.gauge("kv_latent_pool_bytes",
+              "device bytes of the latent (MLA) pool: one row a token a "
+              "layer as stored (padded to whole lane tiles), trash block "
+              "included").set(
+        cache.num_blocks * cache.block_size * cache.bytes_per_token)
+    reg.gauge("kv_latent_row_bytes",
+              "bytes of one token's latent row in one layer as computed: "
+              "the latent and the shared rotated key, without the "
+              "padding").set(cache.cfg.latent_row * cache.pool_dtype.itemsize)
+
+
+DIALECT = dialect.Dialect(
+    owns=is_latent, new_state=new_state, pool=lambda k: k.rows,
+    prefill_reads=dialect.occupied_reads,
+    refusal=lambda cfg: ("a latent (MLA) cache row (one pool of latents, no "
+                         "K/V heads)", "LATENT_ATTENTION"),
+    state=LatentState, bytes_per_token=kv_bytes_per_token,
+    flash_steps=flash_steps, gauges=gauges,
+    **dialect.carried_layers(
+        block_prefill, block_decode, plan=dialect.rows_plan,
+        flat=lambda pools: ((pools[0].rows,), pools[0].stats),
+        layer_bases=lambda cfg, bufs: layer_bases(cfg, bufs[0].shape[1]),
+        pack=lambda bufs, stats, route: (
+            LatentState(bufs[0], stats, route), None)))

@@ -58,10 +58,10 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference import latent, ssm
-from deepspeed_tpu.inference.hybrid import _ffn, _heads, _rows
+from deepspeed_tpu.inference import dialect, latent, ssm
+from deepspeed_tpu.inference.hybrid import _ffn, _heads, _rows, split_experts
 from deepspeed_tpu.models.gpt import _dense, _norm
-from deepspeed_tpu.models.recurrent import layer_runs
+from deepspeed_tpu.models.recurrent import layer_bases, layer_runs
 from deepspeed_tpu.ops.attention import kda
 
 
@@ -87,37 +87,25 @@ def is_linear(cfg) -> bool:
     return bool(getattr(cfg, "recurrent_state_values", 0))
 
 
-def refuse(cfg, feature: str):
-    """Raise for a serving feature whose programs do not carry the state."""
-    if is_linear(cfg):
-        rule, doc = ("state-space", "STATE_SPACE") if ssm.is_ssm(cfg) \
-            else ("linear-attention", "LINEAR_ATTENTION")
-        raise ValueError(
-            f"{feature} is not supported for a model with a per-slot "
-            f"recurrent state (written by its {rule} layers: it summarises "
-            f"the whole history and rides beside the paged pool): see "
-            f"docs/{doc}.md")
+# the paged layers keep one pool of latent rows (Kimi-Linear's MLA layers),
+# not K and V pools (Jamba's attention layers)
+_latent_rows = latent.DIALECT.owns
 
 
 def new_state(cfg, num_blocks: int, block_size: int, num_slots: int, dtype):
-    """Zeroed LinearState for ``num_blocks`` blocks a paged layer: ``rows``
-    one pool of latent rows, or the K pool of the K/V heads' rows (the V
-    pool beside it is :func:`paged_v_pool`)."""
+    """Zeroed (LinearState, what rides in ``v_pool``'s place) for
+    ``num_blocks`` blocks a paged layer: ``rows`` one pool of latent rows
+    and nothing beside it, or the K pool of the K/V heads' rows and a V
+    pool."""
     Lr = cfg.n_recurrent_layers
-    return LinearState(
+    state = LinearState(
         jnp.zeros((cfg.n_full_layers, num_blocks, block_size,
-                   cfg.latent_lanes if latent.is_latent(cfg)
+                   cfg.latent_lanes if _latent_rows(cfg)
                    else cfg.kv_heads * cfg.head_dim), dtype),
         jnp.zeros((Lr, num_slots) + tuple(cfg.recurrent_state_shape),
                   jnp.float32),
         jnp.zeros((Lr, num_slots, cfg.conv_tail_width), dtype))
-
-
-def paged_v_pool(cfg, state: LinearState):
-    """What rides in ``v_pool``'s place beside ``state``: nothing where the
-    paged layers keep one pool of latent rows, a zeroed V pool where they
-    keep K and V."""
-    return None if latent.is_latent(cfg) else jnp.zeros_like(state.rows)
+    return state, None if _latent_rows(cfg) else jnp.zeros_like(state.rows)
 
 
 def step_plan(active):
@@ -341,3 +329,111 @@ def run_layers(cfg, params, experts, carry, flat, bases, attends, valid,
         loop = recurrent_run(jnp.int32(behind[0]), jnp.int32(behind[1]),
                              loop)
     return loop
+
+
+def serve_layers(eng, params, pools, attends, x, valid, impl: str,
+                 phase: int):
+    """The layers of both serving programs (:func:`run_layers`, whose loop
+    this feeds): ``pools`` = (LinearState, the V pool or None), the paged
+    layers' pool or pools, the recurrent layers' state and their
+    convolution tails side by side in the carry, each stacked over its OWN
+    kind's layers; ``attends``: the two attention sublayers; ``valid``
+    ``[T]``: the rows that are tokens. ``x`` ``[1, C, d]`` or ``[B, 1,
+    d]``; ``phase``: the counters' row (0 prefill, 1 decode)."""
+    cfg = eng.cfg
+    st, v = pools
+    shapes = (st.rows,) + (() if v is None else (v,)) \
+        + (st.state, st.tail)
+    flat = tuple(p.reshape((-1,) + p.shape[2:]) for p in shapes)
+    T = x.shape[0] * x.shape[1]
+    experts, aux = None, {"stats": None, "route": None}
+    if "moe" in params["block"]:
+        params, experts = split_experts(params)
+        aux = eng._dispatch_record(T, st.stats)
+    (y, aux), flat = run_layers(
+        cfg, params, experts, (x.reshape(T, -1), aux), flat,
+        layer_bases(cfg, st.rows.shape[1], st.state.shape[1]), attends,
+        valid, impl)
+    stats = st.stats
+    if stats is not None:
+        stats = stats.at[phase].add(aux["stats"])
+    flat = [f.reshape(p.shape) for f, p in zip(flat, shapes)]
+    return y.reshape(x.shape), (LinearState(
+        flat[0], flat[-2], flat[-1], stats, aux["route"]),
+        None if v is None else flat[1])
+
+
+def prefill_layers(eng, params, pools, x, table_row, positions, n_valid,
+                   slot, lora):
+    impl = eng.decode_impl
+    return serve_layers(
+        eng, params, pools, prefill_attends(
+            eng.cfg, table_row, positions, n_valid, slot, impl), x,
+        jnp.arange(x.shape[1]) < n_valid, impl, 0)
+
+
+def decode_layers(eng, params, pools, x, tables, lengths, active, impl, lora):
+    plan = dialect.rows_plan(eng.cfg, pools, tables, lengths, active)
+    return serve_layers(
+        eng, params, pools, decode_attends(
+            eng.cfg, tables, lengths, active, impl, plan), x, active, impl, 1)
+
+
+def kv_bytes_per_token(cfg, dtype=jnp.bfloat16) -> int:
+    """Bytes ONE token occupies across the PAGED layers: a recurrent layer
+    keeps a state per slot and adds nothing per token."""
+    return (latent.kv_bytes_per_token if _latent_rows(cfg)
+            else dialect.full_layers_kv_bytes)(cfg, dtype)
+
+
+def slot_bytes(cfg, block_size: int, dtype=jnp.bfloat16):
+    """Per recurrent layer the float32 state, and the last tokens'
+    un-convolved rows in the pools' type."""
+    return dialect.SlotBytes(
+        recurrent_state=4 * int(cfg.recurrent_state_values),
+        conv_tail=int(cfg.conv_tail_values) * jnp.dtype(dtype).itemsize)
+
+
+def gauges(reg, cache):
+    if _latent_rows(cache.cfg):
+        latent.gauges(reg, cache)
+    reg.gauge("kv_recurrent_state_bytes",
+              "device bytes of the per-slot recurrent state: float32, per "
+              "recurrent layer and slot one matrix of head_dim x head_dim a "
+              "head (linear attention) or d_state x d_inner (a state-space "
+              "mixer), read and rewritten by every token whatever the "
+              "slot's length").set(cache.recurrent_state_bytes)
+    reg.gauge("kv_conv_tail_bytes",
+              "device bytes of the recurrent layers' per-slot convolution "
+              "tails: per layer and slot the un-convolved rows ([q | k | "
+              "v], or a state-space mixer's x) of the last conv_kernel - 1 "
+              "tokens").set(cache.conv_tail_bytes)
+
+
+def _refusal(cfg):
+    rule, doc = ("state-space", "STATE_SPACE") if ssm.is_ssm(cfg) \
+        else ("linear-attention", "LINEAR_ATTENTION")
+    return (f"a per-slot recurrent state (written by its {rule} layers: it "
+            f"summarises the whole history and rides beside the paged "
+            f"pool)", doc)
+
+
+def prefill_reads(cfg, *a) -> int:
+    """The latent layers beside a recurrent state read as latent.py's do,
+    the attention layers beside a state-space state as the GPT blocks
+    (reached when called, as inference/ssm.py reaches them)."""
+    if _latent_rows(cfg):
+        return dialect.occupied_reads(cfg, *a)
+    from deepspeed_tpu.inference.engine import tile_reads
+    return tile_reads(cfg, *a)
+
+
+DIALECT = dialect.Dialect(
+    owns=is_linear, new_state=new_state, pool=lambda k: k.rows,
+    prefill_layers=prefill_layers, decode_layers=decode_layers,
+    prefill_reads=prefill_reads,
+    refusal=_refusal, state=LinearState,
+    bytes_per_token=kv_bytes_per_token, slot_bytes=slot_bytes,
+    flash_steps=lambda cfg, start, bs: latent.flash_steps(cfg, start, bs)
+    if _latent_rows(cfg) else 0,
+    needs_slot=True, gauges=gauges)

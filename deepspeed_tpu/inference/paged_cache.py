@@ -50,19 +50,16 @@ issues is the one COW block copy (a single compiled program, warmed at
 serving startup).
 
 **Five kinds of cache state** share this allocator, the block tables and
-the two serving programs' calls (:func:`refuse` lists them; each raises by
-name for what cannot yet live with it): K and V pools (the GPT blocks); a
-full layers' pool beside a bounded window RING per slot
-(inference/hybrid.py); ONE pool of latent rows and no V pool (latent.py);
-K and V pools beside a per-slot TAIL of the previous token (cca.py); and a
-paged pool for some layers (latent rows, or K and V) beside a per-slot
-RECURRENT STATE for the others, whichever rule writes it (linear.py:
-linear attention, a state-space mixer). The last is the first whose slot
-costs memory before it holds a token (41.9 MB a slot for Kimi-Linear
-against 8,960 bytes a token; 8.5 MB against 1,024 for Jamba2-3B):
-``recurrent_state_bytes`` / ``conv_tail_bytes`` are held whole from
-construction on, and a byte budget buys the slots first and blocks with
-what is left (``slot_state_bytes``).
+the two serving programs' calls (inference/dialect.py holds one record
+each; ``dialect.refuse`` raises by name for what cannot yet live with
+one): K and V pools (the GPT blocks); a full layers' pool beside a window
+RING per slot (hybrid.py); ONE pool of latent rows (latent.py); K and V
+pools beside a per-slot TAIL (cca.py); and a paged pool for some layers
+beside a per-slot RECURRENT STATE for the others (linear.py). The last is
+the first whose slot costs memory before it holds a token (41.9 MB a slot
+for Kimi-Linear against 8,960 bytes a token; 8.5 MB against 1,024 for
+Jamba2-3B): a byte budget buys the slots first and blocks with what is
+left (``slot_state_bytes``).
 
 Block id 0 is RESERVED as the trash block: the slot programs route
 writes for masked-out lanes (chunk padding, inactive slots) there, so
@@ -96,7 +93,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.utils.env import resolve_flag
-from deepspeed_tpu.inference import cca, hybrid, latent, linear
+from deepspeed_tpu.inference import dialect
 from deepspeed_tpu.inference.host_tier import (
     HostBlockPool, HostCorruption, resolve_host_tier)
 from deepspeed_tpu.inference.prefix_index import PrefixIndex, PrefixMatch
@@ -127,26 +124,6 @@ def resolve_prefix_cache(flag: Optional[bool] = None) -> bool:
 # serving engine wires its model engine's own jits of these functions in
 # (``copy_fn`` / ``gather_fn`` / ``scatter_fn``); a standalone cache runs
 # the module-level ones.
-def refuse(cfg, what: str):
-    """Raise by name for a serving feature that a model whose cache state
-    is more than K and V blocks cannot yet live with. The one list of
-    those dialects (the plain K and V pools of the GPT blocks are the
-    first of five): bounded window state beside the pool
-    (inference/hybrid.py), a latent pool (latent.py), per-slot tails of
-    the previous token (cca.py), a per-slot recurrent state beside a
-    paged pool (linear.py, for either rule that writes one; asked first,
-    a model's latent layers would answer with latent.py's line)."""
-    for dialect in (linear, hybrid, latent, cca):
-        dialect.refuse(cfg, what)
-
-
-def paged_pool(k):
-    """The pool behind the block tables in a K-side state: the array
-    itself, a two-kind state's full layers' pool, a latent state's rows, a
-    CCA state's K rows, the paged layers' rows beside a recurrent state."""
-    return getattr(k, "full", getattr(k, "rows", k))
-
-
 def copy_block(pools, src, dst):
     """Copy ONE pool block (every layer) ``src`` -> ``dst``: the device
     half of copy-on-write. Pools are donated, so the copy is in place in
@@ -251,9 +228,8 @@ class PagedKVCache:
         # pools ("off" keeps the fp pools bit-identical to before)
         self.kv_quant = resolve_kv_quant(kv_quant)
         self.quantized = self.kv_quant == "int8"
-        # a model with sliding-window layers pages only its full layers
-        L = getattr(cfg, "n_full_layers", cfg.n_layers)
-        Hkv, Dh = cfg.kv_heads, cfg.head_dim
+        d = self.dialect = dialect.of(cfg)
+        Hkv = cfg.kv_heads
         # what cannot yet live with bounded window state, with a latent
         # pool, with per-slot tails or a recurrent state raises here, by
         # name
@@ -262,37 +238,25 @@ class PagedKVCache:
                          (resolve_host_tier(host_tier) and prefix_cache,
                           "the host tier (host_tier)")):
             if on:
-                refuse(cfg, what)
-        self.ring_blocks = 0
-        if hybrid.is_hybrid(cfg):
-            from deepspeed_tpu.models.exaone_moe import window_blocks
-            self.ring_blocks = window_blocks(cfg, self.block_size)
-        self.latent = latent.is_latent(cfg)
-        # per-slot tails beside the pools (inference/cca.py): held whole
-        # from construction on, like the window rings
-        self.cca_tail_bytes = self.num_slots \
-            * gpt_lib.kv_cca_tail_bytes_per_slot(cfg, self.dtype)
-        # the recurrent layers' state and convolution tails
-        # (inference/linear.py): a slot costs these before it holds a
-        # token, so slots, not blocks, are what this memory buys
-        state, tail = gpt_lib.kv_recurrent_bytes_per_slot(cfg, self.dtype)
-        self.recurrent_state_bytes = self.num_slots * state
-        self.conv_tail_bytes = self.num_slots * tail
+                dialect.refuse(cfg, what)
+        self.ring_blocks = d.ring_blocks(cfg, self.block_size)
         self.pool_dtype = jnp.dtype(jnp.int8) if self.quantized \
             else self.dtype
-        self.bytes_per_token = gpt_lib.kv_bytes_per_token(
-            cfg, self.pool_dtype)
-        # the window layers' rings: held whole from construction on
-        self.window_bytes = self.num_slots * gpt_lib.kv_window_bytes_per_slot(
-            cfg, self.block_size, self.pool_dtype)
-        # what the slots cost whatever they hold, out of the same budget
-        # as the blocks
+        self.bytes_per_token = d.bytes_per_token(cfg, self.pool_dtype)
+        # what the slots hold whatever their length, whole from
+        # construction on: a slot costs these before it holds a token, so
+        # slots, not blocks, are what this memory buys
+        (self.window_bytes, self.cca_tail_bytes, self.recurrent_state_bytes,
+         self.conv_tail_bytes) = (
+            self.num_slots * b
+            for b in d.slot_bytes(cfg, self.block_size, self.pool_dtype))
+        # what the slots cost out of the same budget as the blocks
         self.slot_state_bytes = self.window_bytes \
             + self.recurrent_state_bytes + self.conv_tail_bytes
         # scale overhead: 2 pools (K and V) × L layers × Hkv heads × fp32
         # per block — amortized it is 2*L*Hkv*4/block_size bytes/token
-        self.scale_bytes_per_block = (2 * L * Hkv * 4) if self.quantized \
-            else 0
+        self.scale_bytes_per_block = (2 * cfg.n_layers * Hkv * 4) \
+            if self.quantized else 0
         if num_blocks is None:
             if hbm_budget_bytes:
                 per_block = (self.bytes_per_token * self.block_size
@@ -314,45 +278,10 @@ class PagedKVCache:
             raise ValueError(
                 f"HBM budget covers {self.num_blocks - 1} blocks; the "
                 f"pool needs at least 1 allocatable block")
-        # one row per cached token, its kv heads folded side by side:
-        # the layout in HBM that the entry parameter, the layer loop and
-        # the kernel share (module docstring)
-        if linear.is_linear(cfg):
-            # a fifth: the paged layers' pool or pools (L counts those
-            # layers alone) and every slot's recurrent state and
-            # convolution tail for the others, zero until used
-            self.k = linear.new_state(cfg, self.num_blocks, self.block_size,
-                                      self.num_slots, self.pool_dtype)
-            self.v = linear.paged_v_pool(cfg, self.k)
-        elif self.latent:
-            # a third kind of state (inference/latent.py): one pool of
-            # latent rows, no V pool
-            self.k = latent.LatentState(jnp.zeros(
-                (L, self.num_blocks, self.block_size, cfg.latent_lanes),
-                self.pool_dtype))
-            self.v = None
-        elif cca.is_cca(cfg):
-            # a fourth: K and V pools as below, and every slot's tail of
-            # the previous token, zero until a sequence leaves one
-            self.k, self.v = cca.new_state(
-                cfg, self.num_blocks, self.block_size, self.num_slots,
-                self.pool_dtype)
-        else:
-            self.k = jnp.zeros(
-                (L, self.num_blocks, self.block_size, Hkv * Dh),
-                self.pool_dtype)
-            self.v = jnp.zeros_like(self.k)
-        if self.ring_blocks:
-            # two kinds of state side by side (inference/hybrid.py): the
-            # pool above is the full layers'; each window layer keeps
-            # ring_blocks blocks per slot, block 0 its trash block
-            win = jnp.zeros((cfg.n_window_layers,
-                             1 + self.num_slots * self.ring_blocks,
-                             self.block_size, Hkv * Dh), self.pool_dtype)
-            self.k = hybrid.PagedState(self.k, win)
-            self.v = hybrid.PagedState(self.v, jnp.zeros_like(win))
+        self.k, self.v = d.new_state(cfg, self.num_blocks, self.block_size,
+                                     self.num_slots, self.pool_dtype)
         if self.quantized:
-            self.k_scale = jnp.zeros((L, self.num_blocks, Hkv),
+            self.k_scale = jnp.zeros((cfg.n_layers, self.num_blocks, Hkv),
                                      jnp.float32)
             self.v_scale = jnp.zeros_like(self.k_scale)
         else:
@@ -1154,12 +1083,8 @@ class PagedKVCache:
     def pools(self) -> tuple:
         """The cache's device state as ONE value, what every block copy
         takes and every serving program hands back: ``(k, v)``, with
-        int8 pools ``(k, v, k_scale, v_scale)``; for a model of two
-        attention kinds k and v are hybrid.PagedState, for one with
-        latent attention ``(latent.LatentState, None)``, for one with
-        convolutional attention ``(cca.CCAState, v)``, for one with a
-        per-slot recurrent state ``(linear.LinearState, None)`` beside a
-        latent pool and ``(linear.LinearState, v)`` beside K and V."""
+        int8 pools ``(k, v, k_scale, v_scale)``; k and v as the model's
+        dialect made them (inference/dialect.py ``new_state``)."""
         return (self.k, self.v) + (self.scales or ())
 
     @pools.setter

@@ -144,7 +144,7 @@ import numpy as np
 from deepspeed_tpu.inference import sampling
 from deepspeed_tpu.inference.adapters import (AdapterLoadError, AdapterPool,
                                               resolve_lora_serve)
-from deepspeed_tpu.inference import paged_cache
+from deepspeed_tpu.inference import dialect
 from deepspeed_tpu.inference.host_tier import resolve_host_tier
 from deepspeed_tpu.inference.paged_cache import (CacheExhausted,
                                                  PagedKVCache,
@@ -601,7 +601,7 @@ class ServingEngine:
                  "the fused decode horizon (decode_horizon)"),
                 (self.lora_serve, "LoRA serving (lora_serve)")):
             if on:
-                paged_cache.refuse(engine.cfg, what)
+                dialect.refuse(engine.cfg, what)
         # the engine's own jits of the three block copies (COW, and the
         # host tier's gather and scatter) are wired in when present:
         # each takes the cache's pools whole, scales included
@@ -622,7 +622,7 @@ class ServingEngine:
         # the EFFECTIVE switch: the cache gates the tier on the prefix
         # index existing (only indexed blocks ever spill)
         self.host_tier = self.cache.host_tier
-        if hasattr(self.cache.k, "stats") and self.telemetry.enabled \
+        if self.cache.dialect.state is not None and self.telemetry.enabled \
                 and hasattr(engine.cfg, "moe_k"):
             # expert-layer counters ride with the K state, on the device
             # (read_expert_counters pulls them); a dense model beside a
@@ -888,46 +888,8 @@ class ServingEngine:
                       "device bytes of the sliding-window layers' per-slot "
                       "rings (K+V, all slots; 0 without such layers)").set(
                 self.cache.window_bytes)
-            if self.cache.latent:
-                # a third kind of state (inference/latent.py)
-                reg.gauge("kv_latent_pool_bytes",
-                          "device bytes of the latent (MLA) pool: one row "
-                          "a token a layer as stored (padded to whole "
-                          "lane tiles), trash block included").set(
-                    (self.cache.num_blocks * self.cache.block_size
-                     * self.cache.bytes_per_token))
-                reg.gauge("kv_latent_row_bytes",
-                          "bytes of one token's latent row in one layer "
-                          "as computed: the latent and the shared rotated "
-                          "key, without the padding").set(
-                    engine.cfg.latent_row * self.cache.pool_dtype.itemsize)
-            if self.cache.cca_tail_bytes:
-                # per-slot state that is not blocks (inference/cca.py)
-                reg.gauge("kv_cca_tail_bytes",
-                          "device bytes of the per-slot tails of "
-                          "convolutional (CCA) attention: per layer and "
-                          "slot the previous token's compressed row, its "
-                          "first convolution's output and its half of the "
-                          "next value, whatever the slot's length").set(
-                    self.cache.cca_tail_bytes)
-            if self.cache.recurrent_state_bytes:
-                # state that summarises a whole history (inference/
-                # linear.py): bought per SLOT, before a token is held
-                reg.gauge("kv_recurrent_state_bytes",
-                          "device bytes of the per-slot recurrent state: "
-                          "float32, per recurrent layer and slot one matrix "
-                          "of head_dim x head_dim a head (linear attention) "
-                          "or d_state x d_inner (a state-space mixer), read "
-                          "and rewritten by every token whatever the slot's "
-                          "length").set(
-                    self.cache.recurrent_state_bytes)
-                reg.gauge("kv_conv_tail_bytes",
-                          "device bytes of the recurrent layers' per-slot "
-                          "convolution tails: per layer and slot the "
-                          "un-convolved rows ([q | k | v], or a state-space "
-                          "mixer's x) of the last conv_kernel - 1 "
-                          "tokens").set(
-                    self.cache.conv_tail_bytes)
+            # a latent pool, per-slot tails, a recurrent state
+            self.cache.dialect.gauges(reg, self.cache)
             self._h_kv_err = reg.histogram(
                 "serving_kv_quant_error",
                 "sampled upper bound on the max-abs KV dequantization "

@@ -990,54 +990,11 @@ def kv_bytes_per_token(cfg: GPTConfig, dtype=jnp.bfloat16) -> int:
     """Bytes of K+V cache ONE token occupies across all layers — the
     paged-cache allocator's budget unit (inference/paged_cache.py). The
     static engine pays this for `max_batch x S_max` slots up front; the
-    paged cache pays it per token actually in flight. Counted by layer
-    kind: a sliding-window layer keeps a bounded ring per slot
-    (:func:`kv_window_bytes_per_slot`) and adds nothing per token; a
-    latent (MLA) layer keeps ONE row of ``latent_lanes`` values a token
-    (the latent and the shared key, padded to whole lane tiles as the pool
-    stores it) and no K or V heads; a linear-attention layer keeps a state
-    per slot (:func:`kv_recurrent_bytes_per_slot`) and adds nothing per
-    token (``n_full_layers`` then counts the layers that do)."""
-    layers = getattr(cfg, "n_full_layers", cfg.n_layers)
-    if getattr(cfg, "kv_lora_rank", 0):
-        return int(layers * cfg.latent_lanes * jnp.dtype(dtype).itemsize)
-    return int(2 * layers * cfg.kv_heads * cfg.head_dim
+    paged cache pays it per token actually in flight. The plain K and V
+    pools' count: another cache dialect says its own beside it
+    (inference/dialect.py ``bytes_per_token``, ``slot_bytes``)."""
+    return int(2 * cfg.n_layers * cfg.kv_heads * cfg.head_dim
                * jnp.dtype(dtype).itemsize)
-
-
-def kv_window_bytes_per_slot(cfg: GPTConfig, block_size: int,
-                             dtype=jnp.bfloat16) -> int:
-    """Bytes of K+V one serving slot holds in the sliding-window layers'
-    rings, whatever its length (0 for a model with no such layers)."""
-    layers = getattr(cfg, "n_window_layers", 0)
-    if not layers:
-        return 0
-    from deepspeed_tpu.models.exaone_moe import window_blocks
-    return int(2 * layers * window_blocks(cfg, block_size) * block_size
-               * cfg.kv_heads * cfg.head_dim * jnp.dtype(dtype).itemsize)
-
-
-def kv_cca_tail_bytes_per_slot(cfg: GPTConfig, dtype=jnp.bfloat16) -> int:
-    """Bytes one serving slot holds beside its blocks where keys and
-    values are made by convolutions over time (models/zaya.py,
-    inference/cca.py): per layer the previous token's compressed row, its
-    first convolution's output and its half of the next value, whatever
-    the slot's length (0 for a model with no such attention)."""
-    return int(cfg.n_layers * getattr(cfg, "cca_tail_values", 0)
-               * jnp.dtype(dtype).itemsize)
-
-
-def kv_recurrent_bytes_per_slot(cfg: GPTConfig,
-                                dtype=jnp.bfloat16) -> Tuple[int, int]:
-    """(recurrent state, convolution tails): bytes one serving slot holds
-    beside its blocks, whatever its length, where some layers keep a
-    recurrent state (linear attention, models/kimi_linear.py; a state-space
-    mixer, models/jamba.py; inference/linear.py): per such layer the
-    float32 state, and the last tokens' un-convolved rows in the pools'
-    type ((0, 0) for a model with no such layers)."""
-    return (4 * int(getattr(cfg, "recurrent_state_values", 0)),
-            int(getattr(cfg, "conv_tail_values", 0))
-            * jnp.dtype(dtype).itemsize)
 
 
 def decode_geometry(cfg: GPTConfig, block_size: int,

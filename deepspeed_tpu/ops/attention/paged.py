@@ -104,37 +104,6 @@ def resolve_decode_impl(impl: Optional[str] = None) -> str:
     return impl
 
 
-def paged_hbm_bytes_per_token(cfg, num_slots: int, mean_len: float,
-                              max_len: int, dtype=jnp.bfloat16,
-                              impl: str = "pallas",
-                              block_size: Optional[int] = None,
-                              scale_bytes_per_block: int = 0) -> int:
-    """Analytic HBM bytes the attention cache path moves per decoded
-    token (all layers, K+V) — the PERF.md comparison unit.
-
-    gather: reads the whole ``[B, NB*block, ...]`` virtual cache out of
-    the pool AND writes the transient gathered copy, then the einsums
-    read the copy again — 3 passes over ``num_slots * max_len`` tokens.
-    pallas: reads only the occupied blocks of each live slot, once.
-
-    ``dtype`` must be the ACTUAL pool dtype (int8 under DS_KV_QUANT,
-    bf16/f32 otherwise — the bench passes ``cache.pool_dtype``);
-    ``scale_bytes_per_block`` + ``block_size`` fold the quantized pools'
-    per-block fp32 scale overhead into the per-token cost."""
-    per_tok = 2.0 * cfg.n_layers * cfg.kv_heads * cfg.head_dim \
-        * jnp.dtype(dtype).itemsize
-    if getattr(cfg, "kv_lora_rank", 0):
-        # latent attention: one row a token a layer, no K or V heads
-        from deepspeed_tpu.models.gpt import kv_bytes_per_token
-        per_tok = float(kv_bytes_per_token(cfg, dtype))
-    if scale_bytes_per_block and block_size:
-        # the scale pools are read alongside every block DMA
-        per_tok += scale_bytes_per_block / float(block_size)
-    if impl == "gather":
-        return int(3 * num_slots * int(max_len) * per_tok)
-    return int(int(num_slots * mean_len) * per_tok)
-
-
 def blocks_per_step(nb: int, bs: int) -> int:
     """Table entries one grid step attends (``P``), from the static
     shapes alone: the fewest blocks that make ``TILE_TOKENS`` tokens, or

@@ -12,8 +12,8 @@ VMEM tiles on the way into the MXU, fp32 accumulation over K tiles,
 per-output-channel scale applied once at the end.
 
 Enable in serving with DS_INT8_FUSED=1 (inference/engine.py wires it
-through gpt._dense); ``tools/infer_bench.py`` measures fused vs
-XLA-dequant so the flag only ships where it wins.
+through gpt._dense); no cell of the benchmark measures fused against
+XLA-dequant yet (ROADMAP.md Queue 2), so the flag stays off.
 """
 
 import functools

@@ -18,7 +18,7 @@ Design constraints (docs/OBSERVABILITY.md):
   allocation, no unbounded reservoir), and percentiles are estimated by
   linear interpolation inside the owning bucket — the classic
   Prometheus ``histogram_quantile`` math, reproduced host-side so
-  ``infer_bench`` rows do not need a scrape cycle;
+  a benchmark row does not need a scrape cycle;
 - **unit-agnostic** — serving clocks are caller-supplied (step index in
   tests, ``perf_counter`` seconds in the bench), so the default bucket
   ladder spans both regimes log-spaced.

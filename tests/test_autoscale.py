@@ -30,6 +30,7 @@ from deepspeed_tpu.models import gpt
 from deepspeed_tpu.telemetry import Telemetry
 from deepspeed_tpu.utils import faults as faults_lib
 from deepspeed_tpu.utils.faults import Fault
+from tools.load_gen import drive, make_requests
 from tools.trace_analyze import analyze_fleet_trace
 
 pytestmark = pytest.mark.usefixtures("devices")
@@ -211,6 +212,47 @@ def test_controller_scales_up_under_burst(eng):
     assert snap["gauges"]["autoscale_target_replicas"] == 3
     # cooldown held: fleet-shape changes are >= cooldown apart
     assert ups[1]["at"] - ups[0]["at"] >= 2.0
+
+
+def test_policy_fleet_holds_the_ttft_slo_a_fixed_fleet_violates():
+    """The closed-loop SLO contrast (docs/OBSERVABILITY.md): ONE seeded
+    load_gen population with a rate spike in the middle, on the
+    scheduler's step clock, through a FIXED 1-replica fleet and through
+    a fleet that starts at 1 replica with the controller active. The
+    fixed fleet queues through the spike and violates the stated
+    p99-TTFT budget; the controller sees the windowed p99 cross it,
+    scales up through the factory and holds it."""
+    ttft_slo = 12.0
+    cfg = gpt.GPTConfig(vocab_size=512, n_layers=2, n_heads=4, d_model=32,
+                        max_seq_len=40 + 24 + 8, use_flash_attention=False,
+                        remat=False, dtype=jnp.float32)
+    eng = InferenceEngine(config=cfg, dtype=jnp.float32,
+                          params=gpt.init_params(jax.random.PRNGKey(0), cfg))
+    entries = make_requests(seed=0, mix="chat", vocab_size=cfg.vocab_size,
+                            phases=[(6, 0.2), (60, 0.5), (30, 0.05)],
+                            max_prompt_len=40)
+
+    def srv(tel):
+        return mk_srv(eng, telemetry=tel, block_size=8, num_blocks=None,
+                      prefill_chunk=16)
+    tel_f = Telemetry()
+    fixed = ReplicaRouter([srv(tel_f)], telemetry=tel_f)
+    res_f = drive(fixed, entries, mode="open", slo_ttft=ttft_slo)
+    tel_p = Telemetry()
+    ctrl = SLOController(ttft_slo=ttft_slo, window=16.0, eval_every=2,
+                         max_replicas=3, cooldown=4.0, idle_to_retire=1e9,
+                         min_samples=3, queue_high=2.0)
+    policy = ReplicaRouter([srv(tel_p)],
+                           replica_factory=lambda i, tag: srv(tel_p),
+                           telemetry=tel_p, autoscale=ctrl)
+    res_p = drive(policy, entries, mode="open", slo_ttft=ttft_slo)
+    assert res_f["ttft_p99"] > ttft_slo >= res_p["ttft_p99"], (
+        res_f["ttft_p99"], res_p["ttft_p99"])
+    assert res_p["slo_attainment"] > res_f["slo_attainment"]
+    counters = policy.fleet_snapshot()["counters"]
+    assert counters["router_scale_ups"] >= 1
+    assert counters["autoscale_scale_ups"] == counters["router_scale_ups"]
+    assert counters["autoscale_decisions"] == len(ctrl.decisions)
 
 
 def test_controller_retires_on_sustained_idle(eng):

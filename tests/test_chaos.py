@@ -238,12 +238,14 @@ def test_watchdog_degraded_error_keeps_everything(eng):
     ref1 = _solo_refs(eng, [p1], 12)[0]
     ref2 = _solo_refs(eng, [p2], 3)[0]
     with faults_lib.injected(
-            Fault("serving.decode", "slow", step=4, count=2, param=0.05)):
-        # 10ms budget: well above a normal decode dispatch (which now
-        # includes the fused in-program sampler), well below the 50ms
-        # injected slow fault — same calibration as the drain tests
+            Fault("serving.decode", "slow", step=4, count=2, param=0.6)):
+        # the budget is wall time on a CPU that the other workers of a
+        # whole run share: 0.25 s is out of an ordinary decode step's
+        # reach however loaded the host (57 ms was seen), and the 0.6 s
+        # injected slow fault still clears it — same calibration as the
+        # drain tests
         srv = ServingEngine(eng, num_slots=2, block_size=4, num_blocks=24,
-                            step_time_budget_s=0.01, watchdog_grace=2,
+                            step_time_budget_s=0.25, watchdog_grace=2,
                             spec_decode=False, decode_horizon=1)
         with pytest.raises(DegradedError, match="over budget") as ei:
             srv.run([ServeRequest(rid="a", prompt=p1, max_new_tokens=12),
@@ -438,9 +440,9 @@ def test_pending_snapshot_cold_resumes_into_fresh_engine(eng):
     prompts = prompts_of((6, 9, 12), seed=43)
     refs = _solo_refs(eng, prompts, 8)
     with faults_lib.injected(
-            Fault("serving.decode", "slow", step=3, param=0.05), seed=0):
+            Fault("serving.decode", "slow", step=3, param=0.6), seed=0):
         srv = ServingEngine(eng, num_slots=2, block_size=4, num_blocks=24,
-                            prefill_chunk=8, step_time_budget_s=0.01,
+                            prefill_chunk=8, step_time_budget_s=0.25,
                             watchdog_grace=1, spec_decode=False,
                             decode_horizon=1)
         with pytest.raises(DegradedError) as ei:

@@ -56,7 +56,7 @@ def test_chip_smoke_has_no_cpu_mode():
     assert '"ok"' not in r.stdout
 
 
-@pytest.mark.parametrize("script", ["bench.py", "tools/infer_bench.py"])
+@pytest.mark.parametrize("script", ["bench.py"])
 def test_bench_scripts_fail_without_a_tpu(script):
     r = subprocess.run([sys.executable, script], cwd=REPO,
                        env=dict(os.environ, JAX_PLATFORMS="cpu"),

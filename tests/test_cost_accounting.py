@@ -344,10 +344,12 @@ def test_degraded_error_writes_postmortem_roundtrip(eng, tmp_path):
     outdir = str(tmp_path / "flight")
     p1, p2 = prompts_of((6, 9), seed=12)
     with faults_lib.injected(
-            Fault("serving.decode", "slow", step=4, count=2, param=0.05),
+            Fault("serving.decode", "slow", step=4, count=2, param=0.6),
             seed=0) as inj:
+        # a budget no ordinary step of a loaded CPU reaches, under a
+        # fault that still clears it (tests/test_chaos.py)
         srv = ServingEngine(eng, num_slots=2, block_size=4, num_blocks=24,
-                            step_time_budget_s=0.01, watchdog_grace=2,
+                            step_time_budget_s=0.25, watchdog_grace=2,
                             spec_decode=False, decode_horizon=1,
                             telemetry=Telemetry(),
                             flight_recorder=True, flight_dir=outdir)

@@ -268,6 +268,56 @@ def test_parked_jump_under_bursty_open_load(eng):
 
 
 # ---------------------------------------------------------------------------
+# the per-kind SLO contrast
+# ---------------------------------------------------------------------------
+
+def test_split_holds_every_slo_the_monolithic_fleet_violates():
+    """Disaggregated against monolithic at the SAME replica count
+    (docs/ROBUSTNESS.md): ONE seeded mixed rag+chat load_gen trace, on
+    the scheduler's step clock. Two mixed-role replicas interleave long
+    rag prefills with chat decodes and break at least one per-kind p99
+    budget of load_gen.SLO_TARGETS; 1 prefill + 1 decode hold ALL of
+    them, with the same tokens a request (a migration resumes exactly,
+    a fallback re-prefills cold) and nothing compiled in the drive."""
+    lg = pytest.importorskip("tools.load_gen")
+    cfg = gpt.GPTConfig(vocab_size=512, n_layers=2, n_heads=4, d_model=32,
+                        max_seq_len=64 + 16 + 8, use_flash_attention=False,
+                        remat=False, dtype=jnp.float32)
+    eng = InferenceEngine(config=cfg, dtype=jnp.float32,
+                          params=gpt.init_params(jax.random.PRNGKey(0), cfg))
+    entries = lg.make_requests(seed=3, mix="mixed", phases=[(110, 0.27)],
+                               vocab_size=cfg.vocab_size, max_prompt_len=64)
+
+    def fleet():
+        return mk_fleet(eng, block_size=8, num_blocks=24)
+
+    def p99(res, key, kind):
+        return float(np.percentile(
+            [r[key] for r in res["per_request"]
+             if r["kind"] == kind and r[key] is not None], 99))
+
+    def over_budget(res):
+        return [(kind, key, p99(res, key, kind))
+                for kind in ("chat", "rag") for key in ("ttft", "tpot")
+                if p99(res, key, kind) > lg.SLO_TARGETS[kind][key]]
+
+    mono = ReplicaRouter(fleet(), telemetry=True)
+    res_m = lg.drive(mono, entries, mode="open", include_tokens=True)
+    split = ReplicaRouter(fleet(), roles=["prefill", "decode"],
+                          telemetry=True)
+    watch = CompileWatch(max_compiles=0, label="disagg steady state")
+    with watch:
+        res_d = lg.drive(split, entries, mode="open", include_tokens=True)
+    assert over_budget(res_m), "the monolithic fleet broke no budget"
+    assert not over_budget(res_d), over_budget(res_d)
+    assert {r["rid"]: r["tokens"] for r in res_m["per_request"]} \
+        == {r["rid"]: r["tokens"] for r in res_d["per_request"]}
+    assert watch.compiles == 0
+    assert split.stats["migrations"] >= 1
+    assert_pools_clean(split)
+
+
+# ---------------------------------------------------------------------------
 # compile contract
 # ---------------------------------------------------------------------------
 

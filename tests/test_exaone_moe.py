@@ -231,15 +231,17 @@ def test_no_recompile_in_steady_state():
 
 
 def test_head_size_and_kv_accounting_by_layer_kind():
+    from deepspeed_tpu.inference import dialect
     from deepspeed_tpu.models import gpt
     cfg = U.tiny_config()
     assert cfg.head_dim == 16 and cfg.d_model // cfg.n_heads == 8
     assert cfg.qkv_dim == (4 + 2 * 2) * 16
-    assert gpt.kv_bytes_per_token(cfg, jnp.bfloat16) == 2 * 2 * 2 * 16 * 2
-    assert gpt.kv_window_bytes_per_slot(cfg, 4, jnp.bfloat16) \
+    d = dialect.of(cfg)
+    assert d.bytes_per_token(cfg, jnp.bfloat16) == 2 * 2 * 2 * 16 * 2
+    assert d.slot_bytes(cfg, 4, jnp.bfloat16).window \
         == 2 * 6 * 3 * 4 * 2 * 16 * 2
     dense = gpt.GPTConfig(n_layers=3, n_heads=4, d_model=32)
-    assert gpt.kv_window_bytes_per_slot(dense, 4) == 0
+    assert dialect.of(dense).slot_bytes(dense, 4, jnp.bfloat16).window == 0
     assert gpt.kv_bytes_per_token(dense) == 2 * 3 * 4 * 8 * 2
     odd = gpt.GPTConfig(n_layers=1, n_heads=3, d_model=32, head_size=16)
     assert odd.head_dim == 16

@@ -173,7 +173,7 @@ def test_moe_num_params_matches_init():
 
 
 def test_infer_estimator_calibration():
-    """The infer_bench grid (gpt2-medium/large, b8-32, 584-token cache)
+    """A static-cache grid (gpt2-medium/large, b8-32, 584-token cache)
     is SAFE; a 32k-cache x 256-batch config is REFUSED (KV cache alone
     exceeds HBM)."""
     cfg = gpt.preset("gpt2-large", max_seq_len=584, dtype=jnp.bfloat16)

@@ -17,7 +17,7 @@ import pytest
 import jamba_util as U
 # the drive that records logits across an eviction and its replay
 from test_kimi_linear import _serve_recording
-from deepspeed_tpu.inference import linear, ssm as ssm_blocks
+from deepspeed_tpu.inference import dialect, linear, ssm as ssm_blocks
 from deepspeed_tpu.models import gpt, jamba, recurrent
 from deepspeed_tpu.ops.attention import ssm
 
@@ -247,7 +247,7 @@ def test_decode_leaves_idle_and_prefilling_slots_state_bit_unchanged(model):
     cfg, params = model
     p = _layer(params["ssm"], 1)
     slots, Di = 3, cfg.d_inner
-    st = linear.new_state(cfg, 9, 4, slots, jnp.float32)
+    st, _ = linear.new_state(cfg, 9, 4, slots, jnp.float32)
     state = jax.random.normal(jax.random.key(1), st.state.shape) \
         .reshape((-1,) + st.state.shape[2:])
     tails = jax.random.normal(jax.random.key(2), st.tail.shape) \
@@ -385,14 +385,15 @@ def test_no_recompile_in_steady_state(served):
 
 def test_cache_accounting_and_the_published_sizes():
     cfg = U.tiny_config()
-    assert linear.is_linear(cfg) and ssm_blocks.is_ssm(cfg)
-    assert gpt.kv_bytes_per_token(cfg, jnp.bfloat16) == 2 * 2 * 8 * 2
-    assert gpt.kv_recurrent_bytes_per_slot(cfg, jnp.bfloat16) \
+    d = dialect.of(cfg)
+    assert d is linear.DIALECT and ssm_blocks.is_ssm(cfg)
+    assert d.bytes_per_token(cfg, jnp.bfloat16) == 2 * 2 * 8 * 2
+    assert d.slot_bytes(cfg, 4, jnp.bfloat16)[2:] \
         == (6 * 64 * 8 * 4, 6 * 3 * 64 * 2)
     # slots, not blocks, are what a budget buys first
     from deepspeed_tpu.inference.paged_cache import PagedKVCache
-    per_slot = sum(gpt.kv_recurrent_bytes_per_slot(cfg, jnp.float32))
-    block = 4 * gpt.kv_bytes_per_token(cfg, jnp.float32)
+    per_slot = sum(d.slot_bytes(cfg, 4, jnp.float32))
+    block = 4 * d.bytes_per_token(cfg, jnp.float32)
     cache = PagedKVCache(cfg, num_slots=3, block_size=4, dtype=jnp.float32,
                          hbm_budget_bytes=3 * per_slot + 10 * block)
     assert cache.num_blocks == 11
@@ -406,8 +407,8 @@ def test_cache_accounting_and_the_published_sizes():
     assert [int(i) for i in np.flatnonzero(real.attn_kinds)] == [7, 21]
     assert real.d_inner == 5120 and real.head_dim == 128
     assert real.recurrent_state_shape == (16, 5120)
-    assert gpt.kv_bytes_per_token(real, jnp.bfloat16) == 1024
-    assert gpt.kv_recurrent_bytes_per_slot(real) == (8_519_680, 798_720)
+    assert d.bytes_per_token(real, jnp.bfloat16) == 1024
+    assert d.slot_bytes(real, 16, jnp.bfloat16)[2:] == (8_519_680, 798_720)
     starts, counts, behind = recurrent.layer_runs(real)
     assert list(starts) == [0, 8] and list(counts) == [7, 13]
     assert behind == (22, 6)

@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 import kimi_linear_util as U
-from deepspeed_tpu.inference import latent, linear
+from deepspeed_tpu.inference import dialect, engine, latent, linear
 from deepspeed_tpu.models import gpt, kimi_linear
 from deepspeed_tpu.moe import expert_share
 from deepspeed_tpu.ops.attention import kda
@@ -275,7 +275,7 @@ def test_decode_leaves_idle_and_prefilling_slots_state_bit_unchanged(model):
     cfg, params = model
     p = _kda_layer(params)
     slots, C = 3, 96
-    st = linear.new_state(cfg, 9, 4, slots, jnp.float32)
+    st, _ = linear.new_state(cfg, 9, 4, slots, jnp.float32)
     state = jax.random.normal(jax.random.key(1), st.state.shape) \
         .reshape((-1,) + st.state.shape[2:])
     tails = jax.random.normal(jax.random.key(2), st.tail.shape) \
@@ -481,15 +481,17 @@ def test_no_recompile_in_steady_state(served):
 
 def test_cache_accounting_and_the_published_sizes():
     cfg = U.tiny_config()
-    assert gpt.kv_bytes_per_token(cfg, jnp.bfloat16) == 2 * 128 * 2
-    assert gpt.kv_recurrent_bytes_per_slot(cfg, jnp.bfloat16) \
+    d = dialect.of(cfg)
+    assert d.bytes_per_token(cfg, jnp.bfloat16) == 2 * 128 * 2
+    assert d.slot_bytes(cfg, 4, jnp.bfloat16)[2:] \
         == (5 * 4 * 8 * 8 * 4, 5 * 3 * 96 * 2)
-    assert gpt.kv_recurrent_bytes_per_slot(gpt.GPTConfig()) == (0, 0)
-    assert not linear.is_linear(gpt.GPTConfig())
+    plain = dialect.of(gpt.GPTConfig())
+    assert plain.slot_bytes(gpt.GPTConfig(), 4, jnp.bfloat16)[2:] == (0, 0)
+    assert d is linear.DIALECT and plain is engine.DIALECT
     # slots, not blocks, are what a budget buys first
     from deepspeed_tpu.inference.paged_cache import PagedKVCache
-    per_slot = sum(gpt.kv_recurrent_bytes_per_slot(cfg, jnp.float32))
-    block = 4 * gpt.kv_bytes_per_token(cfg, jnp.float32)
+    per_slot = sum(d.slot_bytes(cfg, 4, jnp.float32))
+    block = 4 * d.bytes_per_token(cfg, jnp.float32)
     cache = PagedKVCache(cfg, num_slots=3, block_size=4, dtype=jnp.float32,
                          hbm_budget_bytes=3 * per_slot + 10 * block)
     assert cache.num_blocks == 11
@@ -504,8 +506,9 @@ def test_cache_accounting_and_the_published_sizes():
                     22, 23, 25, 26),
         full_attn_layers=(4, 8, 12, 16, 20, 24, 27))
     assert real.latent_row == 576 and real.latent_lanes == 640
-    assert gpt.kv_bytes_per_token(real, jnp.bfloat16) == 8960
-    assert gpt.kv_recurrent_bytes_per_slot(real) == (41_943_040, 1_474_560)
+    assert d.bytes_per_token(real, jnp.bfloat16) == 8960
+    assert d.slot_bytes(real, 16, jnp.bfloat16)[2:] \
+        == (41_943_040, 1_474_560)
     starts, counts, behind = kimi_linear.layer_runs(real)
     assert list(counts) == [2, 3, 3, 3, 3, 3, 2] and behind == (27, 0)
     assert list(starts) == [1, 4, 8, 12, 16, 20, 24]

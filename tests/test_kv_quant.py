@@ -20,7 +20,6 @@ from deepspeed_tpu.inference.paged_cache import PagedKVCache
 from deepspeed_tpu.ops import quantizer
 from deepspeed_tpu.ops.attention.paged import (paged_decode_attention,
                                                paged_decode_reference,
-                                               paged_hbm_bytes_per_token,
                                                paged_verify_attention,
                                                paged_verify_reference)
 from deepspeed_tpu.ops.quantizer import (kv_block_scales,
@@ -176,18 +175,17 @@ def test_paged_cache_int8_budget_doubles_blocks(devices):
     assert q.used_block_bytes() == 2 * per_block_q
 
 
-def test_paged_hbm_bytes_per_token_dtype_aware():
+def test_cache_bytes_per_token_dtype_aware(devices):
     cfg = tiny()
-    fp = paged_hbm_bytes_per_token(cfg, 4, 32.0, 64, dtype=jnp.float32,
-                                   impl="pallas")
-    i8 = paged_hbm_bytes_per_token(cfg, 4, 32.0, 64, dtype=jnp.int8,
-                                   impl="pallas")
-    assert fp == 4 * i8                   # pure dtype ratio, no scales
-    scale_b = 2 * cfg.n_layers * cfg.kv_heads * 4
-    i8s = paged_hbm_bytes_per_token(cfg, 4, 32.0, 64, dtype=jnp.int8,
-                                    impl="pallas", block_size=8,
-                                    scale_bytes_per_block=scale_b)
-    assert i8 < i8s < fp                  # scale sidecar amortized per token
+    fp, i8 = (PagedKVCache(cfg, num_slots=2, block_size=8, num_blocks=6,
+                           dtype=jnp.float32, kv_quant=mode)
+              for mode in ("off", "int8"))
+    assert fp.bytes_per_token == 4 * i8.bytes_per_token   # no scales
+    fp.allocate(0, 8)
+    i8.allocate(0, 8)
+    # the scale sidecar amortised over a block's tokens
+    assert i8.bytes_per_token < i8.used_block_bytes() / 8 \
+        < fp.used_block_bytes() / 8 == fp.bytes_per_token
 
 
 # ---------------------------------------------------------------------------

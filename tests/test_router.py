@@ -306,13 +306,17 @@ def test_router_drain_parity_watchdog_degraded(eng):
     # grace=1: serving.decode visits are fleet-global (shared injector),
     # so consecutive slow visits can straddle two replicas and a grace
     # of 2 would never accumulate on either. The budget is wall time: a
-    # 10 ms one was crossed by an undisturbed decode step when the other
-    # workers of a whole run loaded the host (a second replica broke);
-    # 50 ms under a 250 ms fault keeps the same five-fold margin
+    # 10 ms one, and then a 50 ms one, was crossed by an undisturbed
+    # decode step when the other workers of a whole run loaded the host
+    # (a second replica broke); 0.25 s is out of such a step's reach, and
+    # the 0.6 s fault still clears it. A process's first decode COMPILES
+    # for seconds (this test alone, or first on its worker, broke a replica
+    # on that): the fleet's two programs are run once before any watchdog
+    mk_fleet(eng, n=1)[0].run(mk_reqs(prompts_of((5,), seed=29), n=2))
     router = _parity_run(
         eng,
-        [Fault("serving.decode", "slow", step=5, param=0.25)],
-        step_time_budget_s=0.05, watchdog_grace=1)
+        [Fault("serving.decode", "slow", step=5, param=0.6)],
+        step_time_budget_s=0.25, watchdog_grace=1)
     assert router.health().count(BROKEN) == 1
     assert router.stats["drained_requests"] >= 1
 

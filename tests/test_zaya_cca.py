@@ -322,7 +322,8 @@ def test_linear_router_programs_lower_to_the_parents_text(family):
 
     assert text(expert_share.sparse_ffn) == text(_parents_sparse_ffn)
     assert expert_share.stat_fields(cfg) == expert_share.STAT_FIELDS
-    assert not expert_share.has_router_state(cfg) and not cca.is_cca(cfg)
+    assert not expert_share.has_router_state(cfg) \
+        and not cca.DIALECT.owns(cfg)
 
 
 def test_counters_gauges_and_spans_with_telemetry(model):
@@ -408,21 +409,24 @@ def test_no_recompile_in_steady_state(model):
 
 
 def test_kv_accounting_and_the_published_sizes():
+    from deepspeed_tpu.inference import dialect, engine
     from deepspeed_tpu.models import gpt
     cfg = U.tiny_config()
-    assert gpt.kv_bytes_per_token(cfg, jnp.bfloat16) == 2 * 4 * 2 * 8 * 2
-    assert gpt.kv_cca_tail_bytes_per_slot(cfg, jnp.bfloat16) == 4 * 104 * 2
-    assert gpt.kv_cca_tail_bytes_per_slot(gpt.GPTConfig()) == 0
-    assert gpt.kv_window_bytes_per_slot(cfg, 4, jnp.bfloat16) == 0
-    assert not cca.is_cca(gpt.GPTConfig())
+    d, bf16 = dialect.of(cfg), jnp.bfloat16
+    assert d is cca.DIALECT
+    assert d.bytes_per_token(cfg, bf16) == 2 * 4 * 2 * 8 * 2
+    assert d.slot_bytes(cfg, 4, bf16).cca_tail == 4 * 104 * 2
+    assert engine.DIALECT.slot_bytes(gpt.GPTConfig(), 4, bf16).cca_tail == 0
+    assert d.slot_bytes(cfg, 4, bf16).window == 0
+    assert dialect.of(gpt.GPTConfig()) is engine.DIALECT
     # stage 0 of ZAYA1-8B as the benchmark runs it
     real = zaya.ZayaConfig(n_layers=20, n_heads=8, d_model=2048,
                            vocab_size=262272, max_seq_len=6144)
     assert real.head_dim == 128 and real.kv_heads == 2
     assert real.cca_channels == 1280 and real.rotary_channels == 64
-    assert gpt.kv_bytes_per_token(real, jnp.bfloat16) == 20480
+    assert d.bytes_per_token(real, bf16) == 20480
     assert real.cca_tail_values * 2 == 5376            # 5.4 KB a layer a slot
-    assert gpt.kv_cca_tail_bytes_per_slot(real) * 40 == 4300800
+    assert d.slot_bytes(real, 16, bf16).cca_tail * 40 == 4300800
     shapes = jax.eval_shape(
         lambda: zaya.init_params(jax.random.PRNGKey(0), real))
     n = sum(int(np.prod(a.shape)) for a in jax.tree_util.tree_leaves(shapes))

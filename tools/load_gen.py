@@ -98,9 +98,9 @@ MIXES: Dict[str, Dict[str, Any]] = {
 # per-kind SLO budgets in scheduler token-time units (one unit ≈ one
 # decode iteration): ``ttft`` bounds submit -> first token, ``tpot``
 # bounds the mean inter-token gap of the decode stream. These are the
-# targets the disagg compare row must hold for BOTH kinds at once
-# (tools/infer_bench.py bench_serving_disagg_compare); drive() records
-# the raw per-request numbers so attainment is offline-recomputable.
+# targets a prefill/decode split must hold for BOTH kinds at once;
+# drive() records the raw per-request numbers so attainment is
+# offline-recomputable.
 SLO_TARGETS: Dict[str, Dict[str, float]] = {
     "chat": {"ttft": 12.0, "tpot": 2.5},
     "rag": {"ttft": 14.0, "tpot": 8.0},

@@ -56,20 +56,60 @@ def build(n_leaves: int, elems: int):
     return opt, grads
 
 
-def timed_step(opt, grads, read_seam=None):
+class WallClock:
+    """The host's own clock: the CLI's."""
+    now = staticmethod(time.perf_counter)
+
+    def wait(self, until: float):
+        time.sleep(max(0.0, until - self.now()))
+
+    def adam_done(self):
+        pass
+
+
+class ModelledClock:
+    """A clock that is MODELLED, not read: it advances by the link's waits
+    and by ``adam_s`` at every ``adam_done``, so what :func:`run` returns
+    follows from the ORDER in which the real step enqueues, waits and
+    updates, whatever the host's load (tests/test_offload.py)."""
+
+    def __init__(self, adam_s: float):
+        self.t, self.adam_s = 0.0, adam_s
+
+    def now(self) -> float:
+        return self.t
+
+    def wait(self, until: float):
+        self.t = max(self.t, until)
+
+    def adam_done(self):
+        self.t += self.adam_s
+
+
+def timed_step(opt, grads, clock, read_seam=None, on_event=None):
+    """One step with every pipeline event of the main thread stamped on
+    ``clock``; returns (wall, events)."""
     import threading
     main = threading.main_thread()
     events = []
-    # main-thread filter: when run inside the test suite, a prior
-    # engine's DPU background thread may still fire the global probe
-    off._pipeline_probe = (
-        lambda ev, i, k: events.append((ev, i, k, time.perf_counter()))
-        if threading.current_thread() is main else None)
+
+    def probe(ev, i, k):
+        # main-thread filter: when run inside the test suite, a prior
+        # engine's DPU background thread may still fire the global probe
+        if threading.current_thread() is not main:
+            return
+        if ev == "adam_done":
+            clock.adam_done()
+        events.append((ev, i, k, clock.now()))
+        if on_event is not None:
+            on_event(ev, i, k)
+
+    off._pipeline_probe = probe
     off._read_shard = read_seam
     try:
-        t0 = time.perf_counter()
+        t0 = clock.now()
         opt.step(grads)
-        wall = time.perf_counter() - t0
+        wall = clock.now() - t0
     finally:
         off._pipeline_probe = None
         off._read_shard = None
@@ -96,10 +136,14 @@ def ideal_pipeline(t_x: float, adam: list) -> float:
     return end + t_x
 
 
-def run(bw_gbps: float, n_leaves: int = 10, elems: int = 8_000_000):
+def run(bw_gbps: float, n_leaves: int = 10, elems: int = 8_000_000,
+        clock=None):
+    """One step behind an emulated link of ``bw_gbps`` on ``clock`` (the
+    host's by default); returns (efficiency, vs_serial)."""
+    clock = clock or WallClock()
     opt, grads = build(n_leaves, elems)
     opt.step(grads)                      # warmup: optimizer state init
-    bare_wall, bare_ev = timed_step(opt, grads)
+    bare_wall, bare_ev = timed_step(opt, grads, clock)
     adam = adam_durations(bare_ev)
 
     bytes_per = elems * 4
@@ -111,38 +155,19 @@ def run(bw_gbps: float, n_leaves: int = 10, elems: int = 8_000_000):
         # FIFO-serialized DMA completion: ordinal assigned at enqueue.
         # Unknown keys (a foreign engine's background step) pass through.
         tgt = enq.get((i, k))
-        if tgt is None:
-            return raw
-        now = time.perf_counter()
-        if tgt > now:
-            time.sleep(tgt - now)
+        if tgt is not None:
+            clock.wait(tgt)
         return raw
 
     t0_holder = {}
-    # re-timestamp enqueues with FIFO ordinals inside the probe
-    events = []
 
-    import threading
-    main = threading.main_thread()
-
-    def probe_full(ev, i, k):
-        if threading.current_thread() is not main:
-            return
-        now = time.perf_counter()
-        events.append((ev, i, k, now))
+    def on_enqueue(ev, i, k):
+        # re-timestamp enqueues with FIFO ordinals inside the probe
         if ev == "d2h_enqueue":
-            t0 = t0_holder.setdefault("t0", now)
+            t0 = t0_holder.setdefault("t0", clock.now())
             enq[(i, k)] = t0 + (len(enq) + 1) * t_x
 
-    off._pipeline_probe = probe_full
-    off._read_shard = read_seam
-    try:
-        t_start = time.perf_counter()
-        opt.step(grads)
-        wall = time.perf_counter() - t_start
-    finally:
-        off._pipeline_probe = None
-        off._read_shard = None
+    wall, _ = timed_step(opt, grads, clock, read_seam, on_enqueue)
 
     ideal = ideal_pipeline(t_x, adam)
     serial = n_leaves * t_x + sum(adam) + t_x    # no-overlap model
