@@ -1155,7 +1155,8 @@ class InferenceEngine:
                  f"dtype={jnp.dtype(dtype).name} "
                  f"{'encoder' if self.is_encoder else 'decoder'}, "
                  f"platform={dev0.platform}, devices={mesh.devices.size}, "
-                 f"decode_impl={self.decode_impl}{tables}", ranks=[0])
+                 f"decode_impl={self.decode_impl}{tables}"
+                 f"{self.dialect.ready_note(config)}", ranks=[0])
 
     # ------------------------------------------------------------------
     # params are threaded explicitly (never via self) so jit treats the
@@ -1364,8 +1365,12 @@ class InferenceEngine:
         layers' counters, added to row ``phase`` of it; where the router
         has a state (moe/expert_share.py ``route_mlp``) that rides there
         too, ``r`` ``[T, R]`` float32, zeros under the first layer. A
-        model with no leading dense layer runs the one scan. Returns (x,
-        flat, stats, route)."""
+        model with no leading dense layer runs the one scan. Data of the
+        config, not of this loop: how many layers lead (``n_dense_layers``,
+        0 where every layer holds both kinds of FFN), and which kind a
+        layer of either stack holds, which its block reads off the layer's
+        parameters (inference/hybrid.py ``ffn_kind``: "dense", "sparse" or
+        "both"). Returns (x, flat, stats, route)."""
         cfg = self.cfg
         params, experts = split_experts(params)
         block = functools.partial(block, experts=experts)

@@ -96,8 +96,34 @@ def _swiglu(h, p):
                   * _dense(h, p["mlp_in"]), p["mlp_out"])
 
 
+def ffn_kind(p) -> str:
+    """Which FFN a layer's parameters hold: the ONE statement of the three
+    cases, for every dialect's blocks and for the layer loop
+    (engine._dense_then_sparse).
+
+    - "dense": ``mlp_gate`` / ``mlp_in`` / ``mlp_out`` beside ``ln2``, no
+      ``moe``: ``x + F(ln2(x))``. The ``n_dense_layers`` leading layers of
+      a config (stack ``dense_block``), and the two sublayers of a "both"
+      layer.
+    - "sparse": ``moe`` in the dense FFN's place (stack ``block``):
+      ``x + M(ln2(x))``, the expert share of moe/expert_share.py.
+    - "both": a shortcut-connected layer (stack ``block``): two sublayers
+      ``a`` and ``b``, each an attention and a dense FFN with their own
+      norms, and ONE ``moe`` that reads ``a``'s ``ln2`` of the stream after
+      the first attention and whose result joins the stream at the END of
+      the layer (inference/latent.py ``_shortcut_layer``). A config of such
+      layers has ``n_dense_layers`` 0: every layer is of this kind.
+
+    :func:`split_experts` takes the expert kernels out of ``block`` for
+    "sparse" and "both" alike; a config without ``moe`` anywhere never
+    calls it."""
+    if "moe" not in p:
+        return "dense"
+    return "both" if "a" in p else "sparse"
+
+
 def split_experts(params):
-    """(params whose sparse stack lacks the expert kernels, those kernels
+    """(params whose ``block`` stack lacks the expert kernels, those kernels
     flat over (layer, held expert)): the layer loop scans the first and
     closes over the second, so no layer's experts are sliced out."""
     moe = params["block"]["moe"]
@@ -108,22 +134,42 @@ def split_experts(params):
     return dict(params, block=rest), flat
 
 
-def _ffn(x2, p, cfg, impl, valid, aux, index, experts):
-    """The block's FFN on ``x2`` [T, d] (after attention): dense SwiGLU or
-    the expert share. Returns (x2 + ffn, aux)."""
-    h = _norm(x2, p["ln2"], cfg)
-    if "moe" not in p:
-        with jax.named_scope("mlp"):
-            return x2 + _swiglu(h, p), aux
+def _experts(h, moe, cfg, impl, valid, aux, index, experts):
+    """The expert share on the normed ``h`` [T, d], with the dispatch's
+    routing record and counters kept in ``aux``. Returns (M(h), aux)."""
     y, sel, stats, _ = expert_share.sparse_ffn(
-        h, p["moe"], cfg, "gmm" if impl == "pallas" else "ragged_dot",
+        h, moe, cfg, "gmm" if impl == "pallas" else "ragged_dot",
         valid=valid, mlp=_swiglu, experts=experts, layer=index)
     aux = dict(aux, route=aux["route"].at[index].set(sel))
     if aux["stats"] is not None:
         # the busiest expert and the touched count add up over layers and
         # dispatches; their means divide by layer_calls
         aux["stats"] = aux["stats"] + stats
+    return y, aux
+
+
+def _ffn(x2, p, cfg, impl, valid, aux, index, experts):
+    """The block's FFN on ``x2`` [T, d] (after attention), a "dense" or a
+    "sparse" one (:func:`ffn_kind`). Returns (x2 + ffn, aux)."""
+    h = _norm(x2, p["ln2"], cfg)
+    if ffn_kind(p) == "dense":
+        with jax.named_scope("mlp"):
+            return x2 + _swiglu(h, p), aux
+    y, aux = _experts(h, p["moe"], cfg, impl, valid, aux, index, experts)
     return x2 + y, aux
+
+
+def _ffn_shortcut(x1, sub, moe, cfg, impl, valid, aux, index, experts,
+                  scope: str):
+    """The first half's FFNs of a "both" layer (:func:`ffn_kind`) on ``x1``
+    [T, d] (after the first attention): ``u = ln2(x1)`` feeds the dense
+    SwiGLU, which joins the stream here (under ``scope``), AND the expert
+    share, which does not yet. Returns (x1 + F(u), M(u), aux)."""
+    with jax.named_scope(scope):
+        h = _norm(x1, sub["ln2"], cfg)
+    m, aux = _experts(h, moe, cfg, impl, valid, aux, index, experts)
+    with jax.named_scope(scope), jax.named_scope("mlp"):
+        return x1 + _swiglu(h, sub), m, aux
 
 
 def _softmax_attend(scores, v, dtype, spec):

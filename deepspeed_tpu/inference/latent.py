@@ -50,7 +50,8 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.inference import dialect
-from deepspeed_tpu.inference.hybrid import _ffn
+from deepspeed_tpu.inference.hybrid import (_ffn, _ffn_shortcut, _swiglu,
+                                            ffn_kind)
 from deepspeed_tpu.models.dots_vlm import layer_bases
 from deepspeed_tpu.models.gpt import _dense, _norm
 from deepspeed_tpu.ops.attention.paged import NEG_INF
@@ -82,7 +83,13 @@ def _project(h, p, cfg, positions):
     query goes through a low rank with its own norm (``q_a``, ``q_a_norm``,
     ``q_b``), without it through ONE projection ``q``; and with
     ``mla_use_nope`` the ``d_r`` values of the query and of the cached key
-    are NOT rotated (no positions enter: models/kimi_linear.py)."""
+    are NOT rotated (no positions enter: models/kimi_linear.py). A third:
+    ``q_lora_scale`` / ``kv_lora_scale`` multiply the two NORMED low ranks
+    (so both parts of the query, and the latent as the row holds it, but
+    not the shared key); 1.0, which is no operation, where the config has
+    neither. And the query's up-projection may be stored transposed
+    (``q_b_t`` ``[H (d_n + d_r), r_q]`` in ``q_b``'s place:
+    models/longcat_flash.py says why)."""
     H, dn, dr = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     rkv = cfg.kv_lora_rank
 
@@ -91,10 +98,18 @@ def _project(h, p, cfg, positions):
             return x
         return apply_rotary_freqs(x, positions, cfg.rope_inv_freq)
 
+    def scaled(x, by):
+        return x if by == 1.0 else x * jnp.asarray(by, x.dtype)
+
     with jax.named_scope("mla_q"):
         if getattr(cfg, "q_lora_rank", None):
             c_q = _norm(_dense(h, p["q_a"]), p["q_a_norm"], cfg)
-            q = _dense(c_q, p["q_b"])
+            c_q = scaled(c_q, getattr(cfg, "q_lora_scale", 1.0))
+            if "q_b" in p:
+                q = _dense(c_q, p["q_b"])
+            else:
+                q = jnp.einsum("tr,nr->tn", c_q,
+                               p["q_b_t"]["kernel"].astype(c_q.dtype))
         else:
             q = _dense(h, p["q"])
         q = q.reshape(-1, H, dn + dr)
@@ -102,7 +117,8 @@ def _project(h, p, cfg, positions):
         q_r = rotate(q[..., dn:])
     with jax.named_scope("mla_kv_down"):
         ckv = _dense(h, p["kv_a"])
-        c = _norm(ckv[:, :rkv], p["kv_a_norm"], cfg)
+        c = scaled(_norm(ckv[:, :rkv], p["kv_a_norm"], cfg),
+                   getattr(cfg, "kv_lora_scale", 1.0))
         k_r = rotate(ckv[:, rkv:])
         rows = jnp.concatenate(
             [c, k_r, jnp.zeros((c.shape[0], cfg.latent_lanes
@@ -249,14 +265,58 @@ def attend_prefill(x, pool, table_row, positions, n_valid, p, cfg, rows_at,
         return x + _dense(attn.reshape(C, H * dv), p["attn_out"]), pool
 
 
+def _shortcut_layer(attend, x, pool, p, cfg, base, impl, valid, aux,
+                    experts):
+    """A shortcut-connected DOUBLE layer (hybrid.ffn_kind "both") on the
+    carry's ``x`` (``[1, C, d]`` or ``[B, 1, d]``: ``T`` tokens of ``d``),
+    returned in that shape; ``attend(x [T, d], pool, sublayer params,
+    rows_at) -> (x + attention, pool)`` is the prompt chunk's or the decode
+    step's::
+
+        x1 = x  + MLA_a(ln1a(x))
+        u  = ln2a(x1);  m = M(u)       # the shortcut: m is not added yet
+        x2 = x1 + F_a(u)
+        x3 = x2 + MLA_b(ln1b(x2))
+        x4 = x3 + F_b(ln2b(x3))
+        out = x4 + m
+
+    Two cache rows a token: ``base["rows"]`` ``[2]`` are the two
+    sublayers' offsets into the one flat pool. On one chip the expert
+    share runs where it stands; nothing here stands in for the exchange
+    that the shortcut lets a deployment run under the second half."""
+    shape = x.shape
+    x = x.reshape(-1, shape[-1])
+    with jax.named_scope("scmoe_a"):
+        x1, pool = attend(x, pool, p["a"], base["rows"][0])
+    x2, m, aux = _ffn_shortcut(x1, p["a"], p["moe"], cfg, impl, valid, aux,
+                               base["index"], experts, "scmoe_a")
+    with jax.named_scope("scmoe_b"):
+        x3, pool = attend(x2, pool, p["b"], base["rows"][1])
+        h = _norm(x3, p["b"]["ln2"], cfg)
+        with jax.named_scope("mlp"):
+            # the layer's last sum and the carry's shape are compiled into
+            # the down-projection's fusion, which a trace names after its
+            # LAST operation: under this scope it reads as what its time is
+            return (x3 + _swiglu(h, p["b"]) + m).reshape(shape), pool, aux
+
+
 def block_prefill(carry, pools, table_row, positions, n_valid, p, cfg, base,
                   impl, experts):
     """One layer over a PROMPT CHUNK of one slot. ``carry`` = (x ``[1, C,
-    d]``, aux); ``pools`` = (rows,), flat over layers; ``table_row`` the
-    slot's block table; ``base`` this layer's offset into the pool and its
-    sparse index (models/dots_vlm.layer_bases); ``experts``: every sparse
-    layer's expert kernels (hybrid.split_experts)."""
+    d]``, aux); ``pools`` = (rows,), flat over the attention sublayers;
+    ``table_row`` the slot's block table; ``base`` this layer's offset(s)
+    into the pool and its sparse index (models/dots_vlm.layer_bases);
+    ``experts``: every sparse layer's expert kernels
+    (hybrid.split_experts)."""
     x, aux = carry
+    if ffn_kind(p) == "both":
+        def attend(x, pool, sub, rows_at):
+            return attend_prefill(x, pool, table_row, positions, n_valid,
+                                  sub, cfg, rows_at, impl)
+        valid = jnp.arange(x.shape[1]) < n_valid
+        y, pool, aux = _shortcut_layer(attend, x, pools[0], p, cfg, base,
+                                       impl, valid, aux, experts)
+        return (y, aux), (pool,)
     x2, pool = attend_prefill(x[0], pools[0], table_row, positions, n_valid,
                               p, cfg, base["rows"], impl)
     valid = jnp.arange(x.shape[1]) < n_valid
@@ -313,8 +373,15 @@ def attend_decode(x, pool, tables, lengths, active, p, cfg, rows_at, impl,
 def block_decode(carry, pools, tables, lengths, active, p, cfg, base, impl,
                  experts, plan=None):
     """One layer for ONE new token per slot (:func:`attend_decode`, then
-    the FFN)."""
+    the FFN; or the double layer, :func:`_shortcut_layer`)."""
     x, aux = carry
+    if ffn_kind(p) == "both":
+        def attend(x, pool, sub, rows_at):
+            return attend_decode(x, pool, tables, lengths, active, sub, cfg,
+                                 rows_at, impl, plan)
+        y, pool, aux = _shortcut_layer(attend, x, pools[0], p, cfg, base,
+                                       impl, active, aux, experts)
+        return (y, aux), (pool,)
     x2, pool = attend_decode(x[:, 0], pools[0], tables, lengths, active, p,
                              cfg, base["rows"], impl, plan)
     y, aux = _ffn(x2, p, cfg, impl, active, aux, base["index"], experts)
@@ -322,25 +389,27 @@ def block_decode(carry, pools, tables, lengths, active, p, cfg, base, impl,
 
 
 def new_state(cfg, num_blocks: int, block_size: int, num_slots: int, dtype):
-    """Zeroed (LatentState, None): one pool of latent rows, no V pool."""
-    return LatentState(jnp.zeros((cfg.n_layers, num_blocks, block_size,
+    """Zeroed (LatentState, None): one pool of latent rows over every
+    attention sublayer, no V pool."""
+    return LatentState(jnp.zeros((cfg.n_full_layers, num_blocks, block_size,
                                   cfg.latent_lanes), dtype)), None
 
 
 def kv_bytes_per_token(cfg, dtype=jnp.bfloat16) -> int:
-    """One row of ``latent_lanes`` values a latent layer (the latent and
-    the shared key, padded to whole lane tiles), no K or V heads."""
-    return int(getattr(cfg, "n_full_layers", cfg.n_layers)
-               * cfg.latent_lanes * jnp.dtype(dtype).itemsize)
+    """One row of ``latent_lanes`` values an attention sublayer (the latent
+    and the shared key, padded to whole lane tiles), no K or V heads:
+    ``n_full_layers`` of them, the latent layers of a model that has
+    others beside them, two a layer where layers are double."""
+    return int(cfg.n_full_layers * cfg.latent_lanes
+               * jnp.dtype(dtype).itemsize)
 
 
 def flash_steps(cfg, start: int, bs: int) -> int:
     """Flash steps of a prefill chunk at ``start`` (:func:`attend_prefill`:
-    every occupied history block of ``bs`` and the chunk's own tile, a
-    layer): ``mla_prefill`` kernel blocks where ``decode_impl`` is
-    "pallas", plain flash steps otherwise."""
-    return getattr(cfg, "n_full_layers", cfg.n_layers) \
-        * ((start + bs - 1) // bs + 1)
+    every occupied history block of ``bs`` and the chunk's own tile, an
+    attention sublayer): ``mla_prefill`` kernel blocks where
+    ``decode_impl`` is "pallas", plain flash steps otherwise."""
+    return cfg.n_full_layers * ((start + bs - 1) // bs + 1)
 
 
 def gauges(reg, cache):
@@ -362,6 +431,7 @@ DIALECT = dialect.Dialect(
                          "K/V heads)", "LATENT_ATTENTION"),
     state=LatentState, bytes_per_token=kv_bytes_per_token,
     flash_steps=flash_steps, gauges=gauges,
+    ready_note=lambda cfg: f", latent rows a token: {cfg.n_full_layers}",
     **dialect.carried_layers(
         block_prefill, block_decode, plan=dialect.rows_plan,
         flat=lambda pools: ((pools[0].rows,), pools[0].stats),

@@ -2222,7 +2222,12 @@ class ServingEngine:
                     ("experts_touched_mean",
                      vals["experts_touched"] / calls),
                     # only a router with a skip output counts these
-                    ("pairs_skipped", vals.get("pairs_skipped"))):
+                    ("pairs_skipped", vals.get("pairs_skipped")),
+                    # only a router with zero-compute experts these
+                    ("pairs_zero", vals.get("pairs_zero")),
+                    ("real_pairs_max_token_mean",
+                     vals["real_pairs_max_token"] / calls
+                     if "real_pairs_max_token" in vals else None)):
                 if value is None:
                     continue
                 self.metrics.gauge(

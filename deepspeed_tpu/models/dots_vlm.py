@@ -59,6 +59,11 @@ class DotsVLMConfig(GPTConfig):
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+    # factors on the two NORMED low ranks (inference/latent.py _project)
+    q_lora_scale: float = 1.0
+    kv_lora_scale: float = 1.0
+    # attention sublayers a layer, each with a cache row of its own
+    attn_sublayers: int = 1
     # YaRN on the rope part (rope_factor 1 = plain rotary)
     rope_theta: float = 10000.0
     rope_factor: float = 40.0
@@ -80,7 +85,9 @@ class DotsVLMConfig(GPTConfig):
     experts_held: Optional[Tuple[int, int]] = None
 
     def __post_init__(self):
-        assert 0 < self.n_dense_layers < self.n_layers
+        # leading "dense" layers, then "sparse" ones; or none leading and
+        # every layer "both" (inference/hybrid.py ffn_kind states the three)
+        assert 0 <= self.n_dense_layers < self.n_layers
         assert self.num_experts % self.n_group == 0
         assert 0 < self.topk_group <= self.n_group
         assert self.qk_rope_head_dim % 2 == 0
@@ -94,6 +101,12 @@ class DotsVLMConfig(GPTConfig):
     @property
     def n_sparse_layers(self) -> int:
         return self.n_layers - self.n_dense_layers
+
+    @property
+    def n_full_layers(self) -> int:
+        """The attention sublayers whose history is rows of the paged
+        pool: every layer's."""
+        return self.n_layers * self.attn_sublayers
 
     @property
     def latent_row(self) -> int:
@@ -168,12 +181,17 @@ def init_params(rng: jax.Array, cfg: DotsVLMConfig, std: float = 0.02,
 
 def layer_bases(cfg: DotsVLMConfig, n_blocks: int):
     """Where each layer's rows start in the flat latent pool
-    (engine._scan_layers; ``n_blocks`` blocks a layer) and a sparse layer's
-    row ``index`` in the dispatch's routing record. Split (dense layers,
-    sparse layers)."""
-    nd = cfg.n_dense_layers
+    (engine._scan_layers; ``n_blocks`` blocks an attention sublayer) and a
+    sparse layer's row ``index`` in the dispatch's routing record. Split
+    (dense layers, sparse layers). With ``attn_sublayers`` S > 1 a layer's
+    ``rows`` are S offsets, sublayer j of layer l at ``(l S + j)
+    n_blocks``."""
+    nd, S = cfg.n_dense_layers, cfg.attn_sublayers
     layers = np.arange(cfg.n_layers)
-    bases = {"rows": (layers * n_blocks).astype(np.int32),
+    rows = layers * S * n_blocks
+    if S > 1:
+        rows = rows[:, None] + np.arange(S) * n_blocks
+    bases = {"rows": rows.astype(np.int32),
              "index": np.maximum(layers - nd, 0).astype(np.int32)}
     return ({k: jnp.asarray(v[:nd]) for k, v in bases.items()},
             {k: jnp.asarray(v[nd:]) for k, v in bases.items()})

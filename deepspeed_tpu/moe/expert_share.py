@@ -2,14 +2,19 @@
 
 The layer is TOLD which routed experts it holds (``held = (first, count)``
 of the published ``num_experts``). It routes every token over ALL experts
-(sigmoid scores, a per-expert bias that only selects, top-k (inside the
-best groups where the config has a group limit), weights renormalised over
-the k and scaled), computes only the (token, expert)
-pairs that fall on the experts held here, and adds the shared expert, which
-every chip computes alike. The router is data of the config: with
-``router_hidden`` it is an MLP with a state carried from layer to layer, a
-top-1 choice over the experts and a skip, and no shared expert
-(``route_mlp``; models/zaya.py). What the absent experts would add is left out:
+(scores, a per-expert bias that only selects, top-k (inside the best groups
+where the config has a group limit), weights scaled), computes only the
+(token, expert) pairs that fall on the experts held here, and adds what
+every chip computes alike: the shared expert, and the zero-compute experts'
+term. The router is data of the config: ``router_scoring`` (sigmoid, or a
+softmax over the router's whole width), ``router_renorm`` (whether the k
+weights are renormalised over the k), ``n_zero_experts`` (outputs of the
+router past the last expert: identity experts that return the token
+unchanged, hold no weights and need no exchange; :func:`sparse_ffn`), as
+``n_group`` is; with ``router_hidden`` it is an MLP with a state carried
+from layer to layer, a top-1 choice over the experts and a skip, and no
+shared expert (``route_mlp``; models/zaya.py). What the absent experts
+would add is left out:
 on one chip the layer runs without its exchange, and nothing stands in for
 the other chips or their traffic (docs/EXPERT_SHARE.md).
 
@@ -47,25 +52,43 @@ def has_router_state(cfg) -> bool:
     return bool(getattr(cfg, "router_hidden", 0))
 
 
+def n_zero_experts(cfg) -> int:
+    """Outputs of the config's router past its last expert that are
+    zero-compute (identity) experts (:func:`sparse_ffn`)."""
+    return int(getattr(cfg, "n_zero_experts", 0))
+
+
 def stat_fields(cfg) -> Tuple[str, ...]:
-    """The counters a config's expert layers keep: ``STAT_FIELDS`` and,
-    where the router can choose no expert, ``pairs_skipped``."""
+    """The counters a config's expert layers keep: ``STAT_FIELDS``; where
+    the router can choose no expert, ``pairs_skipped``; where it has
+    zero-compute experts, ``pairs_zero`` (the pairs on them) and
+    ``real_pairs_max_token`` (the most real experts any one token of a
+    layer call chose, summed over the calls as ``busiest_expert_pairs``
+    is)."""
     return STAT_FIELDS + (("pairs_skipped",) if has_router_state(cfg)
-                          else ())
+                          else ()) \
+        + (("pairs_zero", "real_pairs_max_token") if n_zero_experts(cfg)
+           else ())
 
 
 def route(h, router: Dict, k: int, scaling: float, n_group: int = 1,
-          topk_group: int = 1) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """h ``[T, d]`` -> (selected experts ``[T, k]`` int32, their weights
-    ``[T, k]`` float32). Scores and selection in float32 at full matmul
-    precision: a rounded score swaps near-tied experts. With ``n_group``
-    > 1 the experts lie in that many equal groups in order, and a token
-    selects only inside the ``topk_group`` groups whose two best biased
-    scores sum highest (DeepSeek-V3's ``noaux_tc``)."""
+          topk_group: int = 1, scoring: str = "sigmoid",
+          renorm: bool = True) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """h ``[T, d]`` -> (selected outputs ``[T, k]`` int32, their weights
+    ``[T, k]`` float32) over the router's whole width (the kernel's: the
+    experts and, behind them, any zero-compute experts). Scores and
+    selection in float32 at full matmul precision: a rounded score swaps
+    near-tied experts. ``scoring`` "sigmoid" scores each output alone,
+    "softmax" over all of them; the weights are the chosen scores,
+    renormalised over the k with ``renorm``, times ``scaling``. With
+    ``n_group`` > 1 the experts lie in that many equal groups in order,
+    and a token selects only inside the ``topk_group`` groups whose two
+    best biased scores sum highest (DeepSeek-V3's ``noaux_tc``)."""
     logits = jnp.dot(h.astype(jnp.float32),
                      router["kernel"].astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    scores = jax.nn.sigmoid(logits)
+    scores = jax.nn.sigmoid(logits) if scoring == "sigmoid" \
+        else jax.nn.softmax(logits, axis=-1)
     biased = scores + router["bias"].astype(jnp.float32)
     if n_group > 1:
         T, E = biased.shape
@@ -76,7 +99,10 @@ def route(h, router: Dict, k: int, scaling: float, n_group: int = 1,
         biased = jnp.where(kept[:, :, None], per, -jnp.inf).reshape(T, E)
     _, sel = jax.lax.top_k(biased, k)
     w = jnp.take_along_axis(scores, sel, axis=-1)
-    w = w / jnp.sum(w, axis=-1, keepdims=True) * scaling
+    if renorm:
+        w = w / jnp.sum(w, axis=-1, keepdims=True) * scaling
+    else:
+        w = w * scaling
     return sel.astype(jnp.int32), w
 
 
@@ -206,19 +232,44 @@ def held_experts_ffn(h, experts: Dict, sel, w, held: Tuple[int, int],
     return out.astype(h.dtype), stats
 
 
+def zero_experts_term(h, sel, w, num_experts: int, valid=None):
+    """What the zero-compute (identity) experts give ``h`` ``[T, d]``: a
+    pair whose selection lies past the last expert returns the token
+    unchanged, so the term is ``h * sum_k w_k [sel_k >= num_experts]``. It
+    needs no weights and no exchange: every chip computes it alike, as it
+    does a shared expert. Returns (``[T, d]`` in h's dtype, int32
+    ``[pairs_zero, real_pairs_max_token]`` over the ``valid`` tokens)."""
+    zero = sel >= num_experts
+    real = ~zero
+    if valid is not None:
+        zero = jnp.logical_and(zero, valid[:, None])
+        real = jnp.logical_and(real, valid[:, None])
+    share = jnp.sum(jnp.where(zero, w, 0.0), axis=-1)             # [T] f32
+    out = (h.astype(jnp.float32) * share[:, None]).astype(h.dtype)
+    stats = jnp.stack([jnp.sum(zero, dtype=jnp.int32),
+                       jnp.max(jnp.sum(real, axis=-1, dtype=jnp.int32))])
+    return out, stats
+
+
 def sparse_ffn(h, moe: Dict, cfg, impl: str, valid=None, mlp=None,
                experts=None, layer=None, state=None):
-    """Router, the held experts' routed part and the shared expert for
-    ``h`` ``[T, d]``. ``mlp(h, p) -> [T, d]`` is the dense SwiGLU the
-    engine uses; ``experts`` / ``layer``: every sparse layer's expert
-    kernels and this layer's index (:func:`held_experts_ffn`), else
-    ``moe["experts"]``. Which router runs is the config's: the linear
-    sigmoid one (:func:`route`), or, with ``router_hidden``, the MLP that
-    takes the layer below's ``state`` and hands its own on
-    (:func:`route_mlp`); a selection past the last expert (its skip) is a
-    pair on no expert, sorted last with the absent ones, and is counted.
-    No shared expert where the config has none. Returns (routed + shared,
-    selection ``[T, k]``, stats as :func:`stat_fields`, state)."""
+    """Router, the held experts' routed part and what every chip computes
+    alike (the shared expert, the zero-compute experts' term) for ``h``
+    ``[T, d]``. ``mlp(h, p) -> [T, d]`` is the dense SwiGLU the engine
+    uses; ``experts`` / ``layer``: every sparse layer's expert kernels and
+    this layer's index (:func:`held_experts_ffn`), else ``moe["experts"]``.
+    Data of the config, not of this code: which router runs (the linear
+    one, :func:`route`, with the config's ``router_scoring`` and
+    ``router_renorm``; or, with ``router_hidden``, the MLP that takes the
+    layer below's ``state`` and hands its own on, :func:`route_mlp`), how
+    wide it is past the experts and what a selection there means: the
+    MLP's one further output is its skip, a pair on no expert, sorted last
+    with the absent ones and counted; ``n_zero_experts`` further outputs
+    are identity experts, pairs on no expert that STILL add their weight
+    times the token (:func:`zero_experts_term`, under ``moe_zero``), and
+    are counted. No shared expert where the config has none. Returns
+    (routed + shared + zero-compute term, selection ``[T, k]``, stats as
+    :func:`stat_fields`, state)."""
     stateful = has_router_state(cfg)
     with jax.named_scope("moe_router"):
         if stateful:
@@ -226,7 +277,9 @@ def sparse_ffn(h, moe: Dict, cfg, impl: str, valid=None, mlp=None,
         else:
             sel, w = route(h, moe["router"], cfg.moe_k, cfg.routed_scaling,
                            getattr(cfg, "n_group", 1),
-                           getattr(cfg, "topk_group", 1))
+                           getattr(cfg, "topk_group", 1),
+                           getattr(cfg, "router_scoring", "sigmoid"),
+                           getattr(cfg, "router_renorm", True))
     with jax.named_scope("moe_experts"):
         routed, stats = held_experts_ffn(
             h, moe["experts"] if experts is None else experts, sel, w,
@@ -237,6 +290,11 @@ def sparse_ffn(h, moe: Dict, cfg, impl: str, valid=None, mlp=None,
                 skipped = jnp.logical_and(skipped, valid[:, None])
             stats = jnp.concatenate(
                 [stats, jnp.sum(skipped, dtype=jnp.int32)[None]])
+    if n_zero_experts(cfg):
+        with jax.named_scope("moe_zero"):
+            zero, more = zero_experts_term(h, sel, w, cfg.num_experts, valid)
+            routed = routed + zero
+            stats = jnp.concatenate([stats, more])
     if not cfg.n_shared_experts:
         return routed, sel, stats, state
     with jax.named_scope("moe_shared"):
