@@ -289,6 +289,10 @@ class PagedKVCache:
             self.v_scale = None
         self._free: List[int] = list(range(self.num_blocks - 1, 0, -1))
         self._owned: List[List[int]] = [[] for _ in range(num_slots)]
+        # len(_owned[slot]) as an array, stored wherever a slot's list
+        # changes (never rebuilt): the scheduler's one comparison a step
+        # for the slots that need a block (ServingEngine._grow_decoding)
+        self.owned_count = np.zeros((num_slots,), np.int32)
         self._refcount = np.zeros((self.num_blocks,), np.int32)
         # a slot's row: its blocks in the (full layers') pool and, behind
         # them, the ids of its ring blocks in a window layer, which never
@@ -408,7 +412,7 @@ class PagedKVCache:
         state, internal fragmentation of slot tables (tail-block waste:
         allocated-but-unwritten token positions over allocated capacity),
         and the prefix-cache counters."""
-        cap_tokens = sum(len(o) for o in self._owned) * self.block_size
+        cap_tokens = int(self.owned_count.sum()) * self.block_size
         frag = (1.0 - self.tokens_in_flight / cap_tokens) if cap_tokens \
             else 0.0
         return {
@@ -567,6 +571,7 @@ class PagedKVCache:
             self._refcount[bid] = 1
         all_ids = m.block_ids + ids
         self._owned[slot] = list(all_ids)
+        self.owned_count[slot] = len(all_ids)
         self.tables[slot, :self.blocks_per_slot] = 0
         self.tables[slot, :len(all_ids)] = all_ids
         self.lengths[slot] = m.matched
@@ -697,6 +702,7 @@ class PagedKVCache:
             self._refcount[bid] = 1
             self.tables[slot, len(self._owned[slot])] = bid
             self._owned[slot].append(bid)
+            self.owned_count[slot] += 1
         self._mark()
 
     def advance(self, slot: int, n_tokens: int) -> None:
@@ -707,6 +713,26 @@ class PagedKVCache:
         self.lengths[slot] = new_len
         self.peak_tokens_in_flight = max(self.peak_tokens_in_flight,
                                          self.tokens_in_flight)
+
+    def advance_each(self, slots: np.ndarray) -> None:
+        """:meth:`advance` by ONE token for every slot of ``slots`` (an
+        index array without repeats: the slots a batched decode step
+        wrote), with one test of the tables and one reading of the peak
+        for all of them."""
+        self.lengths[slots] += 1
+        # every slot's, the untouched ones' too: no slot ever holds more
+        # tokens than its blocks cover
+        assert (self.lengths <= self.owned_count * self.block_size).all(), \
+            (slots, self.lengths, self.owned_count)
+        self.peak_tokens_in_flight = max(self.peak_tokens_in_flight,
+                                         self.tokens_in_flight)
+
+    def needs_block(self, slots: np.ndarray) -> np.ndarray:
+        """Which of ``slots`` (a mask over all slots) cannot take one more
+        token as their tables stand: the next token lies past the blocks
+        they own, or they are :meth:`at_capacity`."""
+        return slots & ((self.lengths >= self.owned_count * self.block_size)
+                        | (self.lengths >= self.tokens_per_slot))
 
     def capacity_tokens(self, slot: int) -> int:
         """Token positions the slot's allocated blocks cover — the cap
@@ -759,6 +785,7 @@ class PagedKVCache:
         keep = self.blocks_for(n_tokens)
         while len(self._owned[slot]) > keep:
             bid = self._owned[slot].pop()
+            self.owned_count[slot] -= 1
             self.tables[slot, len(self._owned[slot])] = 0
             self._release(bid)
         self.lengths[slot] = n_tokens
@@ -776,6 +803,7 @@ class PagedKVCache:
         for bid in reversed(self._owned[slot]):
             self._release(bid)
         self._owned[slot] = []
+        self.owned_count[slot] = 0
         self.tables[slot, :self.blocks_per_slot] = 0
         self.lengths[slot] = 0
         self.active[slot] = False
@@ -1035,6 +1063,7 @@ class PagedKVCache:
         for bid in bids:
             self._refcount[bid] = 1
         self._owned[slot] = list(bids)
+        self.owned_count[slot] = len(bids)
         self.tables[slot, :self.blocks_per_slot] = 0
         self.tables[slot, :len(bids)] = bids
         self.lengths[slot] = length

@@ -98,6 +98,26 @@ cache adds ONE more program (the copy-on-write block copy), compiled
 eagerly at construction via ``cache.warm_cow()`` so steady state stays
 recompile-free with the cache on.
 
+A step does not walk its slots in Python. What the scheduler needs of
+every slot's request each step lives in per-slot arrays beside
+``cache.lengths`` (the last emitted token, the generated count,
+``max_new_tokens``, ``eos_id``, the deadline, which phase holds the slot),
+written where a slot changes hands (``_seat`` / ``_vacate``) and per
+token by the emit. Expiry is one comparison with the deadlines;
+capacity one comparison with the block tables, and only a slot at a
+block border (once in ``block_size`` steps) or at its budget reaches
+``ensure_capacity`` and its eviction ladder; the decode program's
+``tokens`` / ``active`` / ``gen_counts`` operands are the arrays; the
+emit is one ``lengths[live] += 1``, one ``tolist()``, two appends a
+request (``out`` and ``token_times`` stay lists, current when ``step``
+returns) and the terminal test as one array expression. The per-slot
+path (``_emit_sampled`` > ``_emit_token``) is taken by the slots whose
+request needs per-token Python — stop sequences, ``logprobs``, a
+repetition penalty (``_slow_emit``) — and by the horizon and speculative
+steps; both kinds of slot sit in one step, and requests finish in
+ascending slot order either way. Nothing is read from a flag: which
+path a slot takes is a property of its request and of its table.
+
 Telemetry (``telemetry=True`` / ``DS_TELEMETRY=on``,
 docs/OBSERVABILITY.md): every lifecycle transition (enqueue, admit with
 prefix-hit tags, prefill chunks, evict/requeue, finish/timeout/shed),
@@ -728,6 +748,25 @@ class ServingEngine:
         self.slots: List[Optional[ServeRequest]] = [None] * num_slots
         self.finished: List[ServeRequest] = []
         self._progress = np.zeros((num_slots,), np.int64)  # prefilled toks
+        # what a step needs of every slot's request, as arrays beside
+        # cache.lengths, so that a step tests and feeds all slots at once.
+        # Written by _seat and _vacate, the one pair through which a slot
+        # or its request's state changes, and per token by the two emit
+        # paths: a request's deadline (inf: none), eos (-1: none) and
+        # max_new_tokens; which phase holds the slot; for a decoding slot
+        # its last emitted token and its generated count (0 elsewhere:
+        # they are the decode program's operands); and which decoding
+        # slots emit through _emit_sampled, one by one (_slow_emit)
+        self._held = np.zeros((num_slots,), bool)
+        self._prefilling = np.zeros((num_slots,), bool)
+        self._decoding = np.zeros((num_slots,), bool)
+        self._slow = np.zeros((num_slots,), bool)
+        self._deadline = np.full((num_slots,), np.inf)
+        self._eos = np.full((num_slots,), -1, np.int64)
+        self._max_new = np.zeros((num_slots,), np.int64)
+        self._last_tok = np.zeros((num_slots,), np.int32)
+        self._gen = np.zeros((num_slots,), np.int32)
+        self._grown = 0         # the serve.decode span's, as _kv_steps
         self._admit_counter = 0
         self._over_budget = 0            # consecutive watchdog strikes
         self._watchdog_msg: Optional[str] = None
@@ -747,6 +786,14 @@ class ServingEngine:
                     else self.metrics.gauge)
             self._stat[key] = make(f"serving_{key}", help_)
         self.stats = _StatsView(self._stat)
+        # beside decode_steps and occupancy_sum: slow / occupancy_sum is
+        # the share of slot-steps that took the per-slot emit
+        self._c_emit_slow = self.metrics.counter(
+            "serving_emit_slow_slots_total",
+            "slot-steps of plain decode steps that emitted through the "
+            "per-slot path (_emit_sampled: the request has stop sequences, "
+            "asked for logprobs or carries a repetition penalty); every "
+            "other live slot of a step is emitted by array operations")
         # a prefill chunk's latent layers run the engine's decode_impl
         self._mla_tiles = self._stat[
             "mla_prefill_tiles_kernel_total" if engine.decode_impl == "pallas"
@@ -1149,7 +1196,7 @@ class ServingEngine:
 
     @property
     def busy(self) -> bool:
-        return bool(self.queue) or any(s is not None for s in self.slots)
+        return bool(self.queue) or bool(self._held.any())
 
     def step(self, now: Optional[float] = None) -> int:
         """One scheduler iteration: expire, admit, prefill chunks,
@@ -1192,7 +1239,7 @@ class ServingEngine:
                 occ = self._decode_step(now)
                 c4 = self._span_counts()
                 if c4:
-                    s_decode.set(live=occ, blocks=c4[3],
+                    s_decode.set(live=occ, blocks=c4[3], grown=self._grown,
                                  state_slots=occ if
                                  self.cache.recurrent_state_bytes else 0,
                                  kv_steps=self._kv_steps,
@@ -1313,11 +1360,7 @@ class ServingEngine:
             self.cache.abort_parked()
             for slot, r in enumerate(self.slots):
                 if r is not None:
-                    self._release_adapter(slot, r)
-                    self.cache.free(slot)
-                    self.slots[slot] = None
-                    self.sampler.release(slot)
-                    self._slot_params[slot] = None
+                    self._vacate(slot, r)
             self.queue.clear()
             self._update_backpressure()
         return snap
@@ -1341,11 +1384,7 @@ class ServingEngine:
         caller's snapshot path owns it then)."""
         for slot, r in enumerate(self.slots):
             if r is not None and r.rid == rid and r.state == "handoff":
-                self._release_adapter(slot, r)
-                self.cache.free(slot)
-                self.slots[slot] = None
-                self.sampler.release(slot)
-                self._slot_params[slot] = None
+                self._vacate(slot, r)
                 return True
         return False
 
@@ -1354,14 +1393,13 @@ class ServingEngine:
         """Retire every request whose deadline has passed — slot holders
         free their blocks immediately (no zombie slot squatting), queued
         requests never claim one."""
-        for slot, req in enumerate(self.slots):
-            if req is not None and req.deadline is not None \
-                    and now >= req.deadline:
-                logger.warning(
-                    f"serving: request {req.rid} passed its deadline "
-                    f"({req.deadline}) with {len(req.out)} of "
-                    f"{req.max_new_tokens} tokens; timing out")
-                self._finish(slot, req, now, state="timeout")
+        for slot in np.flatnonzero(now >= self._deadline).tolist():
+            req = self.slots[slot]
+            logger.warning(
+                f"serving: request {req.rid} passed its deadline "
+                f"({req.deadline}) with {len(req.out)} of "
+                f"{req.max_new_tokens} tokens; timing out")
+            self._finish(slot, req, now, state="timeout")
         if not self.queue:
             return
         keep = deque()
@@ -1394,12 +1432,12 @@ class ServingEngine:
         # FIFO head-of-line: no queue jumping, so a preempted-and-
         # requeued request (appendleft) resumes before newer arrivals
         while self.queue:
-            slot = next((i for i, s in enumerate(self.slots) if s is None),
-                        None)
-            if slot is None:
+            free = np.flatnonzero(~self._held)
+            if not free.size:
                 break
+            slot = int(free[0])
             req = self.queue[0]
-            occupied = any(s is not None for s in self.slots)
+            occupied = free.size < self.num_slots
             # idle engine: skip the watermark so a lone request that
             # fits the pool always makes progress (no livelock); the
             # admission charge covers only the uncached suffix when the
@@ -1494,7 +1532,6 @@ class ServingEngine:
                         state="error", generated=len(req.out))
                     continue
             self._unqueue(req)
-            self.slots[slot] = req
             if arow is not None:
                 self._slot_arows[slot] = arow
             # prefill resumes at the matched boundary — the shared
@@ -1504,7 +1541,6 @@ class ServingEngine:
             if matched > 0 and not parked:
                 self._stat["prefix_hits"].inc()
                 self._stat["prefix_tokens_saved"].inc(matched)
-            req.state = "prefill"
             req._admit_seq = self._admit_counter
             self._admit_counter += 1
             # sampling lanes for this slot: resolved knobs become the
@@ -1516,6 +1552,7 @@ class ServingEngine:
                                              self.top_k, self.seed)
             self._slot_params[slot] = params
             self.sampler.admit(slot, params, req._work)
+            self._seat(slot, req, "prefill")
             self._accept_ewma[slot] = 1.0
             self._spec_obs[slot] = 0
             if self._h_temp is not None:
@@ -1529,9 +1566,8 @@ class ServingEngine:
                 matched=int(matched), evictions=req.evictions)
 
     def _prefill_step(self, now: float) -> None:
-        for slot, req in enumerate(self.slots):
-            if req is None or req.state != "prefill":
-                continue
+        for slot in np.flatnonzero(self._prefilling).tolist():
+            req = self.slots[slot]
             done = int(self._progress[slot])
             n = min(self.prefill_chunk, len(req._work) - done)
             attended = self.engine.prefill_attended(
@@ -1606,16 +1642,28 @@ class ServingEngine:
         if req.state not in TERMINAL_STATES:
             # prefill-only role: park the finished prefill for
             # the router's KV migration instead of decoding it
-            req.state = "handoff" if self.prefill_only \
-                else "decode"
+            self._seat(slot, req,
+                       "handoff" if self.prefill_only else "decode")
 
-    def _decode_step(self, now: float) -> int:
-        # every decoding slot needs room for ONE more token; exhaustion
-        # evicts the youngest request rather than OOMing the pool
-        for slot, req in enumerate(self.slots):
-            if req is None or req.state != "decode":
-                continue
-            if self.cache.at_capacity(slot):
+    def _grow_decoding(self, now: float) -> int:
+        """Room for ONE more token in every decoding slot; exhaustion
+        evicts the youngest request rather than OOMing the pool. One
+        comparison over the slots finds the few that need anything (a
+        slot at a block border, once in ``block_size`` steps; a slot at
+        its budget) and only those are visited, in slot order. While the
+        fault injector has a fault armed every decoding slot is visited:
+        ``cache.ensure`` is matched by its visit index, so chaos runs
+        keep the cadence of one visit a decoding slot a step
+        (docs/ROBUSTNESS.md). Returns the slots whose table grew."""
+        cache = self.cache
+        marked = self._decoding if self.faults.faults \
+            else cache.needs_block(self._decoding)
+        grown = 0
+        for slot in np.flatnonzero(marked).tolist():
+            if not self._decoding[slot]:
+                continue            # evicted for an earlier slot's block
+            req = self.slots[slot]
+            if cache.at_capacity(slot):
                 # block budget exhausted: the kernel's next cache write
                 # would clamp into the slot's LAST LIVE block — finish
                 # (truncate) the request before it reaches the kernel.
@@ -1623,16 +1671,16 @@ class ServingEngine:
                 # long, so a preempted slot would requeue forever.
                 logger.warning(
                     f"serving: request {req.rid} hit the per-slot block "
-                    f"budget ({self.cache.tokens_per_slot} tokens) in "
+                    f"budget ({cache.tokens_per_slot} tokens) in "
                     f"slot {slot}; finishing with {len(req.out)} of "
                     f"{req.max_new_tokens} tokens")
                 self._finish(slot, req, now)
                 continue
-            cow0 = self.cache.cow_copies
+            cow0 = cache.cow_copies
+            owned0 = cache.owned_count[slot]
             while True:
                 try:
-                    self.cache.ensure_capacity(
-                        slot, int(self.cache.lengths[slot]) + 1)
+                    cache.ensure_capacity(slot, int(cache.lengths[slot]) + 1)
                     break
                 except CacheExhausted:
                     if self._evict_one(exclude=slot):
@@ -1652,31 +1700,35 @@ class ServingEngine:
                             f"tokens")
                         self._finish(slot, req, now)
                     break
+            grown += int(cache.owned_count[slot] > owned0)
             if self.costs.enabled:
                 # mid-decode divergence copies are this request's bytes
-                self.costs.charge_cow(req, self.cache.cow_copies - cow0)
-        live = [i for i, r in enumerate(self.slots)
-                if r is not None and r.state == "decode"]
+                self.costs.charge_cow(req, cache.cow_copies - cow0)
+        return grown
+
+    def _decode_step(self, now: float) -> int:
+        self._grown = self._grow_decoding(now)
+        live = np.flatnonzero(self._decoding)
+        n_live = int(live.size)
         if self.telemetry.enabled:
             # grid steps of a paged_decode call (of the full table): the
             # live slots' run, beside `blocks` the fill of the tiles; the
             # other slots' (a trash-block tile of a slot with no request,
             # the progress of one in prefill) are the ones the plan drops
             cache = self.cache
-            tiles = [tiles_run(int(n), cache.blocks_per_slot,
-                               cache.block_size,
-                               None if cache.ring_blocks
-                               else self.engine.cfg.attn_window)
-                     for n in cache.lengths]
-            self._kv_steps = sum(tiles[i] for i in live)
-            self._idle_tiles = sum(tiles) - self._kv_steps
+            tiles = tiles_run(cache.lengths, cache.blocks_per_slot,
+                              cache.block_size,
+                              None if cache.ring_blocks
+                              else self.engine.cfg.attn_window)
+            self._kv_steps = int(tiles[live].sum())
+            self._idle_tiles = int(tiles.sum()) - self._kv_steps
             # cached rows the step reads in a layer that pages its whole
             # history: each live slot's tokens and the one it writes
-            self._kv_tokens = int(sum(cache.lengths[i] + 1 for i in live))
-        if not live:
+            self._kv_tokens = int(cache.lengths[live].sum()) + n_live
+        if not n_live:
             return 0
         if self.spec_decode:
-            occ = self._spec_decode_step(live, now)
+            occ = self._spec_decode_step(live.tolist(), now)
             if occ is not None:
                 return occ
             # draft/verify faulted before dispatch: degrade THIS step to
@@ -1684,19 +1736,18 @@ class ServingEngine:
             # speed; the donated pools are intact, the live list is
             # unchanged — no slot was advanced or emitted into)
         elif self.decode_horizon > 1:
-            occ = self._horizon_decode_step(live, now)
+            occ = self._horizon_decode_step(live.tolist(), now)
             if occ is not None:
                 return occ
             # horizon faulted before dispatch: degrade THIS step to the
             # plain single-step path below — same contract as spec
             # (pools intact, no slot state moved, never a dropped token)
-        tokens = np.zeros((self.num_slots,), np.int32)
-        active = np.zeros((self.num_slots,), bool)
-        gen_counts = np.zeros((self.num_slots,), np.int32)
-        for i in live:
-            tokens[i] = self.slots[i].out[-1]
-            active[i] = True
-            gen_counts[i] = len(self.slots[i].out)
+        # the per-slot arrays are the operands (zeros where no slot
+        # decodes); copies, so that what a wrapper of _device_call keeps
+        # is this step's
+        tokens = self._last_tok.copy()
+        active = self._decoding.copy()
+        gen_counts = self._gen.copy()
         lanes = self.sampler.lanes(gen_counts)
         budget = self.step_time_budget_s
         t0 = time.perf_counter() if budget is not None else 0.0
@@ -1715,7 +1766,7 @@ class ServingEngine:
             # its own cached context; the weight read splits exactly
             self.costs.charge_batched(
                 "decode", [(self.slots[i], 1, int(self.cache.lengths[i]))
-                           for i in live])
+                           for i in live.tolist()])
         # one host transfer covers every slot's token + logprob (the
         # sampler already ran inside the compiled decode program)
         tracer = self.telemetry.tracer
@@ -1723,13 +1774,62 @@ class ServingEngine:
                          bytes=toks.nbytes + lps.nbytes, d2h=1):
             toks, lps = jax.device_get((toks, lps))
         with tracer.span("serve.emit", step=self._step_clock,
-                         tokens=len(live)):
-            for i in live:
-                self.cache.advance(i, 1)
+                         tokens=n_live) as s_emit:
+            s_emit.set(slow=self._emit_step(live, toks, lps, now))
+        return n_live
+
+    def _emit_step(self, live: np.ndarray, toks: np.ndarray,
+                   lps: np.ndarray, now: float) -> int:
+        """Emit a plain decode step's tokens: ``toks[i]`` for every slot
+        ``i`` of ``live``. The slots in the ``_slow`` mask (stop
+        sequences, logprobs, a repetition penalty: _slow_emit) go through
+        :meth:`_emit_sampled` one by one; for all the others the step is
+        array operations and one bare loop of the two appends a request,
+        and only the slots the terminal test marks are visited again.
+        Requests finish in ascending slot order whichever way their slot
+        emitted, so ``finished`` reads as if every slot had been walked.
+        Returns how many slots emitted one by one."""
+        self.cache.advance_each(live)
+        fast_mask, fast, visit, n_slow = self._decoding, live, [], 0
+        if self._slow.any():
+            fast_mask = self._decoding & ~self._slow
+            fast = np.flatnonzero(fast_mask)
+            visit = np.flatnonzero(self._slow).tolist()
+            n_slow = len(visit)
+        if fast.size:
+            got = toks[fast]
+            self._last_tok[fast] = got
+            self._gen[fast] += 1
+            sampled = int(np.count_nonzero(self.sampler.temps[fast] > 0.0))
+            if sampled:
+                self._stat["sampled_tokens"].inc(sampled)
+            slots, at = self.slots, fast.tolist()
+            if self._h_tpot is not None:
+                # telemetry on: a decoding request has its first token
+                for i in at:
+                    self._h_tpot.observe(
+                        max(0.0, now - slots[i].token_times[-1]), at=now)
+            for i, tok in zip(at, got.tolist()):
+                req = slots[i]
+                req.out.append(tok)
+                req.token_times.append(now)
+            # whole arrays under the mask (an empty slot reads 0 >= 0)
+            ended = np.flatnonzero(
+                fast_mask & ((self._gen >= self._max_new)
+                             | (self._last_tok == self._eos)))
+            if ended.size:
+                visit = sorted(visit + ended.tolist())
+        if n_slow:
+            self._c_emit_slow.inc(n_slow)
+        for i in visit:
+            req = self.slots[i]
+            if self._slow[i]:
                 self._emit_sampled(
-                    i, self.slots[i], int(toks[i]),
+                    i, req, int(toks[i]),
                     float(lps[i]), now)  # dslint: disable=DS001 — lps is host numpy already (the single batched pull above)
-        return len(live)
+            else:
+                self._finish(i, req, now)
+        return n_slow
 
     def _horizon_decode_step(self, live: List[int],
                              now: float) -> Optional[int]:
@@ -2105,13 +2205,8 @@ class ServingEngine:
         must not turn the cap negative."""
         if now is None:
             return None
-        slack = None
-        for req in self.slots:
-            if req is None or req.deadline is None:
-                continue
-            remain = max(0.0, req.deadline - now)
-            slack = remain if slack is None else min(slack, remain)
-        return slack
+        soonest = float(self._deadline.min())
+        return None if soonest == math.inf else max(0.0, soonest - now)
 
     def _device_call(self, site: str, fn, *args, now: Optional[float] = None):
         """Run a slot program with fault injection + transient-error
@@ -2292,6 +2387,51 @@ class ServingEngine:
             jax.profiler.stop_trace()
         return outdir
 
+    def _slow_emit(self, slot: int, req: ServeRequest) -> bool:
+        """Whether a decoding slot's tokens go through
+        :meth:`_emit_sampled` one by one: its request matches stop
+        sequences against ``out``, records log-probabilities, or marks
+        the sampler's ``seen`` mask (a repetition penalty). Read from the
+        request and the slot's sampling lane where the request starts to
+        decode; nothing else is per token for a request without them."""
+        return bool(req.stop or req.logprobs
+                    or self.sampler.rep_pens[slot] != 1.0)
+
+    def _seat(self, slot: int, req: ServeRequest, state: str) -> None:
+        """``req`` holds ``slot`` in ``state`` (prefill | decode |
+        handoff). With :meth:`_vacate` the ONE pair that writes
+        ``slots[slot]`` and a slot holder's ``state``, because it also
+        writes the per-slot arrays a step reads instead of the requests:
+        the arrays cannot disagree with the requests."""
+        self.slots[slot] = req
+        req.state = state
+        self._held[slot] = True
+        self._deadline[slot] = np.inf if req.deadline is None \
+            else req.deadline
+        self._eos[slot] = -1 if req.eos_id is None else req.eos_id
+        self._max_new[slot] = req.max_new_tokens
+        decoding = state == "decode"
+        self._prefilling[slot] = state == "prefill"
+        self._decoding[slot] = decoding
+        self._slow[slot] = decoding and self._slow_emit(slot, req)
+        self._last_tok[slot] = req.out[-1] if decoding else 0
+        self._gen[slot] = len(req.out) if decoding else 0
+
+    def _vacate(self, slot: int, req: ServeRequest) -> None:
+        """``req`` gives ``slot`` up (finished, preempted, handed off or
+        drained): its adapter pin, blocks and sampling lane go back and
+        the slot's arrays read as an empty slot's. The caller has set
+        the state ``req`` leaves in."""
+        self._release_adapter(slot, req)
+        self.cache.free(slot)
+        self.slots[slot] = None
+        self.sampler.release(slot)
+        self._slot_params[slot] = None
+        self._held[slot] = self._prefilling[slot] = False
+        self._decoding[slot] = self._slow[slot] = False
+        self._deadline[slot] = np.inf
+        self._last_tok[slot] = self._gen[slot] = 0
+
     def _release_adapter(self, slot: int, req: ServeRequest) -> None:
         """Drop the slot's adapter pin (if it holds one) and zero its
         table row. The nonzero row IS the pin marker — a request whose
@@ -2308,11 +2448,7 @@ class ServingEngine:
         """Retire a request: blocks back to the pool, slot reopened."""
         req.state = state
         req.finished_at = now
-        self._release_adapter(slot, req)
-        self.cache.free(slot)
-        self.slots[slot] = None
-        self.sampler.release(slot)
-        self._slot_params[slot] = None
+        self._vacate(slot, req)
         self.finished.append(req)
         if state == "timeout":
             self._stat["timeouts"].inc()
@@ -2344,6 +2480,10 @@ class ServingEngine:
         prev = req.token_times[-1] if req.token_times else None
         req.out.append(tok)
         req.token_times.append(now)
+        if self._decoding[slot]:
+            # the next step's operands (a prefill's first token: _seat)
+            self._last_tok[slot] = tok
+            self._gen[slot] = len(req.out)
         if req.first_token_at is None:
             req.first_token_at = now
             if self._h_ttft is not None and req.submitted_at is not None:
@@ -2408,9 +2548,5 @@ class ServingEngine:
         self.telemetry.tracer.event(
             "evict", rid=req.rid, step=self._step_clock, slot=slot,
             generated=len(req.out))
-        self._release_adapter(slot, req)
-        self.cache.free(slot)
-        self.slots[slot] = None
-        self.sampler.release(slot)
-        self._slot_params[slot] = None
+        self._vacate(slot, req)
         self.queue.appendleft(req)

@@ -74,6 +74,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -114,16 +115,17 @@ def blocks_per_step(nb: int, bs: int) -> int:
     return min(nb, -(-TILE_TOKENS // bs))
 
 
-def tiles_run(length: int, nb: int, bs: int, window: Optional[int] = None,
-              q_len: int = 1) -> int:
+def tiles_run(length, nb: int, bs: int, window: Optional[int] = None,
+              q_len: int = 1):
     """Grid steps of one slot that fetch and compute (the kernel's own
     arithmetic, on the host, for the ``kv_steps`` counter): the tiles
     between the one that holds the band's first block and the one that
-    holds position ``length + q_len - 1``."""
+    holds position ``length + q_len - 1``. ``length`` is one slot's or an
+    array of every slot's."""
     P = blocks_per_step(nb, bs)
-    hi = min((length + q_len - 1) // bs, nb - 1)
-    lo = 0 if window is None else min(max((length - window + 1) // bs, 0),
-                                      nb - 1)
+    hi = np.minimum((length + q_len - 1) // bs, nb - 1)
+    lo = 0 if window is None \
+        else np.clip((length - window + 1) // bs, 0, nb - 1)
     return hi // P - lo // P + 1
 
 

@@ -18,7 +18,10 @@ Sites currently instrumented:
 ====================== =====================================================
 ``serving.decode``     before each batched decode-slots dispatch
 ``serving.prefill``    before each prefill-chunk dispatch
-``cache.ensure``       inside ``PagedKVCache.ensure_capacity`` (growth)
+``cache.ensure``       inside ``PagedKVCache.ensure_capacity`` (growth);
+                       while any fault is armed the scheduler visits it
+                       once a decoding slot a step, else only for the
+                       slots whose table grows (docs/ROBUSTNESS.md)
 ``cache.allocate``     inside ``PagedKVCache.allocate`` (admission)
 ``cache.match``        before the prefix-index lookup in ``allocate``;
                        ``cache_exhausted`` degrades the request to a

@@ -168,8 +168,8 @@ def test_full_budget_slot_finished_not_overwritten(devices):
     # drive the slot to the edge of its block budget by hand
     srv.cache.ensure_capacity(slot, srv.cache.tokens_per_slot)
     srv.cache.lengths[slot] = srv.cache.tokens_per_slot
-    req.state = "decode"
     req.out.append(1)
+    srv._seat(slot, req, "decode")
     used_before = srv.cache.used_blocks
     assert srv._decode_step(now=0.0) == 0     # nothing decoded
     assert req.state == "done" and req in srv.finished
