@@ -48,6 +48,7 @@ import numpy as np
 from deepspeed_tpu.inference import dialect
 from deepspeed_tpu.inference.hybrid import _decode_attend, _heads, _rows
 from deepspeed_tpu.inference.latent import _attend_tile
+from deepspeed_tpu.inference.paged_cache import write_chunk
 from deepspeed_tpu.models.gpt import _dense, _norm
 from deepspeed_tpu.models.zaya import layer_bases
 from deepspeed_tpu.moe import expert_share
@@ -172,7 +173,6 @@ def block_prefill(carry, pools, table_row, positions, n_valid, slot, p, cfg,
     H, Hkv, Dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
     group = H // Hkv
     bs = k_pool.shape[1]
-    NB = table_row.shape[0]
     start = positions[0]
     valid = jnp.arange(C) < n_valid
     scale = 1.0 / np.sqrt(Dh)
@@ -201,10 +201,10 @@ def block_prefill(carry, pools, table_row, positions, n_valid, slot, p, cfg,
         vtails = vtails.at[at].set(jnp.where(keep, v2[last], vtails[at]))
 
     with jax.named_scope("kv_write"):
-        blk = table_row[jnp.clip(positions // bs, 0, NB - 1)]
-        blk = jnp.where(valid, blk, 0) + base["rows"]
-        k_pool = k_pool.at[blk, positions % bs].set(_rows(k))
-        v_pool = v_pool.at[blk, positions % bs].set(_rows(v))
+        k_pool = write_chunk(k_pool, table_row, start, n_valid, _rows(k),
+                             base["rows"])
+        v_pool = write_chunk(v_pool, table_row, start, n_valid, _rows(v),
+                             base["rows"])
 
     with jax.named_scope("paged_attn"), jax.named_scope("attn_cca"):
         # a KV head's group of query heads as rows of one product

@@ -865,11 +865,10 @@ def _attn_prefill_paged(x, pools, table_row, positions, n_valid, p,
     trow = table_row + base              # this layer's blocks
     if k_scale is None:
         with jax.named_scope("kv_write"):
-            blk = table_row[jnp.clip(positions // bs, 0, NB - 1)]
-            blk = jnp.where(valid, blk, 0) + base   # padded lanes -> trash
-            off = positions % bs
-            k_pool = k_pool.at[blk, off].set(_rows(k[0]))
-            v_pool = v_pool.at[blk, off].set(_rows(v[0]))
+            k_pool = paged_cache.write_chunk(
+                k_pool, table_row, positions[0], n_valid, _rows(k[0]), base)
+            v_pool = paged_cache.write_chunk(
+                v_pool, table_row, positions[0], n_valid, _rows(v[0]), base)
         attn = _attend_occupied(q[0], k_pool, v_pool, trow, positions,
                                 n_valid, cfg)[None]
     else:

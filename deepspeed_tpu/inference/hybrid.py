@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.inference import dialect
+from deepspeed_tpu.inference.paged_cache import write_chunk
 from deepspeed_tpu.models.exaone_moe import layer_bases, window_blocks
 from deepspeed_tpu.models.gpt import _dense, _norm
 from deepspeed_tpu.moe import expert_share
@@ -215,14 +216,13 @@ def block_prefill(carry, pools, table_row, positions, n_valid, p, cfg, base,
                 + jnp.arange(bs, dtype=jnp.int32)[None]).reshape(-1)
 
     with jax.named_scope("kv_write"):
-        off = positions % bs
-        fblk = full_row[jnp.clip(positions // bs, 0, NB - 1)]
-        fblk = jnp.where(jnp.logical_and(valid, ~sliding), fblk, 0) \
-            + base["full"]
-        kf = kf.at[fblk, off].set(_rows(k))
-        vf = vf.at[fblk, off].set(_rows(v))
+        # a sliding layer keeps nothing in the full layers' pools
+        n_full = jnp.where(sliding, 0, n_valid)
+        kf = write_chunk(kf, full_row, start, n_full, _rows(k), base["full"])
+        vf = write_chunk(vf, full_row, start, n_full, _rows(v), base["full"])
         # the ring keeps the chunk's last ring*block tokens: each of its
         # places is written once
+        off = positions % bs
         keep = jnp.logical_and(valid, positions >= start + n_valid - RB * bs)
         wblk = ring[(positions // bs) % RB]
         wblk = jnp.where(jnp.logical_and(keep, sliding), wblk, 0) \

@@ -52,6 +52,7 @@ import jax.numpy as jnp
 from deepspeed_tpu.inference import dialect
 from deepspeed_tpu.inference.hybrid import (_ffn, _ffn_shortcut, _swiglu,
                                             ffn_kind)
+from deepspeed_tpu.inference.paged_cache import write_chunk
 from deepspeed_tpu.models.dots_vlm import layer_bases
 from deepspeed_tpu.models.gpt import _dense, _norm
 from deepspeed_tpu.ops.attention.paged import NEG_INF
@@ -199,9 +200,7 @@ def attend_prefill(x, pool, table_row, positions, n_valid, p, cfg, rows_at,
     C = x.shape[0]
     H, dv = cfg.n_heads, cfg.v_head_dim
     bs = pool.shape[1]
-    NB = table_row.shape[0]
     start = positions[0]
-    valid = jnp.arange(C) < n_valid
     scale = cfg.softmax_scale
     kernel = impl == "pallas"
 
@@ -214,9 +213,7 @@ def attend_prefill(x, pool, table_row, positions, n_valid, p, cfg, rows_at,
             q = jnp.concatenate([q_n, q_r], axis=-1).transpose(1, 0, 2)
 
     with jax.named_scope("kv_write"):
-        blk = table_row[jnp.clip(positions // bs, 0, NB - 1)]
-        blk = jnp.where(valid, blk, 0) + rows_at
-        pool = pool.at[blk, positions % bs].set(rows)
+        pool = write_chunk(pool, table_row, start, n_valid, rows, rows_at)
 
     def attend(state, tile, kpos):
         if kernel:

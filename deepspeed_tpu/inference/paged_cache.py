@@ -149,6 +149,76 @@ def scatter_block(pools, blocks, dst):
     return tuple(p.at[:, dst].set(b) for p, b in zip(pools, blocks))
 
 
+def chunk_blocks(start, n, bs: int, nb: int):
+    """The entries of its slot's table (``nb`` blocks of ``bs``) in which a
+    prefill chunk of ``n`` real tokens at position ``start`` has a row:
+    (first entry, how many). None for ``n`` 0, and none past the table's
+    end. Integer arithmetic that holds for traced ``start`` and ``n``
+    (:func:`write_chunk`'s choice of the windows it writes) and for Python
+    ones (the scheduler's ``blocks`` count)."""
+    first = start // bs
+    end = (start + n + bs - 1) // bs
+    end = end - (end - nb) * (end > nb)     # min(end, nb), for a tracer too
+    return first, (end - first) * (end > first) * (n > 0)
+
+
+# the most bytes one window of a chunk's write may hold. The compiler (a
+# v5e's, read ahead of time) splits a gather whose slice passes 512 KiB by
+# lanes, and each part first slices the POOL out: 1.7 GiB of temporaries in
+# the dots.vlm1 cell's prefill program, whose block of 512 rows is 640 KiB.
+# Half of that
+WRITE_WINDOW_BYTES = 256 << 10
+
+
+def write_window(bs: int, row_bytes: int) -> int:
+    """Rows in one window of :func:`write_chunk`: the whole block where it
+    fits ``WRITE_WINDOW_BYTES`` (GPT-2 XL's 16 rows are 50 KiB), else the
+    block halved until it does (128 of a latent block's 512 rows), and
+    never a part of the device's tile of 16 rows, so that the pool seen in
+    windows is the pool's own bytes."""
+    g = bs
+    while g % 32 == 0 and g * row_bytes > WRITE_WINDOW_BYTES:
+        g //= 2
+    return g
+
+
+def write_chunk(pool, table_row, start, n_valid, rows, base=0):
+    """Write one slot's prompt chunk into ``pool`` ``[N, bs, lanes]`` as the
+    few WHOLE windows it touches (a window: a block, or an aligned part of a
+    large one, :func:`write_window`). ``rows`` ``[C, lanes]`` sit at
+    positions ``start + [0, C)`` of the slot's row, the first ``n_valid`` of
+    them real; ``table_row`` ``[NB]`` is the slot's block table, ``base`` the
+    layer's offset into the pool. The run lies in at most ``(C + g - 2) // g
+    + 1`` windows of ``g`` rows (static: 5 for 64 rows in blocks of 16, 5
+    for 512 in windows of 128, 2 for 512 in 512): those are read, the real
+    rows laid over them at ``start % g``, everything else of them kept, and
+    the windows written back. A window with no real row
+    (:func:`chunk_blocks`) is read from and written to the trash block
+    instead, so no live row is written twice. Outside the trash block the
+    pool is bit for bit what ``pool.at[blk, pos % bs].set(rows)`` over the
+    real rows leaves; that scatter moves a row an index, one after the
+    other, and this one a window (PERF.md, PR 48). No branch on alignment: a
+    branch that returns a pool copies it."""
+    C, (N, bs, lanes), NB = rows.shape[0], pool.shape, table_row.shape[0]
+    g = write_window(bs, lanes * pool.dtype.itemsize)
+    per = bs // g                           # windows a block
+    n = (C + g - 2) // g + 1
+    first, count = chunk_blocks(start, n_valid, g, NB * per)
+    i = jnp.arange(n, dtype=jnp.int32)
+    w = first + i                           # the windows of the slot's row
+    blk = table_row[jnp.clip(w // per, 0, NB - 1)] + base
+    ids = jnp.where(i < count, blk * per + w % per, base * per)
+    windows = pool.reshape(N * per, g, lanes)
+    old = windows[ids].reshape(n * g, lanes)
+    at = start % g
+    laid = jax.lax.dynamic_update_slice_in_dim(
+        old, rows.astype(pool.dtype), at, axis=0)
+    r = jnp.arange(n * g, dtype=jnp.int32)[:, None]
+    real = jnp.logical_and(r >= at, r < at + n_valid)
+    return windows.at[ids].set(
+        jnp.where(real, laid, old).reshape(n, g, lanes)).reshape(pool.shape)
+
+
 _default_cow = jax.jit(copy_block, donate_argnums=(0,))
 _default_gather = jax.jit(gather_blocks)
 _default_scatter = jax.jit(scatter_block, donate_argnums=(0,))

@@ -167,7 +167,7 @@ from deepspeed_tpu.inference.adapters import (AdapterLoadError, AdapterPool,
 from deepspeed_tpu.inference import dialect
 from deepspeed_tpu.inference.host_tier import resolve_host_tier
 from deepspeed_tpu.inference.paged_cache import (CacheExhausted,
-                                                 PagedKVCache,
+                                                 PagedKVCache, chunk_blocks,
                                                  resolve_prefix_cache)
 from deepspeed_tpu.inference.spec_decode import (make_draft,
                                                  resolve_spec_decode,
@@ -215,6 +215,9 @@ _STAT_FIELDS = (
     ("admitted", "c", "requests admitted to a slot"),
     ("completed", "c", "requests finished with state=done"),
     ("prefill_chunks", "c", "prefill chunk dispatches"),
+    ("prefill_write_blocks_total", "c",
+     "blocks of their slots' rows in which prefill chunks have a row "
+     "(paged_cache.chunk_blocks: what a chunk's write moves, whole)"),
     ("decode_steps", "c", "batched decode dispatches"),
     ("timeouts", "c", "requests retired at their deadline"),
     ("shed", "c", "requests rejected by the bounded queue"),
@@ -1576,12 +1579,15 @@ class ServingEngine:
             self._stat["prefill_attended_tokens_total"].inc(attended)
             self._stat["prefill_row_tokens_total"].inc(
                 self.cache.tokens_per_slot)
+            blocks = chunk_blocks(done, n, self.cache.block_size,
+                                  self.cache.blocks_per_slot)[1]
+            self._stat["prefill_write_blocks_total"].inc(blocks)
             self._mla_tiles.inc(self.engine.mla_prefill_tiles(
                 done, self.cache.block_size))
             with self.telemetry.tracer.span(
                     "serve.prefill", rid=req.rid, step=self._step_clock,
                     slot=slot, start=done, n=n, history=done,
-                    attended=attended,
+                    attended=attended, blocks=blocks,
                     tail=int(done > 0 and self.cache.cca_tail_bytes > 0),
                     state=int(done > 0
                               and self.cache.recurrent_state_bytes > 0)):

@@ -468,6 +468,37 @@ def param_copy_bytes(instructions: Dict[str, Dict]) -> int:
                    ins.get("param") or ins.get("op", "")).startswith("params["))
 
 
+def scatter_windows(hlo_text: str, scope: str) -> List[int]:
+    """Update windows of every ``scatter`` of a compiled module
+    (``compiled.as_text()``) whose ``op_name`` holds ``scope``, fused ones
+    included (so the text and not :func:`parse_provenance`'s table): the
+    dimensions of its updates that are not ``update_window_dims``,
+    multiplied. The device moves a scatter's windows one after the other
+    and pays by the window, not by the byte: a prefill chunk's write read
+    64 one-row windows here until it moved its 5 whole blocks
+    (inference/paged_cache.py ``write_chunk``)."""
+    shapes: Dict[str, str] = {}
+    found = []
+    for line in hlo_text.splitlines():
+        m = _INSTR.match(line)
+        if m is None or m.group(3).startswith("("):
+            continue
+        shape, _, rest = m.group(3).partition(" ")
+        shapes[m.group(2)] = shape
+        if rest.startswith("scatter(") and scope in (
+                _attr(rest, "op_name") or ""):
+            found.append(rest)
+    counts = []
+    for rest in found:
+        operands = re.findall(r"%([\w.\-]+)", rest[:_balanced(rest, 7)])
+        window = _attr(rest, "update_window_dims").strip("{}")
+        window = {int(d) for d in window.split(",") if d}
+        dims = shape_dims(shapes[operands[-1]])[1]
+        counts.append(math.prod(d for i, d in enumerate(dims)
+                                if i not in window))
+    return counts
+
+
 def provenance_module_name(hlo_text: str) -> str:
     """``jit_serve_decode_slots`` out of ``HloModule jit_serve_...``."""
     m = re.match(r"\s*HloModule\s+([\w.\-]+)", hlo_text)

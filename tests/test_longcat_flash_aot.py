@@ -23,7 +23,8 @@ def test_eight_rows_a_token_in_one_pool_that_is_never_copied(v5e, program):
     from deepspeed_tpu.inference.engine import InferenceEngine, _named
     from deepspeed_tpu.models import longcat_flash
     from deepspeed_tpu.telemetry.costs import (parse_provenance,
-                                               pool_copy_bytes)
+                                               pool_copy_bytes,
+                                               scatter_windows)
     cfg = longcat_flash.LongcatFlashConfig(
         vocab_size=512, n_layers=4, n_heads=4, d_model=256, d_ff=512,
         max_seq_len=512, dtype=jnp.bfloat16, q_lora_rank=256,
@@ -71,3 +72,6 @@ def test_eight_rows_a_token_in_one_pool_that_is_never_copied(v5e, program):
     assert exe.memory_analysis().temp_size_in_bytes < pool // 4
     assert ("mla_prefill" if program == "prefill_slot"
             else "mla_decode") in text and "gmm" in text
+    if program == "prefill_slot":   # a chunk's rows: whole blocks, not rows
+        assert 0 < max(scatter_windows(text, "kv_write")) \
+            <= (C + bs - 2) // bs + 1 == 2

@@ -19,11 +19,13 @@ import pytest
 from deepspeed_tpu.inference import engine as engine_lib
 from deepspeed_tpu.inference.engine import InferenceEngine
 from deepspeed_tpu.inference.hybrid import _rows, causal_band
+from deepspeed_tpu.inference.paged_cache import chunk_blocks
 from deepspeed_tpu.inference.serving import ServeRequest, ServingEngine
 from deepspeed_tpu.models import gpt
 from deepspeed_tpu.ops.attention.paged import (blocks_per_step,
                                                gather_pool_blocks,
                                                resolve_decode_impl)
+from deepspeed_tpu.telemetry import Telemetry
 
 def tiny(**over):
     cfg = gpt.GPTConfig(vocab_size=128, n_layers=2, n_heads=4, d_model=32,
@@ -318,8 +320,10 @@ def test_prefill_chunk_reads_the_occupied_part_of_its_row(devices, start,
                                                           n_valid, variant):
     """The dense pass over the shortest run of tiles that holds what the
     chunk's valid queries see is the whole-row softmax: the same output on
-    every valid lane, and the same pools to the last bit (the write is
-    what it was, and a shared block is read, never written)."""
+    every valid lane, and the same pools to the last bit outside the
+    layer's trash block, which holds whatever came last (the reference
+    scatters a row a token, the engine moves whole blocks:
+    tests/test_write_chunk.py; a shared block is read, never written)."""
     pools, table_for, new, ref, other, base = _read_problem(variant)
     table_row = table_for(start)
     y, got = new(pools, table_row, start, n_valid)
@@ -329,7 +333,8 @@ def test_prefill_chunk_reads_the_occupied_part_of_its_row(devices, start,
                                atol=2e-5, rtol=2e-5)
     assert np.isfinite(np.asarray(y)).all()
     for a, b, before in zip(got, want, pools):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_array_equal(np.delete(np.asarray(a), base, 0),
+                                      np.delete(np.asarray(b), base, 0))
         if _READ_VARIANTS[variant]["shared"]:
             np.testing.assert_array_equal(np.asarray(a)[other + base],
                                           np.asarray(before)[other + base])
@@ -363,3 +368,54 @@ def test_attended_tiles_cover_what_a_chunk_sees_and_little_more(bs, nb,
             tlo, thi = _traced_tiles(jnp.int32(start), jnp.int32(n), bs, nb,
                                      window)
             assert (int(tlo), int(thi)) == (lo, hi)
+
+
+# ---------------------------------------------------------------------------
+# the chunk's write: whole blocks, also from inside a copy-on-write block
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("matched,blocks", [(0, 4), (40, 5)],
+                         ids=["aligned", "mid-block"])
+def test_a_chunk_admitted_inside_a_block_writes_one_block_more(devices,
+                                                               matched,
+                                                               blocks):
+    """With the prefix cache on, a request whose prompt leaves a cached one
+    INSIDE a block is admitted at an unaligned ``start`` (the block is
+    copied on write): its chunk of 64 in blocks of 16 has rows in 5 blocks
+    where an aligned one has them in 4, the span and the counter say so,
+    and it emits the tokens it emits with the cache off."""
+    cfg = gpt.GPTConfig(vocab_size=128, n_layers=2, n_heads=4, d_model=32,
+                        max_seq_len=256, use_flash_attention=False,
+                        remat=False, dtype=jnp.float32)
+    params = gpt.init_params(jax.random.PRNGKey(0), cfg)
+    donor, = prompts_of((120,), seed=3)
+    later = donor.copy()
+    later[matched:] = 1 + (later[matched:] + 7) % 127    # leaves it there
+    kw = dict(num_slots=2, block_size=16, num_blocks=40, prefill_chunk=64)
+
+    def serve(prefix_cache):
+        eng = InferenceEngine(config=cfg, params=params, dtype=jnp.float32)
+        tel = Telemetry()
+        srv = ServingEngine(eng, prefix_cache=prefix_cache, telemetry=tel,
+                            **kw)
+        srv.run([ServeRequest(rid="donor", prompt=donor, max_new_tokens=4)])
+        before = srv.stats["prefill_write_blocks_total"]
+        out = srv.run([ServeRequest(rid="later", prompt=later,
+                                    max_new_tokens=6)])
+        spans = [r[5] for r in tel.tracer.spans()
+                 if r[1] == "serve.prefill" and r[2] == "later"]
+        return out["later"], spans, srv, before
+
+    ref, cold_spans, _, _ = serve(False)
+    out, spans, srv, before = serve(True)
+    np.testing.assert_array_equal(out, ref)
+    assert srv.cache.cow_copies == (1 if matched % 16 else 0)
+    assert [(f["start"], f["n"]) for f in spans] == [
+        (s, min(64, 120 - s)) for s in range(matched, 120, 64)]
+    assert spans[0]["blocks"] == blocks
+    assert [f["blocks"] for f in cold_spans] == [4, 4]      # 64, then 56
+    nb = srv.cache.blocks_per_slot
+    assert [f["blocks"] for f in spans] == [
+        chunk_blocks(f["start"], f["n"], 16, nb)[1] for f in spans]
+    assert srv.stats["prefill_write_blocks_total"] - before \
+        == sum(f["blocks"] for f in spans)

@@ -26,13 +26,14 @@ import pytest
 
 from deepspeed_tpu.inference.engine import (InferenceEngine, _named,
                                             whole_lane_tables)
+from deepspeed_tpu.inference.paged_cache import write_chunk
 from deepspeed_tpu.models import gpt
 from deepspeed_tpu.ops.attention.paged import blocks_per_step
 from deepspeed_tpu.telemetry.costs import (ProgramCostRegistry,
                                            param_copy_bytes,
                                            parse_provenance,
                                            pool_copy_bytes, probe_compiled,
-                                           shape_dims)
+                                           scatter_windows, shape_dims)
 
 CELL = json.loads((pathlib.Path(__file__).parent.parent / "benchmark"
                    / "configs" / "gpt2-xl-serve.json").read_text())
@@ -229,6 +230,75 @@ def test_prefill_program_gathers_the_occupied_part_of_a_row(compiled_cell):
     assert gathered[0] * 8 == gathered[tiles - 1] == NB * bs
 
 
+def test_prefill_chunk_is_written_as_the_whole_blocks_it_touches(
+        compiled_cell):
+    """Read off the compiled programs: the prefill chunk's write into a pool
+    is ONE scatter of at most 5 update windows, the whole blocks a run of 64
+    rows can touch in blocks of 16 (``paged_cache.write_chunk``; until PR 48
+    one window a row: 64, moved one after the other, 4.9% of the docs cell's
+    device time a pool). The decode step's write is one row a slot."""
+    sv = CELL["serving"]
+    C, bs, B = sv["prefill_chunk"], sv["block_size"], sv["num_slots"]
+    nblk = (C + bs - 2) // bs + 1
+    assert nblk == 5
+    exes = compiled_cell[1]
+    windows = scatter_windows(exes["prefill_slot"][0].as_text(), "kv_write")
+    assert len(windows) == 2 and max(windows) <= nblk, windows    # K and V
+    # the reading is not blind
+    assert scatter_windows(exes["decode_slots"][0].as_text(),
+                           "kv_write") == [B, B]
+
+
+@pytest.mark.parametrize("name,N,bs,lanes,NB,windows", [
+    ("dotsvlm1", 6 * 769, 512, 640, 48, 5),
+    ("longcat", 8 * 513, 512, 640, 12, 5),
+    ("zaya1", 20 * 241, 1024, 256, 6, 2),
+    ("jamba2", 2 * 4097, 512, 128, 24, 2)])
+def test_a_large_block_is_written_in_windows_the_compiler_does_not_split(
+        v5e, name, N, bs, lanes, NB, windows):
+    """``write_chunk`` alone at the large-block cells' pools, a chunk of
+    512: a gather whose slice passes 512 KiB (a latent block of 512 rows is
+    640 KiB) is split by lanes, and each part first slices the POOL out
+    (1.7 GiB of temporaries in the dots.vlm1 cell's prefill program, which
+    ``pool_copy_bytes`` does not see: a ``slice``, not a ``copy``). In
+    windows of at most ``WRITE_WINDOW_BYTES`` nothing pool-sized is made."""
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    i32 = jnp.int32
+    exe = jax.jit(write_chunk, donate_argnums=(0,)).trace(
+        S((N, bs, lanes), jnp.bfloat16), S((NB,), i32), S((), i32),
+        S((), i32), S((512, lanes), jnp.bfloat16), S((), i32)).lower(
+        lowering_platforms=("tpu",)).compile()
+    mem = exe.memory_analysis()
+    assert mem.temp_size_in_bytes < 4 * 512 * lanes * 2, mem
+    assert mem.alias_size_in_bytes == N * bs * lanes * 2
+    assert scatter_windows(exe.as_text(), "") == [windows]
+
+
+def test_scatter_windows_reads_fused_scatters_by_scope():
+    text = """HloModule jit_serve_prefill_slot
+
+%fused_computation.1 (p0: bf16[99,16,128], p1: s32[5], p2: bf16[5,16,128]) -> bf16[99,16,128] {
+  %p0 = bf16[99,16,128]{2,1,0} parameter(0)
+  %p1 = s32[5]{0} parameter(1)
+  %p2 = bf16[5,16,128]{2,1,0:T(8,128)(2,1)} parameter(2)
+  ROOT %scatter.1 = bf16[99,16,128]{2,1,0} scatter(%p0, %p1, %p2), update_window_dims={1,2}, inserted_window_dims={0}, scatter_dims_to_operand_dims={0}, index_vector_dim=1, to_apply=%region_0.1, metadata={op_name="jit(serve_prefill_slot)/while/body/kv_write/scatter"}
+}
+
+ENTRY %main.1 (a: bf16[99,16,128], b: s32[64,2], c: bf16[64,128]) -> bf16[99,16,128] {
+  %a = bf16[99,16,128]{2,1,0} parameter(0)
+  %b = s32[64,2]{1,0} parameter(1)
+  %c = bf16[64,128]{1,0} parameter(2)
+  ROOT %scatter.2 = bf16[99,16,128]{2,1,0} scatter(%a, %b, %c), update_window_dims={1}, inserted_window_dims={0,1}, scatter_dims_to_operand_dims={0,1}, index_vector_dim=1, to_apply=%region_0.1, metadata={op_name="jit(serve_prefill_slot)/sample/scatter"}
+}
+"""
+    assert scatter_windows(text, "kv_write") == [5]
+    assert scatter_windows(text, "sample") == [64]
+    assert scatter_windows(text, "") == [5, 64]
+    assert scatter_windows(text, "kv_gather") == []
+
+
 def test_pool_copy_bytes_counts_pool_shaped_copies_only():
     table = {
         "copy.40": {"opcode": "copy",
@@ -391,6 +461,9 @@ def test_no_copy_of_the_state_or_the_pool_is_compiled_in(v5e, program):
     assert exe.memory_analysis().temp_size_in_bytes < buffers // 4
     if program == "decode_slots":
         assert "kda_step" in text and "mla_decode" in text
+    else:       # the chunk's rows go into the pool as whole blocks
+        assert 0 < max(scatter_windows(text, "kv_write")) \
+            <= (C + bs - 2) // bs + 1 == 2
 
 
 @pytest.mark.parametrize("program", ["prefill_slot", "decode_slots"])
@@ -474,3 +547,6 @@ def test_state_space_state_is_stored_unpadded_and_never_copied(v5e, program):
         # temporary: nothing of a buffer's size beside them
         assert mem.temp_size_in_bytes < 20 * C * cfg.max_seq_len * 4
         assert "ssm_scan" in text
+        # the chunk's K and V go into their pools as whole blocks
+        assert 0 < max(scatter_windows(text, "kv_write")) \
+            <= (C + bs - 2) // bs + 1 == 2

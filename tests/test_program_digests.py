@@ -15,14 +15,18 @@ import pytest
 
 import program_text as PT
 
-# sha256[:16] of str(jax.make_jaxpr(program)) at the utils' tiny configs,
-# recorded at the parent of PR 46 and equal on its final tree
+# sha256[:16] of str(jax.make_jaxpr(program)) at the utils' tiny configs.
+# The decode programs' are those recorded at the parent of PR 46 and equal
+# on its final tree. The prefill programs' were re-recorded by PR 48, which
+# MEANT to alter all five: a chunk's rows go into the pool as the whole
+# windows they touch (paged_cache.write_chunk), not as one scattered row a
+# token; the pool they leave is the same (tests/test_write_chunk.py)
 PARENT = {
-    "dots_vlm": ("95ad7f2fb99d5cd2", "6e38d962ac6c627a"),
-    "exaone_moe": ("07f8e1ca05c9bbf4", "4a6802b404de5550"),
-    "kimi_linear": ("dfd27d16662d0b57", "a284d9953d44de1f"),
-    "zaya": ("13bcd8bc05ad8588", "008d914cbe2106ca"),
-    "jamba": ("ef1032d1179aa9d3", "3e097490323d7ce2"),
+    "dots_vlm": ("1f4bc36bb35dad08", "6e38d962ac6c627a"),
+    "exaone_moe": ("acfa3d133f05e785", "4a6802b404de5550"),
+    "kimi_linear": ("ff3efea06af0b4f2", "a284d9953d44de1f"),
+    "zaya": ("71c82477980eded6", "008d914cbe2106ca"),
+    "jamba": ("7bf3f1d239e4fc52", "3e097490323d7ce2"),
 }
 
 
