@@ -1,5 +1,8 @@
 """Paged serving blocks for a model whose layers are of two attention
-kinds (models/exaone_moe.py): ``full`` layers keep their history in the
+kinds (models/exaone_moe.py; models/smallthinker.py is the same dialect
+with other data: a window of 4,096, so a ring as long as a long prompt; no
+q/k norm; a router that reads the layer's input before attention,
+:func:`route_layer_input`): ``full`` layers keep their history in the
 paged pool behind the slot's block table, exactly as the GPT blocks do;
 ``sliding`` layers can never read more than ``attn_window`` tokens back, so
 each slot keeps a bounded RING of ``window_blocks`` blocks per window layer
@@ -78,8 +81,8 @@ def _rows(heads):
 
 def _qkv(h, p, cfg, positions, sliding):
     """h ``[..., T, d]`` -> q ``[..., T, H, Dh]``, k, v ``[..., T, Hkv, Dh]``:
-    RMSNorm per head on q and k, rotary (rotate-half, all channels) in
-    sliding layers only."""
+    RMSNorm per head on q and k where the config has it (``qk_norm``),
+    rotary (rotate-half, all channels) in sliding layers only."""
     H, Hkv, Dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
     qkv = _dense(h, p["qkv"])
     q, k, v = jnp.split(qkv, [H * Dh, (H + Hkv) * Dh], axis=-1)
@@ -135,12 +138,30 @@ def split_experts(params):
     return dict(params, block=rest), flat
 
 
-def _experts(h, moe, cfg, impl, valid, aux, index, experts):
+def route_layer_input(x, p, cfg):
+    """(selection, weights) of a layer whose router reads the layer's
+    INPUT ``x`` (the block's, [1, C, d] or [B, 1, d]: T = C or B tokens),
+    the raw stream before ``ln1`` and attention
+    (``cfg.router_reads == "layer_input"``: models/smallthinker.py), for
+    :func:`_ffn` to hand the experts; None for every other layer, whose
+    router reads what its experts read."""
+    if getattr(cfg, "router_reads", "ffn_input") != "layer_input" \
+            or ffn_kind(p) == "dense":
+        return None
+    with jax.named_scope("moe_router"):
+        return expert_share.route_by_config(
+            x.reshape(-1, x.shape[-1]), p["moe"]["router"], cfg)
+
+
+def _experts(h, moe, cfg, impl, valid, aux, index, experts, routed=None):
     """The expert share on the normed ``h`` [T, d], with the dispatch's
-    routing record and counters kept in ``aux``. Returns (M(h), aux)."""
+    routing record and counters kept in ``aux``; ``routed``: the selection
+    made at the top of the layer (:func:`route_layer_input`). Returns
+    (M(h), aux)."""
     y, sel, stats, _ = expert_share.sparse_ffn(
         h, moe, cfg, "gmm" if impl == "pallas" else "ragged_dot",
-        valid=valid, mlp=_swiglu, experts=experts, layer=index)
+        valid=valid, mlp=_swiglu, experts=experts, layer=index,
+        routed=routed)
     aux = dict(aux, route=aux["route"].at[index].set(sel))
     if aux["stats"] is not None:
         # the busiest expert and the touched count add up over layers and
@@ -149,14 +170,15 @@ def _experts(h, moe, cfg, impl, valid, aux, index, experts):
     return y, aux
 
 
-def _ffn(x2, p, cfg, impl, valid, aux, index, experts):
+def _ffn(x2, p, cfg, impl, valid, aux, index, experts, routed=None):
     """The block's FFN on ``x2`` [T, d] (after attention), a "dense" or a
     "sparse" one (:func:`ffn_kind`). Returns (x2 + ffn, aux)."""
     h = _norm(x2, p["ln2"], cfg)
     if ffn_kind(p) == "dense":
         with jax.named_scope("mlp"):
             return x2 + _swiglu(h, p), aux
-    y, aux = _experts(h, p["moe"], cfg, impl, valid, aux, index, experts)
+    y, aux = _experts(h, p["moe"], cfg, impl, valid, aux, index, experts,
+                      routed)
     return x2 + y, aux
 
 
@@ -171,6 +193,20 @@ def _ffn_shortcut(x1, sub, moe, cfg, impl, valid, aux, index, experts,
     m, aux = _experts(h, moe, cfg, impl, valid, aux, index, experts)
     with jax.named_scope(scope), jax.named_scope("mlp"):
         return x1 + _swiglu(h, sub), m, aux
+
+
+# float32 scores a prefill chunk's attention holds at once, at most: beside
+# them live their exponentials and the bf16 probabilities, three times this
+SCORE_BYTES = 128 << 20
+
+
+def _score_blocks(chunk: int, scores: int) -> int:
+    """Into how many equal blocks of queries (a power of two, at most the
+    chunk) ``scores`` float32 values are cut to fit SCORE_BYTES."""
+    n = 1
+    while 4 * scores > n * SCORE_BYTES and n < chunk:
+        n *= 2
+    return n
 
 
 def _softmax_attend(scores, v, dtype, spec):
@@ -202,6 +238,7 @@ def block_prefill(carry, pools, table_row, positions, n_valid, p, cfg, base,
     valid = jnp.arange(C) < n_valid
     scale = 1.0 / np.sqrt(Dh)
 
+    routed = route_layer_input(x, p, cfg)
     with jax.named_scope("attn_qkv"):
         h = _norm(x, p["ln1"], cfg)
         q, k, v = _qkv(h, p, cfg, positions[None], sliding)
@@ -233,25 +270,41 @@ def block_prefill(carry, pools, table_row, positions, n_valid, p, cfg, base,
     qg = q.reshape(C, Hkv, group, Dh)
     qpos = positions[:, None, None, None]
 
+    def by_kv_head(kc, vc, kpos, window=None):
+        """Attention of the chunk's queries over keys ``kc`` [S, Hkv, Dh]
+        a KV head at a time: [C, group, S] float32 scores, an eighth of
+        the chunk's temporaries; where even those pass SCORE_BYTES (a row
+        of 16,384), a block of queries at a time."""
+        nq = _score_blocks(C, C * group * kc.shape[0])
+
+        def one_kv_head(qkv):
+            qh, kh, vh = qkv
+
+            def attend(qp):
+                qb, pb = qp
+                s = jnp.einsum("cgd,sd->cgs", qb, kh).astype(jnp.float32)
+                s = causal_band(s * scale, kpos, pb[:, None, None], window)
+                return _softmax_attend(s, vh, x.dtype, "cgs,sd->cgd")
+
+            if nq == 1:
+                return attend((qh, positions))
+            out = jax.lax.map(attend, (
+                qh.reshape(nq, C // nq, group, Dh),
+                positions.reshape(nq, C // nq)))
+            return out.reshape(C, group, Dh)
+
+        out = jax.lax.map(one_kv_head, (
+            qg.transpose(1, 0, 2, 3), kc.transpose(1, 0, 2),
+            vc.transpose(1, 0, 2)))                    # [Hkv, C, g, Dh]
+        return out.transpose(1, 0, 2, 3)
+
     def full_attn(_):
         with jax.named_scope("attn_full"):
             trow = full_row + base["full"]
             kc = _heads(kf[trow], Hkv).reshape(NB * bs, Hkv, Dh)
             vc = _heads(vf[trow], Hkv).reshape(NB * bs, Hkv, Dh)
             kpos = jax.lax.broadcasted_iota(jnp.int32, (1, 1, NB * bs), 2)
-
-            def one_kv_head(qkv):
-                # a KV head at a time: [C, group, NB*bs] float32 scores,
-                # an eighth of the chunk's temporaries
-                qh, kh, vh = qkv
-                s = jnp.einsum("cgd,sd->cgs", qh, kh).astype(jnp.float32)
-                s = causal_band(s * scale, kpos, positions[:, None, None])
-                return _softmax_attend(s, vh, x.dtype, "cgs,sd->cgd")
-
-            out = jax.lax.map(one_kv_head, (
-                qg.transpose(1, 0, 2, 3), kc.transpose(1, 0, 2),
-                vc.transpose(1, 0, 2)))                # [Hkv, C, g, Dh]
-            return out.transpose(1, 0, 2, 3)
+            return by_kv_head(kc, vc, kpos)
 
     def window_attn(_):
         with jax.named_scope("attn_window"):
@@ -261,6 +314,10 @@ def block_prefill(carry, pools, table_row, positions, n_valid, p, cfg, base,
             kpos = jnp.concatenate([
                 jnp.where(jnp.logical_and(hpos >= 0, hpos < start), hpos,
                           jnp.int32(2 ** 30)), positions])
+            if _score_blocks(C, C * H * kc.shape[0]) > 1:
+                # a ring as long as the window itself (4,096 against
+                # K-EXAONE's 128): as the full layers
+                return by_kv_head(kc, vc, kpos[None, None, :], W)
             s = jnp.einsum("ckgd,skd->ckgs", qg, kc).astype(jnp.float32)
             s = causal_band(s * scale, kpos[None, None, None, :], qpos, W)
             return _softmax_attend(s, vc, x.dtype, "ckgs,skd->ckgd")
@@ -269,7 +326,8 @@ def block_prefill(carry, pools, table_row, positions, n_valid, p, cfg, base,
         attn = jax.lax.cond(sliding, window_attn, full_attn, None)
     with jax.named_scope("attn_out"):
         x2 = x[0] + _dense(attn.reshape(C, H * Dh), p["attn_out"])
-    y, aux = _ffn(x2, p, cfg, impl, valid, aux, base["index"], experts)
+    y, aux = _ffn(x2, p, cfg, impl, valid, aux, base["index"], experts,
+                  routed)
     return (y[None], aux), (kf, vf, kw, vw)
 
 
@@ -322,6 +380,7 @@ def block_decode(carry, pools, tables, lengths, active, p, cfg, base, impl,
     sliding, W = base["sliding"], cfg.attn_window
     scale = 1.0 / np.sqrt(Dh)
 
+    routed = route_layer_input(x, p, cfg)
     with jax.named_scope("attn_qkv"):
         h = _norm(x, p["ln1"], cfg)
         q, k, v = _qkv(h, p, cfg, lengths[:, None], sliding)
@@ -363,7 +422,8 @@ def block_decode(carry, pools, tables, lengths, active, p, cfg, base, impl,
         attn = jax.lax.cond(sliding, window_attn, full_attn, None)
     with jax.named_scope("attn_out"):
         x2 = x[:, 0] + _dense(attn.reshape(B, H * Dh), p["attn_out"])
-    y, aux = _ffn(x2, p, cfg, impl, active, aux, base["index"], experts)
+    y, aux = _ffn(x2, p, cfg, impl, active, aux, base["index"], experts,
+                  routed)
     return (y[:, None], aux), (kf, vf, kw, vw)
 
 

@@ -439,6 +439,29 @@ class PagedKVCache:
         return (self.num_blocks - 1) - len(self._free)
 
     @property
+    def ring_rows_allocated(self) -> int:
+        """Token places of the window layers' rings (inference/hybrid.py),
+        all slots and window layers; 0 for a model without such layers."""
+        if not self.ring_blocks:
+            return 0
+        return self.num_slots * self.ring_blocks * self.block_size \
+            * self.cfg.n_window_layers
+
+    @property
+    def ring_rows_used(self) -> int:
+        """Of those, the places that hold a token a query can still see:
+        ``min(length, attn_window)`` a slot (a free slot's length is 0)."""
+        if not self.ring_blocks:
+            return 0
+        return int(np.minimum(self.lengths, self.cfg.attn_window).sum()) \
+            * self.cfg.n_window_layers
+
+    def ring_wrapped(self, length: int) -> bool:
+        """Whether a slot of ``length`` tokens is past its window: its
+        window layers then read a full ring and its writes reuse places."""
+        return bool(self.ring_blocks) and length > self.cfg.attn_window
+
+    @property
     def held_blocks(self) -> int:
         """Blocks mapped into at least one slot table (refcount > 0)."""
         return int((self._refcount > 0).sum())

@@ -938,6 +938,15 @@ class ServingEngine:
                       "device bytes of the sliding-window layers' per-slot "
                       "rings (K+V, all slots; 0 without such layers)").set(
                 self.cache.window_bytes)
+            reg.gauge("kv_window_ring_rows_allocated",
+                      "rows (token places) of the window layers' rings, "
+                      "all slots and window layers; 0 without such "
+                      "layers").set(self.cache.ring_rows_allocated)
+            self._g_ring_used = reg.gauge(
+                "kv_window_ring_rows_used",
+                "rows of the window layers' rings that hold a token a "
+                "query can still see: min(length, window) a seated slot "
+                "a window layer (sampled)")
             # a latent pool, per-slot tails, a recurrent state
             self.cache.dialect.gauges(reg, self.cache)
             self._h_kv_err = reg.histogram(
@@ -1590,7 +1599,8 @@ class ServingEngine:
                     attended=attended, blocks=blocks,
                     tail=int(done > 0 and self.cache.cca_tail_bytes > 0),
                     state=int(done > 0
-                              and self.cache.recurrent_state_bytes > 0)):
+                              and self.cache.recurrent_state_bytes > 0),
+                    ring_wrapped=int(self.cache.ring_wrapped(done + n))):
                 if done == 0 and self._state_resets is not None:
                     self._state_resets.inc()
                     if req.evictions:
@@ -2322,6 +2332,9 @@ class ServingEngine:
                      / max(self.engine.cfg.held[1], 1)),
                     ("experts_touched_mean",
                      vals["experts_touched"] / calls),
+                    # only ReLU-gated experts count these (in sixteens)
+                    ("act_zero", vals.get("act_zero")),
+                    ("act_total", vals.get("act_total")),
                     # only a router with a skip output counts these
                     ("pairs_skipped", vals.get("pairs_skipped")),
                     # only a router with zero-compute experts these
@@ -2351,6 +2364,7 @@ class ServingEngine:
         self._g_held.set(int(self.cache.held_blocks))
         self._g_cached.set(int(self.cache.cached_blocks))
         self._g_free.set(int(self.cache.free_blocks))
+        self._g_ring_used.set(self.cache.ring_rows_used)
         admitted = self._stat["admitted"].value
         self._g_hit_rate.set(
             round(self._stat["prefix_hits"].value / admitted, 4)

@@ -63,7 +63,8 @@ class ExaoneMoEConfig(GPTConfig):
         if "sliding" in self.layer_kinds:
             assert self.attn_window, "sliding layers need attn_window"
         assert self.head_size, "the dialect states its head size"
-        assert 0 < self.n_dense_layers < self.n_layers
+        # 0: every layer sparse (models/smallthinker.py)
+        assert 0 <= self.n_dense_layers < self.n_layers
         first, count = self.held
         assert 0 <= first and first + count <= self.num_experts
 

@@ -58,15 +58,26 @@ def n_zero_experts(cfg) -> int:
     return int(getattr(cfg, "n_zero_experts", 0))
 
 
+def expert_act(cfg) -> str:
+    """The gate's activation in the config's experts: "silu" (SwiGLU) or
+    "relu" (ReLU-gated: :func:`held_experts_ffn` then counts the zeros)."""
+    return getattr(cfg, "expert_act", "silu")
+
+
 def stat_fields(cfg) -> Tuple[str, ...]:
     """The counters a config's expert layers keep: ``STAT_FIELDS``; where
+    the experts gate with a ReLU, ``act_zero`` (gate activations that are
+    exactly 0 among the held pairs' ``f`` values) beside ``act_total``
+    (those values), both counted in SIXTEENS: the counters are int32 and a
+    minute of decode dispatches sees 4e9 values; where
     the router can choose no expert, ``pairs_skipped``; where it has
     zero-compute experts, ``pairs_zero`` (the pairs on them) and
     ``real_pairs_max_token`` (the most real experts any one token of a
     layer call chose, summed over the calls as ``busiest_expert_pairs``
     is)."""
-    return STAT_FIELDS + (("pairs_skipped",) if has_router_state(cfg)
-                          else ()) \
+    return STAT_FIELDS + (("act_zero", "act_total") if expert_act(cfg)
+                          == "relu" else ()) \
+        + (("pairs_skipped",) if has_router_state(cfg) else ()) \
         + (("pairs_zero", "real_pairs_max_token") if n_zero_experts(cfg)
            else ())
 
@@ -89,7 +100,9 @@ def route(h, router: Dict, k: int, scaling: float, n_group: int = 1,
                      precision=jax.lax.Precision.HIGHEST)
     scores = jax.nn.sigmoid(logits) if scoring == "sigmoid" \
         else jax.nn.softmax(logits, axis=-1)
-    biased = scores + router["bias"].astype(jnp.float32)
+    # the bias only selects; a router without one selects by its scores
+    biased = scores + router["bias"].astype(jnp.float32) \
+        if "bias" in router else scores
     if n_group > 1:
         T, E = biased.shape
         per = biased.reshape(T, n_group, E // n_group)
@@ -104,6 +117,17 @@ def route(h, router: Dict, k: int, scaling: float, n_group: int = 1,
     else:
         w = w * scaling
     return sel.astype(jnp.int32), w
+
+
+def route_by_config(h, router: Dict, cfg):
+    """:func:`route` with the config's data: the ONE statement of which
+    linear router a config runs, for a layer that routes on its FFN's
+    input (:func:`sparse_ffn`) and for one that routes on the layer's
+    input before attention (inference/hybrid.py ``router_reads``)."""
+    return route(h, router, cfg.moe_k, cfg.routed_scaling,
+                 getattr(cfg, "n_group", 1), getattr(cfg, "topk_group", 1),
+                 getattr(cfg, "router_scoring", "sigmoid"),
+                 getattr(cfg, "router_renorm", True))
 
 
 def _rms(x, scale, eps):
@@ -183,7 +207,7 @@ def _grouped(x, w, sizes, impl: str):
 
 def held_experts_ffn(h, experts: Dict, sel, w, held: Tuple[int, int],
                      impl: str, valid: Optional[jnp.ndarray] = None,
-                     layer=None):
+                     layer=None, act: str = "silu"):
     """The routed part of the layer that THIS chip's experts give.
 
     h ``[T, d]``; ``experts``: ``wg`` / ``wi`` / ``wo`` kernels stacked
@@ -193,9 +217,11 @@ def held_experts_ffn(h, experts: Dict, sel, w, held: Tuple[int, int],
     sparse layers, ``[layers * count, ...]``, and the layer's experts are
     groups ``layer * count ...`` of them: a layer loop hands the kernel
     the whole stack, because slicing a layer's experts out for a custom
-    call copies them (1.2 GB a layer a dispatch: PERF.md, PR 28).
+    call copies them (1.2 GB a layer a dispatch: PERF.md, PR 28). ``act``
+    is the gate's activation (:func:`expert_act`).
     Returns (``[T, d]`` in h's dtype, int32 stats in the order of
-    ``STAT_FIELDS``)."""
+    ``STAT_FIELDS``, with "relu" followed by ``act_zero`` and ``act_total``
+    in sixteens: :func:`stat_fields`)."""
     T, d = h.shape
     K = sel.shape[1]
     first, count = held
@@ -217,9 +243,9 @@ def held_experts_ffn(h, experts: Dict, sel, w, held: Tuple[int, int],
     if layer is not None:
         groups = jax.lax.dynamic_update_slice(
             jnp.zeros((wg.shape[0],), jnp.int32), sizes, (layer * count,))
-    a = jax.nn.silu(_grouped(x, wg, groups, impl)) \
-        * _grouped(x, wi, groups, impl)
-    y = _grouped(a, wo, groups, impl)                         # [M', d]
+    gate = getattr(jax.nn, act)(_grouped(x, wg, groups, impl))
+    y = _grouped(gate * _grouped(x, wi, groups, impl), wo, groups,
+                 impl)                                        # [M', d]
     # back to (token, k) order; a pair that met no held expert reads a
     # row nobody wrote, and is masked
     inv = jnp.zeros((M,), jnp.int32).at[order].set(
@@ -229,6 +255,13 @@ def held_experts_ffn(h, experts: Dict, sel, w, held: Tuple[int, int],
     stats = jnp.stack([
         jnp.sum(sizes), jnp.sum(every) * K, jnp.max(sizes),
         jnp.sum(sizes > 0), jnp.int32(1)]).astype(jnp.int32)
+    if act == "relu":
+        # the sorted rows before the first absent pair are the held ones
+        held_row = jnp.arange(gate.shape[0]) < jnp.sum(sizes)
+        zeros = jnp.sum(jnp.logical_and(gate == 0, held_row[:, None]),
+                        dtype=jnp.int32)
+        stats = jnp.concatenate([stats, jnp.stack(
+            [zeros, jnp.sum(sizes) * gate.shape[1]]) // 16])
     return out.astype(h.dtype), stats
 
 
@@ -252,7 +285,7 @@ def zero_experts_term(h, sel, w, num_experts: int, valid=None):
 
 
 def sparse_ffn(h, moe: Dict, cfg, impl: str, valid=None, mlp=None,
-               experts=None, layer=None, state=None):
+               experts=None, layer=None, state=None, routed=None):
     """Router, the held experts' routed part and what every chip computes
     alike (the shared expert, the zero-compute experts' term) for ``h``
     ``[T, d]``. ``mlp(h, p) -> [T, d]`` is the dense SwiGLU the engine
@@ -267,23 +300,26 @@ def sparse_ffn(h, moe: Dict, cfg, impl: str, valid=None, mlp=None,
     with the absent ones and counted; ``n_zero_experts`` further outputs
     are identity experts, pairs on no expert that STILL add their weight
     times the token (:func:`zero_experts_term`, under ``moe_zero``), and
-    are counted. No shared expert where the config has none. Returns
+    are counted. No shared expert where the config has none. ``routed`` =
+    (selection, weights) made earlier in the layer, from another tensor
+    than ``h`` (:func:`route_by_config` on the layer's input): no router
+    runs here then. Returns
     (routed + shared + zero-compute term, selection ``[T, k]``, stats as
     :func:`stat_fields`, state)."""
     stateful = has_router_state(cfg)
-    with jax.named_scope("moe_router"):
-        if stateful:
-            sel, w, state = route_mlp(h, moe["router"], state, cfg.norm_eps)
-        else:
-            sel, w = route(h, moe["router"], cfg.moe_k, cfg.routed_scaling,
-                           getattr(cfg, "n_group", 1),
-                           getattr(cfg, "topk_group", 1),
-                           getattr(cfg, "router_scoring", "sigmoid"),
-                           getattr(cfg, "router_renorm", True))
+    if routed is not None:
+        sel, w = routed
+    else:
+        with jax.named_scope("moe_router"):
+            if stateful:
+                sel, w, state = route_mlp(h, moe["router"], state,
+                                          cfg.norm_eps)
+            else:
+                sel, w = route_by_config(h, moe["router"], cfg)
     with jax.named_scope("moe_experts"):
         routed, stats = held_experts_ffn(
             h, moe["experts"] if experts is None else experts, sel, w,
-            cfg.held, impl, valid, layer)
+            cfg.held, impl, valid, layer, expert_act(cfg))
         if stateful:
             skipped = sel >= cfg.num_experts
             if valid is not None:
