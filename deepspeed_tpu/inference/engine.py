@@ -775,7 +775,7 @@ def _attend_rows(q, kc, vc, positions, first, cfg: GPTConfig):
 
 
 def _attend_occupied(q, k_pool, v_pool, trow, positions, n_valid,
-                     cfg: GPTConfig):
+                     cfg: GPTConfig, window: Optional[int], rows=None):
     """Attention of one slot's prompt chunk over the OCCUPIED part of its
     row. ``q`` ``[C, H, Dh]`` at ``positions``; the pools hold the chunk's
     own K and V already; ``trow`` ``[NB]`` this layer's block ids. One dense
@@ -788,11 +788,18 @@ def _attend_occupied(q, k_pool, v_pool, trow, positions, n_valid,
     owner) the causal band masks.
     The branches take the pools as read-only operands and return the
     attention only: a branch that returns a pool copies it (PERF.md, PR 40).
-    Returns ``[C, H * Dh]``."""
+    Returns ``[C, H * Dh]``.
+
+    ``window``: the layer's (the plain blocks': ``cfg.attn_window``).
+    ``rows(q, kc, vc, positions, first)``: another dense pass than
+    ``_attend_rows`` over the run's rows (inference/hybrid.py: its own cut
+    of the scores; a window layer's ring, a row whose chunk is not in the
+    pool: ``n_valid`` 0); what it returns is returned."""
     Hkv, Dh = cfg.kv_heads, cfg.head_dim
     bs, NB = k_pool.shape[1], trow.shape[0]
-    lo, hi, P = attended_tiles(positions[0], n_valid, bs, NB,
-                               cfg.attn_window)
+    lo, hi, P = attended_tiles(positions[0], n_valid, bs, NB, window)
+    if rows is None:
+        rows = partial(_attend_rows, cfg=cfg)
     tiles = -(-NB // P)
     # whole tiles of table entries: past the table's end the last entry
     # again, at positions no query reaches
@@ -805,7 +812,7 @@ def _attend_occupied(q, k_pool, v_pool, trow, positions, n_valid,
         with jax.named_scope("kv_gather"):
             kc = _heads(k_pool[blocks], Hkv).reshape(n * P * bs, Hkv, Dh)
             vc = _heads(v_pool[blocks], Hkv).reshape(n * P * bs, Hkv, Dh)
-        return _attend_rows(q, kc, vc, positions, lo * P * bs, cfg)
+        return rows(q, kc, vc, positions, lo * P * bs)
 
     return jax.lax.switch(
         jnp.clip(hi - lo, 1, tiles) - 1,
@@ -870,7 +877,7 @@ def _attn_prefill_paged(x, pools, table_row, positions, n_valid, p,
             v_pool = paged_cache.write_chunk(
                 v_pool, table_row, positions[0], n_valid, _rows(v[0]), base)
         attn = _attend_occupied(q[0], k_pool, v_pool, trow, positions,
-                                n_valid, cfg)[None]
+                                n_valid, cfg, cfg.attn_window)[None]
     else:
         with jax.named_scope("kv_write"):
             kq0 = _heads(k_pool[trow], Hkv)
@@ -918,10 +925,14 @@ def _attn_prefill_paged(x, pools, table_row, positions, n_valid, p,
     return h, attn, (k_pool, v_pool, k_scale, v_scale)
 
 
-def tile_reads(cfg, start: int, n: int, bs: int, nb: int) -> int:
+def tile_reads(cfg, start: int, n: int, bs: int, nb: int,
+               windowed: bool = True) -> int:
     """Positions of its slot's row a prefill chunk of the blocks above
-    reads: whole :func:`attended_tiles`, its own rows among them."""
-    lo, hi, P = attended_tiles(start, n, bs, nb, cfg.attn_window)
+    reads: whole :func:`attended_tiles`, its own rows among them. Not
+    ``windowed``: in a layer that sees its whole history whatever
+    ``cfg.attn_window`` says (inference/hybrid.py's full layers)."""
+    lo, hi, P = attended_tiles(start, n, bs, nb,
+                               cfg.attn_window if windowed else None)
     return min(max(hi - lo, 1) * P * bs, nb * bs)
 
 

@@ -195,9 +195,11 @@ def _ffn_shortcut(x1, sub, moe, cfg, impl, valid, aux, index, experts,
         return x1 + _swiglu(h, sub), m, aux
 
 
-# float32 scores a prefill chunk's attention holds at once, at most: beside
-# them live their exponentials and the bf16 probabilities, three times this
-SCORE_BYTES = 128 << 20
+# float32 scores a prefill chunk's attention holds at once, at most: half of
+# a v5e's 128 MiB of VMEM, where XLA then keeps them beside their bf16
+# probabilities (at 128 MiB the probabilities of a branch of 8,192 keys went
+# to HBM, 59 MB of temporaries the whole-row program did not have)
+SCORE_BYTES = 64 << 20
 
 
 def _score_blocks(chunk: int, scores: int) -> int:
@@ -214,6 +216,46 @@ def _softmax_attend(scores, v, dtype, spec):
     return jnp.einsum(spec, probs, v)
 
 
+def _attend(qg, kc, vc, qpos, kpos, window=None):
+    """Attention of a chunk's queries ``qg`` [C, Hkv, group, Dh] at
+    ``qpos`` [C] over keys ``kc`` [S, Hkv, Dh] at ``kpos`` [S], masked to
+    the causal band (and ``window``), a KV head at a time: [C, group, S]
+    float32 scores, an eighth of the chunk's temporaries; where even those
+    pass SCORE_BYTES (a row of 16,384), a block of queries at a time. A
+    window layer whose scores fit it for all heads (K-EXAONE's ring of 144
+    rows) attends in one pass."""
+    C, Hkv, group, Dh = qg.shape
+    S = kc.shape[0]
+    scale = 1.0 / np.sqrt(Dh)
+    if window is not None and _score_blocks(C, C * Hkv * group * S) == 1:
+        s = jnp.einsum("ckgd,skd->ckgs", qg, kc).astype(jnp.float32)
+        s = causal_band(s * scale, kpos[None, None, None, :],
+                        qpos[:, None, None, None], window)
+        return _softmax_attend(s, vc, qg.dtype, "ckgs,skd->ckgd")
+    nq = _score_blocks(C, C * group * S)
+
+    def one_kv_head(qkv):
+        qh, kh, vh = qkv
+
+        def attend(qp):
+            qb, pb = qp
+            s = jnp.einsum("cgd,sd->cgs", qb, kh).astype(jnp.float32)
+            s = causal_band(s * scale, kpos[None, None, :],
+                            pb[:, None, None], window)
+            return _softmax_attend(s, vh, qg.dtype, "cgs,sd->cgd")
+
+        if nq == 1:
+            return attend((qh, qpos))
+        out = jax.lax.map(attend, (qh.reshape(nq, C // nq, group, Dh),
+                                   qpos.reshape(nq, C // nq)))
+        return out.reshape(C, group, Dh)
+
+    out = jax.lax.map(one_kv_head, (
+        qg.transpose(1, 0, 2, 3), kc.transpose(1, 0, 2),
+        vc.transpose(1, 0, 2)))                        # [Hkv, C, g, Dh]
+    return out.transpose(1, 0, 2, 3)
+
+
 def block_prefill(carry, pools, table_row, positions, n_valid, p, cfg, base,
                   impl, experts):
     """One layer over a PROMPT CHUNK of one slot. ``carry`` = (x ``[1, C,
@@ -221,14 +263,18 @@ def block_prefill(carry, pools, table_row, positions, n_valid, p, cfg, base,
     layers; ``table_row`` = the slot's full-layer block table followed by
     its ring block ids; ``base`` = this layer's offsets and kind
     (models/exaone_moe.layer_bases); ``experts`` = every sparse layer's
-    expert kernels (:func:`split_experts`). A window layer attends over the ring's
-    history (read BEFORE the chunk's writes reuse its oldest blocks) and the
-    chunk itself, and keeps the chunk's last ``ring * block`` tokens."""
+    expert kernels (:func:`split_experts`). Either kind attends the
+    shortest run of whole tiles that holds every key a valid query may see
+    (engine._attend_occupied: the lengths follow from the table's shape
+    and the ring's): a full layer over its slot's row, the chunk's rows
+    already in the pool; a window layer over the ring's history (read
+    BEFORE the chunk's writes reuse its oldest blocks) and the chunk
+    itself, of which it keeps the last ``ring * block`` tokens."""
+    from deepspeed_tpu.inference.engine import _attend_occupied
     x, aux = carry
     kf, vf, kw, vw = pools
     C = x.shape[1]
     H, Hkv, Dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
-    group = H // Hkv
     bs = kf.shape[1]
     RB = window_blocks(cfg, bs)
     NB = table_row.shape[0] - RB
@@ -236,21 +282,41 @@ def block_prefill(carry, pools, table_row, positions, n_valid, p, cfg, base,
     sliding, W = base["sliding"], cfg.attn_window
     start = positions[0]
     valid = jnp.arange(C) < n_valid
-    scale = 1.0 / np.sqrt(Dh)
 
     routed = route_layer_input(x, p, cfg)
     with jax.named_scope("attn_qkv"):
         h = _norm(x, p["ln1"], cfg)
         q, k, v = _qkv(h, p, cfg, positions[None], sliding)
         q, k, v = q[0], k[0], v[0]                       # [C, heads, Dh]
+    qg = q.reshape(C, Hkv, H // Hkv, Dh)
 
-    with jax.named_scope("kv_gather"):
-        hb = start // bs - (RB - 1) + jnp.arange(RB, dtype=jnp.int32)
-        hblk = ring[hb % RB] + base["win"]
-        hk = _heads(kw[hblk], Hkv).reshape(RB * bs, Hkv, Dh)
-        hv = _heads(vw[hblk], Hkv).reshape(RB * bs, Hkv, Dh)
-        hpos = (hb[:, None] * bs
-                + jnp.arange(bs, dtype=jnp.int32)[None]).reshape(-1)
+    def full_attn(kf, vf, kw, vw):
+        def rows(qg, kc, vc, qpos, first):
+            kpos = first + jnp.arange(kc.shape[0], dtype=jnp.int32)
+            return _attend(qg, kc, vc, qpos, kpos)
+
+        with jax.named_scope("attn_full"):
+            return _attend_occupied(qg, kf, vf, full_row + base["full"],
+                                    positions, n_valid, cfg, None, rows)
+
+    def window_attn(kf, vf, kw, vw):
+        # the ring as a row in logical order, oldest block first (as
+        # block_decode reads it): the history lies in [0, qpos[0]) of it,
+        # the chunk is not in it yet
+        lo, _ = _ring_span(cfg, bs, start)
+        trow = ring[(lo + jnp.arange(RB, dtype=jnp.int32)) % RB] \
+            + base["win"]
+
+        def rows(qg, hk, hv, qpos, first):
+            hpos = first + jnp.arange(hk.shape[0], dtype=jnp.int32)
+            kpos = jnp.concatenate([
+                jnp.where(hpos < qpos[0], hpos, jnp.int32(2 ** 30)), qpos])
+            return _attend(qg, jnp.concatenate([hk, k], axis=0),
+                           jnp.concatenate([hv, v], axis=0), qpos, kpos, W)
+
+        with jax.named_scope("attn_window"):
+            return _attend_occupied(qg, kw, vw, trow, positions - lo * bs,
+                                    0, cfg, W, rows)
 
     with jax.named_scope("kv_write"):
         # a sliding layer keeps nothing in the full layers' pools
@@ -264,71 +330,15 @@ def block_prefill(carry, pools, table_row, positions, n_valid, p, cfg, base,
         wblk = ring[(positions // bs) % RB]
         wblk = jnp.where(jnp.logical_and(keep, sliding), wblk, 0) \
             + base["win"]
-        kw = kw.at[wblk, off].set(_rows(k))
-        vw = vw.at[wblk, off].set(_rows(v))
-
-    qg = q.reshape(C, Hkv, group, Dh)
-    qpos = positions[:, None, None, None]
-
-    def by_kv_head(kc, vc, kpos, window=None):
-        """Attention of the chunk's queries over keys ``kc`` [S, Hkv, Dh]
-        a KV head at a time: [C, group, S] float32 scores, an eighth of
-        the chunk's temporaries; where even those pass SCORE_BYTES (a row
-        of 16,384), a block of queries at a time."""
-        nq = _score_blocks(C, C * group * kc.shape[0])
-
-        def one_kv_head(qkv):
-            qh, kh, vh = qkv
-
-            def attend(qp):
-                qb, pb = qp
-                s = jnp.einsum("cgd,sd->cgs", qb, kh).astype(jnp.float32)
-                s = causal_band(s * scale, kpos, pb[:, None, None], window)
-                return _softmax_attend(s, vh, x.dtype, "cgs,sd->cgd")
-
-            if nq == 1:
-                return attend((qh, positions))
-            out = jax.lax.map(attend, (
-                qh.reshape(nq, C // nq, group, Dh),
-                positions.reshape(nq, C // nq)))
-            return out.reshape(C, group, Dh)
-
-        out = jax.lax.map(one_kv_head, (
-            qg.transpose(1, 0, 2, 3), kc.transpose(1, 0, 2),
-            vc.transpose(1, 0, 2)))                    # [Hkv, C, g, Dh]
-        return out.transpose(1, 0, 2, 3)
-
-    def full_attn(_):
-        with jax.named_scope("attn_full"):
-            trow = full_row + base["full"]
-            kc = _heads(kf[trow], Hkv).reshape(NB * bs, Hkv, Dh)
-            vc = _heads(vf[trow], Hkv).reshape(NB * bs, Hkv, Dh)
-            kpos = jax.lax.broadcasted_iota(jnp.int32, (1, 1, NB * bs), 2)
-            return by_kv_head(kc, vc, kpos)
-
-    def window_attn(_):
-        with jax.named_scope("attn_window"):
-            kc = jnp.concatenate([hk, k], axis=0)
-            vc = jnp.concatenate([hv, v], axis=0)
-            # history a ring place really holds: positions in [0, start)
-            kpos = jnp.concatenate([
-                jnp.where(jnp.logical_and(hpos >= 0, hpos < start), hpos,
-                          jnp.int32(2 ** 30)), positions])
-            if _score_blocks(C, C * H * kc.shape[0]) > 1:
-                # a ring as long as the window itself (4,096 against
-                # K-EXAONE's 128): as the full layers
-                return by_kv_head(kc, vc, kpos[None, None, :], W)
-            s = jnp.einsum("ckgd,skd->ckgs", qg, kc).astype(jnp.float32)
-            s = causal_band(s * scale, kpos[None, None, None, :], qpos, W)
-            return _softmax_attend(s, vc, x.dtype, "ckgs,skd->ckgd")
-
+        rings = kw.at[wblk, off].set(_rows(k)), vw.at[wblk, off].set(_rows(v))
     with jax.named_scope("paged_attn"):
-        attn = jax.lax.cond(sliding, window_attn, full_attn, None)
+        # read-only operands; the rings as the chunk found them
+        attn = jax.lax.cond(sliding, window_attn, full_attn, kf, vf, kw, vw)
     with jax.named_scope("attn_out"):
         x2 = x[0] + _dense(attn.reshape(C, H * Dh), p["attn_out"])
     y, aux = _ffn(x2, p, cfg, impl, valid, aux, base["index"], experts,
                   routed)
-    return (y[None], aux), (kf, vf, kw, vw)
+    return (y[None], aux), (kf, vf, *rings)
 
 
 def _ring_span(cfg, bs: int, lengths):
@@ -446,11 +456,17 @@ def slot_bytes(cfg, block_size: int, dtype=jnp.bfloat16):
         * cfg.kv_heads * cfg.head_dim * jnp.dtype(dtype).itemsize))
 
 
+def _full_reads(cfg, *a) -> int:
+    """What a chunk reads of its slot's row in a full layer: the program's
+    own tiles, on the host."""
+    from deepspeed_tpu.inference.engine import tile_reads
+    return tile_reads(cfg, *a, windowed=False)
+
+
 # the full pool and the window rings side by side in the layer loop's carry
 DIALECT = dialect.Dialect(
     owns=is_hybrid, new_state=new_state, pool=lambda k: k.full,
-    # the full layers read the whole row
-    prefill_reads=lambda cfg, start, n, bs, nb: nb * bs,
+    prefill_reads=_full_reads,
     refusal=lambda cfg: ("sliding-window layers (bounded per-slot window "
                          "state)", "EXPERT_SHARE"),
     state=PagedState, bytes_per_token=dialect.full_layers_kv_bytes,

@@ -107,3 +107,103 @@ def serve_logits(cfg, params, prompts, new_tokens, num_slots=2,
         got[r.rid] = (np.concatenate([r.prompt, np.asarray(r.out, np.int32)]),
                       np.stack(logits[r.rid]))
     return srv, got
+
+
+def serve_chunks(cfg, params, prompts, new_tokens, whole=False, **kw):
+    """:func:`serve_logits` one request at a time, and beside it every
+    prefill chunk's ``(start, n_valid, logits of its last token)`` in the
+    order served. ``whole``: with ONE length to read compiled in
+    (engine.PREFILL_READ_LENGTHS 1): every chunk attends its slot's whole
+    row and the whole ring, the form before the tiles."""
+    import pytest
+    from deepspeed_tpu.inference import engine
+    chunks = []
+    orig = engine.InferenceEngine.prefill_into_slot
+
+    def prefill(self, k_pool, v_pool, table_row, tokens, start, n_valid,
+                **k):
+        out = orig(self, k_pool, v_pool, table_row, tokens, start, n_valid,
+                   **k)
+        chunks.append((int(start), int(n_valid),
+                       np.asarray(out[0], np.float32).reshape(-1)))
+        return out
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(engine.InferenceEngine, "prefill_into_slot", prefill)
+        if whole:
+            mp.setattr(engine, "PREFILL_READ_LENGTHS", 1)
+        srv, got = serve_logits(cfg, params, prompts, new_tokens,
+                                num_slots=1, **kw)
+    return srv, got, chunks
+
+
+def gathered_blocks(jaxpr, pool_block):
+    """Leading sizes of the gathers of whole pool blocks (results ``[n,
+    *pool_block]``) in ``jaxpr``: (those outside every ``lax.switch``,
+    [per switch, in program order: per branch, the sizes inside it])."""
+    def subs(eqn):
+        for v in eqn.params.values():
+            for j in v if isinstance(v, (tuple, list)) else (v,):
+                j = getattr(j, "jaxpr", j)
+                if hasattr(j, "eqns"):
+                    yield j
+
+    def walk(j, outside, switches):
+        for eqn in j.eqns:
+            if eqn.primitive.name == "gather":
+                shape = eqn.outvars[0].aval.shape
+                if shape[1:] == tuple(pool_block):
+                    outside.append(shape[0])
+            elif eqn.primitive.name == "cond" \
+                    and len(eqn.params["branches"]) > 2:
+                branches = []
+                for b in eqn.params["branches"]:
+                    branches.append([])
+                    walk(b.jaxpr, branches[-1], switches)
+                switches.append(branches)
+            else:
+                for sub in subs(eqn):
+                    walk(sub, outside, switches)
+        return outside, switches
+
+    return walk(getattr(jaxpr, "jaxpr", jaxpr), [], [])
+
+
+# what each prompt's chunks meet, at chunks of 40 (off a block's edge), blocks
+# of 16 and rows of 1,024 positions: 8 tiles of 128
+EDGES = {"one short of a tile": 127, "on a tile": 128, "one past a tile": 129,
+         "every length of the row, a padded last chunk": 1000}
+EDGE = dict(prefill_chunk=40, block_size=16)
+
+
+def serve_edges(cfg, params):
+    """The EDGES prompts served as the program serves them and in the
+    whole-row, whole-ring form: (prompts, srv, (got, chunks), (got, chunks)
+    of the whole form)."""
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(1, 96, n) for n in EDGES.values()]
+    srv, *tiles = serve_chunks(cfg, params, prompts, 3, **EDGE)
+    _, *whole = serve_chunks(cfg, params, prompts, 3, whole=True, **EDGE)
+    return prompts, srv, tiles, whole
+
+
+def assert_tiles_as_whole(edges, rid):
+    """Every chunk of prompt ``rid`` gives the logits of the form that
+    attends the slot's whole row and the whole ring whatever they hold, to
+    float32 rounding, and so do the tokens behind it; what the scheduler
+    counts as read is the full layers' whole tiles of 128 up to the chunk's
+    end, not the row's 1,024."""
+    prompts, srv, (got, chunks), (got_whole, whole) = edges
+    C = EDGE["prefill_chunk"]
+    first = sum(-(-len(p) // C) for p in prompts[:rid])
+    mine = chunks[first:first + -(-len(prompts[rid]) // C)]
+    assert [(s, n) for s, n, _ in mine] == [
+        (s, min(C, len(prompts[rid]) - s))
+        for s in range(0, len(prompts[rid]), C)]
+    for (s, n, lg), (sw, nw, lw) in zip(mine, whole[first:]):
+        assert (s, n) == (sw, nw)
+        np.testing.assert_allclose(lg, lw, atol=2e-5, err_msg=str((s, n)))
+    np.testing.assert_array_equal(got[rid][0], got_whole[rid][0])
+    np.testing.assert_allclose(got[rid][1], got_whole[rid][1], atol=2e-5)
+    assert [srv.engine.prefill_attended(s, n, 16, 64) for s, n, _ in mine] \
+        == [-(-(s + n) // 128) * 128 for s, n, _ in mine]

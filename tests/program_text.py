@@ -11,11 +11,16 @@ from deepspeed_tpu.inference import dialect
 from deepspeed_tpu.inference.engine import InferenceEngine
 
 
-def serving_programs_text(cfg, params, impl="gather", B=2, C=16, bs=4,
-                          NB=8):
-    """{"prefill_slot": jaxpr text, "decode_slots": jaxpr text} of the
-    engine's two paged programs for ``cfg`` over abstract arguments
-    (``params``: the tree's shapes, as ``jax.eval_shape`` gives them)."""
+def serving_programs_text(cfg, params, **kw):
+    """{"prefill_slot": jaxpr text, "decode_slots": jaxpr text} of
+    :func:`serving_programs`."""
+    return {k: str(v) for k, v in serving_programs(cfg, params, **kw).items()}
+
+
+def serving_programs(cfg, params, impl="gather", B=2, C=16, bs=4, NB=8):
+    """{"prefill_slot": jaxpr, "decode_slots": jaxpr} of the engine's two
+    paged programs for ``cfg`` over abstract arguments (``params``: the
+    tree's shapes, as ``jax.eval_shape`` gives them)."""
     eng = InferenceEngine.__new__(InferenceEngine)
     eng.cfg, eng.max_seq_len, eng.dtype = cfg, cfg.max_seq_len, cfg.dtype
     eng.decode_impl = impl
@@ -39,7 +44,7 @@ def serving_programs_text(cfg, params, impl="gather", B=2, C=16, bs=4,
         lambda *a: eng._decode_slots_fn(*a[:7], impl, *a[7:]))(
         params, k, v, S((B, row), i32), S((B,), i32), S((B,), i32),
         S((B,), jnp.bool_), S((B, 2), u32), *lanes, S((B, V), jnp.bool_))
-    return {"prefill_slot": str(prefill), "decode_slots": str(decode)}
+    return {"prefill_slot": prefill, "decode_slots": decode}
 
 
 def digest(text: str) -> str:

@@ -38,8 +38,9 @@ CASES = {
         exaone_moe_util.tiny_config, lambda: hybrid.DIALECT,
         [(2, 49, 4, 32), (6, 7, 4, 32)] * 2,
         dict(bytes_per_token=512, window_bytes=36864, ring_blocks=3), 27,
+        # the full layers' tiles whatever the window (the ring's) says
         ("sliding-window layers (bounded per-slot window state)",
-         "EXPERT_SHARE"), [1024] * 8, [0, 0, 0]),
+         "EXPERT_SHARE"), TILES, [0, 0, 0]),
     "dots": (
         dots_vlm_util.tiny_config, lambda: latent.DIALECT, [(4, 49, 4, 128)],
         dict(bytes_per_token=2048), 24,

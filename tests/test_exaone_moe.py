@@ -127,6 +127,40 @@ def test_bounded_window_state_equals_whole_history(served, monkeypatch):
         np.testing.assert_allclose(got[rid][1], got_whole[rid][1], atol=2e-5)
 
 
+# rows of 1,024 at blocks of 16 are 8 tiles of 128; the window's ring (2
+# blocks) is shorter than a tile: ONE length
+@pytest.fixture(scope="module")
+def edges():
+    cfg = U.tiny_config(max_seq_len=1024)
+    params = U.tiny_params(cfg)
+    return cfg, params, U.serve_edges(cfg, params)
+
+
+@pytest.mark.parametrize("rid", range(len(U.EDGES)), ids=list(U.EDGES))
+def test_a_chunk_attends_the_tiles_it_sees_as_the_whole_row(edges, rid):
+    cfg, params, served = edges
+    U.assert_tiles_as_whole(served, rid)
+    prompts, _, (got, _), _ = served
+    assert _worst(U.reference(), cfg, params, prompts, {rid: got[rid]}) \
+        < SOUND
+
+
+def test_only_the_longest_branch_gathers_a_whole_row():
+    """The full layers' reads of pool blocks lie in the branches of a
+    ``lax.switch``, a tile more in each; a ring shorter than a tile is
+    read whole, outside any switch, beside the 4 windows of a block in
+    which write_chunk lays the chunk's rows. One body for the leading
+    dense layer and one for the sparse layers."""
+    import program_text as PT
+    cfg = U.tiny_config(max_seq_len=1024)
+    params = jax.eval_shape(lambda: U.tiny_params(cfg))
+    jaxpr = PT.serving_programs(cfg, params, C=40, bs=16, NB=64)
+    outside, switches = U.gathered_blocks(
+        jaxpr["prefill_slot"], (16, cfg.kv_heads * cfg.head_dim))
+    assert outside == [4, 4, 2, 2] * 2
+    assert switches == [[[8 * n] * 2 for n in range(1, 9)]] * 2
+
+
 @pytest.mark.parametrize("variant", [
     "softmax_router", "no_scale", "unnormalised", "bias_in_weights",
     "no_bias", "wrong_held", "rotary_on_full", "no_qk_norm"])

@@ -20,10 +20,19 @@ import program_text as PT
 # on its final tree. The prefill programs' were re-recorded by PR 48, which
 # MEANT to alter all five: a chunk's rows go into the pool as the whole
 # windows they touch (paged_cache.write_chunk), not as one scattered row a
-# token; the pool they leave is the same (tests/test_write_chunk.py)
+# token; the pool they leave is the same (tests/test_write_chunk.py).
+# ``exaone_moe``'s prefill program was re-recorded by PR 51, which MEANT to
+# alter it (and ``smallthinker``'s, the same dialect, whose pair joins
+# here): a chunk attends the whole tiles it can see of its slot's row and
+# of the ring (hybrid.block_prefill through engine._attend_occupied) and
+# the ring's gather lies inside the window branch; the logits are the
+# whole-row form's (tests/test_exaone_moe.py,
+# tests/test_smallthinker_serving.py). Its
+# decode program and both programs of the other four are the parent's
 PARENT = {
     "dots_vlm": ("1f4bc36bb35dad08", "6e38d962ac6c627a"),
-    "exaone_moe": ("acfa3d133f05e785", "4a6802b404de5550"),
+    "exaone_moe": ("5156e3f3e5fb0868", "4a6802b404de5550"),
+    "smallthinker": ("bfccd691bf9b57c4", "98f190518fa6bd18"),
     "kimi_linear": ("ff3efea06af0b4f2", "a284d9953d44de1f"),
     "zaya": ("71c82477980eded6", "008d914cbe2106ca"),
     "jamba": ("7bf3f1d239e4fc52", "3e097490323d7ce2"),

@@ -10,6 +10,8 @@ import numpy as np
 import pytest
 
 import smallthinker_util as U
+import exaone_moe_util as X
+import program_text as PT
 from exaone_moe_util import serve_logits
 from deepspeed_tpu.inference import dialect, hybrid
 from deepspeed_tpu.models import exaone_moe, smallthinker
@@ -64,11 +66,14 @@ def test_prefill_then_decode_matches_the_reference(served):
 
 def test_score_blocks_bound_the_chunks_temporaries(served, monkeypatch):
     """At the cell's sizes a chunk's float32 scores pass SCORE_BYTES: a
-    window layer then attends a KV head at a time and a full layer a block
-    of queries at a time. Forced here by a tiny bound: the same logits."""
+    window layer then attends a KV head at a time and a full layer's longer
+    branches a block of queries at a time. Forced here by a tiny bound: the
+    same logits."""
     cfg, params, prompts, _, got = served
-    assert hybrid._score_blocks(512, 512 * 7 * 16384) == 2
-    assert hybrid._score_blocks(512, 512 * 28 * 4736) == 4
+    assert hybrid._score_blocks(512, 512 * 7 * 16384) == 4
+    assert hybrid._score_blocks(512, 512 * 7 * 4096) == 1
+    assert hybrid._score_blocks(512, 512 * 28 * 1152) == 1      # one tile
+    assert hybrid._score_blocks(512, 512 * 28 * 4992) == 8
     assert hybrid._score_blocks(256, 256 * 8 * 4096) == 1       # K-EXAONE
     assert hybrid._score_blocks(256, 256 * 64 * 400) == 1
     monkeypatch.setattr(hybrid, "SCORE_BYTES", 1 << 12)
@@ -77,6 +82,42 @@ def test_score_blocks_bound_the_chunks_temporaries(served, monkeypatch):
     for rid in got:
         np.testing.assert_array_equal(got[rid][0], cut[rid][0])
         np.testing.assert_allclose(got[rid][1], cut[rid][1], atol=2e-5)
+
+
+# window 300 at blocks of 16: a ring of 20 blocks read in tiles of 8, three
+# lengths, the longest past the ring's end; the 1,000-token prompt passes the
+# window and goes round the ring
+@pytest.fixture(scope="module")
+def edges():
+    cfg = U.tiny_config(window=300, max_seq_len=1024)
+    params = U.tiny_params(cfg)
+    return cfg, params, X.serve_edges(cfg, params)
+
+
+@pytest.mark.parametrize("rid", range(len(X.EDGES)), ids=list(X.EDGES))
+def test_a_chunk_attends_the_tiles_it_sees_as_the_whole_row_and_ring(
+        edges, rid):
+    cfg, params, served = edges
+    X.assert_tiles_as_whole(served, rid)
+    prompts, _, (got, _), _ = served
+    assert _worst(cfg, params, prompts, {rid: got[rid]})[rid] < SOUND
+
+
+def test_no_chunk_gathers_a_whole_row_or_ring_but_in_the_longest_branch():
+    """The prefill program's reads of pool blocks all lie in the branches
+    of a ``lax.switch``, a tile more in each: 8 lengths of the 64-entry row
+    in a full layer, 3 of the 20-block ring (the longest run past its end)
+    in a window layer, K and V alike."""
+    cfg = U.tiny_config(window=300, max_seq_len=1024)
+    params = jax.eval_shape(lambda: U.tiny_params(cfg))
+    rows = cfg.kv_heads * cfg.head_dim
+    text = PT.serving_programs(cfg, params, C=40, bs=16, NB=64)
+    outside, switches = X.gathered_blocks(text["prefill_slot"], (16, rows))
+    # the 4 windows of a block in which write_chunk lays the chunk's rows
+    assert outside == [4, 4]
+    assert sorted(switches, key=len) == [
+        [[8 * n] * 2 for n in range(1, 4)],
+        [[8 * n] * 2 for n in range(1, 9)]]
 
 
 @pytest.mark.parametrize("variant", VARIANTS)
