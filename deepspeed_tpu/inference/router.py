@@ -898,6 +898,7 @@ class ReplicaRouter:
         # owns the device-resident chain (parked until admission adopts)
         for k in keys:
             self._mig_pool.discard(k)
+        src.srv.costs.flush()   # the entry copies the seated request's cost
         entry = snapshot_entry(req, kv_handle={
             "blocks": int(handle["n_blocks"]),
             "length": int(handle["length"]),
@@ -942,6 +943,7 @@ class ReplicaRouter:
             self._mig_pool.discard(k)
         if dest is not None:
             dest.srv.cache.drop_parked(req.rid)
+        src.srv.costs.flush()   # the entry copies the seated request's cost
         entry = snapshot_entry(req)
         src.srv.release_handoff(req.rid)
         self._stat["migration_fallbacks"].inc()

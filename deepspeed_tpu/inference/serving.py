@@ -128,7 +128,14 @@ Chrome-trace/Perfetto exporters. ``stats`` is now a READ-ONLY mapping
 view over registry counters (same keys, same values as the old dict);
 the scheduler deadline clock is a private field, so mutating a metric
 can never move a deadline. Default off: the off path swaps in no-op
-twins and is token-bit-identical to on (tests/test_telemetry.py).
+twins and is token-bit-identical to on (tests/test_telemetry.py). The
+plane does not walk the slots either: a batched dispatch, a step's
+block-seconds and a step's TPOT are one call each over the slots'
+arrays (telemetry/costs.py ``charge_batched``, metrics.py
+``observe_many``), a slot's cost accumulators fold into its request
+where the slot is vacated, and every dispatch's ring record says what
+the plane itself took of the host time before it (``self_us`` /
+``self_parts``, ``_account_gap``).
 
 Per-request sampling (docs/SAMPLING.md): every ``ServeRequest`` may
 carry its own temperature/top_k/top_p/seed/repetition_penalty plus
@@ -723,9 +730,10 @@ class ServingEngine:
         # of a horizon's tokens stamp at dispatch time)
         self._horizon_ticks = 1
         self._token_tick = 0.0
-        self._kv_steps = 0      # the serve.decode span's, telemetry on
-        self._idle_tiles = 0    # beside it: the steps the plan left out
-        self._kv_tokens = 0
+        self._kv_tokens = 0     # the serve.decode span's, telemetry on
+        # beside it on a sampled step: (kv_steps, idle_tiles), the grid
+        # steps the live slots run and the ones the plan left out
+        self._tiles = None
         self.last_step_span = 1.0
         self.token_time_unit = 0.0
         # the account of the host time between two dispatches
@@ -738,6 +746,15 @@ class ServingEngine:
         self._gap_caller = 0.0
         self._gap_step_t1: Optional[float] = None
         self._gap_empty = True
+        # what the plane took of the host itself since the last dispatch
+        # (self_us / self_parts on serve.dispatch, _account_gap): seconds
+        # on the tracer's clock stamped around the step's histogram
+        # observes, its span counts and the sampled gauges; the spans'
+        # own enter and exit are the tracer's (span_self), the charges
+        # the accountant's (self_s)
+        self._self_hist = self._self_counts = self._self_gauges = 0.0
+        self._clock = self.telemetry.tracer._clock \
+            if self.telemetry.enabled else None
         # per-request sampling: engine-wide ctor knobs are DEFAULTS a
         # request's own fields override (sampling.resolve_params); the
         # resolved knobs live as slot-indexed arrays the fused sampler
@@ -769,7 +786,12 @@ class ServingEngine:
         self._max_new = np.zeros((num_slots,), np.int64)
         self._last_tok = np.zeros((num_slots,), np.int32)
         self._gen = np.zeros((num_slots,), np.int32)
-        self._grown = 0         # the serve.decode span's, as _kv_steps
+        # telemetry on: when a decoding slot's last token was emitted
+        # (its request's token_times[-1]), for a step's TPOT in one
+        # gather; written by _seat and the two emit paths
+        self._tok_t = np.zeros((num_slots,), np.float64) \
+            if self.telemetry.enabled else None
+        self._grown = 0         # the serve.decode span's, as _kv_tokens
         self._admit_counter = 0
         self._over_budget = 0            # consecutive watchdog strikes
         self._watchdog_msg: Optional[str] = None
@@ -833,8 +855,9 @@ class ServingEngine:
             self._h_occ = reg.histogram(
                 "serving_batch_occupancy", "decoding slots per step",
                 buckets=tuple(float(i) for i in range(num_slots + 1)))
-            # wall seconds of every step and of its four phases, from
-            # the step's spans (serve.step and its children)
+            # wall seconds of every step, and of its four phases on the
+            # sampled steps, from the step's spans (serve.step and its
+            # children)
             self._h_step = reg.histogram(
                 "serving_step_s", help="total wall seconds per step",
                 buckets=_PHASE_BUCKETS)
@@ -870,6 +893,14 @@ class ServingEngine:
                 "serving_engine_empty_seconds_total",
                 "seconds of the gaps that follow an empty engine: pauses, "
                 "in no other gap metric")
+            self._c_self = reg.counter(
+                "serving_telemetry_self_seconds_total",
+                "host seconds the telemetry plane stamped on its own "
+                "account (span enter and exit, cost charges, histogram "
+                "observes, span counts, sampled gauges; what ran under a "
+                "running program included): over the sum of the three "
+                "serving_gap_*_seconds_total, the plane's share of the "
+                "host time between dispatches")
             self._g_held = reg.gauge(
                 "serving_hbm_blocks_held", "pool blocks with refcount > 0")
             self._g_cached = reg.gauge(
@@ -1039,7 +1070,8 @@ class ServingEngine:
                 param_itemsize = 2
             self.costs = CostAccountant(
                 engine.cfg, kv_tok, block_bytes, param_itemsize,
-                registry=self.metrics)
+                registry=self.metrics, slots=self.slots,
+                clock=self._clock or time.perf_counter)
             self.cost_registry = ProgramCostRegistry()
             self.cost_registry.populate(engine, cache=self.cache)
             if self.telemetry.enabled:
@@ -1084,6 +1116,7 @@ class ServingEngine:
         """Per-request postmortem rows: every finished request plus the
         in-flight set, each with its lifecycle state and cost
         footprint."""
+        self.costs.flush()      # a view: seated requests' cost current
         rows = []
         for req in self.finished:
             rows.append({"rid": req.rid, "state": req.state,
@@ -1254,10 +1287,11 @@ class ServingEngine:
                     s_decode.set(live=occ, blocks=c4[3], grown=self._grown,
                                  state_slots=occ if
                                  self.cache.recurrent_state_bytes else 0,
-                                 kv_steps=self._kv_steps,
-                                 idle_tiles=self._idle_tiles,
                                  kv_tokens=self._kv_tokens,
                                  evicted=c4[2] - c3[2])
+                    if self._tiles is not None:     # a sampled step
+                        s_decode.set(kv_steps=self._tiles[0],
+                                     idle_tiles=self._tiles[1])
             with tracer.span("serve.spill", step=clock) as s_spill:
                 self._spill_step()
             with tracer.span("serve.bookkeep", step=clock):
@@ -1266,14 +1300,20 @@ class ServingEngine:
             # the phases tile the step: admission ends with serve.admit,
             # prefill with the start of serve.decode, decode (the
             # host-tier tick with it) with serve.spill
+            t = self._clock()
             self._h_step.observe(s_step.dur)
-            self._h_phase["admission"].observe(s_admit.t1 - s_step.t0)
-            self._h_phase["prefill"].observe(s_decode.t0 - s_admit.t1)
-            self._h_phase["decode"].observe(s_spill.t1 - s_decode.t0)
-            self._h_phase["bookkeeping"].observe(s_step.t1 - s_spill.t1)
+            if clock % self.telemetry.sample_every == 0:
+                # the four phases on the sampled steps (serving_step_s
+                # and the spans hold every step's stamps)
+                self._h_phase["admission"].observe(s_admit.t1 - s_step.t0)
+                self._h_phase["prefill"].observe(s_decode.t0 - s_admit.t1)
+                self._h_phase["decode"].observe(s_spill.t1 - s_decode.t0)
+                self._h_phase["bookkeeping"].observe(
+                    s_step.t1 - s_spill.t1)
             self._gap_step_t1 = s_step.t1
             if not self.busy:
                 self._gap_empty = True
+            self._self_hist += self._clock() - t
         if self._watchdog_msg is not None:
             msg, self._watchdog_msg = self._watchdog_msg, None
             self._over_budget = 0
@@ -1287,9 +1327,12 @@ class ServingEngine:
         a step's spans; () with telemetry off."""
         if not self.telemetry.enabled:
             return ()
+        t = self._clock()
         st = self._stat
-        return (st["timeouts"].value, st["admitted"].value,
-                st["evictions"].value, self.cache.used_blocks)
+        counts = (st["timeouts"].value, st["admitted"].value,
+                  st["evictions"].value, self.cache.used_blocks)
+        self._self_counts += self._clock() - t
+        return counts
 
     def _bookkeep(self, occ: int, clock: int) -> None:
         """Everything ``step`` does after the host-tier tick: the
@@ -1304,19 +1347,20 @@ class ServingEngine:
         if self.costs.enabled:
             # KV residency integrates at horizon boundaries: every slot
             # holder is billed its block count x the ticks this step
-            # consumed (scheduler-clock units; seconds under wall_clock)
-            for i, r in enumerate(self.slots):
-                if r is not None:
-                    self.costs.charge_block_seconds(
-                        r, self.cache.blocks_for(int(self.cache.lengths[i])),
-                        self._horizon_ticks)
+            # consumed (scheduler-clock units; seconds under wall_clock):
+            # one call over the seated mask, blocks_for of every length
+            self.costs.charge_block_seconds(
+                self._held, self.cache.lengths, self.cache.block_size,
+                self._horizon_ticks)
         self._stat["steps"].inc()
         self._stat["occupancy_sum"].inc(occ)
         peak = self._stat["peak_occupancy"]
         peak.set(max(peak.value, occ))
         self._update_backpressure()
         if self._h_occ is not None:
+            t = self._clock()
             self._h_occ.observe(occ)
+            self._self_hist += self._clock() - t
             if clock % self.telemetry.sample_every == 0:
                 self._sample_gauges()
 
@@ -1355,6 +1399,7 @@ class ServingEngine:
         path (the router's drain): every slot's blocks — including
         prefix-cache pins — go back to the pool and the queue empties,
         so the snapshot is the only remaining owner of the work."""
+        self.costs.flush()      # a view: seated requests' cost current
         snap = []
         for slot, r in enumerate(self.slots):
             if r is not None:
@@ -1727,20 +1772,27 @@ class ServingEngine:
         live = np.flatnonzero(self._decoding)
         n_live = int(live.size)
         if self.telemetry.enabled:
-            # grid steps of a paged_decode call (of the full table): the
-            # live slots' run, beside `blocks` the fill of the tiles; the
-            # other slots' (a trash-block tile of a slot with no request,
-            # the progress of one in prefill) are the ones the plan drops
+            t = self._clock()
             cache = self.cache
-            tiles = tiles_run(cache.lengths, cache.blocks_per_slot,
-                              cache.block_size,
-                              None if cache.ring_blocks
-                              else self.engine.cfg.attn_window)
-            self._kv_steps = int(tiles[live].sum())
-            self._idle_tiles = int(tiles.sum()) - self._kv_steps
             # cached rows the step reads in a layer that pages its whole
             # history: each live slot's tokens and the one it writes
             self._kv_tokens = int(cache.lengths[live].sum()) + n_live
+            self._tiles = None
+            if self._step_clock % self.telemetry.sample_every == 0:
+                # on the sampled cadence (every slot's arithmetic; no
+                # benchmark metric reads them): grid steps of a
+                # paged_decode call (of the full table): the live slots'
+                # run, beside `blocks` the fill of the tiles; the other
+                # slots' (a trash-block tile of a slot with no request,
+                # the progress of one in prefill) are the ones the plan
+                # drops
+                tiles = tiles_run(cache.lengths, cache.blocks_per_slot,
+                                  cache.block_size,
+                                  None if cache.ring_blocks
+                                  else self.engine.cfg.attn_window)
+                kv_steps = int(tiles[live].sum())
+                self._tiles = (kv_steps, int(tiles.sum()) - kv_steps)
+            self._self_counts += self._clock() - t
         if not n_live:
             return 0
         if self.spec_decode:
@@ -1780,9 +1832,8 @@ class ServingEngine:
         if self.costs.enabled:
             # one batched dispatch: each live slot decoded 1 token over
             # its own cached context; the weight read splits exactly
-            self.costs.charge_batched(
-                "decode", [(self.slots[i], 1, int(self.cache.lengths[i]))
-                           for i in live.tolist()])
+            self.costs.charge_batched("decode", live, 1,
+                                      self.cache.lengths[live])
         # one host transfer covers every slot's token + logprob (the
         # sampler already ran inside the compiled decode program)
         tracer = self.telemetry.tracer
@@ -1821,10 +1872,13 @@ class ServingEngine:
                 self._stat["sampled_tokens"].inc(sampled)
             slots, at = self.slots, fast.tolist()
             if self._h_tpot is not None:
-                # telemetry on: a decoding request has its first token
-                for i in at:
-                    self._h_tpot.observe(
-                        max(0.0, now - slots[i].token_times[-1]), at=now)
+                # telemetry on: a decoding request has its first token,
+                # and _tok_t holds when each slot's last one was emitted
+                t = self._clock()
+                self._h_tpot.observe_many(
+                    np.maximum(0.0, now - self._tok_t[fast]), at=now)
+                self._tok_t[fast] = now
+                self._self_hist += self._clock() - t
             for i, tok in zip(at, got.tolist()):
                 req = slots[i]
                 req.out.append(tok)
@@ -1945,10 +1999,9 @@ class ServingEngine:
         if self.costs.enabled:
             # one fused dispatch: each live slot produced its own token
             # count over its own pre-advance context
-            self.costs.charge_batched(
-                "decode",
-                [(self.slots[i], int(produced[i]),
-                  int(self.cache.lengths[i])) for i in live])
+            at = np.asarray(live)
+            self.costs.charge_batched("decode", at, produced[at],
+                                      self.cache.lengths[at])
         ticks = 1
         prod_by_slot = {}
         for i in live:
@@ -2060,9 +2113,8 @@ class ServingEngine:
             # the verify program scores all G chunk positions per live
             # slot whatever gets accepted — the compute is spent either
             # way, so attribution bills the full chunk
-            self.costs.charge_batched(
-                "verify", [(self.slots[i], G, int(self.cache.lengths[i]))
-                           for i in live])
+            self.costs.charge_batched("verify", np.asarray(live), G,
+                                      self.cache.lengths[live])
         # the target's greedy choice at every chunk position — the SAME
         # fp32-cast device argmax the fused sampler's greedy lane takes,
         # so accepted tokens are bit-identical to what plain decode
@@ -2287,23 +2339,58 @@ class ServingEngine:
         ``caller`` (what lay outside every ``serve.step``: the loop that
         calls ``step``) and ``sched`` (the rest: a retried attempt and
         its backoff with it). A gap after an empty engine is a pause:
-        it goes to ``serving_engine_empty_seconds_total`` alone."""
+        it goes to ``serving_engine_empty_seconds_total`` alone.
+
+        ``self_us`` beside ``gap_us`` is the part of THIS gap that the
+        telemetry plane spent on its own account, which an engine with
+        telemetry off would not have: the sum of what was stamped since
+        the previous dispatch's wait returned, on the same clock, by
+        part (``self_parts``, microseconds, in the order of
+        telemetry/tracer.py ``SELF_PARTS``):
+        ``spans`` (inside ``_Span.__enter__`` before ``t0`` and inside
+        ``__exit__`` after ``t1``), ``accountant`` (the cost charges and
+        folds), ``histograms`` (the step's observes, and this method),
+        ``counts`` (``_span_counts``, the decode step's ``kv_tokens`` and
+        sampled tiles), ``gauges`` (``_sample_gauges`` on its cadence)
+        and ``hidden``: the enqueue span's exit and the wait span's
+        entry, which ran under this dispatch's program and lie in no
+        gap, so ``self_us`` leaves them out and ``self_us <= gap_us``.
+        Not stamped, so in neither: the keyword arguments a ``span(...)``
+        call evaluates, the ``if telemetry`` tests, the per-token path's
+        observes (docs/OBSERVABILITY.md "Overhead" has their size)."""
+        tracer = self.telemetry.tracer
+        t = self._clock()
         prev_t, self._gap_prev_t = self._gap_prev_t, wait.t1
         caller, self._gap_caller = self._gap_caller, 0.0
         empty, self._gap_empty = self._gap_empty, False
         self._gap_prev_site = site
         self._c_wait.inc(wait.dur)
-        if prev_t is None:
-            return
-        gap = enqueue.t1 - prev_t
-        dispatch.set(gap_us=round(gap * 1e6), caller_us=round(caller * 1e6))
-        if empty:
-            self._c_empty.inc(gap)
-            return
-        self._h_gap.observe(gap)
-        self._c_gap_enqueue.inc(enqueue.dur)
-        self._c_gap_caller.inc(caller)
-        self._c_gap_sched.inc(gap - enqueue.dur - caller)
+        # the wait span's exit came after wait.t1: it is the next gap's
+        hidden = enqueue.o1 + wait.o0
+        spans = tracer.span_self - hidden - wait.o1
+        acct, hist = self.costs.self_s, self._self_hist
+        counts, gauges = self._self_counts, self._self_gauges
+        tracer.span_self, self.costs.self_s = wait.o1, 0.0
+        self._self_hist = self._self_counts = self._self_gauges = 0.0
+        own = spans + acct + hist + counts + gauges
+        self._c_self.inc(own + hidden)
+        if prev_t is not None:
+            gap = enqueue.t1 - prev_t
+            gap_us = round(gap * 1e6)
+            dispatch.set(
+                gap_us=gap_us, caller_us=round(caller * 1e6),
+                # the parts lie inside the gap; min() is for the rounding
+                self_us=min(round(own * 1e6), gap_us),
+                self_parts=(spans * 1e6, acct * 1e6, hist * 1e6,
+                            counts * 1e6, gauges * 1e6, hidden * 1e6))
+            if empty:
+                self._c_empty.inc(gap)
+            else:
+                self._h_gap.observe(gap)
+                self._c_gap_enqueue.inc(enqueue.dur)
+                self._c_gap_caller.inc(caller)
+                self._c_gap_sched.inc(gap - enqueue.dur - caller)
+        self._self_hist += self._clock() - t
 
     def read_expert_counters(self) -> Dict[str, Dict[str, float]]:
         """Pull the expert-share layers' counters from the device (they
@@ -2361,6 +2448,7 @@ class ServingEngine:
         """Sampled-step gauge refresh: HBM block states + prefix hit
         rate. Host numpy reductions — cheap, but they run every
         ``telemetry.sample_every``-th step, not every step."""
+        t = self._clock()
         self._g_held.set(int(self.cache.held_blocks))
         self._g_cached.set(int(self.cache.cached_blocks))
         self._g_free.set(int(self.cache.free_blocks))
@@ -2376,6 +2464,7 @@ class ServingEngine:
             step = jax.device_get(jnp.maximum(  # dslint: disable=DS001 — sampled-cadence pull, mirrors the gauge refresh above
                 jnp.max(self.cache.k_scale), jnp.max(self.cache.v_scale)))
             self._h_kv_err.observe(float(step) / 2.0)
+        self._self_gauges += self._clock() - t
 
     def _degraded(self, message: str) -> DegradedError:
         # the flight recorder fires BEFORE the error leaves the engine:
@@ -2436,12 +2525,17 @@ class ServingEngine:
         self._slow[slot] = decoding and self._slow_emit(slot, req)
         self._last_tok[slot] = req.out[-1] if decoding else 0
         self._gen[slot] = len(req.out) if decoding else 0
+        if decoding and self._tok_t is not None and req.token_times:
+            self._tok_t[slot] = req.token_times[-1]
 
     def _vacate(self, slot: int, req: ServeRequest) -> None:
         """``req`` gives ``slot`` up (finished, preempted, handed off or
         drained): its adapter pin, blocks and sampling lane go back and
-        the slot's arrays read as an empty slot's. The caller has set
-        the state ``req`` leaves in."""
+        the slot's arrays read as an empty slot's (with cost accounting
+        on, what the slot accrued is folded onto ``req`` first). The
+        caller has set the state ``req`` leaves in."""
+        if self.costs.enabled:
+            self.costs.fold(slot, req)
         self._release_adapter(slot, req)
         self.cache.free(slot)
         self.slots[slot] = None
@@ -2513,6 +2607,7 @@ class ServingEngine:
                 "first_token", rid=req.rid, step=self._step_clock, slot=slot)
         elif self._h_tpot is not None and prev is not None:
             self._h_tpot.observe(max(0.0, now - prev), at=now)
+            self._tok_t[slot] = now
         if req.stop:
             for s in req.stop:
                 ls = len(s)

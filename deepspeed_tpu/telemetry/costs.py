@@ -25,20 +25,26 @@ per request, and per tenant. Three pieces:
 - :class:`CostAccountant` — exact integer per-dispatch charges rolled
   into global ``serving_flops_total``/``serving_hbm_bytes_total``/
   ``serving_kv_block_seconds`` counters AND per-request footprints,
-  with tenant rollup keyed by ``adapter_id``. Charges are computed
-  per live slot and summed into the globals from the *same* integers,
-  so conservation (sum of footprints == global counters, per dispatch
-  class) holds exactly by construction.
+  with tenant rollup keyed by ``adapter_id``. A batched dispatch is
+  charged as array operations over its live slots into per-slot
+  ``int64`` accumulators, and the globals take the sums of the *same*
+  integers, so conservation (sum of footprints == global counters, per
+  dispatch class) holds exactly by construction; a slot's accumulators
+  fold into its request where the slot is vacated and at every view
+  (:meth:`CostAccountant.flush`).
 
-Everything here is host-side arithmetic on python ints — no jax calls
-on the charge path, no device sync, zero new compiled programs
-(``CompileWatch(0)`` holds with the plane on).
+Everything here is host-side integer arithmetic (python ints and numpy
+``int64``) — no jax calls on the charge path, no device sync, zero new
+compiled programs (``CompileWatch(0)`` holds with the plane on).
 """
 
 import json
 import math
 import re
-from typing import Dict, List, Optional, Sequence, Tuple
+import time
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
+
+import numpy as np
 
 from deepspeed_tpu.utils.jit_registry import (DISPATCH_CLASSES,
                                               engine_programs)
@@ -133,6 +139,26 @@ def model_flops_per_token(cfg, include_head: bool = True) -> int:
     return 2 * matmul_params(cfg, include_head)
 
 
+def _ctx_sum(n, s):
+    """Keys attended by ``n`` consecutive tokens from position ``s``:
+    the sum of ``s + i + 1`` over them. Ints, or integer arrays of one
+    entry a slot (the accountant's batched charge)."""
+    return n * s + (n * (n + 1)) // 2
+
+
+def _flops(cfg, n, ctx, flops_tok: int):
+    """Forward FLOPs of ``n`` tokens that attend ``ctx`` keys in all
+    (:func:`_ctx_sum`): linear in both, so it holds for one charge and
+    for the sums of many."""
+    return n * flops_tok + 4 * cfg.n_layers * cfg.d_model * ctx
+
+
+def _kv_bytes(kv_bytes_tok: int, n, ctx):
+    """KV-cache bytes of the same tokens: each streams the cache up to
+    its position (``ctx`` rows read) and writes its own row."""
+    return kv_bytes_tok * (ctx + n)
+
+
 def attn_flops(cfg, n_tokens: int, start_pos: int) -> int:
     """Forward attention-score FLOPs for ``n_tokens`` consecutive
     tokens starting at absolute position ``start_pos``: the token at
@@ -140,9 +166,7 @@ def attn_flops(cfg, n_tokens: int, start_pos: int) -> int:
     ``2 * d_model`` FLOPs per (query, key) pair per layer — the
     inference-shape refinement of the training formula's
     ``12 * L * d * s`` (which is 3x fwd at full context)."""
-    n, s = int(n_tokens), int(start_pos)
-    ctx_sum = n * s + (n * (n + 1)) // 2     # sum of (s + i + 1)
-    return 4 * cfg.n_layers * cfg.d_model * ctx_sum
+    return _flops(cfg, 0, _ctx_sum(int(n_tokens), int(start_pos)), 0)
 
 
 def infer_flops(cfg, n_tokens: int, start_pos: int,
@@ -150,8 +174,9 @@ def infer_flops(cfg, n_tokens: int, start_pos: int,
     """Total forward FLOPs to process ``n_tokens`` new tokens of one
     sequence whose cache already holds ``start_pos`` tokens — linear
     (weight matmul) plus causal attention. Exact integer."""
-    return (int(n_tokens) * model_flops_per_token(cfg, include_head)
-            + attn_flops(cfg, n_tokens, start_pos))
+    n = int(n_tokens)
+    return _flops(cfg, n, _ctx_sum(n, int(start_pos)),
+                  model_flops_per_token(cfg, include_head))
 
 
 def weight_bytes(cfg, param_itemsize: int = 2) -> int:
@@ -169,9 +194,8 @@ def infer_hbm_bytes(cfg, n_tokens: int, start_pos: int,
     position) plus KV writes for the new tokens, plus optionally one
     full weight read (callers split the weight read across the live
     slots of a batched dispatch — see :func:`split_even`)."""
-    n, s = int(n_tokens), int(start_pos)
-    ctx_sum = n * s + (n * (n + 1)) // 2
-    kv = int(kv_bytes_tok) * (ctx_sum + n)    # reads + writes
+    n = int(n_tokens)
+    kv = _kv_bytes(int(kv_bytes_tok), n, _ctx_sum(n, int(start_pos)))
     return kv + (weight_bytes(cfg, param_itemsize) if include_weights
                  else 0)
 
@@ -720,28 +744,65 @@ def probe_compiled(compiled) -> Dict:
 # per-dispatch accountant
 # --------------------------------------------------------------------------
 
+# the dispatch classes that charge_batched charges: a slot's share of
+# them accrues in the per-slot accumulators; the others (prefill, cow,
+# spill) are one call a dispatch and land on the request at once
+BATCHED_CLASSES: Tuple[str, ...] = ("decode", "verify")
+# what a slot accrues of a batched class: tokens processed, keys they
+# attended (_ctx_sum), dispatches, and its shares of the weight reads.
+# FLOPs and bytes are linear in them (_flops, _kv_bytes), so a slot's
+# footprint is computed where it is folded, not once a dispatch
+_ACCRUED = ("tokens", "ctx", "dispatches", "weight_bytes")
+
+
 class CostAccountant:
     """Exact integer attribution of dispatch costs.
 
     One instance per :class:`ServingEngine`. Every charge computes the
-    cost per live slot (each slot's own token count and cache context),
-    adds the integers to that request's footprint AND the same integers
-    to the global per-class totals — so the conservation invariant
+    cost per live slot (each slot's own token count and cache context)
+    and adds the same integers to the global per-class totals — so the
+    conservation invariant
 
         sum(per-request footprints) + system footprint == globals
 
     holds exactly per dispatch class, with no float rounding and no
-    remainder leakage (:func:`split_even` handles shared costs such as
-    the per-dispatch weight read). Costs with no owning request (spill
-    of refcount-zero blocks) land in ``self.system``. When a metrics
-    registry is supplied the cross-class totals also feed the
+    remainder leakage (:func:`split_even`'s rule handles shared costs
+    such as the per-dispatch weight read). Costs with no owning request
+    (spill of refcount-zero blocks) land in ``self.system``. When a
+    metrics registry is supplied the cross-class totals also feed the
     ``serving_flops_total``/``serving_hbm_bytes_total``/
-    ``serving_kv_block_seconds`` counters."""
+    ``serving_kv_block_seconds`` counters.
+
+    A batched dispatch and the block-seconds of a step are charged as a
+    few array operations, whatever the number of slots, into per-slot
+    ``int64`` accumulators beside ``cache.lengths`` (``slots`` is the
+    engine's own list of seated requests, one entry a slot). FLOPs and
+    bytes are linear in what a slot accrues (its tokens, the keys they
+    attended, its dispatches and weight-read shares), so the totals
+    take the dispatch's sums at once and a slot's own FLOPs and bytes
+    are computed where its accumulators are folded into its request's
+    ``cost`` and its tenant's footprint: where the slot is vacated
+    (:meth:`fold`) and by :meth:`flush`, which every view calls first
+    (:meth:`snapshot`, :attr:`tenants`; the engine's
+    ``pending_snapshot`` and flight rows): ``req.cost`` of a request
+    STILL SEATED is current after a view and not between two. The
+    totals and the registry's counters are current after every charge.
+    An accumulator holds one request's stay in one slot: at most
+    ``max_seq_len`` tokens, ``max_seq_len ** 2`` keys, and a dispatch a
+    token of at most the whole weight read each (1e6 dispatches of
+    2e11 bytes are 2e17), all far under 2**63; the totals and the
+    footprints are python ints and cannot overflow.
+
+    ``self_s`` is the host seconds spent inside the charges and the
+    folds since it was last taken, on ``clock``: the ``accountant`` part
+    of a dispatch's ``self_us`` (inference/serving.py ``_account_gap``)."""
 
     enabled = True
 
     def __init__(self, cfg, kv_bytes_tok: int, block_bytes: int,
-                 param_itemsize: int = 2, registry=None):
+                 param_itemsize: int = 2, registry=None,
+                 slots: Optional[List] = None,
+                 clock: Callable[[], float] = time.perf_counter):
         self.cfg = cfg
         self.kv_bytes_tok = int(kv_bytes_tok)
         self.block_bytes = int(block_bytes)
@@ -752,7 +813,14 @@ class CostAccountant:
                        for cls in DISPATCH_CLASSES}
         self.block_seconds_total = 0
         self.system = new_footprint()
-        self.tenants: Dict[str, Dict] = {}
+        self._tenants: Dict[str, Dict] = {}
+        self._slots = slots if slots is not None else []
+        n = len(self._slots)
+        self._acc = {cls: {k: np.zeros((n,), np.int64) for k in _ACCRUED}
+                     for cls in BATCHED_CLASSES}
+        self._acc_bs = np.zeros((n,), np.int64)
+        self._clock = clock
+        self.self_s = 0.0
         self._c_flops = self._c_bytes = self._c_blocks = None
         if registry is not None:
             self._c_flops = registry.counter(
@@ -770,59 +838,74 @@ class CostAccountant:
 
     def _tenant(self, req) -> Dict:
         key = getattr(req, "adapter_id", None) or "base"
-        t = self.tenants.get(key)
+        t = self._tenants.get(key)
         if t is None:
-            t = self.tenants[key] = new_footprint()
+            t = self._tenants[key] = new_footprint()
         return t
 
     def _add(self, cls: str, req, flops: int, nbytes: int,
              dispatches: int = 0) -> None:
+        # system charges roll up under a reserved tenant
+        tenant = self._tenant(req) if req is not None \
+            else self._tenants.setdefault("system", new_footprint())
         for fp in ((req.cost if req is not None else self.system),
-                   self.totals):
+                   self.totals, tenant):
             slot = fp[cls]
             slot["flops"] += flops
             slot["hbm_bytes"] += nbytes
             slot["dispatches"] += dispatches
-        if req is not None:
-            t = self._tenant(req)[cls]
-            t["flops"] += flops
-            t["hbm_bytes"] += nbytes
-            t["dispatches"] += dispatches
-        else:
-            # system charges roll up under a reserved tenant
-            t = self.tenants.setdefault("system", new_footprint())[cls]
-            t["flops"] += flops
-            t["hbm_bytes"] += nbytes
-            t["dispatches"] += dispatches
         if self._c_flops is not None:
             self._c_flops.inc(flops)
             self._c_bytes.inc(nbytes)
 
-    # .. charge API (serving hot loop — host ints only) .................
+    # .. charge API (serving hot loop — host integers only) .............
 
     def charge_prefill(self, req, n_tokens: int, start_pos: int) -> None:
         """One prefill-chunk dispatch: single slot owns the whole cost,
         weight read included."""
-        flops = infer_flops(self.cfg, n_tokens, start_pos)
-        nbytes = infer_hbm_bytes(self.cfg, n_tokens, start_pos,
-                                 self.kv_bytes_tok, self.param_itemsize)
-        self._add("prefill", req, flops, nbytes, dispatches=1)
+        t0 = self._clock()
+        n = int(n_tokens)
+        ctx = _ctx_sum(n, int(start_pos))
+        self._add("prefill", req, _flops(self.cfg, n, ctx, self._flops_tok),
+                  _kv_bytes(self.kv_bytes_tok, n, ctx) + self._weight_bytes,
+                  dispatches=1)
+        self.self_s += self._clock() - t0
 
-    def charge_batched(self, cls: str, items) -> None:
-        """One batched dispatch (decode/horizon/verify): ``items`` is a
-        sequence of ``(req, n_tokens, start_pos)`` per live slot. Each
-        slot is charged its own KV/attention cost; the single weight
-        read is split exactly across the live slots."""
-        items = list(items)
-        if not items:
+    def charge_batched(self, cls: str, slots, n_tokens, start_pos) -> None:
+        """One batched dispatch (decode/horizon/verify) over the live
+        ``slots`` (an index array): slot ``slots[j]`` processed
+        ``n_tokens[j]`` tokens (or the one int, all of them) over the
+        ``start_pos[j]`` its cache held (an integer array). Each slot
+        is charged its own KV/attention cost; the single weight read
+        is split exactly across the live slots as :func:`split_even`
+        does, the first ``weights % live`` of them a byte more."""
+        k = len(slots)
+        if not k:
             return
-        shares = split_even(self._weight_bytes, len(items))
-        for (req, n, s), wshare in zip(items, shares):
-            flops = infer_flops(self.cfg, n, s)
-            nbytes = infer_hbm_bytes(self.cfg, n, s, self.kv_bytes_tok,
-                                     self.param_itemsize,
-                                     include_weights=False) + wshare
-            self._add(cls, req, flops, nbytes, dispatches=1)
+        t0 = self._clock()
+        acc = self._acc[cls]
+        ctx = _ctx_sum(n_tokens, start_pos)
+        acc["ctx"][slots] += ctx
+        acc["tokens"][slots] += n_tokens
+        acc["dispatches"][slots] += 1
+        base, rem = divmod(self._weight_bytes, k)
+        acc["weight_bytes"][slots] += base
+        if rem:
+            acc["weight_bytes"][slots[:rem]] += 1
+        # the dispatch's sums, python ints from here on
+        n = int(n_tokens.sum()) if isinstance(n_tokens, np.ndarray) \
+            else int(n_tokens) * k
+        ctx = int(ctx.sum())
+        flops = _flops(self.cfg, n, ctx, self._flops_tok)
+        nbytes = _kv_bytes(self.kv_bytes_tok, n, ctx) + self._weight_bytes
+        tot = self.totals[cls]
+        tot["flops"] += flops
+        tot["hbm_bytes"] += nbytes
+        tot["dispatches"] += k
+        if self._c_flops is not None:
+            self._c_flops.inc(flops)
+            self._c_bytes.inc(nbytes)
+        self.self_s += self._clock() - t0
 
     def charge_cow(self, req, n_blocks: int) -> None:
         """Copy-on-write block copies triggered by ``req``: read+write
@@ -842,22 +925,71 @@ class CostAccountant:
         self._add("spill", req, 0, self.block_bytes * int(n_blocks),
                   dispatches=int(n_blocks))
 
-    def charge_block_seconds(self, req, blocks: int, ticks: int) -> None:
-        """KV residency integrated at a horizon boundary: ``blocks``
-        held for ``ticks`` scheduler-clock units."""
-        bs = int(blocks) * int(ticks)
-        if bs <= 0:
-            return
-        req.cost["block_seconds"] += bs
-        self._tenant(req)["block_seconds"] += bs
-        self.block_seconds_total += bs
-        if self._c_blocks is not None:
-            self._c_blocks.inc(bs)
+    def charge_block_seconds(self, held, lengths, block_size: int,
+                             ticks: int) -> None:
+        """KV residency integrated at a horizon boundary, one call a
+        step: every slot of the mask ``held`` is billed the blocks its
+        entry of ``lengths`` (the cache's, an array over all slots)
+        takes, for ``ticks`` scheduler-clock units."""
+        t0 = self._clock()
+        bs = (lengths + (block_size - 1)) // block_size * held
+        if ticks != 1:
+            bs *= int(ticks)
+        total = int(bs.sum())
+        if total > 0:
+            self._acc_bs += bs
+            self.block_seconds_total += total
+            if self._c_blocks is not None:
+                self._c_blocks.inc(total)
+        self.self_s += self._clock() - t0
+
+    # .. folding ........................................................
+
+    def fold(self, slot: int, req) -> None:
+        """Move what ``slot`` accrued onto ``req`` (its footprint and
+        its tenant's) and zero it: where the slot is vacated, and for
+        every seated slot by :meth:`flush`."""
+        t0 = self._clock()
+        for cls, acc in self._acc.items():
+            d = int(acc["dispatches"][slot])
+            if d:
+                n, ctx = int(acc["tokens"][slot]), int(acc["ctx"][slot])
+                flops = _flops(self.cfg, n, ctx, self._flops_tok)
+                nbytes = _kv_bytes(self.kv_bytes_tok, n, ctx) \
+                    + int(acc["weight_bytes"][slot])
+                for fp in (req.cost[cls], self._tenant(req)[cls]):
+                    fp["flops"] += flops
+                    fp["hbm_bytes"] += nbytes
+                    fp["dispatches"] += d
+                for k in _ACCRUED:
+                    acc[k][slot] = 0
+        bs = int(self._acc_bs[slot])
+        if bs:
+            req.cost["block_seconds"] += bs
+            self._tenant(req)["block_seconds"] += bs
+            self._acc_bs[slot] = 0
+        self.self_s += self._clock() - t0
+
+    def flush(self) -> None:
+        """Fold every seated slot's accumulators onto its request, so
+        that ``req.cost`` and the tenants read what has been charged:
+        every view calls it first."""
+        for slot, req in enumerate(self._slots):
+            if req is not None:
+                self.fold(slot, req)
 
     # .. views ..........................................................
 
+    @property
+    def tenants(self) -> Dict[str, Dict]:
+        """Footprint per tenant (``adapter_id``, ``"base"`` without
+        one, ``"system"`` for unowned charges), current: a view."""
+        self.flush()
+        return self._tenants
+
     def snapshot(self) -> Dict:
         """Plain-data dump for flight recorder / bench rows."""
+        tenants = self.tenants
         return {
             "totals": {cls: dict(v) for cls, v in self.totals.items()},
             "flops_total": sum(v["flops"] for v in self.totals.values()),
@@ -868,7 +1000,7 @@ class CostAccountant:
                        for cls in DISPATCH_CLASSES}
             | {"block_seconds": self.system["block_seconds"]},
             "tenants": {k: merge_footprints([v])
-                        for k, v in sorted(self.tenants.items())},
+                        for k, v in sorted(tenants.items())},
         }
 
 
@@ -884,7 +1016,7 @@ class NoopCostAccountant:
     def charge_prefill(self, req, n_tokens, start_pos):
         pass
 
-    def charge_batched(self, cls, items):
+    def charge_batched(self, cls, slots, n_tokens, start_pos):
         pass
 
     def charge_cow(self, req, n_blocks):
@@ -893,7 +1025,10 @@ class NoopCostAccountant:
     def charge_spill(self, n_blocks, req=None, restore=False):
         pass
 
-    def charge_block_seconds(self, req, blocks, ticks):
+    def charge_block_seconds(self, held, lengths, block_size, ticks):
+        pass
+
+    def flush(self):
         pass
 
     def snapshot(self) -> Dict:

@@ -11,7 +11,9 @@ share one scalar sink.
 Design constraints (docs/OBSERVABILITY.md):
 
 - **host-side only** — observing a value is a dict lookup plus an int
-  add; nothing here touches jax, so the registry can sit inside the
+  add (``observe_many``: one ``searchsorted`` and one ``bincount`` for
+  a step's worth of values); nothing here touches jax, so the registry
+  can sit inside the
   scheduler hot loop without violating the dslint DS001 contract;
 - **fixed buckets** — histograms bucket at observe time into
   preallocated cumulative-friendly counts (no per-observation
@@ -26,7 +28,10 @@ Design constraints (docs/OBSERVABILITY.md):
 
 from bisect import bisect_left
 from collections import deque
+from itertools import repeat
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+
+import numpy as np
 
 # log-ish ladder covering sub-millisecond wall clocks AND integer step
 # clocks: 1-2.5-5 decades from 100us to 250 units
@@ -96,7 +101,7 @@ class Histogram:
     observe; it never feeds the Prometheus exposition, which stays
     cumulative-only."""
     __slots__ = ("name", "help", "uppers", "counts", "sum", "count",
-                 "_vmax", "_ring", "_seq")
+                 "_vmax", "_ring", "_seq", "_uppers_arr")
 
     #: default ring depth — enough for a few windows of serving traffic
     #: without unbounded growth (SLO windows are tens of observations)
@@ -112,6 +117,7 @@ class Histogram:
         if not ups:
             raise ValueError(f"histogram {name}: needs >= 1 finite bucket")
         self.uppers = ups
+        self._uppers_arr = np.asarray(ups, np.float64)
         self.counts = [0] * (len(ups) + 1)   # [+ overflow]
         self.sum = 0.0
         self.count = 0
@@ -133,6 +139,28 @@ class Histogram:
             self._vmax = v
         self._ring.append((self._seq if at is None else float(at), v))
         self._seq += 1
+
+    def observe_many(self, values, at: Optional[float] = None) -> None:
+        """Record an array of observations made at one instant ``at``
+        (a step's TPOT of every slot), in order: buckets, ``count``,
+        the largest value and the ring as after ``observe`` of each
+        (``sum`` to float rounding: one addition of the array's sum),
+        for a constant number of calls whatever the array's length."""
+        values = np.asarray(values, np.float64)
+        n = values.size
+        if not n:
+            return
+        hit = np.bincount(self._uppers_arr.searchsorted(values),
+                          minlength=len(self.counts))
+        for i in np.flatnonzero(hit).tolist():
+            self.counts[i] += int(hit[i])
+        self.sum += float(values.sum())
+        self.count += n
+        self._vmax = max(self._vmax, float(values.max()))
+        ats = range(self._seq, self._seq + n) if at is None \
+            else repeat(float(at))
+        self._ring.extend(zip(ats, values.tolist()))
+        self._seq += n
 
     def window_values(self, window: Optional[float] = None,
                       now: Optional[float] = None) -> List[float]:

@@ -40,6 +40,14 @@ beside the counts given at construction, so a reader of the trace finds
 an event's ring record, and with it the counts ``set()`` at exit. A span's self time is its duration minus its
 children's (:func:`span_self_times`); the Chrome export draws spans as
 nested slices on the scheduler lane.
+
+What a span costs the host it measures is stamped too: ``__enter__`` reads
+the clock on its first line as well as on its last (``t0``), ``__exit__``
+on its last as well as on its first (``t1``), and the two differences (the
+ring slot, the annotation's enter and exit: ``o0`` and ``o1`` on the span)
+add up in ``RequestTracer.span_self``, which the serving scheduler takes
+at every dispatch (``self_us`` / ``self_parts`` on ``serve.dispatch``,
+inference/serving.py ``_account_gap``).
 """
 
 import json
@@ -51,6 +59,13 @@ import jax
 # record layout: (ts, etype, rid, step, slot, data-dict-or-None); a span
 # record carries three more fields: (..., end_ts, span_id, parent_id)
 _TS, _ETYPE, _RID, _STEP, _SLOT, _DATA, _END, _SID, _PARENT = range(9)
+
+# the order of ``self_parts`` on a ``serve.dispatch`` ring record: what the
+# telemetry plane stamped of its own host time since the dispatch before
+# (inference/serving.py ``_account_gap``); ``self_us`` is their sum less
+# the last, which ran under a running program
+SELF_PARTS = ("spans", "accountant", "histograms", "counts", "gauges",
+              "hidden")
 
 # lifecycle phases, in the order a healthy request traverses them
 SPAN_QUEUED = "queued"
@@ -79,12 +94,12 @@ class _Span:
     only at the end (tokens emitted, bytes pulled)."""
 
     __slots__ = ("_tr", "_ann", "_idx", "name", "rid", "step", "slot",
-                 "counts", "sid", "parent", "t0", "t1")
+                 "counts", "sid", "parent", "t0", "t1", "o0", "o1")
 
     def __init__(self, tracer, name, rid, step, slot, counts):
         self._tr, self.name, self.rid = tracer, name, rid
         self.step, self.slot, self.counts = step, slot, counts
-        self.t0 = self.t1 = 0.0
+        self.t0 = self.t1 = self.o0 = self.o1 = 0.0
 
     def set(self, **counts) -> None:
         self.counts.update(counts)
@@ -95,6 +110,7 @@ class _Span:
 
     def __enter__(self):
         tr = self._tr
+        c0 = tr._clock()
         tr._last_sid += 1
         self.sid = tr._last_sid
         self.parent = tr._open[-1] if tr._open else 0
@@ -106,6 +122,8 @@ class _Span:
                                                  **self.counts)
         self._ann.__enter__()
         self.t0 = tr._clock()
+        self.o0 = self.t0 - c0
+        tr.span_self += self.o0
         return self
 
     def __exit__(self, *exc):
@@ -119,6 +137,8 @@ class _Span:
             tr._buf[self._idx % tr.capacity] = (
                 self.t0, self.name, self.rid, self.step, self.slot,
                 self.counts or None, self.t1, self.sid, self.parent)
+        self.o1 = tr._clock() - self.t1
+        tr.span_self += self.o1
         return False
 
 
@@ -158,6 +178,9 @@ class RequestTracer:
         self._n = 0          # total records ever written
         self._last_sid = 0   # span ids start at 1; parent 0 = no parent
         self._open: List[int] = []   # ids of the spans open now
+        # seconds inside _Span.__enter__ before t0 and inside __exit__
+        # after t1 since the scheduler last took them (_account_gap)
+        self.span_self = 0.0
 
     # -- recording (hot path) ------------------------------------------
     def event(self, etype: str, rid: Any = None, step: int = -1,
@@ -200,6 +223,7 @@ class RequestTracer:
         self._buf = [None] * self.capacity
         self._n = 0
         self._open = []
+        self.span_self = 0.0
 
     # -- export --------------------------------------------------------
     def to_chrome_trace(self) -> Dict:
@@ -257,13 +281,17 @@ class RequestTracer:
             data = data or {}
             if is_span(rec):
                 a = {"step": step, "span_id": rec[_SID],
-                     "parent_id": rec[_PARENT],
-                     "self_us": round(selfs[rec[_SID]] * 1e6, 3)}
+                     "parent_id": rec[_PARENT]}
                 if rid is not None:
                     a["rid"] = str(rid)
                 if slot >= 0:
                     a["slot"] = slot
                 a.update(data)
+                if "self_us" in data:
+                    # a dispatch's count of the plane's own host time;
+                    # ``self_us`` stays the span's self time here
+                    a["plane_self_us"] = data["self_us"]
+                a["self_us"] = round(selfs[rec[_SID]] * 1e6, 3)
                 events.append({"ph": "X", "pid": 1, "tid": 0,
                                "cat": "span", "name": etype,
                                "ts": us(ts),

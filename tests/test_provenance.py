@@ -153,7 +153,7 @@ def test_kernel_programs_say_how_the_paged_grid_is_cut(devices,
     r = np.random.default_rng(1)
     reqs = [ServeRequest(rid=i, prompt=r.integers(1, 128, n).astype(np.int32),
                          max_new_tokens=4) for i, n in enumerate((9, 5))]
-    tel = Telemetry()
+    tel = Telemetry(sample_every=1)     # kv_steps rides the sampled steps
     srv = ServingEngine(_tiny_engine(), num_slots=2, block_size=4,
                         num_blocks=24, prefill_chunk=8, telemetry=tel,
                         decode_impl="pallas")
