@@ -13,7 +13,7 @@ from typing import Callable, NamedTuple, Optional
 import jax.numpy as jnp
 
 from deepspeed_tpu.models.gpt import kv_bytes_per_token
-from deepspeed_tpu.ops.attention.paged import decode_plan
+from deepspeed_tpu.ops.attention.paged import decode_plan, pool_row_bytes
 
 
 class SlotBytes(NamedTuple):
@@ -48,6 +48,10 @@ class Dialect(NamedTuple):
     slot_bytes: Callable = lambda cfg, block_size, dtype: SlotBytes()
     # entries behind the blocks of a table row
     ring_blocks: Callable = lambda cfg, block_size: 0
+    # (cfg, pool) -> a pool row's bytes, by which the paged_decode kernel
+    # cuts its tile (paged.blocks_per_step); None where the rows are latents
+    # and mla_decode attends them in its own tile
+    tile_row_bytes: Callable = lambda cfg, pool: pool_row_bytes(pool)
     # latent flash steps of a prefill chunk at start
     flash_steps: Callable = lambda cfg, start, bs: 0
     needs_slot: bool = False  # the prefill finds its state by slot index
@@ -102,8 +106,11 @@ def full_layers_kv_bytes(cfg, dtype=jnp.bfloat16) -> int:
 
 
 def rows_plan(cfg, pools, tables, lengths, active):
-    """The paged kernel's grid over a K-side state's ``rows``."""
-    return decode_plan(lengths, tables.shape[1], pools[0].rows.shape[2],
+    """The decode kernel's grid over a K-side state's ``rows``, in the tile
+    of the kernel that attends them: the dialect says which."""
+    rows = pools[0].rows
+    return decode_plan(lengths, tables.shape[1], rows.shape[2],
+                       row_bytes=of(cfg).tile_row_bytes(cfg, rows),
                        active=active)
 
 

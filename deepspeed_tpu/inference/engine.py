@@ -40,7 +40,8 @@ from deepspeed_tpu.moe import expert_share
 from deepspeed_tpu.ops import quantizer
 from deepspeed_tpu.ops.attention.paged import (blocks_per_step,
                                                decode_plan,
-                                               gather_pool_blocks)
+                                               gather_pool_blocks,
+                                               pool_row_bytes)
 from deepspeed_tpu.models.gpt import (GPTConfig, _dense,
                                       _norm, _qkv_split_rotary)
 from deepspeed_tpu.parallel import mesh as mesh_lib
@@ -461,6 +462,7 @@ def _paged_plan(pools, tables, lengths, active, cfg, q_len: int = 1):
     in it. A few integer operations that the compiler drops from a
     program on the gather path."""
     return decode_plan(lengths, tables.shape[1], pools[0].shape[2],
+                       row_bytes=pool_row_bytes(pools[0]),
                        window=cfg.attn_window, q_len=q_len, active=active)
 
 
@@ -1629,12 +1631,14 @@ class InferenceEngine:
                 lora)
         sink = self.provenance
         if sink is not None and pid not in sink.provenance:
-            L, N, bs = self.dialect.pool(k_pool).shape[:3]
+            pool = self.dialect.pool(k_pool)
+            L, N, bs = pool.shape[:3]
             grid = ()
             if kernel_table:
                 B, nb = kernel_table
                 nb -= self.dialect.ring_blocks(self.cfg, bs)
-                per_step = blocks_per_step(nb, bs)
+                per_step = blocks_per_step(
+                    nb, bs, self.dialect.tile_row_bytes(self.cfg, pool))
                 grid = (per_step, B * -(-nb // per_step))
             copied = sink.add_provenance(
                 pid, program.lower(*args).compile().as_text(),

@@ -348,16 +348,21 @@ def _ring_span(cfg, bs: int, lengths):
     return lo, lengths - lo * bs
 
 
-def decode_plans(cfg, bs: int, tables, lengths, active):
+def decode_plans(cfg, pool, tables, lengths, active):
     """The paged kernel's two grids of one decode dispatch (ops/attention/
     paged.py ``decode_plan``), worked out once outside the layer loop: the
     full layers' over the table, the window layers' over the ring in
-    logical order, both from the slots that are ``active``."""
-    from deepspeed_tpu.ops.attention.paged import decode_plan
+    logical order, both from the slots that are ``active`` and both in
+    tiles of what a row of ``pool`` (the full layers' K; the rings' rows
+    are the same) weighs."""
+    from deepspeed_tpu.ops.attention.paged import decode_plan, pool_row_bytes
+    bs, row = pool.shape[2], pool_row_bytes(pool)
     RB = window_blocks(cfg, bs)
-    return (decode_plan(lengths, tables.shape[1] - RB, bs, active=active),
+    return (decode_plan(lengths, tables.shape[1] - RB, bs, row_bytes=row,
+                        active=active),
             decode_plan(_ring_span(cfg, bs, lengths)[1], RB, bs,
-                        window=cfg.attn_window, active=active))
+                        row_bytes=row, window=cfg.attn_window,
+                        active=active))
 
 
 def _decode_attend(q, k_pool, v_pool, tables, lengths, window, impl, scale,
@@ -476,7 +481,7 @@ DIALECT = dialect.Dialect(
     **dialect.carried_layers(
         block_prefill, block_decode,
         plan=lambda cfg, pools, tables, lengths, active: decode_plans(
-            cfg, pools[0].full.shape[2], tables, lengths, active),
+            cfg, pools[0].full, tables, lengths, active),
         flat=lambda pools: ((pools[0].full, pools[1].full, pools[0].win,
                              pools[1].win), pools[0].stats),
         layer_bases=lambda cfg, bufs: layer_bases(cfg, bufs[0].shape[1],

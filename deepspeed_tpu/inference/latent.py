@@ -428,6 +428,7 @@ DIALECT = dialect.Dialect(
                          "K/V heads)", "LATENT_ATTENTION"),
     state=LatentState, bytes_per_token=kv_bytes_per_token,
     flash_steps=flash_steps, gauges=gauges,
+    tile_row_bytes=lambda cfg, pool: None,
     ready_note=lambda cfg: f", latent rows a token: {cfg.n_full_layers}",
     **dialect.carried_layers(
         block_prefill, block_decode, plan=dialect.rows_plan,

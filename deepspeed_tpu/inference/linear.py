@@ -436,4 +436,6 @@ DIALECT = dialect.Dialect(
     bytes_per_token=kv_bytes_per_token, slot_bytes=slot_bytes,
     flash_steps=lambda cfg, start, bs: latent.flash_steps(cfg, start, bs)
     if _latent_rows(cfg) else 0,
+    tile_row_bytes=lambda cfg, pool: None if _latent_rows(cfg)
+    else dialect.pool_row_bytes(pool),
     needs_slot=True, gauges=gauges)

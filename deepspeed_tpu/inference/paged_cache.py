@@ -350,6 +350,8 @@ class PagedKVCache:
                 f"pool needs at least 1 allocatable block")
         self.k, self.v = d.new_state(cfg, self.num_blocks, self.block_size,
                                      self.num_slots, self.pool_dtype)
+        # what the decode kernel cuts its tile by (ServingEngine's kv_steps)
+        self.tile_row_bytes = d.tile_row_bytes(cfg, d.pool(self.k))
         if self.quantized:
             self.k_scale = jnp.zeros((cfg.n_layers, self.num_blocks, Hkv),
                                      jnp.float32)

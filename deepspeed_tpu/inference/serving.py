@@ -1789,7 +1789,8 @@ class ServingEngine:
                 tiles = tiles_run(cache.lengths, cache.blocks_per_slot,
                                   cache.block_size,
                                   None if cache.ring_blocks
-                                  else self.engine.cfg.attn_window)
+                                  else self.engine.cfg.attn_window,
+                                  row_bytes=cache.tile_row_bytes)
                 kv_steps = int(tiles[live].sum())
                 self._tiles = (kv_steps, int(tiles.sum()) - kv_steps)
             self._self_counts += self._clock() - t

@@ -111,11 +111,13 @@ def mla_decode_attention(q: jnp.ndarray, pool: jnp.ndarray,
     tables = jnp.asarray(tables, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
     nb = tables.shape[1]
+    # the token rule's tile (paged.py's byte rule is paged_decode's: a step
+    # here is saturated at a block of 512, PERF.md 6, PR 46)
     P = blocks_per_step(nb, bs)
     nt = -(-nb // P)
     if plan is None:
         plan = decode_plan(lengths, nb, bs)
-    assert plan.cut == (nb, bs, None, 1) \
+    assert plan.cut == (nb, bs, None, 1, P) \
         and plan.held.shape == (P, B * nt), (plan.cut, plan.held.shape)
 
     def qmap(w, tables_ref, held_ref, slots_ref, tiles_ref, lengths_ref):

@@ -1,5 +1,6 @@
 """Paged-attention decode kernel: Pallas TPU flash-decode through the
-block table, a tile of 128 to 256 tokens a grid step.
+block table, a tile of 128 to 256 tokens a grid step or, where a block is
+large, of the fewest blocks whose K and V make a megabyte.
 
 The pool this kernel reads is the one the paged cache stores and the
 layer loop carries: ``[N', block, Hkv*Dh]``, a token's kv heads side by
@@ -15,10 +16,15 @@ online softmax, Dao 2023):
 
 - one grid step attends a TILE of ``P`` consecutive table entries of
   one slot, ``T = P * block`` key positions; :func:`blocks_per_step`
-  works ``P`` out from the table's and the block's sizes alone (8 blocks
-  of 16; the window ring's 9 entries are one tile). A step costs what a
-  step costs whatever it does, so a step that does a sixteenth of a tile
-  spends the call on steps;
+  works ``P`` out from the table's, the block's and the pool row's sizes
+  alone (8 blocks of 16; the window ring's 9 entries are one tile; 4
+  blocks of 128 rows of 1 KiB, or of 512 rows of 256 bytes: a window
+  ring of 33 such blocks is 9 steps a slot, not 33). A step's fetch
+  does not hide what the step costs besides (0.8 us at one block of
+  256 KiB whose bytes take 0.32; 1.6 at four, whose bytes take 1.28:
+  PERF.md 6, PR 53), so a step that fetches little spends the call on
+  steps: the tile follows the BYTES, ``STEP_BYTES`` of K and V a step,
+  and the block size stays the allocator's to choose;
 - the grid is a WORK LIST, not (slots x tiles): :func:`decode_plan`
   works out from the lengths, in XLA, each step's slot and tile (a
   slot's tiles from the one that holds its band's first block to the
@@ -45,7 +51,7 @@ online softmax, Dao 2023):
 - the tile that holds the last (or the band's first) position is masked
   by position exactly like the gather path, which also covers the
   blocks a tile did not fetch and a last tile that overhangs the table
-  (``NB`` need not divide by ``P``);
+  (``NB`` need not divide by ``P``: 33 ring blocks in tiles of 4);
 - all heads of a tile at once, with the tile's keys on the lanes:
   scores are ``[rows, T]``, so the softmax update fills whole
   registers. The kv heads go in chunks whose lanes end on a lane tile's
@@ -81,6 +87,11 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 LANES = 128
 TILE_TOKENS = 128     # the fewest key positions a full tile holds
+# what a paged_decode grid step fetches, K and V together, before it takes
+# no further table entry, and the most entries (pool views a side) the byte
+# rule takes: blocks_per_step; the census behind the number: PERF.md 6, PR 53
+STEP_BYTES = 1 << 20
+STEP_VIEWS = 8
 
 
 def resolve_decode_impl(impl: Optional[str] = None) -> str:
@@ -105,24 +116,48 @@ def resolve_decode_impl(impl: Optional[str] = None) -> str:
     return impl
 
 
-def blocks_per_step(nb: int, bs: int) -> int:
+def pool_row_bytes(pool) -> int:
+    """Bytes of one row of a pool (its last dimension): what a key
+    position costs a fetch of K, and as much again of V."""
+    return pool.shape[-1] * jnp.dtype(pool.dtype).itemsize
+
+
+def blocks_per_step(nb: int, bs: int, row_bytes: Optional[int] = None) -> int:
     """Table entries one grid step attends (``P``), from the static
-    shapes alone: the fewest blocks that make ``TILE_TOKENS`` tokens, or
-    the whole table where that is no more than two such tiles (the
-    window ring's 9 x 16 = 144 tokens are one step)."""
+    shapes alone.
+
+    The token rule (``row_bytes`` None: ``mla_decode``'s tile, and the
+    base of a prefill chunk's read lengths, engine.attended_tiles): the
+    fewest blocks that make ``TILE_TOKENS`` tokens, or the whole table
+    where that is no more than two such tiles (the window ring's 9 x 16
+    = 144 tokens are one step).
+
+    The byte rule (``row_bytes``: :func:`pool_row_bytes` of the pool;
+    ``paged_decode``'s and ``paged_verify``'s tile): a step's fixed cost
+    hides behind its fetch only if the fetch is long enough, so a step
+    takes the fewest entries whose K and V blocks reach ``STEP_BYTES``;
+    never fewer than the token rule's tile; never more views than
+    ``STEP_VIEWS`` unless the token rule already takes more (a view is a
+    copy of its own, and blocks of 16 tokens are paced by their copies'
+    number, not their bytes); never more than the table. An int8 pool
+    fetches half as much a position and takes twice the entries."""
     if nb * bs <= 2 * TILE_TOKENS:
         return nb
-    return min(nb, -(-TILE_TOKENS // bs))
+    P = -(-TILE_TOKENS // bs)
+    if row_bytes is not None:
+        P = max(P, min(-(-STEP_BYTES // (2 * bs * row_bytes)), STEP_VIEWS))
+    return min(nb, P)
 
 
 def tiles_run(length, nb: int, bs: int, window: Optional[int] = None,
-              q_len: int = 1):
+              q_len: int = 1, row_bytes: Optional[int] = None):
     """Grid steps of one slot that fetch and compute (the kernel's own
     arithmetic, on the host, for the ``kv_steps`` counter): the tiles
     between the one that holds the band's first block and the one that
     holds position ``length + q_len - 1``. ``length`` is one slot's or an
-    array of every slot's."""
-    P = blocks_per_step(nb, bs)
+    array of every slot's; ``row_bytes`` as :func:`blocks_per_step` takes
+    it (the kernel's pool's; None: the token rule's tile)."""
+    P = blocks_per_step(nb, bs, row_bytes)
     hi = np.minimum((length + q_len - 1) // bs, nb - 1)
     lo = 0 if window is None \
         else np.clip((length - window + 1) // bs, 0, nb - 1)
@@ -307,8 +342,9 @@ class DecodePlan(NamedTuple):
     [B*nt] each step's slot and tile, the first ``steps`` filled;
     ``held`` [P, B*nt] where in the flattened ``[B*NB]`` block table the
     block stands that ref i of a step names; ``cut`` the static
-    (table entries, block size, window, q_len) it was worked out for,
-    which the call it is handed to checks against its own; ``live`` [B]
+    (table entries, block size, window, q_len, ``P``) it was worked out
+    for, which the call it is handed to checks against its own (``P``
+    follows the kernel and its pool: :func:`blocks_per_step`); ``live`` [B]
     bool the slots the list was cut from (None: all of them), by which
     the call zeroes the rows no step writes (:func:`zero_idle_rows`)."""
     steps: jnp.ndarray
@@ -320,12 +356,17 @@ class DecodePlan(NamedTuple):
 
 
 def decode_plan(lengths, num_entries: int, block_size: int, *,
+                row_bytes: Optional[int] = None,
                 window: Optional[int] = None, q_len: int = 1,
                 active=None) -> DecodePlan:
     """Work the grid of a call out from the lengths, in XLA. It depends
     on nothing else, so a program that attends many layers at the same
     lengths works it out ONCE, outside its layer loop, and hands it to
     every call (``plan=``): a call given none works out its own.
+    ``row_bytes``: :func:`pool_row_bytes` of the pool a ``paged_decode`` /
+    ``paged_verify`` call reads, whose tile follows it
+    (:func:`blocks_per_step`); None for ``mla_decode``, whose tile is the
+    token rule's. A plan of another tile than its call's is refused there.
 
     A slot's steps are the tiles from the one that holds its band's
     first block to the one that holds its position: at least one, and
@@ -348,7 +389,7 @@ def decode_plan(lengths, num_entries: int, block_size: int, *,
     nb, bs = num_entries, block_size
     lengths = jnp.asarray(lengths, jnp.int32)
     B = lengths.shape[0]
-    P = blocks_per_step(nb, bs)
+    P = blocks_per_step(nb, bs, row_bytes)
     nt = -(-nb // P)
     W = B * nt
     lo, hi = _band(lengths, bs, nb, window, q_len)
@@ -370,7 +411,7 @@ def decode_plan(lengths, num_entries: int, block_size: int, *,
     src = jnp.where(last >= 0, last, first)            # [P, W]: a step
     held = jnp.minimum(jnp.take_along_axis(entry, src, axis=1), nb - 1)
     return DecodePlan(ends[-1], slot, tile, slot[src] * nb + held,
-                      (nb, bs, window, q_len), active)
+                      (nb, bs, window, q_len, P), active)
 
 
 def zero_idle_rows(out, plan: DecodePlan):
@@ -466,7 +507,7 @@ def _paged_attention_call(q_rows, k_pool, v_pool, tables, lengths, *,
     tables = jnp.asarray(tables, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
     nb = tables.shape[1]
-    P = blocks_per_step(nb, bs)
+    P = blocks_per_step(nb, bs, pool_row_bytes(k_pool))
     nt = -(-nb // P)
     hc = _head_chunk(n_kv, Dh)
     C, cw = n_kv // hc, hc * Dh
@@ -522,12 +563,13 @@ def _paged_attention_call(q_rows, k_pool, v_pool, tables, lengths, *,
     if hc > 1:
         scratch.append(pltpu.VMEM((C, Rr, cw), q_rows.dtype))
     if plan is None:
-        plan = decode_plan(lengths, nb, bs, window=window, q_len=q_len)
-    # a plan of another table, window or chunk would run the wrong tiles
-    # without a word
-    assert plan.cut == (nb, bs, window, q_len) \
+        plan = decode_plan(lengths, nb, bs, row_bytes=pool_row_bytes(k_pool),
+                           window=window, q_len=q_len)
+    # a plan of another table, window, chunk or tile would run the wrong
+    # tiles without a word
+    assert plan.cut == (nb, bs, window, q_len, P) \
         and plan.held.shape == (P, B * nt), \
-        (plan.cut, plan.held.shape, (nb, bs, window, q_len), (P, B * nt))
+        (plan.cut, plan.held.shape, (nb, bs, window, q_len, P), (P, B * nt))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(plan.steps,),

@@ -131,6 +131,26 @@ def test_cache_attributes_are_the_parents(case):
                         hbm_budget_bytes=budget).num_blocks == 11
 
 
+def test_the_dialect_says_which_decode_kernel_cuts_the_tile(case):
+    """``paged_decode``'s tile follows a pool row's bytes and ``mla_decode``
+    keeps the 128-token rule: the record says which attends its rows (a
+    recurrent state's paged layers are latents in one config and K/V heads
+    in the other), the cache keeps the answer for ``kv_steps``, and
+    ``rows_plan`` cuts the grid by it."""
+    from deepspeed_tpu.ops.attention.paged import blocks_per_step
+    cfg, d, shapes = case[:3]
+    cache = PagedKVCache(cfg, num_slots=2, block_size=4, dtype=jnp.float32)
+    latents = d is latent.DIALECT or hasattr(cfg, "kda_layers")
+    want = None if latents else 4 * shapes[0][-1]
+    assert cache.tile_row_bytes == want
+    assert d.tile_row_bytes(cfg, d.pool(cache.k)) == want
+    if d is engine.DIALECT or d is hybrid.DIALECT:
+        return                                  # plans of their own
+    plan = dialect.rows_plan(cfg, (cache.k, cache.v), cache.tables,
+                             cache.lengths, None)
+    assert plan.cut == (24, 4, None, 1, blocks_per_step(24, 4, want))
+
+
 def test_the_one_refusal_says_what_each_module_said(case):
     cfg, d, words = case[0], case[1], case[5]
     if words is None:

@@ -101,6 +101,11 @@ MASKED_CALLS = [
     pytest.param(64, 8, 128, 48, 256, 16, None, id="kexaone-full-table256"),
     pytest.param(64, 8, 128, 48, 9, 16, 128, id="kexaone-ring9-window128"),
     pytest.param(8, 2, 128, 40, 6, 1024, None, id="zaya1-block1024"),
+    pytest.param(28, 4, 128, 24, 33, 128, 4096,
+                 id="smallthinker-ring33-window4096"),
+    pytest.param(28, 4, 128, 24, 128, 128, None,
+                 id="smallthinker-full-table128"),
+    pytest.param(20, 1, 128, 192, 24, 512, None, id="jamba2-block512"),
 ]
 
 
@@ -109,13 +114,17 @@ def test_paged_attention_lowers_with_a_plan_of_the_active_slots(
         H, Hkv, D, B, nb, bs, window):
     """The work list cut from the slots that decode (a traced mask), and
     the zeroing of the rows it leaves out, through the TPU lowering at
-    the cells' shapes."""
+    the cells' shapes, in the tile their pool's row gives (blocks of 128
+    and of 512: four a step)."""
     pool = S((B * nb + 1, bs, Hkv * D))
+    assert paged.blocks_per_step(nb, bs, paged.pool_row_bytes(pool)) == {
+        16: 9 if nb == 9 else 8, 128: 4, 512: 4, 1024: 1}[bs]
     args = (S((B, Hkv, H // Hkv, D)), pool, pool, S((B, nb), jnp.int32),
             S((B,), jnp.int32))
 
     def call(q, k, v, t, ln, active=None):
-        plan = paged.decode_plan(ln, nb, bs, window=window, active=active)
+        plan = paged.decode_plan(ln, nb, bs, row_bytes=paged.pool_row_bytes(k),
+                                 window=window, active=active)
         return paged.paged_decode_attention(q, k, v, t, ln, scale=0.125,
                                             window=window, plan=plan)
     masked = lower_tpu(call, *args, S((B,), jnp.bool_))

@@ -211,12 +211,28 @@ def _quant_pool_problem(seed=0, B=3, Hkv=2, group=2, Dh=32, bs=8, NB=4):
     return q, kp, vp, kq, ks, vq, vs, tables, lengths
 
 
+# a block of 128 rows of 2 heads of 128: four a step in float32 (1 KiB a
+# row), eight in int8 (a quarter of the bytes, and the most views a step)
+LARGE_BLOCK = dict(Hkv=2, group=2, Dh=128, bs=128, NB=20)
+
+
+@pytest.mark.parametrize("problem", [{}, LARGE_BLOCK],
+                         ids=["block8", "block128-tile8"])
 def test_paged_kernel_int8_matches_quant_reference(devices,
-                                                   pallas_interpret):
+                                                   pallas_interpret,
+                                                   problem):
     """The kernel's in-register dequantize == the gather reference over
     the SAME int8 pools: only softmax reassociation apart (allclose at
-    the fp parity tolerance, not the quant tolerance)."""
-    q, _, _, kq, ks, vq, vs, tables, lengths = _quant_pool_problem()
+    the fp parity tolerance, not the quant tolerance). An int8 pool's
+    tile follows ITS bytes: other entries a step than the float32 pool
+    of the same blocks, per-tile scales and all."""
+    from deepspeed_tpu.ops.attention.paged import (blocks_per_step,
+                                                   pool_row_bytes)
+    q, kp, _, kq, ks, vq, vs, tables, lengths = _quant_pool_problem(**problem)
+    NB, bs = tables.shape[1], kq.shape[1]
+    if problem:
+        assert (blocks_per_step(NB, bs, pool_row_bytes(kq)),
+                blocks_per_step(NB, bs, pool_row_bytes(kp))) == (8, 4)
     out = paged_decode_attention(q, kq, vq, tables, lengths, scale=0.25,
                                  k_scale=ks, v_scale=vs)
     ref = paged_decode_reference(q, kq, vq, tables, lengths, scale=0.25,
@@ -240,10 +256,13 @@ def test_paged_kernel_int8_error_vs_fp_is_bounded(devices,
     assert err <= 8.0 * step, (err, step)
 
 
+@pytest.mark.parametrize("problem", [{}, LARGE_BLOCK],
+                         ids=["block8", "block128-tile8"])
 @pytest.mark.parametrize("G", [2, 3])
 def test_paged_verify_int8_matches_quant_reference(devices,
-                                                   pallas_interpret, G):
-    q, _, _, kq, ks, vq, vs, tables, lengths = _quant_pool_problem()
+                                                   pallas_interpret, G,
+                                                   problem):
+    q, _, _, kq, ks, vq, vs, tables, lengths = _quant_pool_problem(**problem)
     B, Hkv, group, Dh = q.shape
     rng = np.random.default_rng(7)
     qg = jnp.asarray(rng.normal(size=(B, G, Hkv, group, Dh)), jnp.float32)

@@ -99,6 +99,40 @@ def test_kernel_in_interpret_mode_equals_the_plain_latent_decode(bs, nb):
     np.testing.assert_array_equal(np.asarray(again), np.asarray(got))
 
 
+@pytest.mark.parametrize("bs,nb,per_step", [(512, 48, 1), (128, 12, 1),
+                                            (16, 300, 8), (64, 5, 2)])
+def test_the_latent_kernels_tile_is_the_token_rules(bs, nb, per_step):
+    """``mla_decode`` keeps the tile it had before ``paged_decode``'s began
+    to follow its pool's bytes (PR 53): the fewest blocks of 128 positions,
+    whatever a row weighs. It takes a plan of that tile and refuses one cut
+    by the bytes where the two differ (12 blocks of 128 rows of 1,280
+    bytes: four a step by the bytes)."""
+    from deepspeed_tpu.ops.attention.paged import (blocks_per_step,
+                                                   decode_plan,
+                                                   pool_row_bytes)
+    B, H, row = 2, 4, 640
+    pool = jax.ShapeDtypeStruct((1 + B * nb, bs, row), jnp.bfloat16)
+    q = jax.ShapeDtypeStruct((B, H, row), jnp.bfloat16)
+    tables = jax.ShapeDtypeStruct((B, nb), jnp.int32)
+    lengths = jax.ShapeDtypeStruct((B,), jnp.int32)
+    assert blocks_per_step(nb, bs) == per_step
+
+    def call(q, pool, tables, lengths, by_bytes):
+        rb = pool_row_bytes(pool) if by_bytes else None
+        plan = decode_plan(lengths, nb, bs, row_bytes=rb)
+        assert plan.cut[-1] == blocks_per_step(nb, bs, rb)
+        return mla.mla_decode_attention(q, pool, tables, lengths,
+                                        value_width=512, scale=0.1,
+                                        interpret=True, plan=plan)
+    out = jax.eval_shape(functools.partial(call, by_bytes=False), q, pool,
+                         tables, lengths)
+    assert out.shape == (B, H, 512)
+    if blocks_per_step(nb, bs, row * 2) != per_step:
+        with pytest.raises(AssertionError):
+            jax.eval_shape(functools.partial(call, by_bytes=True), q, pool,
+                           tables, lengths)
+
+
 @pytest.mark.parametrize("live", ["some-live", "none-live"])
 @pytest.mark.parametrize("bs,nb", [(16, 24), (128, 3)])
 def test_kernel_does_not_visit_a_slot_that_does_not_decode(bs, nb, live):

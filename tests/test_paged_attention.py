@@ -16,10 +16,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deepspeed_tpu.ops.attention.paged import (blocks_per_step, decode_plan,
+from deepspeed_tpu.ops.attention.paged import (STEP_BYTES, STEP_VIEWS,
+                                               blocks_per_step, decode_plan,
                                                paged_decode_attention,
                                                paged_decode_reference,
-                                               tiles_run)
+                                               paged_verify_attention,
+                                               paged_verify_reference,
+                                               pool_row_bytes, tiles_run)
 
 def _pool_problem(seed=0, B=3, Hkv=2, group=2, Dh=32, bs=8, NB=4):
     """Random pools ``[N, block, Hkv*Dh]`` (heads folded into the rows,
@@ -88,24 +91,40 @@ def test_paged_kernel_ignores_stale_blocks(devices, pallas_interpret):
 
 
 # the serving cells' head shapes (heads, kv heads, head size, table
-# entries, window) with small pools, and a table whose length is prime
+# entries, window, block) with small pools, and a table whose length is
+# prime
 CELL_SHAPES = [
-    pytest.param(25, 25, 64, 64, None, id="gpt2-xl-table64"),
-    pytest.param(64, 8, 128, 256, None, id="kexaone-full-table256"),
-    pytest.param(64, 8, 128, 9, 128, id="kexaone-ring9-window128"),
-    pytest.param(25, 25, 64, 13, None, id="gpt2-xl-prime-table13"),
+    pytest.param(25, 25, 64, 64, None, 16, id="gpt2-xl-table64"),
+    pytest.param(64, 8, 128, 256, None, 16, id="kexaone-full-table256"),
+    pytest.param(64, 8, 128, 9, 128, 16, id="kexaone-ring9-window128"),
+    pytest.param(25, 25, 64, 13, None, 16, id="gpt2-xl-prime-table13"),
 ]
+# large blocks, where the tile follows the row's bytes (float32 pools here:
+# rows of 1,024 and of 256 bytes): 4 blocks of 128 and of 512 a step; a
+# window ring and a table that do not divide by 4, a band that starts in
+# the middle of a tile
+BYTE_SHAPES = [
+    pytest.param(8, 2, 128, 33, 4096, 128, id="block128-ring33-window4096"),
+    pytest.param(8, 2, 128, 20, 700, 128, id="block128-table20-window700"),
+    pytest.param(4, 1, 64, 6, None, 512, id="block512-table6-mqa"),
+]
+CELL_SHAPES += BYTE_SHAPES
 
 
-def _edge_lengths(nb, bs, window):
-    """Slot lengths at every edge of a block, a tile and the table. A
-    ring table's lengths are relative to its first block, so they stay
-    within the ring and (the band being the caller's whole table) past
-    nothing the window has dropped."""
-    P = blocks_per_step(nb, bs)
+def _row_bytes(Hkv, Dh):
+    return Hkv * Dh * 4                 # float32 pools
+
+
+def _edge_lengths(nb, bs, window, P):
+    """Slot lengths at every edge of a block, a tile (``P`` blocks) and
+    the table, and one whose band starts in the middle of a tile. A ring
+    table's lengths are relative to its first block, so they stay within
+    the ring and (the band being the caller's whole table) past nothing
+    the window has dropped."""
     edges = [0, bs - 1, bs, P * bs - 1, P * bs, P * bs + 1, nb * bs - 1]
     if window is not None:
-        edges += [window - 1, window, window + bs // 2]
+        edges += [window - 1, window, window + bs // 2,
+                  window + (P // 2) * bs + bs // 2]
     return sorted({min(n, nb * bs - 1) for n in edges})
 
 
@@ -114,7 +133,8 @@ def _cell_problem(H, Hkv, Dh, nb, window, seed=0, bs=16):
     the trash block 0 (as the paged cache leaves them); the other slots'
     entries past their length name blocks of their own, poisoned."""
     rng = np.random.default_rng(seed)
-    lengths = _edge_lengths(nb, bs, window)
+    lengths = _edge_lengths(
+        nb, bs, window, blocks_per_step(nb, bs, _row_bytes(Hkv, Dh)))
     lengths.append(lengths[len(lengths) // 2])       # the trash-table slot
     B = len(lengths)
     N = B * nb + 1
@@ -126,14 +146,14 @@ def _cell_problem(H, Hkv, Dh, nb, window, seed=0, bs=16):
     return q, kp, vp, tables, np.asarray(lengths, np.int32)
 
 
-@pytest.mark.parametrize("H,Hkv,Dh,nb,window", CELL_SHAPES)
+@pytest.mark.parametrize("H,Hkv,Dh,nb,window,bs", CELL_SHAPES)
 def test_paged_kernel_matches_reference_at_cell_shapes(
-        devices, pallas_interpret, H, Hkv, Dh, nb, window):
+        devices, pallas_interpret, H, Hkv, Dh, nb, window, bs):
     """The tile walk against the dense gathered softmax at the serving
     cells' head shapes, every slot at another edge: an empty cache, a
     block's last and first position, a tile's last, first and second, the
     table's last; unused entries naming the trash block."""
-    q, kp, vp, tables, lengths = _cell_problem(H, Hkv, Dh, nb, window)
+    q, kp, vp, tables, lengths = _cell_problem(H, Hkv, Dh, nb, window, bs=bs)
     args = (q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(tables),
             jnp.asarray(lengths))
     out = paged_decode_attention(*args, scale=Dh ** -0.5, window=window)
@@ -142,28 +162,36 @@ def test_paged_kernel_matches_reference_at_cell_shapes(
                                atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("H,Hkv,Dh,nb,window", CELL_SHAPES)
+@pytest.mark.parametrize("H,Hkv,Dh,nb,window,bs", CELL_SHAPES)
 def test_tile_walk_keeps_its_promises(devices, pallas_interpret, H, Hkv, Dh,
-                                      nb, window):
+                                      nb, window, bs):
     """What the mechanism promises, from shapes alone: a grid step
-    attends 128 to 256 tokens (the whole table where it is shorter), the
+    attends 128 to 256 tokens (the whole table where it is shorter) or,
+    where a block is large, the fewest blocks whose K and V reach
+    ``STEP_BYTES`` (at most ``STEP_VIEWS``, at most the table), the
     host's count of the steps that run is the kernel's own arithmetic,
     and a block past a slot's length or wholly below its band never
     reaches the output, NaN and all."""
-    bs = 16
-    P = blocks_per_step(nb, bs)
-    assert P * bs == min(nb * bs, 128) or 128 <= P * bs <= 256, P
-    q, kp, vp, tables, lengths = _cell_problem(H, Hkv, Dh, nb, window)
+    rb = _row_bytes(Hkv, Dh)
+    P, floor = blocks_per_step(nb, bs, rb), blocks_per_step(nb, bs)
+    if bs == 16:
+        assert P == floor
+        assert P * bs == min(nb * bs, 128) or 128 <= P * bs <= 256, P
+    else:
+        assert floor < P == min(nb, STEP_VIEWS,
+                                -(-STEP_BYTES // (2 * bs * rb)))
+    q, kp, vp, tables, lengths = _cell_problem(H, Hkv, Dh, nb, window, bs=bs)
     for b, n in enumerate(lengths):
         hi = n // bs
         lo = 0 if window is None else max(n - window + 1, 0) // bs
-        assert tiles_run(int(n), nb, bs, window) == hi // P - lo // P + 1
+        assert tiles_run(int(n), nb, bs, window, row_bytes=rb) \
+            == hi // P - lo // P + 1
         for e in range(nb):
             if (e > hi or e < lo) and tables[b, e] != 0:
                 kp[tables[b, e]] = np.nan
                 vp[tables[b, e]] = np.nan
-    assert tiles_run(0, nb, bs, window) == 1
-    assert tiles_run(nb * bs - 1, nb, bs) == -(-nb // P)
+    assert tiles_run(0, nb, bs, window, row_bytes=rb) == 1
+    assert tiles_run(nb * bs - 1, nb, bs, row_bytes=rb) == -(-nb // P)
     out = paged_decode_attention(q, jnp.asarray(kp), jnp.asarray(vp),
                                  jnp.asarray(tables), jnp.asarray(lengths),
                                  scale=Dh ** -0.5, window=window)
@@ -178,24 +206,26 @@ def test_tile_walk_keeps_its_promises(devices, pallas_interpret, H, Hkv, Dh,
                                atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("H,Hkv,Dh,nb,window", CELL_SHAPES)
+@pytest.mark.parametrize("H,Hkv,Dh,nb,window,bs", CELL_SHAPES)
 def test_decode_plan_fetches_attended_blocks_once(devices, H, Hkv, Dh, nb,
-                                                  window):
+                                                  window, bs):
     """The grid worked out from the lengths: as many steps as the slots'
     tiles that run, in slot order; every attended table entry named by
     the ref of its place in the tile at its own step; a ref's index
     changes only to an attended entry, so nothing else is fetched and
     nothing twice."""
-    bs = 16
-    P = blocks_per_step(nb, bs)
-    lengths = np.asarray(_edge_lengths(nb, bs, window), np.int32)
+    rb = _row_bytes(Hkv, Dh)
+    P = blocks_per_step(nb, bs, rb)
+    lengths = np.asarray(_edge_lengths(nb, bs, window, P), np.int32)
     B = len(lengths)
-    plan = decode_plan(jnp.asarray(lengths), nb, bs, window=window)
+    plan = decode_plan(jnp.asarray(lengths), nb, bs, row_bytes=rb,
+                       window=window)
     steps = int(plan.steps)
     slot, tile = np.asarray(plan.slot)[:steps], np.asarray(plan.tile)[:steps]
     held = np.asarray(plan.held)[:, :steps]
-    assert plan.held.shape == (P, B * -(-nb // P))
-    assert steps == sum(tiles_run(int(n), nb, bs, window) for n in lengths)
+    assert plan.held.shape == (P, B * -(-nb // P)) and plan.cut[-1] == P
+    assert steps == sum(tiles_run(int(n), nb, bs, window, row_bytes=rb)
+                        for n in lengths)
     assert sorted(set(slot)) == list(range(B))
     assert (np.diff(slot) >= 0).all()
     attended = set()
@@ -226,34 +256,41 @@ MASKS = {
     "none-live": lambda B: np.zeros(B, bool),
     "all-live": lambda B: np.ones(B, bool),
 }
-# (table entries, window, q_len): the full table, the window ring, a
-# windowed table of several tiles, a verify chunk
-PLAN_CUTS = [pytest.param(64, None, 1, id="table64"),
-             pytest.param(9, 128, 1, id="ring9-window128"),
-             pytest.param(32, 100, 1, id="table32-window100"),
-             pytest.param(64, None, 5, id="table64-verify5")]
+# (table entries, window, q_len, block, a pool row's bytes, the tile): the
+# full table, the window ring, a windowed table of several tiles, a verify
+# chunk; then tiles by the bytes: a ring of 33 blocks of 128 in tiles of 4,
+# the same blocks of an int8 pool (a quarter of the bytes) in tiles of 8, a
+# verify chunk over a table of 24 blocks of 512 in tiles of 4
+PLAN_CUTS = [
+    pytest.param(64, None, 1, 16, None, 8, id="table64"),
+    pytest.param(9, 128, 1, 16, None, 9, id="ring9-window128"),
+    pytest.param(32, 100, 1, 16, None, 8, id="table32-window100"),
+    pytest.param(64, None, 5, 16, None, 8, id="table64-verify5"),
+    pytest.param(33, 4096, 1, 128, 1024, 4, id="block128-ring33-tile4"),
+    pytest.param(33, 4096, 1, 128, 256, 8, id="block128-ring33-tile8"),
+    pytest.param(24, None, 3, 512, 256, 4, id="block512-table24-verify3")]
 
 
 @pytest.mark.parametrize("mask", MASKS)
-@pytest.mark.parametrize("nb,window,q_len", PLAN_CUTS)
-def test_decode_plan_of_the_active_slots(devices, nb, window, q_len, mask):
+@pytest.mark.parametrize("nb,window,q_len,bs,rb,P", PLAN_CUTS)
+def test_decode_plan_of_the_active_slots(devices, nb, window, q_len, bs, rb,
+                                         P, mask):
     """``decode_plan(active=)`` is the plan of the live slots alone, slot
     indices mapped back: a slot that does not decode has no step, first
     and last slot included, every attended block of a live slot is still
     fetched once and nothing else is; with every slot live, and with no
     mask, the arrays are the parent's number for number."""
-    bs = 16
-    P = blocks_per_step(nb, bs)
-    lengths = np.asarray(_edge_lengths(nb, bs, window), np.int32)
+    assert blocks_per_step(nb, bs, rb) == P
+    lengths = np.asarray(_edge_lengths(nb, bs, window, P), np.int32)
     lengths = np.minimum(lengths, nb * bs - q_len)
     B = len(lengths)
     active = MASKS[mask](B)
-    kw = dict(window=window, q_len=q_len)
+    kw = dict(row_bytes=rb, window=window, q_len=q_len)
     plan = decode_plan(jnp.asarray(lengths), nb, bs, active=active, **kw)
-    assert plan.cut == (nb, bs, window, q_len)
+    assert plan.cut == (nb, bs, window, q_len, P)
     np.testing.assert_array_equal(np.asarray(plan.live), active)
     steps = int(plan.steps)
-    per_slot = [tiles_run(int(n), nb, bs, window, q_len) if a else 0
+    per_slot = [tiles_run(int(n), nb, bs, window, q_len, rb) if a else 0
                 for n, a in zip(lengths, active)]
     assert steps == sum(per_slot)
     slot = np.asarray(plan.slot)
@@ -296,23 +333,25 @@ def test_decode_plan_of_the_active_slots(devices, nb, window, q_len, mask):
 
 
 # ZAYA1's attention (8 query / 2 KV heads of 128, a table of 6 blocks)
-# beside the older cells'; its block is 1,024 on the chip, 128 here
+# beside the older cells'; its block is 1,024 on the chip, 128 here; and
+# the tiles by the bytes
 MASKED_SHAPES = CELL_SHAPES[:3] + [
-    pytest.param(8, 2, 128, 6, None, id="zaya1-table6")]
+    pytest.param(8, 2, 128, 6, None, 128, id="zaya1-table6")] + BYTE_SHAPES
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "window"))
 def _attend_live_slots(q, kp, vp, tables, lengths, active, *, scale, window):
-    plan = decode_plan(lengths, tables.shape[1], kp.shape[1], window=window,
+    plan = decode_plan(lengths, tables.shape[1], kp.shape[1],
+                       row_bytes=pool_row_bytes(kp), window=window,
                        active=active)
     return paged_decode_attention(q, kp, vp, tables, lengths, scale=scale,
                                   window=window, plan=plan)
 
 
 @pytest.mark.parametrize("live", ["some-live", "none-live"])
-@pytest.mark.parametrize("H,Hkv,Dh,nb,window", MASKED_SHAPES)
+@pytest.mark.parametrize("H,Hkv,Dh,nb,window,bs", MASKED_SHAPES)
 def test_slots_that_do_not_decode_are_not_visited(devices, pallas_interpret,
-                                                  H, Hkv, Dh, nb, window,
+                                                  H, Hkv, Dh, nb, window, bs,
                                                   live):
     """A slot with no request (length 0, its table the trash block) and a
     slot in mid-prefill (inactive, its progress as its length, blocks of
@@ -320,7 +359,6 @@ def test_slots_that_do_not_decode_are_not_visited(devices, pallas_interpret,
     block of the prefilling slot the live slots read, to the bit, what
     they read without the poison, and the rows of the slots that do not
     decode are exactly zero. With no slot live the call returns zeros."""
-    bs = 128 if nb == 6 else 16
     q, kp, vp, tables, lengths = _cell_problem(H, Hkv, Dh, nb, window, bs=bs)
     B = len(lengths)
     idle, prefilling = 0, B // 2
@@ -352,17 +390,17 @@ def test_slots_that_do_not_decode_are_not_visited(devices, pallas_interpret,
                                    rtol=2e-5)
 
 
-@pytest.mark.parametrize("H,Hkv,Dh,nb,window", CELL_SHAPES)
+@pytest.mark.parametrize("H,Hkv,Dh,nb,window,bs", CELL_SHAPES)
 def test_a_slots_bad_block_stays_its_own(devices, pallas_interpret, H, Hkv,
-                                         Dh, nb, window):
+                                         Dh, nb, window, bs):
     """Fault isolation between slots: a ref whose entry a step's slot
     does not attend still holds the block it fetched for the slot
     before, and a probability of 0 times that block's NaN would be a
     NaN. With every block that every second slot ATTENDS poisoned (K
     and V, infinities too), the slots between them read what the
     reference reads."""
-    q, kp, vp, tables, lengths = _cell_problem(H, Hkv, Dh, nb, window)
-    bs, B = 16, len(lengths)
+    q, kp, vp, tables, lengths = _cell_problem(H, Hkv, Dh, nb, window, bs=bs)
+    B = len(lengths)
     bad = np.arange(B) % 2 == 0
     clean = (q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(tables),
              jnp.asarray(lengths))
@@ -382,24 +420,56 @@ def test_a_slots_bad_block_stays_its_own(devices, pallas_interpret, H, Hkv,
 
 
 @pytest.mark.parametrize("other", [dict(window=64), dict(q_len=2),
-                                   dict(nb=12)],
-                         ids=["window", "q_len", "table"])
+                                   dict(nb=12), dict(row_bytes=None),
+                                   dict(row_bytes=256)],
+                         ids=["window", "q_len", "table", "token-tile",
+                              "int8-tile"])
 def test_a_plan_fits_its_call_or_the_call_refuses(devices, pallas_interpret,
                                                   other):
-    """A plan worked out for another window, chunk or table would fire
-    the kernel's first and last tile at the wrong steps without a word:
-    the call checks what the plan was cut for."""
-    q, kp, vp, tables, lengths = _cell_problem(25, 25, 64, 13, None)
+    """A plan worked out for another window, chunk, table or tile (the
+    token rule's one block of 128 a step, or an int8 pool's eight, where
+    the call's float32 pool takes four) would fire the kernel's first and
+    last tile at the wrong steps without a word: the call checks what the
+    plan was cut for."""
+    shape = dict(nb=20, bs=128, rb=1024, heads=(8, 2, 128)) \
+        if "row_bytes" in other else dict(nb=13, bs=16, rb=6400,
+                                          heads=(25, 25, 64))
+    nb, bs, rb = shape["nb"], shape["bs"], shape["rb"]
+    q, kp, vp, tables, lengths = _cell_problem(*shape["heads"], nb, None,
+                                               bs=bs)
     args = (q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(tables),
             jnp.asarray(lengths))
-    good = decode_plan(args[4], 13, 16)
+    good = decode_plan(args[4], nb, bs, row_bytes=rb)
+    assert good.cut == (nb, bs, None, 1, blocks_per_step(nb, bs, rb))
     np.testing.assert_array_equal(
         np.asarray(paged_decode_attention(*args, scale=0.125, plan=good)),
         np.asarray(paged_decode_attention(*args, scale=0.125)))
-    kw = {**dict(window=None, q_len=1, nb=13), **other}
-    wrong = decode_plan(args[4], kw.pop("nb"), 16, **kw)
+    kw = {**dict(window=None, q_len=1, nb=nb, row_bytes=rb), **other}
+    wrong = decode_plan(args[4], kw.pop("nb"), bs, **kw)
+    assert wrong.cut != good.cut
     with pytest.raises(AssertionError):
         paged_decode_attention(*args, scale=0.125, plan=wrong)
+
+
+@pytest.mark.parametrize("G", [2, 5])
+@pytest.mark.parametrize("H,Hkv,Dh,nb,window,bs", BYTE_SHAPES)
+def test_paged_verify_matches_reference_at_byte_tiles(
+        devices, pallas_interpret, H, Hkv, Dh, nb, window, bs, G):
+    """A verify chunk (``q_len`` > 1) over tiles cut by the bytes: chunk
+    query i of a slot at every edge attends positions up to its own, the
+    chunk's last query in the tile after its first where the chunk
+    straddles a tile's edge."""
+    q, kp, vp, tables, lengths = _cell_problem(H, Hkv, Dh, nb, window, bs=bs)
+    lengths = np.minimum(lengths, nb * bs - G)
+    rng = np.random.default_rng(3)
+    qg = jnp.asarray(rng.normal(size=(len(lengths), G) + q.shape[1:]),
+                     jnp.float32)
+    args = (qg, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(tables),
+            jnp.asarray(lengths))
+    out = paged_verify_attention(*args, scale=Dh ** -0.5, window=window)
+    ref = paged_verify_reference(*args, scale=Dh ** -0.5, window=window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
 
 
 def test_paged_kernel_no_dense_gather(devices):

@@ -370,6 +370,35 @@ def test_attended_tiles_cover_what_a_chunk_sees_and_little_more(bs, nb,
             assert (int(tlo), int(thi)) == (lo, hi)
 
 
+# (block, table entries, window) -> attended_tiles at a chunk of 64 tokens
+# at the row's start, middle and end, as PR 53's parent returned them
+PREFILL_TILES = {
+    "smallthinker-ring": ((128, 33, 4096), [(0, 1, 5), (0, 4, 5), (0, 7, 5)]),
+    "smallthinker-row": ((128, 128, None),
+                         [(0, 1, 16), (0, 5, 16), (0, 8, 16)]),
+    "kexaone-ring": ((16, 9, 128), [(0, 1, 9), (0, 1, 9), (0, 1, 9)]),
+    "kexaone-row": ((16, 256, None), [(0, 1, 32), (0, 5, 32), (0, 8, 32)]),
+    "gpt2-xl-row": ((16, 64, None), [(0, 1, 8), (0, 5, 8), (0, 8, 8)]),
+    "jamba2-row": ((512, 24, None), [(0, 1, 3), (0, 5, 3), (0, 8, 3)]),
+    "zaya1-row": ((1024, 6, None), [(0, 1, 1), (0, 4, 1), (0, 6, 1)]),
+}
+
+
+@pytest.mark.parametrize("cell", PREFILL_TILES)
+def test_prefill_read_tiles_do_not_follow_the_decode_kernels_bytes(cell):
+    """A prefill chunk's read lengths stand on the 128-position tile,
+    whatever the decode kernel's step fetches since it follows its pool's
+    bytes (``blocks_per_step(nb, bs, row_bytes)``: four blocks of 128 a
+    step for these rows): the ring's tiles stay 640 positions and the
+    row's 2,048 in smallthinker (PERF.md 6, PR 51), and no prefill
+    program changes."""
+    (bs, nb, window), want = PREFILL_TILES[cell]
+    got = [engine_lib.attended_tiles(start, 64, bs, nb, window)
+           for start in (0, nb * bs // 2, nb * bs - 64)]
+    assert got == want
+    assert blocks_per_step(nb, bs) == -(-128 // bs) or nb * bs <= 256
+
+
 # ---------------------------------------------------------------------------
 # the chunk's write: whole blocks, also from inside a copy-on-write block
 # ---------------------------------------------------------------------------

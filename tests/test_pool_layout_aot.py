@@ -184,8 +184,8 @@ def test_decode_program_attends_through_the_mosaic_kernel(compiled_cell):
     sv = CELL["serving"]
     B, bs = sv["num_slots"], sv["block_size"]
     NB = CELL["model"]["n_positions"] // bs
-    P = blocks_per_step(NB, bs)
-    assert 128 <= P * bs <= 256
+    P = blocks_per_step(NB, bs, 2 * CELL["model"]["n_embd"])
+    assert 128 <= P * bs <= 256      # blocks of 16: the views cap the bytes
     reg = ProgramCostRegistry()
     reg.add_provenance("decode_slots", exe.as_text(), pool_blocks=(N, L * N),
                        paged_grid=(P, B * -(-NB // P)))

@@ -94,9 +94,9 @@ ENTRY %main (p: f32[4]) -> f32[4] {
     assert "p" not in table
 
 
-def _tiny_engine():
+def _tiny_engine(max_seq_len=64):
     cfg = gpt.GPTConfig(vocab_size=128, n_layers=2, n_heads=4, d_model=32,
-                        max_seq_len=64, use_flash_attention=False,
+                        max_seq_len=max_seq_len, use_flash_attention=False,
                         remat=False, dtype=jnp.float32)
     params = gpt.init_params(jax.random.PRNGKey(0), cfg)
     return InferenceEngine(config=cfg, params=params, dtype=jnp.float32)
@@ -142,25 +142,30 @@ def test_serving_programs_have_stable_names_and_scopes(served, pid, module,
     assert pid in json.loads(srv.cost_registry.dumps())["provenance"]
 
 
-def test_kernel_programs_say_how_the_paged_grid_is_cut(devices,
-                                                       pallas_interpret):
+@pytest.mark.parametrize("bs,max_seq_len,per_step", [
+    (4, 64, 16), (128, 2048, 8)], ids=["block4-table16", "block128-table16"])
+def test_kernel_programs_say_how_the_paged_grid_is_cut(
+        devices, pallas_interpret, bs, max_seq_len, per_step):
     """With telemetry on, a decode program asked for the kernel records
     how ``paged_decode``'s grid is cut (registry entry, gauges), and each
     ``serve.decode`` span the steps of it that run for the live slots:
-    never more than the grid, and (8 blocks of 4 tokens: one tile a
-    slot) one a live slot here. A gather program records neither."""
+    never more than the grid, and one a live slot here (16 blocks of 4
+    tokens are one tile; of 16 blocks of 128 x 128 bytes a tile holds the
+    8 that a step may view, where 128 tokens would make it one block). A
+    gather program records neither."""
     from deepspeed_tpu.ops.attention.paged import blocks_per_step
     r = np.random.default_rng(1)
     reqs = [ServeRequest(rid=i, prompt=r.integers(1, 128, n).astype(np.int32),
                          max_new_tokens=4) for i, n in enumerate((9, 5))]
     tel = Telemetry(sample_every=1)     # kv_steps rides the sampled steps
-    srv = ServingEngine(_tiny_engine(), num_slots=2, block_size=4,
-                        num_blocks=24, prefill_chunk=8, telemetry=tel,
-                        decode_impl="pallas")
+    srv = ServingEngine(_tiny_engine(max_seq_len), num_slots=2,
+                        block_size=bs, num_blocks=24, prefill_chunk=8,
+                        telemetry=tel, decode_impl="pallas")
     srv.run(reqs)
     entry = srv.cost_registry.entries["decode_slots"]
     nb = srv.cache.blocks_per_slot
-    P = blocks_per_step(nb, 4)
+    P = blocks_per_step(nb, bs, srv.cache.tile_row_bytes)
+    assert (nb, srv.cache.tile_row_bytes, P) == (16, 128, per_step)
     assert entry["paged_blocks_per_step"] == P
     assert entry["paged_grid_steps"] == 2 * -(-nb // P)
     # (pool_copy_bytes is the compiled chip program's to show: the
@@ -177,8 +182,9 @@ def test_kernel_programs_say_how_the_paged_grid_is_cut(devices,
         assert attrs["kv_steps"] == attrs["live"] * 1
         assert attrs["kv_steps"] <= entry["paged_grid_steps"]
         assert attrs["blocks"] <= attrs["kv_steps"] * P * 2
-    off = ServingEngine(_tiny_engine(), num_slots=2, block_size=4,
-                        num_blocks=24, prefill_chunk=8, telemetry=Telemetry())
+    off = ServingEngine(_tiny_engine(max_seq_len), num_slots=2,
+                        block_size=bs, num_blocks=24, prefill_chunk=8,
+                        telemetry=Telemetry())
     off.run([ServeRequest(rid=9, prompt=reqs[0].prompt.copy(),
                           max_new_tokens=2)])
     assert "paged_grid_steps" not in off.cost_registry.entries["decode_slots"]

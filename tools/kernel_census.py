@@ -214,18 +214,32 @@ def paged_rows():
                 yield name, run
 
 
-# the serving cells' decode calls: (name, slots, kv heads, group, head
-# size, table entries, window, layers that share the stacked pool,
-# blocks a layer). The ring table holds a window layer's last 9 blocks
-# in logical order, lengths relative to the first
-PAGED_CELL_SHAPES = (
-    ("gpt2-xl table64", 17, 25, 1, 64, 64, None, 48, 1088),
-    ("k-exaone full table256", 48, 8, 8, 128, 256, None, 2, 12288),
-    ("k-exaone ring table9 w128", 48, 8, 8, 128, 9, 128, 6, 432))
-PAGED_REPS = 20         # sweeps over the layers in one timed program
 # (name, share of the slots that decode, tokens a decoding slot holds)
 PAGED_FILLS = (("chat-like", 0.4, 290), ("docs-like", 0.8, 760),
                ("reason-like", 1.0, 1000))
+# the serving cells' decode calls: (name, slots, kv heads, group, head
+# size, block, table entries, window, layers that share the stacked pool,
+# blocks a layer, fills). A ring table holds a window layer's last blocks
+# in logical order, lengths relative to the first. The last four are the
+# large-block cells', every slot decoding, at half, about and twice what a
+# slot holds in its cell (a ring: half, 80% and all of it)
+PAGED_CELL_SHAPES = (
+    ("gpt2-xl table64", 17, 25, 1, 64, 16, 64, None, 48, 1088, PAGED_FILLS),
+    ("k-exaone full table256", 48, 8, 8, 128, 16, 256, None, 2, 12288,
+     PAGED_FILLS),
+    ("k-exaone ring table9 w128", 48, 8, 8, 128, 16, 9, 128, 6, 432,
+     PAGED_FILLS),
+    ("smallthinker ring table33 w4096", 24, 4, 7, 128, 128, 33, 4096, 9, 793,
+     (("half", 1.0, 2100), ("cell-like", 1.0, 3380), ("full", 1.0, 4200))),
+    ("smallthinker full table128", 24, 4, 7, 128, 128, 128, None, 3, 3073,
+     (("half", 1.0, 2200), ("cell-like", 1.0, 4400), ("twice", 1.0, 8800))),
+    ("jamba2 table24", 192, 1, 20, 128, 512, 24, None, 2, 4097,
+     (("half", 1.0, 1400), ("cell-like", 1.0, 2800), ("twice", 1.0, 5600))),
+    ("zaya1 table6", 40, 2, 4, 128, 1024, 6, None, 20, 241,
+     (("half", 1.0, 1200), ("cell-like", 1.0, 2400), ("twice", 1.0, 4800))))
+PAGED_REPS = 20         # sweeps over the layers in one timed program
+# what paged.STEP_BYTES is swept over at the four large-block shapes
+PAGED_STEP_BYTES = tuple(k << 10 for k in (256, 512, 1024, 2048, 4096))
 
 
 @functools.lru_cache(maxsize=None)
@@ -235,7 +249,8 @@ def _paged_layers(L, N, bs, nb, scale, window):
     the lengths, a loop over the layers (each call's result feeding the
     next one's queries), PAGED_REPS sweeps of it."""
     def layers(q, k, v, tables, lengths, active=None):
-        plan = P.decode_plan(lengths, nb, bs, window=window, active=active)
+        plan = P.decode_plan(lengths, nb, bs, row_bytes=P.pool_row_bytes(k),
+                             window=window, active=active)
 
         def layer(q, l):
             out = P.paged_decode_attention(
@@ -252,7 +267,8 @@ def _paged_layers(L, N, bs, nb, scale, window):
 @functools.partial(jax.jit, static_argnames=("scale", "window"))
 def _paged_decode_jit(q, k, v, tables, lengths, active=None, *, scale,
                       window):
-    plan = P.decode_plan(lengths, tables.shape[1], k.shape[1], window=window,
+    plan = P.decode_plan(lengths, tables.shape[1], k.shape[1],
+                         row_bytes=P.pool_row_bytes(k), window=window,
                          active=active)
     return P.paged_decode_attention(q, k, v, tables, lengths, scale=scale,
                                     window=window, plan=plan)
@@ -275,54 +291,98 @@ def _time_layers(layers, L, *args):
     return _best_seconds(layers, *args) / (PAGED_REPS * L) * 1e6
 
 
+def _paged_time_row(B, Hkv, G, D, bs, nb, window, L, N, fills):
+    """One shape's row of :func:`paged_time_rows` under the paged.STEP_BYTES
+    of the moment."""
+    # made on the chip: a cell's pools are gigabytes
+    kk, kv, kq = jax.random.split(jax.random.PRNGKey(5), 3)
+    k = jax.random.normal(kk, (L * N, bs, Hkv * D), jnp.bfloat16)
+    v = jax.random.normal(kv, (L * N, bs, Hkv * D), jnp.bfloat16)
+    q = jax.random.normal(kq, (B, Hkv, G, D), jnp.bfloat16)
+    scale = 1.0 / np.sqrt(D)
+    row_bytes = P.pool_row_bytes(k)
+    per_step = P.blocks_per_step(nb, bs, row_bytes)
+    # every slot its own blocks, block 0 the trash block
+    tables = jnp.asarray(
+        1 + (np.arange(B * nb) % (N - 1)).reshape(B, nb), jnp.int32)
+    layers = _paged_layers(L, N, bs, nb, scale, window)
+    cases = []
+    for fill, share, tokens in fills:
+        live = np.arange(B) < round(share * B)
+        held = min(tokens, nb * bs - 1)
+        if window is not None:      # relative to the ring's start
+            held = min(tokens, (nb - 1) * bs + tokens % bs)
+        lengths = np.where(live, held, 0)
+        cases.append((fill, lengths,
+                      int(np.sum(np.where(live, held // bs + 1, 1))),
+                      int(np.sum(P.tiles_run(lengths, nb, bs, window,
+                                             row_bytes=row_bytes)))))
+    # jitted: called eagerly, each of the kernel's views of a
+    # pool would be a program argument of the pool's size
+    first = jnp.asarray(cases[0][1], jnp.int32)
+    out = _paged_decode_jit(q, k, v, tables, first, scale=scale,
+                            window=window)
+    row = {"fwd_err": _err(out, P.paged_decode_reference(
+        *_f32(q, k[:N], v[:N]), tables, first, scale=scale,
+        window=window))}
+    by_block, by_step = [], []
+    for fill, lengths, blocks, steps in cases:
+        us = _time_layers(layers, L, q, k, v, tables,
+                          jnp.asarray(lengths, jnp.int32))
+        row.update({f"us_{fill}": round(us, 1), f"blocks_{fill}": blocks,
+                    f"steps_{fill}": steps,
+                    f"us_step_{fill}": round(us / steps, 3)})
+        by_block.append((blocks, us))
+        by_step.append((steps, us))
+    slope, fixed = np.polyfit(*zip(*by_block), 1)
+    row.update(us_fixed=round(float(fixed), 1),
+               us_per_block=round(float(slope), 3))
+    if len({steps for steps, _ in by_step}) > 1:
+        # what one more step costs, beside what its bytes alone would
+        row["us_per_step"] = round(float(np.polyfit(*zip(*by_step), 1)[0]), 3)
+    bytes_step = per_step * 2 * bs * row_bytes
+    return {**row, "blocks_per_step": per_step, "bytes_step": bytes_step,
+            "us_step_bytes": round(bytes_step / 819e9 * 1e6, 3),
+            "grid_steps": B * -(-nb // per_step),
+            "ok": row["fwd_err"] < TOL}
+
+
 def paged_time_rows():
-    """Microseconds a ``paged_decode`` call at the three serving cells'
-    shapes and three fills, timed as the decode program runs it
-    (:func:`_paged_layers`), and the fit a reader needs: a fixed cost a
-    call and a cost per occupied block. Checked against the gather
-    reference at the first fill."""
-    r = np.random.default_rng(5)
-    bs = 16
-    for name, B, Hkv, G, D, nb, window, L, N in PAGED_CELL_SHAPES:
-        def run(B=B, Hkv=Hkv, G=G, D=D, nb=nb, window=window, L=L, N=N):
-            k = _rand(r, (L * N, bs, Hkv * D))
-            v = _rand(r, (L * N, bs, Hkv * D))
-            q = _rand(r, (B, Hkv, G, D))
-            scale = 1.0 / np.sqrt(D)
-            # every slot its own blocks, block 0 the trash block
-            tables = jnp.asarray(
-                1 + (np.arange(B * nb) % (N - 1)).reshape(B, nb), jnp.int32)
-            layers = _paged_layers(L, N, bs, nb, scale, window)
-            fills = []
-            for fill, share, tokens in PAGED_FILLS:
-                live = np.arange(B) < round(share * B)
-                held = min(tokens, nb * bs - 1)
-                if window is not None:      # relative to the ring's start
-                    held = min(tokens, (nb - 1) * bs + tokens % bs)
-                fills.append((fill, np.where(live, held, 0),
-                              int(np.sum(np.where(live, held // bs + 1, 1)))))
-            # jitted: called eagerly, each of the kernel's views of a
-            # pool would be a program argument of the pool's size
-            first = jnp.asarray(fills[0][1], jnp.int32)
-            out = _paged_decode_jit(q, k, v, tables, first, scale=scale,
-                                    window=window)
-            row = {"fwd_err": _err(out, P.paged_decode_reference(
-                *_f32(q, k[:N], v[:N]), tables, first, scale=scale,
-                window=window))}
-            points = []
-            for fill, lengths, blocks in fills:
-                us = _time_layers(layers, L, q, k, v, tables,
-                                  jnp.asarray(lengths, jnp.int32))
-                row[f"us_{fill}"] = round(us, 1)
-                row[f"blocks_{fill}"] = blocks
-                points.append((blocks, us))
-            slope, fixed = np.polyfit(*zip(*points), 1)
-            return {**row, "us_fixed": round(float(fixed), 1),
-                    "us_per_block": round(float(slope), 3),
-                    "blocks_per_step": P.blocks_per_step(nb, bs),
-                    "grid_steps": B * -(-nb // P.blocks_per_step(nb, bs)),
-                    "ok": row["fwd_err"] < TOL}
-        yield f"paged decode time {name}", run
+    """Microseconds a ``paged_decode`` call at the serving cells' shapes
+    and three fills each, timed as the decode program runs it
+    (:func:`_paged_layers`), and the fits a reader needs: a fixed cost a
+    call and a cost per occupied block; the steps a call takes, what a
+    step fetches at most (``bytes_step``; ``us_step_bytes`` at the chip's
+    bandwidth) and what a step takes (``us_step_<fill>``, and
+    ``us_per_step`` as the fills' slope), so that ``bytes / bandwidth +
+    fixed`` is read off one table. Checked against the gather reference at
+    the first fill."""
+    for name, *shape in PAGED_CELL_SHAPES:
+        yield (f"paged decode time {name}",
+               functools.partial(_paged_time_row, *shape))
+
+
+def paged_tile_rows():
+    """The rows of :func:`paged_time_rows` at the four large-block shapes
+    with ``paged.STEP_BYTES`` swept (``PAGED_STEP_BYTES``): the census
+    behind the module's constant. A traced program read the constant when
+    it was traced, so every cache of one is dropped on both edges."""
+    def set_step_bytes(n):
+        P.STEP_BYTES = n
+        _paged_layers.cache_clear()
+        jax.clear_caches()
+
+    def run(step_bytes, shape):
+        old = P.STEP_BYTES
+        set_step_bytes(step_bytes)
+        try:
+            return {"step_bytes": step_bytes, **_paged_time_row(*shape)}
+        finally:
+            set_step_bytes(old)
+    for name, *shape in PAGED_CELL_SHAPES[3:]:
+        for step_bytes in PAGED_STEP_BYTES:
+            yield (f"paged decode tile {name} step {step_bytes >> 10}KiB",
+                   functools.partial(run, step_bytes, shape))
 
 
 # the GPT-2 XL cells' decode dispatches as the scheduler leaves the
@@ -340,8 +400,7 @@ def paged_masked_time_rows():
     block and in the prefilling slots' occupied blocks: the live rows are the
     unmasked call's to the bit, the others exactly zero."""
     r = np.random.default_rng(6)
-    bs = 16
-    _, B, Hkv, G, D, nb, window, L, N = PAGED_CELL_SHAPES[0]
+    _, B, Hkv, G, D, bs, nb, window, L, N, _ = PAGED_CELL_SHAPES[0]
     for name, n_live, held, n_pre, done in PAGED_MASKED:
         def run(n_live=n_live, held=held, n_pre=n_pre, done=done):
             k = _rand(r, (L * N, bs, Hkv * D))
@@ -361,8 +420,9 @@ def paged_masked_time_rows():
             row = {"us_every_slot": round(_time_layers(layers, L, *args), 1),
                    "us_live_slots": round(
                        _time_layers(layers, L, *args, active), 1)}
-            tiles = [P.tiles_run(int(n), nb, bs, window) for n in lengths]
-            row["steps_every_slot"] = sum(tiles)
+            tiles = P.tiles_run(lengths, nb, bs, window,
+                                row_bytes=P.pool_row_bytes(k))
+            row["steps_every_slot"] = int(tiles.sum())
             row["steps_live_slots"] = int(np.dot(tiles, live))
             # one layer's pools: a poisoned copy of all 48 would not fit
             bad = np.concatenate(
@@ -851,7 +911,8 @@ def main():
     failed = 0
     with open(OUT, "a") as out:
         for rows in (flash_rows, ring_block_rows, paged_rows,
-                     paged_time_rows, paged_masked_time_rows, mla_time_rows,
+                     paged_time_rows, paged_tile_rows,
+                     paged_masked_time_rows, mla_time_rows,
                      mla_prefill_time_rows, grouped_time_rows,
                      dispatch_operand_rows,
                      int8_matmul_rows, blocksparse_rows):
