@@ -487,6 +487,59 @@ def _mlp_behind(x, h, attn, p, cfg: GPTConfig, lora=None):
         return x + _ffn(_norm(x, p["ln2"], cfg), p, cfg, lora=lora)
 
 
+def _heads_by_config(cfg) -> bool:
+    """Whether the paged attention's q, k, v are more than a split and a
+    rotation of one projection (:func:`_qkv_heads`)."""
+    return any(getattr(cfg, name, False) for name in (
+        "attn_output_gate", "qk_norm", "rotary_half"))
+
+
+def _qkv_heads(qkv, p, cfg: GPTConfig, positions, B: int, S: int):
+    """The fused projection ``qkv`` ``[B, S, .]`` -> q ``[B, S, H, Dh]``, k,
+    v ``[B, S, Hkv, Dh]`` and the attention's output gate ``[B, S, H * Dh]``
+    or None. Data of the config, each absent from a plain GPT block (whose
+    prologue is ``_qkv_split_rotary``, and is traced as it was):
+    ``attn_output_gate``: the query's part is twice as wide, per head ``[q |
+    gate]``, and ``sigmoid(gate)`` scales the attention's output before its
+    projection; ``qk_norm``: ``_norm`` (with the config's ``norm_offset``)
+    over each head's q and k, scales ``q_norm`` / ``k_norm``;
+    ``rotary_half``: the rotate-half convention on the first ``rotary_dim``
+    channels of every head, not the interleaved one."""
+    H, Dh, Hkv = cfg.n_heads, cfg.head_dim, cfg.kv_heads
+    gated = getattr(cfg, "attn_output_gate", False)
+    wq = (2 if gated else 1) * H * Dh
+    q, k, v = jnp.split(qkv, [wq, wq + Hkv * Dh], axis=-1)
+    gate = None
+    if gated:
+        q, gate = jnp.split(q.reshape(B, S, H, 2 * Dh), 2, axis=-1)
+        gate = gate.reshape(B, S, H * Dh)
+    q = q.reshape(B, S, H, Dh)
+    k = k.reshape(B, S, Hkv, Dh)
+    v = v.reshape(B, S, Hkv, Dh)
+    if getattr(cfg, "qk_norm", False):
+        q, k = _norm(q, p["q_norm"], cfg), _norm(k, p["k_norm"], cfg)
+    if cfg.rotary_dim and getattr(cfg, "rotary_half", False):
+        from deepspeed_tpu.ops.attention.rotary import \
+            apply_rotary_half_partial
+        q, k = (apply_rotary_half_partial(a, positions, cfg.rotary_dim,
+                                          cfg.rope_theta) for a in (q, k))
+    elif cfg.rotary_dim:
+        from deepspeed_tpu.ops.attention.rotary import apply_rotary
+        q, k = apply_rotary(q, k, positions, cfg.rotary_dim,
+                            base=cfg.rope_theta)
+    return q, k, v, gate
+
+
+def _gate_output(attn, gate):
+    """The attention ``[B, S, H * Dh]`` times ``sigmoid(gate)``
+    (:func:`_qkv_heads`); as it came where the config has no gate."""
+    if gate is None:
+        return attn
+    with jax.named_scope("attn_gate"):
+        return (attn.astype(jnp.float32) * jax.nn.sigmoid(
+            gate.astype(jnp.float32))).astype(attn.dtype)
+
+
 def _attn_decode_paged(x, pools, tables, lengths, active, p,
                        cfg: GPTConfig, impl: str = "gather", lora=None,
                        base=0, plan=None):
@@ -532,12 +585,16 @@ def _attn_decode_paged(x, pools, tables, lengths, active, p,
     with jax.named_scope("attn_qkv"):
         h = _norm(x, p["ln1"], cfg)
         qkv = _dense(h, p["qkv"], lora=lr("qkv"))
-        q, k, v = jnp.split(qkv, [H * Dh, (H + Hkv) * Dh], axis=-1)
-        if cfg.rotary_dim:
-            from deepspeed_tpu.ops.attention.rotary import apply_rotary
-            q, k = apply_rotary(
-                q.reshape(B, 1, H, Dh), k.reshape(B, 1, Hkv, Dh),
-                lengths[:, None], cfg.rotary_dim, base=cfg.rope_theta)
+        gate = None
+        if _heads_by_config(cfg):
+            q, k, v, gate = _qkv_heads(qkv, p, cfg, lengths[:, None], B, 1)
+        else:
+            q, k, v = jnp.split(qkv, [H * Dh, (H + Hkv) * Dh], axis=-1)
+            if cfg.rotary_dim:
+                from deepspeed_tpu.ops.attention.rotary import apply_rotary
+                q, k = apply_rotary(
+                    q.reshape(B, 1, H, Dh), k.reshape(B, 1, Hkv, Dh),
+                    lengths[:, None], cfg.rotary_dim, base=cfg.rope_theta)
         q = q.reshape(B, Hkv, group, Dh)
         k = k.reshape(B, Hkv, Dh)
         v = v.reshape(B, Hkv, Dh)
@@ -582,7 +639,7 @@ def _attn_decode_paged(x, pools, tables, lengths, active, p,
             attn = paged_decode_attention(
                 q, k_pool, v_pool, tabs, lengths, scale=float(scale),
                 window=cfg.attn_window, k_scale=k_scale,
-                v_scale=v_scale, plan=plan).reshape(B, 1, D)
+                v_scale=v_scale, plan=plan).reshape(B, 1, H * Dh)
     else:
         with jax.named_scope("kv_gather"):
             # [B, NB*bs, Hkv, Dh]
@@ -597,7 +654,9 @@ def _attn_decode_paged(x, pools, tables, lengths, active, p,
             scores = causal_band(scores, idx, lengths[:, None, None, None],
                                  cfg.attn_window)
             probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-            attn = jnp.einsum("bkgs,bskd->bkgd", probs, vc).reshape(B, 1, D)
+            attn = jnp.einsum("bkgs,bskd->bkgd", probs,
+                              vc).reshape(B, 1, H * Dh)
+    attn = _gate_output(attn, gate)
     with jax.named_scope("attn_out"):
         attn = _dense(attn, p["attn_out"], lora=lr("attn_out"))
     if k_scale is None:
@@ -822,6 +881,34 @@ def _attend_occupied(q, k_pool, v_pool, trow, positions, n_valid,
         q, k_pool, v_pool, trow, positions, jnp.int32(lo))
 
 
+def _grouped_rows(cfg: GPTConfig):
+    """The dense pass of :func:`_attend_occupied` for GROUPED queries on
+    several K/V heads (``1 < kv_heads < n_heads``), or None for the two
+    shapes ``_attend_rows``' one product serves well (every head its own
+    K/V head; all heads on one): inference/hybrid.py's ``_attend``, a KV
+    head at a time and, where a head's float32 scores pass its
+    ``SCORE_BYTES``, a block of queries at a time. ``_attend_rows``' one
+    einsum over ``[C, Hkv, group, S]`` compiles, with both a head's group
+    and the K/V heads in it, to a convolution that keeps the chunk's
+    queries on the lanes: 16 ms a layer for every 3,072 keys of history at
+    2 K/V heads under 8 query rows of 256 (PERF.md, PR 54). ``_attend``
+    scales by ``1 / sqrt(head_dim)``: a config with another ``attn_scale``
+    keeps ``_attend_rows``."""
+    H, Hkv = cfg.n_heads, cfg.kv_heads
+    if Hkv in (1, H) or cfg.attn_scale is not None:
+        return None
+    from deepspeed_tpu.inference.hybrid import _attend
+
+    def rows(q, kc, vc, positions, first):
+        C = q.shape[0]
+        kpos = first + jnp.arange(kc.shape[0], dtype=jnp.int32)
+        with jax.named_scope("paged_attn"):
+            out = _attend(q.reshape(C, Hkv, H // Hkv, -1), kc, vc,
+                          positions, kpos, cfg.attn_window)
+        return out.reshape(C, -1)
+    return rows
+
+
 def _block_prefill_paged(x, pools, table_row, positions, n_valid, p,
                          cfg: GPTConfig, lora=None, base=0):
     """One block over a PROMPT CHUNK for one slot:
@@ -868,7 +955,12 @@ def _attn_prefill_paged(x, pools, table_row, positions, n_valid, p,
     with jax.named_scope("attn_qkv"):
         h = _norm(x, p["ln1"], cfg)
         qkv = _dense(h, p["qkv"], lora=lr("qkv"))
-        q, k, v = gpt_lib._qkv_split_rotary(qkv, cfg, positions[None], B, C)
+        gate = None
+        if _heads_by_config(cfg):
+            q, k, v, gate = _qkv_heads(qkv, p, cfg, positions[None], B, C)
+        else:
+            q, k, v = gpt_lib._qkv_split_rotary(qkv, cfg, positions[None],
+                                                B, C)
 
     valid = jnp.arange(C) < n_valid
     trow = table_row + base              # this layer's blocks
@@ -879,7 +971,8 @@ def _attn_prefill_paged(x, pools, table_row, positions, n_valid, p,
             v_pool = paged_cache.write_chunk(
                 v_pool, table_row, positions[0], n_valid, _rows(v[0]), base)
         attn = _attend_occupied(q[0], k_pool, v_pool, trow, positions,
-                                n_valid, cfg, cfg.attn_window)[None]
+                                n_valid, cfg, cfg.attn_window,
+                                _grouped_rows(cfg))[None]
     else:
         with jax.named_scope("kv_write"):
             kq0 = _heads(k_pool[trow], Hkv)
@@ -920,6 +1013,7 @@ def _attn_prefill_paged(x, pools, table_row, positions, n_valid, p,
             vc = quantizer.kv_dequantize_blocks(
                 vq, vsn, dtype=x.dtype).reshape(NB * bs, Hkv, Dh)
         attn = _attend_rows(q[0], kc, vc, positions, 0, cfg)[None]
+    attn = _gate_output(attn, gate)
     with jax.named_scope("attn_out"):
         attn = _dense(attn, p["attn_out"], lora=lr("attn_out"))
     if k_scale is None:

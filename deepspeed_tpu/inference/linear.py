@@ -7,18 +7,28 @@ it holds a token.
   whatever the sequence's length and not recomputable from any block, and
   beside it the un-convolved rows of the last ``conv_kernel - 1`` tokens,
   the left context of a depthwise convolution. No block table reaches
-  either. Two RULES write such a state today, and the store, the layer
-  loop and the resume rule below are the state's, not a rule's:
-  gated delta-rule linear attention (KDA, models/kimi_linear.py: this
-  file's ``kda_*`` blocks over ops/attention/kda.py; ``linear_heads``
-  matrices ``[Dv, Dk]`` a layer, its tail the ``[q | k | v]`` rows) and a
-  Mamba-1 state-space mixer (models/jamba.py: inference/ssm.py over
-  ops/attention/ssm.py; ``[d_state, d_inner]`` a layer, its tail the ``x``
-  rows).
-- The PAGED layers keep rows behind the slot's block table: ONE pool of
-  latent rows (Kimi-Linear's MLA layers, latent.py's two attention paths
-  called from here) or the GPT blocks' K and V pools (Jamba's attention
-  layers, the engine's own two paths called from ssm.py).
+  either. THREE RULES write such a state today, and the store, the layer
+  loop and the resume rule below are the state's, not a rule's
+  (:func:`rule_of`: the config's ``recurrent_rule``):
+  "kda", gated delta-rule linear attention with a decay per key channel
+  (models/kimi_linear.py; ``linear_heads`` matrices ``[Dv, Dk]`` a layer,
+  its tail the ``[q | k | v]`` rows); "gdn", the same rule with ONE decay a
+  head (Gated DeltaNet, models/qwen3_next.py; the same state, tail and step
+  kernel, another chunk form): both are this file's ``delta_*`` blocks over
+  ops/attention/kda.py, told apart by a :class:`DeltaRule` record; and
+  "ssm", a Mamba-1 state-space mixer (models/jamba.py: inference/ssm.py
+  over ops/attention/ssm.py; ``[d_state, d_inner]`` a layer, its tail the
+  ``x`` rows).
+- The PAGED layers keep rows behind the slot's block table, of one of TWO
+  KINDS (:func:`_latent_rows`: the config's ``paged_kind``): "latent", ONE
+  pool of latent rows (Kimi-Linear's MLA layers, latent.py's two attention
+  paths called from here), or "kv", the GPT blocks' K and V pools (Jamba's
+  attention layers and Qwen3-Next's gated ones: the engine's own two paths
+  called from here).
+
+The rule and the paged kind are chosen APART, each by its own datum of the
+config (:func:`prefill_attends`, :func:`decode_attends`): Kimi-Linear is
+(kda, latent), Jamba (ssm, kv), Qwen3-Next the cross pair (gdn, kv).
 
 :class:`LinearState` rides in ``k_pool``'s place (``v_pool`` is None, or
 the V pool where the paged layers keep two): ``rows`` ``[L_paged, N,
@@ -35,10 +45,10 @@ A prefill chunk carries its slot's state from chunk to chunk THROUGH the
 state buffer: it starts from the slot's state and tail when ``start > 0``
 and from zeros when ``start = 0`` (a reused slot starts clean without
 anything being cleared), runs the rule's chunk form (``kda_chunk``,
-``ssm_scan``) and leaves the state after its last valid token. A decode
-dispatch is one recurrent step (``kda_step``, ``ssm_step``) over the
-ACTIVE slots, in place; the state of an idle slot, or of one still in
-prefill, is not touched. A preempted request recomputes from position 0,
+``gdn_chunk``, ``ssm_scan``) and leaves the state after its last valid
+token. A decode dispatch is one recurrent step (``kda_step``, ``ssm_step``)
+over the ACTIVE slots, in place; the state of an idle slot, or of one still
+in prefill, is not touched. A preempted request recomputes from position 0,
 as any other: the replay rebuilds the state.
 
 One compiled body per KIND of layer (:func:`run_layers`): the leading
@@ -53,7 +63,9 @@ host tier, int8 pools, speculation/verify, the fused horizon, LoRA, tensor
 parallelism; nor the static-cache paths. docs/LINEAR_ATTENTION.md,
 docs/STATE_SPACE.md."""
 
-from typing import NamedTuple, Optional
+import contextlib
+import functools
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -87,9 +99,13 @@ def is_linear(cfg) -> bool:
     return bool(getattr(cfg, "recurrent_state_values", 0))
 
 
-# the paged layers keep one pool of latent rows (Kimi-Linear's MLA layers),
-# not K and V pools (Jamba's attention layers)
-_latent_rows = latent.DIALECT.owns
+def _latent_rows(cfg) -> bool:
+    """The paged layers keep ONE pool of latent rows (Kimi-Linear's MLA
+    layers), not K and V pools (Jamba's and Qwen3-Next's attention layers):
+    ``paged_kind`` ("latent" | "kv"), or, for a config from before that
+    key, whether it has a ``kv_lora_rank``."""
+    kind = getattr(cfg, "paged_kind", None)
+    return latent.DIALECT.owns(cfg) if kind is None else kind == "latent"
 
 
 def new_state(cfg, num_blocks: int, block_size: int, num_slots: int, dtype):
@@ -126,6 +142,29 @@ def _unit(x, eps):
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
 
 
+def _conv(xs, left, p):
+    """The depthwise causal convolution over a token's un-convolved row
+    ``xs`` ``[T, C]`` and the ``taps - 1`` rows before it (``left``, the
+    oldest first): tap j meets the token taps - 1 - j back. float32."""
+    f32 = jnp.float32
+    w = p["conv"]["kernel"].astype(f32)                        # [taps, C]
+    return xs.astype(f32) * w[-1] + sum(
+        rows.astype(f32) * w[j] for j, rows in enumerate(left))
+
+
+def _decay(p, step, H):
+    """``g = -exp(A_log) softplus(step + dt_bias)`` of ``H`` heads, float32:
+    ``step`` ``[T, H * n]`` gives ``[T, H, n]`` (KDA: a decay a key
+    channel), ``[T, H]`` gives ``[T, H]`` (Gated DeltaNet: one a head)."""
+    f32 = jnp.float32
+    rate = jnp.exp(p["A_log"].astype(f32))
+    if step.shape[-1] == H:
+        return -rate * jax.nn.softplus(step.astype(f32)
+                                       + p["dt_bias"].astype(f32))
+    return -rate[:, None] * jax.nn.softplus(
+        _heads(step.astype(f32) + p["dt_bias"].astype(f32), H))
+
+
 def _project(h, p):
     """h ``[T, d]`` (normed) -> the tokens' un-convolved ``[q | k | v]``
     rows ``[T, C]`` and what the decay, the output gate and the write
@@ -144,31 +183,80 @@ def _mix(proj, left, p, cfg):
     f32 = jnp.float32
     xs, decay, gate, write = proj
     with jax.named_scope("kda_mix"):
-        # the depthwise convolution: tap j meets the token taps - 1 - j back
-        w = p["conv"]["kernel"].astype(f32)                    # [taps, C]
-        y = xs.astype(f32) * w[-1] + sum(
-            rows.astype(f32) * w[j] for j, rows in enumerate(left))
+        y = _conv(xs, left, p)
         q, k, v = (_heads(a, H) for a in jnp.split(jax.nn.silu(y), 3, -1))
         q = _unit(q, cfg.l2_eps) * Dh ** -0.5
         k = _unit(k, cfg.l2_eps)
-        g = -jnp.exp(p["A_log"].astype(f32))[:, None] * jax.nn.softplus(
-            _heads(decay.astype(f32) + p["dt_bias"].astype(f32), H))
+        g = _decay(p, decay, H)
         b = jax.nn.sigmoid(write.astype(f32))
         gate = jax.nn.sigmoid(_heads(gate.astype(f32), H))
     return (q, k, v, g, b), gate
 
 
-def _output(x, o, gate, p, cfg):
-    """The rule's output ``[T, H, Dh]`` float32, normalised per head, gated
-    and projected back onto the stream ``x``."""
-    with jax.named_scope("kda_out"):
+def _project_gdn(h, p):
+    """Gated DeltaNet's :func:`_project`: ``[q | k | v | z]`` from ONE
+    projection and ``[b | alpha]`` from another; the same four results."""
+    with jax.named_scope("gdn_proj"):
+        C = p["conv"]["kernel"].shape[-1]
+        qkvz, ba = _dense(h, p["in_qkvz"]), _dense(h, p["in_ba"])
+        write, decay = jnp.split(ba, 2, axis=-1)
+        return qkvz[:, :C], decay, qkvz[:, C:], write
+
+
+def _mix_gdn(proj, left, p, cfg):
+    """Gated DeltaNet's :func:`_mix`: ``linear_key_heads`` heads of q and k,
+    each repeated onto the consecutive value heads it serves, ONE decay a
+    value head (g ``[T, H]``), and the gate ``silu(z)`` ``[T, H, Dh]``."""
+    Hk, H, Dh = cfg.linear_key_heads, cfg.linear_heads, cfg.linear_head_dim
+    f32 = jnp.float32
+    xs, decay, z, write = proj
+    with jax.named_scope("gdn_mix"):
+        y = jax.nn.silu(_conv(xs, left, p))
+        q, k, v = jnp.split(y, [Hk * Dh, 2 * Hk * Dh], axis=-1)
+        q = _unit(_heads(q, Hk), cfg.l2_eps) * Dh ** -0.5
+        k = _unit(_heads(k, Hk), cfg.l2_eps)
+        q, k = (jnp.repeat(a, H // Hk, axis=-2) for a in (q, k))
+        g = _decay(p, decay, H)
+        b = jax.nn.sigmoid(write.astype(f32))
+        gate = jax.nn.silu(_heads(z.astype(f32), H))
+    return (q, k, _heads(v, H), g, b), gate
+
+
+class DeltaRule(NamedTuple):
+    """What tells the two gated delta rules apart; the blocks below are
+    what they share. ``name``: the scopes ``attn_<name>``, ``<name>_proj``
+    / ``_mix`` / ``_out`` and the chunk form's; ``project`` / ``mix``: the
+    layer's projections and what makes the rule's operands of them;
+    ``chunk``: the rule's chunk form (ops/attention/kda.py). The decode
+    step is ONE kernel for both: its packed rows carry the decay on the
+    128 lanes of a row a head, and a head's scalar fills that row."""
+    name: str
+    project: Callable
+    mix: Callable
+    chunk: Callable
+
+
+KDA = DeltaRule("kda", _project, _mix, kda.kda_chunk)
+GDN = DeltaRule("gdn", _project_gdn, _mix_gdn, kda.gdn_chunk)
+
+
+def _on_lanes(g, q):
+    """The decay as the step kernel takes it: a value a key channel."""
+    return g if g.ndim == q.ndim else jnp.broadcast_to(g[..., None], q.shape)
+
+
+def _output(x, o, gate, p, cfg, name):
+    """The rule's output ``[T, H, Dh]`` float32, normalised per head (a
+    plain scale), gated and projected back onto the stream ``x``."""
+    with jax.named_scope(name + "_out"):
         o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
                               + cfg.norm_eps) \
             * p["o_norm"]["scale"].astype(jnp.float32)
         return x + _dense(_rows(o * gate).astype(x.dtype), p["attn_out"])
 
 
-def kda_prefill(x, state, tails, slot, positions, n_valid, p, cfg, at):
+def delta_prefill(rule, x, state, tails, slot, positions, n_valid, p, cfg,
+                  at):
     """The linear-attention sublayer over a PROMPT CHUNK of slot ``slot``:
     ``x`` ``[C, d]`` -> (x + attention, state, tails); the slot's state and
     tail lie at ``at + slot`` of the flat buffers."""
@@ -177,47 +265,49 @@ def kda_prefill(x, state, tails, slot, positions, n_valid, p, cfg, at):
     at = at + slot
     resumed = positions[0] > 0
     valid = jnp.arange(C) < n_valid
-    with jax.named_scope("paged_attn"), jax.named_scope("attn_kda"):
+    with jax.named_scope("paged_attn"), jax.named_scope("attn_" + rule.name):
         h = _norm(x, p["ln1"], cfg)
         # position 0 is left-padded with zeros and starts from a zero
         # state: a reused slot starts clean without anything being cleared
         t0 = jnp.where(resumed, tails[at], 0).reshape(taps - 1, -1)
         s0 = jnp.where(resumed, state[at], 0.0)
-        proj = _project(h, p)
+        proj = rule.project(h, p)
         rows = jnp.concatenate([t0, proj[0]], axis=0)          # [taps-1+C, C]
         left = [rows[j:j + C] for j in range(taps - 1)]
-        (q, k, v, g, b), gate = _mix(proj, left, p, cfg)
+        (q, k, v, g, b), gate = rule.mix(proj, left, p, cfg)
         # a padding token leaves the state alone
-        g = jnp.where(valid[:, None, None], g, 0.0)
+        g = jnp.where(jnp.expand_dims(valid, tuple(range(1, g.ndim))), g,
+                      0.0)
         b = jnp.where(valid[:, None], b, 0.0)
-        o, s = kda.kda_chunk(q, k, v, g, b, s0)
+        o, s = rule.chunk(q, k, v, g, b, s0)
         state = state.at[at].set(s)
         # what the next chunk (or the first decode step) resumes from: the
         # rows of the last VALID tokens; with none, the tail as it was
         tails = tails.at[at].set(jax.lax.dynamic_slice_in_dim(
             rows, n_valid, taps - 1).reshape(-1))
-        return _output(x, o, gate, p, cfg), state, tails
+        return _output(x, o, gate, p, cfg, rule.name), state, tails
 
 
-def kda_decode(x, state, tails, active, p, cfg, at, impl, plan):
+def delta_decode(rule, x, state, tails, active, p, cfg, at, impl, plan):
     """The linear-attention sublayer for ONE new token per slot: ``x``
     ``[B, d]`` -> (x + attention, state, tails). Slot ``s``'s state and
     tail lie at ``at + s``; only the ACTIVE slots' are rewritten."""
     B = x.shape[0]
-    with jax.named_scope("paged_attn"), jax.named_scope("attn_kda"):
+    with jax.named_scope("paged_attn"), jax.named_scope("attn_" + rule.name):
         h = _norm(x, p["ln1"], cfg)
         # [B, 3 C], read out BEFORE the update below is formed: fused into
         # it, the shifted read kept the update from running in place and
         # the whole buffer was copied in and out of the program
         t0 = jax.lax.optimization_barrier(
             jax.lax.dynamic_slice_in_dim(tails, at, B))
-        proj = _project(h, p)
+        proj = rule.project(h, p)
         C = proj[0].shape[-1]
-        (q, k, v, g, b), gate = _mix(
+        (q, k, v, g, b), gate = rule.mix(
             proj, jnp.split(t0, cfg.conv_kernel - 1, axis=1), p, cfg)
         own = jnp.concatenate([t0[:, C:], proj[0]], axis=1)
         tails = jax.lax.dynamic_update_slice_in_dim(
             tails, jnp.where(active[:, None], own, t0), at, 0)
+        g = _on_lanes(g, q)
         if impl == "pallas":
             order, count = plan
             state, o = kda.kda_step(state, kda.pack_step(q, k, g, v, b),
@@ -228,51 +318,90 @@ def kda_decode(x, state, tails, active, p, cfg, at, impl, plan):
             with jax.named_scope("kda_step"):
                 state, o = kda.kda_step_reference(state, q, k, v, g, b, at,
                                                   active)
-        return _output(x, o, gate, p, cfg), state, tails
+        return _output(x, o, gate, p, cfg, rule.name), state, tails
+
+
+kda_prefill = functools.partial(delta_prefill, KDA)
+kda_decode = functools.partial(delta_decode, KDA)
+gdn_prefill = functools.partial(delta_prefill, GDN)
+gdn_decode = functools.partial(delta_decode, GDN)
+
+
+def rule_of(cfg) -> str:
+    """Which rule writes the config's recurrent state: ``recurrent_rule``
+    ("kda" | "gdn" | "ssm"), or, for a config from before that key, the
+    state-space one where it has a ``mamba_d_state`` and KDA else."""
+    return getattr(cfg, "recurrent_rule", None) \
+        or ("ssm" if ssm.is_ssm(cfg) else "kda")
+
+
+@contextlib.contextmanager
+def _kv_scope(cfg):
+    """The scopes of a K/V attention layer with an output gate; a plain one
+    (Jamba's) keeps the engine's own."""
+    if not getattr(cfg, "attn_output_gate", False):
+        yield
+        return
+    with jax.named_scope("paged_attn"), jax.named_scope("attn_gated"):
+        yield
 
 
 def prefill_attends(cfg, table_row, positions, n_valid, slot, impl):
     """The two attention sublayers of a PROMPT CHUNK of slot ``slot``, as
     :func:`run_layers` calls them, the recurrent kind's first:
-    ``attend(x [C, d], flat, p, base) -> (x + attention, flat)`` with
-    ``flat`` = (rows, state, tails), or the state-space rule's pair
-    (inference/ssm.py) over (K pool, V pool, state, tails)."""
-    if ssm.is_ssm(cfg):
-        return ssm.prefill_attends(cfg, table_row, positions, n_valid, slot,
-                                   impl)
+    ``attend(x [C, d], flat, p, base) -> (x + attention, flat)``. ``flat``
+    ends (state, tails) and starts with the paged kind's pool or pools:
+    (rows,) of latents or (K pool, V pool). Each half by its OWN datum of
+    the config: the rule (:func:`rule_of`) and the paged kind."""
+    from deepspeed_tpu.inference.engine import _attn_prefill_paged
+    block = {"kda": kda_prefill, "gdn": gdn_prefill,
+             "ssm": functools.partial(ssm.ssm_prefill, impl=impl)}[
+                 rule_of(cfg)]
 
-    def linear_attn(x, flat, p, base):
-        rows, state, tails = flat
-        y, state, tails = kda_prefill(x, state, tails, slot, positions,
-                                      n_valid, p, cfg, base["state"])
-        return y, (rows, state, tails)
+    def recurrent_attn(x, flat, p, base):
+        y, state, tails = block(x, flat[-2], flat[-1], slot, positions,
+                                n_valid, p, cfg, base["state"])
+        return y, flat[:-2] + (state, tails)
 
     def latent_attn(x, flat, p, base):
         y, rows = latent.attend_prefill(x, flat[0], table_row, positions,
                                         n_valid, p, cfg, base["rows"], impl)
         return y, (rows,) + flat[1:]
-    return linear_attn, latent_attn
+
+    def kv_attn(x, flat, p, base):
+        with _kv_scope(cfg):
+            _, attn, kv = _attn_prefill_paged(
+                x[None], flat[:2], table_row, positions, n_valid, p, cfg,
+                base=base["rows"])
+        return x + attn[0], kv + flat[2:]
+    return recurrent_attn, latent_attn if _latent_rows(cfg) else kv_attn
 
 
 def decode_attends(cfg, tables, lengths, active, impl, paged_plan):
     """The same for ONE new token per slot (``x`` ``[B, d]``);
     ``paged_plan``: the paged layers' kernel's grid for these lengths."""
+    from deepspeed_tpu.inference.engine import _attn_decode_paged
     plan = step_plan(active)
-    if ssm.is_ssm(cfg):
-        return ssm.decode_attends(cfg, tables, lengths, active, impl,
-                                  paged_plan, plan)
+    block = {"kda": kda_decode, "gdn": gdn_decode,
+             "ssm": ssm.ssm_decode}[rule_of(cfg)]
 
-    def linear_attn(x, flat, p, base):
-        rows, state, tails = flat
-        y, state, tails = kda_decode(x, state, tails, active, p, cfg,
-                                     base["state"], impl, plan)
-        return y, (rows, state, tails)
+    def recurrent_attn(x, flat, p, base):
+        y, state, tails = block(x, flat[-2], flat[-1], active, p, cfg,
+                                base["state"], impl, plan)
+        return y, flat[:-2] + (state, tails)
 
     def latent_attn(x, flat, p, base):
         y, rows = latent.attend_decode(x, flat[0], tables, lengths, active,
                                        p, cfg, base["rows"], impl, paged_plan)
         return y, (rows,) + flat[1:]
-    return linear_attn, latent_attn
+
+    def kv_attn(x, flat, p, base):
+        with _kv_scope(cfg):
+            _, attn, kv = _attn_decode_paged(
+                x[:, None], flat[:2], tables, lengths, active, p, cfg,
+                impl=impl, base=base["rows"], plan=paged_plan)
+        return x + attn[:, 0], kv + flat[2:]
+    return recurrent_attn, latent_attn if _latent_rows(cfg) else kv_attn
 
 
 def run_layers(cfg, params, experts, carry, flat, bases, attends, valid,
@@ -411,7 +540,7 @@ def gauges(reg, cache):
 
 
 def _refusal(cfg):
-    rule, doc = ("state-space", "STATE_SPACE") if ssm.is_ssm(cfg) \
+    rule, doc = ("state-space", "STATE_SPACE") if rule_of(cfg) == "ssm" \
         else ("linear-attention", "LINEAR_ATTENTION")
     return (f"a per-slot recurrent state (written by its {rule} layers: it "
             f"summarises the whole history and rides beside the paged "

@@ -2429,7 +2429,13 @@ class ServingEngine:
                     ("pairs_zero", vals.get("pairs_zero")),
                     ("real_pairs_max_token_mean",
                      vals["real_pairs_max_token"] / calls
-                     if "real_pairs_max_token" in vals else None)):
+                     if "real_pairs_max_token" in vals else None),
+                    # only a shared expert with a gate of its own: 256ths
+                    # in sixteens, over the tokens (pairs_total / k)
+                    ("shared_gate_mean",
+                     vals["shared_gate_q8"] / 16.0 * self.engine.cfg.moe_k
+                     / max(vals["pairs_total"], 1)
+                     if "shared_gate_q8" in vals else None)):
                 if value is None:
                     continue
                 self.metrics.gauge(

@@ -16,7 +16,9 @@ token; a decode dispatch is one step (``ssm_step``) over the ACTIVE slots,
 in place. The attention layers are the engine's own: ``n_heads`` query
 heads on the one K/V head, nothing rotated, through
 ``_attn_prefill_paged`` (``_attend_occupied``) and ``_attn_decode_paged``
-(``paged_decode``), their pools in the loop's carry beside the state.
+(``paged_decode``), their pools in the loop's carry beside the state: the
+K/V half of linear.py's ``prefill_attends`` / ``decode_attends``, which
+pair it with this rule by the config's data.
 docs/STATE_SPACE.md."""
 
 import jax
@@ -142,44 +144,3 @@ def ssm_decode(x, state, tails, active, p, cfg, at, impl, plan):
                 state, y = ssm.ssm_step_reference(
                     state, _transition(p), xc, delta, B, Cm, at, active)
         return _output(x, y, xc, z, p), state, tails
-
-
-def prefill_attends(cfg, table_row, positions, n_valid, slot, impl):
-    """The two mixers of a PROMPT CHUNK of slot ``slot``, as
-    linear.run_layers calls them: ``attend(x [C, d], flat, p, base) -> (x +
-    mixer, flat)`` with ``flat`` = (K pool, V pool, state, tails)."""
-    from deepspeed_tpu.inference.engine import _attn_prefill_paged
-
-    def state_space(x, flat, p, base):
-        k, v, state, tails = flat
-        y, state, tails = ssm_prefill(x, state, tails, slot, positions,
-                                      n_valid, p, cfg, base["state"], impl)
-        return y, (k, v, state, tails)
-
-    def attention(x, flat, p, base):
-        _, attn, kv = _attn_prefill_paged(
-            x[None], flat[:2], table_row, positions, n_valid, p, cfg,
-            base=base["rows"])
-        return x + attn[0], kv + flat[2:]
-    return state_space, attention
-
-
-def decode_attends(cfg, tables, lengths, active, impl, paged_plan,
-                   step_plan):
-    """The same for ONE new token per slot (``x`` ``[B, d]``);
-    ``paged_plan``: the paged kernel's grid for these lengths;
-    ``step_plan``: the step kernel's work list (linear.step_plan)."""
-    from deepspeed_tpu.inference.engine import _attn_decode_paged
-
-    def state_space(x, flat, p, base):
-        k, v, state, tails = flat
-        y, state, tails = ssm_decode(x, state, tails, active, p, cfg,
-                                     base["state"], impl, step_plan)
-        return y, (k, v, state, tails)
-
-    def attention(x, flat, p, base):
-        _, attn, kv = _attn_decode_paged(
-            x[:, None], flat[:2], tables, lengths, active, p, cfg,
-            impl=impl, base=base["rows"], plan=paged_plan)
-        return x + attn[:, 0], kv + flat[2:]
-    return state_space, attention

@@ -283,7 +283,12 @@ def _norm(x, p, cfg):
         xf = x.astype(jnp.float32)
         y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1,
                                         keepdims=True) + eps)
-        return (y * p["scale"].astype(jnp.float32)).astype(x.dtype)
+        scale = p["scale"].astype(jnp.float32)
+        if getattr(cfg, "norm_offset", False):
+            # the stored scale is an OFFSET from one (zeros at
+            # initialisation: models/qwen3_next.py)
+            scale = 1.0 + scale
+        return (y * scale).astype(x.dtype)
     return _layernorm(x, p["scale"], p["bias"], eps=eps)
 
 

@@ -58,6 +58,12 @@ def n_zero_experts(cfg) -> int:
     return int(getattr(cfg, "n_zero_experts", 0))
 
 
+def has_shared_gate(cfg) -> bool:
+    """Whether the config's shared expert is scaled by a sigmoid gate of
+    its own, ``sigmoid(h . w_sg)`` (:func:`sparse_ffn`)."""
+    return bool(getattr(cfg, "shared_expert_gate", False))
+
+
 def expert_act(cfg) -> str:
     """The gate's activation in the config's experts: "silu" (SwiGLU) or
     "relu" (ReLU-gated: :func:`held_experts_ffn` then counts the zeros)."""
@@ -74,12 +80,16 @@ def stat_fields(cfg) -> Tuple[str, ...]:
     zero-compute experts, ``pairs_zero`` (the pairs on them) and
     ``real_pairs_max_token`` (the most real experts any one token of a
     layer call chose, summed over the calls as ``busiest_expert_pairs``
-    is)."""
+    is); where the shared expert has a gate of its own, ``shared_gate_q8``
+    (the gate's value in 256ths summed over a call's valid tokens, also in
+    SIXTEENS: times 16 over 256 x tokens it is the gate's mean, and says
+    whether the shared expert is on)."""
     return STAT_FIELDS + (("act_zero", "act_total") if expert_act(cfg)
                           == "relu" else ()) \
         + (("pairs_skipped",) if has_router_state(cfg) else ()) \
         + (("pairs_zero", "real_pairs_max_token") if n_zero_experts(cfg)
-           else ())
+           else ()) \
+        + (("shared_gate_q8",) if has_shared_gate(cfg) else ())
 
 
 def route(h, router: Dict, k: int, scaling: float, n_group: int = 1,
@@ -300,7 +310,10 @@ def sparse_ffn(h, moe: Dict, cfg, impl: str, valid=None, mlp=None,
     with the absent ones and counted; ``n_zero_experts`` further outputs
     are identity experts, pairs on no expert that STILL add their weight
     times the token (:func:`zero_experts_term`, under ``moe_zero``), and
-    are counted. No shared expert where the config has none. ``routed`` =
+    are counted. No shared expert where the config has none; with
+    ``shared_expert_gate`` its term is times ``sigmoid(h . w_sg)``, a gate
+    a token (``moe["shared_gate"]``), and the gate's sum is counted.
+    ``routed`` =
     (selection, weights) made earlier in the layer, from another tensor
     than ``h`` (:func:`route_by_config` on the layer's input): no router
     runs here then. Returns
@@ -335,4 +348,13 @@ def sparse_ffn(h, moe: Dict, cfg, impl: str, valid=None, mlp=None,
         return routed, sel, stats, state
     with jax.named_scope("moe_shared"):
         shared = mlp(h, moe["shared"])
+        if has_shared_gate(cfg):
+            gate = jax.nn.sigmoid(jnp.dot(
+                h.astype(jnp.float32),
+                moe["shared_gate"]["kernel"].astype(jnp.float32)))  # [T, 1]
+            shared = (shared.astype(jnp.float32) * gate).astype(shared.dtype)
+            q8 = jnp.round(gate[:, 0] * 256.0).astype(jnp.int32)
+            if valid is not None:
+                q8 = jnp.where(valid, q8, 0)
+            stats = jnp.concatenate([stats, jnp.sum(q8)[None] // 16])
     return routed + shared, sel, stats, state

@@ -16,8 +16,9 @@ the state without a relayout. Three forms of the same numbers:
   sub-chunk, ``w_i = v_i - S'_i^T k_i`` (what the delta rule writes) obeys
   ``(I + tril(A) Diag(b)) W = V - K~ S_0``, ``A_ij = sum_c k_ic k_jc
   exp(G_ic - G_jc)``, ``K~ = k exp(G)``: ONE unit-triangular solve per
-  sub-chunk and head, independent of the state, so all sub-chunks solve at
-  once and only ``W = U - M S_0``, the outputs ``Q~ S_0 + tril(P) Diag(b)
+  sub-chunk and head (:func:`_solve_unit_lower`: matrix products),
+  independent of the state, so all sub-chunks solve at once and only ``W =
+  U - M S_0``, the outputs ``Q~ S_0 + tril(P) Diag(b)
   W`` and ``S = Diag(exp G_c) S_0 + K-^T Diag(b) W`` run in sequence, all
   matmuls. ``exp(G_i - G_j)`` is formed per PAIR (never ``exp(-G_j)``,
   which overflows under a strong decay), every other factor is at most 1.
@@ -28,6 +29,16 @@ the state without a relayout. Three forms of the same numbers:
   kernel that reads and rewrites those slots' state IN PLACE in the flat
   ``[layers * slots, H, Dv, Dk]`` buffer and never touches another slot's.
   Memory-bound: 2 x 64 KiB a head a slot against ~0.1 MFLOP.
+
+The same rule with ONE decay a head a token, ``a_t`` a scalar (Gated
+DeltaNet; models/qwen3_next.py), shares the state's layout, the recurrence
+(:func:`gdn_recurrence`) and the step kernel (the packed rows carry the
+decay as a row of 128 lanes a head: the scalar fills it), and has a chunk
+form of its own, :func:`gdn_chunk`: the pair decay ``exp(G_i - G_j)`` is
+then ``[c, c]`` a head, so ``A = (K K^T) * D`` and ``P = (Q K^T) * D`` are
+matrix products and a mask (:func:`_gdn_pairs`) where KDA's pairs are
+formed a key channel at a time on the vector unit (:func:`_kda_pairs`);
+everything behind ``A`` and ``P`` is one function, :func:`_chunk_form`.
 """
 
 import functools
@@ -56,45 +67,101 @@ def kda_recurrence(q, k, v, g, b, s0):
     return o, s
 
 
-def kda_chunk(q, k, v, g, b, s0, sub: int = 64):
-    """The chunkwise-parallel form of :func:`kda_recurrence`, same
-    arguments and results. A token that must leave the state alone (chunk
-    padding) comes with ``g = 0`` and ``b = 0``."""
+def _kda_pairs(q, k, G, seen):
+    """KDA's ``A`` (keys against keys) and ``P`` (queries against keys)
+    ``[n, H, c, c]``: the decay between each pair is a VECTOR over the key
+    channels, so each pair's product is formed on the vector unit, a
+    sub-chunk at a time (``[H, c, c, K]`` alive at once)."""
+    def pairs(x):
+        qs, ks, Gs = x
+        E = jnp.exp(jnp.where(seen[None, :, :, None],
+                              Gs[:, :, None] - Gs[:, None], -jnp.inf))
+        kd = ks[:, None] * E                           # [H, i, j, K]
+        return (jnp.sum(ks[:, :, None] * kd, -1),
+                jnp.sum(qs[:, :, None] * kd, -1))
+    return jax.lax.map(pairs, (q, k, G))
+
+
+def _gdn_pairs(q, k, G, seen):
+    """The same with ONE decay a head a token (``G`` ``[n, H, c, 1]``): the
+    pair decay ``exp(G_i - G_j)`` is a scalar, ``[n, H, c, c]``, so ``A =
+    (K K^T) * D`` and ``P = (Q K^T) * D`` are two matrix products and a
+    mask; no ``[H, c, c, K]`` temporary exists."""
+    Gs = G[..., 0]
+    D = jnp.exp(jnp.where(seen, Gs[..., :, None] - Gs[..., None, :],
+                          -jnp.inf))
+    return (jnp.einsum("nhik,nhjk->nhij", k, k, precision=HIGHEST) * D,
+            jnp.einsum("nhik,nhjk->nhij", q, k, precision=HIGHEST) * D)
+
+
+SOLVE_BLOCK = 8
+
+
+def _solve_unit_lower(L, rhs):
+    """``X`` with ``(I + L) X = rhs`` for STRICTLY lower-triangular ``L``
+    ``[..., c, c]`` and ``rhs`` ``[..., c, w]``, as matrix products: blocks
+    of ``SOLVE_BLOCK`` rows, each diagonal block inverted by its Neumann
+    series (``L_ii`` is nilpotent: ``(I + L_ii)^-1 = prod_j (I +
+    (-L_ii)^(2^j))``, two squarings for 8 rows) and the blocks below it by forward
+    substitution. ``lax.linalg.triangular_solve`` walks the ``c`` rows one
+    after the other in a custom call: 0.69 ms a layer a 512-token chunk on a
+    v5e, 16% of a prefill-heavy window (PERF.md, PR 54). The series is kept
+    to 8 rows because its terms can be large where the result is not: for a
+    run of identical unit keys ``L`` is all ones, ``L^4`` reaches 35 at 8
+    rows and ``L^8`` 6,435 at 16, where the outputs then lose three digits
+    (tests/test_qwen3_next.py holds that case to the recurrence)."""
+    c = L.shape[-1]
+    bl = SOLVE_BLOCK if c % SOLVE_BLOCK == 0 else c
+    nb = c // bl
+
+    def mm(a, x):
+        return jnp.einsum("...ij,...jk->...ik", a, x, precision=HIGHEST)
+
+    def rows(x, i):
+        return x[..., i * bl:(i + 1) * bl, :]
+    neg = jnp.stack([-rows(L, i)[..., i * bl:(i + 1) * bl]
+                     for i in range(nb)], axis=-3)         # [..., nb, bl, bl]
+    inv, power, reach = jnp.eye(bl) + neg, neg, 2
+    while reach < bl:
+        power = mm(power, power)
+        inv = inv + mm(inv, power)
+        reach *= 2
+    out = []
+    for i in range(nb):
+        r = rows(rhs, i)
+        if i:
+            r = r - mm(rows(L, i)[..., :i * bl], jnp.concatenate(out, -2))
+        out.append(mm(inv[..., i, :, :], r))
+    return jnp.concatenate(out, axis=-2)
+
+
+def _chunk_form(q, k, v, g, b, s0, sub, pairs, scope):
+    """What the two delta rules' chunk forms share (the module docstring's
+    algebra): ``g`` ``[T, H, K]`` or ``[T, H, 1]``, and ``pairs`` the rule's
+    way to ``A`` and ``P``; every other factor broadcasts."""
     T, H, K = q.shape
     V = v.shape[-1]
     n = -(-T // sub)
-    with jax.named_scope("kda_chunk"):
+    with jax.named_scope(scope):
         def cut(x):
             """[T, H, ...] -> [n, H, sub, ...], zeros behind the last."""
             x = jnp.pad(x.astype(jnp.float32),
                         ((0, n * sub - T),) + ((0, 0),) * (x.ndim - 1))
             return jnp.moveaxis(x.reshape((n, sub) + x.shape[1:]), 1, 2)
         q, k, v, g, b = (cut(x) for x in (q, k, v, g, b[..., None]))
-        G = jnp.cumsum(g, axis=2)                          # [n, H, c, K]
+        G = jnp.cumsum(g, axis=2)                          # [n, H, c, K|1]
         i = jnp.arange(sub)
         seen = i[:, None] >= i[None, :]                    # key j <= query i
-
-        def pairs(x):
-            """One sub-chunk's A (keys against keys) and P (queries against
-            keys) ``[H, c, c]``, the decay between each pair inside."""
-            qs, ks, Gs = x
-            E = jnp.exp(jnp.where(seen[None, :, :, None],
-                                  Gs[:, :, None] - Gs[:, None], -jnp.inf))
-            kd = ks[:, None] * E                           # [H, i, j, K]
-            return (jnp.sum(ks[:, :, None] * kd, -1),
-                    jnp.sum(qs[:, :, None] * kd, -1))
-        A, P = jax.lax.map(pairs, (q, k, G))
+        A, P = pairs(q, k, G, seen)
         P = jnp.where(seen, P, 0.0)
         # (I + tril(A, -1) Diag(b)) [U | M] = [V | K~]
-        tri = jnp.where(i[:, None] > i[None, :],
-                        A * jnp.swapaxes(b, -1, -2), 0.0) + jnp.eye(sub)
+        low = jnp.where(i[:, None] > i[None, :],
+                        A * jnp.swapaxes(b, -1, -2), 0.0)
         kt = k * jnp.exp(G)
-        sol = jax.lax.linalg.triangular_solve(
-            tri, jnp.concatenate([v, kt], -1), left_side=True, lower=True,
-            unit_diagonal=True)
+        sol = _solve_unit_lower(low, jnp.concatenate([v, kt], -1))
         U, M = sol[..., :V], sol[..., V:]
         qt = q * jnp.exp(G)
-        last = G[:, :, -1:]                                # [n, H, 1, K]
+        last = G[:, :, -1:]                                # [n, H, 1, K|1]
         kbar = k * jnp.exp(last - G)
 
         def step(s, x):
@@ -109,6 +176,29 @@ def kda_chunk(q, k, v, g, b, s0, sub: int = 64):
                             (U, M, P, qt, kbar, b, last))
         o = jnp.moveaxis(o, 1, 2).reshape(n * sub, H, V)[:T]
     return o, s
+
+
+def kda_chunk(q, k, v, g, b, s0, sub: int = 64):
+    """The chunkwise-parallel form of :func:`kda_recurrence`, same
+    arguments and results. A token that must leave the state alone (chunk
+    padding) comes with ``g = 0`` and ``b = 0``."""
+    return _chunk_form(q, k, v, g, b, s0, sub, _kda_pairs, "kda_chunk")
+
+
+def gdn_recurrence(q, k, v, g, b, s0):
+    """:func:`kda_recurrence` with ONE decay a head a token, ``g`` ``[T,
+    H]`` (Gated DeltaNet; models/qwen3_next.py): what :func:`gdn_chunk`
+    and the step kernel fed that decay on every lane are held to."""
+    return kda_recurrence(q, k, v, jnp.broadcast_to(g[..., None], q.shape),
+                          b, s0)
+
+
+def gdn_chunk(q, k, v, g, b, s0, sub: int = 64):
+    """The chunkwise-parallel form of :func:`gdn_recurrence`: the algebra
+    of :func:`kda_chunk` with the pair decays formed as ``[c, c]`` scalars
+    (:func:`_gdn_pairs`), under the scope ``gdn_chunk``."""
+    return _chunk_form(q, k, v, g[..., None], b, s0, sub, _gdn_pairs,
+                       "gdn_chunk")
 
 
 def _step_kernel(state_ids, rows, count, s_ref, x_ref, so_ref, o_ref, *,

@@ -635,3 +635,83 @@ def test_smallthinker_cell_programs_fit_a_v5e(v5e, program):
             assert math.prod(shape) <= C * group * NB * bs, shape
     if program == "decode_slots":
         assert "paged_decode" in text
+
+
+QWEN3_NEXT = json.loads((
+    pathlib.Path(__file__).parent.parent / "benchmark" / "configs"
+    / "qwen3-next-80b-a3b-serve-ep16pp2.json").read_text())
+
+
+@pytest.mark.parametrize("program", ["prefill_slot", "decode_slots"])
+def test_qwen3_next_cell_programs_fit_a_v5e(v5e, program):
+    """Qwen3-Next's two serving programs (the linear dialect's delta rule
+    with one decay a head beside the engine's gated K/V attention) compiled
+    for a v5e at the cell's own sizes, 24 layers and the configuration's
+    slots, chunk and pool: no copy of a pool, of the recurrent state or of
+    the tails, all four updated in place; no per-channel pair decay
+    (``[32, 64, 64, 128]``: KDA's chunk form) under ``gdn_chunk``, whose
+    pairs are ``[.., 64, 64]`` matrix products; and at least 1.0 GiB of the
+    chip's 15.75 left at the program's peak."""
+    import re
+    from deepspeed_tpu.inference import linear
+    from deepspeed_tpu.models import qwen3_next
+    c, sv = QWEN3_NEXT, QWEN3_NEXT["serving"]
+    cfg = qwen3_next.Qwen3NextConfig(
+        vocab_size=c["vocab_size"], n_layers=c["num_hidden_layers"],
+        d_model=c["hidden_size"], max_seq_len=sv["max_total"],
+        dtype=jnp.bfloat16, experts_held=(0, c["num_experts"]),
+        num_experts=c["published"]["num_experts"],
+        use_flash_attention=False, remat=False)
+    assert qwen3_next.num_params(cfg) == c["parameters_held_here"]
+    B, C, bs = sv["num_slots"], sv["prefill_chunk"], sv["block_size"]
+    NB = cfg.max_seq_len // bs
+    N = sv["num_blocks"] + 1
+    La, Lg = cfg.n_full_layers, cfg.n_recurrent_layers
+    assert (La, Lg, cfg.kv_heads * cfg.head_dim) == (6, 18, 512)
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = jax.tree_util.tree_map(
+        lambda a: S(a.shape, jnp.bfloat16), jax.eval_shape(
+            lambda: qwen3_next.init_params(jax.random.PRNGKey(0), cfg)))
+    pool = S((La, N, bs, 512), jnp.bfloat16)
+    state = linear.LinearState(
+        pool, S((Lg, B, 32, 128, 128), jnp.float32),
+        S((Lg, B, 3 * 8192), jnp.bfloat16))
+    eng = InferenceEngine.__new__(InferenceEngine)
+    eng.cfg, eng.max_seq_len, eng.dtype = cfg, cfg.max_seq_len, jnp.bfloat16
+    eng.decode_impl = "pallas"
+    i32, f32, u32, V = jnp.int32, jnp.float32, jnp.uint32, cfg.vocab_size
+    if program == "prefill_slot":
+        fn = jax.jit(_named(eng._prefill_slot_fn, "serve_prefill_slot"),
+                     donate_argnums=(1, 2))
+        args = (params, state, pool, S((NB,), i32), S((C,), i32), S((), i32),
+                S((), i32), S((2,), u32), S((), i32), S((), f32), S((), i32),
+                S((), f32), S((), f32), S((V,), jnp.bool_), None, None,
+                S((), i32))
+    else:
+        fn = jax.jit(_named(eng._decode_slots_fn, "serve_decode_slots"),
+                     donate_argnums=(1, 2), static_argnums=(7,))
+        args = (params, state, pool, S((B, NB), i32), S((B,), i32),
+                S((B,), i32), S((B,), jnp.bool_), "pallas", S((B, 2), u32),
+                S((B,), i32), S((B,), f32), S((B,), i32), S((B,), f32),
+                S((B,), f32), S((B, V), jnp.bool_))
+    exe = fn.trace(*args).lower(lowering_platforms=("tpu",)).compile()
+    text = exe.as_text()
+    table = parse_provenance(text)
+    assert pool_copy_bytes(table, (N, La * N)) == 0
+    assert pool_copy_bytes(table, (Lg * B,)) == 0
+    buffers = 2 * pool.size * 2 + state.state.size * 4 + state.tail.size * 2
+    m = exe.memory_analysis()
+    assert m.alias_size_in_bytes >= buffers
+    peak = m.argument_size_in_bytes + m.output_size_in_bytes \
+        - m.alias_size_in_bytes + m.temp_size_in_bytes
+    assert 15.75 * (1 << 30) - peak >= 1.0 * (1 << 30), peak / (1 << 30)
+    if program == "decode_slots":
+        assert "kda_step" in text and "paged_decode" in text
+    else:
+        # the pair decays are scalars a pair: sub-chunks of 64 by 64, no
+        # key-channel dimension behind them
+        assert not re.search(r"f32\[(\d+,)*64,64,128\]", text)
+        assert re.search(r"f32\[(\d+,)*64,64\]", text)

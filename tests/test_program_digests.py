@@ -28,14 +28,24 @@ import program_text as PT
 # the ring's gather lies inside the window branch; the logits are the
 # whole-row form's (tests/test_exaone_moe.py,
 # tests/test_smallthinker_serving.py). Its
-# decode program and both programs of the other four are the parent's
+# decode program and both programs of the other four are the parent's.
+# PR 54 re-recorded ``kimi_linear``'s prefill program (below) and added
+# ``gpt``; the others are its parent's
 PARENT = {
     "dots_vlm": ("1f4bc36bb35dad08", "6e38d962ac6c627a"),
     "exaone_moe": ("5156e3f3e5fb0868", "4a6802b404de5550"),
     "smallthinker": ("bfccd691bf9b57c4", "98f190518fa6bd18"),
-    "kimi_linear": ("ff3efea06af0b4f2", "a284d9953d44de1f"),
+    # prefill re-recorded by PR 54, which MEANT to alter it: the chunk
+    # form's unit-triangular solve is matrix products (kda._solve_unit_lower)
+    # in place of lax.linalg.triangular_solve; the numbers are the
+    # recurrence's (tests/test_kimi_linear.py, tests/test_qwen3_next.py)
+    "kimi_linear": ("1743aa2d78785415", "a284d9953d44de1f"),
     "zaya": ("71c82477980eded6", "008d914cbe2106ca"),
     "jamba": ("7bf3f1d239e4fc52", "3e097490323d7ce2"),
+    # the plain K and V pools (GPT-2 XL's programs), recorded at the parent
+    # of PR 54, whose `_attn_*_paged` gained an output gate, q/k norms and
+    # rotate-half rotary as data that this configuration leaves absent
+    "gpt": ("7b43959e8c186def", "6a0a65698605df44"),
 }
 
 
