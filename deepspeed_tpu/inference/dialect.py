@@ -57,8 +57,9 @@ class Dialect(NamedTuple):
     needs_slot: bool = False  # the prefill finds its state by slot index
     # registers the dialect's own gauges, by literal name (dslint DS014)
     gauges: Callable = lambda reg, cache: None
-    # (cfg) -> what the "inference engine ready" line says of the state
-    ready_note: Callable = lambda cfg: ""
+    # (cfg, impl) -> what the "inference engine ready" line says of the
+    # state and of what the engine's impl chooses in the dialect's programs
+    ready_note: Callable = lambda cfg, impl=None: ""
 
 
 def carried_layers(block_prefill, block_decode, plan, flat, layer_bases,

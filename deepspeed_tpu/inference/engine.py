@@ -1262,7 +1262,8 @@ class InferenceEngine:
                  f"{'encoder' if self.is_encoder else 'decoder'}, "
                  f"platform={dev0.platform}, devices={mesh.devices.size}, "
                  f"decode_impl={self.decode_impl}{tables}"
-                 f"{self.dialect.ready_note(config)}", ranks=[0])
+                 f"{self.dialect.ready_note(config, self.decode_impl)}",
+                 ranks=[0])
 
     # ------------------------------------------------------------------
     # params are threaded explicitly (never via self) so jit treats the

@@ -429,7 +429,8 @@ DIALECT = dialect.Dialect(
     state=LatentState, bytes_per_token=kv_bytes_per_token,
     flash_steps=flash_steps, gauges=gauges,
     tile_row_bytes=lambda cfg, pool: None,
-    ready_note=lambda cfg: f", latent rows a token: {cfg.n_full_layers}",
+    ready_note=lambda cfg, impl=None: ", latent rows a token: "
+    f"{cfg.n_full_layers}",
     **dialect.carried_layers(
         block_prefill, block_decode, plan=dialect.rows_plan,
         flat=lambda pools: ((pools[0].rows,), pools[0].stats),

@@ -204,9 +204,11 @@ def _project_gdn(h, p):
 
 
 def _mix_gdn(proj, left, p, cfg):
-    """Gated DeltaNet's :func:`_mix`: ``linear_key_heads`` heads of q and k,
-    each repeated onto the consecutive value heads it serves, ONE decay a
-    value head (g ``[T, H]``), and the gate ``silu(z)`` ``[T, H, Dh]``."""
+    """Gated DeltaNet's :func:`_mix`: ``linear_key_heads`` heads of q and k
+    (``[T, Hk, Dh]``: each serves ``H / Hk`` consecutive value heads, and
+    the rule's chunk form and :func:`delta_decode` repeat it where they
+    must), ONE decay a value head (g ``[T, H]``), and the gate ``silu(z)``
+    ``[T, H, Dh]``."""
     Hk, H, Dh = cfg.linear_key_heads, cfg.linear_heads, cfg.linear_head_dim
     f32 = jnp.float32
     xs, decay, z, write = proj
@@ -215,7 +217,6 @@ def _mix_gdn(proj, left, p, cfg):
         q, k, v = jnp.split(y, [Hk * Dh, 2 * Hk * Dh], axis=-1)
         q = _unit(_heads(q, Hk), cfg.l2_eps) * Dh ** -0.5
         k = _unit(_heads(k, Hk), cfg.l2_eps)
-        q, k = (jnp.repeat(a, H // Hk, axis=-2) for a in (q, k))
         g = _decay(p, decay, H)
         b = jax.nn.sigmoid(write.astype(f32))
         gate = jax.nn.silu(_heads(z.astype(f32), H))
@@ -256,10 +257,11 @@ def _output(x, o, gate, p, cfg, name):
 
 
 def delta_prefill(rule, x, state, tails, slot, positions, n_valid, p, cfg,
-                  at):
+                  at, impl):
     """The linear-attention sublayer over a PROMPT CHUNK of slot ``slot``:
     ``x`` ``[C, d]`` -> (x + attention, state, tails); the slot's state and
-    tail lie at ``at + slot`` of the flat buffers."""
+    tail lie at ``at + slot`` of the flat buffers; ``impl``: the engine's,
+    by which the rule's chunk form chooses its kernel (kda.chunk_form)."""
     C = x.shape[0]
     taps = cfg.conv_kernel
     at = at + slot
@@ -279,7 +281,7 @@ def delta_prefill(rule, x, state, tails, slot, positions, n_valid, p, cfg,
         g = jnp.where(jnp.expand_dims(valid, tuple(range(1, g.ndim))), g,
                       0.0)
         b = jnp.where(valid[:, None], b, 0.0)
-        o, s = rule.chunk(q, k, v, g, b, s0)
+        o, s = rule.chunk(q, k, v, g, b, s0, impl=impl)
         state = state.at[at].set(s)
         # what the next chunk (or the first decode step) resumes from: the
         # rows of the last VALID tokens; with none, the tail as it was
@@ -307,6 +309,7 @@ def delta_decode(rule, x, state, tails, active, p, cfg, at, impl, plan):
         own = jnp.concatenate([t0[:, C:], proj[0]], axis=1)
         tails = jax.lax.dynamic_update_slice_in_dim(
             tails, jnp.where(active[:, None], own, t0), at, 0)
+        q, k = (kda.per_value_head(a, v.shape[-2]) for a in (q, k))
         g = _on_lanes(g, q)
         if impl == "pallas":
             order, count = plan
@@ -355,12 +358,11 @@ def prefill_attends(cfg, table_row, positions, n_valid, slot, impl):
     the config: the rule (:func:`rule_of`) and the paged kind."""
     from deepspeed_tpu.inference.engine import _attn_prefill_paged
     block = {"kda": kda_prefill, "gdn": gdn_prefill,
-             "ssm": functools.partial(ssm.ssm_prefill, impl=impl)}[
-                 rule_of(cfg)]
+             "ssm": ssm.ssm_prefill}[rule_of(cfg)]
 
     def recurrent_attn(x, flat, p, base):
         y, state, tails = block(x, flat[-2], flat[-1], slot, positions,
-                                n_valid, p, cfg, base["state"])
+                                n_valid, p, cfg, base["state"], impl=impl)
         return y, flat[:-2] + (state, tails)
 
     def latent_attn(x, flat, p, base):
@@ -557,6 +559,16 @@ def prefill_reads(cfg, *a) -> int:
     return tile_reads(cfg, *a)
 
 
+def _ready_note(cfg, impl):
+    """Which chunk form a Gated DeltaNet prefill program is traced with."""
+    if rule_of(cfg) != "gdn":
+        return ""
+    form = kda.chunk_form(impl, cfg.linear_heads, cfg.linear_head_dim,
+                          cfg.linear_head_dim,
+                          key_heads=cfg.linear_key_heads)
+    return f", gdn_chunk={form}"
+
+
 DIALECT = dialect.Dialect(
     owns=is_linear, new_state=new_state, pool=lambda k: k.rows,
     prefill_layers=prefill_layers, decode_layers=decode_layers,
@@ -567,4 +579,4 @@ DIALECT = dialect.Dialect(
     if _latent_rows(cfg) else 0,
     tile_row_bytes=lambda cfg, pool: None if _latent_rows(cfg)
     else dialect.pool_row_bytes(pool),
-    needs_slot=True, gauges=gauges)
+    needs_slot=True, gauges=gauges, ready_note=_ready_note)

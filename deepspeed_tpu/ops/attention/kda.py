@@ -39,6 +39,26 @@ then ``[c, c]`` a head, so ``A = (K K^T) * D`` and ``P = (Q K^T) * D`` are
 matrix products and a mask (:func:`_gdn_pairs`) where KDA's pairs are
 formed a key channel at a time on the vector unit (:func:`_kda_pairs`);
 everything behind ``A`` and ``P`` is one function, :func:`_chunk_form`.
+
+A FOURTH form, of the scalar rule only: with ``impl`` "pallas" and heads of
+128 :func:`gdn_chunk` is ONE Mosaic kernel a call
+(:func:`_gdn_chunk_kernel`). Grid (groups of 16 value heads) x (the chunk's
+sub-chunks of 64, in sequence); the group's float32 state is the kernel's
+output block, loaded from ``s0`` at the first sub-chunk, resident in VMEM
+across them and written back once; a grid step reads its sub-chunk's q, k
+(per KEY head, unrepeated), v rows and its g and b, and writes its ``o``:
+no ``[n, H, c, ...]`` intermediate exists in HBM. Two heads' ``[64, 64]``
+matrices lie side by side on the 128 lanes and multiply the block-diagonal
+of the other operand, so every product of the solve fills the matrix unit.
+``G`` is a product with the lower-triangular ones; the unit-triangular
+inverse is built by merging diagonal blocks pairwise from single rows up to
+64 (:func:`_inverse_unit_lower`: NO Neumann series, so nothing grows on a
+run of identical keys) and applied in one product, ``W = (I + L)^-1 (V - K~
+S)``; every product keeps float32 operands at the highest precision.
+:func:`kda_chunk` is still XLA: its pair stage is ``[64, 64, 128]`` work a
+head on the vector unit, a kernel of its own that :func:`_pairs_tail` is
+written to follow (the decay broadcasts from a column as from a row of
+lanes a token).
 """
 
 import functools
@@ -178,10 +198,13 @@ def _chunk_form(q, k, v, g, b, s0, sub, pairs, scope):
     return o, s
 
 
-def kda_chunk(q, k, v, g, b, s0, sub: int = 64):
+def kda_chunk(q, k, v, g, b, s0, sub: int = 64, *, impl=None):
     """The chunkwise-parallel form of :func:`kda_recurrence`, same
     arguments and results. A token that must leave the state alone (chunk
-    padding) comes with ``g = 0`` and ``b = 0``."""
+    padding) comes with ``g = 0`` and ``b = 0``. Plain XLA under every
+    ``impl``: the vector decay's pair stage has no kernel yet
+    (:func:`gdn_chunk` has)."""
+    del impl
     return _chunk_form(q, k, v, g, b, s0, sub, _kda_pairs, "kda_chunk")
 
 
@@ -193,12 +216,269 @@ def gdn_recurrence(q, k, v, g, b, s0):
                           b, s0)
 
 
-def gdn_chunk(q, k, v, g, b, s0, sub: int = 64):
+# value heads a grid step of the chunk kernel: 8 pairs go through each stage
+# together (the census' sweep: 0.50 / 0.34 / 0.29 / 0.27 ms at 2 / 4 / 8 / 16)
+CHUNK_HEADS = 16
+CHUNK_SUB = 64          # two heads' [64, 64] matrices fill a row of 128 lanes
+
+
+def chunk_form(impl, H: int, K: int, V: int, sub: int = CHUNK_SUB,
+               key_heads=None) -> str:
+    """Which implementation :func:`gdn_chunk` takes for these shapes:
+    "mosaic" (the kernel: ``impl`` "pallas", both head sizes the 128 lanes,
+    sub-chunks of 64, the value heads in pairs that have a key head each or
+    share one) or "xla" (:func:`_chunk_form`)."""
+    rep = H // (key_heads or H)
+    served = impl == "pallas" and K == V == 128 and sub == CHUNK_SUB \
+        and H % 2 == 0 \
+        and (rep == 1 or rep % 2 == 0 and _chunk_heads(H) % rep == 0)
+    return "mosaic" if served else "xla"
+
+
+def _chunk_heads(H: int) -> int:
+    """Value heads a grid step: the most, up to ``CHUNK_HEADS``, that
+    divide an even ``H``."""
+    return next(hb for hb in (16, 8, 4, 2)
+                if hb <= CHUNK_HEADS and H % hb == 0)
+
+
+def gdn_chunk(q, k, v, g, b, s0, sub: int = CHUNK_SUB, *, impl=None,
+              interpret: bool = False):
     """The chunkwise-parallel form of :func:`gdn_recurrence`: the algebra
-    of :func:`kda_chunk` with the pair decays formed as ``[c, c]`` scalars
-    (:func:`_gdn_pairs`), under the scope ``gdn_chunk``."""
-    return _chunk_form(q, k, v, g[..., None], b, s0, sub, _gdn_pairs,
-                       "gdn_chunk")
+    of :func:`kda_chunk` with the pair decays formed as ``[c, c]`` scalars,
+    under the scope ``gdn_chunk``. q and k may come with FEWER heads than
+    v, ``[T, Hk, K]``: value head ``h`` reads key head ``h // (H / Hk)``
+    (Gated DeltaNet's grouped keys, unrepeated). With ``impl`` "pallas"
+    and shapes the kernel takes (:func:`chunk_form`) ONE Mosaic kernel
+    (:func:`_gdn_chunk_kernel`); else plain XLA (:func:`_gdn_pairs` and
+    :func:`_chunk_form`), what the kernel is held to."""
+    Hk, K = q.shape[1:]
+    H, V = v.shape[1:]
+    if chunk_form(impl, H, K, V, sub, Hk) == "xla":
+        q, k = (per_value_head(a, H) for a in (q, k))
+        return _chunk_form(q, k, v, g[..., None], b, s0, sub, _gdn_pairs,
+                           "gdn_chunk")
+    with jax.named_scope("gdn_chunk"):
+        return _gdn_chunk_call(q, k, v, g, b, s0, interpret)
+
+
+def per_value_head(a, H: int):
+    """q or k ``[..., Hk, K]`` -> ``[..., H, K]``: a key head repeated onto
+    the consecutive value heads it serves; with a head each, ``a``."""
+    return a if a.shape[-2] == H else jnp.repeat(a, H // a.shape[-2], -2)
+
+
+def _mm(a, x, dims=((1,), (0,))):
+    return jax.lax.dot_general(a, x, (dims, ((), ())), precision=HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _blocks(x, left):
+    """``[c, 2 c]`` (two heads' ``[c, c]`` matrices side by side on the
+    lanes) -> the ``[2 c, 2 c]`` block-diagonal matrix of the two."""
+    return jnp.concatenate([jnp.where(left, x, 0.0),
+                            jnp.where(left, 0.0, x)], axis=0)
+
+
+def _inverse_unit_lower(lows, row, col, left):
+    """``(I + L)^-1`` for STRICTLY lower-triangular ``L``, two heads' side
+    by side (each of ``lows`` ``[c, 2 c]``; ``row`` / ``col``: an entry's
+    row and its column inside its head), by merging diagonal blocks
+    pairwise, ``inv([[A, 0], [C, B]]) = [[A^-1, 0], [-B^-1 C A^-1,
+    B^-1]]``, from single rows (whose inverse is 1) up to ``c``: no Neumann
+    series at all, so no term is larger than the inverses' own entries (the
+    run of identical keys for which :func:`_solve_unit_lower` stops its
+    series at 8 rows). Every product is ``[c, 2 c] x [2 c, 2 c]``: both
+    heads in one pass of the matrix unit's 128 x 128. The pairs of heads
+    go level by level together: a level's two products depend on each
+    other, the pairs' do not."""
+    c = lows[0].shape[0]
+    eye = jnp.where(row == col, 1.0, 0.0)
+    invs = [eye] * len(lows)
+    shift = 0                                   # blocks of m = 2^shift rows
+    while 1 << shift < c:
+        # below the diagonal blocks of m rows, inside those of 2 m
+        at = (row >> shift + 1 == col >> shift + 1) \
+            & (row >> shift != col >> shift)
+        below = [jnp.where(at, low, 0.0) for low in lows]
+        if shift == 0:      # blocks of one row: A^-1 = B^-1 = 1, no product
+            invs = [eye - x for x in below]
+        else:
+            # -B^-1 C A^-1 fills the lower rows of a block of 2 m alone:
+            # where those are whole tiles of 8 sublanes, only they pass
+            # through the matrix unit
+            m = 1 << shift
+            tiles = m % 8 == 0
+            part = [jnp.concatenate([inv[i + m:i + 2 * m]
+                                     for i in range(0, c, 2 * m)], axis=0)
+                    for inv in invs] if tiles else invs
+            half = [_mm(x, _blocks(y, left)) for x, y in zip(part, below)]
+            part = [x - _mm(y, _blocks(inv, left))
+                    for x, y, inv in zip(part, half, invs)]
+            invs = [jnp.concatenate(
+                [y for i in range(0, c, 2 * m)
+                 for y in (inv[i:i + m], x[i // 2:i // 2 + m])], axis=0)
+                for inv, x in zip(invs, part)] if tiles else part
+        shift += 1
+    return invs
+
+
+def _gdn_pair_stage(x, shared: bool, row, col, left):
+    """``A`` and ``P`` of two heads side by side, ``[c, 2 c]`` each, for
+    every pair of ``x`` (:func:`_pairs_tail`'s): the pair decay ``exp(G_i -
+    G_j)`` is formed per pair under the mask, as :func:`_gdn_pairs` forms
+    it. ``shared``: the two heads read ONE key head (q and k hold its rows
+    twice), so one head's rows against both copies of the keys give ``K
+    K^T`` and ``Q K^T`` on both halves of the lanes; else the product of
+    the stacked rows also holds the two heads' cross terms, which the
+    matrix unit's 128 columns carry anyway, and they are dropped."""
+    c = row.shape[0]
+    # [K; Q] K^T: one latch of the keys for both
+    kq = [_mm(jnp.concatenate([k[:c], q[:c]] if shared else [k, q], axis=0),
+              k, ((1,), (1,))) for q, k, _, _, _ in x]
+    A, P = [], []
+    for (_, _, _, G, _), kq in zip(x, kq):
+        if shared:
+            kk, qk = kq[:c], kq[c:]
+        else:
+            kk = jnp.where(left, kq[:c], kq[c:2 * c])
+            qk = jnp.where(left, kq[2 * c:3 * c], kq[3 * c:])
+        # a token's running log-decay on its head's lanes, and as a row:
+        # entry [j, lane of j] of each head, the same numbers
+        G = jnp.where(left, G[:c], G[c:])
+        Gj = jnp.sum(jnp.where(row == col, G, 0.0), axis=0, keepdims=True)
+        D = jnp.exp(jnp.where(row >= col, G - Gj, -jnp.inf))
+        A.append(kk * D)
+        P.append(jnp.where(row >= col, qk * D, 0.0))
+    return A, P
+
+
+def _pairs_tail(x, A, P, s, row, col, left):
+    """Everything behind ``A`` and ``P`` for a sub-chunk of several pairs
+    of heads, their states resident; per pair: ``x`` = (q, k ``[2 c, Dk]``,
+    v ``[2 c, Dv]`` the two heads' rows stacked, ``G`` ``[2 c, 1]`` the
+    running log-decay (a decay a key channel would come ``[2 c, Dk]`` and
+    broadcast the same way), ``b`` ``[2 c, 1]``), ``A``, ``P`` ``[c, 2 c]``
+    side by side, ``s`` the pair's two states ``[Dv, Dk]``. Returns per
+    pair (o ``[2 c, Dv]``, the two states after)."""
+    c = A[0].shape[0]
+    halves = (slice(0, c), slice(c, 2 * c))
+    # b as a row, a column of A a token written
+    lows = [jnp.where(row > col, A * jnp.sum(
+        jnp.where(row == col, jnp.where(left, b[:c], b[c:]), 0.0), axis=0,
+        keepdims=True), 0.0) for (_, _, _, _, b), A in zip(x, A)]
+    invs = _inverse_unit_lower(lows, row, col, left)
+    # stage by stage over the pairs, as the inverse: what follows one
+    # product in the program's text does not wait for it
+    eG = [jnp.exp(G) for _, _, _, G, _ in x]
+    # [K~; Q~] S: what the state holds for the keys, and reads for q
+    ks = [[_mm(jnp.concatenate([k[h] * e[h], q[h] * e[h]], axis=0), s[i],
+               ((1,), (1,))) for i, h in enumerate(halves)]
+          for (q, k, _, _, _), e, s in zip(x, eG, s)]
+    # (I + tril(A) Diag(b)) W = V - K~ S
+    wb = [_mm(_blocks(inv, left),
+              v - jnp.concatenate([y[:c] for y in ks], axis=0)) * b
+          for (_, _, v, _, b), inv, ks in zip(x, invs, ks)]
+    o = [jnp.concatenate([y[c:] for y in ks], axis=0)
+         + _mm(_blocks(P, left), wb) for ks, P, wb in zip(ks, P, wb)]
+    after = []
+    for (_, k, _, G, _), wb, s in zip(x, wb, s):
+        pair = []
+        for i, h in enumerate(halves):
+            # on the lanes first: Mosaic broadcasts a [1, 1] along one axis
+            Gk = G[h] + jnp.zeros_like(k[h])
+            last = Gk[c - 1:c]                             # [1, Dk]
+            pair.append(s[i] * jnp.exp(last) + _mm(
+                wb[h], k[h] * jnp.exp(last - Gk), ((0,), (0,))))
+        after.append(pair)
+    return list(zip(o, after))
+
+
+def _gdn_chunk_kernel(q_ref, k_ref, v_ref, gb_ref, s0_ref, o_ref, s_ref, *,
+                      heads: int, rep: int):
+    """One grid step: ``heads`` value heads' sub-chunk of ``c`` tokens.
+    v_ref, o_ref ``[c, heads * D]`` (a head's rows are 128 lanes of the
+    ``[T, H * D]`` array as it lies), q_ref, k_ref ``[c, heads / rep * D]``
+    (``rep`` value heads read one key head); gb_ref ``[c, 128]``: g on
+    lanes ``0 .. heads``, b on lanes ``64 .. 64 + heads``; s0_ref / s_ref
+    ``[heads, Dv, Dk]``: the output block is the same for every sub-chunk
+    of the head group, so the state stays in VMEM from the first (loaded
+    from s0) to the last (written back once)."""
+    @pl.when(pl.program_id(1) == 0)
+    def _first():
+        s_ref[...] = s0_ref[...]
+
+    c = v_ref.shape[0]
+    D = v_ref.shape[1] // heads
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, 2 * c), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (c, 2 * c), 1)
+    left = lane < c
+    col = lane & (c - 1)
+    gb = gb_ref[...]
+    # the running sum of g down the sub-chunk, every head at once: a product
+    # with the lower-triangular ones (the ones are exact in every pass)
+    Gs = _mm(jnp.where(jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+                       >= jax.lax.broadcasted_iota(jnp.int32, (c, c), 1),
+                       1.0, 0.0), gb)
+
+    def rows(ref, h, rep=1):
+        return jnp.concatenate(
+            [ref[:, (h + i) // rep * D:((h + i) // rep + 1) * D]
+             .astype(jnp.float32) for i in range(2)], axis=0)
+
+    def column(x, at):
+        return jnp.concatenate([x[:, at + i:at + i + 1] for i in range(2)],
+                               axis=0)
+    pairs = range(0, heads, 2)
+    x = [(rows(q_ref, h, rep), rows(k_ref, h, rep), rows(v_ref, h),
+          column(Gs, h), column(gb, 64 + h)) for h in pairs]
+    A, P = _gdn_pair_stage(x, rep % 2 == 0, row, col, left)
+    out = _pairs_tail(x, A, P, [(s_ref[h], s_ref[h + 1]) for h in pairs],
+                      row, col, left)
+    for h, (o, s) in zip(pairs, out):
+        for i in range(2):
+            o_ref[:, (h + i) * D:(h + i + 1) * D] = o[i * c:(i + 1) * c]
+            s_ref[h + i] = s[i]
+
+
+def _gdn_chunk_call(q, k, v, g, b, s0, interpret):
+    """q, k ``[T, Hk, 128]``, v ``[T, H, 128]``, g, b ``[T, H]``, s0 ``[H,
+    128, 128]`` -> (o ``[T, H, 128]`` float32, the state after). Grid:
+    (groups of heads) x (sub-chunks, in sequence); nothing but the operands
+    and the results touches HBM."""
+    T, H, D = v.shape
+    c = CHUNK_SUB
+    hb, rep = _chunk_heads(H), H // q.shape[1]
+    n = -(-T // c)
+
+    def flat(x):
+        return jnp.pad(x.reshape(T, -1), ((0, n * c - T), (0, 0)))
+
+    def lanes(x):
+        """[T, H] -> [H / hb, n c, 64]: a group's heads on the first lanes,
+        zeros behind them and behind the last token (a padding token: g =
+        0, b = 0)."""
+        x = jnp.pad(x.astype(jnp.float32), ((0, n * c - T), (0, 0)))
+        return jnp.pad(jnp.moveaxis(x.reshape(n * c, H // hb, hb), 1, 0),
+                       ((0, 0), (0, 0), (0, 64 - hb)))
+    gb = jnp.concatenate([lanes(g), lanes(b)], axis=-1)
+    wide = pl.BlockSpec((c, hb * D), lambda j, i: (i, j))
+    keys = pl.BlockSpec((c, hb // rep * D), lambda j, i: (i, j))
+    state = pl.BlockSpec((hb, D, D), lambda j, i: (j, 0, 0))
+    o, s = pl.pallas_call(
+        functools.partial(_gdn_chunk_kernel, heads=hb, rep=rep),
+        name="gdn_chunk", grid=(H // hb, n),
+        in_specs=[keys, keys, wide,
+                  pl.BlockSpec((None, c, 128), lambda j, i: (j, i, 0)),
+                  state],
+        out_specs=[wide, state],
+        out_shape=[jax.ShapeDtypeStruct((n * c, H * D), jnp.float32),
+                   jax.ShapeDtypeStruct((H, D, D), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        **({"interpret": True} if interpret else {}),
+    )(flat(q), flat(k), flat(v), gb, s0.astype(jnp.float32))
+    return o[:T].reshape(T, H, D), s
 
 
 def _step_kernel(state_ids, rows, count, s_ref, x_ref, so_ref, o_ref, *,

@@ -290,7 +290,7 @@ def test_decode_leaves_idle_and_prefilling_slots_state_bit_unchanged(model):
     def prefill(x, s, t, n_valid):
         return linear.kda_prefill(x, s, t, jnp.int32(1),
                                   jnp.asarray([5], jnp.int32), n_valid, p,
-                                  cfg, at)
+                                  cfg, at, "gather")
 
     # one test, one call each: jitted for speed, not for reuse
     decode, prefill = jax.jit(decode), jax.jit(prefill)

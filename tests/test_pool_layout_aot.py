@@ -648,10 +648,13 @@ def test_qwen3_next_cell_programs_fit_a_v5e(v5e, program):
     with one decay a head beside the engine's gated K/V attention) compiled
     for a v5e at the cell's own sizes, 24 layers and the configuration's
     slots, chunk and pool: no copy of a pool, of the recurrent state or of
-    the tails, all four updated in place; no per-channel pair decay
-    (``[32, 64, 64, 128]``: KDA's chunk form) under ``gdn_chunk``, whose
-    pairs are ``[.., 64, 64]`` matrix products; and at least 1.0 GiB of the
-    chip's 15.75 left at the program's peak."""
+    the tails, all four updated in place; the chunk form ONE Mosaic kernel
+    under ``gdn_chunk`` (it lowers for a v5e at 32 value heads on 16 key
+    heads of 128 and a chunk of 512), so neither a per-channel pair decay
+    (``[32, 64, 64, 128]``: KDA's chunk form) nor the XLA form's ``[..,
+    64, 64]`` pairs and 8 MiB ``[8, 32, 64, 128]`` float32 temporaries are
+    in the program; and at least 1.0 GiB of the chip's 15.75 left at the
+    program's peak."""
     import re
     from deepspeed_tpu.inference import linear
     from deepspeed_tpu.models import qwen3_next
@@ -711,7 +714,9 @@ def test_qwen3_next_cell_programs_fit_a_v5e(v5e, program):
     if program == "decode_slots":
         assert "kda_step" in text and "paged_decode" in text
     else:
-        # the pair decays are scalars a pair: sub-chunks of 64 by 64, no
-        # key-channel dimension behind them
-        assert not re.search(r"f32\[(\d+,)*64,64,128\]", text)
-        assert re.search(r"f32\[(\d+,)*64,64\]", text)
+        # the kernel keeps a sub-chunk's pairs, its solve and the walk over
+        # the sub-chunks in VMEM: what the XLA form stacked in HBM is gone
+        assert re.search(r'custom_call_target="tpu_custom_call"[^\n]*'
+                         r"gdn_chunk/gdn_chunk/pallas_call", text)
+        assert not re.search(r"f32\[(\d+,)*64,64(,128)?\]", text)
+        assert not re.search(r"f32\[8,32,64,(128|256)\]", text)

@@ -216,3 +216,32 @@ def test_no_recompile_in_steady_state(served):
             srv.step()
             guard += 1
             assert guard < 500
+
+
+def test_the_chunk_kernel_serves_what_the_xla_form_serves(
+        monkeypatch, pallas_interpret):
+    """Linear heads of 128 (the kernel's: a head is the 128 lanes), two
+    value heads a key head as published, ``decode_impl="pallas"`` off a TPU
+    with the kernels interpreted: the prefill chunks go through ONE
+    ``gdn_chunk`` kernel a layer (chunks of 16 tokens, padded to its
+    sub-chunk of 64; the state handed from chunk to chunk and on to the
+    step kernel), and every logit is the XLA form's and the reference's."""
+    import functools
+    from jax.experimental.pallas.ops.tpu import megablox
+    cfg = U.tiny_config(n_layers=4, linear_head_dim=128)
+    params = U.tiny_params(cfg)
+    assert linear._ready_note(cfg, "pallas") == ", gdn_chunk=mosaic"
+    assert linear._ready_note(cfg, "gather") == ", gdn_chunk=xla"
+    assert linear._ready_note(U.tiny_config(), "pallas") == ", gdn_chunk=xla"
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(1, 96, 37), rng.integers(1, 96, 21)]
+    _, want = U.serve_logits(cfg, params, prompts, 4)
+    monkeypatch.setattr(megablox, "gmm",
+                        functools.partial(megablox.gmm, interpret=True))
+    monkeypatch.setenv("DS_PAGED_DECODE_IMPL", "pallas")
+    srv, got = U.serve_logits(cfg, params, prompts, 4)
+    assert srv.decode_impl == "pallas"
+    for rid in want:
+        np.testing.assert_array_equal(got[rid][0], want[rid][0])
+        np.testing.assert_allclose(got[rid][1], want[rid][1], atol=SOUND)
+    assert _worst(U.reference(), cfg, params, prompts, got) < SOUND

@@ -622,6 +622,74 @@ def mla_prefill_time_rows():
         yield f"mla prefill step time {name}", run
 
 
+GDN_CHUNK_REPS = 18     # a qwen3next chunk's linear layers, in turn
+
+
+def gdn_chunk_time_rows():
+    """Milliseconds ONE ``gdn_chunk`` call takes (ops/attention/kda.py: the
+    scalar-decay chunk form) over a 512-token prefill chunk at Qwen3-Next's
+    32 value heads on 16 key heads, all of 128, as plain XLA and as the
+    Mosaic kernel (``ms_kernel_own_keys``: fed a key head a value head),
+    and the kernel with other numbers of heads a grid step; both forms
+    against the token recurrence under decays that overflow ``exp(-G)``
+    (``err_*``: over the largest output, and the state's)."""
+    from deepspeed_tpu.ops.attention import kda
+    T, H, Hk, D = 512, 32, 16, 128
+
+    def inputs():
+        ks = jax.random.split(jax.random.PRNGKey(7), 6)
+
+        def unit(x):
+            return x / jnp.sqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+        q = unit(jax.nn.silu(jax.random.normal(ks[0], (T, Hk, D)))) \
+            * D ** -0.5
+        k = unit(jax.nn.silu(jax.random.normal(ks[1], (T, Hk, D))))
+        v = jax.nn.silu(jax.random.normal(ks[2], (T, H, D)))
+        g = -jnp.exp(jax.random.uniform(ks[3], (T, H), minval=-6.0,
+                                        maxval=2.5))
+        b = jax.nn.sigmoid(jax.random.normal(ks[4], (T, H)))
+        return q, k, v, g, b, jax.random.normal(ks[5], (H, D, D))
+
+    def program(impl):
+        def many(q, k, v, g, b, s):
+            def one(_, c):
+                o, s = kda.gdn_chunk(q, k, c[0], g, b, c[1], impl=impl)
+                return v + 1e-3 * o, s
+            return jax.lax.fori_loop(0, GDN_CHUNK_REPS, one, (v, s))
+        return jax.jit(many)
+
+    def once(impl):
+        return jax.jit(functools.partial(kda.gdn_chunk, impl=impl))
+
+    def run():
+        args = inputs()
+        each = tuple(kda.per_value_head(a, H) for a in args[:2]) + args[2:]
+        want = jax.jit(kda.gdn_recurrence)(*each)
+        row = {"ms_kernel_own_keys": round(
+            _best_seconds(program("pallas"), *each) / GDN_CHUNK_REPS * 1e3,
+            4)}
+        for impl, tag in (("gather", "xla"), ("pallas", "kernel")):
+            row[f"ms_{tag}"] = round(_best_seconds(program(impl), *args)
+                                     / GDN_CHUNK_REPS * 1e3, 4)
+            got = once(impl)(*args)
+            row[f"err_{tag}"] = max(_err(a, w) for a, w in zip(got, want))
+        return {**row, "ok": max(row["err_xla"], row["err_kernel"]) < 1e-4}
+    yield "gdn chunk time qwen3next", run
+
+    def sweep():
+        args, row, was = inputs(), {}, kda.CHUNK_HEADS
+        try:
+            for hb in (2, 4, 8, 16):
+                kda.CHUNK_HEADS = hb
+                row[f"ms_heads_{hb}"] = round(
+                    _best_seconds(program("pallas"), *args)
+                    / GDN_CHUNK_REPS * 1e3, 4)
+        finally:
+            kda.CHUNK_HEADS = was
+        return {**row, "ok": True}
+    yield "gdn chunk heads a step qwen3next", sweep
+
+
 # the four sparse cells' expert layers: (cell, d, f, experts held, experts
 # routed over, experts a token, sparse layers stacked behind the ``layer``
 # index, tokens of a decode dispatch, tokens of a prefill chunk, the tiles
@@ -913,7 +981,8 @@ def main():
         for rows in (flash_rows, ring_block_rows, paged_rows,
                      paged_time_rows, paged_tile_rows,
                      paged_masked_time_rows, mla_time_rows,
-                     mla_prefill_time_rows, grouped_time_rows,
+                     mla_prefill_time_rows, gdn_chunk_time_rows,
+                     grouped_time_rows,
                      dispatch_operand_rows,
                      int8_matmul_rows, blocksparse_rows):
             for name, run in rows():
