@@ -329,6 +329,13 @@ def drive(ctx, srv, log, counts, mix, seconds, rng, trace=False):
     # inside the dispatches) and the longest stretches between two steps
     steps = log.named("step", ws, t_end)
     n_steps = len(steps)
+    # every dispatch that lay inside the window: how many, and the longest
+    # one (a stall of the machine's shows here and in no median; it stays
+    # in every end-to-end metric, and the result's line says it)
+    dispatched = [s[2] - s[1] for s in log.spans
+                  if s[0].endswith("_dispatch") and ws <= s[1]
+                  and s[2] <= t_end]
+    longest_dispatch_s = max(dispatched or [0.0])
     longest_steps = [
         [a - ws, 1e3 * (b - a), 1e3 * sum(
             s[2] - s[1] for s in log.spans
@@ -352,7 +359,8 @@ def drive(ctx, srv, log, counts, mix, seconds, rng, trace=False):
         ttft_p95_ms=pct(ttft, 95) if not closed else None,
         serve_tok_s=e2e["serve_tok_s"],
         output_tok_s=emitted / (t_end - ws),
-        steps=n_steps,
+        steps=n_steps, dispatches=len(dispatched),
+        longest_dispatch_s=longest_dispatch_s,
         gen_late_p99_ms=pct(late, 99) if not closed else None,
         # slots decoding per decode dispatch: over the window, and per 5 s
         # of it (the first bin against the rest shows whether the ramp was
@@ -404,6 +412,8 @@ def drive(ctx, srv, log, counts, mix, seconds, rng, trace=False):
         "unfinished_at_end": unfinished_at_end,
         "window_row": row, "run": run,
         "compiles_inside": compiled_inside,
+        # beside the metrics in the result's line (run.py)
+        "longest_dispatch_s": longest_dispatch_s,
         # requests the window finished, for the check after it
         "finished_in_window": [r.req for r in sub if r.done is not None
                                and ws <= r.done <= t_end

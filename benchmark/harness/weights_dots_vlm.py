@@ -7,11 +7,12 @@ a whole stack is ever alive beside 10 GiB of weights. Then
 ``balance_router_bias`` runs the family's load-balancing rule on the
 selection bias to rest."""
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from harness.weights import (SIGMOID_RATE, balanced_bias,
+                             worst_load_over_mean)
 
 
 def dots_vlm_params(seed: int, cfg, dtype, std: float = 0.02,
@@ -73,39 +74,6 @@ def dots_vlm_params(seed: int, cfg, dtype, std: float = 0.02,
                 1, 0, 2).reshape(d, V)}}
 
 
-def _select(biased, rule):
-    """The router's selection for biased scores ``[N, E]``; ``rule`` =
-    (groups, groups kept, k): top-k inside the kept groups, those whose two
-    best scores sum highest."""
-    N, E = biased.shape
-    G, kept_groups, k = rule
-    if G > 1:
-        per = biased.reshape(N, G, E // G)
-        best2 = jnp.sum(jax.lax.top_k(per, 2)[0], -1)
-        kept = jnp.any(jax.lax.top_k(best2, kept_groups)[1][:, :, None]
-                       == jnp.arange(G), axis=1)
-        biased = jnp.where(kept[:, :, None], per, -jnp.inf).reshape(N, E)
-    return jax.lax.top_k(biased, k)[1]
-
-
-@functools.partial(jax.jit, static_argnames=("rule", "steps"))
-def _balanced_bias(scores, bias, rule, steps):
-    """The family's own balancing (DeepSeek-V3's auxiliary-loss-free rule:
-    an expert that gets more than its share has its selection bias
-    lowered, one that gets less raised), run to rest on the calibration
-    tokens' scores ``[N, E]``: float32 bias ``[E]``."""
-    E = scores.shape[1]
-
-    def step(i, b):
-        sel = _select(scores + b, rule)
-        load = jnp.zeros((E,), jnp.float32).at[sel.reshape(-1)].add(1.0)
-        load = load / (sel.size / E)
-        rate = 0.05 * (0.02 / 0.05) ** (i / max(steps - 1, 1))
-        return b + rate * jnp.clip(1.0 - load, -1.0, 1.0)
-
-    return jax.lax.fori_loop(0, steps, step, bias.astype(jnp.float32))
-
-
 def balance_router_bias(params, cfg, seed, reference, hp, tokens=4096,
                         steps=300):
     """Replace each sparse layer's selection bias (random so far) by one
@@ -144,11 +112,6 @@ def balance_router_bias(params, cfg, seed, reference, hp, tokens=4096,
     def sparse_ffn(x, p):
         return reference._sparse_ffn(x, p, dict(key), none, False, free)[0]
 
-    def worst(scores, b):
-        sel = np.asarray(_select(scores + b.astype(jnp.float32), rule))
-        load = np.bincount(sel.reshape(-1), minlength=cfg.num_experts)
-        return float(load.max() / load.mean())
-
     def layer(stack, l):
         return jax.tree_util.tree_map(lambda a: a[l], params[stack])
 
@@ -164,8 +127,10 @@ def balance_router_bias(params, cfg, seed, reference, hp, tokens=4096,
     for l in range(cfg.n_sparse_layers):
         p = layer("block", l)
         x, scores = scores_of(x, p)
-        b = _balanced_bias(scores, old[l], rule, int(steps)).astype(old.dtype)
-        report.append([worst(scores, old[l]), worst(scores, b)])
+        b = balanced_bias(scores, old[l], rule, int(steps),
+                          SIGMOID_RATE).astype(old.dtype)
+        report.append([worst_load_over_mean(scores, old[l], rule),
+                       worst_load_over_mean(scores, b, rule)])
         biases.append(b)
         p["moe"]["router"]["bias"] = b
         x = jax.block_until_ready(sparse_ffn(x, p))

@@ -15,7 +15,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from harness.weights_dots_vlm import _balanced_bias, _select
+from harness.weights import (SIGMOID_RATE, balanced_bias,
+                             worst_load_over_mean)
 
 SEQUENCES_AT_ONCE = 8
 
@@ -105,7 +106,7 @@ def balance_router_bias(params, cfg, seed, reference, hp, tokens=4096,
                         steps=300, sequences=None, counted=None):
     """Replace each sparse layer's selection bias (random so far) by one at
     REST under the family's auxiliary-loss-free balancing rule
-    (``weights_dots_vlm._balanced_bias``, with one group: plain top-k), as
+    (``weights.balanced_bias``, with one group: plain top-k), as
     a trained model's is: calibration tokens go through the layers once
     (the plain reference's own layer functions: this is calibration, not a
     check), and at each sparse layer the bias is run to rest on that
@@ -144,11 +145,6 @@ def balance_router_bias(params, cfg, seed, reference, hp, tokens=4096,
     ffn = jax.jit(jax.vmap(lambda x, p: reference.ffn_layer(
         x, p, free, key=key, variant=none, fp8=False)[0], in_axes=(0, None)))
 
-    def worst(scores, b):
-        sel = np.asarray(_select(scores + b.astype(jnp.float32), rule))
-        load = np.bincount(sel.reshape(-1), minlength=cfg.num_experts)
-        return float(load.max() / load.mean())
-
     # SEQUENCES_AT_ONCE sequences in one call; the stream stays in those
     # pieces from layer to layer
     pieces = range(0, ids.shape[0], SEQUENCES_AT_ONCE)
@@ -163,9 +159,10 @@ def balance_router_bias(params, cfg, seed, reference, hp, tokens=4096,
         if l >= nd:
             scores = jnp.concatenate([scores_of(x, p).reshape(
                 -1, cfg.num_experts) for x in xs])[counted]
-            b = _balanced_bias(scores, old[l - nd], rule,
-                               int(steps)).astype(old.dtype)
-            report.append([worst(scores, old[l - nd]), worst(scores, b)])
+            b = balanced_bias(scores, old[l - nd], rule, int(steps),
+                              SIGMOID_RATE).astype(old.dtype)
+            report.append([worst_load_over_mean(scores, old[l - nd], rule),
+                           worst_load_over_mean(scores, b, rule)])
             biases.append(b)
             p["moe"]["router"]["bias"] = b
             del scores

@@ -7,11 +7,11 @@ expert) at a time, so that no float32 copy of a whole stack is ever alive
 beside 9.6 GiB of weights. Then ``balance_router_bias`` runs the family's
 load-balancing rule on the selection bias to rest."""
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from harness.weights import balanced_bias, softmax_rate, stored_bias
 
 SEQUENCES_AT_ONCE = 16
 
@@ -67,38 +67,6 @@ def longcat_flash_params(seed: int, cfg, dtype, std: float = 0.02):
             "ln_f": {"scale": ones(d)},
             "lm_head": {"kernel": normal((rows, d, V // rows)).transpose(
                 1, 0, 2).reshape(d, V)}}
-
-
-def _stored(b, dtype):
-    """The bias as the program reads it: centred (a common offset chooses
-    nothing, and costs the stored type its resolution) and in ``dtype``."""
-    return (b - jnp.mean(b)).astype(dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("k", "steps", "dtype"))
-def _balanced_bias(probs, bias, k, steps, dtype):
-    """The family's balancing rule (an output chosen more often than its
-    share has its selection bias lowered, one chosen less often raised: a
-    step against each output's excess load), run to rest on the calibration
-    tokens' probabilities ``[N, E + Z]`` for the choice ``top_k(p + b)``.
-    Every output's share is ``1 / (E + Z)``: the real experts level among
-    themselves and the zero-compute experts at ``Z / (E + Z)`` of the
-    pairs. The choice is made with the bias AS STORED (:func:`_stored`), so
-    the rule comes to rest among the values the served type can hold; the
-    float32 bias it keeps moving is returned."""
-    n_out = probs.shape[1]
-
-    def step(i, b):
-        sel = jax.lax.top_k(probs + _stored(b, dtype).astype(jnp.float32),
-                            k)[1]
-        load = jnp.zeros((n_out,), jnp.float32).at[sel.reshape(-1)].add(1.0) \
-            / (sel.size / n_out)
-        # probabilities of a softmax over E + Z outputs lie near 1 / (E +
-        # Z): the rate starts at that scale and ends three orders below it
-        rate = (0.2 / n_out) * 1e-3 ** (i / max(steps - 1, 1))
-        return b + rate * jnp.clip(1.0 - load, -1.0, 1.0)
-
-    return jax.lax.fori_loop(0, steps, step, bias.astype(jnp.float32))
 
 
 def balance_router_bias(params, cfg, seed, reference, hp, tokens=4096,
@@ -170,8 +138,13 @@ def balance_router_bias(params, cfg, seed, reference, hp, tokens=4096,
             probs.append(pr.reshape(-1, pr.shape[-1]))
         del x, u, pr
         probs = jnp.concatenate(probs)[counted]
-        b = _stored(_balanced_bias(probs, old[l], K, int(steps), old.dtype),
-                    old.dtype)
+        # every output's share is 1 / (E + Z): the real experts level
+        # among themselves and the zero-compute ones at Z / (E + Z) of the
+        # pairs; the choice is made with the bias as stored
+        b = stored_bias(balanced_bias(
+            probs, old[l], (1, 1, K), int(steps),
+            softmax_rate(probs.shape[1]), stored=jnp.dtype(old.dtype).name),
+            old.dtype)
         before, after = loads(probs, old[l]), loads(probs, b)
         report.append([before[0], after[0], before[1], after[1]])
         biases.append(b)

@@ -9,11 +9,11 @@ jitted call per leaf, a layer (or an expert) at a time, so that no float32
 copy of a whole stack is ever alive beside 8.7 GiB of weights. Then
 ``balance_router_bias`` runs the selection bias to rest."""
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from harness.weights import balanced_bias, softmax_rate, stored_bias
 
 
 # exp(temp) multiplies the keys. At its initial 0 a random model's scores are
@@ -91,36 +91,6 @@ def zaya_params(seed: int, cfg, dtype, std: float = 0.02,
             "block": block, "ln_f": {"scale": ones(d)}}
 
 
-def _stored(b, dtype):
-    """The bias as the program reads it: centred (a common offset chooses
-    nothing, and costs the stored type its resolution) and in ``dtype``."""
-    return (b - jnp.mean(b)).astype(dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("steps", "dtype"))
-def _balanced_bias(probs, bias, target, steps, dtype):
-    """The auxiliary-loss-free balancing rule (an output chosen more often
-    than its share has its selection bias lowered, one chosen less often
-    raised), run to rest on the calibration tokens' probabilities ``[N, E +
-    1]`` for the top-1 choice ``argmax(p + b)``; ``target`` ``[E + 1]`` the
-    share each output should take. The choice is made with the bias AS
-    STORED (:func:`_stored`), so the rule comes to rest among the values the
-    served type can hold; the float32 bias it keeps moving is returned."""
-    n_out = probs.shape[1]
-
-    def step(i, b):
-        sel = jnp.argmax(probs + _stored(b, dtype).astype(jnp.float32), -1)
-        load = jnp.zeros((n_out,), jnp.float32).at[sel].add(1.0) \
-            / probs.shape[0]
-        # probabilities of a softmax over E + 1 outputs lie near 1 / (E +
-        # 1) and differ between tokens by a fraction of that: the rate
-        # starts at that scale and ends three orders below it
-        rate = (0.2 / n_out) * 1e-3 ** (i / max(steps - 1, 1))
-        return b + rate * jnp.clip(1.0 - load / target, -1.0, 1.0)
-
-    return jax.lax.fori_loop(0, steps, step, bias.astype(jnp.float32))
-
-
 def balance_router_bias(params, cfg, seed, reference, hp, tokens=4096,
                         steps=1500, skip_share=None, sequences=None,
                         counted=None):
@@ -193,8 +163,11 @@ def balance_router_bias(params, cfg, seed, reference, hp, tokens=4096,
             probs.append(pr.reshape(-1, E + 1))
         del x, pr
         probs = jnp.concatenate(probs)[counted]
-        b = _stored(_balanced_bias(probs, old[l], target, int(steps),
-                                   old.dtype), old.dtype)
+        # top-1 of E + 1 outputs towards `target`; the choice is made with
+        # the bias as stored
+        b = stored_bias(balanced_bias(
+            probs, old[l], (1, 1, 1), int(steps), softmax_rate(E + 1),
+            target=target, stored=jnp.dtype(old.dtype).name), old.dtype)
         report.append([loads(probs, old[l])[0], *loads(probs, b)])
         biases.append(b)
         p["moe"]["router"]["bias"] = b

@@ -23,6 +23,7 @@ T_PROCESS = __import__("time").perf_counter()
 import argparse  # noqa: E402
 import glob  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
@@ -204,12 +205,22 @@ def main():
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     out["metrics"] = metrics
     out["device"] = device
+    # a serving run's longest single dispatch of its window, so that a
+    # reader can tell a stalled run from a slow program (ISSUE 56 B.3); the
+    # contract's reader ignores the key
+    if "longest_dispatch_s" in res:
+        out["longest_dispatch_s"] = res["longest_dispatch_s"]
     # every number compared beside its limit, last on standard error too
     say(info="compared", correct=correct,
         compared=[[n, v, lim] for n, v, lim in compared])
     for n, v, lim in compared:
         print(f"compared: {n} = {v} (limit {lim})", file=sys.stderr)
     print(f"correct: {correct}", file=sys.stderr, flush=True)
+    # and in the result's line, under a key of its own that comes last (a
+    # number that is not finite goes as text: the line stays strict JSON)
+    out["compared"] = {
+        n: [v if not isinstance(v, float) or math.isfinite(v) else repr(v), lim]
+        for n, v, lim in compared}
     if args.rehearse:
         print("REHEARSAL on " + devs[0].platform + " (tiny size, NOT a "
               "measurement of the cell; no result is printed): "

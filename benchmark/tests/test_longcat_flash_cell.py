@@ -175,7 +175,7 @@ def test_every_listed_metric_has_a_reader_and_the_entries_are_there():
     assert work == dict(work, config=CONFIG, traffic="agent-backlog",
                         chips=1)
     tput = next(m for m in man["end_to_end"] if m["name"] == "serve_tok_s")
-    assert CELL in tput["workloads"] and tput["bound"] == 0.02
+    assert CELL in tput["workloads"] and tput["bound"] == 0.04
 
 
 def test_the_traffic_file_has_only_keys_the_generator_reads():

@@ -133,7 +133,7 @@ def test_every_listed_metric_has_a_reader_and_new_entries_were_appended():
         if CELL in m.get("workloads", ()) and m["name"] not in NEW_METRICS:
             assert m["workloads"].index(CELL) >= 1, m["name"]
     tput = next(m for m in man["end_to_end"] if m["name"] == "serve_tok_s")
-    assert CELL in tput["workloads"] and tput["bound"] == 0.02
+    assert CELL in tput["workloads"] and tput["bound"] == 0.04
     assert sum(w["chips"] == 4 for w in man["workloads"]) == 1
 
 
@@ -144,7 +144,9 @@ def test_nothing_accepted_changed():
     def git(*args):
         return subprocess.run(("git", "-C", ROOT) + args, text=True,
                               capture_output=True)
-    base = git("log", "--format=%H", "-n", "1", "--grep", "^PR 53:")
+    # PR 56 (a `benchmark` PR) edited accepted files, as only its kind
+    # may: what stands since then is what no later PR may edit
+    base = git("log", "--format=%H", "-n", "1", "--grep", "^PR 56:")
     if base.returncode or not base.stdout.strip():
         pytest.skip("no git history to compare with")
     parent = base.stdout.strip()

@@ -42,27 +42,28 @@ def test_open_loop_same_multiset_and_counts_for_every_seed():
 
 
 def test_chat_cell_rate_ramp_and_window_counts():
-    """The cell as PR 27 re-anchored it: 0.8 of the knee of 2.75 req/s, a
-    ramp of 33 requests (15 s, over four residence times of about 3.5 s),
-    99 requests due inside a 45 s window, and one fixed schedule."""
+    """The cell as PR 56 re-anchored it: 0.8 of the knee of 7.0 req/s swept
+    on that tree's program, a ramp of 84 requests (15 s, ten residence times
+    of about 1.4 s), 252 requests due inside a 45 s window, and one fixed
+    schedule."""
     m = mix("chat-open-0p8")
-    assert m["rate_rps"] == 2.2 == round(0.8 * 2.75, 6)
-    assert m["ramp_requests"] == 33
+    assert m["rate_rps"] == 5.6 == round(0.8 * 7.0, 6)
+    assert m["ramp_requests"] == 84
     plans = [traffic.open_loop_plan(m, 45.0, np.random.default_rng(
         m["schedule_seed"])) for _ in range(2)]
     assert plans[0] == plans[1]               # the schedule is the mix's
     ramp_s, plan = plans[0]
-    assert ramp_s == 33 / 2.2 >= 3 * 3.5
+    assert abs(ramp_s - 15.0) < 1e-9 and ramp_s >= 10 * 1.4
     due = np.array([d for d, _, _ in plan])
-    assert (due < ramp_s).sum() == 33
-    assert ((due >= ramp_s) & (due < ramp_s + 45.0)).sum() == 99 == len(
-        plan) - 33
+    assert (due < ramp_s).sum() == 84
+    assert ((due >= ramp_s) & (due < ramp_s + 45.0)).sum() == 252 == len(
+        plan) - 84
     # the neighbouring rates of the steadiness test keep the multiset's
     # shape: the same quantile grid at another count
-    for scale, n_win in ((0.9, 89), (1.1, 109)):
-        _, p2 = traffic.open_loop_plan(dict(m, rate_rps=2.2 * scale), 45.0,
+    for scale, n_win in ((0.9, 227), (1.1, 277)):
+        _, p2 = traffic.open_loop_plan(dict(m, rate_rps=5.6 * scale), 45.0,
                                        np.random.default_rng(23))
-        assert len(p2) == 33 + n_win
+        assert len(p2) == 84 + n_win
         assert abs(np.median([p for _, p, _ in p2]) - 192) <= 4
 
 

@@ -180,7 +180,7 @@ def test_every_listed_metric_has_a_reader_and_new_entries_were_appended():
         if CELL in m.get("workloads", ()) and m["name"] not in NEW_METRICS:
             assert m["workloads"][-1] == CELL, m["name"]
     tput = next(m for m in man["end_to_end"] if m["name"] == "serve_tok_s")
-    assert tput["workloads"][-1] == CELL and tput["bound"] == 0.02
+    assert tput["workloads"][-1] == CELL and tput["bound"] == 0.04
 
 
 def test_nothing_accepted_changed():
@@ -190,7 +190,9 @@ def test_nothing_accepted_changed():
     def git(*args):
         return subprocess.run(("git", "-C", ROOT) + args, text=True,
                               capture_output=True)
-    base = git("log", "--format=%H", "-n", "1", "--grep", "^PR 33:")
+    # PR 56 (a `benchmark` PR) edited accepted files, as only its kind
+    # may: what stands since then is what no later PR may edit
+    base = git("log", "--format=%H", "-n", "1", "--grep", "^PR 56:")
     if base.returncode or not base.stdout.strip():
         pytest.skip("no git history to compare with")
     parent = base.stdout.strip()

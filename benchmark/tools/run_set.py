@@ -28,7 +28,11 @@ WINDOW_KEYS = (
     "tok_s_per_5s", "submitted_per_5s", "prefill_share_of_step_s",
     "prefill_dispatch_ms_p50", "decode_dispatch_ms_p50",
     "host_outside_dispatch_ms_per_step", "queued_at_end",
-    "unfinished_at_end", "compiles_inside", "kv_blocks_peak", "steps")
+    "unfinished_at_end", "compiles_inside", "kv_blocks_peak", "steps",
+    # the cause table's columns (PERF.md section 6, PR 56)
+    "dispatches", "longest_dispatch_s", "prompt_tokens_prefilled",
+    "output_tokens", "longest_steps_at_s_ms_dispatched_ms",
+    "losses", "step_ms_median")
 
 
 def iqr_spread(values):
@@ -78,6 +82,15 @@ def one_run(manifest, args, seed):
         elif obj.get("info") == "setup":
             row["setup_items"] = {k: v for k, v in obj.items()
                                   if k != "info"}
+        elif obj.get("info") == "moe_counters":
+            # device counters of a traced run: the held experts' share of
+            # the routed pairs and the busiest held expert over the mean
+            row["moe_counters"] = {k: v for k, v in obj.items()
+                                   if k != "info"}
+        elif obj.get("info") == "stalled_dispatches":
+            row["stalls_at_s_ms"] = [[st.get("at_s"), st.get("ms"),
+                                      st.get("name")]
+                                     for st in obj.get("stalls", [])]
         elif obj.get("info") == "correctness":
             row["max_abs_logit_error"] = obj.get("max_abs_logit_error")
         elif obj.get("info") == "correctness_after_window":
@@ -134,7 +147,7 @@ def main():
                           if k2.startswith("metric:")) + [
                 "itl_p95_ms", "tpot_p90_ms", "tpot_p50_ms", "itl_mean_ms",
                 "ttft_p50_ms", "serve_tok_s", "decode_occupancy",
-                "served_gap_max"]
+                "served_gap_max", "dispatches", "longest_dispatch_s"]
             say(summary_of_set=k, label=args.label, runs=len(rows),
                 all_correct=all(r.get("correct") for r in rows),
                 failed=sum(r.get("failed", 0) for r in good),
