@@ -22,22 +22,25 @@ Shapes are static and worst-case: ``T * k`` pair rows, sorted by expert
 with the pairs on absent experts (and on padded tokens) last; no token is
 dropped and there is no capacity factor. The grouped product over the held
 experts' stacked weights ``[count, d, f]`` is ``impl="gmm"`` (the Mosaic
-grouped matmul of ``jax.experimental.pallas.ops.tpu.megablox``: it visits
-only the row tiles that hold pairs, so a decode step reads each touched
-expert's weights once and a prefill chunk does the pairs' FLOPs) or
-``impl="ragged_dot"`` (``jax.lax.ragged_dot``, portable; what the CPU
-tests run)."""
+grouped matmul of ops/grouped_matmul.py, megablox's kernel: it visits only
+the row tiles that hold pairs, so a decode step reads each touched expert's
+weights once and a prefill chunk does the pairs' FLOPs; the layer makes the
+kernel's group metadata ONCE, over its own ``count`` groups, and its three
+products share it) or ``impl="ragged_dot"`` (``jax.lax.ragged_dot``,
+portable; what the CPU tests run)."""
 
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.ops import grouped_matmul
+
 # The PREFERRED (tm, tk, tn) of the grouped matmul, chosen on the chip at
 # K-EXAONE's 6144 x 2048 experts, 48 and 512 tokens (PERF.md, PR 28). What a
 # product is handed is :func:`grouped_tiling`'s: for k and for n the largest
 # whole-lane tile within the preferred one that divides the dimension,
-# because megablox pays for a tile the shape does not fill (PERF.md, PR 44:
+# because the kernel pays for a tile the shape does not fill (PERF.md, PR 44:
 # Kimi-Linear's 2,304 took three 1,024-tiles for 2.25 tiles of weights and a
 # float32 mask over the last k-tile of every visited expert; 3 x 768 is 12%
 # faster a decode call there, 2 x 1,152 the same within 1%).
@@ -182,14 +185,14 @@ def _whole_tile(dim: int, pref: int) -> int:
     """The largest multiple of 128 lanes that divides ``dim`` exactly and
     is no larger than ``pref`` (so a tile never outgrows the VMEM the
     preferred one was measured at); ``min(pref, dim)`` where no multiple
-    of 128 does (megablox then masks the ragged last tile)."""
+    of 128 does (the kernel then masks the ragged last tile)."""
     top = min(pref, dim)
     return next((t for t in range(top // 128 * 128, 0, -128)
                  if dim % t == 0), top)
 
 
 def grouped_tiling(k: int, n: int) -> Tuple[int, int, int]:
-    """The (tm, tk, tn) handed to megablox for a product ``[M, k] x [G, k,
+    """The (tm, tk, tn) handed to the kernel for a product ``[M, k] x [G, k,
     n]``: ``GMM_TILING``'s row tile, and for each of ``k`` and ``n`` the
     largest whole-lane tile that divides it and is not above
     ``GMM_TILING``'s. ``GMM_TILING`` itself wherever it divides both."""
@@ -205,14 +208,25 @@ def ragged_tile_share(k: int, n: int, tiling: Tuple[int, int, int]) -> float:
     return 1.0 - (k * n) / (-(-k // tk) * tk * -(-n // tn) * tn)
 
 
-def _grouped(x, w, sizes, impl: str):
-    """Rows of ``x`` [M, a], grouped by ``sizes`` [G], times ``w`` [G, a, b].
-    Rows past the groups come back undefined."""
+def _grouped(x, w, sizes, impl: str, layer=None, metadata=None):
+    """Rows of ``x`` [M, a], grouped by ``sizes`` [G], times the ``G``
+    matrices of ``w`` [layers * G, a, b] that are ``layer``'s (``w`` [G, a,
+    b] and layer None: the first and only). Rows past the groups come back
+    undefined. "gmm" reads ``w`` in place behind ``layer * G`` and takes
+    ``metadata`` (ops/grouped_matmul.py ``group_metadata`` of ``sizes``;
+    made here where the caller has none to share); "ragged_dot" takes the
+    layer's slice."""
+    G = sizes.shape[0]
+    base = 0 if layer is None else layer * G
     if impl == "ragged_dot":
+        if layer is not None:
+            w = jax.lax.dynamic_slice_in_dim(w, base, G)
         return jax.lax.ragged_dot(x, w, sizes)
-    from jax.experimental.pallas.ops.tpu.megablox import gmm
-    return gmm(x, w, sizes, preferred_element_type=x.dtype,
-               tiling=grouped_tiling(w.shape[1], w.shape[2]))
+    tiling = grouped_tiling(w.shape[1], w.shape[2])
+    if metadata is None:
+        metadata = grouped_matmul.group_metadata(sizes, x.shape[0],
+                                                 tiling[0])
+    return grouped_matmul.gmm(x, w, metadata, base, tiling)
 
 
 def held_experts_ffn(h, experts: Dict, sel, w, held: Tuple[int, int],
@@ -227,8 +241,12 @@ def held_experts_ffn(h, experts: Dict, sel, w, held: Tuple[int, int],
     sparse layers, ``[layers * count, ...]``, and the layer's experts are
     groups ``layer * count ...`` of them: a layer loop hands the kernel
     the whole stack, because slicing a layer's experts out for a custom
-    call copies them (1.2 GB a layer a dispatch: PERF.md, PR 28). ``act``
-    is the gate's activation (:func:`expert_act`).
+    call copies them (1.2 GB a layer a dispatch: PERF.md, PR 28), and the
+    kernel's weight block adds ``layer * count`` to a tile's group. The
+    group metadata is made here, once, from the layer's own ``count``
+    sizes, for all three products (made over the stack's groups it cost
+    more than a product's kernel where the experts are small: PERF.md,
+    PR 57). ``act`` is the gate's activation (:func:`expert_act`).
     Returns (``[T, d]`` in h's dtype, int32 stats in the order of
     ``STAT_FIELDS``, with "relu" followed by ``act_zero`` and ``act_total``
     in sixteens: :func:`stat_fields`)."""
@@ -242,26 +260,32 @@ def held_experts_ffn(h, experts: Dict, sel, w, held: Tuple[int, int],
     # pairs by held expert, everything else behind them
     key = jnp.where(on, local, count).reshape(-1)
     order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
+    # a compare and a sum over [pairs, count]: a scatter-add takes the
+    # pairs one after the other on a TPU (45 us for 5,120: PERF.md, PR 57)
+    sizes = jnp.sum(key[:, None] == jnp.arange(count, dtype=jnp.int32),
+                    axis=0, dtype=jnp.int32)
     M = T * K
     pad = -M % GMM_TILING[0] if impl == "gmm" else 0
     tok = jnp.pad(order // K, (0, pad))
     x = h[tok]                                                # [M', d]
     wg, wi, wo = (experts[n]["kernel"].astype(h.dtype)
                   for n in ("wg", "wi", "wo"))
-    groups = sizes
-    if layer is not None:
-        groups = jax.lax.dynamic_update_slice(
-            jnp.zeros((wg.shape[0],), jnp.int32), sizes, (layer * count,))
-    gate = getattr(jax.nn, act)(_grouped(x, wg, groups, impl))
-    y = _grouped(gate * _grouped(x, wi, groups, impl), wo, groups,
-                 impl)                                        # [M', d]
-    # back to (token, k) order; a pair that met no held expert reads a
-    # row nobody wrote, and is masked
-    inv = jnp.zeros((M,), jnp.int32).at[order].set(
-        jnp.arange(M, dtype=jnp.int32))
-    pairs = y[inv].reshape(T, K, d).astype(jnp.float32)
-    out = jnp.sum(jnp.where(on[..., None], pairs * w[..., None], 0.0), axis=1)
+    # ONE metadata for the layer's three products, over its own groups
+    meta = grouped_matmul.group_metadata(sizes, x.shape[0], GMM_TILING[0]) \
+        if impl == "gmm" else None
+    gate = getattr(jax.nn, act)(_grouped(x, wg, sizes, impl, layer, meta))
+    y = _grouped(gate * _grouped(x, wi, sizes, impl, layer, meta), wo,
+                 sizes, impl, layer, meta)                    # [M', d]
+    # back to pair order, the k-th choices of all tokens together: [K, T,
+    # d] is whole tiles of tokens (``[T, K, d]`` pads K to the sublanes and
+    # is written out in float32 before it is summed); the inverse of the
+    # permutation by a second sort, which unlike a scatter does not take
+    # the pairs one by one. A pair that met no held expert reads a row
+    # nobody wrote, and is masked
+    inv = jnp.argsort(order).astype(jnp.int32).reshape(T, K).T.reshape(-1)
+    pairs = y[inv].reshape(K, T, d).astype(jnp.float32)
+    out = jnp.sum(jnp.where(on.T[..., None], pairs * w.T[..., None], 0.0),
+                  axis=0)
     stats = jnp.stack([
         jnp.sum(sizes), jnp.sum(every) * K, jnp.max(sizes),
         jnp.sum(sizes > 0), jnp.int32(1)]).astype(jnp.int32)

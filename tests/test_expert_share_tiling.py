@@ -1,9 +1,8 @@
-"""The tiles the grouped expert product hands megablox follow the
+"""The tiles the grouped expert product hands its kernel follow the
 product's own shape (moe/expert_share.py ``grouped_tiling``): no ragged k-
 or n-tile at any benchmark configuration's experts, the preferred tile
 itself wherever it divides, and the same layer whatever the split."""
 
-import functools
 import json
 import os
 
@@ -102,16 +101,13 @@ def _layer(d=384, f=256, held=4, experts=8, T=24, K=3, layers=3):
 
 @pytest.mark.parametrize("split", ["rule", "as_before"])
 @pytest.mark.parametrize("layer", [None, 1])
-def test_megablox_at_a_width_the_preferred_tile_does_not_divide(
-        monkeypatch, layer, split):
-    """d = 384 under a preferred tile of 256: the rule hands megablox
+def test_the_kernel_at_a_width_the_preferred_tile_does_not_divide(
+        monkeypatch, pallas_interpret, layer, split):
+    """d = 384 under a preferred tile of 256: the rule hands the kernel
     three whole tiles of 128 where ``min(preferred, d)`` gave 256 + a
     masked 128; both are ``ragged_dot``'s layer, output and counters, with
     every sparse layer's experts stacked behind the ``layer`` index and
     without."""
-    from jax.experimental.pallas.ops.tpu import megablox
-    monkeypatch.setattr(megablox, "gmm",
-                        functools.partial(megablox.gmm, interpret=True))
     monkeypatch.setattr(expert_share, "GMM_TILING", (128, 256, 256))
     if split == "as_before":
         monkeypatch.setattr(expert_share, "grouped_tiling", _as_before)

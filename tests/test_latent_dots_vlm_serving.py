@@ -4,7 +4,6 @@ benchmark's plain reference at small sizes: the latent pool served, the
 controls, the counters and what raises. The blocks and kernels below it:
 tests/test_latent_dots_vlm.py."""
 
-import functools
 
 import jax.numpy as jnp
 import numpy as np
@@ -198,10 +197,7 @@ def test_the_kernels_serve_what_the_portable_path_serves(
     (``mla_prefill`` in the prefill program, ``mla_decode`` in the decode
     program, the grouped products): the reference's logits, and the flash
     steps counted under ``kernel``."""
-    from jax.experimental.pallas.ops.tpu import megablox
     cfg, params, prompts, _, _ = served
-    monkeypatch.setattr(megablox, "gmm",
-                        functools.partial(megablox.gmm, interpret=True))
     monkeypatch.setenv("DS_PAGED_DECODE_IMPL", "pallas")
     srv, got = U.serve_logits(cfg, params, prompts, 7)
     assert srv.engine.decode_impl == "pallas"

@@ -4,7 +4,6 @@ smallest shape that has two blocks. The dialect itself:
 tests/test_longcat_flash.py; the pool's layout in the programs compiled for
 a v5e: tests/test_longcat_flash_aot.py."""
 
-import functools
 
 import numpy as np
 
@@ -18,12 +17,9 @@ def test_the_kernels_serve_what_the_portable_path_serves(
     """``decode_impl`` "pallas" off a TPU, every Mosaic kernel interpreted
     (``mla_prefill``, ``mla_decode``, the grouped products), at the
     smallest shape that has two blocks: a prompt of 6 in blocks of 4."""
-    from jax.experimental.pallas.ops.tpu import megablox
     cfg = U.tiny_config()
     params = U.tiny_params(cfg)
     prompts = [np.random.default_rng(4).integers(1, 96, 6)]
-    monkeypatch.setattr(megablox, "gmm",
-                        functools.partial(megablox.gmm, interpret=True))
     monkeypatch.setenv("DS_PAGED_DECODE_IMPL", "pallas")
     srv, got = U.serve_logits(cfg, params, prompts, 3, num_slots=1,
                               prefill_chunk=8)

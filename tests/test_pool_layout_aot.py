@@ -16,9 +16,11 @@ embedding and the 3.3 MB positional table on every dispatch (PERF.md,
 PR 39).
 """
 
+import functools
 import json
 import math
 import pathlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -397,17 +399,13 @@ ENTRY %main.1 (p0: bf16[4,9,4,32]) -> bf16[4,9,4,32] {
     assert metrics.gauge("program_param_copy_bytes_decode_slots").value == 0
 
 
-@pytest.mark.parametrize("program", ["prefill_slot", "decode_slots"])
-def test_no_copy_of_the_state_or_the_pool_is_compiled_in(v5e, program):
-    """The same reading for the linear-attention dialect's three buffers
-    (inference/linear.py): the serving program compiled ahead of time for a v5e, with the
-    Mosaic kernels, at the published head sizes (the tiling is theirs) and
-    few layers, slots and experts, runs of 1 and 3 linear layers and one
-    behind the last latent layer. No ``copy`` holds a value shaped like the
-    latent pool, the recurrent state or the convolution tails, and all
-    three are updated in place (a ``lax.cond`` on the layer's kind copied
-    the whole state in the latent branch; a fused shifted read of the tail
-    copied the tails in and out: PERF.md, PR 40)."""
+@functools.lru_cache(maxsize=None)
+def _kimi_linear_compiled(v5e, program):
+    """The linear-attention dialect's serving program compiled for a v5e
+    with the Mosaic kernels, at the published head sizes (the tiling is
+    theirs) and few layers, slots and experts, runs of 1 and 3 linear
+    layers and one behind the last latent layer: (executable, its text,
+    the state's buffers, (N, Lm, Lk, B, C, bs), the config)."""
     from deepspeed_tpu.inference import linear
     from deepspeed_tpu.models import kimi_linear
     cfg = kimi_linear.KimiLinearConfig(
@@ -450,7 +448,22 @@ def test_no_copy_of_the_state_or_the_pool_is_compiled_in(v5e, program):
                 S((B,), i32), S((B,), f32), S((B,), i32), S((B,), f32),
                 S((B,), f32), S((B, V), jnp.bool_))
     exe = fn.trace(*args).lower(lowering_platforms=("tpu",)).compile()
-    text = exe.as_text()
+    return exe, exe.as_text(), state, (N, Lm, Lk, B, C, bs), cfg
+
+
+@pytest.mark.parametrize("program", ["prefill_slot", "decode_slots"])
+def test_no_copy_of_the_state_or_the_pool_is_compiled_in(v5e, program):
+    """The same reading for the linear-attention dialect's three buffers
+    (inference/linear.py): the serving program compiled ahead of time for a v5e, with the
+    Mosaic kernels, at the published head sizes (the tiling is theirs) and
+    few layers, slots and experts, runs of 1 and 3 linear layers and one
+    behind the last latent layer. No ``copy`` holds a value shaped like the
+    latent pool, the recurrent state or the convolution tails, and all
+    three are updated in place (a ``lax.cond`` on the layer's kind copied
+    the whole state in the latent branch; a fused shifted read of the tail
+    copied the tails in and out: PERF.md, PR 40)."""
+    exe, text, state, (N, Lm, Lk, B, C, bs), _ = _kimi_linear_compiled(
+        v5e, program)
     table = parse_provenance(text)
     # the pool's blocks (one layer's, all layers'), the state's and the
     # tails' leading dimension (all layers' slots: 48)
@@ -479,7 +492,6 @@ def test_state_space_state_is_stored_unpadded_and_never_copied(v5e, program):
     would pad to 128 lanes: 8 times as much). No ``copy`` holds a value
     shaped like a pool, the state or the tails, and all four are updated in
     place."""
-    import re
     from deepspeed_tpu.inference import linear
     from deepspeed_tpu.models import jamba
     cfg = jamba.JambaConfig(
@@ -566,7 +578,6 @@ def test_smallthinker_cell_programs_fit_a_v5e(v5e, program):
     tensor over a full layer's whole row for more than one KV head
     (``[512, 28, 16384]`` would be 940 MB), and at least 1.0 GiB of the
     chip's 15.75 left at the program's peak."""
-    import re
     from deepspeed_tpu.inference import hybrid
     from deepspeed_tpu.models import exaone_moe, smallthinker
     c, sv = SMALLTHINKER, SMALLTHINKER["serving"]
@@ -642,20 +653,11 @@ QWEN3_NEXT = json.loads((
     / "qwen3-next-80b-a3b-serve-ep16pp2.json").read_text())
 
 
-@pytest.mark.parametrize("program", ["prefill_slot", "decode_slots"])
-def test_qwen3_next_cell_programs_fit_a_v5e(v5e, program):
-    """Qwen3-Next's two serving programs (the linear dialect's delta rule
-    with one decay a head beside the engine's gated K/V attention) compiled
-    for a v5e at the cell's own sizes, 24 layers and the configuration's
-    slots, chunk and pool: no copy of a pool, of the recurrent state or of
-    the tails, all four updated in place; the chunk form ONE Mosaic kernel
-    under ``gdn_chunk`` (it lowers for a v5e at 32 value heads on 16 key
-    heads of 128 and a chunk of 512), so neither a per-channel pair decay
-    (``[32, 64, 64, 128]``: KDA's chunk form) nor the XLA form's ``[..,
-    64, 64]`` pairs and 8 MiB ``[8, 32, 64, 128]`` float32 temporaries are
-    in the program; and at least 1.0 GiB of the chip's 15.75 left at the
-    program's peak."""
-    import re
+@functools.lru_cache(maxsize=None)
+def _qwen3_next_compiled(v5e, program):
+    """Qwen3-Next's serving program compiled for a v5e at the cell's own
+    sizes, 24 layers and the configuration's slots, chunk and pool:
+    (executable, its text, the pool, the state, (N, La, Lg, B), config)."""
     from deepspeed_tpu.inference import linear
     from deepspeed_tpu.models import qwen3_next
     c, sv = QWEN3_NEXT, QWEN3_NEXT["serving"]
@@ -701,7 +703,24 @@ def test_qwen3_next_cell_programs_fit_a_v5e(v5e, program):
                 S((B,), i32), S((B,), f32), S((B,), i32), S((B,), f32),
                 S((B,), f32), S((B, V), jnp.bool_))
     exe = fn.trace(*args).lower(lowering_platforms=("tpu",)).compile()
-    text = exe.as_text()
+    return exe, exe.as_text(), pool, state, (N, La, Lg, B), cfg
+
+
+@pytest.mark.parametrize("program", ["prefill_slot", "decode_slots"])
+def test_qwen3_next_cell_programs_fit_a_v5e(v5e, program):
+    """Qwen3-Next's two serving programs (the linear dialect's delta rule
+    with one decay a head beside the engine's gated K/V attention) compiled
+    for a v5e at the cell's own sizes, 24 layers and the configuration's
+    slots, chunk and pool: no copy of a pool, of the recurrent state or of
+    the tails, all four updated in place; the chunk form ONE Mosaic kernel
+    under ``gdn_chunk`` (it lowers for a v5e at 32 value heads on 16 key
+    heads of 128 and a chunk of 512), so neither a per-channel pair decay
+    (``[32, 64, 64, 128]``: KDA's chunk form) nor the XLA form's ``[..,
+    64, 64]`` pairs and 8 MiB ``[8, 32, 64, 128]`` float32 temporaries are
+    in the program; and at least 1.0 GiB of the chip's 15.75 left at the
+    program's peak."""
+    exe, text, pool, state, (N, La, Lg, B), _ = _qwen3_next_compiled(
+        v5e, program)
     table = parse_provenance(text)
     assert pool_copy_bytes(table, (N, La * N)) == 0
     assert pool_copy_bytes(table, (Lg * B,)) == 0
@@ -720,3 +739,64 @@ def test_qwen3_next_cell_programs_fit_a_v5e(v5e, program):
                          r"gdn_chunk/gdn_chunk/pallas_call", text)
         assert not re.search(r"f32\[(\d+,)*64,64(,128)?\]", text)
         assert not re.search(r"f32\[8,32,64,(128|256)\]", text)
+
+
+_HLO_LINE = re.compile(r"\s*(?:ROOT )?%([\w.\-]+) = (\S+) ([\w\-]+)\(")
+
+
+@pytest.mark.parametrize("program", ["prefill_slot", "decode_slots"])
+@pytest.mark.parametrize("config", ["qwen3_next", "kimi_linear"])
+def test_a_sparse_layer_makes_its_group_metadata_once(v5e, config, program):
+    """The expert layer in the compiled v5e programs (Qwen3-Next's at the
+    cell's sizes: 24 sparse layers of 32 held experts; the linear dialect's
+    small ones: 7 of 4): a layer body's three grouped products take the
+    SAME metadata operands (the visited tiles' count, the group offsets,
+    each tile's group and row tile, the layer's base into the stack), made
+    over the layer's own ``count`` groups; nothing under ``moe_experts``
+    searches or is as long as the stack's ``layers * count`` groups
+    (megablox's metadata, made inside each product over the whole stack,
+    was: PERF.md, PR 57); and the stack reaches the kernel as the loop's
+    own value, never copied or sliced."""
+    if config == "qwen3_next":
+        _, text, _, _, _, cfg = _qwen3_next_compiled(v5e, program)
+        tokens = QWEN3_NEXT["serving"]["prefill_chunk" if program
+                                       == "prefill_slot" else "num_slots"]
+    else:
+        _, text, _, (_, _, _, B, C, _), cfg = _kimi_linear_compiled(
+            v5e, program)
+        tokens = C if program == "prefill_slot" else B
+    count = cfg.held[1]
+    n_sparse = cfg.n_layers - cfg.n_dense_layers
+    d, f = cfg.d_model, cfg.moe_d_ff
+    shape_of, opcode_of, calls = {}, {}, []
+    for line in text.splitlines():
+        m = _HLO_LINE.match(line)
+        if m is None:
+            continue
+        shape_of[m.group(1)], opcode_of[m.group(1)] = m.group(2), m.group(3)
+        if m.group(3) == "custom-call" and "tpu_custom_call" in line \
+                and "moe_experts/gmm" in line:
+            calls.append(re.findall(r"%([\w.\-]+)", line[:line.index(
+                "custom_call_target")].split("custom-call(", 1)[1]))
+    # a layer body: the layers behind a dense one and the runs between
+    # attention kinds compile to loops of their own
+    bodies = {tuple(c[:5]) for c in calls}
+    assert calls and len(calls) == 3 * len(bodies), (len(calls), bodies)
+    tiles = -(-tokens * cfg.moe_k // 128)
+    for _, offsets, group_ids, m_tile_ids, _ in bodies:
+        assert shape_of[offsets].startswith(f"s32[{count + 1}]")
+        assert shape_of[group_ids].startswith(f"s32[{tiles + count - 1}]")
+        assert shape_of[m_tile_ids].startswith(f"s32[{tiles + count - 1}]")
+    scoped = [ln for ln in text.splitlines() if "moe_experts" in ln]
+    assert not any("searchsorted" in ln for ln in scoped)
+    assert not any(re.search(rf"\[{n_sparse * count}\]", ln) for ln in scoped)
+    # the experts: the stack [layers * count, a, b] or a layer's [count, a,
+    # b] is nowhere the result of a copy, a slice or a fusion
+    stack = re.compile(rf"bf16\[({n_sparse * count}|{count}),"
+                       rf"({d},{f}|{f},{d})\]")
+    moved = {n: opcode_of[n] for n, s in shape_of.items() if stack.match(s)
+             and opcode_of[n] in ("copy", "fusion", "slice", "dynamic-slice",
+                                  "dynamic-update-slice")}
+    assert not moved, moved
+    weights = {c[-1] for c in calls}
+    assert all(stack.match(shape_of[w]) for w in weights), weights

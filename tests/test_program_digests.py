@@ -29,18 +29,27 @@ import program_text as PT
 # whole-row form's (tests/test_exaone_moe.py,
 # tests/test_smallthinker_serving.py). Its
 # decode program and both programs of the other four are the parent's.
-# PR 54 re-recorded ``kimi_linear``'s prefill program (below) and added
-# ``gpt``; the others are its parent's
+# PR 54 re-recorded ``kimi_linear``'s prefill program and added ``gpt``
+# (its chunk form's unit-triangular solve is matrix products,
+# kda._solve_unit_lower, in place of lax.linalg.triangular_solve; the
+# numbers are the recurrence's: tests/test_kimi_linear.py,
+# tests/test_qwen3_next.py).
+# PR 57 re-recorded BOTH programs of the five sparse configurations, and
+# MEANT to alter all ten: the expert layer's grouped product takes the
+# layer's own ``count`` group sizes (expert_share._grouped: off a TPU
+# ``ragged_dot`` over the layer's slice of the stacked experts, where it
+# ran over every sparse layer's groups with the layer's sizes written into
+# a zero vector), its sizes are a compare and a sum where they were a
+# scatter-add, and the un-sort is a second sort and one gather in [K, T, d]
+# order where it was a scattered inverse and [T, K, d]; the layer's output
+# and counters are the parent's (tests/test_grouped_matmul.py). ``jamba`` (no experts) and ``gpt`` hold
+# the parent's text
 PARENT = {
-    "dots_vlm": ("1f4bc36bb35dad08", "6e38d962ac6c627a"),
-    "exaone_moe": ("5156e3f3e5fb0868", "4a6802b404de5550"),
-    "smallthinker": ("bfccd691bf9b57c4", "98f190518fa6bd18"),
-    # prefill re-recorded by PR 54, which MEANT to alter it: the chunk
-    # form's unit-triangular solve is matrix products (kda._solve_unit_lower)
-    # in place of lax.linalg.triangular_solve; the numbers are the
-    # recurrence's (tests/test_kimi_linear.py, tests/test_qwen3_next.py)
-    "kimi_linear": ("1743aa2d78785415", "a284d9953d44de1f"),
-    "zaya": ("71c82477980eded6", "008d914cbe2106ca"),
+    "dots_vlm": ("2b780e38a2e951ae", "e67ccb088e089a34"),
+    "exaone_moe": ("e0d4515839ff1e9d", "aa76f196d5f60505"),
+    "smallthinker": ("57547f5c185d7057", "a67e09874ab75224"),
+    "kimi_linear": ("b12ec40e5c564edd", "06428131a43a079b"),
+    "zaya": ("5aedcea79e1e57cd", "6ecc263b8d76ed88"),
     "jamba": ("7bf3f1d239e4fc52", "3e097490323d7ce2"),
     # the plain K and V pools (GPT-2 XL's programs), recorded at the parent
     # of PR 54, whose `_attn_*_paged` gained an output gate, q/k norms and

@@ -226,8 +226,6 @@ def test_the_chunk_kernel_serves_what_the_xla_form_serves(
     ``gdn_chunk`` kernel a layer (chunks of 16 tokens, padded to its
     sub-chunk of 64; the state handed from chunk to chunk and on to the
     step kernel), and every logit is the XLA form's and the reference's."""
-    import functools
-    from jax.experimental.pallas.ops.tpu import megablox
     cfg = U.tiny_config(n_layers=4, linear_head_dim=128)
     params = U.tiny_params(cfg)
     assert linear._ready_note(cfg, "pallas") == ", gdn_chunk=mosaic"
@@ -236,8 +234,6 @@ def test_the_chunk_kernel_serves_what_the_xla_form_serves(
     rng = np.random.default_rng(5)
     prompts = [rng.integers(1, 96, 37), rng.integers(1, 96, 21)]
     _, want = U.serve_logits(cfg, params, prompts, 4)
-    monkeypatch.setattr(megablox, "gmm",
-                        functools.partial(megablox.gmm, interpret=True))
     monkeypatch.setenv("DS_PAGED_DECODE_IMPL", "pallas")
     srv, got = U.serve_logits(cfg, params, prompts, 4)
     assert srv.decode_impl == "pallas"

@@ -705,18 +705,20 @@ GROUPED_REPS = 104      # calls in one timed program, the layers in turn
 
 def grouped_time_rows():
     """Microseconds a ``held_experts_ffn`` call takes (moe/expert_share.py:
-    the sort, the gather, three megablox ``gmm`` products, the un-sort and
-    the weighted sum; the scope ``moe_experts``) at the four sparse cells'
-    expert shapes, for a decode dispatch's tokens and a prefill chunk's,
-    every sparse layer's experts stacked behind the ``layer`` index as the
-    serving programs hand them over. ``tiling_up`` / ``tiling_down`` are
-    what ``grouped_tiling`` hands megablox for ``wg`` / ``wi`` ``[d, f]``
-    and ``wo`` ``[f, d]``, ``ragged_tile_share`` the share of the fetched
-    tile area outside the matrix, ``us_up`` / ``us_down`` one product
-    alone, ``least_us`` the touched experts' weights over 819 GB/s. Where
-    a cell lists tiles, each is also timed FORCED on the dimension the
-    preferred tile does not divide (1024 there is ``min(preferred, dim)``:
-    what every product was handed until PR 44). The kernel path is checked
+    the sort, the gather, the group metadata, three ``gmm`` products
+    (ops/grouped_matmul.py), the un-sort and the weighted sum; the scope
+    ``moe_experts``) at the four sparse cells' expert shapes, for a decode
+    dispatch's tokens and a prefill chunk's, every sparse layer's experts
+    stacked behind the ``layer`` index as the serving programs hand them
+    over. ``tiling_up`` / ``tiling_down`` are what ``grouped_tiling`` hands
+    the kernel for ``wg`` / ``wi`` ``[d, f]`` and ``wo`` ``[f, d]``,
+    ``ragged_tile_share`` the share of the fetched tile area outside the
+    matrix, ``us_up`` / ``us_down`` one product alone (its own metadata and
+    the kernel), ``least_us`` the touched experts' weights over 819 GB/s.
+    Where a cell lists tiles, each is also timed FORCED on the dimension
+    the preferred tile does not divide (1024 there is ``min(preferred,
+    dim)``: what every product was handed until PR 44). The kernel path is
+    checked
     against ``ragged_dot``."""
     for (cell, *shape, t_dec, t_chunk, forced) in GROUPED_SHAPES:
         for phase, T in (("decode", t_dec), ("chunk", t_chunk)):
@@ -727,10 +729,11 @@ def grouped_time_rows():
 
 
 @functools.lru_cache(maxsize=None)
-def _grouped_programs(held, L, tile):
+def _grouped_programs(held, L, tile, form="own"):
     """The jitted programs of one :func:`grouped_time_rows` row. ``tile``
-    is only a key: a program traced under one forced tiling is not
-    another's."""
+    and ``form`` are only keys: a program traced under one forced tiling,
+    or under the parent's grouped product (:func:`moe_experts_rows`), is
+    not another's."""
     from deepspeed_tpu.moe import expert_share as ES
 
     def stack(a):
@@ -746,9 +749,7 @@ def _grouped_programs(held, L, tile):
 
     def product(x, kernel, groups):
         def call(i, x):
-            lay = jax.lax.dynamic_update_slice(
-                jnp.zeros((L * held,), jnp.int32), groups, (i % L * held,))
-            y = ES._grouped(x, kernel, lay, "gmm")
+            y = ES._grouped(x, kernel, groups, "gmm", i % L)
             return x.at[0, 0].add(y[0, 0] * jnp.asarray(1e-6, x.dtype))
         return jax.lax.fori_loop(0, GROUPED_REPS, call, x)
 
@@ -761,6 +762,23 @@ def _grouped_programs(held, L, tile):
                                    "ragged_dot")
     return (jax.jit(stack), jax.jit(ffn), jax.jit(product),
             jax.jit(last_layer), jax.jit(plain))
+
+
+def _grouped_inputs(r, stack, d, f, held, total, k, T):
+    """One layer's held experts, the same stacked for every sparse layer
+    (``stack``), ``T`` tokens, their selections over ``total`` experts and
+    even weights, the held groups' sizes and the padded pair rows."""
+    one = {n: {"kernel": _rand(r, (held,) + s) * s[0] ** -0.5}
+           for n, s in (("wg", (d, f)), ("wi", (d, f)), ("wo", (f, d)))}
+    experts = {n: {"kernel": stack(e["kernel"])} for n, e in one.items()}
+    h = _rand(r, (T, d))
+    sel = jnp.asarray(np.stack([r.choice(total, k, replace=False)
+                                for _ in range(T)]), jnp.int32)
+    w = jnp.full((T, k), 1.0 / k, jnp.float32)
+    local = np.asarray(sel).reshape(-1)
+    groups = jnp.asarray(np.bincount(local[local < held], minlength=held),
+                         jnp.int32)
+    return one, experts, h, sel, w, groups, -(-T * k // 128) * 128
 
 
 def _grouped_time_row(d, f, held, total, k, L, T, tile):
@@ -782,19 +800,10 @@ def _grouped_time_row(d, f, held, total, k, L, T, tile):
             held, L, tile)
         up, down = ES.grouped_tiling(d, f), ES.grouped_tiling(f, d)
         r = np.random.default_rng(17)
-        one = {n: {"kernel": _rand(r, (held,) + s) * s[0] ** -0.5}
-               for n, s in (("wg", (d, f)), ("wi", (d, f)), ("wo", (f, d)))}
-        experts = {n: {"kernel": stack(e["kernel"])} for n, e in one.items()}
-        h = _rand(r, (T, d))
-        sel = jnp.asarray(np.stack([r.choice(total, k, replace=False)
-                                    for _ in range(T)]), jnp.int32)
-        w = jnp.full((T, k), 1.0 / k, jnp.float32)
+        one, experts, h, sel, w, groups, M = _grouped_inputs(
+            r, stack, d, f, held, total, k, T)
         got, stats = last_layer(h, experts, sel, w)
         want, _ = plain(h, one, sel, w)
-        local = np.asarray(sel).reshape(-1)
-        groups = jnp.asarray(np.bincount(local[local < held],
-                                         minlength=held), jnp.int32)
-        M = -(-T * k // 128) * 128
         touched = int(stats[3])
         row = {"d": d, "f": f, "held": held, "experts": total, "k": k,
                "layers": L, "tokens": T, "tiling_up": list(up),
@@ -812,6 +821,112 @@ def _grouped_time_row(d, f, held, total, k, L, T, tile):
         return {**row, "ok": row["fwd_err"] < TOL}
     finally:
         ES.grouped_tiling = rule
+
+
+# three sparse cells' expert layers, smallest experts first: (cell, d, f,
+# experts held, experts routed over, experts a token, sparse layers stacked
+# behind the ``layer`` index, tokens of a decode dispatch, tokens of a
+# prefill chunk). Groups of the stack: 768, 416, 112
+MOE_SHAPES = (
+    ("qwen3next", 2048, 512, 32, 512, 10, 24, 48, 512),
+    ("kimilinear", 2304, 1024, 16, 256, 8, 26, 40, 512),
+    ("kexaone", 6144, 2048, 16, 128, 8, 7, 48, 256))
+
+
+def _parent_grouped(x, w, sizes, impl, layer=None, metadata=None):
+    """``expert_share._grouped`` as it was until PR 57: the layer's sizes
+    written into a zero vector over every group of the stack, and
+    megablox's ``gmm``, which makes its metadata inside, over all of
+    them."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    from deepspeed_tpu.moe import expert_share as ES
+    groups = sizes
+    if layer is not None:
+        groups = jax.lax.dynamic_update_slice(
+            jnp.zeros((w.shape[0],), jnp.int32), sizes,
+            (layer * sizes.shape[0],))
+    return gmm(x, w, groups, preferred_element_type=x.dtype,
+               tiling=ES.grouped_tiling(w.shape[1], w.shape[2]))
+
+
+def moe_experts_rows():
+    """Microseconds a ``held_experts_ffn`` call takes (the scope
+    ``moe_experts``) with the PARENT's grouped product (megablox's ``gmm``
+    over every sparse layer's stacked experts, its metadata made inside
+    each of the three products over ``layers * held`` groups) and with this
+    tree's (ONE metadata a layer over its ``held`` groups,
+    ops/grouped_matmul.py, the stack read behind ``layer * held``); the
+    rest of the layer (the sort, the sizes, the gather, the un-sort) is
+    this tree's in both. At three cells' shapes, decode and
+    prefill-chunk tokens; one product alone in both forms (``us_up``: the
+    metadata and the kernel), the metadata alone (``us_metadata``: the
+    parent's over the stack, this tree's over the layer), and the touched
+    experts' bytes' time. The two forms' outputs are compared bit for
+    bit."""
+    for (cell, *shape, t_dec, t_chunk) in MOE_SHAPES:
+        for phase, T in (("decode", t_dec), ("chunk", t_chunk)):
+            yield (f"moe experts {cell} {phase} T{T}",
+                   functools.partial(_moe_experts_row, *shape, T))
+
+
+def _moe_experts_row(d, f, held, total, k, L, T):
+    import importlib
+    from deepspeed_tpu.moe import expert_share as ES
+    from deepspeed_tpu.ops import grouped_matmul as GM
+    megablox = importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+    def us(fn, *args):
+        return round(_best_seconds(fn, *args) / GROUPED_REPS * 1e6, 2)
+
+    r = np.random.default_rng(17)
+    _, experts, h, sel, w, groups, M = _grouped_inputs(
+        r, _grouped_programs(held, L, None)[0], d, f, held, total, k, T)
+    x = _rand(r, (M, d))
+
+    def metadata(form):
+        def call(i, acc):
+            sizes = jnp.roll(groups, i)     # of the loop: not hoisted
+            if form == "own":
+                md = GM.group_metadata(sizes, M, 128)
+            else:
+                lay = jax.lax.dynamic_update_slice(
+                    jnp.zeros((L * held,), jnp.int32), sizes,
+                    (i % L * held,))
+                md = megablox.make_group_metadata(
+                    group_sizes=lay, m=M, tm=128, start_group=jnp.int32(0),
+                    num_nonzero_groups=L * held, visit_empty_groups=False)
+            return acc + sum(jnp.sum(a) for a in jax.tree_util.tree_leaves(
+                md))
+
+        def program():
+            return jax.lax.fori_loop(0, GROUPED_REPS, call, 0)
+        return jax.jit(program)
+
+    row = {"d": d, "f": f, "held": held, "stack_groups": L * held, "k": k,
+           "tokens": T, "rows": M}
+    out = {}
+    rule = ES._grouped
+    for form in ("parent", "own"):
+        if form == "parent":
+            ES._grouped = _parent_grouped
+        try:
+            _, ffn, product, last_layer, _ = _grouped_programs(
+                held, L, None, form)
+            out[form], stats = last_layer(h, experts, sel, w)
+            row.update({
+                f"us_ffn_{form}": us(ffn, h, experts, sel, w),
+                f"us_up_{form}": us(product, x, experts["wg"]["kernel"],
+                                    groups),
+                f"us_metadata_{form}": us(metadata(form))})
+        finally:
+            ES._grouped = rule
+    touched = int(stats[3])
+    row.update({"experts_touched": touched, "pairs_held": int(stats[0]),
+                "least_us": round(touched * 3 * d * f * 2 / 819e9 * 1e6, 2),
+                "same_bits": bool(jnp.array_equal(out["parent"],
+                                                  out["own"]))})
+    return {**row, "ok": row["same_bits"]}
 
 
 # the two serving configurations' dispatch shapes: (configuration, slots,
@@ -982,7 +1097,7 @@ def main():
                      paged_time_rows, paged_tile_rows,
                      paged_masked_time_rows, mla_time_rows,
                      mla_prefill_time_rows, gdn_chunk_time_rows,
-                     grouped_time_rows,
+                     grouped_time_rows, moe_experts_rows,
                      dispatch_operand_rows,
                      int8_matmul_rows, blocksparse_rows):
             for name, run in rows():
