@@ -51,8 +51,13 @@ class GPTConfig:
     # without the flash-fwd recompute (cpu_checkpointing analog)
     remat_policy: str = "selective"
     use_flash_attention: bool = True
-    # 1024-blocks measured fastest at seq>=1024 on v5e (PERF.md); the
-    # kernel clamps to the sequence length for shorter inputs
+    # the GRID tile of the flash kernels. 1024-blocks measured fastest at
+    # seq>=1024 on v5e (PERF.md): a grid step has a fixed cost and
+    # re-fetches K and V, which eat what smaller blocks' skipped steps
+    # give. A block that straddles the causal diagonal is walked as a
+    # triangle of sub-tiles INSIDE the step by the two backward kernels
+    # (flash.SUB_TILE; flash.tile_census counts them). The kernel clamps
+    # to the sequence length for shorter inputs
     flash_block_q: int = 1024
     flash_block_kv: int = 1024
     # backward-kernel tiles (None = same as forward). The dq/dkv kernels
@@ -397,6 +402,16 @@ def _flash_blocks(cfg: GPTConfig, seq_len: int):
     return bq, bkv
 
 
+def _flash_bwd_blocks(cfg: GPTConfig, seq_len: int):
+    """(bwd_block_q, bwd_block_kv) overrides for the flash backward
+    kernels, each None where the forward's block stands. They pass
+    through the same divisibility normalization as the forward blocks (a
+    non-dividing block would truncate the backward grid)."""
+    from deepspeed_tpu.ops.attention.flash import fit_block
+    return tuple(fit_block(b, seq_len) if b else None
+                 for b in (cfg.flash_block_bwd_q, cfg.flash_block_bwd_kv))
+
+
 def _flash_eligible(cfg: GPTConfig, seq_len: int) -> bool:
     return _flash_blocks(cfg, seq_len) is not None
 
@@ -411,7 +426,18 @@ def attention_impl(cfg: GPTConfig, seq_len: Optional[int] = None) -> str:
         if cfg.sp_impl == "ring":
             S //= cfg.mesh.shape["sequence"]
     blocks = _flash_blocks(cfg, S)
-    return sp + (f"flash({blocks[0]}x{blocks[1]})" if blocks else "dense")
+    if not blocks:
+        return sp + "dense"
+    # how often the backward kernels' sub-tile walk engages: static, from
+    # the call's geometry (a ring's diagonal step under sequence parallel)
+    from deepspeed_tpu.ops.attention.flash import (resolve_window_impl,
+                                                   tile_census)
+    bwd = [own or fwd for own, fwd in zip(_flash_bwd_blocks(cfg, S), blocks)]
+    done, grid = tile_census(
+        S, S, *bwd, True,
+        resolve_window_impl(cfg.attn_window, cfg.attn_window_impl))
+    return sp + (f"flash({blocks[0]}x{blocks[1]}, backward sub-tiles "
+                 f"{done}/{grid})")
 
 
 def _flash_per_device(q, k, v, segment_ids, kv_mask, **kw):
@@ -454,7 +480,7 @@ def _attention(q, k, v, cfg: GPTConfig, segment_ids=None, kv_mask=None):
     segment_ids: optional [B, S] packed-sequence ids — attention stays
     inside each segment (block-diagonal x causal).
     kv_mask: optional [B, S] key-validity mask (left-padded prompts)."""
-    from deepspeed_tpu.ops.attention.flash import fit_block, mha_reference
+    from deepspeed_tpu.ops.attention.flash import mha_reference
     scale = cfg.attn_scale  # None -> kernels default to 1/sqrt(Dh)
     if cfg.sequence_parallel and cfg.mesh is not None:
         # GQA works under both SP impls: ring rotates the small grouped
@@ -470,6 +496,7 @@ def _attention(q, k, v, cfg: GPTConfig, segment_ids=None, kv_mask=None):
             from deepspeed_tpu.ops.attention.ulysses import ulysses_attention
             S = q.shape[1]
             blocks = _flash_blocks(cfg, S)
+            bwd_q, bwd_kv = _flash_bwd_blocks(cfg, S)
             return ulysses_attention(
                 q, k, v, cfg.mesh, causal=True, scale=scale,
                 use_flash=blocks is not None,
@@ -478,10 +505,7 @@ def _attention(q, k, v, cfg: GPTConfig, segment_ids=None, kv_mask=None):
                 segment_ids=segment_ids, kv_mask=kv_mask,
                 window=cfg.attn_window,
                 window_impl=cfg.attn_window_impl,
-                bwd_block_q=(fit_block(cfg.flash_block_bwd_q, S)
-                             if cfg.flash_block_bwd_q else None),
-                bwd_block_kv=(fit_block(cfg.flash_block_bwd_kv, S)
-                              if cfg.flash_block_bwd_kv else None))
+                bwd_block_q=bwd_q, bwd_block_kv=bwd_kv)
         if cfg.sp_impl != "ring":
             raise ValueError(f"unknown sp_impl {cfg.sp_impl!r} "
                              "(expected 'ring' or 'ulysses')")
@@ -501,14 +525,8 @@ def _attention(q, k, v, cfg: GPTConfig, segment_ids=None, kv_mask=None):
             window_impl=cfg.attn_window_impl)
     blocks = _flash_blocks(cfg, q.shape[1])
     if blocks is not None:
-        # bwd overrides pass through the same divisibility normalization
-        # as the fwd blocks (a non-dividing block would truncate the
-        # backward grid); fall back to the fwd block when none divides
-        S = q.shape[1]
-        bwd_q = (fit_block(cfg.flash_block_bwd_q, S)
-                 if cfg.flash_block_bwd_q else None)
-        bwd_kv = (fit_block(cfg.flash_block_bwd_kv, S)
-                  if cfg.flash_block_bwd_kv else None)
+        # fall back to the fwd block when no bwd override divides
+        bwd_q, bwd_kv = _flash_bwd_blocks(cfg, q.shape[1])
         return _flash_per_device(
             q, k, v, segment_ids, kv_mask, causal=True, scale=scale,
             block_q=blocks[0], block_kv=blocks[1], window=cfg.attn_window,

@@ -12,7 +12,10 @@ dimension) with running max / sum / accumulator in VMEM scratch that
 persists across the sequential KV iterations.
 Backward: recompute-based FA2 — one kernel accumulating (dk, dv) over Q
 blocks, one accumulating dq over KV blocks, using the saved logsumexp and
-the precomputed per-row delta = rowsum(dO * O).
+the precomputed per-row delta = rowsum(dO * O). A backward grid step whose
+block straddles an edge of the attention band (the causal diagonal, a
+window's lower edge) walks the block as sub-tiles and computes only those
+that meet the band (_block_walks, _tile_walk; tile_census counts them).
 
 All matmuls hit the MXU in the input dtype with fp32 accumulation
 (preferred_element_type); softmax statistics in fp32.
@@ -71,7 +74,6 @@ def resolve_window_impl(window, window_impl=None):
                          f"expected 'banded' or 'masked'")
     return ("masked", int(window)) if impl == "masked" else int(window)
 LANES = 128
-STATS = 8   # lane width for per-row softmax stats (lse/delta) — sublane-aligned
 
 
 def _ceil_to(x, m):
@@ -137,22 +139,208 @@ def _causal_kv_index_map(block_q, block_kv, num_kv, window=None, q_off=0):
 
 
 def _band_run(qi, ki, block_q, block_kv, causal, window, q_off=0):
-    """Whether grid step (qi, ki) intersects the attention band."""
+    """Whether tile (qi, ki) of a [block_q, block_kv] tiling intersects
+    the attention band. One predicate at two scales: the grid asks it of
+    its blocks (traced indices), and a block that straddles an edge of
+    the band asks it of its sub-tiles (Python ints in, a Python bool
+    out: see _tile_walk)."""
     window = _norm_window(window)[1]     # banded geometry only
     run = True
     if causal:
         run = qi * block_q + block_q - 1 + q_off >= ki * block_kv
     if window is not None:
         # lowest q row of the block must still reach the block's last col
-        run = jnp.logical_and(
-            run,
+        run = run & (
             ki * block_kv + block_kv - 1 >= qi * block_q + q_off - window + 1)
     return run
+
+
+def _band_full(qi, ki, block_q, block_kv, causal, window, q_off=0):
+    """Whether tile (qi, ki) lies wholly inside the band: no causal or
+    window mask changes a score of it. Static geometry (Python ints)."""
+    window = _norm_window(window)[0]     # the mask's window, either impl
+    full = True
+    if causal:
+        full = qi * block_q + q_off >= ki * block_kv + block_kv - 1
+    if window is not None:
+        # highest q row of the tile still sees the tile's first col
+        full = full and qi * block_q + block_q - 1 + q_off - ki * block_kv \
+            < window
+    return full
+
+
+# A grid step of the two backward kernels whose block straddles an edge
+# of the band walks the block as square sub-tiles of this side and
+# computes only those that meet the band: GPT-2 XL's one 1,024 x 1,024
+# block a head is 10 of 16 (tools/kernel_census.py "flash train": the
+# readings that chose 256 are in PERF.md 6, PR 58). The forward kernel
+# keeps the single product: its online softmax pays the row statistics
+# (lane reductions, [rows, 1] columns) once a sub-tile, and every form of
+# the walk measured there was slower than the whole square.
+SUB_TILE = 256
+# Each distinct position of the band's edge inside a block is one more
+# copy of the walk in the kernel's text: equal blocks have one (plus up
+# to two for a window's lower edge); beyond this many the blocks keep
+# the single product.
+MAX_WALKS = 4
+
+
+def _sub_tile(block_q, block_kv):
+    """Side of the sub-tiles a [block_q, block_kv] block is walked as,
+    or None where the block holds no second one."""
+    t = SUB_TILE
+    if block_q % t or block_kv % t or block_q * block_kv < 2 * t * t:
+        return None
+    return t
+
+
+def _block_walks(S, Skv, block_q, block_kv, causal, window, q_off=0):
+    """Which grid blocks are walked as sub-tiles, from the call's static
+    geometry: ``(offsets, plain)``. A block's place against the band is
+    a function of ``d`` alone, its first q position less its first key
+    (``qi * block_q + q_off - ki * block_kv``); ``offsets`` are the d at
+    which a block that runs straddles an edge of the band (the causal
+    diagonal, or a window's lower edge), and ``plain`` says whether any
+    other block runs at all (a block wholly inside the band, or every
+    block of a call that has no sub-tile: the single product)."""
+    if not causal or _sub_tile(block_q, block_kv) is None:
+        return (), True
+    mask_w = _norm_window(window)[0]
+    offsets, plain = set(), False
+    for qi in range(S // block_q):
+        for ki in range(Skv // block_kv):
+            if not _band_run(qi, ki, block_q, block_kv, causal, window,
+                             q_off):
+                continue
+            # the masked window impl also runs blocks wholly below the
+            # band (they wash out of the softmax): single product there
+            if _band_run(qi, ki, block_q, block_kv, causal, mask_w, q_off) \
+                    and not _band_full(qi, ki, block_q, block_kv, causal,
+                                       window, q_off):
+                offsets.add(qi * block_q + q_off - ki * block_kv)
+            else:
+                plain = True
+    if len(offsets) > MAX_WALKS:
+        return (), True
+    return tuple(sorted(offsets)), plain
+
+
+def _tile_walk(block_q, block_kv, causal, window, d, kv_major=False):
+    """The sub-tiles of a block at offset ``d`` (see _block_walks) that
+    meet the band, as ``((start, size, ((start, size, masked), ...)),
+    ...)``: q sub-tiles outside and the kv sub-tiles each meets inside,
+    or with ``kv_major`` the other way round (the dk/dv kernel). The
+    predicates are the grid's own, asked of sub-tile (i, j) with ``d``
+    for ``q_off``; ``masked`` is False for a sub-tile wholly inside the
+    band, which needs no iota, compare or select. Neighbours of one
+    kind come as one longer tile (at d = 0: the strip below the
+    diagonal, then the sub-tile on it)."""
+    t = _sub_tile(block_q, block_kv)
+    nq, nk = block_q // t, block_kv // t
+    walk = []
+    for a in range(nk if kv_major else nq):
+        inner = []
+        for b in range(nq if kv_major else nk):
+            i, j = (b, a) if kv_major else (a, b)
+            if _band_run(i, j, t, t, causal, window, d):
+                masked = not _band_full(i, j, t, t, causal, window, d)
+                if inner and inner[-1][2] == masked \
+                        and inner[-1][0] + inner[-1][1] == b * t:
+                    # a run of sub-tiles of one kind is one product
+                    inner[-1] = (inner[-1][0], inner[-1][1] + t, masked)
+                else:
+                    inner.append((b * t, t, masked))
+        if inner:
+            walk.append((a * t, t, tuple(inner)))
+    return tuple(walk)
+
+
+def tile_census(S, Skv, block_q, block_kv, causal=True, window=None,
+                q_off=0):
+    """``(computed, in the grid)``: how many tiles of the [S, Skv] score
+    matrix the backward kernels compute for this static geometry,
+    counted in the sub-tiles of _sub_tile (in whole blocks where a block
+    holds no second one). GPT-2 XL's training call, one 1,024 x 1,024
+    block a head, reads (10, 16); a non-causal call reads all of them.
+    The forward kernel computes every block that meets the band whole."""
+    block_q, block_kv = min(block_q, S), min(block_kv, Skv)
+    t = _sub_tile(block_q, block_kv)
+    per_block = 1 if t is None else (block_q // t) * (block_kv // t)
+    offsets, _ = _block_walks(S, Skv, block_q, block_kv, causal, window,
+                              q_off)
+    walked = {d: sum(size // t for _, _, inner in
+                     _tile_walk(block_q, block_kv, causal, window, d)
+                     for _, size, _ in inner) for d in offsets}
+    computed = sum(
+        walked.get(qi * block_q + q_off - ki * block_kv, per_block)
+        for qi in range(S // block_q) for ki in range(Skv // block_kv)
+        if _band_run(qi, ki, block_q, block_kv, causal, window, q_off))
+    return computed, (S // block_q) * (Skv // block_kv) * per_block
 
 
 def _window_mask(s, rows, cols, window):
     """cols within (rows - window, rows]: Mistral-style local attention."""
     return jnp.where(rows - cols < window, s, NEG_INF)
+
+
+def _at(ref, start, size):
+    """``size`` rows of the block a ref holds, from ``start`` (entries,
+    of a [1, 1, n] metadata block)."""
+    return ref[0, 0, start:start + size]
+
+
+def _scores(q, k, scale, row0, col0, masked, window, mask, qseg, kseg,
+            kv_major=False):
+    """Scaled scores of one tile in float32, with every mask that
+    applies: [rows of q, rows of k], or with ``kv_major`` their transpose
+    [rows of k, rows of q] straight from the product (the dk/dv kernel:
+    p^T and ds^T are then what its two accumulating products take, and
+    the row statistics broadcast as the lane-dense rows they arrive as).
+    ``row0`` / ``col0`` are the global positions of the tile's first q
+    row and first key; ``masked`` is whether the causal (and window)
+    mask can change a score of this tile."""
+    a, b = (k, q) if kv_major else (q, k)
+    s = jax.lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
+    q_axis = 1 if kv_major else 0
+
+    def along(x, axis):              # a vector laid along one axis of s
+        return x[None, :] if axis else x[:, None]
+    if masked:
+        rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis) + row0
+        cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis) \
+            + col0
+        s = jnp.where(rows >= cols, s, NEG_INF)
+        if window is not None:
+            s = _window_mask(s, rows, cols, _norm_window(window)[0])
+    if mask is not None:
+        s = jnp.where(along(mask, 1 - q_axis) > 0, s, NEG_INF)
+    if qseg is not None:
+        s = jnp.where(along(qseg, q_axis) == along(kseg, 1 - q_axis),
+                      s, NEG_INF)
+    return s
+
+
+def _for_step_tiles(body, qi, ki, *, block_q, block_kv, causal, window,
+                    q_off, walks, kv_major=False):
+    """Run ``body(tiles)`` for grid step (qi, ki) if its block meets the
+    band: with the block's one tile (the single product), or, where the
+    block straddles an edge of the band at one of the static offsets of
+    ``walks`` (_block_walks), with its sub-tiles that meet the band."""
+    offsets, plain = walks
+    run = _band_run(qi, ki, block_q, block_kv, causal, window, q_off)
+    d = qi * block_q + q_off - ki * block_kv
+    for w in offsets:
+        pl.when(d == w)(functools.partial(
+            body, _tile_walk(block_q, block_kv, causal, window, w,
+                             kv_major)))
+        run = run & (d != w)
+    if plain:
+        outer, inner = (block_kv, block_q) if kv_major \
+            else (block_q, block_kv)
+        pl.when(run)(functools.partial(
+            body, ((0, outer, ((0, inner, causal),)),)))
 
 
 # ---------------------------------------------------------------------------
@@ -182,24 +370,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
     @pl.when(run)
     def _body():
         q = q_ref[0, 0]                  # [block_q, d]
-        k = k_ref[0, 0]                  # [block_kv, d]
-        v = v_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # [bq, bkv]
-
-        if causal:
-            rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) \
-                + qi * block_q + q_off
-            cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + ki * block_kv
-            s = jnp.where(rows >= cols, s, NEG_INF)
-            if window is not None:
-                s = _window_mask(s, rows, cols, _norm_window(window)[0])
-        if has_mask:
-            s = jnp.where(mask_ref[0, 0][None, :] > 0, s, NEG_INF)
-        if has_segs:
-            s = jnp.where(qseg_ref[0, 0][:, None] == kseg_ref[0, 0][None, :],
-                          s, NEG_INF)
+        v = v_ref[0, 0]                  # [block_kv, d]
+        s = _scores(q, k_ref[0, 0], scale, qi * block_q + q_off,
+                    ki * block_kv, causal, window,
+                    mask_ref[0, 0] if has_mask else None,
+                    qseg_ref[0, 0] if has_segs else None,
+                    kseg_ref[0, 0] if has_segs else None)   # [bq, bkv]
 
         m_prev = m_scratch[:, :1]                        # [bq, 1]
         m_cur = jnp.max(s, axis=-1, keepdims=True)       # [bq, 1]
@@ -217,11 +393,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
 
     @pl.when(ki == num_kv - 1)
     def _finish():
-        l = l_scratch[:, :1]
+        l = l_scratch[:]                 # [bq, LANES], every lane the same
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scratch[:] / l_safe).astype(o_ref.dtype)
-        lse = m_scratch[:, :1] + jnp.log(l_safe)
-        lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref.shape[2:]).astype(jnp.float32)
+        o_ref[0, 0] = (acc_scratch[:] / l_safe[:, :1]).astype(o_ref.dtype)
+        # the row statistic leaves lane-dense, one [1, bq] row (_stat_spec)
+        lse_ref[0, 0] = jnp.transpose(m_scratch[:] + jnp.log(l_safe))[:1]
 
 
 def _mask_spec(block_kv, kvmap):
@@ -246,6 +422,28 @@ def _qseg_spec(block_q, qmap):
         return (ids[0], 0, qblk)
 
     return pl.BlockSpec((1, 1, block_q), smap)
+
+
+def _stat_spec(block_q, qmap):
+    """Block spec of a per-row statistic (lse, delta), following qmap.
+    The [B, H, S] statistic is fed as [B, H, 1, S], one lane-dense row of
+    block_q a block: the dq kernel makes a column of it (_stat_col), the
+    dk/dv kernel's transposed tiles take it as the row it is. As
+    [B, H, S, 8] columns it was 8 lanes of a 128-lane tile a row: the
+    copies of those blocks, not the arithmetic, were what the backward
+    kernels waited for (tools/kernel_census.py "flash stats floor";
+    PERF.md 6, PR 58)."""
+    def smap(*ids):
+        b, h, qblk, _ = qmap(*ids)
+        return (b, h, 0, qblk)
+
+    return pl.BlockSpec((1, 1, 1, block_q), smap)
+
+
+def _stat_col(ref, start, size):
+    """``size`` rows of a per-row statistic from ``start``, as the
+    [size, 1] column that broadcasts against a tile's scores."""
+    return ref[0, 0, 0, start:start + size][:, None]
 
 
 def _group_head(map_fn, group: int):
@@ -308,7 +506,7 @@ def _flash_fwd(q, k, v, mask, qsegs, ksegs, causal, scale, block_q, block_kv,
 
     out_shape = [
         jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
-        jax.ShapeDtypeStruct((B, H, S, STATS), jnp.float32),
+        jax.ShapeDtypeStruct((B, H, 1, S), jnp.float32),
     ]
     o, lse = pl.pallas_call(
         kernel,
@@ -317,7 +515,7 @@ def _flash_fwd(q, k, v, mask, qsegs, ksegs, causal, scale, block_q, block_kv,
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, 1, block_q, D), qmap),
-            pl.BlockSpec((1, 1, block_q, STATS), qmap),
+            _stat_spec(block_q, qmap),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, LANES), jnp.float32),
@@ -328,17 +526,26 @@ def _flash_fwd(q, k, v, mask, qsegs, ksegs, causal, scale, block_q, block_kv,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
     )(*operands)
-    return o, lse[..., 0]
+    return o, lse[:, :, 0]
 
 
 # ---------------------------------------------------------------------------
 # backward kernels
 # ---------------------------------------------------------------------------
 
+def _grad_tile(s, dp, lse, delta, scale):
+    """(p, ds) of one tile from its masked scores ``s`` and ``dp`` (do @
+    v^T, or both transposed): the recomputed probabilities and the
+    scores' gradient, in float32. ``lse`` / ``delta`` broadcast against
+    the tile: [rows, 1] columns, or [1, rows] for a transposed tile."""
+    p = jnp.exp(s - lse)
+    return p, p * (dp - delta) * scale
+
+
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     *rest, causal: bool, has_mask: bool, has_segs: bool,
                     scale: float, block_q: int, block_kv: int, num_q: int,
-                    window=None, q_off: int = 0):
+                    window=None, q_off: int = 0, walks=((), True)):
     rest = list(rest)
     mask_ref = rest.pop(0) if has_mask else None
     qseg_ref = rest.pop(0) if has_segs else None
@@ -352,45 +559,41 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scratch[:] = jnp.zeros_like(dk_scratch)
         dv_scratch[:] = jnp.zeros_like(dv_scratch)
 
-    run = _band_run(qi, ki, block_q, block_kv, causal, window, q_off)
+    def _body(tiles):
+        # a tile is computed TRANSPOSED, [rows of k, rows of q]: p^T and
+        # ds^T are what the two accumulating products take
+        for c0, cn, q_tiles in tiles:
+            k = _at(k_ref, c0, cn)                       # [cn, d]
+            v = _at(v_ref, c0, cn)
+            mask = _at(mask_ref, c0, cn) if has_mask else None
+            kseg = _at(kseg_ref, c0, cn) if has_segs else None
+            dk = dv = 0.0
+            for r0, rn, masked in q_tiles:
+                q = _at(q_ref, r0, rn)                   # [rn, d]
+                do = _at(do_ref, r0, rn)
+                st = _scores(q, k, scale, qi * block_q + q_off + r0,
+                             ki * block_kv + c0, masked, window, mask,
+                             _at(qseg_ref, r0, rn) if has_segs else None,
+                             kseg, kv_major=True)        # [cn, rn]
+                # dp^T = v @ do^T
+                dpt = jax.lax.dot_general(v, do, (((1,), (1,)), ((), ())),
+                                          preferred_element_type=jnp.float32)
+                pt, dst = _grad_tile(
+                    st, dpt, lse_ref[0, 0, :, r0:r0 + rn],      # [1, rn]
+                    delta_ref[0, 0, :, r0:r0 + rn], scale)
+                # dv += p^T @ do ; dk += ds^T @ q
+                dv += jax.lax.dot_general(
+                    pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                dk += jax.lax.dot_general(
+                    dst.astype(q.dtype), q, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            dk_scratch[c0:c0 + cn] += dk
+            dv_scratch[c0:c0 + cn] += dv
 
-    @pl.when(run)
-    def _body():
-        q = q_ref[0, 0]                # [bq, d]
-        k = k_ref[0, 0]                # [bkv, d]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0]              # [bq, d]
-        lse = lse_ref[0, 0][:, :1]     # [bq, 1]
-        delta = delta_ref[0, 0][:, :1]  # [bq, 1]
-
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) \
-                + qi * block_q + q_off
-            cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + ki * block_kv
-            s = jnp.where(rows >= cols, s, NEG_INF)
-            if window is not None:
-                s = _window_mask(s, rows, cols, _norm_window(window)[0])
-        if has_mask:
-            s = jnp.where(mask_ref[0, 0][None, :] > 0, s, NEG_INF)
-        if has_segs:
-            s = jnp.where(qseg_ref[0, 0][:, None] == kseg_ref[0, 0][None, :],
-                          s, NEG_INF)
-        p = jnp.exp(s - lse)                               # [bq, bkv]
-
-        # dv += p^T @ do
-        dv_scratch[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        # dp = do @ v^T ; ds = p * (dp - delta) * scale
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale                      # [bq, bkv]
-        # dk += ds^T @ q
-        dk_scratch[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _for_step_tiles(_body, qi, ki, block_q=block_q, block_kv=block_kv,
+                    causal=causal, window=window, q_off=q_off, walks=walks,
+                    kv_major=True)
 
     @pl.when(qi == num_q - 1)
     def _finish():
@@ -401,7 +604,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    *rest, causal: bool, has_mask: bool, has_segs: bool,
                    scale: float, block_q: int, block_kv: int, num_kv: int,
-                   window=None, q_off: int = 0):
+                   window=None, q_off: int = 0, walks=((), True)):
     rest = list(rest)
     mask_ref = rest.pop(0) if has_mask else None
     qseg_ref = rest.pop(0) if has_segs else None
@@ -414,38 +617,33 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dq_scratch[:] = jnp.zeros_like(dq_scratch)
 
-    run = _band_run(qi, ki, block_q, block_kv, causal, window, q_off)
+    def _body(tiles):
+        for r0, rn, kv_tiles in tiles:
+            q = _at(q_ref, r0, rn)
+            do = _at(do_ref, r0, rn)
+            lse = _stat_col(lse_ref, r0, rn)            # [rn, 1]
+            delta = _stat_col(delta_ref, r0, rn)
+            qseg = _at(qseg_ref, r0, rn) if has_segs else None
+            dq = 0.0
+            for c0, cn, masked in kv_tiles:
+                k = _at(k_ref, c0, cn)
+                v = _at(v_ref, c0, cn)
+                s = _scores(
+                    q, k, scale, qi * block_q + q_off + r0,
+                    ki * block_kv + c0, masked, window,
+                    _at(mask_ref, c0, cn) if has_mask else None,
+                    qseg, _at(kseg_ref, c0, cn) if has_segs else None)
+                # dp = do @ v^T
+                dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                         preferred_element_type=jnp.float32)
+                _, ds = _grad_tile(s, dp, lse, delta, scale)
+                dq += jax.lax.dot_general(
+                    ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            dq_scratch[r0:r0 + rn] += dq
 
-    @pl.when(run)
-    def _body():
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0][:, :1]
-        delta = delta_ref[0, 0][:, :1]
-
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) \
-                + qi * block_q + q_off
-            cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + ki * block_kv
-            s = jnp.where(rows >= cols, s, NEG_INF)
-            if window is not None:
-                s = _window_mask(s, rows, cols, _norm_window(window)[0])
-        if has_mask:
-            s = jnp.where(mask_ref[0, 0][None, :] > 0, s, NEG_INF)
-        if has_segs:
-            s = jnp.where(qseg_ref[0, 0][:, None] == kseg_ref[0, 0][None, :],
-                          s, NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        dq_scratch[:] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _for_step_tiles(_body, qi, ki, block_q=block_q, block_kv=block_kv,
+                    causal=causal, window=window, q_off=q_off, walks=walks)
 
     @pl.when(ki == num_kv - 1)
     def _finish():
@@ -472,12 +670,12 @@ def _flash_bwd(causal, scale, block_q, block_kv, window, res, g, q_off=0,
     has_mask = mask is not None
     has_segs = qsegs is not None
     assert (qsegs is None) == (ksegs is None)
+    walks = _block_walks(S, Skv, block_q, block_kv, causal, window, q_off)
 
     if delta is None:
         delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                         axis=-1)                              # [B,H,S]
-    lse_b = jnp.broadcast_to(lse[..., None], (B, H, S, STATS))
-    delta_b = jnp.broadcast_to(delta[..., None], (B, H, S, STATS))
+    lse_b, delta_b = lse[:, :, None], delta[:, :, None]   # [B, H, 1, S]
 
     def qmap(b, h, i, j):
         return (b, h, i, 0)
@@ -496,8 +694,8 @@ def _flash_bwd(causal, scale, block_q, block_kv, window, res, g, q_off=0,
         pl.BlockSpec((1, 1, block_kv, D), kvmap_q_outer_h),
         pl.BlockSpec((1, 1, block_kv, D), kvmap_q_outer_h),
         pl.BlockSpec((1, 1, block_q, D), qmap),
-        pl.BlockSpec((1, 1, block_q, STATS), qmap),
-        pl.BlockSpec((1, 1, block_q, STATS), qmap),
+        _stat_spec(block_q, qmap),
+        _stat_spec(block_q, qmap),
     ]
     operands = [q, k, v, do, lse_b, delta_b]
     if has_mask:
@@ -511,7 +709,8 @@ def _flash_bwd(causal, scale, block_q, block_kv, window, res, g, q_off=0,
         functools.partial(_bwd_dq_kernel, causal=causal, has_mask=has_mask,
                           has_segs=has_segs,
                           scale=scale, block_q=block_q, block_kv=block_kv,
-                          num_kv=num_kv, window=window, q_off=q_off),
+                          num_kv=num_kv, window=window, q_off=q_off,
+                          walks=walks),
         name="flash_bwd_dq",
         grid=(B, H, num_q, num_kv),
         in_specs=in_specs,
@@ -557,8 +756,8 @@ def _flash_bwd(causal, scale, block_q, block_kv, window, res, g, q_off=0,
         pl.BlockSpec((1, 1, block_kv, D), kvmap_in_h),
         pl.BlockSpec((1, 1, block_kv, D), kvmap_in_h),
         pl.BlockSpec((1, 1, block_q, D), qmap_kv_outer),
-        pl.BlockSpec((1, 1, block_q, STATS), qmap_kv_outer),
-        pl.BlockSpec((1, 1, block_q, STATS), qmap_kv_outer),
+        _stat_spec(block_q, qmap_kv_outer),
+        _stat_spec(block_q, qmap_kv_outer),
     ]
     operands = [q, k, v, do, lse_b, delta_b]
     if has_mask:
@@ -574,7 +773,8 @@ def _flash_bwd(causal, scale, block_q, block_kv, window, res, g, q_off=0,
         functools.partial(_bwd_dkv_kernel, causal=causal, has_mask=has_mask,
                           has_segs=has_segs,
                           scale=scale, block_q=block_q, block_kv=block_kv,
-                          num_q=num_q, window=window, q_off=q_off),
+                          num_q=num_q, window=window, q_off=q_off,
+                          walks=walks),
         name="flash_bwd_dkv",
         grid=(B, H, num_kv, num_q),
         in_specs=in_specs,

@@ -109,7 +109,12 @@ def test_training_default_off_tpu_is_dense_and_says_so(devices, ready_lines):
 def test_flash_asked_for_on_a_tpu_and_unusable_raises(monkeypatch):
     monkeypatch.setattr("deepspeed_tpu.utils.on_tpu", lambda: True)
     cfg, _ = tiny()
-    assert gpt.attention_impl(cfg, 256) == "flash(256x256)"
+    # a 256 block holds no second sub-tile: the backward walks nothing
+    assert gpt.attention_impl(cfg, 256) == \
+        "flash(256x256, backward sub-tiles 1/1)"
+    # GPT-2 XL's training geometry: one 1,024 block a head, 10 of 16
+    assert gpt.attention_impl(cfg, 1024) == \
+        "flash(1024x1024, backward sub-tiles 10/16)"
     with pytest.raises(ValueError, match="no flash block"):
         gpt.attention_impl(cfg, 100)
     assert gpt.attention_impl(

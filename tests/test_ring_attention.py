@@ -252,26 +252,33 @@ def test_ring_flash_kernel_matches_dense(devices, pallas_interpret):
                                    rtol=1e-3, atol=1e-3, err_msg=nm)
 
 
-def test_ring_flash_window_matches_dense(devices, pallas_interpret):
+@pytest.mark.parametrize("ring,S,blk,window", [
+    pytest.param(4, 256, 32, 96, id="ring4-S256-blk32-W96"),
+    # each device holds ONE 512 block: the diagonal step's block straddles
+    # the causal diagonal and the next step's the window's lower edge, and
+    # both hold several sub-tiles (flash.SUB_TILE) for the backward to walk
+    pytest.param(2, 1024, 512, 640, id="ring2-S1024-blk512-W640")])
+def test_ring_flash_window_matches_dense(devices, pallas_interpret, ring, S,
+                                         blk, window):
     """Flash-kernel ring steps with a sliding window: the banded partial
     block (static q_off) goes through the kernel's offset index maps."""
-    mesh = make_mesh(MeshSpec(data=2, sequence=4))
+    mesh = make_mesh(MeshSpec(data=8 // ring, sequence=ring))
     ks = jax.random.split(jax.random.PRNGKey(8), 3)
-    q, k, v = (jax.random.normal(kk, (1, 256, 2, 8), jnp.float32)
+    q, k, v = (jax.random.normal(kk, (1, S, 2, 8), jnp.float32)
                for kk in ks)
     out = ring_attention(q, k, v, mesh, causal=True, use_flash=True,
-                         block_q=32, block_kv=32, window=96)
-    ref = mha_reference(q, k, v, causal=True, window=96)
+                         block_q=blk, block_kv=blk, window=window)
+    ref = mha_reference(q, k, v, causal=True, window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
     # grads too: the q_off-shifted windowed BACKWARD index maps (the
     # clip-based first/last q-block computation in _flash_bwd) are
     # otherwise uncovered
     g_r = jax.grad(lambda q, k, v: jnp.sum(ring_attention(
-        q, k, v, mesh, causal=True, use_flash=True, block_q=32,
-        block_kv=32, window=96) ** 2), argnums=(0, 1, 2))(q, k, v)
+        q, k, v, mesh, causal=True, use_flash=True, block_q=blk,
+        block_kv=blk, window=window) ** 2), argnums=(0, 1, 2))(q, k, v)
     g_d = jax.grad(lambda q, k, v: jnp.sum(mha_reference(
-        q, k, v, causal=True, window=96) ** 2),
+        q, k, v, causal=True, window=window) ** 2),
         argnums=(0, 1, 2))(q, k, v)
     for a, b, nm in zip(g_r, g_d, "qkv"):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),

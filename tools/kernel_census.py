@@ -8,7 +8,9 @@ arithmetic, one row per kernel and shape. A census records what each
 kernel does, so a failing row is printed with the compiler's message and
 the run goes on; the exit code is non-zero if any row failed.
 
-Usage: python tools/kernel_census.py [substring ...]
+Usage: python tools/kernel_census.py [substring ...] [--parent=DIR]
+(``--parent``: a ``git archive`` of the parent commit, whose flash kernels
+the "flash train shape" row then times and checks on the same inputs)
 One JSON line per row, also appended to chiprun_out/kernel_census.jsonl.
 Needs a TPU; one process.
 """
@@ -107,6 +109,197 @@ def flash_rows():
             return {"fwd_err": fwd, "bwd_err": bwd,
                     "ok": fwd < TOL and bwd < TOL}
         yield name, run
+
+
+# GPT-2 XL's training call, in the kernels' own layout [B, H, S, D]: one
+# 1,024 x 1,024 grid block a head (benchmark/configs/gpt2-xl-train-*.json)
+FLASH_TRAIN = (16, 25, 1024, 64, 1024)
+FLASH_REPS = 20
+# the sub-tile sides "flash train sub-tile" forces (F.SUB_TILE); 1,024 is
+# no second sub-tile: the single product a grid step
+FLASH_SUB_TILES = (128, 256, 512, 1024)
+
+
+def _parent_flash():
+    """The flash module of the tree that ``--parent=DIR`` names (a
+    ``git archive`` of the parent commit), or None."""
+    import importlib.util
+    root = next((a.split("=", 1)[1] for a in sys.argv[1:]
+                 if a.startswith("--parent=")), None)
+    if root is None:
+        return None
+    spec = importlib.util.spec_from_file_location(
+        "parent_flash", os.path.join(
+            root, "deepspeed_tpu", "ops", "attention", "flash.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _flash_train_calls(M, blk, scale):
+    """The three kernels of module ``M`` alone, as jitted programs of
+    FLASH_REPS calls each chained through its first output (XLA drops the
+    call whose results nothing reads, so ``dq`` and ``dkv`` are timed
+    apart), and one forward + backward."""
+    def fwd(q, k, v):
+        return M._flash_fwd(q, k, v, None, None, None, True, scale, blk,
+                            blk)
+
+    def bwd(q, k, v, o, lse, do):
+        return M._flash_bwd(True, scale, blk, blk, None,
+                            (q, k, v, None, None, None, o, lse), do)
+
+    def chain(f):
+        def many(x, *rest):
+            return jax.lax.fori_loop(
+                0, FLASH_REPS, lambda _, x: f(x, *rest).astype(x.dtype), x)
+        return jax.jit(many)
+    return {
+        "fwd": chain(lambda q, k, v: fwd(q, k, v)[0]),
+        "dq": chain(lambda do, q, k, v, o, lse:
+                    bwd(q, k, v, o, lse, do)[0]),
+        "dkv": chain(lambda do, q, k, v, o, lse:
+                     sum(bwd(q, k, v, o, lse, do)[1:])),
+    }, jax.jit(fwd), jax.jit(bwd)
+
+
+def _flash_train_read(M, q, k, v, do, ref=None):
+    """us a call of each kernel of ``M`` at the training shape and, with
+    ``ref`` (the float32 reference's o, dq, dk, dv), each output's error."""
+    blk = FLASH_TRAIN[-1]
+    scale = 1.0 / np.sqrt(q.shape[-1])
+    timed, fwd, bwd = _flash_train_calls(M, blk, scale)
+    o, lse = fwd(q, k, v)
+    grads = bwd(q, k, v, o, lse, do)
+    row = {}
+    if ref is not None:
+        row = {f"{n}_err": _err(a, b)
+               for n, a, b in zip(("o", "dq", "dk", "dv"), (o,) + grads, ref)}
+    row["us_fwd"] = _best_seconds(timed["fwd"], q, k, v) / FLASH_REPS * 1e6
+    for n in ("dq", "dkv"):
+        row[f"us_{n}"] = _best_seconds(
+            timed[n], do, q, k, v, o, lse) / FLASH_REPS * 1e6
+    return {a: round(b, 6 if a.endswith("err") else 1)
+            for a, b in row.items()}, (o,) + grads
+
+
+def _flash_train_inputs():
+    B, H, S, D, _ = FLASH_TRAIN
+    ks = jax.random.split(jax.random.PRNGKey(58), 4)
+    return [jax.random.normal(kk, (B, H, S, D), jnp.bfloat16) for kk in ks]
+
+
+@jax.jit
+def _flash_train_ref(q, k, v, do):
+    """(o, dq, dk, dv) of ``mha_reference`` in float32, causal, [B, H, S, D]
+    in and out."""
+    def rf(q, k, v):
+        def t(x):
+            return x.transpose(0, 2, 1, 3)
+        return t(F.mha_reference(t(q), t(k), t(v), causal=True))
+    o, vjp = jax.vjp(rf, *_f32(q, k, v))
+    return (o,) + vjp(do.astype(jnp.float32))
+
+
+def flash_train_rows():
+    """The three flash kernels at GPT-2 XL's training shape (bf16
+    [16, 25, 1024, 64], one 1,024 block a head, causal): ``o``, ``dq``,
+    ``dk``, ``dv`` against ``mha_reference`` in float32 with one random
+    ``do``, microseconds a call of each kernel, and the sub-tile census;
+    beside them the same readings with the sub-tile walk off (the single
+    product a grid step) and, with ``--parent=DIR``, of the parent
+    tree's module on the same inputs. ``ok``: every error of the walk
+    within 1.5 times the single product's (and the parent's)."""
+    def run():
+        q, k, v, do = _flash_train_inputs()
+        B, H, S, D, blk = FLASH_TRAIN
+
+        ref = [jnp.concatenate(x) for x in zip(*(
+            _flash_train_ref(*(a[i:i + 4] for a in (q, k, v, do)))
+            for i in range(0, B, 4)))]   # four batch rows: 0.4 GB of scores
+        row = {"sub_tiles": list(F.tile_census(S, S, blk, blk, True))}
+        row["walk"], outs = _flash_train_read(F, q, k, v, do, ref)
+        old = F.SUB_TILE
+        F.SUB_TILE = blk                 # no second sub-tile in a block
+        try:
+            row["single"], base = _flash_train_read(F, q, k, v, do, ref)
+        finally:
+            F.SUB_TILE = old
+        parent = _parent_flash()
+        if parent is not None:
+            row["parent"], base = _flash_train_read(parent, q, k, v, do,
+                                                    ref)
+        # the walk against the single product (the parent's, if given):
+        # the same terms summed in another order
+        row["walk_vs_base"] = {
+            n: round(_err(a, b), 6)
+            for n, a, b in zip(("o", "dq", "dk", "dv"), outs, base)}
+        worst = max(
+            row["walk"][n] / max(row[base][n], 1e-9)
+            for base in ("single", "parent") if base in row
+            for n in ("o_err", "dq_err", "dk_err", "dv_err"))
+        return {**row, "worst_err_ratio": worst,
+                "ok": worst <= 1.5 and max(
+                    row["walk"][n] for n in row["walk"]
+                    if n.endswith("err")) < TOL}
+    yield "flash train shape", run
+
+    def sweep(t):
+        q, k, v, do = _flash_train_inputs()
+        B, H, S, D, blk = FLASH_TRAIN
+        old = F.SUB_TILE
+        F.SUB_TILE = t
+        try:
+            return {"sub_tiles": list(F.tile_census(S, S, blk, blk, True)),
+                    **_flash_train_read(F, q, k, v, do)[0], "ok": True}
+        finally:
+            F.SUB_TILE = old
+    for t in FLASH_SUB_TILES:
+        yield f"flash train sub-tile {t}", functools.partial(sweep, t)
+
+
+def _stats_floor_call(stat_shape, stat_block, stat_map):
+    """A kernel with the backward kernels' operands and grid at the
+    training shape and an empty body: what a grid step waits for when it
+    computes nothing, with the two row statistics in ``stat_shape``."""
+    from jax.experimental import pallas as pl
+    B, H, S, D, blk = FLASH_TRAIN
+    big = pl.BlockSpec((1, 1, blk, D), lambda b, h, i, j: (b, h, i, 0))
+    stat = pl.BlockSpec(stat_block, stat_map)
+
+    def body(q, k, v, do, lse, delta, dq):
+        dq[...] = jnp.zeros_like(dq)
+    call = pl.pallas_call(
+        body, grid=(B, H, S // blk, S // blk),
+        in_specs=[big] * 4 + [stat] * 2, out_specs=big,
+        out_shape=jax.ShapeDtypeStruct((B, H, S, D), jnp.bfloat16))
+    x = jnp.zeros((B, H, S, D), jnp.bfloat16)
+    st = jnp.zeros(stat_shape, jnp.float32)
+
+    @jax.jit
+    def many(x, st):
+        return jax.lax.fori_loop(
+            0, FLASH_REPS, lambda _, x: call(x, x, x, x, st, st), x)
+    return _best_seconds(many, x, st) / FLASH_REPS * 1e6
+
+
+def flash_stats_floor_rows():
+    """Microseconds a call of an EMPTY kernel that takes what ``flash_bwd_dq``
+    takes (q, k, v, do, lse, delta in; dq out) at the training shape: with
+    the two row statistics as [B, H, S, 8] columns (the layout before PR
+    58) and as [B, H, 1, S] rows (flash._stat_spec). The difference is
+    what the column blocks' copies cost a call whatever it computes."""
+    def run():
+        B, H, S, D, blk = FLASH_TRAIN
+        row = {
+            "us_columns_of_8": round(_stats_floor_call(
+                (B, H, S, 8), (1, 1, blk, 8),
+                lambda b, h, i, j: (b, h, i, 0)), 1),
+            "us_rows": round(_stats_floor_call(
+                (B, H, 1, S), (1, 1, 1, blk),
+                lambda b, h, i, j: (b, h, 0, i)), 1)}
+        return {**row, "ok": row["us_rows"] < row["us_columns_of_8"]}
+    yield "flash stats floor", run
 
 
 def ring_block_rows():
@@ -1089,11 +1282,13 @@ def blocksparse_rows():
 
 def main():
     dev = require_tpu("kernel_census")
-    wanted = sys.argv[1:]
+    wanted = [a for a in sys.argv[1:] if not a.startswith("--")]
     os.makedirs(os.path.dirname(OUT), exist_ok=True)
     failed = 0
     with open(OUT, "a") as out:
-        for rows in (flash_rows, ring_block_rows, paged_rows,
+        for rows in (flash_rows, flash_train_rows, flash_stats_floor_rows,
+                     ring_block_rows,
+                     paged_rows,
                      paged_time_rows, paged_tile_rows,
                      paged_masked_time_rows, mla_time_rows,
                      mla_prefill_time_rows, gdn_chunk_time_rows,
