@@ -100,10 +100,6 @@ class GPTConfig:
     # sliding-window (local) attention: token i attends (i-window, i]
     # only — O(S*window) compute and HBM reads in the flash kernel
     attn_window: Optional[int] = None
-    # "banded" (O(S*W) index-map clamps) or "masked" (in-body mask over
-    # plain causal geometry, O(S^2) reads); None resolves from
-    # DS_FLASH_WINDOW_IMPL (default banded)
-    attn_window_impl: Optional[str] = None
     # --- llama-family architecture knobs -------------------------------
     # norm: 'layernorm' (GPT-2) or 'rmsnorm' (llama — scale only, no
     # mean subtraction); activation: 'gelu' or 'swiglu' (gated MLP with
@@ -430,12 +426,9 @@ def attention_impl(cfg: GPTConfig, seq_len: Optional[int] = None) -> str:
         return sp + "dense"
     # how often the backward kernels' sub-tile walk engages: static, from
     # the call's geometry (a ring's diagonal step under sequence parallel)
-    from deepspeed_tpu.ops.attention.flash import (resolve_window_impl,
-                                                   tile_census)
+    from deepspeed_tpu.ops.attention.flash import tile_census
     bwd = [own or fwd for own, fwd in zip(_flash_bwd_blocks(cfg, S), blocks)]
-    done, grid = tile_census(
-        S, S, *bwd, True,
-        resolve_window_impl(cfg.attn_window, cfg.attn_window_impl))
+    done, grid = tile_census(S, S, *bwd, True, cfg.attn_window)
     return sp + (f"flash({blocks[0]}x{blocks[1]}, backward sub-tiles "
                  f"{done}/{grid})")
 
@@ -504,7 +497,6 @@ def _attention(q, k, v, cfg: GPTConfig, segment_ids=None, kv_mask=None):
                 block_kv=blocks[1] if blocks else cfg.flash_block_kv,
                 segment_ids=segment_ids, kv_mask=kv_mask,
                 window=cfg.attn_window,
-                window_impl=cfg.attn_window_impl,
                 bwd_block_q=bwd_q, bwd_block_kv=bwd_kv)
         if cfg.sp_impl != "ring":
             raise ValueError(f"unknown sp_impl {cfg.sp_impl!r} "
@@ -521,8 +513,7 @@ def _attention(q, k, v, cfg: GPTConfig, segment_ids=None, kv_mask=None):
             window=cfg.attn_window, use_flash=blocks is not None,
             block_q=blocks[0] if blocks else 512,
             block_kv=blocks[1] if blocks else 512,
-            layout=cfg.sp_layout,
-            window_impl=cfg.attn_window_impl)
+            layout=cfg.sp_layout)
     blocks = _flash_blocks(cfg, q.shape[1])
     if blocks is not None:
         # fall back to the fwd block when no bwd override divides
@@ -530,7 +521,6 @@ def _attention(q, k, v, cfg: GPTConfig, segment_ids=None, kv_mask=None):
         return _flash_per_device(
             q, k, v, segment_ids, kv_mask, causal=True, scale=scale,
             block_q=blocks[0], block_kv=blocks[1], window=cfg.attn_window,
-            window_impl=cfg.attn_window_impl,
             bwd_block_q=bwd_q, bwd_block_kv=bwd_kv)
     return mha_reference(q, k, v, causal=True, scale=scale,
                          segment_ids=segment_ids, kv_mask=kv_mask,

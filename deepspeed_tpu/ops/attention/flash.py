@@ -32,47 +32,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-
-
-def _norm_window(window):
-    """Decode the static ``window`` argument into
-    ``(mask_window, band_window)``.
-
-    ``window`` is either an int — the BANDED implementation: DMA-eliding
-    index-map clamps + band-aware grid skipping + in-body mask — or the
-    tagged tuple ``("masked", int)`` — the second implementation, which
-    expresses the sliding window purely as an in-body mask over the
-    plain causal geometry. It was written when an earlier toolchain's
-    Mosaic hung on the banded form; both compile under libtpu 0.0.34
-    (tools/kernel_census.py; ROADMAP D6 keeps one). Cost: O(S^2) HBM
-    reads/compute like plain causal instead of O(S*W) — correctness is
-    identical because fully out-of-band blocks wash out of the online
-    softmax exactly like fully-masked kv_mask blocks (see
-    flash_attention docstring)."""
-    if window is None:
-        return None, None
-    if isinstance(window, tuple):
-        impl, w = window
-        assert impl == "masked", f"unknown window impl {impl!r}"
-        return int(w), None
-    return int(window), int(window)
-
-
-def resolve_window_impl(window, window_impl=None):
-    """Tag ``window`` for the masked implementation when requested
-    (explicit arg wins, else DS_FLASH_WINDOW_IMPL, default banded).
-    Shared by every window entry point (flash_attention, ring,
-    ulysses)."""
-    if window is None or isinstance(window, tuple):
-        return window
-    from deepspeed_tpu.utils.env import resolve_flag
-    impl = window_impl or resolve_flag("DS_FLASH_WINDOW_IMPL")
-    if impl not in ("banded", "masked"):
-        # ValueError, not assert: this validates user input (env var /
-        # config) and must survive python -O
-        raise ValueError(f"unknown window impl {impl!r}: "
-                         f"expected 'banded' or 'masked'")
-    return ("masked", int(window)) if impl == "masked" else int(window)
 LANES = 128
 
 
@@ -123,8 +82,6 @@ def _causal_kv_index_map(block_q, block_kv, num_kv, window=None, q_off=0):
     the ring loop is unrolled), so all causal/window geometry shifts by
     it."""
 
-    window = _norm_window(window)[1]     # banded geometry only
-
     def kvmap(b, h, qi, ki):
         limit = jnp.minimum((qi * block_q + block_q - 1 + q_off) // block_kv,
                             num_kv - 1)
@@ -144,7 +101,6 @@ def _band_run(qi, ki, block_q, block_kv, causal, window, q_off=0):
     its blocks (traced indices), and a block that straddles an edge of
     the band asks it of its sub-tiles (Python ints in, a Python bool
     out: see _tile_walk)."""
-    window = _norm_window(window)[1]     # banded geometry only
     run = True
     if causal:
         run = qi * block_q + block_q - 1 + q_off >= ki * block_kv
@@ -158,7 +114,6 @@ def _band_run(qi, ki, block_q, block_kv, causal, window, q_off=0):
 def _band_full(qi, ki, block_q, block_kv, causal, window, q_off=0):
     """Whether tile (qi, ki) lies wholly inside the band: no causal or
     window mask changes a score of it. Static geometry (Python ints)."""
-    window = _norm_window(window)[0]     # the mask's window, either impl
     full = True
     if causal:
         full = qi * block_q + q_off >= ki * block_kv + block_kv - 1
@@ -205,21 +160,16 @@ def _block_walks(S, Skv, block_q, block_kv, causal, window, q_off=0):
     block of a call that has no sub-tile: the single product)."""
     if not causal or _sub_tile(block_q, block_kv) is None:
         return (), True
-    mask_w = _norm_window(window)[0]
     offsets, plain = set(), False
     for qi in range(S // block_q):
         for ki in range(Skv // block_kv):
             if not _band_run(qi, ki, block_q, block_kv, causal, window,
                              q_off):
                 continue
-            # the masked window impl also runs blocks wholly below the
-            # band (they wash out of the softmax): single product there
-            if _band_run(qi, ki, block_q, block_kv, causal, mask_w, q_off) \
-                    and not _band_full(qi, ki, block_q, block_kv, causal,
-                                       window, q_off):
-                offsets.add(qi * block_q + q_off - ki * block_kv)
-            else:
+            if _band_full(qi, ki, block_q, block_kv, causal, window, q_off):
                 plain = True
+            else:
+                offsets.add(qi * block_q + q_off - ki * block_kv)
     if len(offsets) > MAX_WALKS:
         return (), True
     return tuple(sorted(offsets)), plain
@@ -313,7 +263,7 @@ def _scores(q, k, scale, row0, col0, masked, window, mask, qseg, kseg,
             + col0
         s = jnp.where(rows >= cols, s, NEG_INF)
         if window is not None:
-            s = _window_mask(s, rows, cols, _norm_window(window)[0])
+            s = _window_mask(s, rows, cols, window)
     if mask is not None:
         s = jnp.where(along(mask, 1 - q_axis) > 0, s, NEG_INF)
     if qseg is not None:
@@ -733,15 +683,13 @@ def _flash_bwd(causal, scale, block_q, block_kv, window, res, g, q_off=0,
         # valid for the last kv blocks). With a sliding window the LAST
         # valid q block is bounded too — late steps clamp down the same
         # way.
-        band_w = _norm_window(window)[1]   # banded geometry only
-
         def qmap_kv_outer(b, h, ki, qi):
             first = jnp.clip((ki * block_kv - q_off) // block_q,
                              0, num_q - 1)
             qi = jnp.maximum(qi, first)
-            if band_w is not None:
+            if window is not None:
                 last = jnp.clip(
-                    (ki * block_kv + block_kv - 1 + band_w - 1 - q_off)
+                    (ki * block_kv + block_kv - 1 + window - 1 - q_off)
                     // block_q,
                     0, num_q - 1)
                 qi = jnp.minimum(qi, last)
@@ -866,8 +814,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     segment_ids: Optional[jnp.ndarray] = None,
                     window: Optional[int] = None,
                     bwd_block_q: Optional[int] = None,
-                    bwd_block_kv: Optional[int] = None,
-                    window_impl: Optional[str] = None) -> jnp.ndarray:
+                    bwd_block_kv: Optional[int] = None) -> jnp.ndarray:
     """Flash attention over [B, S, H, D] tensors.
 
     Head dims that are sublane-aligned (multiple of 8) run unpadded: Mosaic
@@ -897,11 +844,6 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     window: optional sliding-window size (requires causal): token i
     attends tokens (i-window, i] only — O(S*window) compute AND HBM
     reads (out-of-band blocks' fetches are elided via index-map clamps).
-
-    window_impl: "banded" (default; also via DS_FLASH_WINDOW_IMPL) keeps
-    the O(S*W) index-map clamps; "masked" expresses the window purely as
-    an in-body mask over plain causal geometry — O(S^2) reads (see
-    _norm_window).
     """
     B, S, H, D = q.shape
     Hkv = k.shape[2]
@@ -914,8 +856,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         assert k.shape[1] == S, "segment_ids requires self-attention (Skv == S)"
     if window is not None:
         assert causal, "sliding window attention requires causal=True"
-        assert isinstance(window, tuple) or window >= 1
-        window = resolve_window_impl(window, window_impl)
+        assert window >= 1
     q, k, v, D, Dp = _pad_heads(q, k, v)
     # kernel-internal layout is [B, H, S, D]
     q = q.transpose(0, 2, 1, 3)

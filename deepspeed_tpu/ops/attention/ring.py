@@ -73,8 +73,8 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from deepspeed_tpu.ops.attention.flash import (
-    NEG_INF, _norm_window, _pad_heads, flash_block_bwd_t,
-    flash_block_fwd_t, refuse_partial_manual, resolve_window_impl)
+    NEG_INF, _pad_heads, flash_block_bwd_t, flash_block_fwd_t,
+    refuse_partial_manual)
 
 
 def _largest_divisor(n: int, cap: int) -> int:
@@ -115,12 +115,9 @@ def _num_steps(n: int, S_loc: int, causal: bool, window) -> int:
     """Ring hops that can ever intersect the attention band. For causal
     sliding-window attention, block i's closest key is i*S_loc - (S_loc-1)
     tokens behind the query — once that is >= window the step is dead for
-    EVERY device and the rotation chain stops early. (Host arithmetic:
-    the early stop applies to the masked impl too — a dead step is dead
-    regardless of how in-band blocks mask.)"""
-    win = _norm_window(window)[0]
-    if causal and win is not None:
-        return min(n, -(-(win + S_loc - 1) // S_loc))
+    EVERY device and the rotation chain stops early."""
+    if causal and window is not None:
+        return min(n, -(-(window + S_loc - 1) // S_loc))
     return n
 
 
@@ -128,17 +125,13 @@ def _step_cfg(i: int, S_loc: int, causal: bool, window):
     """Static mask geometry of ring step i: (causal, q_off, window) for
     the local block call. Step 0 is self-attention; step i >= 1 sees keys
     exactly i*S_loc tokens behind every query, so causality is automatic
-    (mask-free) unless a sliding window cuts a band through the block.
-    ``window`` may be the tagged ("masked", W) form — geometry uses the
-    int, but the RETURNED window keeps the tag so the flash block leafs
-    pick the requested impl (flash._norm_window)."""
-    win = _norm_window(window)[0]
+    (mask-free) unless a sliding window cuts a band through the block."""
     if not causal:
         return False, 0, None
     if i == 0:
         return True, 0, window
     off = i * S_loc
-    if win is None or off + S_loc - 1 < win:
+    if window is None or off + S_loc - 1 < window:
         return False, 0, None       # fully in band: no masking at all
     return True, off, window
 
@@ -149,7 +142,6 @@ def _step_cfg(i: int, S_loc: int, causal: bool, window):
 
 def _mask_scores(s, rows, cols, blk_causal, window, qsegs, ksegs, kvm):
     """Apply causal/window/segment/validity masks to [B, H, Sq, c]."""
-    window = _norm_window(window)[0]     # mask arithmetic needs the int
     if blk_causal:
         m = rows[None, None, :, None] >= cols[None, None, None, :]
         if window is not None:
@@ -555,8 +547,7 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                    use_flash: Optional[bool] = None,
                    block_q: int = 512, block_kv: int = 512,
                    chunk: int = 1024,
-                   layout: str = "contiguous",
-                   window_impl: Optional[str] = None) -> jnp.ndarray:
+                   layout: str = "contiguous") -> jnp.ndarray:
     """Exact (causal) attention with the sequence dim sharded over ``axis``.
 
     q,k,v: [B, S, H, D] global arrays whose S dim is (or will be) sharded
@@ -588,9 +579,6 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         scale = 1.0 / np.sqrt(q.shape[-1])
     if window is not None:
         assert causal, "sliding window requires causal attention"
-        # tag for the masked implementation when asked for; the tag
-        # rides the nondiff window arg into the flash block leafs
-        window = resolve_window_impl(window, window_impl)
     if layout not in ("contiguous", "zigzag"):
         raise ValueError(f"unknown ring layout {layout!r}")
     if layout == "zigzag":

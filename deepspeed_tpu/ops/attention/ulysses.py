@@ -35,8 +35,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 def _ulysses_local(q, k, v, segs, mask, *, axis: str, causal: bool,
                    scale: float, use_flash: bool, block_q: int,
                    block_kv: int, window: Optional[int],
-                   bwd_block_q: Optional[int], bwd_block_kv: Optional[int],
-                   window_impl: Optional[str] = None):
+                   bwd_block_q: Optional[int], bwd_block_kv: Optional[int]):
     """Inside shard_map: q local [B, S_loc, H, D]; k/v may carry Hkv < H
     heads (GQA) -> out [B, S_loc, H, D]. segs/mask: [B, S_loc] or None."""
     sp = jax.lax.axis_size(axis)
@@ -71,7 +70,7 @@ def _ulysses_local(q, k, v, segs, mask, *, axis: str, causal: bool,
         out = flash_attention(qh, kh, vh, causal=causal, scale=scale,
                               block_q=block_q, block_kv=block_kv,
                               segment_ids=full_segs, kv_mask=full_mask,
-                              window=window, window_impl=window_impl,
+                              window=window,
                               bwd_block_q=bwd_block_q,
                               bwd_block_kv=bwd_block_kv)
     else:
@@ -94,8 +93,7 @@ def ulysses_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                       kv_mask: Optional[jnp.ndarray] = None,
                       window: Optional[int] = None,
                       bwd_block_q: Optional[int] = None,
-                      bwd_block_kv: Optional[int] = None,
-                      window_impl: Optional[str] = None) -> jnp.ndarray:
+                      bwd_block_kv: Optional[int] = None) -> jnp.ndarray:
     """Exact (causal) attention with the sequence dim sharded over ``axis``
     via head<->sequence all-to-alls. q,k,v: [B, S, H, D] global arrays.
 
@@ -118,7 +116,7 @@ def ulysses_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     inner = partial(_ulysses_local, axis=axis, causal=causal, scale=scale,
                     use_flash=use_flash, block_q=block_q, block_kv=block_kv,
                     window=window, bwd_block_q=bwd_block_q,
-                    bwd_block_kv=bwd_block_kv, window_impl=window_impl)
+                    bwd_block_kv=bwd_block_kv)
     spec = P(None, axis, None, None)
     tok_spec = P(None, axis)
     args = [q, k, v]

@@ -91,9 +91,6 @@ FLAGS: Dict[str, Flag] = dict([
     _mk("DS_PAGED_DECODE_IMPL", "str", None,
         "paged-decode kernel override ('pallas'/'gather'); unset picks "
         "the platform default (pallas on TPU, gather elsewhere)"),
-    _mk("DS_FLASH_WINDOW_IMPL", "str", "banded",
-        "windowed flash-attention implementation ('banded'/'masked'); "
-        "both compile on the chip, ROADMAP D6 keeps one"),
     _mk("DS_INT8_FUSED", "bool", False,
         "route int8 dense entries through the Pallas fused "
         "dequant-matmul kernel (TPU-only experiment; models/gpt.py)"),

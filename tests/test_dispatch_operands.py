@@ -8,10 +8,10 @@ Checked here: (a) every dispatch of a serving run is bit-equal to
 operand by operand from the scheduler's HOST state, greedy and sampled
 with a repetition penalty, across eviction and resume; (b) the spans'
 ``h2d`` / ``d2h`` and the ``sampler_mask_uploads`` counter; (c) nothing
-compiles in steady state; (d) the programs AS THE ENGINE JITS THEM,
+compiles in steady state. That the programs AS THE ENGINE JITS THEM,
 compiled ahead of time for a v5e at the GPT-2 XL cell's sizes, hold no
-copy of the pool (tests/test_pool_layout_aot.py compiles the functions
-without the shell).
+copy of the pool is read in tests/test_pool_layout_aot.py, off the one
+compile that file's readers share.
 """
 
 import jax
@@ -25,12 +25,9 @@ from deepspeed_tpu.inference.engine import (InferenceEngine, _packed,
 from deepspeed_tpu.inference.serving import ServeRequest, ServingEngine
 from deepspeed_tpu.models import gpt
 from deepspeed_tpu.telemetry import Telemetry
-from deepspeed_tpu.telemetry.costs import parse_provenance, pool_copy_bytes
 from deepspeed_tpu.utils.compile_guard import CompileWatch
 
 from exaone_moe_util import tiny_config, tiny_params
-from test_pool_layout_aot import (CELL, TEMP_LIMIT,  # noqa: F401 (v5e: a fixture)
-                                  _cell_programs, v5e)
 
 
 def _engine(model):
@@ -225,41 +222,3 @@ def test_steady_state_compiles_nothing(devices):
     assert steps >= 20
     assert eng._prefill_slot._cache_size() == 1
     assert eng._decode_slots._cache_size() == 1
-
-
-@pytest.mark.parametrize("program", ["prefill_slot", "decode_slots"])
-def test_shelled_programs_hold_no_copy_of_the_pool(v5e, program):
-    """The cell's two programs behind the packed operand, with the
-    engine's own jit options and the layouts its wrappers build; the
-    functions, the skeleton weights and the operands' shapes are
-    tests/test_pool_layout_aot.py's."""
-    (L, N), programs = _cell_programs(v5e)
-    plain, args = {name: (fn, a) for name, fn, a in programs}[program]
-    params, pool = args[:2]
-    zeros = [np.zeros(a.shape) for a in args[3:7]]
-    V = args[-1].shape[-1]
-    if program == "prefill_slot":
-        B = CELL["serving"]["num_slots"]
-        lanes, _ = InferenceEngine._samp_lanes(None, 1, V, scalar=True)
-        parts = (*(("i", z) for z in zeros), *lanes)
-    else:
-        B = args[3].shape[0]
-        lanes, _ = InferenceEngine._samp_lanes(None, B, V)
-        parts = (*(("i", z) for z in zeros[:3]), ("b", zeros[3]),
-                 ("static", "pallas"), *lanes)
-    packed, layout = pack_operands(*parts)
-    name = f"serve_{program}"
-    jitted = jax.jit(_packed(plain.__wrapped__, name),
-                     static_argnames=("layout",),
-                     donate_argnames=("k_pool", "v_pool", "scales"))
-    exe = jitted.trace(
-        params, pool, pool,
-        jax.ShapeDtypeStruct(packed.shape, jnp.int32, sharding=v5e), layout,
-        jax.ShapeDtypeStruct((B, V), jnp.bool_, sharding=v5e)) \
-        .lower(lowering_platforms=("tpu",)).compile()
-    text = exe.as_text()
-    assert f"HloModule jit_{name}" in text
-    assert pool_copy_bytes(parse_provenance(text), (N, L * N)) == 0
-    if program == "decode_slots":
-        assert "paged_decode" in text
-    assert exe.memory_analysis().temp_size_in_bytes < TEMP_LIMIT

@@ -213,7 +213,8 @@ def test_gqa_grads_parity(devices, causal, S, blk):
                                    rtol=5e-3, atol=5e-3, err_msg=n)
 
 
-@pytest.mark.parametrize("blk", [128, 512])
+# 256: a block with no second sub-tile (flash._sub_tile), two a side
+@pytest.mark.parametrize("blk", [128, 256, 512])
 @pytest.mark.parametrize("window", [32, 100, 256])
 def test_sliding_window_forward_parity(devices, window, blk):
     q, k, v = _rand_qkv(B=1, S=512, H=2, D=32)
@@ -229,7 +230,11 @@ def test_sliding_window_forward_parity(devices, window, blk):
     # the window's lower edge crosses the one block, and its sub-tiles
     pytest.param(512, 512, 96, id="S512-blk512-W96"),
     # two blocks a row: the diagonal one, and one the lower edge crosses
-    pytest.param(1024, 512, 300, id="S1024-blk512-W300")])
+    pytest.param(1024, 512, 300, id="S1024-blk512-W300"),
+    # blocks of one sub-tile: the single product under a window
+    pytest.param(512, 256, 96, id="S512-blk256-W96"),
+    # the lower edge leaves the sub-tiles on the diagonal
+    pytest.param(512, 512, 300, id="S512-blk512-W300")])
 def test_sliding_window_grads_parity(devices, S, blk, W):
     q, k, v = _rand_qkv(B=1, S=S, H=2, D=32, seed=11)
 
@@ -261,62 +266,19 @@ def test_sliding_window_model_matches_reference(devices):
                                rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("window", [32, 100, 256])
-def test_sliding_window_masked_impl_forward_parity(devices, window):
-    """The "masked" fallback (in-body mask over plain causal geometry —
-    the Mosaic-proven construct set; see _norm_window) must match both
-    the dense reference and the banded implementation exactly: the two
-    impls differ only in which blocks are fetched/skipped, never in
-    what any in-band block computes."""
-    q, k, v = _rand_qkv(B=1, S=512, H=2, D=32)
-    masked = F.flash_attention(q, k, v, causal=True, block_q=128,
-                               block_kv=128, window=window,
-                               window_impl="masked")
-    ref = F.mha_reference(q, k, v, causal=True, window=window)
-    np.testing.assert_allclose(np.asarray(masked), np.asarray(ref),
-                               rtol=2e-3, atol=2e-3)
-    banded = F.flash_attention(q, k, v, causal=True, block_q=128,
-                               block_kv=128, window=window,
-                               window_impl="banded")
-    np.testing.assert_allclose(np.asarray(masked), np.asarray(banded),
-                               rtol=1e-6, atol=1e-6)
-
-
-@pytest.mark.parametrize("blk", [128, 512])
-def test_sliding_window_masked_impl_grads_parity(devices, blk):
-    q, k, v = _rand_qkv(B=1, S=512, H=2, D=32, seed=11)
-    W = 96
-
-    def loss_m(q, k, v):
-        return (F.flash_attention(q, k, v, causal=True, block_q=blk,
-                                  block_kv=blk, window=W,
-                                  window_impl="masked") ** 2).sum()
-
-    def loss_r(q, k, v):
-        return (F.mha_reference(q, k, v, causal=True, window=W) ** 2).sum()
-
-    gm = jax.grad(loss_m, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_r, argnums=(0, 1, 2))(q, k, v)
-    for a, b, n in zip(gm, gr, "qkv"):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=5e-3, atol=5e-3, err_msg=n)
-
-
-def test_window_impl_env_default(devices, monkeypatch):
-    """DS_FLASH_WINDOW_IMPL=masked flips the default, so hardware
-    deployments can quarantine the banded kernel without code changes
-    (PARITY.md note)."""
+def test_window_impl_selector_is_gone(devices):
+    """There is one window geometry. A stale ``window_impl=`` /
+    ``attn_window_impl=`` fails loudly; it cannot silently run another
+    kernel than the one its author asked for."""
+    from deepspeed_tpu.models import gpt as gpt_lib
     q, k, v = _rand_qkv(B=1, S=256, H=2, D=32)
-    monkeypatch.setenv("DS_FLASH_WINDOW_IMPL", "masked")
-    out = F.flash_attention(q, k, v, causal=True, block_q=128,
-                            block_kv=128, window=64)
-    ref = F.mha_reference(q, k, v, causal=True, window=64)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-3, atol=2e-3)
-    monkeypatch.setenv("DS_FLASH_WINDOW_IMPL", "bogus")
-    with pytest.raises(ValueError, match="window impl"):
-        F.flash_attention(q, k, v, causal=True, block_q=128,
-                          block_kv=128, window=64)
+    with pytest.raises(TypeError, match="window_impl"):
+        F.flash_attention(q, k, v, causal=True, block_q=128, block_kv=128,
+                          window=64, window_impl="masked")
+    with pytest.raises(TypeError, match="attn_window_impl"):
+        gpt_lib.GPTConfig(vocab_size=64, n_layers=1, n_heads=2, d_model=16,
+                          max_seq_len=64, attn_window=8,
+                          attn_window_impl="masked")
 
 
 @pytest.mark.parametrize("S,blk,window", [
@@ -423,8 +385,8 @@ def test_block_entry_points_q_off(devices, q_off, window):
     pytest.param((2048, 2048, 1024, 1024, True), (36, 64), id="S2048"),
     # the window's lower edge is skipped by the same walk
     pytest.param((1024, 1024, 1024, 1024, True, 300), (9, 16), id="W300"),
-    pytest.param((1024, 1024, 1024, 1024, True, ("masked", 300)), (10, 16),
-                 id="W300-masked-impl"),
+    pytest.param((1024, 1024, 512, 512, True, 300), (9, 16),
+                 id="W300-blk512"),
     # ring steps: the diagonal one, and one the window's lower edge cuts
     pytest.param((512, 512, 512, 512, True, None, 0), (3, 4), id="ring0"),
     pytest.param((512, 512, 512, 512, True, 512, 512), (3, 4),

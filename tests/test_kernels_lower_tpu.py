@@ -63,12 +63,12 @@ def test_flash_forward_and_backward_lower(H, Hkv, D, blk):
 def test_flash_masks_segments_and_windows_lower():
     q, m, sg = S((2, 1024, 8, 64)), S((2, 1024), jnp.float32), \
         S((2, 1024), jnp.int32)
-    for impl in ("banded", "masked"):
-        def fl(q, k, v, m, sg, impl=impl):
-            return flash.flash_attention(
-                q, k, v, causal=True, block_q=256, block_kv=256, kv_mask=m,
-                segment_ids=sg, window=300, window_impl=impl)
-        assert "tpu_custom_call" in lower_tpu(fl, q, q, q, m, sg)
+
+    def fl(q, k, v, m, sg):
+        return flash.flash_attention(
+            q, k, v, causal=True, block_q=256, block_kv=256, kv_mask=m,
+            segment_ids=sg, window=300)
+    assert "tpu_custom_call" in lower_tpu(fl, q, q, q, m, sg)
 
 
 # the K-EXAONE cell's two tables: (slots, table entries, window)

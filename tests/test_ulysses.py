@@ -194,16 +194,14 @@ def test_ulysses_packed_gpt_trains(devices):
     assert np.isfinite(l_sp)
 
 
-
-def test_ulysses_window_masked_impl_matches_dense(devices):
-    """window_impl='masked' (the PARITY.md quarantine fallback) must
-    thread through the SP path too — a config that requests it under
-    Ulysses may never silently compile the banded kernel."""
-    from deepspeed_tpu.ops.attention.flash import mha_reference
+def test_ulysses_window_flash_matches_dense(devices, pallas_interpret):
+    """The window reaches the flash kernel through the SP path: after the
+    seq->head all-to-all each rank runs the banded kernel over full rows
+    (blocks of 32: the window's lower edge skips whole blocks)."""
     mesh = make_mesh(MeshSpec(data=1, sequence=8))
     q, k, v = _qkv(B=2, S=64, H=8, D=16)
     out = ulysses_attention(q, k, v, mesh, causal=True, window=16,
-                            window_impl="masked")
+                            use_flash=True, block_q=32, block_kv=32)
     ref = mha_reference(q, k, v, causal=True, window=16)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)

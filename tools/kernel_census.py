@@ -60,7 +60,7 @@ def _rand(r, shape, dtype=jnp.bfloat16):
 def flash_rows():
     """Forward + all three gradients of flash_attention vs mha_reference,
     at the model shapes and over the feature matrix (mask, segments,
-    banded and masked windows, backward tiles)."""
+    windows, backward tiles)."""
     r = np.random.default_rng(0)
     S = 1024
     mask = jnp.asarray((r.random((2, S)) > 0.2).astype(np.float32))
@@ -72,14 +72,11 @@ def flash_rows():
     cases += [
         ("flash kv_mask", small, 256, {"kv_mask": mask}),
         ("flash segments", small, 256, {"segment_ids": segs}),
-        ("flash window banded", small, 256,
-         {"window": 256, "window_impl": "banded"}),
-        ("flash window masked", small, 256,
-         {"window": 256, "window_impl": "masked"}),
+        ("flash window banded", small, 256, {"window": 256}),
         ("flash window banded GQA+segments", (8, 2, 64), 256,
-         {"window": 256, "window_impl": "banded", "segment_ids": segs}),
+         {"window": 256, "segment_ids": segs}),
         ("flash window banded H25 W300", (25, 25, 64), 512,
-         {"window": 300, "window_impl": "banded"}),
+         {"window": 300}),
         ("flash bwd tiles 128", small, 256,
          {"bwd_block_q": 128, "bwd_block_kv": 128}),
     ]
